@@ -1,0 +1,717 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that paddle-tpu still starts on the chip.
+
+    python chip_smoke.py             # one TPU chip (what the driver runs)
+    python chip_smoke.py --chips 4   # the sharded trainer on a 4-chip mesh, and
+                                     # what it is compared with; nothing else
+
+One process: it imports jax once and owns the chip. It drives the two main
+paths through the entry points users call, at the full width of
+``gpt3-1.3b`` (hidden 2048, 16 heads x 128, ffn 8192, vocab 50304, context
+2048; random weights from ``--seed``):
+
+  device   platform must be ``tpu`` — anything else is an error, never a CPU pass
+  kernels  flash attention (fwd + bwd) and the ragged paged-attention kernel
+           (bf16 and int8 pool, MHA and GQA 16/4), compiled (interpret=False),
+           against their jnp references
+  serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
+           ``serve_llm``; HTTP ``POST /generate`` checked against
+           ``net.generate``; once with attention_impl="xla", once "pallas"
+  train    ``Model.prepare(amp_configs="O1")`` + ``Model.fit`` at 1.3B width,
+           flash attention and the fused loss on, AdamW; depth cut to what
+           one chip holds (printed as ``reduced``)
+
+Every phase prints one JSON object on its own line. The script exits non-zero
+at the first thing that is wrong and prints the ``ok`` line only when every
+phase passed. Nothing here is a benchmark: the seconds printed are smoke
+readings (compilation included where said).
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import sys
+import threading
+import time
+import urllib.request
+
+MODEL = "gpt3-1.3b"
+SEQ = 2048
+PAGE = 16
+MAX_SEQS = 8
+NEW_TOKENS = 64
+# The default-impl ("xla") prefill gathers max_len of K and V in f32 for every
+# query row: rows x max_len x kv_heads x d x 4 B, twice (K and V), per layer
+# (ops/paged_attention._gathered_attention). At max_len 2048 and 16 x 128
+# that is 33.5 MB a row: the TPU compiler's memory analysis of the mixed-tick
+# program read 5.3 GiB of temporaries at prefill_chunk 128 (136 rows) — too
+# much beside 2.5 GiB of weights and a pool meant to fill the rest — and half
+# that at 64, so the smoke serves with prefill_chunk 64 and keeps max_len at
+# the model's published 2048.
+PREFILL_CHUNK = 64
+DECODE_TICKS = 4
+# Share of the device's bytes_limit a phase plans to use.
+HBM_SHARE = 0.90
+# Kernel vs jnp reference, on O(1) values: bf16 inputs, f32 accumulation, and
+# a reference whose f32 einsum runs at the TPU's default (bf16-pass) precision.
+KERNEL_ATOL = 2e-2
+KERNEL_RTOL = 2e-2
+# The flash kernel's gradients, each divided by its reference's largest
+# magnitude: bf16 gradients of a sum of squares over 2048 keys.
+GRAD_ATOL = 4e-2
+# Greedy streams: the repo's contract is token identity with net.generate.
+# With bf16 weights two programs that sum in a different order can break a
+# near-tie; a differing token is admitted only when the reference's own logits
+# (teacher-forced on the engine's stream) put it within TIE_TOL of the top
+# logit: four bf16 ulps at the top logits' magnitude (4..8 -> ulp 2^-5).
+TIE_TOL = 0.125
+# One-device vs mesh loss streams of the same model, seed and batch (bf16
+# compute, different reduction orders across shards).
+MESH_LOSS_RTOL = 1e-2
+# First-step loss, flash + fused loss vs the XLA attention + dense logits.
+REF_LOSS_RTOL = 1e-2
+# No device of the mesh may hold more than this multiple of the mean.
+BALANCE = 1.25
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def emit(obj) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def check(cond, msg) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def free_device_memory() -> None:
+    """Between phases: dropped models and engines release their buffers at
+    collection, and jit caches may still hold what they closed over."""
+    import jax
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()
+
+
+def hbm(dev):
+    """(bytes_limit, bytes_in_use) of a device."""
+    stats = dev.memory_stats() or {}
+    check("bytes_limit" in stats, f"{dev} reports no memory_stats()")
+    return int(stats["bytes_limit"]), int(stats["bytes_in_use"])
+
+
+# ---------------------------------------------------------------------------
+# device
+# ---------------------------------------------------------------------------
+
+_cache_events = {"hits": 0, "misses": 0}
+
+
+def phase_device(want_count: int) -> dict:
+    import jax
+    devs = jax.devices()
+    dev = devs[0]
+    if dev.platform != "tpu":
+        raise SmokeFailure(
+            f"jax found platform {dev.platform!r}, not 'tpu': chip_smoke "
+            f"runs on the chip or fails")
+    check(len(devs) == want_count,
+          f"expected {want_count} device(s), jax reports {len(devs)}")
+    from paddle_tpu.core import compile_cache
+    cache_dir, origin = compile_cache.enable()
+
+    def on_event(name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            _cache_events["hits"] += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            _cache_events["misses"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devs)}
+    emit({"phase": "device", "jax": jax.__version__, **device,
+          "bytes_limit": hbm(dev)[0],
+          "compile_cache_dir": cache_dir,
+          "compile_cache_dir_from": origin})
+    return device
+
+
+# ---------------------------------------------------------------------------
+# kernels
+# ---------------------------------------------------------------------------
+
+def _max_err(got, ref, atol=KERNEL_ATOL):
+    import numpy as np
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    check(np.isfinite(got).all(), "kernel output is not finite")
+    err = np.abs(got - ref)
+    ok = bool((err <= atol + KERNEL_RTOL * np.abs(ref)).all())
+    return float(err.max()), ok
+
+
+def phase_kernels(seed: int, heads: int = 16, d: int = 128,
+                  seq: int = SEQ) -> None:
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.ops.flash_attention import flash_attention
+    from paddle_tpu.ops.paged_attention import (
+        QuantizedKV, paged_attention_kernel, quantize_kv,
+        ragged_paged_attention)
+
+    fa_mod = importlib.import_module("paddle_tpu.ops.flash_attention")
+    check(fa_mod.INTERPRET is False,
+          "the Pallas interpret switch is on: kernels would not compile")
+    t0 = time.time()
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+
+    # flash attention, forward and backward, against the XLA branch of
+    # scaled_dot_product_attention
+    shape = (2, seq, heads, d)
+    q, k, v = (jax.random.normal(kk, shape, jnp.bfloat16)
+               for kk in keys[:3])
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) ** 2).sum()
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def xla(q, k, v):
+        return F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, training=False, use_flash=False)
+
+    out_f = jax.jit(flash)(q, k, v)
+    out_x = jax.jit(xla)(q, k, v)
+    g_f = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    g_x = jax.jit(jax.grad(loss(xla), argnums=(0, 1, 2)))(q, k, v)
+    errs = {"fwd": _max_err(out_f, out_x)}
+    for name, a, b in zip(("dq", "dk", "dv"), g_f, g_x):
+        # gradients of a sum of squares over `seq` keys: compare at their
+        # own scale
+        s = float(jnp.abs(b.astype(jnp.float32)).max()) or 1.0
+        errs[name] = _max_err(a.astype(jnp.float32) / s,
+                              b.astype(jnp.float32) / s, GRAD_ATOL)
+    emit({"phase": "kernels", "kernel": "flash_attention",
+          "shape": list(shape), "dtype": "bfloat16", "interpret": False,
+          "max_abs_err": {n: round(e, 5) for n, (e, _) in errs.items()},
+          "atol": KERNEL_ATOL, "grad_atol_at_unit_scale": GRAD_ATOL,
+          "rtol": KERNEL_RTOL})
+    check(all(ok for _, ok in errs.values()),
+          f"flash attention disagrees with the XLA reference: {errs}")
+
+    # ragged paged attention: rows with full, partial-page, one-token and
+    # empty contexts over a shuffled page pool
+    rng = np.random.RandomState(seed)
+    pages_per_seq = seq // PAGE
+    rows = 24
+    num_pages = rows * pages_per_seq // 4 + 1
+    lens = rng.randint(1, seq + 1, rows)
+    lens[:4] = (seq, 1, PAGE + 3, 0)
+    tables = rng.randint(1, num_pages, (rows, pages_per_seq))
+    for kv_heads in (heads, heads // 4):
+        pool_shape = (num_pages, PAGE, kv_heads, d)
+        kf = jax.random.normal(keys[3], pool_shape, jnp.float32)
+        vf = jax.random.normal(keys[4], pool_shape, jnp.float32)
+        qq = jax.random.normal(keys[5], (rows, heads, d), jnp.bfloat16)
+        for pool in ("bf16", "int8"):
+            if pool == "int8":
+                kq, ks = quantize_kv(kf)
+                vq, vs = quantize_kv(vf)
+                kk, vv = QuantizedKV(kq, ks), QuantizedKV(vq, vs)
+            else:
+                kq, vq, ks, vs = (kf.astype(jnp.bfloat16),
+                                  vf.astype(jnp.bfloat16), None, None)
+                kk, vv = kq, vq
+            tb, ln = jnp.asarray(tables, jnp.int32), jnp.asarray(
+                lens, jnp.int32)
+            got = jax.jit(lambda q, k, v, ks, vs: paged_attention_kernel(
+                q, k, v, tb, ln, interpret=False, k_scales=ks,
+                v_scales=vs))(qq, kq, vq, ks, vs)
+            ref = jax.jit(lambda q, k, v: ragged_paged_attention(
+                q, k, v, tb, ln, impl="xla"))(qq, kk, vv)
+            err, ok = _max_err(got, ref)
+            emit({"phase": "kernels", "kernel": "paged_attention",
+                  "pool": pool, "heads": heads, "kv_heads": kv_heads,
+                  "head_dim": d, "page_size": PAGE, "rows": rows,
+                  "max_len": seq, "interpret": False,
+                  "max_abs_err": round(err, 5), "atol": KERNEL_ATOL,
+                  "rtol": KERNEL_RTOL})
+            check(ok, f"paged attention ({pool}, kv_heads {kv_heads}) "
+                      f"disagrees with _gathered_attention: {err}")
+            check(float(jnp.abs(got[3].astype(jnp.float32)).max()) == 0.0,
+                  "an empty context must give a zero row")
+    emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+
+def make_prompts(seed: int, vocab: int, lengths, shared_prefix: int):
+    """Random prompts; the first two share a page-aligned prefix."""
+    import numpy as np
+    rng = np.random.RandomState(seed + 1)
+    check(shared_prefix % PAGE == 0, "the shared prefix is page-aligned")
+    prefix = rng.randint(0, vocab, shared_prefix).tolist()
+    prompts = []
+    for i, n in enumerate(lengths):
+        body = rng.randint(0, vocab, n).tolist()
+        prompts.append(prefix + body[shared_prefix:] if i < 2 else body)
+    return prompts
+
+
+def post_generate(url: str, prompt, new_tokens: int) -> dict:
+    body = json.dumps({"prompt_ids": prompt, "max_new_tokens": new_tokens,
+                       "temperature": 0.0}).encode()
+    req = urllib.request.Request(
+        url + "/generate", data=body,
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=900) as resp:
+        check(resp.status == 200, f"POST /generate -> {resp.status}")
+        return json.loads(resp.read())
+
+
+def by_length(prompts):
+    """Indices of the prompts, grouped by length. net.generate is an eager
+    loop whose every op compiles once per shape (about 100 s a new prompt
+    length at 24 layers on the chip): prompts of one length go through it,
+    and through the teacher-forced forward below, as one batch."""
+    groups = {}
+    for i, p in enumerate(prompts):
+        groups.setdefault(len(p), []).append(i)
+    return list(groups.values())
+
+
+def check_streams(net, prompts, outs, refs) -> list:
+    """Hold each greedy stream to net.generate's: identical, or every token
+    from the first difference on within TIE_TOL of the top of the
+    reference's own teacher-forced logits."""
+    import jax.numpy as jnp
+    import numpy as np
+    verdicts = [None] * len(prompts)
+    for group in by_length(prompts):
+        for i in group:
+            check(len(outs[i]) == len(refs[i]),
+                  f"stream {i} has {len(outs[i])} tokens, reference "
+                  f"{len(refs[i])}")
+            if outs[i] == refs[i]:
+                verdicts[i] = {"identical": True}
+        if all(verdicts[i] for i in group):
+            continue
+        n = len(prompts[group[0]])
+        ids = jnp.asarray([list(prompts[i]) + list(outs[i][:-1])
+                           for i in group], jnp.int32)
+        logits = np.asarray(net(ids)[:, n - 1:], np.float32)
+        for row, i in enumerate(group):
+            if verdicts[i]:
+                continue
+            out = outs[i]
+            first = next(j for j, (a, b) in enumerate(zip(out, refs[i]))
+                         if a != b)
+            margins = (logits[row].max(-1)
+                       - logits[row][np.arange(len(out)), out])
+            verdicts[i] = {
+                "identical": False, "first_diff": first,
+                "margin_at_first_diff": round(float(margins[first]), 4),
+                "max_margin_from_there": round(
+                    float(margins[first:].max()), 4),
+                "tie_tol": TIE_TOL}
+            check(float(margins[first:].max()) <= TIE_TOL,
+                  f"stream {i} leaves the reference beyond a tie: "
+                  f"{verdicts[i]}")
+    return verdicts
+
+
+def serve_once(net, impl: str, prompts, refs, num_pages: int,
+               new_tokens: int) -> list:
+    from paddle_tpu.inference.llm import LLMEngine, serve_llm
+    from paddle_tpu.observability import metrics as obs_metrics
+    from paddle_tpu.observability import perf
+
+    def device_errors():
+        fam = obs_metrics.default_registry().get("llm_device_errors_total")
+        return 0.0 if fam is None else float(fam.value)
+
+    def compile_seconds():
+        return perf.instance().breakdown().get("llm", {}).get(
+            "phases", {}).get("compile", 0.0)
+
+    err0, comp0, t0 = device_errors(), compile_seconds(), time.time()
+    eng = LLMEngine(net, max_seqs=MAX_SEQS, page_size=PAGE,
+                    num_pages=num_pages, max_len=SEQ, kv_dtype="bf16",
+                    attention_impl=impl, prefill_chunk=PREFILL_CHUNK,
+                    decode_ticks_per_dispatch=DECODE_TICKS)
+    srv = serve_llm(eng)
+    try:
+        url = "http://%s:%d" % srv.server_address[:2]
+        outs = [None] * len(prompts)
+        errors = []
+
+        def ask(i):
+            try:
+                outs[i] = post_generate(url, prompts[i], new_tokens)
+            except Exception as e:  # noqa: BLE001 — reported below
+                errors.append(f"request {i}: {e!r}")
+
+        ask(0)                      # seeds the prefix cache
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(1, len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        wall = time.time() - t0
+        check(not errors, f"[{impl}] {errors}")
+        streams = [o["output_ids"] for o in outs]
+        programs = sorted(
+            f"{h.kind}{list(h.sig)}" for h in perf.instance().programs()
+            if h.component == "llm")
+        health = eng.health
+        n_err = device_errors() - err0
+        emit({"phase": "serve", "attention_impl": impl, "model": MODEL,
+              "weights": "bfloat16", "kv_dtype": eng.kv_dtype,
+              "num_pages": num_pages,
+              "pool_tokens": (num_pages - 1) * PAGE,
+              "prefill_chunk": PREFILL_CHUNK,
+              "decode_ticks_per_dispatch": DECODE_TICKS,
+              "requests": len(prompts),
+              "prompt_tokens": [len(p) for p in prompts],
+              "tokens_generated": sum(len(s) for s in streams),
+              "truncated": sum(bool(o["truncated"]) for o in outs),
+              "wall_seconds_with_compile": round(wall, 1),
+              "first_dispatch_seconds_per_program_sum": round(
+                  compile_seconds() - comp0, 1),
+              "program_count": len(programs), "programs": programs,
+              "prefix_cache_hit_tokens": eng.n_cached_tokens,
+              "host_dispatches": eng.n_host_dispatches,
+              "engine_health": health,
+              "llm_device_errors_total": n_err})
+        check(health == "healthy" and n_err == 0,
+              f"[{impl}] the engine caught a device or compile error "
+              f"(health {health}, llm_device_errors_total +{n_err})")
+        check(not any(o["truncated"] for o in outs),
+              f"[{impl}] a request was truncated")
+        check(eng.n_cached_tokens >= PAGE,
+              f"[{impl}] the shared prefix was not served from the cache")
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        eng.close()
+    verdicts = check_streams(net, prompts, streams, refs)
+    emit({"phase": "serve", "attention_impl": impl,
+          "vs_net_generate": verdicts,
+          "identical": sum(v["identical"] for v in verdicts)})
+    return streams
+
+
+def phase_serve(seed: int, cfg_overrides=None,
+                lengths=(640, 640, 1500, 640, 1500),
+                shared_prefix: int = 512,
+                new_tokens: int = NEW_TOKENS) -> None:
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.models.gpt import GPTForCausalLM, gpt_config
+
+    cfg = gpt_config(MODEL, hidden_dropout=0.0, attention_dropout=0.0,
+                     **(cfg_overrides or {}))
+    t0 = time.time()
+    pt.seed(seed)
+    net = GPTForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    n_params = sum(int(v.size) for v in net.state_dict().values())
+    prompts = make_prompts(seed, cfg.vocab_size, lengths, shared_prefix)
+    build_s = time.time() - t0
+
+    # the reference streams first, while the device holds only the weights
+    t0 = time.time()
+    refs = [None] * len(prompts)
+    for group in by_length(prompts):
+        toks = net.generate(
+            jnp.asarray([prompts[i] for i in group], jnp.int32),
+            max_new_tokens=new_tokens)
+        for row, i in enumerate(group):
+            refs[i] = [int(t) for t in toks[row, len(prompts[i]):]]
+    ref_s = time.time() - t0
+
+    # pool: most of what the weights leave, after the temporaries of the
+    # widest program (the xla-impl mixed tick: see PREFILL_CHUNK)
+    limit, in_use = hbm(jax.devices()[0])
+    rows = PREFILL_CHUNK + MAX_SEQS
+    row_bytes = SEQ * cfg.num_kv_heads * cfg.head_dim * 4 * 2
+    temp = int(rows * row_bytes * 1.25)
+    page_bytes = (cfg.num_layers * PAGE * cfg.num_kv_heads * cfg.head_dim
+                  * 2 * 2)
+    num_pages = int((limit * HBM_SHARE - in_use - temp) // page_bytes)
+    check(num_pages * PAGE >= sum(lengths) + len(lengths) * new_tokens,
+          f"pool of {num_pages} pages cannot hold the smoke's requests")
+    emit({"phase": "serve", "model": MODEL, "layers": cfg.num_layers,
+          "hidden": cfg.hidden_size, "heads": cfg.num_heads,
+          "head_dim": cfg.head_dim, "vocab": cfg.vocab_size,
+          "params": n_params, "build_seconds": round(build_s, 1),
+          "net_generate_seconds": round(ref_s, 1),
+          "bytes_limit": limit, "weights_bytes_in_use": in_use,
+          "planned_temp_bytes": temp, "kv_pool_bytes": num_pages
+          * page_bytes})
+
+    xla = serve_once(net, "xla", prompts, refs, num_pages, new_tokens)
+    gc.collect()    # the first engine's pool; the eager ops stay compiled
+    pallas = serve_once(net, "pallas", prompts, refs, num_pages, new_tokens)
+    emit({"phase": "serve", "xla_vs_pallas_identical_streams": sum(
+        a == b for a, b in zip(xla, pallas)), "of": len(prompts)})
+    del net
+    free_device_memory()
+
+
+# ---------------------------------------------------------------------------
+# train
+# ---------------------------------------------------------------------------
+
+def train_cfg(layers: int, flash: bool, overrides=None):
+    from paddle_tpu.models.gpt import gpt_config
+    return gpt_config(MODEL, num_layers=layers, hidden_dropout=0.0,
+                      attention_dropout=0.0, use_flash=flash,
+                      fused_loss=flash, **(overrides or {}))
+
+
+def build_trainer(seed: int, cfg, mesh=None):
+    import paddle_tpu as pt
+    from paddle_tpu import parallel
+    from paddle_tpu.models.gpt import (GPTForCausalLM,
+                                       GPTFusedPretrainingCriterion,
+                                       GPTPretrainingCriterion)
+    pt.seed(seed)
+    net = GPTForCausalLM(cfg)
+    model = pt.Model(net)
+    model.prepare(
+        optimizer=pt.optimizer.AdamW(learning_rate=1e-4, parameters=net,
+                                     weight_decay=0.01),
+        loss=(GPTFusedPretrainingCriterion() if cfg.fused_loss
+              else GPTPretrainingCriterion()),
+        amp_configs="O1")
+    if mesh is not None:
+        parallel.distributed_model(model, mesh=mesh)
+    return model
+
+
+def fit_steps(model, ids, steps: int):
+    """``Model.fit`` over ``steps`` copies of one batch; the loss stream."""
+    import numpy as np
+    from paddle_tpu.hapi.callbacks import Callback
+    from paddle_tpu.io import TensorDataset
+
+    class Record(Callback):
+        def __init__(self):
+            super().__init__()
+            self.losses = []
+
+        def on_train_batch_end(self, step, logs=None):
+            self.losses.append(float(logs["loss"]))
+
+    rec = Record()
+    data = np.tile(ids, (steps, 1))
+    t0 = time.time()
+    model.fit(TensorDataset([data, data]), batch_size=ids.shape[0],
+              epochs=1, verbose=0, shuffle=False, callbacks=[rec])
+    return rec.losses, time.time() - t0
+
+
+def check_losses(losses, what: str) -> None:
+    import math
+    check(all(math.isfinite(x) for x in losses),
+          f"{what}: loss is not finite: {losses}")
+    check(losses[-1] < losses[0],
+          f"{what}: loss did not fall on a repeated batch: {losses}")
+
+
+def fit_depth(seed: int, ids, depths, overrides=None):
+    """The deepest of ``depths`` whose compiled train step
+    (``memory_analysis()``: arguments + temporaries) stays inside HBM_SHARE
+    of the device. Returns the prepared model."""
+    import jax
+    from paddle_tpu.parallel import planner
+    limit = hbm(jax.devices()[0])[0]
+    tried = []
+    for layers in depths:
+        model = build_trainer(seed, train_cfg(layers, True, overrides))
+        t0 = time.time()
+        need = planner.measured_step_bytes(model, (ids,), (ids,))
+        tried.append({"layers": layers, "step_bytes": int(need),
+                      "compile_seconds": round(time.time() - t0, 1)})
+        if need <= limit * HBM_SHARE:
+            return model, layers, tried, limit
+        del model
+        free_device_memory()
+    raise SmokeFailure(f"no depth of {list(depths)} fits one chip: {tried}")
+
+
+def reference_first_loss(seed: int, layers: int, ids, overrides=None):
+    """First-step loss of the same model with XLA attention and dense
+    logits (use_flash=False, fused_loss=False): the forward loss at the
+    seed's weights."""
+    model = build_trainer(seed, train_cfg(layers, False, overrides))
+    loss = float(model.eval_batch([ids], [ids])["loss"])
+    del model
+    free_device_memory()
+    return loss
+
+
+def phase_train(seed: int, batch: int = 2, steps: int = 4,
+                depths=(14, 12, 10, 8, 6, 4), overrides=None):
+    import numpy as np
+    from paddle_tpu.models.gpt import PRESETS
+    cfg = train_cfg(depths[0], True, overrides)
+    seq = cfg.max_position_embeddings
+    ids = np.random.RandomState(seed + 2).randint(
+        0, cfg.vocab_size, (batch, seq)).astype(np.int32)
+    # 16 layers compile to 14.4 GiB on the described v5e (sandbox AOT):
+    # over HBM_SHARE of the chip's 15.75 GiB, so the search starts at 14
+    model, layers, tried, limit = fit_depth(seed, ids, depths, overrides)
+    losses, secs = fit_steps(model, ids, steps)
+    del model
+    free_device_memory()
+    ref = reference_first_loss(seed, layers, ids, overrides)
+    rel = abs(losses[0] - ref) / abs(ref)
+    emit({"phase": "train", "model": MODEL, "hidden": cfg.hidden_size,
+          "heads": cfg.num_heads, "ffn": cfg.ffn_hidden_size,
+          "vocab": cfg.vocab_size, "seq": seq, "batch": batch,
+          "amp": "O1", "optimizer": "AdamW", "use_flash": True,
+          "fused_loss": True,
+          "reduced": {"num_layers": [PRESETS[MODEL]["num_layers"], layers]},
+          "depth_search": tried, "bytes_limit": limit,
+          "hbm_share": HBM_SHARE, "steps": steps, "losses": losses,
+          "fit_seconds_with_compile": round(secs, 1),
+          "first_loss_no_flash_no_fused": ref,
+          "first_loss_rel_diff": round(rel, 6), "rtol": REF_LOSS_RTOL})
+    check_losses(losses, "train")
+    check(rel <= REF_LOSS_RTOL,
+          f"first-step loss {losses[0]} vs {ref} without flash and the "
+          f"fused loss: {rel:.4f} > {REF_LOSS_RTOL}")
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: the sharded trainer, and what it is compared with
+# ---------------------------------------------------------------------------
+
+def device_bytes():
+    import jax
+    out = []
+    for d in jax.devices():
+        s = d.memory_stats() or {}
+        out.append({"id": d.id, "bytes_in_use": int(s.get(
+            "bytes_in_use", -1)), "peak_bytes_in_use": int(s.get(
+                "peak_bytes_in_use", -1))})
+    return out
+
+
+def check_balance(rows, key: str, what: str) -> None:
+    vals = [r[key] for r in rows]
+    check(min(vals) >= 0, f"{what}: a device reports no {key}")
+    mean = sum(vals) / len(vals)
+    check(max(vals) <= BALANCE * mean,
+          f"{what}: a device holds {max(vals)} B of {key}, more than "
+          f"{BALANCE} x the mean {mean:.0f}: {rows}")
+
+
+def phase_mesh(seed: int, axes=None, steps: int = 3, cut_batch: int = 2,
+               full_batch: int = 4, depths=(14, 12, 10, 8, 6, 4),
+               full_layers=None, overrides=None) -> None:
+    import numpy as np
+    from paddle_tpu import parallel
+    from paddle_tpu.models.gpt import PRESETS
+    axes = axes or {"fsdp": 2, "tp": 2}
+    full_layers = full_layers or PRESETS[MODEL]["num_layers"]
+    cfg = train_cfg(depths[0], True, overrides)
+    seq = cfg.max_position_embeddings
+    rng = np.random.RandomState(seed + 2)
+    ids = rng.randint(0, cfg.vocab_size, (cut_batch, seq)).astype(np.int32)
+
+    # the comparison: the depth-cut model on one device, then on the mesh
+    model, layers, tried, limit = fit_depth(seed, ids, depths, overrides)
+    one, one_s = fit_steps(model, ids, steps)
+    del model
+    free_device_memory()
+    check_losses(one, "one device")
+
+    mesh = parallel.init_mesh(**axes)
+    try:
+        model = build_trainer(seed, train_cfg(layers, True, overrides),
+                              mesh=mesh)
+        sharded, mesh_s = fit_steps(model, ids, steps)
+        cut_bytes = device_bytes()
+        del model
+        free_device_memory()
+        rel = max(abs(a - b) / abs(a) for a, b in zip(one, sharded))
+        emit({"phase": "mesh_vs_one_device", "model": MODEL,
+              "reduced": {"num_layers": [PRESETS[MODEL]["num_layers"],
+                                         layers]},
+              "depth_search": tried, "axes": axes, "batch": cut_batch,
+              "seq": seq, "steps": steps, "losses_one_device": one,
+              "losses_mesh": sharded, "max_rel_diff": round(rel, 6),
+              "rtol": MESH_LOSS_RTOL,
+              "fit_seconds_with_compile": {"one_device": round(one_s, 1),
+                                           "mesh": round(mesh_s, 1)},
+              "device_bytes": cut_bytes})
+        check_losses(sharded, "mesh")
+        check(rel <= MESH_LOSS_RTOL,
+              f"one-device and mesh loss streams differ by {rel:.4f} > "
+              f"{MESH_LOSS_RTOL}")
+        check_balance(cut_bytes, "bytes_in_use", "cut model on the mesh")
+
+        # full depth on the mesh: the configuration that needs four chips
+        ids = rng.randint(0, cfg.vocab_size,
+                          (full_batch, seq)).astype(np.int32)
+        model = build_trainer(seed, train_cfg(full_layers, True, overrides),
+                              mesh=mesh)
+        losses, secs = fit_steps(model, ids, steps)
+        full_bytes = device_bytes()
+        emit({"phase": "mesh_full_depth", "model": MODEL,
+              "layers": full_layers, "axes": axes, "batch": full_batch,
+              "seq": seq, "steps": steps, "losses": losses,
+              "fit_seconds_with_compile": round(secs, 1),
+              "device_bytes": full_bytes, "bytes_limit": limit,
+              "balance": BALANCE})
+        check_losses(losses, "full depth on the mesh")
+        check_balance(full_bytes, "bytes_in_use", "full depth on the mesh")
+        del model
+        free_device_memory()
+    finally:
+        parallel.set_mesh(None)
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+    t0 = time.time()
+    try:
+        device = phase_device(args.chips)
+        if args.chips == 4:
+            phase_mesh(args.seed)
+        else:
+            phase_kernels(args.seed)
+            phase_serve(args.seed)
+            phase_train(args.seed)
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)
+        return 1
+    emit({"phase": "compile_cache", **_cache_events,
+          "seconds_total": round(time.time() - t0, 1)})
+    emit({"ok": True, "device": device})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

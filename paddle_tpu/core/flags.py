@@ -261,12 +261,6 @@ define_flag("mem_near_oom_fraction", 0.92,
             "ONCE (reason near_oom) — the pre-crash baseline an "
             "actual RESOURCE_EXHAUSTED dump diffs against. 0 "
             "disables.", flag_type=float)
-define_flag("compilation_cache_dir", "",
-            "Persistent XLA compilation cache directory (jax "
-            "jax_compilation_cache_dir), enabled at Model.prepare() "
-            "time. Repeated runs of the same program skip the 10-120 s "
-            "train-step compiles that the train_compile_seconds "
-            "histogram records. Empty disables (in-memory cache only).")
 define_flag("goodput_observability", True,
             "Arm the wall-clock time ledger (observability/goodput.py):"
             " hot paths attribute every second since arming to one "

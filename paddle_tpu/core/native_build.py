@@ -1,9 +1,15 @@
 """Shared build-on-first-use helper for the native (.cc → .so) pieces.
 
-One place for the compile command, mtime-based rebuild check, and the
+One place for the compile command, the rebuild check and the
 ``PTDF_CC`` compiler override used by the datafeed, the sparse
-accessor, and any future native module. (The PJRT predictor keeps its
-own build — it needs the TensorFlow include path.)
+accessor, the PJRT predictor and any future native module.
+
+The ``.so`` files are build products: git ignores them and a checkout
+has none, so first use compiles them from the ``.cc`` beside them. A
+library is rebuilt when its source is newer than the local build. The
+compile writes a temporary file and renames it into place, so several
+processes (test workers, a fleet's replicas) may race to the first
+build.
 """
 
 from __future__ import annotations
@@ -23,7 +29,13 @@ def build_native_lib(src: str, so: str, extra_flags=()) -> str:
         if (not os.path.exists(so) or
                 os.path.getmtime(so) < os.path.getmtime(src)):
             cc = os.environ.get("PTDF_CC", "g++")
+            tmp = f"{so}.{os.getpid()}.tmp"
             cmd = [cc, "-O2", "-std=c++17", "-shared", "-fPIC",
-                   "-pthread", *extra_flags, src, "-o", so]
-            subprocess.run(cmd, check=True, capture_output=True)
+                   "-pthread", *extra_flags, src, "-o", tmp]
+            try:
+                subprocess.run(cmd, check=True, capture_output=True)
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
     return so

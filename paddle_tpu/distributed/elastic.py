@@ -394,8 +394,8 @@ class ElasticManager:
         while True:
             # STAT_ADD wiring (launcher process): a train-with-restart
             # run leaves a non-empty StatRegistry.snapshot() and these
-            # ride the Prometheus/JSONL exports — VERDICT r5's
-            # 8-hours-dead-tunnel failure mode becomes one counter read
+            # ride the Prometheus/JSONL exports — a run that has been
+            # dead for hours becomes one counter read
             stat_add("elastic.generations")
             self._spawn()
             status, code = self._watch_generation()

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import amp
-from ..core import flags, rng
+from ..core import compile_cache, flags, rng
 from ..io import DataLoader, Dataset
 from ..metric import Metric
 from ..nn.layer import Layer, functional_call, split_state
@@ -236,42 +236,6 @@ class _LazyMetricValue(_FloatView):
         return self._val
 
 
-_cache_dir_enabled = None
-
-
-def _enable_compilation_cache(path: str) -> None:
-    """Point jax's persistent compilation cache at ``path`` (flag
-    ``compilation_cache_dir``): repeated runs of the same program reload
-    compiled executables instead of re-running the 10-120 s XLA compiles
-    the train_compile_seconds histogram records. Threshold knobs drop to
-    zero so even fast-compiling steps are cached; failures degrade to
-    the in-memory cache (older jax without CPU-cache support)."""
-    global _cache_dir_enabled
-    if not path or _cache_dir_enabled == path:
-        return
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        for knob, val in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # knob not in this jax version
-                pass
-        # anything jitted before prepare() initialized the cache
-        # singleton as disabled; re-initialize it against the new dir
-        try:
-            from jax._src.compilation_cache import reset_cache
-            reset_cache()
-        except Exception:
-            pass
-        _cache_dir_enabled = path
-    except Exception as e:  # noqa: BLE001 — cache is an optimization
-        import warnings
-        warnings.warn(f"compilation_cache_dir={path!r} not enabled: {e}")
-
-
 class Model:
     """ref: python/paddle/hapi/model.py:915."""
 
@@ -375,7 +339,7 @@ class Model:
         self._reset_mem_scope()
         if _memobs.enabled():
             self._register_memory()
-        _enable_compilation_cache(flags.get_flag("compilation_cache_dir"))
+        compile_cache.enable()
         self._register_status_provider()
 
     def _register_status_provider(self) -> None:
@@ -425,6 +389,12 @@ class Model:
             self._params = dict(trainable)
             self._frozen = dict(frozen)
             self._buffers = dict(buffers)
+            if self._shard_params is not None:
+                # the network still holds the UNSHARDED arrays it was
+                # built with, all on the first device: rebind it to the
+                # sharded trees so that copy is freed (a full f32 model
+                # on one chip of the mesh otherwise)
+                self._sync_state_out()
             built = True
         if self._opt_state is None and self._optimizer is not None:
             self._opt_state = self._optimizer.init_state(self._params)

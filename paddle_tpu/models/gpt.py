@@ -483,7 +483,11 @@ class GPTForCausalLM(Layer):
                 f"prompt {s} + max_new_tokens {max_new_tokens} exceeds "
                 f"max_position_embeddings "
                 f"{self.cfg.max_position_embeddings}")
-        caches = self.init_caches(b, max_len)
+        # the cache holds what the blocks compute: the weights' dtype
+        # (a bf16-cast net writes bf16 K/V)
+        caches = self.init_caches(
+            b, max_len, dtype=next(p for _, p in
+                                   self.named_parameters()).dtype)
         key = jax.random.PRNGKey(seed)
         # prefill
         logits, caches = self(input_ids, caches=caches)

@@ -14,6 +14,7 @@ its own layout assignment so no manual transposes are needed.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Sequence, Tuple, Union
 
@@ -720,6 +721,22 @@ def square_error_cost(input, label):
 # rebuilt as jnp einsum; Pallas flash-attention lives in paddle_tpu.ops)
 # ---------------------------------------------------------------------------
 
+def _flash_shard_spec(mesh, q_shape, k_shape):
+    """PartitionSpec under which the flash kernel runs per device on a
+    multi-device mesh: batch over the data axes (dp, fsdp), heads over
+    tp. None when the mesh has another live axis (pp/sp/ep own their
+    own manual regions) or the shapes do not divide."""
+    from jax.sharding import PartitionSpec
+    if any(a not in ("dp", "fsdp", "tp") for a in mesh.axis_names):
+        return None
+    data = math.prod(mesh.axis_size(a) for a in mesh.data_axes)
+    tp = mesh.axis_size("tp")
+    if q_shape[0] % data or q_shape[2] % tp or k_shape[2] % tp:
+        return None
+    return PartitionSpec(*mesh.batch_spec(), None,
+                         "tp" if tp > 1 else None, None)
+
+
 def scaled_dot_product_attention(q, k, v, attn_mask=None,
                                  dropout_p: float = 0.0,
                                  is_causal: bool = False,
@@ -744,8 +761,23 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None,
         if flash_attention_available(q.shape, k.shape, attn_mask,
                                      dropout_p, training,
                                      is_causal=is_causal):
-            return flash_attention(q, k, v, causal=is_causal,
-                                   sm_scale=scale)
+            flash = functools.partial(flash_attention, causal=is_causal,
+                                      sm_scale=scale)
+            from ..parallel.mesh import get_mesh
+            mesh = get_mesh(required=False)
+            if mesh is None or mesh.size == 1:
+                return flash(q, k, v)
+            # a Mosaic kernel cannot be partitioned by GSPMD ("wrap the
+            # call in a shard_map"): attention is independent per batch
+            # row and per head, so each device runs the kernel on its
+            # own (data-axes batch, tp heads) block. Layouts this does
+            # not cover take the XLA math below.
+            spec = _flash_shard_spec(mesh, q.shape, k.shape)
+            if spec is not None:
+                return jax.shard_map(flash, mesh=mesh.mesh,
+                                     in_specs=(spec, spec, spec),
+                                     out_specs=spec,
+                                     check_vma=False)(q, k, v)
     if q.shape[2] != k.shape[2]:  # grouped-query: materialize kv repeat
         rep = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)

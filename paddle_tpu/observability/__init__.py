@@ -16,7 +16,7 @@ without numbers). Four parts:
   JSONL file reporter (atexit-flushed), jax device-memory gauges;
 - goodput: the wall-clock time ledger (``/goodputz``) — every second
   since arming attributed to one bucket (productive vs the badput
-  taxonomy), reconciled with an explicit unattributed residual, with
+  classification), reconciled with an explicit unattributed residual, with
   SLO-trip watermark forensics and fleet federation;
 - memory: the HBM attribution ledger (``/memz``) — owners register
   reservations at allocation boundaries, reads reconcile against
@@ -32,7 +32,7 @@ Hot paths ship instrumented: ``inference.llm`` (metrics + a span tree
 per request: queue → prefill chunks → first token → decode),
 ``hapi.Model`` (metrics + epoch/dispatch/metric-drain spans),
 ``io.checkpoint``, ``distributed.elastic``, and the DataLoader
-prefetch path. Metric names and the span taxonomy are tabled in
+prefetch path. Metric names and the span table are tabled in
 docs/OBSERVABILITY.md.
 """
 

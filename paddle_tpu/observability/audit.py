@@ -74,7 +74,7 @@ from ..core import flags as _flags
 # one chain link = 16 bytes; hex heads are 32 chars in payloads
 DIGEST_SIZE = 16
 
-# divergence taxonomy — every drift_divergence_total{kind} value
+# divergence classes — every drift_divergence_total{kind} value
 KINDS = ("failover", "migration", "shadow")
 
 # -- enable flag (pinned: one module-bool check on the drain path) ---------

@@ -139,8 +139,9 @@ def detect_peaks(device_kind: Optional[str] = None) -> PeakSpec:
     """Resolve the peak (FLOP/s, HBM B/s) this process measures MFU
     against: flag overrides win (``perf_peak_flops`` in FLOP/s,
     ``perf_peak_hbm_gbps`` in GB/s — the knob for TPU generations the
-    table doesn't know yet), then the device-kind table, then the CPU
-    fallback."""
+    table doesn't know yet), then the device-kind table. A TPU whose
+    kind is in neither is an ERROR — never the CPU placeholder, which
+    only a non-TPU backend gets."""
     if device_kind is None:
         try:
             import jax
@@ -154,11 +155,16 @@ def detect_peaks(device_kind: Optional[str] = None) -> PeakSpec:
         if sub in kind:
             bw = b
             break
-    source = "table" if flops is not None else "cpu-fallback"
-    if flops is None:
-        flops, bw = CPU_FALLBACK_PEAKS
     f_over = float(_flags.get_flag("perf_peak_flops") or 0.0)
     b_over = float(_flags.get_flag("perf_peak_hbm_gbps") or 0.0) * 1e9
+    source = "table" if flops is not None else "cpu-fallback"
+    if flops is None:
+        if "tpu" in kind and not (f_over > 0 and b_over > 0):
+            raise ValueError(
+                f"TPU device_kind {device_kind!r} is not in PEAK_TABLE: "
+                f"add its published peaks there, or set the "
+                f"perf_peak_flops and perf_peak_hbm_gbps flags")
+        flops, bw = CPU_FALLBACK_PEAKS
     if f_over > 0:
         flops, source = f_over, "override"
     if b_over > 0:

@@ -3,8 +3,9 @@
 The reference implements its fused hot-path ops as hand-written CUDA
 (reference: paddle/fluid/operators/fused/fused_attention_op.cu,
 fmha_ref.h, fused_multi_transformer_op.cu). The TPU-native equivalents
-live here as Pallas kernels compiled by Mosaic, with `interpret=True`
-fallback so the same kernels run (slowly) on CPU test meshes.
+live here as Pallas kernels compiled by Mosaic. They compile for the
+TPU or raise; the CPU test suite runs them through the Pallas
+interpreter by one switch of its own (tests/conftest.py).
 """
 
 from .flash_attention import flash_attention  # noqa

@@ -38,17 +38,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions;
-# accept either so the kernel runs on every toolchain in the image
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128  # VPU lane width: row-statistics are stored lane-replicated
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+# Pallas kernels here compile for the TPU or raise. Interpret mode is a
+# test device: tests/conftest.py sets this for the CPU suite; nothing in
+# the program does, and nothing infers it from the backend.
+INTERPRET = False
+
+
+def _default_interpret() -> bool:
+    return INTERPRET
 
 
 def _pick_block(seq: int, target: int) -> int:
@@ -161,7 +162,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -277,7 +278,7 @@ def _bwd(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -309,7 +310,7 @@ def _bwd(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -356,7 +357,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     internally runs BHSD tiles on the MXU.
     """
     if interpret is None:
-        interpret = _on_cpu()
+        interpret = INTERPRET
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
     if hq % hkv != 0:
@@ -377,7 +378,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
 def flash_attention_available(q_shape, k_shape, attn_mask, dropout_p,
                               training, is_causal: bool = False) -> bool:
-    """Whether the Pallas path handles this configuration."""
+    """Whether the Pallas path handles this configuration. The kernel
+    is a TPU program: off the TPU the XLA math serves (interpret mode
+    only under the tests' switch)."""
+    if not INTERPRET and jax.default_backend() != "tpu":
+        return False
     if attn_mask is not None:
         return False
     if dropout_p > 0.0 and training:

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _LANES, _on_cpu
+from .flash_attention import _LANES, _default_interpret
 from .flash_attention import DEFAULT_MASK_VALUE as _MASK_VALUE
 
 
@@ -229,22 +229,31 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     traffic scales with the true context length (``pl.when`` skips
     pages past it entirely), and nothing is materialized in between.
 
-    Grid: (batch, kv_heads, pages_per_seq); the page dim is sequential
-    so the online-softmax scratch (acc/m/l) carries across it. GQA is
-    native: the q block per kv head is its [group, D] query rows
-    (group = heads // kv_heads), matching the repeat-kv convention.
+    Grid: (batch, pages_per_seq); the page dim is sequential so the
+    online-softmax scratch (acc/m/l) carries across it. One grid step
+    holds the page for ALL kv heads: the block is
+    ``(1, page_size, kv_heads, d)``, whose trailing two dims are the
+    pool's own, which is what the TPU lowering requires of a block
+    that is not (8, 128)-divisible. With the page laid out
+    [page_size, kv_heads, d] (heads on sublanes, d on lanes) a
+    single query row per head is a broadcast-multiply and a lane
+    reduction, so the step runs on the VPU with no relayout: decode
+    attention is matrix-vector work and has nothing for the MXU. GQA
+    is native: q arrives as [group, kv_heads, d] and each group row
+    reuses the page in VMEM.
 
     int8 KV (``k_scales``/``v_scales`` [num_pages, page_size]):
-    dequantization happens IN-KERNEL — each grid step streams the
-    page's f32 scale row alongside its int8 block and multiplies in
-    VMEM, so HBM traffic stays at the quantized byte count (the whole
-    point of the int8 pool). NOTE: real-TPU int8 tiling wants
-    (32, 128) min tiles; the decode block here is page-granular and
-    validated in interpret mode (CPU) — the on-chip tile-shape sweep
-    rides tpu_sweep once hardware is reachable again.
+    dequantization happens IN-KERNEL — each grid step brings the
+    page's f32 scale row into SMEM alongside its int8 block and
+    multiplies in VMEM, so HBM traffic stays at the quantized byte
+    count (the whole point of the int8 pool).
+
+    ``interpret`` defaults to the module switch
+    ``flash_attention.INTERPRET`` (False: the kernel compiles for the
+    TPU or raises).
     """
     if interpret is None:
-        interpret = _on_cpu()  # same convention as flash_attention
+        interpret = _default_interpret()
     b, n_heads, d = q.shape
     n_pages, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
@@ -252,7 +261,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
     quantized = k_scales is not None
 
-    qg = q.reshape(b, kv_heads, group, d)
+    # [b, group, kv_heads, d]: a group row is one (kv_heads, d) tile
+    qg = q.reshape(b, kv_heads, group, d).transpose(0, 2, 1, 3)
     tables = jnp.clip(block_tables, 0).astype(jnp.int32)
     lens = context_lens.astype(jnp.int32)
 
@@ -262,7 +272,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
         else:
             o_ref, acc_ref, m_ref, l_ref = rest
         bi = pl.program_id(0)
-        j = pl.program_id(2)
+        j = pl.program_id(1)
 
         @pl.when(j == 0)
         def _init():
@@ -274,84 +284,80 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
 
         @pl.when(j * page_size < ctx)
         def _compute():
-            qb = q_ref[0, 0]                     # [group, d]
-            k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page_size, d]
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
+            k = k_ref[0].astype(jnp.float32)     # [page_size, kvh, d]
+            v = v_ref[0].astype(jnp.float32)
             if quantized:
-                # dequantize in VMEM: one scale per page row
-                k = k * ks_ref[0, :][:, None]
-                v = v * vs_ref[0, :][:, None]
-            s = jax.lax.dot_general(
-                qb.astype(jnp.float32), k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            col = jax.lax.broadcasted_iota(
-                jnp.int32, (group, page_size), 1)
-            s = jnp.where(col < ctx - j * page_size, s,
-                          _MASK_VALUE)           # [group, page_size]
-            m_prev = m_ref[:, :1]
-            l_prev = l_ref[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1,
-                                                keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+                # dequantize in VMEM: one SMEM scalar per page row
+                k = jnp.stack([k[p] * ks_ref[0, 0, p]
+                               for p in range(page_size)])
+                v = jnp.stack([v[p] * vs_ref[0, 0, p]
+                               for p in range(page_size)])
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, kv_heads, 1), 0)
+            valid = row < ctx - j * page_size
+            for g in range(group):
+                qb = q_ref[0, g].astype(jnp.float32)  # [kvh, d]
+                s = jnp.sum(qb[None] * k, axis=-1,
+                            keepdims=True) * sm_scale
+                s = jnp.where(valid, s, _MASK_VALUE)  # [ps, kvh, 1]
+                m_prev = m_ref[g, :, :1]              # [kvh, 1]
+                l_prev = l_ref[g, :, :1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new[None])
+                l_new = alpha * l_prev + jnp.sum(p, axis=0)
+                acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v,
+                                                          axis=0)
+                m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
         @pl.when(j == pages_per_seq - 1)
         def _finalize():
-            l = l_ref[:, :1]
+            l = l_ref[:, :, :1]
             l_safe = jnp.where(l == 0.0, 1.0, l)  # empty slot → zeros
-            o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
-    page_spec = pl.BlockSpec((1, page_size, 1, d),
-                             lambda bi, h, j, ctx, tbl: (tbl[bi, j], 0,
-                                                         h, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, group, d),
-                     lambda bi, h, j, ctx, tbl: (bi, h, 0, 0)),
-        # the paged gather: this index map IS the block table read
-        page_spec,
-        page_spec,
-    ]
+    # the paged gather: this index map IS the block table read
+    page_spec = pl.BlockSpec((1, page_size, kv_heads, d),
+                             lambda bi, j, ctx, tbl: (tbl[bi, j], 0,
+                                                      0, 0))
+    q_spec = pl.BlockSpec((1, group, kv_heads, d),
+                          lambda bi, j, ctx, tbl: (bi, 0, 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
     operands = [lens, tables, qg, k_pages, v_pages]
     if quantized:
-        # the page's scale row streams beside its int8 block
+        # the page's scale row rides beside its int8 block, in SMEM
+        # (scalar reads); [num_pages, 1, page_size] so that the block's
+        # trailing dims are the array's own
         scale_spec = pl.BlockSpec(
-            (1, page_size), lambda bi, h, j, ctx, tbl: (tbl[bi, j], 0))
+            (1, 1, page_size),
+            lambda bi, j, ctx, tbl: (tbl[bi, j], 0, 0),
+            memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        operands += [
+            k_scales.astype(jnp.float32).reshape(n_pages, 1, page_size),
+            v_scales.astype(jnp.float32).reshape(n_pages, 1, page_size)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kv_heads, pages_per_seq),
+        grid=(b, pages_per_seq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda bi, h, j, ctx, tbl: (bi, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
+            pltpu.VMEM((group, kv_heads, d), jnp.float32),
+            pltpu.VMEM((group, kv_heads, _LANES), jnp.float32),
+            pltpu.VMEM((group, kv_heads, _LANES), jnp.float32),
         ],
     )
-    # jax renamed TPUCompilerParams -> CompilerParams across versions;
-    # accept either so the kernel runs on every toolchain in the image
-    _params_cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    out_dtype = q.dtype
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv_heads, group, d),
-                                       out_dtype),
-        compiler_params=_params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, group, kv_heads, d),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(*operands)
-    return out.reshape(b, n_heads, d)
+    return out.transpose(0, 2, 1, 3).reshape(b, n_heads, d)
 
 
 def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,

@@ -351,16 +351,15 @@ def replica_main(spec: dict) -> int:
       chaos soak collects every replica's dumps from one tree.
     """
     import jax
-    jax.config.update("jax_platforms", spec.get("platform", "cpu"))
-    if spec.get("cache_dir"):
-        # a fleet compiles K copies of the same tiny programs; the
-        # persistent cache makes replica N and every respawn hit
-        # replica 1's artifacts (PR 3's compilation_cache_dir wiring,
-        # applied fleet-wide)
-        jax.config.update("jax_compilation_cache_dir",
-                          spec["cache_dir"])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
+    # the replica runs where its spec says, or on what jax finds —
+    # never silently on the CPU; the READY line names the platform
+    if spec.get("platform"):
+        jax.config.update("jax_platforms", spec["platform"])
+    # a fleet compiles K copies of the same programs; the persistent
+    # cache (placed from outside: core/compile_cache.py) makes replica
+    # N and every respawn hit replica 1's artifacts
+    from ..core import compile_cache
+    compile_cache.enable()
     from ..inference.llm import serve_llm
     from ..observability import server as debug
     from ..observability import tracing
@@ -411,6 +410,7 @@ def replica_main(spec: dict) -> int:
             "metrics": f"{dbg.address}/metrics",
             "tracez": f"{dbg.address}/tracez",
             "driftz": f"{dbg.address}/driftz",
+            "platform": jax.devices()[0].platform,
             "pid": os.getpid()}
     if spec.get("role"):
         # disaggregated pool membership ("prefill" / "decode"): rides
@@ -476,8 +476,11 @@ def spawn_replica(spec: dict, timeout: float = 120.0,
     process (SIGKILL it, wait() it)."""
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    child_env = dict(os.environ, JAX_PLATFORMS=spec.get(
-        "platform", "cpu"), PYTHONPATH=repo)
+    # one process per chip: a parent that has touched jax on a TPU
+    # holds it, so a TPU replica is spawned from a parent that has not
+    child_env = dict(os.environ, PYTHONPATH=repo)
+    if spec.get("platform"):
+        child_env["JAX_PLATFORMS"] = spec["platform"]
     if env:
         child_env.update(env)
     proc = subprocess.Popen(

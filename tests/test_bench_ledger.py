@@ -149,13 +149,13 @@ def test_series_keyed_by_workload_and_backend(ledger):
 
 
 def test_emitters_share_the_schema():
-    """The repo trajectory (BENCH_LEDGER.jsonl) carries rows from all
-    three bench tools in the one schema — the acceptance pin. Skipped
+    """The repo trajectory (BENCH_LEDGER.jsonl) carries rows from the
+    bench tools in the one schema — the acceptance pin. Skipped
     only if a fresh checkout hasn't run the bench steps yet."""
     rows = bl.read_ledger(bl.DEFAULT_PATH)
     if not rows:
         pytest.skip("no repo ledger yet (bench tools not run)")
     tools = {r["tool"] for r in rows}
-    assert {"llm_bench", "bench", "tpu_sweep"} <= tools, tools
+    assert {"llm_bench", "bench"} <= tools, tools
     for r in rows:
         assert r["schema"] == "bench_ledger/v1"

@@ -24,7 +24,7 @@ def test_two_process_allreduce_and_dp_parity(tmp_path):
 
     ctx = distributed.spawn(dist_worker.allreduce_and_dp_train,
                             args=(str(tmp_path),), nprocs=2, join=False)
-    ok = ctx.join(timeout=420)
+    ok = ctx.join(timeout=55)
     # on timeout, kill stragglers so the suite never wedges
     for p in ctx.processes:
         if p.exitcode is None:
@@ -52,7 +52,7 @@ def test_sharded_embedding_exceeds_single_host_budget(tmp_path):
     ctx = distributed.spawn(dist_worker.sharded_embedding_train,
                             args=(str(tmp_path), 12, 8, budget),
                             nprocs=2, join=False)
-    ok = ctx.join(timeout=420)
+    ok = ctx.join(timeout=55)
     for p in ctx.processes:
         if p.exitcode is None:
             p.terminate()
@@ -91,7 +91,7 @@ def test_two_process_model_axis_parity(tmp_path, axis):
     ctx = distributed.spawn(dist_worker.model_axis_train,
                             args=(str(tmp_path), axis), nprocs=2,
                             join=False)
-    ok = ctx.join(timeout=420)
+    ok = ctx.join(timeout=55)
     for p in ctx.processes:
         if p.exitcode is None:
             p.terminate()

@@ -2,6 +2,7 @@
 8-device virtual mesh (what the driver does with
 xla_force_host_platform_device_count=N)."""
 
+import os
 import sys
 
 import jax
@@ -13,7 +14,8 @@ pytestmark = pytest.mark.slow  # smoke tier skips (tools/ci.sh --smoke)
 
 
 def _load():
-    sys.path.insert(0, "/root/repo")
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     import __graft_entry__
     return __graft_entry__
 

@@ -431,7 +431,7 @@ print("WORKER OK")
 """
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     p = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
-                       capture_output=True, text=True, timeout=560)
+                       capture_output=True, text=True, timeout=55)
     assert p.returncode == 0 and "WORKER OK" in p.stdout, \
         (p.returncode, p.stdout[-500:], p.stderr[-2000:])
     dumps = [f for f in os.listdir(tmp_path) if "_oom" in f]

@@ -6,13 +6,15 @@ public op surface from its api yaml registry (reference:
 paddle/phi/api/yaml/api.yaml + legacy_api.yaml) and resolves every name
 here; the gate asserts the missing list stays empty."""
 
+import os
 import sys
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 import paddle_tpu as pt  # noqa: E402
 from paddle_tpu.nn import functional as F  # noqa: E402

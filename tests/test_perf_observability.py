@@ -79,6 +79,13 @@ def test_detect_peaks_cpu_fallback_and_override():
                          "perf_peak_hbm_gbps": 0.0})
 
 
+def test_detect_peaks_unknown_tpu_is_an_error():
+    """A TPU kind missing from PEAK_TABLE never gets the CPU
+    placeholder peaks."""
+    with pytest.raises(ValueError, match="PEAK_TABLE"):
+        perf.detect_peaks("TPU v9 hypothetical")
+
+
 def test_bench_peak_delegates_to_one_table():
     import bench
     # CPU backend: bench MFU must read null, not the perf fallback

@@ -28,12 +28,26 @@ def _read_losses(path):
     return out
 
 
+def _finish(p, timeout=55):
+    """communicate() with a bound of its own; a worker that outlives it
+    is killed and fails the test instead of holding the suite."""
+    try:
+        out, _ = p.communicate(timeout=timeout)
+    except subprocess.TimeoutExpired:
+        p.kill()
+        out, _ = p.communicate()
+        raise AssertionError(
+            f"worker still running after {timeout}s:\n"
+            f"{out.decode()[-2000:]}")
+    return out
+
+
 def _run(workdir, wait=True):
     p = subprocess.Popen([sys.executable, WORKER, workdir, str(TOTAL)],
                          stdout=subprocess.PIPE,
                          stderr=subprocess.STDOUT)
     if wait:
-        out, _ = p.communicate(timeout=300)
+        out = _finish(p)
         assert p.returncode == 0, out.decode()
     return p
 
@@ -59,7 +73,7 @@ def test_sigterm_checkpoints_and_resumes_losslessly(tmp_path):
         p.kill()
         raise AssertionError("worker never reached step 8")
     p.send_signal(signal.SIGTERM)
-    out, _ = p.communicate(timeout=120)
+    out = _finish(p)
     from paddle_tpu.distributed.elastic import RESTART_EXIT_CODE
     assert p.returncode == RESTART_EXIT_CODE, (p.returncode, out.decode())
     interrupted = _read_losses(loss_file)
@@ -119,10 +133,10 @@ def test_sigterm_during_first_compile_resumes_losslessly(tmp_path):
         # fast machine: step 0 beat us past the sentinel — the compile
         # race can't be staged here; product behavior is unaffected
         p.send_signal(signal.SIGTERM)
-        p.communicate(timeout=240)
+        _finish(p)
         pytest.skip("worker finished step 0 before the signal landed")
     p.send_signal(signal.SIGTERM)
-    out, _ = p.communicate(timeout=240)
+    out = _finish(p)
     from paddle_tpu.distributed.elastic import RESTART_EXIT_CODE
     assert p.returncode == RESTART_EXIT_CODE, (p.returncode, out.decode())
     interrupted = _read_losses(loss_file)

@@ -22,8 +22,20 @@ def _worker_fail():
     raise ValueError("rank exploded")
 
 
+def _join_bounded(ctx, seconds=50):
+    """join() with a bound of its own; stragglers are killed so a hung
+    rank fails this test instead of holding the suite."""
+    try:
+        return ctx.join(timeout=seconds)
+    finally:
+        for p in ctx.processes:
+            if p.exitcode is None:
+                p.kill()
+
+
 def test_spawn_runs_ranks_with_env(tmp_path):
-    spawn(_worker_write, args=(str(tmp_path),), nprocs=3)
+    assert _join_bounded(spawn(_worker_write, args=(str(tmp_path),),
+                               nprocs=3, join=False))
     import json
     got = sorted(json.load(open(tmp_path / f"r{r}.json"))["rank"]
                  for r in range(3))
@@ -32,7 +44,7 @@ def test_spawn_runs_ranks_with_env(tmp_path):
 
 def test_spawn_propagates_worker_error(tmp_path):
     with pytest.raises(RuntimeError, match="rank exploded"):
-        spawn(_worker_fail, nprocs=2)
+        _join_bounded(spawn(_worker_fail, nprocs=2, join=False))
 
 
 def test_iinfo_finfo():

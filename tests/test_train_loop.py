@@ -378,17 +378,35 @@ def test_train_loop_metrics_registered():
     assert "train_loop_prefetch_wait_seconds_count" in snap
 
 
-def test_compilation_cache_flag(tmp_path):
-    from paddle_tpu.core import flags
+@pytest.mark.parametrize("origin", ["env", "checkout"])
+def test_compilation_cache_placed_from_outside(tmp_path, monkeypatch,
+                                               origin):
+    """The compile cache's directory comes from outside the program:
+    JAX_COMPILATION_CACHE_DIR where it is set (the program sets no
+    other), one fixed path in the checkout where it is not."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.core import compile_cache
     cache = str(tmp_path / "xla-cache")
-    flags.set_flags({"compilation_cache_dir": cache})
+    if origin == "env":
+        monkeypatch.setenv(compile_cache.ENV_VAR, cache)
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        monkeypatch.setattr(compile_cache, "CHECKOUT_CACHE_DIR", cache)
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    monkeypatch.setattr(compile_cache, "_enabled", None)
+    jax.config.update("jax_enable_compilation_cache", True)
     try:
         x, y = _data(16)
         m = _make_model()
         m.train_batch([x], [y])
-        import jax
+        assert compile_cache.enable() == (cache, origin)
         assert jax.config.jax_compilation_cache_dir == cache
-        assert os.path.isdir(cache)
         assert os.listdir(cache), "no persistent cache entries written"
     finally:
-        flags.set_flags({"compilation_cache_dir": ""})
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

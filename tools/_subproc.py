@@ -1,6 +1,6 @@
 """Shared fresh-subprocess runner for the measurement tools.
 
-tpu_sweep.py and feasibility_1p3b.py both isolate each measurement in
+feasibility_1p3b.py and feasibility_xl.py isolate each measurement in
 a fresh interpreter (device-buffer hygiene / per-process device
 counts). One copy of the harness: run the tool script with a flag +
 JSON spec, parse the last stdout line as the result, degrade failures

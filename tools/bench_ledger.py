@@ -1,11 +1,9 @@
 """The perf ledger: ONE canonical bench-row schema + a regression gate.
 
 Before this tool the perf trajectory was unreadable: bench.py printed
-driver rows (``BENCH_rNN.json``: ``{n, cmd, rc, tail, parsed}``),
-tpu_sweep.py appended a second shape to ``PERF_SWEEP.jsonl``,
-llm_bench.py a third — no shared keys, no git anchoring, nothing a
-gate could diff. This module defines the one row every bench tool now
-appends to ``BENCH_LEDGER.jsonl``:
+one row shape, llm_bench.py another — no shared keys, no git
+anchoring, nothing a gate could diff. This module defines the one row
+every bench tool now appends to ``BENCH_LEDGER.jsonl``:
 
     {"schema": "bench_ledger/v1", "run_id": ..., "ts": ...,
      "git_rev": ..., "backend": ..., "tool": ..., "workload": ...,
@@ -32,9 +30,7 @@ chips. The mapping from the legacy row shapes is documented in
 PERF.md ("The perf ledger").
 
 Emitters: ``tools/llm_bench.py`` (serving benches), ``bench.py``
-(train headline), ``tools/tpu_sweep.py`` (hardware sweep rows —
-legacy PERF_SWEEP.jsonl rows are still written alongside for one
-release). Path override: ``PT_BENCH_LEDGER`` env (tests point it at a
+(train headline). Path override: ``PT_BENCH_LEDGER`` env (tests point it at a
 tmp file; ``PT_BENCH_LEDGER=0`` disables appends entirely).
 """
 
@@ -397,8 +393,8 @@ def ci_gate(path: Optional[str] = None,
     if not rows:
         print(f"bench_ledger --ci FAIL: no readable rows in "
               f"{p or '(appends disabled)'} — the perf trajectory is "
-              f"empty. Run the bench tools (llm_bench.py / bench.py / "
-              f"tpu_sweep.py) so the ledger has a baseline.",
+              f"empty. Run the bench tools (llm_bench.py / bench.py) so "
+              f"the ledger has a baseline.",
               file=sys.stderr)
         return 2
     verdicts = compare(rows, tolerance=tolerance)

@@ -1011,7 +1011,8 @@ def fleet_soak(seed: int, workdir: str) -> dict:
     store = TCPStoreServer("127.0.0.1", 0)
     endpoint = f"127.0.0.1:{store.port}"
     obs_dir = os.path.join(workdir, "obs")
-    model = {"vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
+    model = {"platform": "cpu",   # a CPU-only gate by design
+             "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0,
              # every replica traces and collects its dumps under ONE
              # tree the soak can merge on failure
@@ -1019,10 +1020,7 @@ def fleet_soak(seed: int, workdir: str) -> dict:
     engine_kw = {"device_retry_budget": 2, "drain_after": 2,
                  "max_pending": 64, "seed": 0}
     names = ("r0", "r1", "r2")
-    cache_dir = os.path.join(workdir, "xla_cache")
-    os.makedirs(cache_dir, exist_ok=True)
     specs = {n: dict(model, name=n, store=endpoint,
-                     cache_dir=cache_dir,
                      engine=dict(engine_kw)) for n in names}
     # r2's schedule: dispatch calls 3 and 4 fault back-to-back — two
     # CONSECUTIVE device errors at drain_after=2 latch it DRAINING
@@ -1053,10 +1051,8 @@ def fleet_soak(seed: int, workdir: str) -> dict:
     # the reference engine (same weights/seed as every replica)
     # replays failover'd requests to pin token identity; it reads the
     # same compile cache
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0)
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     ref = LocalReplica(make_engine_from_spec(dict(model,
                                                   engine=engine_kw)))
     ref_warm = threading.Thread(
@@ -1309,20 +1305,17 @@ def disagg_soak(seed: int, workdir: str) -> dict:
 
     rng = np.random.RandomState(seed + 1)
     faults.reset()
-    cache_dir = os.path.join(workdir, "xla_cache")
-    os.makedirs(cache_dir, exist_ok=True)
-    model = {"vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
+    model = {"platform": "cpu",   # a CPU-only gate by design
+             "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0}
     engine_kw = {"page_size": 4, "num_pages": 96, "max_seqs": 4,
                  "prefill_buckets": (32,), "seed": 0,
                  "kv_dtype": "int8"}
     spec = dict(model, name="pre0", role="prefill",
-                cache_dir=cache_dir, engine=dict(engine_kw))
+                engine=dict(engine_kw))
     proc, info = spawn_replica(spec, timeout=180)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0)
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     dec = [make_engine_from_spec(dict(model, engine=dict(engine_kw)))
            for _ in range(2)]
     ref = make_engine_from_spec(dict(model, engine=dict(engine_kw)))
@@ -1485,7 +1478,8 @@ def drift_soak(seed: int, workdir: str) -> dict:
     fdir = os.path.join(workdir, "drift_flight")
     rec = flight.FlightRecorder(fdir)
     rec.install()
-    model = {"vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
+    model = {"platform": "cpu",   # a CPU-only gate by design
+             "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0}
     engine_kw = {"max_seqs": 4, "page_size": 4, "num_pages": 64,
                  "prefill_buckets": (32,), "seed": 0,
@@ -1641,28 +1635,25 @@ def autoscale_soak(seed: int, workdir: str) -> dict:
     store = TCPStoreServer("127.0.0.1", 0)
     endpoint = f"127.0.0.1:{store.port}"
     obs_dir = os.path.join(workdir, "obs")
-    model = {"vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
+    model = {"platform": "cpu",   # a CPU-only gate by design
+             "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0,
              "tracing": True, "obs_dir": obs_dir}
     engine_kw = {"device_retry_budget": 2, "max_pending": 64,
                  "seed": 0}
-    cache_dir = os.path.join(workdir, "xla_cache")
-    os.makedirs(cache_dir, exist_ok=True)
     # the seed replica (unmanaged — the autoscaler can only kill what
     # it spawned) boots first and warms the shared compile cache
     procs, infos = {}, {}
     spec0 = dict(model, name="r0", store=endpoint,
-                 cache_dir=cache_dir, engine=dict(engine_kw))
+                 engine=dict(engine_kw))
     procs["r0"], infos["r0"] = spawn_replica(spec0, timeout=180)
     HTTPReplica(infos["r0"]["generate"],
                 infos["r0"]["healthz"]).submit([1, 2, 3],
                                                max_new_tokens=2)
     # reference engine: same weights/seed/cache — replays any
     # failover'd stream nonce-pinned to pin token identity
-    import jax
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0)
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     ref = LocalReplica(make_engine_from_spec(dict(model,
                                                   engine=engine_kw)))
     ref.submit([1, 2, 3], max_new_tokens=1)
@@ -1676,7 +1667,7 @@ def autoscale_soak(seed: int, workdir: str) -> dict:
                         "gold", deadline_s=60.0, target=0.99)},
                     slo_windows=(2.0, 8.0), slo_min_samples=5,
                     slo_breach_threshold=5.0)
-    auto_spec = dict(model, store=endpoint, cache_dir=cache_dir,
+    auto_spec = dict(model, store=endpoint,
                      engine=dict(engine_kw))
     scaler = Autoscaler(
         router, make_subprocess_spawner(auto_spec, timeout=180),
@@ -2447,15 +2438,14 @@ def _train_worker(run_dir: str, k: int, freq: int) -> int:
     (hex floats: the bit-identity assertion needs exact values)."""
     import paddle_tpu as pt
     from paddle_tpu import nn
-    from paddle_tpu.core import flags
     from paddle_tpu.distributed import elastic
     from paddle_tpu.io import TensorDataset
     from paddle_tpu.io import checkpoint as ckpt_mod
 
-    # shared persistent compile cache: relaunches (the whole point of
-    # this soak) skip the XLA compile after the first incarnation
-    flags.set_flags({"compilation_cache_dir":
-                     os.path.join(os.path.dirname(run_dir), "xla_cache")})
+    # the persistent compile cache (Model.prepare turns it on, at the
+    # one path core/compile_cache.py names): relaunches (the whole
+    # point of this soak) skip the XLA compile after the first
+    # incarnation
 
     # phase markers for the parent's kill targeting (patch ONCE — the
     # merged-baseline mode calls this body twice in one process)

@@ -185,16 +185,15 @@ python tools/llm_bench.py --ci --fleet --disagg
 echo "== fused train-loop parity smoke (K=1 vs K=4 bit-identical)"
 python tools/train_loop_smoke.py
 
-echo "== fused train-loop dispatch sweep (CPU)"
-PT_BENCH_FORCE_CPU=1 python bench.py --steps-per-loop 1,8
+echo "== fused train-loop dispatch sweep (rehearsal: toy sizes, control flow only)"
+python bench.py --rehearse --steps-per-loop 1,8
 
-echo "== bench smoke (CPU backend)"
-# PT_BENCH_FORCE_CPU: run the measuring child directly on CPU — the
-# default orchestrator mode would spend its TPU probe windows first
-PT_BENCH_FORCE_CPU=1 python bench.py
+echo "== bench rehearsal (toy sizes; without --rehearse bench.py needs the chip)"
+python bench.py --rehearse
 
 echo "== perf ledger regression gate (BENCH_LEDGER.jsonl trajectory)"
-# the bench steps above appended this run's canonical rows; the gate
+# the llm_bench steps above appended this run's canonical rows (the
+# bench.py rehearsals append nothing: they are not measurements); the gate
 # fails LOUDLY if the trajectory is empty/unreadable or any series
 # regressed past tolerance (wide on CPU, tight on real chips). Rows
 # carry the optional drift_divergences field when the stream auditor

@@ -17,8 +17,8 @@ memory. Calibration: a gpt2-small forward whose true activation peak
 is ~0.6 GiB reads 1.31 GiB here (~2.2x). The bf16+Adafactor rows
 reading ~19-20 GiB therefore predict a REAL footprint around
 9-12 GiB once donation (-3.1 GiB params alias) and memory-aware
-scheduling apply — the single-chip b4/b8 attempts stay queued in
-tools/tpu_sweep.py as the decider. The fp32/AdamW rows are
+scheduling apply — a chip run is the decider (not measured on
+chip). The fp32/AdamW rows are
 conclusive the other way: their ARGUMENT bytes alone (state that
 must exist, no scheduling involved) exceed the budget.
 

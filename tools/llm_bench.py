@@ -10,7 +10,7 @@ rate lives in the `llm_decode_tokens_per_second` histogram, which
 excludes prefill fetches). Emits ONE BENCH-style JSON row whose
 headline is the fraction of prompt-token recomputation eliminated.
 Everything runs on the CPU backend (recompute savings and cache hit
-rate are device-independent; tpu_sweep.py owns on-chip rounds).
+rate are device-independent; times and rates are not device numbers).
 
 FLEET MODE (``--fleet``): the same shared-prefix observation at K=3
 engine replicas behind the serving router. Routing policy is the
@@ -891,13 +891,9 @@ def run_storm(engines, schedule, autoscale: bool):
 def storm_main(args):
     """Static K=3 vs autoscaled min=1/max=3 over the same schedule and
     the same pre-warmed engines. One ledger row carries both."""
-    import tempfile
-
     # persistent compile cache: engine 2..6 reuse engine 1's programs
-    jax.config.update("jax_compilation_cache_dir",
-                      tempfile.mkdtemp(prefix="pt_storm_xla_"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0)
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     from paddle_tpu.inference.llm import LLMEngine
 
     schedule = make_storm_schedule()
@@ -1114,12 +1110,8 @@ def overload_main(args):
     controller must hold gold at the baseline hit ratio AND strictly
     cut the wasted-work fraction (misses converted to cheap typed
     sheds)."""
-    import tempfile
-
-    jax.config.update("jax_compilation_cache_dir",
-                      tempfile.mkdtemp(prefix="pt_overload_xla_"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0)
+    from paddle_tpu.core import compile_cache
+    compile_cache.enable()
     from paddle_tpu.inference.llm import LLMEngine
 
     base_sched, over_sched = make_overload_schedules()

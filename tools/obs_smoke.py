@@ -379,11 +379,10 @@ def fleet_main(outdir: str = "/tmp/pt_obs_fleet_smoke") -> int:
 
     os.makedirs(outdir, exist_ok=True)
     obs_dir = os.path.join(outdir, "obs")
-    cache_dir = os.path.join(outdir, "xla_cache")
-    os.makedirs(cache_dir, exist_ok=True)
-    model = {"vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
+    model = {"platform": "cpu",   # a CPU-only gate by design
+             "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0, "tracing": True,
-             "obs_dir": obs_dir, "cache_dir": cache_dir,
+             "obs_dir": obs_dir,
              "engine": {"seed": 0, "max_pending": 64}}
     names = ("r0", "r1")
     tracing.enable()
