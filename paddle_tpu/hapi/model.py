@@ -437,9 +437,8 @@ class Model:
         loss_fn = self._loss
         outs = _as_tuple(outputs)
         labs = _as_tuple(labels)
-        if isinstance(loss_fn, Layer):
+        with jax.named_scope("loss"):
             return loss_fn(*outs, *labs)
-        return loss_fn(*outs, *labs)
 
     def _metric_outputs(self, outputs, labels):
         outs = _as_tuple(outputs)
@@ -898,7 +897,7 @@ class Model:
         sp = _trace.start_span(
             "train.step", attrs={"batch": batch_n,
                                  "step": self._step_count}) \
-            if _trace.enabled() else None
+            if _trace.active() else None
         t0 = time.perf_counter()
         perf_h, perf_fresh = None, False
         try:
@@ -1023,7 +1022,7 @@ class Model:
         sp = _trace.start_span(
             "train.dispatch", attrs={"k": k, "batch": batch_n,
                                      "step0": self._step_count}) \
-            if _trace.enabled() else None
+            if _trace.active() else None
         t0 = time.perf_counter()
         perf_h, perf_fresh = None, False
         try:
@@ -1143,12 +1142,10 @@ class Model:
         policy escalations (GuardRollback / GuardAbort /
         FloatingPointError) surface from this boundary."""
         if self._metric_pending:
-            sp = _trace.start_span(
-                "train.metric_drain",
-                attrs={"pending": len(self._metric_pending)}) \
-                if _trace.enabled() else None
             t0 = time.perf_counter()
-            try:
+            with _trace.phase(
+                    "train.metric_drain",
+                    attrs={"pending": len(self._metric_pending)}):
                 pending, self._metric_pending = self._metric_pending, []
                 for outs, nsteps, verdicts in pending:
                     keep = None
@@ -1175,9 +1172,6 @@ class Model:
                             for i in range(nsteps):
                                 if keep[i]:
                                     m.update(*(o[i] for o in mos))
-            finally:
-                if sp is not None:
-                    sp.end()
             if self._obs_loop is None:
                 self._obs_loop = _loop_metrics()
             drain_dt = time.perf_counter() - t0
@@ -1409,7 +1403,7 @@ class Model:
         target = tripped + rb.stride
         loader.load_state_dict({"pass": int(cur["pass"]),
                                 "batch": target})
-        if _trace.enabled():
+        if _trace.active():
             _trace.start_span("train.guard", attrs={
                 "kind": rb.kind, "action": "rollback",
                 "step": rb.step, "restored_step": ck_step,
@@ -1573,7 +1567,7 @@ class Model:
                 # thread-local stack); Span.__exit__ records the error.
                 ep_span = _trace.span(
                     "train.epoch", attrs={"epoch": epoch}).__enter__() \
-                    if _trace.enabled() else None
+                    if _trace.active() else None
                 step = resume_step_in_epoch if epoch == start_epoch else 0
                 try:
                     # fold any still-buffered outputs BEFORE reset — the
@@ -1585,16 +1579,6 @@ class Model:
                         self._drain_metric_updates()
                         for m in self._metrics:
                             m.reset()
-                    # model-perspective buckets for profiler.summary():
-                    # no-ops unless a Profiler is active (ref:
-                    # profiler_statistic.py model perspective —
-                    # Dataloader/Forward/.../Optimizer; the compiled step
-                    # fuses fwd+bwd+opt, so the TPU-side split is
-                    # Dataloader / TrainStep / Callbacks)
-                    from ..profiler import _events as _prof_events
-                    from ..profiler import RecordEvent as _Rec
-                    profiling = _prof_events.active
-                    rec = _Rec if profiling else contextlib.nullcontext
                     while True:
                         # one epoch pass; restarts after a numeric-guard
                         # ROLLBACK (the newest verified checkpoint is
@@ -1607,7 +1591,7 @@ class Model:
                             it = iter(loader)
                         try:
                             while True:
-                                with rec("Dataloader"):
+                                with _trace.phase("fit.next_batch"):
                                     batch = next(it, None)
                                 if batch is None:
                                     break
@@ -1617,11 +1601,11 @@ class Model:
                                         jax.tree_util.tree_leaves(
                                             inputs)[0])[0])
                                     if k == k_loop:
-                                        with rec("TrainStep"):
+                                        with _trace.phase("fit.dispatch"):
                                             step_logs = \
                                                 self.train_loop_batch(
                                                     inputs, labels)
-                                        with rec("Callbacks"):
+                                        with _trace.phase("fit.callbacks"):
                                             for logs in step_logs:
                                                 cbks.on_train_batch_begin(
                                                     step)
@@ -1644,9 +1628,9 @@ class Model:
                                     sub_batches = [(inputs, labels)]
                                 for inp, lab in sub_batches:
                                     cbks.on_train_batch_begin(step)
-                                    with rec("TrainStep"):
+                                    with _trace.phase("fit.dispatch"):
                                         logs = self.train_batch(inp, lab)
-                                    with rec("Callbacks"):
+                                    with _trace.phase("fit.callbacks"):
                                         cbks.on_train_batch_end(step,
                                                                 logs)
                                     step += 1
@@ -1673,11 +1657,7 @@ class Model:
                         v, (_LazyMetricValue, _SlabScalar)) else v
                         for n, v in logs.items()}
                     if eval_loader is not None and epoch % eval_freq == 0:
-                        if profiling:
-                            with _Rec("Eval"):
-                                eval_logs = self.evaluate(
-                                    eval_loader, verbose=0, _callbacks=cbks)
-                        else:
+                        with _trace.span("fit.eval"):
                             eval_logs = self.evaluate(
                                 eval_loader, verbose=0, _callbacks=cbks)
                         logs.update({f"eval_{k}": v

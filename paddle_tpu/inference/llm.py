@@ -304,15 +304,15 @@ def _sample(logits, temperature, key, nonces, positions):
     is sampled, never on HOW the scheduler got there — prefix-cache
     hits, chunked prefill, and lookahead all change the device-call
     stream but reproduce identical sampled tokens (test-pinned)."""
-    greedy = jnp.argmax(logits, axis=-1)
-
     def mk(n, p):
         return jax.random.fold_in(jax.random.fold_in(key, n), p)
 
-    keys = jax.vmap(mk)(nonces, positions)
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-    return jnp.where(temperature > 0.0, sampled, greedy)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1)
+        keys = jax.vmap(mk)(nonces, positions)
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+        return jnp.where(temperature > 0.0, sampled, greedy)
 
 
 # speculative-sampling key salts: folded into the engine key BEFORE
@@ -1984,7 +1984,7 @@ class LLMEngine:
         # resolved once, outside the lock: the remote parent (if any)
         # for this request's span tree — cross-process propagation
         remote_ctx = (_propagation.context_from(trace_context)
-                      if _trace.enabled() and trace_context is not None
+                      if _trace.active() and trace_context is not None
                       else None)
         with self._mu:
             if self._closed:
@@ -2011,7 +2011,7 @@ class LLMEngine:
             if shed_why is not None:
                 self._m["shed"].inc()
                 err = AdmissionShed(shed_why, reason=shed_reason)
-                if _trace.enabled():
+                if _trace.active():
                     root = _trace.start_span(
                         "llm.request", parent=remote_ctx, attrs={
                             "prompt_tokens": len(req.prompt),
@@ -2021,7 +2021,7 @@ class LLMEngine:
                 req.future.set_exception(err)
                 req.future.request_id = req.req_id
                 return req.future
-            if _trace.enabled():
+            if _trace.active():
                 # the request's span tree roots HERE (submitter
                 # thread, inside the lock so the tree exists before
                 # the engine loop can see the request); the loop
@@ -2974,15 +2974,29 @@ class LLMEngine:
                 # arrays are settled outputs (no donated input buffer
                 # is still feeding a queued program). Each op resolves
                 # its own future and never raises into the loop.
-                for op, _fut in ctl:
-                    op()
-                # higher priority admits first; FIFO (by submission
-                # order) within a priority class — retries re-enter
-                # the next drain and re-sort with new arrivals
-                pending.sort(key=lambda r: (-r.priority, r.req_id))
-                for req in pending:
-                    self._harvest_admit(req)
-                self._police_slots()
+                #
+                # PHASES: the iteration below is tiled by flat leaf
+                # phases (llm.loop.* / llm.issue.* / llm.drain.*) that
+                # are recorded only while tracing is active: the span
+                # table gets their attrs, the profiler's host plane
+                # their names, which is how a device idle gap finds
+                # the host work that covered it. An issue path whose
+                # phase carries the dispatch's attrs opens it itself.
+                if ctl:
+                    with _trace.phase("llm.loop.control",
+                                      {"ops": len(ctl)}):
+                        for op, _fut in ctl:
+                            op()
+                with _trace.phase("llm.loop.admit",
+                                  {"pending": len(pending)}):
+                    # higher priority admits first; FIFO (by
+                    # submission order) within a priority class —
+                    # retries re-enter the next drain and re-sort with
+                    # new arrivals
+                    pending.sort(key=lambda r: (-r.priority, r.req_id))
+                    for req in pending:
+                        self._harvest_admit(req)
+                    self._police_slots()
                 self._m["queue_depth"].set(self._n_queued)
                 busy = False
                 mixed = self.mixed_tick and bool(self._prefill_q) \
@@ -3009,18 +3023,28 @@ class LLMEngine:
                     # batch: a long prompt's chunks interleave with
                     # decode ticks instead of stalling in-flight
                     # generations for its whole prefill
-                    self._prefill_tick()
+                    with _trace.phase("llm.issue.prefill"):
+                        self._prefill_tick()
                     busy = True
                 self._m["prefill_queue"].set(len(self._prefill_q))
                 live = self._live_slots() if self.spec_k or not mixed \
                     else []
+                if live and self.spec_k:
+                    # both speculative paths plan from realized state:
+                    # a mixed/prefill record's async first token must
+                    # land in req.tokens (in issue order, TTFT at the
+                    # fetch) before budgets are computed, and it may
+                    # already close the slot (they re-filter `live`)
+                    while self._inflight:
+                        self._drain_one()
                 if live and self.spec_k and self.spec_slab:
                     # on-device rounds: draft-K + verify + accept all
                     # inside ONE scan slab dispatch of N rounds
                     self._issue_spec_slab(live)
                     busy = True
                 elif live and self.spec_k:
-                    self._spec_round(live)
+                    with _trace.phase("llm.issue.spec"):
+                        self._spec_round(live)
                     busy = True
                 elif live and self.decode_ticks_per_dispatch > 1:
                     # device-resident decode loop: N ticks, ONE
@@ -3075,7 +3099,8 @@ class LLMEngine:
                                     fut.set_exception(
                                         EngineClosed("engine closed"))
                             return
-                        self._wake.wait(timeout=0.05)
+                        with _trace.phase("llm.loop.idle"):
+                            self._wake.wait(timeout=0.05)
                         self._wake.clear()
             except Exception as e:  # noqa: BLE001
                 # a device/compile error (e.g. a transient PJRT
@@ -3296,57 +3321,62 @@ class LLMEngine:
 
     def _issue(self, live: List[int]):
         """Dispatch ONE decode step for the live slots; tokens chain
-        from the previous step ON DEVICE (no fetch here)."""
-        for slot in list(live):
-            req = self._slots[slot]
-            in_flight = self._inflight_tokens(slot)
-            if len(req.tokens) + in_flight >= req.max_new_tokens:
-                # length completion is already provable on the host:
-                # issuing more would only burn pages/compute on tokens
-                # the drain will discard (and could starve a
-                # concurrent request into truncation)
-                self._begin_close(slot, accept_inflight=True)
-                live.remove(slot)
-                continue
-            pos = int(self.context_lens[slot])
-            if pos >= self.max_len or not self._ensure_page(slot, pos):
-                # in-flight steps cannot cover the remainder (checked
-                # above), so this IS a truncation; the in-flight tokens
-                # are still wanted and delivered by the drain
-                req.truncated = True
-                self._begin_close(slot, accept_inflight=True)
-                live.remove(slot)
-        if not live:
-            return
-        positions = np.zeros((self.max_seqs,), np.int32)
-        lens = np.zeros((self.max_seqs,), np.int32)
-        for slot in live:
-            positions[slot] = self.context_lens[slot]
-            lens[slot] = self.context_lens[slot] + 1
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-        self._guard_recompiles("decode_step")
-        args = (self._params, self._buffers,
-                self._tokens_dev, jnp.asarray(positions),
-                jnp.asarray(self.block_tables), jnp.asarray(lens),
-                self.k_pages, self.v_pages,
-                jnp.asarray(self.temperatures),
-                jnp.asarray(self._nonces), self._key)
-        if _perf.enabled():
-            self._perf_program("decode_step", (), self._decode_fn, args)
-        tokens, self.k_pages, self.v_pages = self._decode_fn(*args)
-        self._count_dispatch()
-        self._tokens_dev = tokens
-        self._issue_seq += 1
-        self._inflight.append((self._issue_seq, list(live), tokens,
-                               "d", None))
-        for slot in live:
-            self.context_lens[slot] += 1
-        self.n_decode_ticks += 1
-        self.tick_history.append("d")
-        self._m["decode_ticks"].inc()
-        self._m["occupancy"].observe(len(live) / self.max_seqs)
-        self._update_kv_gauge()
+        from the previous step ON DEVICE (no fetch here). The body
+        is one ``llm.issue.*`` leaf phase (here and in the other
+        issue paths) that takes the dispatch's attrs."""
+        with _trace.phase("llm.issue.decode") as ph:
+            for slot in list(live):
+                req = self._slots[slot]
+                in_flight = self._inflight_tokens(slot)
+                if len(req.tokens) + in_flight >= req.max_new_tokens:
+                    # length completion is already provable on the host:
+                    # issuing more would only burn pages/compute on tokens
+                    # the drain will discard (and could starve a
+                    # concurrent request into truncation)
+                    self._begin_close(slot, accept_inflight=True)
+                    live.remove(slot)
+                    continue
+                pos = int(self.context_lens[slot])
+                if pos >= self.max_len or not self._ensure_page(slot, pos):
+                    # in-flight steps cannot cover the remainder (checked
+                    # above), so this IS a truncation; the in-flight tokens
+                    # are still wanted and delivered by the drain
+                    req.truncated = True
+                    self._begin_close(slot, accept_inflight=True)
+                    live.remove(slot)
+            if not live:
+                return
+            positions = np.zeros((self.max_seqs,), np.int32)
+            lens = np.zeros((self.max_seqs,), np.int32)
+            for slot in live:
+                positions[slot] = self.context_lens[slot]
+                lens[slot] = self.context_lens[slot] + 1
+            if _faults.enabled():
+                _faults.check("device.dispatch")
+            self._guard_recompiles("decode_step")
+            args = (self._params, self._buffers,
+                    self._tokens_dev, jnp.asarray(positions),
+                    jnp.asarray(self.block_tables), jnp.asarray(lens),
+                    self.k_pages, self.v_pages,
+                    jnp.asarray(self.temperatures),
+                    jnp.asarray(self._nonces), self._key)
+            if _perf.enabled():
+                self._perf_program("decode_step", (), self._decode_fn, args)
+            tokens, self.k_pages, self.v_pages = self._decode_fn(*args)
+            self._count_dispatch()
+            self._tokens_dev = tokens
+            self._issue_seq += 1
+            self._inflight.append((self._issue_seq, list(live), tokens,
+                                   "d", None))
+            ph.set_attr("issue_seq", self._issue_seq) \
+                .set_attr("live_rows", len(live)).set_attr("ticks", 1)
+            for slot in live:
+                self.context_lens[slot] += 1
+            self.n_decode_ticks += 1
+            self.tick_history.append("d")
+            self._m["decode_ticks"].inc()
+            self._m["occupancy"].observe(len(live) / self.max_seqs)
+            self._update_kv_gauge()
 
     def _plan_slab(self, live: List[int], N: int):
         """The decode-side slab plan, shared by the pure-decode slab
@@ -3431,44 +3461,47 @@ class LLMEngine:
         writes all happen on device; the drain (same loop iteration —
         a slab is its own lookahead) replays the device's masking
         decisions from the host copy of the budgets."""
-        N = self.decode_ticks_per_dispatch
-        plan, budgets, n_eff = self._plan_slab(live, N)
-        if not live:
-            return
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-            _faults.check("engine.slab")
-        self._guard_recompiles("decode_loop", (n_eff,))
-        pos_arr = np.zeros((self.max_seqs,), np.int32)
-        bud_arr = np.zeros((self.max_seqs,), np.int32)
-        for slot in live:
-            pos_arr[slot] = plan[slot][0]
-            bud_arr[slot] = budgets[slot]
-        carry = DecodeCarry(
-            tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
-            budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
-            v_pages=self.v_pages)
-        slab_args = (self._params, self._buffers, carry,
-                     jnp.asarray(self.block_tables),
-                     jnp.asarray(self.temperatures),
-                     jnp.asarray(self._nonces), self._key, n_eff)
-        if _perf.enabled():
-            self._perf_program("decode_loop", (n_eff,), self._slab_fn,
-                               slab_args, steps=n_eff)
-        toks, carry = self._slab_fn(*slab_args)
-        self._count_dispatch()
-        self._tokens_dev = carry.tokens
-        self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
-        self._issue_seq += 1
-        # context_lens advances at the DRAIN (the device decides how
-        # far each slot really went — mid-slab EOS stops its writes);
-        # the record carries the host copy of the entry state
-        self._inflight.append((self._issue_seq, list(live), toks, "D",
-                               {"budgets": budgets,
-                                "pos0": {s: plan[s][0] for s in live}}))
-        self.tick_history.append("D")
-        self._m["occupancy"].observe(len(live) / self.max_seqs)
-        self._update_kv_gauge()
+        with _trace.phase("llm.issue.slab") as ph:
+            N = self.decode_ticks_per_dispatch
+            plan, budgets, n_eff = self._plan_slab(live, N)
+            if not live:
+                return
+            if _faults.enabled():
+                _faults.check("device.dispatch")
+                _faults.check("engine.slab")
+            self._guard_recompiles("decode_loop", (n_eff,))
+            pos_arr = np.zeros((self.max_seqs,), np.int32)
+            bud_arr = np.zeros((self.max_seqs,), np.int32)
+            for slot in live:
+                pos_arr[slot] = plan[slot][0]
+                bud_arr[slot] = budgets[slot]
+            carry = DecodeCarry(
+                tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
+                budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
+                v_pages=self.v_pages)
+            slab_args = (self._params, self._buffers, carry,
+                         jnp.asarray(self.block_tables),
+                         jnp.asarray(self.temperatures),
+                         jnp.asarray(self._nonces), self._key, n_eff)
+            if _perf.enabled():
+                self._perf_program("decode_loop", (n_eff,), self._slab_fn,
+                                   slab_args, steps=n_eff)
+            toks, carry = self._slab_fn(*slab_args)
+            self._count_dispatch()
+            self._tokens_dev = carry.tokens
+            self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
+            self._issue_seq += 1
+            # context_lens advances at the DRAIN (the device decides how
+            # far each slot really went — mid-slab EOS stops its writes);
+            # the record carries the host copy of the entry state
+            self._inflight.append((self._issue_seq, list(live), toks, "D",
+                                   {"budgets": budgets,
+                                    "pos0": {s: plan[s][0] for s in live}}))
+            ph.set_attr("issue_seq", self._issue_seq) \
+                .set_attr("live_rows", len(live)).set_attr("ticks", n_eff)
+            self.tick_history.append("D")
+            self._m["occupancy"].observe(len(live) / self.max_seqs)
+            self._update_kv_gauge()
 
     def _issue_mixed(self, live: List[int]):
         """Dispatch ONE fused MIXED slab: up to
@@ -3490,200 +3523,216 @@ class LLMEngine:
         between its phases. The drain replays the device's masking
         from the host copy of (budgets, start tick, start position),
         sharing :meth:`_drain_slab`."""
-        N = self.decode_ticks_per_dispatch
-        ps = self.page_size
-        C = self.prefill_chunk
-        # --- decode side: the SHARED slab plan (never drifts from
-        # the pure-decode slab's coverage/shrink/truncation rules) ---
-        plan, entry_bud, n_eff = self._plan_slab(live, N)
-        # drain metadata: decode slots emit from tick 0 at pos0;
-        # finishing-prefill slots are added below with their start
-        # tick and pos0 = len(prompt) - 1 (the first emission advances
-        # context to len(prompt))
-        meta_bud = dict(entry_bud)
-        meta_pos0 = {s: plan[s][0] for s in plan}
-        start: Dict[int, int] = {}
-        # --- prefill side: pack the slab's chunk schedule --------------
-        ptok = np.zeros((n_eff, C), np.int32)
-        ppos = np.zeros((n_eff, C), np.int32)
-        plim = np.zeros((n_eff, C), np.int32)
-        ptbl = np.zeros((n_eff, C, self.pages_per_seq), np.int32)
-        fin = np.zeros((n_eff, self.max_seqs), bool)
-        fin_row = np.zeros((n_eff, self.max_seqs), np.int32)
-        fin_pos = np.zeros((n_eff, self.max_seqs), np.int32)
-        grant = np.zeros((n_eff, self.max_seqs), np.int32)
-        touched: List[_Request] = []
-        n_prefill_tokens = 0
-        pticks = 0
-        for j in range(n_eff):
-            if not self._prefill_q:
-                # queue drained: STOP the slab here rather than
-                # running decode-only ticks that still carry C padded
-                # chunk rows each — the next loop iteration's
-                # pure-decode slab serves the remainder at decode
-                # shapes (n_run below trims the schedule)
-                break
-            used = 0
-            while self._prefill_q and used < C:
-                req = self._prefill_q[0]
-                n = len(req.prompt)
-                take = min(C - used, n - req.prefill_pos)
-                row = self.block_tables[req.slot]
-                for t in range(take):
-                    p = req.prefill_pos + t
-                    ptok[j, used + t] = req.prompt[p]
-                    ppos[j, used + t] = p
-                    plim[j, used + t] = p + 1
-                    ptbl[j, used + t] = row
-                req.prefill_pos += take
-                used += take
-                if req not in touched:
-                    touched.append(req)
-                if req.spans is not None:
-                    req.spans["prefill"].add_event(
-                        "chunk", {"tokens": take,
-                                  "pos": req.prefill_pos, "tick": j})
-                if req.prefill_pos >= n:
-                    self._prefill_q.popleft()
-                    # emission grant: first token + as many decode
-                    # ticks as the slab has left AND pages can cover
-                    # (positions n .. n+g-2 hold the fed tokens; a
-                    # clamped grant is NOT a truncation — the next
-                    # slab entry re-plans exactly like N=1 would)
-                    # spec-slab engines take the first token ONLY: the
-                    # remaining grant would be target-only decode ticks
-                    # with no draft-KV coverage behind the next verify
-                    # window — their decode belongs to _issue_spec_slab
-                    g_want = 1 if self.spec_k \
-                        else min(req.max_new_tokens, n_eff - j)
-                    g = 1
-                    for tt in range(1, g_want):
-                        pos = n + tt - 1
-                        if pos >= self.max_len:
-                            break
-                        idx = pos // ps
-                        if self.block_tables[req.slot, idx] == 0:
-                            page = self._alloc_page()
-                            if page is None:
-                                break
-                            self.block_tables[req.slot, idx] = page
-                        g += 1
-                    fin[j, req.slot] = True
-                    fin_row[j, req.slot] = used - 1
-                    fin_pos[j, req.slot] = n - 1
-                    grant[j, req.slot] = g
-                    start[req.slot] = j
-                    meta_bud[req.slot] = g
-                    meta_pos0[req.slot] = n - 1
-                    req.prefill_done = True
+        with _trace.phase("llm.issue.mixed") as ph:
+            N = self.decode_ticks_per_dispatch
+            ps = self.page_size
+            C = self.prefill_chunk
+            # a chunk event is stamped with the read its issue phase
+            # started from, and carries the sequence number this dispatch
+            # will take: a request's chunk, the llm.issue.mixed phase and
+            # the device execution that carried it match on both
+            t_issue = ph.t0 or time.perf_counter()
+            seq = self._issue_seq + 1
+            # --- decode side: the SHARED slab plan (never drifts from
+            # the pure-decode slab's coverage/shrink/truncation rules) ---
+            plan, entry_bud, n_eff = self._plan_slab(live, N)
+            # drain metadata: decode slots emit from tick 0 at pos0;
+            # finishing-prefill slots are added below with their start
+            # tick and pos0 = len(prompt) - 1 (the first emission advances
+            # context to len(prompt))
+            meta_bud = dict(entry_bud)
+            meta_pos0 = {s: plan[s][0] for s in plan}
+            start: Dict[int, int] = {}
+            # --- prefill side: pack the slab's chunk schedule --------------
+            ptok = np.zeros((n_eff, C), np.int32)
+            ppos = np.zeros((n_eff, C), np.int32)
+            plim = np.zeros((n_eff, C), np.int32)
+            ptbl = np.zeros((n_eff, C, self.pages_per_seq), np.int32)
+            fin = np.zeros((n_eff, self.max_seqs), bool)
+            fin_row = np.zeros((n_eff, self.max_seqs), np.int32)
+            fin_pos = np.zeros((n_eff, self.max_seqs), np.int32)
+            grant = np.zeros((n_eff, self.max_seqs), np.int32)
+            touched: List[_Request] = []
+            n_prefill_tokens = 0
+            pticks = 0
+            for j in range(n_eff):
+                if not self._prefill_q:
+                    # queue drained: STOP the slab here rather than
+                    # running decode-only ticks that still carry C padded
+                    # chunk rows each — the next loop iteration's
+                    # pure-decode slab serves the remainder at decode
+                    # shapes (n_run below trims the schedule)
+                    break
+                used = 0
+                while self._prefill_q and used < C:
+                    req = self._prefill_q[0]
+                    n = len(req.prompt)
+                    take = min(C - used, n - req.prefill_pos)
+                    row = self.block_tables[req.slot]
+                    for t in range(take):
+                        p = req.prefill_pos + t
+                        ptok[j, used + t] = req.prompt[p]
+                        ppos[j, used + t] = p
+                        plim[j, used + t] = p + 1
+                        ptbl[j, used + t] = row
+                    req.prefill_pos += take
+                    used += take
+                    if req not in touched:
+                        touched.append(req)
                     if req.spans is not None:
-                        tp = time.perf_counter()
-                        req.spans["prefill"].end(tp)
-                        req.spans["first_token"] = _trace.start_span(
-                            "llm.first_token",
-                            parent=req.spans["root"], t0=tp)
-                else:
-                    break   # chunk budget exhausted mid-prompt
-            if used:
-                pticks += 1
-                n_prefill_tokens += used
-        # the slab runs only as long as the prefill schedule needs
-        # (>=1 — the queue was non-empty at entry): decode work beyond
-        # it moves to the next iteration's pure-decode slab, whose
-        # program has no chunk rows. The realized length rounds UP to
-        # a power of two (capped at the coverable bound) so a varying
-        # schedule compiles at most log2(N)+1 mixed programs instead
-        # of one per length — the decode_loop signature discipline;
-        # the padding ticks (no prefill rows) still decode. Budgets
-        # and grants clamp to the trimmed length; over-reserved pages
-        # stay with their slots (used by the very next slab, never
-        # leaked).
-        n_run = min(n_eff, 1 << (max(1, pticks) - 1).bit_length())
-        for slot in list(meta_bud):
-            j0 = start.get(slot, 0)
-            clamped = min(meta_bud[slot], n_run - j0)
-            meta_bud[slot] = clamped
-            if slot in start:
-                grant[j0, slot] = clamped
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-            _faults.check("engine.slab")
-        self._guard_recompiles("mixed_tick", (n_run,))
-        pos_arr = np.zeros((self.max_seqs,), np.int32)
-        bud_arr = np.zeros((self.max_seqs,), np.int32)
-        for slot in plan:
-            pos_arr[slot] = plan[slot][0]
-            bud_arr[slot] = min(entry_bud[slot], n_run)
-        carry = DecodeCarry(
-            tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
-            budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
-            v_pages=self.v_pages)
-        xs = {"tok": jnp.asarray(ptok[:n_run]),
-              "pos": jnp.asarray(ppos[:n_run]),
-              "lim": jnp.asarray(plim[:n_run]),
-              "tbl": jnp.asarray(ptbl[:n_run]),
-              "fin": jnp.asarray(fin[:n_run]),
-              "row": jnp.asarray(fin_row[:n_run]),
-              "fpos": jnp.asarray(fin_pos[:n_run]),
-              "grant": jnp.asarray(grant[:n_run])}
-        mixed_args = (self._params, self._buffers, carry, xs,
-                      jnp.asarray(self.block_tables),
-                      jnp.asarray(self.temperatures),
-                      jnp.asarray(self._nonces), self._key, n_run)
-        if _perf.enabled():
-            self._perf_program("mixed_tick", (n_run,), self._mixed_fn,
-                               mixed_args, steps=n_run)
-        toks, carry = self._mixed_fn(*mixed_args)
-        self._count_dispatch()
-        self._tokens_dev = carry.tokens
-        self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
-        if self.spec_k and self.spec_slab:
-            # draft ride-along over the slab's WHOLE packed chunk
-            # schedule, flattened to one ragged chunk (padding rows
-            # carry zero tables → scratch page 0): same coverage
-            # argument as _prefill_tick's ride-along
-            zeros = jnp.zeros((self.max_seqs,), jnp.int32)
-            self.draft_k_pages, self.draft_v_pages = \
-                self._draft_chunk_fn(
-                    self._draft_params, self._draft_buffers,
-                    jnp.asarray(ptok[:n_run].reshape(-1)),
-                    jnp.asarray(ppos[:n_run].reshape(-1)),
-                    jnp.asarray(plim[:n_run].reshape(-1)),
-                    jnp.asarray(ptbl[:n_run].reshape(
-                        -1, self.pages_per_seq)),
-                    zeros, zeros,
-                    self.draft_k_pages, self.draft_v_pages,
-                    jnp.asarray(self.temperatures),
-                    jnp.asarray(self._nonces), self._key)[1:]
+                        req.spans["prefill"].add_event(
+                            "chunk", {"tokens": take,
+                                      "pos": req.prefill_pos, "tick": j,
+                                      "issue_seq": seq}, ts=t_issue)
+                    if req.prefill_pos >= n:
+                        self._prefill_q.popleft()
+                        # emission grant: first token + as many decode
+                        # ticks as the slab has left AND pages can cover
+                        # (positions n .. n+g-2 hold the fed tokens; a
+                        # clamped grant is NOT a truncation — the next
+                        # slab entry re-plans exactly like N=1 would)
+                        # spec-slab engines take the first token ONLY: the
+                        # remaining grant would be target-only decode ticks
+                        # with no draft-KV coverage behind the next verify
+                        # window — their decode belongs to _issue_spec_slab
+                        g_want = 1 if self.spec_k \
+                            else min(req.max_new_tokens, n_eff - j)
+                        g = 1
+                        for tt in range(1, g_want):
+                            pos = n + tt - 1
+                            if pos >= self.max_len:
+                                break
+                            idx = pos // ps
+                            if self.block_tables[req.slot, idx] == 0:
+                                page = self._alloc_page()
+                                if page is None:
+                                    break
+                                self.block_tables[req.slot, idx] = page
+                            g += 1
+                        fin[j, req.slot] = True
+                        fin_row[j, req.slot] = used - 1
+                        fin_pos[j, req.slot] = n - 1
+                        grant[j, req.slot] = g
+                        start[req.slot] = j
+                        meta_bud[req.slot] = g
+                        meta_pos0[req.slot] = n - 1
+                        req.prefill_done = True
+                        if req.spans is not None:
+                            tp = time.perf_counter()
+                            req.spans["prefill"].end(tp)
+                            req.spans["first_token"] = _trace.start_span(
+                                "llm.first_token",
+                                parent=req.spans["root"], t0=tp)
+                    else:
+                        break   # chunk budget exhausted mid-prompt
+                if used:
+                    pticks += 1
+                    n_prefill_tokens += used
+            # the slab runs only as long as the prefill schedule needs
+            # (>=1 — the queue was non-empty at entry): decode work beyond
+            # it moves to the next iteration's pure-decode slab, whose
+            # program has no chunk rows. The realized length rounds UP to
+            # a power of two (capped at the coverable bound) so a varying
+            # schedule compiles at most log2(N)+1 mixed programs instead
+            # of one per length — the decode_loop signature discipline;
+            # the padding ticks (no prefill rows) still decode. Budgets
+            # and grants clamp to the trimmed length; over-reserved pages
+            # stay with their slots (used by the very next slab, never
+            # leaked).
+            n_run = min(n_eff, 1 << (max(1, pticks) - 1).bit_length())
+            for slot in list(meta_bud):
+                j0 = start.get(slot, 0)
+                clamped = min(meta_bud[slot], n_run - j0)
+                meta_bud[slot] = clamped
+                if slot in start:
+                    grant[j0, slot] = clamped
+            if _faults.enabled():
+                _faults.check("device.dispatch")
+                _faults.check("engine.slab")
+            self._guard_recompiles("mixed_tick", (n_run,))
+            pos_arr = np.zeros((self.max_seqs,), np.int32)
+            bud_arr = np.zeros((self.max_seqs,), np.int32)
+            for slot in plan:
+                pos_arr[slot] = plan[slot][0]
+                bud_arr[slot] = min(entry_bud[slot], n_run)
+            carry = DecodeCarry(
+                tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
+                budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
+                v_pages=self.v_pages)
+            xs = {"tok": jnp.asarray(ptok[:n_run]),
+                  "pos": jnp.asarray(ppos[:n_run]),
+                  "lim": jnp.asarray(plim[:n_run]),
+                  "tbl": jnp.asarray(ptbl[:n_run]),
+                  "fin": jnp.asarray(fin[:n_run]),
+                  "row": jnp.asarray(fin_row[:n_run]),
+                  "fpos": jnp.asarray(fin_pos[:n_run]),
+                  "grant": jnp.asarray(grant[:n_run])}
+            mixed_args = (self._params, self._buffers, carry, xs,
+                          jnp.asarray(self.block_tables),
+                          jnp.asarray(self.temperatures),
+                          jnp.asarray(self._nonces), self._key, n_run)
+            if _perf.enabled():
+                self._perf_program("mixed_tick", (n_run,), self._mixed_fn,
+                                   mixed_args, steps=n_run)
+            toks, carry = self._mixed_fn(*mixed_args)
             self._count_dispatch()
-        self._issue_seq += 1
-        slots_list = sorted(meta_bud)
-        self._inflight.append(
-            (self._issue_seq, slots_list, toks, "M",
-             {"budgets": meta_bud, "pos0": meta_pos0, "start": start}))
-        if self._cache is not None:
-            for req in touched:
-                # promote freshly-written FULL prompt pages to shared
-                # (same incremental registration as the legacy chunk
-                # tick — a quantized page shares by the same token
-                # digests; the bytes it holds are deterministic)
-                for i in range(req.n_reg_pages,
-                               req.prefill_pos // ps):
-                    self._cache.register(
-                        req.digests[i],
-                        int(self.block_tables[req.slot, i]),
-                        req.prompt[i * ps:(i + 1) * ps])
-                req.n_reg_pages = max(req.n_reg_pages,
-                                      req.prefill_pos // ps)
-        self.n_mixed_slabs += 1
-        self.n_prefill_ticks += pticks
-        self._m["prefill_ticks"].inc(pticks)
-        self._m["mixed_slabs"].inc()
-        if n_prefill_tokens:
-            self._m["mixed_prefill_tokens"].inc(n_prefill_tokens)
-        self.tick_history.append("m")
-        self._m["occupancy"].observe(len(slots_list) / self.max_seqs)
-        self._update_kv_gauge()
+            self._tokens_dev = carry.tokens
+            self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
+            if self.spec_k and self.spec_slab:
+                # draft ride-along over the slab's WHOLE packed chunk
+                # schedule, flattened to one ragged chunk (padding rows
+                # carry zero tables → scratch page 0): same coverage
+                # argument as _prefill_tick's ride-along
+                zeros = jnp.zeros((self.max_seqs,), jnp.int32)
+                self.draft_k_pages, self.draft_v_pages = \
+                    self._draft_chunk_fn(
+                        self._draft_params, self._draft_buffers,
+                        jnp.asarray(ptok[:n_run].reshape(-1)),
+                        jnp.asarray(ppos[:n_run].reshape(-1)),
+                        jnp.asarray(plim[:n_run].reshape(-1)),
+                        jnp.asarray(ptbl[:n_run].reshape(
+                            -1, self.pages_per_seq)),
+                        zeros, zeros,
+                        self.draft_k_pages, self.draft_v_pages,
+                        jnp.asarray(self.temperatures),
+                        jnp.asarray(self._nonces), self._key)[1:]
+                self._count_dispatch()
+            self._issue_seq += 1
+            slots_list = sorted(meta_bud)
+            self._inflight.append(
+                (self._issue_seq, slots_list, toks, "M",
+                 {"budgets": meta_bud, "pos0": meta_pos0, "start": start}))
+            # live_rows: slots that can emit in this dispatch (decoding
+            # ones and prompts completing in it); chunk_rows: prompts
+            # that got a chunk; chunk_tokens: their prompt tokens
+            ph.set_attr("issue_seq", self._issue_seq) \
+                .set_attr("live_rows", len(slots_list)) \
+                .set_attr("chunk_rows", len(touched)) \
+                .set_attr("chunk_tokens", n_prefill_tokens) \
+                .set_attr("ticks", n_run)
+            if self._cache is not None:
+                for req in touched:
+                    # promote freshly-written FULL prompt pages to shared
+                    # (same incremental registration as the legacy chunk
+                    # tick — a quantized page shares by the same token
+                    # digests; the bytes it holds are deterministic)
+                    for i in range(req.n_reg_pages,
+                                   req.prefill_pos // ps):
+                        self._cache.register(
+                            req.digests[i],
+                            int(self.block_tables[req.slot, i]),
+                            req.prompt[i * ps:(i + 1) * ps])
+                    req.n_reg_pages = max(req.n_reg_pages,
+                                          req.prefill_pos // ps)
+            self.n_mixed_slabs += 1
+            self.n_prefill_ticks += pticks
+            self._m["prefill_ticks"].inc(pticks)
+            self._m["mixed_slabs"].inc()
+            if n_prefill_tokens:
+                self._m["mixed_prefill_tokens"].inc(n_prefill_tokens)
+            self.tick_history.append("m")
+            self._m["occupancy"].observe(len(slots_list) / self.max_seqs)
+            self._update_kv_gauge()
 
     def _issue_spec_slab(self, live: List[int]):
         """Dispatch up to ``decode_ticks_per_dispatch`` speculative
@@ -3706,89 +3755,90 @@ class LLMEngine:
         Over-reserved pages (low acceptance) stay with their slots
         for the next slab — used or freed at close, never leaked.
 
-        Drains all in-flight records FIRST (like the legacy round):
-        a mixed/prefill record's async first token must land before
-        budgets are computed, and a mixed-finishing slot's
+        The loop has drained all in-flight records FIRST (as for the
+        legacy round): a mixed/prefill record's async first token must
+        land before budgets are computed, and a mixed-finishing slot's
         ``context_lens`` is only advanced by its drain."""
-        while self._inflight:
-            self._drain_one()
-        live = [s for s in live if self._slots[s] is not None
-                and not self._slots[s].closing]
-        if not live:
-            self._maybe_finalize()
-            return
-        N = self.decode_ticks_per_dispatch
-        K = self.spec_k
-        budgets: Dict[int, int] = {}
-        pos0s: Dict[int, int] = {}
-        cov = np.zeros((self.max_seqs,), np.int32)
-        for slot in list(live):
-            req = self._slots[slot]
-            want = req.max_new_tokens - len(req.tokens)
-            if want <= 0:
-                self._begin_close(slot, accept_inflight=True)
-                live.remove(slot)
-                continue
-            pos0 = int(self.context_lens[slot])
-            covered = 0
-            for j in range(min(N * K, want)):
-                pos = pos0 + j
-                if pos >= self.max_len or \
-                        not self._ensure_page(slot, pos):
-                    break
-                covered += 1
-            if covered == 0:
-                # the NEXT token can't be cached — the same condition
-                # plain decode truncates on
-                req.truncated = len(req.tokens) < req.max_new_tokens
-                self._begin_close(slot)
-                live.remove(slot)
-                continue
-            budgets[slot] = min(want, covered)
-            pos0s[slot] = pos0
-            cov[slot] = pos0 + covered
-        if not live:
-            self._maybe_finalize()
-            return
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-            _faults.check("engine.slab")
-        self._guard_recompiles("spec_round", (N, K))
-        pos_arr = np.zeros((self.max_seqs,), np.int32)
-        bud_arr = np.zeros((self.max_seqs,), np.int32)
-        for slot in live:
-            pos_arr[slot] = pos0s[slot]
-            bud_arr[slot] = budgets[slot]
-        carry = DecodeCarry(
-            tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
-            budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
-            v_pages=self.v_pages,
-            draft_k_pages=self.draft_k_pages,
-            draft_v_pages=self.draft_v_pages)
-        args = (self._params, self._buffers, self._draft_params,
-                self._draft_buffers, carry,
-                jnp.asarray(self.block_tables),
-                jnp.asarray(self.temperatures),
-                jnp.asarray(self._nonces), jnp.asarray(cov),
-                self._key, N)
-        if _perf.enabled():
-            self._perf_program("spec_round", (N,),
-                               self._spec_slab_fn, args, steps=N)
-        ys, carry = self._spec_slab_fn(*args)
-        self._count_dispatch()
-        self._tokens_dev = carry.tokens
-        self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
-        self.draft_k_pages = carry.draft_k_pages
-        self.draft_v_pages = carry.draft_v_pages
-        self._issue_seq += 1
-        # ys = (tokens [N, B, K], n_emit [N, B]); context_lens
-        # advances at the DRAIN from the realized emission counts
-        self._inflight.append(
-            (self._issue_seq, list(live), ys, "S",
-             {"budgets": budgets, "pos0": pos0s}))
-        self.tick_history.append("S")
-        self._m["occupancy"].observe(len(live) / self.max_seqs)
-        self._update_kv_gauge()
+        with _trace.phase("llm.issue.spec") as ph:
+            live = [s for s in live if self._slots[s] is not None
+                    and not self._slots[s].closing]
+            if not live:
+                self._maybe_finalize()
+                return
+            N = self.decode_ticks_per_dispatch
+            K = self.spec_k
+            budgets: Dict[int, int] = {}
+            pos0s: Dict[int, int] = {}
+            cov = np.zeros((self.max_seqs,), np.int32)
+            for slot in list(live):
+                req = self._slots[slot]
+                want = req.max_new_tokens - len(req.tokens)
+                if want <= 0:
+                    self._begin_close(slot, accept_inflight=True)
+                    live.remove(slot)
+                    continue
+                pos0 = int(self.context_lens[slot])
+                covered = 0
+                for j in range(min(N * K, want)):
+                    pos = pos0 + j
+                    if pos >= self.max_len or \
+                            not self._ensure_page(slot, pos):
+                        break
+                    covered += 1
+                if covered == 0:
+                    # the NEXT token can't be cached — the same condition
+                    # plain decode truncates on
+                    req.truncated = len(req.tokens) < req.max_new_tokens
+                    self._begin_close(slot)
+                    live.remove(slot)
+                    continue
+                budgets[slot] = min(want, covered)
+                pos0s[slot] = pos0
+                cov[slot] = pos0 + covered
+            if not live:
+                self._maybe_finalize()
+                return
+            if _faults.enabled():
+                _faults.check("device.dispatch")
+                _faults.check("engine.slab")
+            self._guard_recompiles("spec_round", (N, K))
+            pos_arr = np.zeros((self.max_seqs,), np.int32)
+            bud_arr = np.zeros((self.max_seqs,), np.int32)
+            for slot in live:
+                pos_arr[slot] = pos0s[slot]
+                bud_arr[slot] = budgets[slot]
+            carry = DecodeCarry(
+                tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
+                budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
+                v_pages=self.v_pages,
+                draft_k_pages=self.draft_k_pages,
+                draft_v_pages=self.draft_v_pages)
+            args = (self._params, self._buffers, self._draft_params,
+                    self._draft_buffers, carry,
+                    jnp.asarray(self.block_tables),
+                    jnp.asarray(self.temperatures),
+                    jnp.asarray(self._nonces), jnp.asarray(cov),
+                    self._key, N)
+            if _perf.enabled():
+                self._perf_program("spec_round", (N,),
+                                   self._spec_slab_fn, args, steps=N)
+            ys, carry = self._spec_slab_fn(*args)
+            self._count_dispatch()
+            self._tokens_dev = carry.tokens
+            self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
+            self.draft_k_pages = carry.draft_k_pages
+            self.draft_v_pages = carry.draft_v_pages
+            self._issue_seq += 1
+            # ys = (tokens [N, B, K], n_emit [N, B]); context_lens
+            # advances at the DRAIN from the realized emission counts
+            self._inflight.append(
+                (self._issue_seq, list(live), ys, "S",
+                 {"budgets": budgets, "pos0": pos0s}))
+            ph.set_attr("issue_seq", self._issue_seq) \
+                .set_attr("live_rows", len(live)).set_attr("ticks", N)
+            self.tick_history.append("S")
+            self._m["occupancy"].observe(len(live) / self.max_seqs)
+            self._update_kv_gauge()
 
     def _deliver_token(self, slot: int, req: _Request, tok: int,
                        seq: int) -> None:
@@ -3849,44 +3899,47 @@ class LLMEngine:
         if _faults.enabled():
             _faults.check("device.transfer")
         seq, slots_list, tokens, kind, meta = self._inflight.popleft()
-        if kind == "S":
-            # spec-slab record: (committed tokens [N, B, K], realized
-            # per-round emission counts [N, B])
-            host = np.asarray(tokens[0])   # the only blocking fetch
-            host_acc = np.asarray(tokens[1])
-        else:
-            host = np.asarray(tokens)      # the only blocking fetch
-        self._fetch_seq = seq
-        if self._consec_device_errors:
-            # a successful fetch ends the error streak (draining is
-            # sticky until reset_health — see _update_health)
-            self._consec_device_errors = 0
-            self._update_health()
-        if kind == "S":
-            emitted = self._drain_spec_slab(seq, slots_list, host,
-                                            host_acc, meta)
-        elif kind in ("D", "M"):
-            emitted = self._drain_slab(seq, slots_list, host, meta)
-        else:
-            if kind == "d":
-                self.n_steps += 1
-            emitted = 0
-            for slot in slots_list:
-                req = self._slots[slot]
-                if req is None:
-                    continue
-                if req.closing and (not req.accepts_inflight or
-                                    len(req.tokens) >=
-                                    req.max_new_tokens):
-                    continue  # overrun token of a finished request
-                self._deliver_token(slot, req, int(host[slot]), seq)
-                emitted += 1
-        if _perf.enabled() or _goodput.enabled():
-            self._perf_attribute(kind, host.shape[0]
-                                 if kind in ("D", "M", "S") else 0,
-                                 emitted)
-        self._observe_step(emitted, timed=(kind != "p"))
-        self._maybe_finalize()
+        with _trace.phase("llm.drain.wait", {"issue_seq": seq}):
+            if kind == "S":
+                # spec-slab record: (committed tokens [N, B, K],
+                # realized per-round emission counts [N, B])
+                host = np.asarray(tokens[0])   # the only blocking fetch
+                host_acc = np.asarray(tokens[1])
+            else:
+                host = np.asarray(tokens)      # the only blocking fetch
+        with _trace.phase("llm.drain.emit", {"issue_seq": seq}) as ph:
+            self._fetch_seq = seq
+            if self._consec_device_errors:
+                # a successful fetch ends the error streak (draining is
+                # sticky until reset_health — see _update_health)
+                self._consec_device_errors = 0
+                self._update_health()
+            if kind == "S":
+                emitted = self._drain_spec_slab(seq, slots_list, host,
+                                                host_acc, meta)
+            elif kind in ("D", "M"):
+                emitted = self._drain_slab(seq, slots_list, host, meta)
+            else:
+                if kind == "d":
+                    self.n_steps += 1
+                emitted = 0
+                for slot in slots_list:
+                    req = self._slots[slot]
+                    if req is None:
+                        continue
+                    if req.closing and (not req.accepts_inflight or
+                                        len(req.tokens) >=
+                                        req.max_new_tokens):
+                        continue  # overrun token of a finished request
+                    self._deliver_token(slot, req, int(host[slot]), seq)
+                    emitted += 1
+            if _perf.enabled() or _goodput.enabled():
+                self._perf_attribute(kind, host.shape[0]
+                                     if kind in ("D", "M", "S") else 0,
+                                     emitted)
+            self._observe_step(emitted, timed=(kind != "p"))
+            self._maybe_finalize()
+            ph.set_attr("tokens", emitted)
 
     def _drain_slab(self, seq: int, slots_list: List[int], host,
                     meta: dict) -> int:
@@ -4050,13 +4103,11 @@ class LLMEngine:
         K-th draft step exists for cache coverage (it writes d_{K-1}'s KV
         so a fully-accepted round leaves no draft-cache gap); its output
         is discarded."""
-        # drain first: a just-admitted request's async first token
-        # must land in req.tokens (in issue order, observing TTFT at
-        # the fetch) BEFORE this round's accepted tokens are appended
-        # — and that first token's EOS/length may already close the
-        # slot, so the live set is re-filtered after the drain
-        while self._inflight:
-            self._drain_one()
+        # the loop drained first: a just-admitted request's async
+        # first token has landed in req.tokens BEFORE this round's
+        # accepted tokens are appended — and that first token's
+        # EOS/length may already have closed the slot, so the live set
+        # is re-filtered here
         live = [s for s in live if self._slots[s] is not None
                 and not self._slots[s].closing]
         if not live:
@@ -4265,7 +4316,7 @@ def serve_llm(engine, host: str = "127.0.0.1", port: int = 0):
             # ("the router cancelled this mid-decode") reads end to
             # end on one timeline
             cspan = None
-            if _trace.enabled():
+            if _trace.active():
                 ctx = _propagation.extract(
                     self.headers.get("traceparent"))
                 cspan = _trace.start_span(

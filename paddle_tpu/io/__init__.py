@@ -365,7 +365,7 @@ class _PrefetchIterator:
             # the SAME wait the histogram observes: input starvation
             # on the time ledger (input_wait badput)
             _goodput.note("input_wait", t1 - t0)
-        if _tracing.enabled():
+        if _tracing.active():
             # post-hoc span over the wait interval: the input-starved
             # share shows up next to dispatch/drain in span rollups
             _tracing.start_span("io.next_wait", t0=t0).end(t1)
@@ -394,6 +394,14 @@ class _PrefetchIterator:
 
 _mp_dataset = None
 _mp_collate = None
+# One fork pool at a time forks, submits or shuts down. An executor's
+# submit() and shutdown() hold its wake-up lock across a pipe write, where
+# the GIL changes hands; a worker that another loader's thread forks at
+# that moment inherits the lock held, signals every inherited wake-up at
+# its own exit (concurrent.futures.process._python_exit), and waits on it
+# for ever: the pool never joins, and the worker outlives the process with
+# its stdout open.
+_pool_mu = threading.Lock()
 
 
 def _map_worker_init(dataset, collate_fn, wid, num_workers, seed):
@@ -517,13 +525,18 @@ class DataLoader:
             depth = self.num_workers * max(self.prefetch_factor, 1)
             it = itertools.islice(iter(self.batch_sampler), skip, None)
             for batch_idx in it:
-                pending.append(pool.submit(_map_worker_collate, batch_idx))
+                with _pool_mu:      # the first submit forks the workers
+                    pending.append(
+                        pool.submit(_map_worker_collate, batch_idx))
                 if len(pending) >= depth:
                     yield pending.popleft().result()
             while pending:
                 yield pending.popleft().result()
         finally:
-            pool.shutdown(wait=False, cancel_futures=True)
+            # wait: the pool's manager thread closes the wake-up pipe
+            # under the same lock, so it ends inside _pool_mu too
+            with _pool_mu:
+                pool.shutdown(wait=True, cancel_futures=True)
 
     def _produce_multiprocess_iter(self, seed, skip: int = 0):
         """IterableDataset workers: each process iterates its own copy

@@ -131,10 +131,15 @@ def gpt_config(name: str, **overrides) -> GPTConfig:
     return GPTConfig(**cfg)
 
 
+# Device scopes (``Layer._scope``, ``jax.named_scope``): ``ln``, ``attn``
+# (both projections and the attention itself), ``mlp``, ``embed``,
+# ``lm_head``. Set on the sublayers the serving engine's programs call one
+# by one, so a trace of either path names its operations alike.
 def _norm(cfg: GPTConfig):
-    if cfg.norm_type == "rms":
-        return nn.RMSNorm(cfg.hidden_size, epsilon=cfg.layer_norm_epsilon)
-    return nn.LayerNorm(cfg.hidden_size, epsilon=cfg.layer_norm_epsilon)
+    cls = nn.RMSNorm if cfg.norm_type == "rms" else nn.LayerNorm
+    norm = cls(cfg.hidden_size, epsilon=cfg.layer_norm_epsilon)
+    norm._scope = "ln"
+    return norm
 
 
 class GPTAttention(Layer):
@@ -158,6 +163,7 @@ class GPTAttention(Layer):
         self.out_proj = nn.Linear(h, h, weight_attr=I.Normal(
             0.0, cfg.initializer_range / math.sqrt(2 * cfg.num_layers)),
             axes=("heads", "embed"), bias_axes=(None,))
+        self.qkv_proj._scope = self.out_proj._scope = "attn"
 
     def _sp_mesh(self):
         """The installed mesh when it has a real sp axis, else None
@@ -228,10 +234,11 @@ class GPTAttention(Layer):
                 if dense_mask.dtype == jnp.bool_:
                     dense_mask = jnp.where(dense_mask, 0.0, -jnp.inf)
                 causal_mask = causal_mask + dense_mask
-            out = F.scaled_dot_product_attention(
-                q, k, v, attn_mask=causal_mask,
-                dropout_p=self.cfg.attention_dropout,
-                training=self.training, use_flash=False)
+            with jax.named_scope("attn"):
+                out = F.scaled_dot_product_attention(
+                    q, k, v, attn_mask=causal_mask,
+                    dropout_p=self.cfg.attention_dropout,
+                    training=self.training, use_flash=False)
         elif self.cfg.sequence_parallel and \
                 (sp_mesh := self._sp_mesh()) is not None:
             from ..ops.ring_attention import ring_attention
@@ -252,21 +259,24 @@ class GPTAttention(Layer):
                 k = jnp.repeat(k, rep, axis=2)
                 v = jnp.repeat(v, rep, axis=2)
             dp = self.cfg.attention_dropout if self.training else 0.0
-            out = ring_attention(
-                q, k, v, causal=True, mesh=sp_mesh,
-                chunk_size=self.cfg.ring_chunk_size,
-                key_padding_mask=attn_mask,
-                dropout_p=dp,
-                # same key on every sp rank; ring_attention folds in the
-                # block's global coordinates (pipeline tick-RNG trick)
-                dropout_key=rng.next_key("sp_attn") if dp else None)
+            with jax.named_scope("attn"):
+                out = ring_attention(
+                    q, k, v, causal=True, mesh=sp_mesh,
+                    chunk_size=self.cfg.ring_chunk_size,
+                    key_padding_mask=attn_mask,
+                    dropout_p=dp,
+                    # same key on every sp rank; ring_attention folds in
+                    # the block's global coordinates (pipeline tick-RNG
+                    # trick)
+                    dropout_key=rng.next_key("sp_attn") if dp else None)
         else:
             # always causal (decoder-only); an extra additive mask (e.g.
             # padding) composes with it rather than replacing it
-            out = F.scaled_dot_product_attention(
-                q, k, v, attn_mask=dense_mask, is_causal=True,
-                dropout_p=self.cfg.attention_dropout,
-                training=self.training, use_flash=self.cfg.use_flash)
+            with jax.named_scope("attn"):
+                out = F.scaled_dot_product_attention(
+                    q, k, v, attn_mask=dense_mask, is_causal=True,
+                    dropout_p=self.cfg.attention_dropout,
+                    training=self.training, use_flash=self.cfg.use_flash)
             if row_has_key is not None:
                 out = jnp.where(row_has_key[:, :, None, None], out, 0.0)
         out = self.out_proj(out.reshape(b, s, h))
@@ -292,6 +302,7 @@ class GPTMLP(Layer):
                                 axes=("mlp", "embed"), bias_axes=(None,))
         self.act = F.swiglu if self._swiglu else getattr(F, cfg.activation)
         self.dropout = nn.Dropout(cfg.hidden_dropout)
+        self._scope = "mlp"
 
     def forward(self, x):
         return self.dropout(self.fc_out(self.act(self.fc_in(x))))
@@ -337,6 +348,7 @@ class GPTEmbeddings(Layer):
         self.dropout = nn.Dropout(cfg.hidden_dropout)
         self._use_rope = cfg.use_rope
         self._max_pos = cfg.max_position_embeddings
+        self._scope = "embed"
 
     def forward(self, input_ids, position_ids=None):
         s = input_ids.shape[1]
@@ -423,12 +435,13 @@ def _lm_logits(cfg: GPTConfig, embeddings: GPTEmbeddings, hidden,
                lm_head=None):
     """Shared head: tied-embedding matmul (bf16 under AMP; the loss
     upcasts to f32 for its log-softmax) or a separate lm_head."""
-    if cfg.tie_word_embeddings:
-        from .. import amp
-        w = embeddings.word_embeddings.weight  # [V, H]
-        hidden, w = amp.white_cast(hidden, w)
-        return jnp.einsum("bsh,vh->bsv", hidden, w)
-    return lm_head(hidden)
+    with jax.named_scope("lm_head"):
+        if cfg.tie_word_embeddings:
+            from .. import amp
+            w = embeddings.word_embeddings.weight  # [V, H]
+            hidden, w = amp.white_cast(hidden, w)
+            return jnp.einsum("bsh,vh->bsv", hidden, w)
+        return lm_head(hidden)
 
 
 class GPTForCausalLM(Layer):

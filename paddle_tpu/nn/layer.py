@@ -298,12 +298,23 @@ class Layer:
         raise NotImplementedError(
             f"{type(self).__name__} must implement forward()")
 
+    # a layer may name the ``jax.named_scope`` its forward runs under:
+    # metadata on the ops it emits, which a device trace then carries
+    # (profiler/scopes.py). Set on the instance, so that a caller that
+    # walks a model's sublayers by hand (the serving engine's programs)
+    # emits the same names as the model's own forward.
+    _scope: Optional[str] = None
+
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks.values():
             res = hook(self, args)
             if res is not None:
                 args = res if isinstance(res, tuple) else (res,)
-        out = self.forward(*args, **kwargs)
+        if self._scope is None:
+            out = self.forward(*args, **kwargs)
+        else:
+            with jax.named_scope(self._scope):
+                out = self.forward(*args, **kwargs)
         for hook in self._forward_post_hooks.values():
             res = hook(self, args, out)
             if res is not None:

@@ -6,9 +6,10 @@ reference serializes its profiler event tree to a chrome://tracing
 JSON; and the monitor stats that PS-mode jobs scraped ad hoc. Here the
 same two sinks are first-class:
 
-- ``export_chrome_tracing(profiler, path)`` — the profiler facade's
-  host annotations (RecordEvent) as complete-duration ("ph": "X")
-  trace events, loadable in chrome://tracing / Perfetto. Device-side
+- ``export_chrome_tracing(profiler, path)`` — the span table (the
+  profiler facade's RecordEvent host annotations included) as
+  complete-duration ("ph": "X") trace events, loadable in
+  chrome://tracing / Perfetto. Device-side
   timelines stay in the XProf dump under the profiler's log_dir; this
   file is the host-control-plane view the reference's logger gave.
 - ``prometheus_text()`` / ``write_prometheus()`` — text exposition
@@ -111,10 +112,10 @@ def _overlaps_window(t0: float, t1: float, windows) -> bool:
     return any(t0 <= e and s <= t1 for s, e in windows)
 
 
-def export_chrome_tracing(profiler=None, path: str = "trace.json",
-                          include_spans: bool = True) -> str:
-    """Dump the profiler facade's recorded host annotations AND the
-    tracing span table as ONE chrome://tracing-loadable JSON file:
+def export_chrome_tracing(profiler=None, path: str = "trace.json") -> str:
+    """Dump the tracing span table (the profiler facade's RecordEvent
+    host annotations are leaf phases of it) as ONE
+    chrome://tracing-loadable JSON file:
     complete ("ph": "X") events with microsecond timestamps, one row
     (tid) per recording thread, ``process_name``/``thread_name``
     metadata records (ph "M") so Perfetto labels rows instead of
@@ -127,11 +128,8 @@ def export_chrome_tracing(profiler=None, path: str = "trace.json",
     ids in ``args`` ({trace_id, span_id, parent_id, ...attributes}),
     so parent links survive the export.
     """
-    from ..profiler import _events
     from . import tracing as _tracing
-    with _events.lock:
-        events = list(_events.trace)
-    spans = _tracing.finished_spans() if include_spans else []
+    spans = _tracing.finished_spans()
     windows = None
     if profiler is not None and hasattr(profiler, "recording_windows"):
         # a profiler that never reached a RECORD phase has no windows;
@@ -139,9 +137,6 @@ def export_chrome_tracing(profiler=None, path: str = "trace.json",
         # silently producing an empty trace
         windows = profiler.recording_windows() or None
     if windows is not None:
-        events = [ev for ev in events
-                  if _overlaps_window(ev["ts"], ev["ts"] + ev["dur"],
-                                      windows)]
         spans = [sp for sp in spans
                  if _overlaps_window(sp["ts"],
                                      sp["ts"] + (sp["dur"] or 0.0),
@@ -152,8 +147,6 @@ def export_chrome_tracing(profiler=None, path: str = "trace.json",
         "args": {"name": f"paddle_tpu[{pid}]"},
     }]
     tnames = {}
-    for ev in events:
-        tnames.setdefault(ev["tid"], ev.get("tname"))
     for sp in spans:
         tnames.setdefault(sp["tid"], sp.get("tname"))
     for tid, tname in sorted(tnames.items(), key=lambda kv: kv[0] or 0):
@@ -161,21 +154,12 @@ def export_chrome_tracing(profiler=None, path: str = "trace.json",
             "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
             "args": {"name": tname or f"thread-{tid}"},
         })
-    trace_events += [{
-        "name": ev["name"],
-        "ph": "X",
-        "cat": "host",
-        "ts": round(ev["ts"] * 1e6, 3),       # seconds → microseconds
-        "dur": round(ev["dur"] * 1e6, 3),
-        "pid": pid,
-        "tid": ev["tid"],
-    } for ev in events]
     for sp in spans:
         trace_events.append({
             "name": sp["name"],
             "ph": "X",
             "cat": "span",
-            "ts": round(sp["ts"] * 1e6, 3),
+            "ts": round(sp["ts"] * 1e6, 3),   # seconds → microseconds
             "dur": round((sp["dur"] or 0.0) * 1e6, 3),
             "pid": pid,
             "tid": sp["tid"],

@@ -24,7 +24,6 @@ Dump format (one JSON object per line):
      "argv": [...], "metrics": {flattened registry snapshot}}
     {"kind": "span", "live": true,  ...span dict...}   # in flight
     {"kind": "span", "live": false, ...span dict...}   # ring, newest last
-    {"kind": "event", ...}                             # profiler tail
 
 Span dicts carry perf_counter timestamps plus ``ts_wall`` (unix) so
 dumps from different processes can be lined up.
@@ -42,9 +41,6 @@ from typing import Optional
 
 from . import tracing
 from .metrics import MetricRegistry, default_registry
-
-# how many trailing profiler RecordEvent rows ride along in a dump
-_EVENT_TAIL = 256
 
 _installed: Optional["FlightRecorder"] = None
 # RLock: install_flight_recorder holds it across its check-then-install
@@ -98,11 +94,6 @@ class FlightRecorder:
                             f"flight_{os.getpid()}_{reason}.jsonl")
         live = tracing.live_spans()
         finished = tracing.finished_spans()
-        events = []
-        prof = sys.modules.get("paddle_tpu.profiler")
-        if prof is not None:
-            with prof._events.lock:
-                events = list(prof._events.trace)[-_EVENT_TAIL:]
         try:
             metrics = self.registry.snapshot()
         except Exception:  # noqa: BLE001
@@ -125,11 +116,6 @@ class FlightRecorder:
                 sp = dict(sp, live=False, kind="span",
                           ts_wall=tracing.perf_to_wall(sp["ts"]))
                 f.write(json.dumps(sp, default=str) + "\n")
-            for ev in events:
-                f.write(json.dumps({
-                    "kind": "event",
-                    "ts_wall": tracing.perf_to_wall(ev["ts"]), **ev,
-                }, default=str) + "\n")
             f.flush()
             os.fsync(f.fileno())
         return path
@@ -190,9 +176,9 @@ class FlightRecorder:
     def _dump_bounded(self, reason: str, timeout: float = 10.0) -> None:
         """Dump from a helper thread with a bounded join. A signal
         handler runs between bytecodes of the MAIN thread — if that
-        interrupted frame holds tracing._lock / _events.lock /
-        registry locks (non-reentrant), dumping inline would deadlock
-        the handler and the process would hang instead of dying. The
+        interrupted frame holds tracing._lock / registry locks
+        (non-reentrant), dumping inline would deadlock the handler and
+        the process would hang instead of dying. The
         helper thread blocks on the lock instead; if it can't finish
         in time we give up the dump and let the death proceed."""
         t = threading.Thread(target=self.dump, args=(reason,),

@@ -143,7 +143,7 @@ def _active_phase() -> str:
     sp = tracing.current_span()
     if sp is not None:
         return sp.name
-    if tracing.enabled():
+    if tracing.active():
         live = tracing.live_spans()
         if live:
             return live[-1]["name"]

@@ -55,6 +55,7 @@ Reading guide for the tables: docs/OBSERVABILITY.md "Memory surfaces".
 
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 import time
@@ -119,11 +120,18 @@ def next_scope() -> str:
     return f"m{next(_scope_counter)}"
 
 
+# scopes whose owner the garbage collector has finalized, waiting for the
+# ledger to drop them. A finalizer runs wherever an allocation happened to
+# trigger a collection, possibly on a thread that already holds the ledger
+# lock (``_collect`` copies its rows under it), and the lock is not
+# reentrant — nor would re-entering help a loop over the rows. So the
+# finalizer takes no lock: it appends here (atomic), and the process-wide
+# ledger removes the scopes at its next read or registration.
+_finalized: collections.deque = collections.deque()
+
+
 def _cleanup_scope(scope: str) -> None:
-    try:
-        instance().remove_scope(scope)
-    except Exception:  # noqa: BLE001 — interpreter-shutdown tolerance
-        pass
+    _finalized.append(scope)
 
 
 def finalize_scope(owner, scope: str):
@@ -218,7 +226,7 @@ def _active_phase() -> str:
     sp = tracing.current_span()
     if sp is not None:
         return sp.name
-    if tracing.enabled():
+    if tracing.active():
         live = tracing.live_spans()
         if live:
             return live[-1]["name"]
@@ -305,6 +313,11 @@ class MemoryLedger:
         payload, and watermark all describe the same snapshot.
         Providers run OUTSIDE the ledger lock; a None return
         unregisters the provider — its owner is gone."""
+        while _finalized and self is _instance:
+            try:
+                self.remove_scope(_finalized.popleft())
+            except IndexError:      # another reader took the last one
+                break
         with self._mu:
             out = [dict(r) for r in self._entries.values()]
             provs = list(self._providers.items())

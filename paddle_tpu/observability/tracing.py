@@ -8,27 +8,46 @@ analog is the profiler event tree ``ChromeTracingLogger`` serialized
 (SURVEY.md §5) — but that tree is profiler-window-scoped and
 process-perspective; spans here are REQUEST/STEP-scoped and stay cheap
 enough to leave on in production (and are off by default with
-near-zero overhead: one module-flag check per instrumentation site).
+near-zero overhead: one ``active()`` check per instrumentation site).
 
-Two propagation modes, because the hot paths need both:
+Three forms, because the hot paths need all of them:
 
-- thread-local (``with span("train.epoch"): ...``) — nested blocks on
+- container (``with span("train.epoch"): ...``) — nested blocks on
   one thread parent automatically, like the reference's RecordEvent
-  nesting;
+  nesting; table only;
+- leaf phase (``with phase("llm.issue.mixed"): ...``) — one piece of
+  a thread's own work or its own wait: a child of the thread's open
+  container that never becomes a parent, and the one form that also
+  lands on the PROFILER's clock (a ``jax.profiler.TraceAnnotation``
+  for its duration, beside ``PjitFunction(...)`` in the ``.xplane.pb``
+  host plane), so a device idle gap can be named after the phase that
+  covered it. Containers are kept off that clock on purpose: a reducer
+  that blames a gap on the host event covering most of it would blame
+  the outer span for every gap inside it;
 - explicit (``start_span(name, parent=other)``) — the LLM engine's
   request trees span the submitter thread and the engine loop thread,
   so parents are carried on the request object, not the stack.
 
+One switch for both sinks: spans are recorded while ``active()`` —
+after ``enable()`` ("always"), or while a JAX profiler session runs
+(``jax.profiler.start_trace`` .. ``stop_trace``, whoever started it:
+``paddle.profiler.Profiler``, ``POST /profilez``, a benchmark). The
+table of a profiled run then holds exactly the profiled window. A
+tree rooted while active is kept to its end (``start_span`` under a
+real parent is real whatever the switch says by then); a ``phase``
+follows the switch alone.
+
 Finished spans land in the bounded table (``finished_spans()``); live
 ones are tracked (``live_spans()``) so a crash dump shows what was
-in flight. ``exporters.export_chrome_tracing`` merges the table with
-the profiler's RecordEvent stream onto one chrome://tracing timeline;
-when a profiler is actively recording, span durations also feed its
-``summary()`` aggregates (stats only — the trace row comes from this
-table, so nothing renders twice).
+in flight. ``exporters.export_chrome_tracing`` renders the table
+(``profiler.RecordEvent`` is a leaf phase of it) as one chrome://tracing
+timeline; while a ``Profiler`` is started, span durations also feed its
+``summary()`` aggregates.
 
 Stdlib-only by design (like metrics.py): any module may import it
-without cycles, and enabling tracing never drags jax in.
+without cycles, and enabling tracing never drags jax in — the
+profiler is reached through ``sys.modules`` and only once jax is
+loaded.
 """
 
 from __future__ import annotations
@@ -98,7 +117,7 @@ class Span:
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "t1",
                  "attrs", "events", "links", "tid", "tname", "status",
-                 "_dropped_events")
+                 "_dropped_events", "_leaf", "_ann")
 
     def __init__(self, name: str, trace_id: str, span_id: str,
                  parent_id: Optional[str],
@@ -118,6 +137,8 @@ class Span:
         self.tname = t.name
         self.status = "ok"
         self._dropped_events = 0
+        self._leaf = False
+        self._ann = None
 
     # -- identity -------------------------------------------------------
     @property
@@ -187,15 +208,35 @@ class Span:
         if prof is not None and prof._events.active:
             prof._events.record_stat(self.name, self.t1 - self.t0)
 
-    # -- context-manager protocol (thread-local nesting) ---------------
+    # -- context-manager protocol ---------------------------------------
+    # a container nests on the thread-local stack; a leaf phase stays
+    # off it and is annotated on the profiler's clock instead
     def __enter__(self) -> "Span":
-        _stack().append(self)
+        if not self._leaf:
+            _stack().append(self)
+            return self
+        ann_cls = _annotation_cls()
+        if ann_cls is not None and ann_cls.is_enabled():
+            self._ann = ann_cls(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        stack = _stack()
-        if stack and stack[-1] is self:
-            stack.pop()
+        if self._leaf:
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
+                self._ann = None
+            if not active():
+                # the switch went off under it (a wait that outlived the
+                # session, a disable()): a phase follows the switch
+                self.t1 = self.t0
+                with _lock:
+                    _live.pop(self.span_id, None)
+                return
+        else:
+            stack = _stack()
+            if stack and stack[-1] is self:
+                stack.pop()
         if exc_type is not None:
             self.status = "error"
             self.set_attr("error", f"{exc_type.__name__}: {exc}")
@@ -245,12 +286,12 @@ class Span:
 class _NoopSpan:
     """Shared do-nothing span returned while tracing is disabled —
     instrumentation can call through unconditionally; the only cost of
-    disabled tracing is the ``enabled()`` flag check."""
+    disabled tracing is the ``active()`` check."""
 
     __slots__ = ()
     name = "noop"
     trace_id = span_id = parent_id = ""
-    # real timestamps so a caller that sampled `enabled()` just before
+    # real timestamps so a caller that sampled `active()` just before
     # a concurrent disable() (and now holds the noop) can still read
     # t0/t1 — e.g. start_span(..., t0=root.t0) must not raise
     t0 = t1 = 0.0
@@ -319,6 +360,27 @@ def enabled() -> bool:
     return _enabled
 
 
+_annotation = None      # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_cls():
+    global _annotation
+    if _annotation is None:
+        prof = sys.modules.get("jax.profiler")
+        _annotation = getattr(prof, "TraceAnnotation", None)
+    return _annotation
+
+
+def active() -> bool:
+    """Whether spans are being recorded: ``enable()`` was called, or a
+    JAX profiler session is running. What every instrumentation site
+    asks; tens of nanoseconds, and no jax import."""
+    if _enabled:
+        return True
+    ann_cls = _annotation or _annotation_cls()
+    return ann_cls is not None and ann_cls.is_enabled()
+
+
 def set_capacity(n: int) -> None:
     """Resize the finished-span ring, keeping the newest entries."""
     global _table
@@ -357,11 +419,12 @@ def start_span(name: str, parent=_USE_CURRENT,
     """Create a live span (caller owns ``end()``). ``parent`` defaults
     to the calling thread's current ``span()`` block; pass ``None``
     for an explicit root, or any Span/SpanContext for cross-thread
-    trees."""
-    if not _enabled:
-        return NOOP_SPAN
+    trees. Off ``active()`` only a child of a real span is real: a
+    tree that was rooted while active is kept to its end."""
     if parent is _USE_CURRENT:
         parent = current_span()
+    if not (isinstance(parent, Span) or active()):
+        return NOOP_SPAN
     trace_id, parent_id = _resolve_parent(parent)
     span_id = _new_id()
     # a root span mints a 32-hex (W3C trace-id width) trace id so the
@@ -375,9 +438,22 @@ def start_span(name: str, parent=_USE_CURRENT,
 
 def span(name: str, attrs: Optional[Dict[str, Any]] = None,
          parent=_USE_CURRENT) -> Span:
-    """Context-manager form: ``with span("phase"): ...`` — pushes onto
+    """Container form: ``with span("train.epoch"): ...`` — pushes onto
     the thread-local stack so nested blocks parent automatically."""
     return start_span(name, parent=parent, attrs=attrs)
+
+
+def phase(name: str, attrs: Optional[Dict[str, Any]] = None) -> Span:
+    """Leaf form: ``with phase("fit.dispatch"): ...`` — one piece of
+    the calling thread's own work (or its own wait), under the thread's
+    open container, in the table AND on the profiler's clock. Keep the
+    phases of one thread flat: they should tile its loop, not nest."""
+    if not active():        # a phase follows the switch, not its parent
+        return NOOP_SPAN
+    sp = start_span(name, attrs=attrs)
+    if sp is not NOOP_SPAN:     # the switch may have gone off just now
+        sp._leaf = True
+    return sp
 
 
 def current_span() -> Optional[Span]:

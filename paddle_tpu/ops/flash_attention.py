@@ -166,6 +166,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, lse
 
@@ -282,6 +283,7 @@ def _bwd(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_dq",
     )(q, k, v, out, do, lse)
 
     # dk/dv: grid iterates q-blocks sequentially per (q-head, k-block);
@@ -314,6 +316,7 @@ def _bwd(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q, k, v, out, do, lse)
 
     if group > 1:

@@ -91,9 +91,10 @@ def kv_zeros(shape, dtype) -> KVStore:
 def kv_layer(store: KVStore, i) -> KVStore:
     """Per-layer view of a [L, ...]-stacked store (what the attention
     entry point consumes)."""
-    if isinstance(store, QuantizedKV):
-        return QuantizedKV(store.pages[i], store.scales[i])
-    return store[i]
+    with jax.named_scope("kv_layer"):
+        if isinstance(store, QuantizedKV):
+            return QuantizedKV(store.pages[i], store.scales[i])
+        return store[i]
 
 
 def kv_page_size(store: KVStore) -> int:
@@ -136,12 +137,14 @@ def kv_write(store: KVStore, layer, page_idx, offs, rows) -> KVStore:
     with ``page_idx``/``offs`` broadcast over the leading dims —
     exactly the ``.at[i, page_idx, offs].set`` contract the engine's
     layers already use, made dtype-aware in ONE place."""
-    if isinstance(store, QuantizedKV):
-        q, s = quantize_kv(rows)
-        return QuantizedKV(
-            store.pages.at[layer, page_idx, offs].set(q),
-            store.scales.at[layer, page_idx, offs].set(s))
-    return store.at[layer, page_idx, offs].set(rows.astype(store.dtype))
+    with jax.named_scope("kv_write"):
+        if isinstance(store, QuantizedKV):
+            q, s = quantize_kv(rows)
+            return QuantizedKV(
+                store.pages.at[layer, page_idx, offs].set(q),
+                store.scales.at[layer, page_idx, offs].set(s))
+        return store.at[layer, page_idx, offs].set(
+            rows.astype(store.dtype))
 
 
 def _split_kv(store: KVStore):
@@ -510,29 +513,32 @@ def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
     _, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
 
-    tables = jnp.clip(block_tables, 0)               # [B, P]
-    k = jnp.take(k_pages, tables, axis=0)            # [B, P, ps, KVH, d]
-    v = jnp.take(v_pages, tables, axis=0)
-    if k_scales is not None:
-        # int8 pool: dequantize the gathered rows (scale per page row)
-        k = k.astype(jnp.float32) * \
-            jnp.take(k_scales, tables, axis=0)[..., None, None]
-        v = v.astype(jnp.float32) * \
-            jnp.take(v_scales, tables, axis=0)[..., None, None]
     L = pages_per_seq * page_size
-    k = k.reshape(b, L, kv_heads, d)
-    v = v.reshape(b, L, kv_heads, d)
-    if n_heads != kv_heads:
-        rep = n_heads // kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    with jax.named_scope("kv_gather"):
+        tables = jnp.clip(block_tables, 0)             # [B, P]
+        k = jnp.take(k_pages, tables, axis=0)          # [B, P, ps, KVH, d]
+        v = jnp.take(v_pages, tables, axis=0)
+        if k_scales is not None:
+            # int8 pool: dequantize the gathered rows (scale per page
+            # row)
+            k = k.astype(jnp.float32) * \
+                jnp.take(k_scales, tables, axis=0)[..., None, None]
+            v = v.astype(jnp.float32) * \
+                jnp.take(v_scales, tables, axis=0)[..., None, None]
+        k = k.reshape(b, L, kv_heads, d)
+        v = v.reshape(b, L, kv_heads, d)
+        if n_heads != kv_heads:
+            rep = n_heads // kv_heads
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
 
-    logits = jnp.einsum("bqhd,blhd->bhql", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale   # [B,H,K,L]
-    mask = jnp.arange(L)[None, None, :] < limit[:, :, None]  # [B,K,L]
-    logits = jnp.where(mask[:, None], logits, -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1)
-    # fully-masked rows (limit 0, e.g. a freed slot): zeros, not NaN
-    p = jnp.where(limit[:, None, :, None] > 0, p, 0.0)
-    out = jnp.einsum("bhql,blhd->bqhd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    with jax.named_scope("attn_scores"):
+        logits = jnp.einsum("bqhd,blhd->bhql", q.astype(jnp.float32),
+                            k.astype(jnp.float32)) * scale   # [B,H,K,L]
+        mask = jnp.arange(L)[None, None, :] < limit[:, :, None]  # [B,K,L]
+        logits = jnp.where(mask[:, None], logits, -jnp.inf)
+        p = jax.nn.softmax(logits, axis=-1)
+        # fully-masked rows (limit 0, e.g. a freed slot): zeros, not NaN
+        p = jnp.where(limit[:, None, :, None] > 0, p, 0.0)
+        out = jnp.einsum("bhql,blhd->bqhd", p, v.astype(jnp.float32))
+        return out.astype(q.dtype)

@@ -82,19 +82,19 @@ class Optimizer:
                         step) -> tuple[PyTree, PyTree]:
         """Pure update — jit this. ``state`` must come from ``init_state``.
         ``step`` drives the LR schedule on-device."""
-        if self.grad_clip is not None:
-            grads = self.grad_clip(grads)
-        lr = self.lr_fn(jnp.asarray(step))
-        master = state.get("master") if isinstance(state, dict) else None
-        work_params = master if master is not None else params
-        updates, new_state = self._update(grads, state, work_params, lr)
-        new_work = _tree_map(jnp.add, work_params, updates)
-        if master is not None:
-            new_state["master"] = new_work
-            new_params = _cast_like(new_work, params)
-        else:
-            new_params = _cast_like(new_work, params)
-        return new_params, new_state
+        with jax.named_scope("optimizer"):
+            if self.grad_clip is not None:
+                grads = self.grad_clip(grads)
+            lr = self.lr_fn(jnp.asarray(step))
+            master = state.get("master") if isinstance(state, dict) \
+                else None
+            work_params = master if master is not None else params
+            updates, new_state = self._update(grads, state, work_params,
+                                              lr)
+            new_work = _tree_map(jnp.add, work_params, updates)
+            if master is not None:
+                new_state["master"] = new_work
+            return _cast_like(new_work, params), new_state
 
     def _maybe_master_state(self, params) -> dict:
         state: Dict[str, Any] = {}

@@ -11,9 +11,10 @@ Reference being replaced (SURVEY.md §5):
 
 TPU-native design: device-side tracing is jax.profiler/XProf — the
 captured trace (TensorBoard `plugins/profile` format) already contains
-XLA op timelines, memory viewer, and roofline; ``RecordEvent`` maps to
-``jax.profiler.TraceAnnotation`` so host annotations appear on the same
-timeline. What the facade adds: Paddle-shaped scheduling
+XLA op timelines, memory viewer, and roofline; ``RecordEvent`` is a
+leaf phase of the observability span table (``tracing.phase``), which
+opens a ``jax.profiler.TraceAnnotation`` so host annotations appear on
+the same timeline. What the facade adds: Paddle-shaped scheduling
 (wait/warmup/active cycles), host-side wall-clock aggregation for a
 ``summary()`` table without needing the XProf UI, and a StatRegistry for
 counters.
@@ -30,6 +31,8 @@ import time
 from typing import Callable, Dict, Iterable, Optional
 
 import jax
+
+from ..observability import tracing as _tracing
 
 
 class ProfilerTarget(enum.Enum):
@@ -84,37 +87,19 @@ def make_scheduler(*, closed: int, ready: int, record: int,
 # ---------------------------------------------------------------------------
 
 class _HostEvents:
-    """Process-wide so events from worker threads (data loading, async
-    checkpointing) land in the same summary() table.
-
-    Two views of the same stream: ``stats`` (per-name durations, feeds
-    summary()) and ``trace`` (timestamped complete events, feeds
-    observability.export_chrome_tracing — the ChromeTracingLogger
-    analog). The trace is bounded so a long profiled run can't grow
-    host memory without limit; the per-name aggregates keep counting
-    past the cap."""
-
-    TRACE_CAP = 200_000
+    """Process-wide per-name durations behind ``summary()``. Every
+    span that ends while a Profiler is started (``active``) lands here
+    through ``record_stat`` — ``RecordEvent``s among them, and spans
+    from worker threads (data loading, async checkpointing). The
+    timeline itself is the span table's
+    (``observability.export_chrome_tracing``)."""
 
     def __init__(self):
         self.stats: Dict[str, list] = collections.defaultdict(list)
-        self.trace: collections.deque = collections.deque(
-            maxlen=self.TRACE_CAP)
         self.active = False
         self.lock = threading.Lock()
 
-    def record(self, name: str, t0: float, dt: float) -> None:
-        t = threading.current_thread()
-        with self.lock:
-            self.stats[name].append(dt)
-            self.trace.append({"name": name, "ts": t0, "dur": dt,
-                               "tid": t.ident, "tname": t.name})
-
     def record_stat(self, name: str, dt: float) -> None:
-        """Aggregate-only record (no trace row): observability spans
-        feed summary() through this — their timeline rendering comes
-        from the span table, so a trace append here would render each
-        span twice in export_chrome_tracing."""
         with self.lock:
             self.stats[name].append(dt)
 
@@ -130,26 +115,22 @@ _active_owner: Optional["Profiler"] = None
 
 class RecordEvent:
     """Host-side annotation (ref: paddle.profiler.RecordEvent /
-    platform RecordEvent). Shows up in the XProf timeline via
-    TraceAnnotation AND in profiler.summary()."""
+    platform RecordEvent): ``tracing.phase`` under the reference's
+    name and begin/end protocol. While a profiler records it shows up
+    in the XProf timeline, the span table and ``summary()``; otherwise
+    it is a no-op."""
 
     def __init__(self, name: str):
         self.name = name
-        self._ann = None
-        self._t0 = 0.0
+        self._span = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        self._span = _tracing.phase(self.name).__enter__()
 
     def end(self):
-        dt = time.perf_counter() - self._t0
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        if _events.active:
-            _events.record(self.name, self._t0, dt)
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def __enter__(self):
         self.begin()
@@ -218,12 +199,11 @@ class Profiler:
     # -- lifecycle ------------------------------------------------------
     def start(self):
         # clear UNDER the lock: worker threads may be inside
-        # RecordEvent.end() → _events.record() concurrently, and a
+        # RecordEvent.end() → _events.record_stat() concurrently, and a
         # bare clear() races their defaultdict append (lost events /
         # dict-mutated-during-iteration in summary)
         with _events.lock:
             _events.stats.clear()
-            _events.trace.clear()
         self._windows = []
         _events.active = True
         global _active_owner
@@ -265,8 +245,8 @@ class Profiler:
     def summary(self, sorted_by="total") -> str:
         """Statistic report (ref: profiler_statistic.py SummaryView):
         a model-perspective table (Dataloader / TrainStep / Callbacks
-        buckets, auto-recorded by ``Model.fit`` while profiling, with
-        time ratios) followed by the full host-event table. Device-side
+        buckets from ``Model.fit``'s ``fit.*`` phases, with time
+        ratios) followed by the full host-event table. Device-side
         kernel timelines live in the XProf trace under ``log_dir``
         (view with xprof/tensorboard); the host tables cover what the
         reference's CPU-time columns did."""
@@ -293,9 +273,8 @@ class Profiler:
             return lines
 
         out = []
-        perspective = [r for r in rows
-                       if r[0] in ("Dataloader", "TrainStep",
-                                   "Callbacks", "Eval")]
+        perspective = [(_MODEL_PERSPECTIVE[r[0]],) + r[1:] for r in rows
+                       if r[0] in _MODEL_PERSPECTIVE]
         if perspective:
             wall = sum(r[2] for r in perspective)
             out += table("---- Model Perspective "
@@ -304,6 +283,13 @@ class Profiler:
             out.append("")
         out += table("---- Host Events ----", rows)
         return "\n".join(out)
+
+
+# summary()'s model-perspective rows: Model.fit's phases under the
+# reference's bucket names
+_MODEL_PERSPECTIVE = {"fit.next_batch": "Dataloader",
+                      "fit.dispatch": "TrainStep",
+                      "fit.callbacks": "Callbacks", "fit.eval": "Eval"}
 
 
 @contextlib.contextmanager
@@ -320,5 +306,5 @@ def profile(log_dir: str = "./paddle_tpu_profile"):
 # Host-annotation chrome://tracing export (ref: ChromeTracingLogger).
 # Device-side timelines remain in the XProf dump under log_dir
 # (`tensorboard --logdir <log_dir>` or xprof); this file carries the
-# RecordEvent host events the summary() table aggregates.
+# spans (RecordEvents among them) the summary() table aggregates.
 from ..observability.exporters import export_chrome_tracing  # noqa: E402,F401

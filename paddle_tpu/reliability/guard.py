@@ -301,7 +301,7 @@ class GuardPolicy:
             self.last_trip_step = gstep
             self.last_trip_kind = kind
             m["trips"].labels(kind, action).inc()
-            if _trace.enabled():
+            if _trace.active():
                 _trace.start_span("train.guard", attrs={
                     "kind": kind, "action": action, "step": gstep,
                     "loss": repr(float(losses[i])),

@@ -566,7 +566,7 @@ class Autoscaler:
             attrs={"reason": reason,
                    "occupancy": inputs.get("occupancy") or 0.0,
                    "ready": inputs.get("ready", 0)}) \
-            if _trace.enabled() else None
+            if _trace.active() else None
         name = f"{self.name_prefix}-{next(self._seq)}"
         # warming is declared BEFORE the process exists: a membership
         # attach racing this spawn lands the replica in warming, not
@@ -687,7 +687,7 @@ class Autoscaler:
             "autoscale.scale_in",
             attrs={"reason": reason, "replica": m.name,
                    "occupancy": inputs.get("occupancy") or 0.0}) \
-            if _trace.enabled() else None
+            if _trace.active() else None
         m.state = "draining"
         self.router.drain(m.name)
         t0 = self._clock()

@@ -994,7 +994,7 @@ class Router:
                 digest_size=16).digest()
         req.rr_slot = next(self._rr_seq)
         self.n_submitted += 1
-        if _trace.enabled():
+        if _trace.active():
             # router.request roots here — or under a REMOTE parent
             # when the client itself propagated a traceparent (a
             # router fronted by serve_llm extends the caller's trace)

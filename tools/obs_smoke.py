@@ -82,8 +82,10 @@ def main(outdir: str = "/tmp/pt_obs_smoke") -> int:
     xs = [ev for ev in events if ev["ph"] == "X"]
     assert all(ev["dur"] >= 0 for ev in xs)
     names = {ev["name"] for ev in xs}
+    for phase in ("fit.next_batch", "fit.dispatch", "fit.callbacks"):
+        assert phase in names, (phase, names)
     for bucket in ("Dataloader", "TrainStep", "Callbacks"):
-        assert bucket in names, (bucket, names)
+        assert bucket in prof.summary(), bucket
     # spans merged onto the same timeline with parent links + metadata
     span_evs = [ev for ev in xs if ev.get("cat") == "span"]
     span_names = {ev["name"] for ev in span_evs}
