@@ -12,8 +12,10 @@ paths through the entry points users call, at the full width of
 
   device   platform must be ``tpu`` — anything else is an error, never a CPU pass
   kernels  flash attention (fwd + bwd) and the ragged paged-attention kernel
-           (bf16 and int8 pool, MHA and GQA 16/4), compiled (interpret=False),
-           against their jnp references
+           (a layer of a stacked bf16 and int8 pool, MHA and GQA 16/4),
+           compiled (interpret=False), against their jnp references; then
+           the kernel's time a call beside the gathered path's at the
+           serving benchmark's decode and mixed shapes
   serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
            ``serve_llm``; HTTP ``POST /generate`` checked against
            ``net.generate``; once with attention_impl="xla", once "pallas"
@@ -42,9 +44,9 @@ SEQ = 2048
 PAGE = 16
 MAX_SEQS = 8
 NEW_TOKENS = 64
-# The default-impl ("xla") prefill gathers max_len of K and V in f32 for every
-# query row: rows x max_len x kv_heads x d x 4 B, twice (K and V), per layer
-# (ops/paged_attention._gathered_attention). At max_len 2048 and 16 x 128
+# The gathered ("xla") path, served here beside the kernel, gathers max_len
+# of K and V in f32 for every query row: rows x max_len x kv_heads x d x 4 B,
+# twice (K and V), per layer (ops/paged_attention._gathered_attention). At max_len 2048 and 16 x 128
 # that is 33.5 MB a row: the TPU compiler's memory analysis of the mixed-tick
 # program read 5.3 GiB of temporaries at prefill_chunk 128 (136 rows) — too
 # much beside 2.5 GiB of weights and a pool meant to fill the rest — and half
@@ -210,15 +212,18 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
 
     # ragged paged attention: rows with full, partial-page, one-token and
     # empty contexts over a shuffled page pool
+    # over a STACKED pool, as the engine holds it: the call attends one
+    # layer of three and must not see the other two
     rng = np.random.RandomState(seed)
     pages_per_seq = seq // PAGE
     rows = 24
+    layers, layer = 3, 1
     num_pages = rows * pages_per_seq // 4 + 1
     lens = rng.randint(1, seq + 1, rows)
     lens[:4] = (seq, 1, PAGE + 3, 0)
     tables = rng.randint(1, num_pages, (rows, pages_per_seq))
     for kv_heads in (heads, heads // 4):
-        pool_shape = (num_pages, PAGE, kv_heads, d)
+        pool_shape = (layers, num_pages, PAGE, kv_heads, d)
         kf = jax.random.normal(keys[3], pool_shape, jnp.float32)
         vf = jax.random.normal(keys[4], pool_shape, jnp.float32)
         qq = jax.random.normal(keys[5], (rows, heads, d), jnp.bfloat16)
@@ -234,14 +239,15 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
             tb, ln = jnp.asarray(tables, jnp.int32), jnp.asarray(
                 lens, jnp.int32)
             got = jax.jit(lambda q, k, v, ks, vs: paged_attention_kernel(
-                q, k, v, tb, ln, interpret=False, k_scales=ks,
-                v_scales=vs))(qq, kq, vq, ks, vs)
+                q, k, v, tb, ln, layer=layer, interpret=False,
+                k_scales=ks, v_scales=vs))(qq, kq, vq, ks, vs)
             ref = jax.jit(lambda q, k, v: ragged_paged_attention(
-                q, k, v, tb, ln, impl="xla"))(qq, kk, vv)
+                q, k, v, tb, ln, impl="xla", layer=layer))(qq, kk, vv)
             err, ok = _max_err(got, ref)
             emit({"phase": "kernels", "kernel": "paged_attention",
                   "pool": pool, "heads": heads, "kv_heads": kv_heads,
                   "head_dim": d, "page_size": PAGE, "rows": rows,
+                  "layers": layers, "layer": layer,
                   "max_len": seq, "interpret": False,
                   "max_abs_err": round(err, 5), "atol": KERNEL_ATOL,
                   "rtol": KERNEL_RTOL})
@@ -249,7 +255,85 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
                       f"disagrees with _gathered_attention: {err}")
             check(float(jnp.abs(got[3].astype(jnp.float32)).max()) == 0.0,
                   "an empty context must give a zero row")
+    time_paged_attention(seed, heads, d, seq)
     emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
+
+
+def time_paged_attention(seed: int, heads: int = 16, d: int = 128,
+                         seq: int = SEQ, layers: int = 4,
+                         num_pages: int = 2721, calls: int = 24) -> None:
+    """The kernel's time a call beside the gathered path's, at the shapes
+    the serving benchmark's engine gives them: a bf16 pool of 2,721 pages
+    a layer under a 2048-token table; a decode tick's 32 rows (contexts
+    of 16 to 896 tokens, lognormal around 200) and a mixed tick's 96 (the
+    same 32 after 64 chunk rows of two prompts, each row its sequence's
+    table and a limit one longer than the row before). A call's time is
+    that of ``calls`` chained calls in one program, over their number.
+    Smoke readings of one layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+
+    rng = np.random.RandomState(seed + 7)
+    pages_per_seq = seq // PAGE
+    keys = jax.random.split(jax.random.PRNGKey(seed + 7), 3)
+    pool_shape = (layers, num_pages, PAGE, heads, d)
+    k_pool = jax.random.normal(keys[0], pool_shape, jnp.bfloat16)
+    v_pool = jax.random.normal(keys[1], pool_shape, jnp.bfloat16)
+    decode_lens = np.clip(rng.lognormal(np.log(200.0), 0.7, 32), 16,
+                          896).astype(np.int32)
+    decode_lens[5] = 0                          # an empty slot
+    decode_tables = np.zeros((32, pages_per_seq), np.int32)
+    free = rng.permutation(np.arange(1, num_pages))
+    for r, n in enumerate(decode_lens):
+        need = -(-int(n) // PAGE)
+        decode_tables[r, :need], free = free[:need], free[need:]
+    # two prompts mid-prefill: 40 rows from position 130, 24 from 0
+    chunk_lens = np.concatenate([130 + 1 + np.arange(40),
+                                 1 + np.arange(24)]).astype(np.int32)
+    chunk_tables = np.zeros((64, pages_per_seq), np.int32)
+    for rows_, upto in ((slice(0, 40), 170), (slice(40, 64), 24)):
+        need = -(-upto // PAGE)
+        chunk_tables[rows_, :need], free = free[:need], free[need:]
+    shapes = {
+        "decode": (decode_tables, decode_lens),
+        "mixed": (np.concatenate([chunk_tables, decode_tables]),
+                  np.concatenate([chunk_lens, decode_lens]))}
+    for name, (tables, lens) in shapes.items():
+        rows = len(lens)
+        qq = jax.random.normal(keys[2], (rows, heads, d), jnp.bfloat16)
+        tb, ln = jnp.asarray(tables), jnp.asarray(lens)
+        ms, outs = {}, {}
+        for impl in ("pallas", "xla"):
+            one = jax.jit(lambda q, k, v, impl=impl: ragged_paged_attention(
+                q, k, v, tb, ln, impl=impl, layer=layers // 2))
+            outs[impl] = one(qq, k_pool, v_pool).block_until_ready()
+
+            # a tick's worth of calls in ONE program, each fed the one
+            # before: a dispatch from Python costs more than the kernel runs
+            def tick(q, k, v, impl=impl):
+                return jax.lax.fori_loop(
+                    0, calls, lambda i, q: ragged_paged_attention(
+                        q, k, v, tb, ln, impl=impl, layer=i % layers), q)
+
+            fn = jax.jit(tick)
+            fn(qq, k_pool, v_pool).block_until_ready()
+            t1 = time.perf_counter()
+            for _ in range(3):
+                out = fn(qq, k_pool, v_pool)
+            out.block_until_ready()
+            ms[impl] = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+        err, ok = _max_err(outs["pallas"], outs["xla"])
+        live = int(sum(-(-int(n) // PAGE) for n in lens))
+        emit({"phase": "kernels", "kernel": "paged_attention",
+              "timed": name, "rows": rows, "live_pages_read": live,
+              "table_pages": rows * pages_per_seq,
+              "kernel_ms_per_call": round(ms["pallas"], 4),
+              "gathered_ms_per_call": round(ms["xla"], 4),
+              "max_abs_err": round(err, 5), "calls": calls})
+        check(ok, f"paged attention at the {name} tick's shapes disagrees "
+                  f"with _gathered_attention: {err}")
 
 
 # ---------------------------------------------------------------------------

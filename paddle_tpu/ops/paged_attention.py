@@ -9,18 +9,24 @@ KV in fixed-size PAGES shared across requests, with a per-request block
 table mapping logical positions to pages — HBM waste bounded by one
 page per sequence.
 
-TPU-native design: pages are gathered per request with one take() (XLA
-lowers to a dynamic-gather the TPU does well at page granularity —
-contiguous [page_size, kv_heads, d] blocks), then attention runs as
-dense SDPA with a context-length mask. Static shapes throughout
-(pages_per_seq is the compiled maximum; short sequences mask). The
-fancy kernel in the paper fuses the gather into the attention loop —
-that is a later Pallas optimization; this implementation fixes the
-MEMORY model, which is the serving win, and is numerically exact.
+Two paths behind one entry point (:func:`ragged_paged_attention`).
+On a TPU the engine takes :func:`paged_attention_kernel`: a Pallas
+kernel that streams each row's LIVE pages straight out of the stacked
+``[L, num_pages, ...]`` pool, a block of pages a step, with the online
+softmax in float32 — the bytes a tick moves follow the live context.
+Anywhere else it takes the gathered path
+(:func:`_gathered_attention`): every entry of a row's block table
+gathered with one take() at page granularity (contiguous
+[page_size, kv_heads, d] blocks), then dense SDPA with a
+context-length mask. Static shapes throughout (pages_per_seq is the
+compiled maximum; short sequences mask). It is numerically exact, the
+baseline the kernel is held to, and it moves ``max_len`` of K and V
+for every row whatever the row holds.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional, Union
 
@@ -216,40 +222,76 @@ class PagedKVCache:
         self.context_lens = self.context_lens.at[seq].set(start + t)
 
 
+# what the kernel plans to hold in VMEM for the pages in flight (K and V,
+# two slots each); the compiler's own temporaries come on top, inside
+# the default scoped limit of 16 MiB
+_PAGE_BUFFER_BYTES = 4 * 1024 * 1024
+# tokens folded into the softmax state at a time
+_GROUP_TOKENS = 64
+
+
+def _pages_per_block(page_size: int, kv_heads: int, d: int, dtype,
+                     pages_per_seq: int) -> int:
+    """Pages the kernel moves a step: as many as the four page buffers
+    (K and V, double-buffered) hold in ``_PAGE_BUFFER_BYTES``, a page
+    counted at its tiled size in VMEM (kv heads padded to the dtype's
+    sublane packing, d to the lane width). Follows the shapes: 16 pages
+    of 16 tokens for a bf16 pool with 16 heads of 128."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    page_bytes = (page_size * -(-kv_heads // sublanes) * sublanes
+                  * -(-d // _LANES) * _LANES * itemsize)
+    return max(1, min(pages_per_seq,
+                      _PAGE_BUFFER_BYTES // (4 * page_bytes)))
+
+
 def paged_attention_kernel(q, k_pages, v_pages, block_tables,
-                           context_lens, scale: Optional[float] = None,
+                           context_lens, layer=None,
+                           scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            k_scales=None, v_scales=None):
-    """Fused Pallas decode attention over paged KV (the "fancy kernel"
-    the module docstring deferred; Ragged-Paged-Attention lineage).
+    """Fused Pallas attention over the paged KV pool (Ragged-Paged-
+    Attention lineage): every row of ``q`` attends the first
+    ``context_lens[row]`` cached positions of the sequence whose block
+    table is ``block_tables[row]``.
 
-    Same contract as :func:`paged_attention`. The difference is the
-    memory traffic: the XLA path GATHERS every sequence's full padded
-    context ([B, pages_per_seq*page_size, H, D]) into HBM before the
-    dense attention reads it again; here the kernel's BlockSpec index
-    map reads the SCALAR-PREFETCHED block table directly, so each grid
-    step streams exactly one real page from the pool into VMEM —
-    traffic scales with the true context length (``pl.when`` skips
-    pages past it entirely), and nothing is materialized in between.
+    The pool is read where it lives. ``k_pages`` / ``v_pages`` are the
+    engine's STACKED stores ``[L, num_pages, page_size, kv_heads, d]``
+    and ``layer`` (an int or a traced scalar) says which layer this call
+    attends: the kernel finds a page at ``(layer, table[row, j])`` in
+    HBM, so no layer is ever sliced out of the pool. A four-dimensional
+    store (one layer's pages, ``layer`` None) is taken as its ``[1, ...]``
+    view.
 
-    Grid: (batch, pages_per_seq); the page dim is sequential so the
-    online-softmax scratch (acc/m/l) carries across it. One grid step
-    holds the page for ALL kv heads: the block is
-    ``(1, page_size, kv_heads, d)``, whose trailing two dims are the
-    pool's own, which is what the TPU lowering requires of a block
-    that is not (8, 128)-divisible. With the page laid out
-    [page_size, kv_heads, d] (heads on sublanes, d on lanes) a
-    single query row per head is a broadcast-multiply and a lane
-    reduction, so the step runs on the VPU with no relayout: decode
-    attention is matrix-vector work and has nothing for the MXU. GQA
-    is native: q arrives as [group, kv_heads, d] and each group row
-    reuses the page in VMEM.
+    The bytes moved follow the live context. The grid is the rows; a
+    row walks only its own ``ceil(limit / page_size)`` live pages, a
+    BLOCK of them a step (``_pages_per_block``: from the shapes and a
+    VMEM budget), each page one async copy from HBM into one of two
+    VMEM slots. While a block is computed the next one is in flight:
+    the row's next block, or after its last the first block of the next
+    row that has anything to attend, so a row's first pages are already
+    on their way when its grid step begins. Rows with limit 0 cost a
+    grid step and a zero row; pages past a row's limit are never
+    fetched.
 
-    int8 KV (``k_scales``/``v_scales`` [num_pages, page_size]):
-    dequantization happens IN-KERNEL — each grid step brings the
-    page's f32 scale row into SMEM alongside its int8 block and
-    multiplies in VMEM, so HBM traffic stays at the quantized byte
-    count (the whole point of the int8 pool).
+    A page sits in VMEM as it sits in the pool, ``[page_size, kv_heads,
+    d]`` (heads on sublanes, d on lanes): a query row per head is a
+    broadcast-multiply and a lane reduction on the VPU, with no
+    relayout. Decode attention is matrix-vector work. Scores, the
+    online softmax (acc / m / l scratch carried across a row's pages)
+    and the weighted sum are float32; pages are read in the dtype they
+    are stored in. A block's pages are folded into that state
+    ``_GROUP_TOKENS`` tokens at a time (whole groups of pages, then the
+    rest a page at a time): enough independent work a step to keep the
+    VPU busy, few enough that the float32 copies stay small. GQA is
+    native: q arrives as [group, kv_heads, d] and each group row reuses
+    the pages in VMEM.
+
+    int8 KV (``k_scales``/``v_scales`` ``[L, num_pages, page_size]``, or
+    without the layer axis beside a four-dimensional store): the pages
+    cross HBM as int8 and are dequantized in VMEM. The scale rows of a
+    row's table are gathered beside the kernel (4 bytes a token where a
+    page row has ``kv_heads * d``) and ride into SMEM with the row.
 
     ``interpret`` defaults to the module switch
     ``flash_attention.INTERPRET`` (False: the kernel compiles for the
@@ -257,95 +299,200 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     """
     if interpret is None:
         interpret = _default_interpret()
-    b, n_heads, d = q.shape
-    n_pages, page_size, kv_heads, _ = k_pages.shape
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+        layer = 0
+    page_size, kv_heads, d = k_pages.shape[2:]
+    block = _pages_per_block(page_size, kv_heads, d, k_pages.dtype,
+                             block_tables.shape[1])
+    return _paged_attention_call(
+        q, k_pages, v_pages, block_tables, context_lens,
+        jnp.asarray(layer, jnp.int32), k_scales, v_scales,
+        scale=scale if scale is not None else 1.0 / math.sqrt(d),
+        interpret=bool(interpret), block=block,
+        group_pages=max(1, min(block, _GROUP_TOKENS // page_size)))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
+                                             "group_pages"))
+def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
+                          layer, k_scales, v_scales, *, scale, interpret,
+                          block, group_pages):
+    """:func:`paged_attention_kernel` on the stacked pool with a traced
+    ``layer``. Jitted so that an engine program, which calls it once a
+    layer with the same shapes, traces and lowers the kernel once."""
+    quantized = k_scales is not None
+    rows, n_heads, d = q.shape
+    _, _, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     group = n_heads // kv_heads
-    sm_scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    quantized = k_scales is not None
 
-    # [b, group, kv_heads, d]: a group row is one (kv_heads, d) tile
-    qg = q.reshape(b, kv_heads, group, d).transpose(0, 2, 1, 3)
+    # [rows, group, kv_heads, d]: a group row is one (kv_heads, d) tile
+    qg = q.reshape(rows, kv_heads, group, d).transpose(0, 2, 1, 3)
     tables = jnp.clip(block_tables, 0).astype(jnp.int32)
-    lens = context_lens.astype(jnp.int32)
+    lens = jnp.clip(context_lens.astype(jnp.int32), 0,
+                    pages_per_seq * page_size)
+    # next_live[r]: the first row after r with anything to attend
+    # (``rows`` when there is none); the prefetch across rows follows it
+    row_ids = jnp.arange(rows, dtype=jnp.int32)
+    live_from = jax.lax.cummin(
+        jnp.where(lens > 0, row_ids, rows), reverse=True)
+    next_live = jnp.concatenate(
+        [live_from[1:], jnp.full((1,), rows, jnp.int32)])
+    meta = jnp.stack([layer, live_from[0]])
 
-    def kernel(ctx_ref, tbl_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(len_ref, tbl_ref, next_ref, meta_ref, q_ref, k_hbm,
+               v_hbm, *rest):
         if quantized:
-            ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        else:
-            o_ref, acc_ref, m_ref, l_ref = rest
-        bi = pl.program_id(0)
-        j = pl.program_id(1)
+            ks_ref, vs_ref = rest[:2]
+            rest = rest[2:]
+        (o_ref, k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
+         l_ref) = rest
+        r = pl.program_id(0)
+        ctx = len_ref[r]
+        n_pages = pl.cdiv(ctx, page_size)
+        n_blocks = pl.cdiv(n_pages, block)
 
-        @pl.when(j == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[...] = jnp.zeros_like(l_ref)
+        def block_copies(row, blk, slot, do):
+            """``do`` (start or wait) the K and V copy of each live page
+            of block ``blk`` of ``row`` into ``slot``."""
+            first_page = blk * block
+            here = jnp.minimum(
+                block, pl.cdiv(len_ref[row], page_size) - first_page)
 
-        ctx = ctx_ref[bi]
+            def page(p, carry):
+                src = (meta_ref[0], tbl_ref[row, first_page + p])
+                do(pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, p],
+                                         sems.at[0, slot]))
+                do(pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, p],
+                                         sems.at[1, slot]))
+                return carry
 
-        @pl.when(j * page_size < ctx)
-        def _compute():
-            k = k_ref[0].astype(jnp.float32)     # [page_size, kvh, d]
-            v = v_ref[0].astype(jnp.float32)
+            jax.lax.fori_loop(0, here, page, 0)
+
+        def start(row, blk, slot):
+            block_copies(row, blk, slot, lambda c: c.start())
+
+        def wait(row, blk, slot):
+            block_copies(row, blk, slot, lambda c: c.wait())
+
+        def attend_pages(slot, p, n, first_token):
+            """Fold ``n`` (static) pages of ``slot`` from page ``p`` on
+            into the row's softmax state."""
+            tokens = n * page_size
+            k = k_buf[slot, pl.ds(p, n)].astype(jnp.float32).reshape(
+                tokens, kv_heads, d)
+            v = v_buf[slot, pl.ds(p, n)].astype(jnp.float32).reshape(
+                tokens, kv_heads, d)
             if quantized:
                 # dequantize in VMEM: one SMEM scalar per page row
-                k = jnp.stack([k[p] * ks_ref[0, 0, p]
-                               for p in range(page_size)])
-                v = jnp.stack([v[p] * vs_ref[0, 0, p]
-                               for p in range(page_size)])
-            row = jax.lax.broadcasted_iota(
-                jnp.int32, (page_size, kv_heads, 1), 0)
-            valid = row < ctx - j * page_size
+                k = jnp.stack([k[t] * ks_ref[0, 0, first_token + t]
+                               for t in range(tokens)])
+                v = jnp.stack([v[t] * vs_ref[0, 0, first_token + t]
+                               for t in range(tokens)])
+            token = jax.lax.broadcasted_iota(
+                jnp.int32, (tokens, kv_heads, 1), 0)
+            valid = token < ctx - first_token
             for g in range(group):
                 qb = q_ref[0, g].astype(jnp.float32)  # [kvh, d]
                 s = jnp.sum(qb[None] * k, axis=-1,
-                            keepdims=True) * sm_scale
-                s = jnp.where(valid, s, _MASK_VALUE)  # [ps, kvh, 1]
+                            keepdims=True) * scale
+                s = jnp.where(valid, s, _MASK_VALUE)  # [tokens, kvh, 1]
                 m_prev = m_ref[g, :, :1]              # [kvh, 1]
                 l_prev = l_ref[g, :, :1]
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
                 alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new[None])
-                l_new = alpha * l_prev + jnp.sum(p, axis=0)
-                acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v,
+                p_ = jnp.exp(s - m_new[None])
+                l_new = alpha * l_prev + jnp.sum(p_, axis=0)
+                acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p_ * v,
                                                           axis=0)
                 m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
                 l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-        @pl.when(j == pages_per_seq - 1)
-        def _finalize():
-            l = l_ref[:, :, :1]
-            l_safe = jnp.where(l == 0.0, 1.0, l)  # empty slot → zeros
-            o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        @pl.when(ctx == 0)
+        def _empty():                     # empty slot → a zero row
+            o_ref[...] = jnp.zeros_like(o_ref)
 
-    # the paged gather: this index map IS the block table read
-    page_spec = pl.BlockSpec((1, page_size, kv_heads, d),
-                             lambda bi, j, ctx, tbl: (tbl[bi, j], 0,
-                                                      0, 0))
+        @pl.when(ctx > 0)
+        def _attend():
+            @pl.when(r == meta_ref[1])
+            def _first_live_row():        # nobody prefetched for it
+                slot_ref[0] = 0
+                start(r, 0, 0)
+
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+            def attend_block(blk, slot):
+                @pl.when(blk + 1 < n_blocks)
+                def _next_block():
+                    start(r, blk + 1, 1 - slot)
+
+                @pl.when(blk + 1 == n_blocks)
+                def _next_row():
+                    nxt = next_ref[r]
+
+                    @pl.when(nxt < rows)
+                    def _():
+                        start(nxt, 0, 1 - slot)
+
+                wait(r, blk, slot)
+                first_page = blk * block
+                here = jnp.minimum(block, n_pages - first_page)
+
+                def fold(n):
+                    def body(i, p):
+                        attend_pages(slot, p, n,
+                                     (first_page + p) * page_size)
+                        return p + n
+                    return body
+
+                # whole groups of pages first, the rest a page at a time
+                p = jax.lax.fori_loop(0, here // group_pages,
+                                      fold(group_pages), 0)
+                if group_pages > 1:
+                    jax.lax.fori_loop(0, here % group_pages, fold(1), p)
+                return 1 - slot
+
+            slot_ref[0] = jax.lax.fori_loop(0, n_blocks, attend_block,
+                                            slot_ref[0])
+            o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(
+                o_ref.dtype)
+
     q_spec = pl.BlockSpec((1, group, kv_heads, d),
-                          lambda bi, j, ctx, tbl: (bi, 0, 0, 0))
-    in_specs = [q_spec, page_spec, page_spec]
-    operands = [lens, tables, qg, k_pages, v_pages]
+                          lambda r, *_: (r, 0, 0, 0))
+    hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, hbm_spec, hbm_spec]
+    operands = [lens, tables, next_live, meta, qg, k_pages, v_pages]
     if quantized:
-        # the page's scale row rides beside its int8 block, in SMEM
-        # (scalar reads); [num_pages, 1, page_size] so that the block's
-        # trailing dims are the array's own
+        # the scale rows of each row's table, [rows, 1, max tokens]: a
+        # gather of 4 bytes a token done by XLA beside the kernel, one
+        # row of it in SMEM a grid step (scalar reads)
         scale_spec = pl.BlockSpec(
-            (1, 1, page_size),
-            lambda bi, j, ctx, tbl: (tbl[bi, j], 0, 0),
+            (1, 1, pages_per_seq * page_size), lambda r, *_: (r, 0, 0),
             memory_space=pltpu.SMEM)
+
+        def row_scales(scales):
+            return jnp.take(scales[layer].astype(jnp.float32), tables,
+                            axis=0).reshape(
+                rows, 1, pages_per_seq * page_size)
+
         in_specs += [scale_spec, scale_spec]
-        operands += [
-            k_scales.astype(jnp.float32).reshape(n_pages, 1, page_size),
-            v_scales.astype(jnp.float32).reshape(n_pages, 1, page_size)]
+        operands += [row_scales(k_scales), row_scales(v_scales)]
+    page_buffer = pltpu.VMEM((2, block, page_size, kv_heads, d),
+                             k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, pages_per_seq),
+        num_scalar_prefetch=4,
+        grid=(rows,),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
+            page_buffer, page_buffer,
+            pltpu.SemaphoreType.DMA((2, 2)),      # (K | V, slot)
+            pltpu.SMEM((1,), jnp.int32),          # slot of the block due
             pltpu.VMEM((group, kv_heads, d), jnp.float32),
             pltpu.VMEM((group, kv_heads, _LANES), jnp.float32),
             pltpu.VMEM((group, kv_heads, _LANES), jnp.float32),
@@ -353,20 +500,22 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, group, kv_heads, d),
+        out_shape=jax.ShapeDtypeStruct((rows, group, kv_heads, d),
                                        q.dtype),
+        # sequential: the page buffers, their semaphores and the slot
+        # carry a prefetched block from one row's step into the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
     )(*operands)
-    return out.transpose(0, 2, 1, 3).reshape(b, n_heads, d)
+    return out.transpose(0, 2, 1, 3).reshape(rows, n_heads, d)
 
 
 def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
                            token_tables, token_lens,
                            scale: Optional[float] = None,
-                           impl: str = "xla"):
+                           impl: str = "xla", layer=None):
     """THE ragged paged-attention entry point: ONE op serving every
     attention shape the engine dispatches — single-token decodes,
     chunked-prefill suffixes, speculative-verify windows, and a MIXED
@@ -398,17 +547,29 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     body — the engine's fused slab carries the (possibly quantized)
     pool in its :class:`DecodeCarry` and calls this per tick.
 
-    ``impl``: ``"xla"`` (gather + dense masked softmax, f32
-    accumulate), ``"pallas"`` (fused kernel streaming one real page
-    per grid step, int8 dequantized in VMEM), or ``"reference"``
-    (:func:`ragged_paged_attention_reference` — full-f32 exactness
-    baseline, kept callable for the int8 tolerance tests)."""
+    ``layer``: with it, ``kv_k`` / ``kv_v`` are the engine's STACKED
+    ``[L, num_pages, ...]`` stores and the call attends that layer of
+    them (an int or a traced scalar); without it they are one layer's
+    pages. The kernel reads the layer's pages where they lie in the
+    stacked pool; the gathered paths take the layer's view first
+    (:func:`kv_layer`).
+
+    ``impl``: ``"xla"`` (gather of every table entry + dense masked
+    softmax, f32 accumulate: the path off the TPU), ``"pallas"``
+    (:func:`paged_attention_kernel`: a row's live pages streamed out of
+    the pool a block a step, int8 dequantized in VMEM), or
+    ``"reference"`` (:func:`ragged_paged_attention_reference` —
+    full-f32 exactness baseline, kept callable for the int8 tolerance
+    tests)."""
+    if layer is not None and impl != "pallas":
+        kv_k, kv_v = kv_layer(kv_k, layer), kv_layer(kv_v, layer)
     kp, ks = _split_kv(kv_k)
     vp, vs = _split_kv(kv_v)
     if impl == "pallas":
         return paged_attention_kernel(q, kp, vp, token_tables,
-                                      token_lens, scale=scale,
-                                      k_scales=ks, v_scales=vs)
+                                      token_lens, layer=layer,
+                                      scale=scale, k_scales=ks,
+                                      v_scales=vs)
     if impl == "reference":
         return ragged_paged_attention_reference(
             q, kv_k, kv_v, token_tables, token_lens,

@@ -2,7 +2,8 @@
 described (not attached) v5e at the GPT-3-1.3B head shape: 16 heads x 128,
 page 16, context 2048. Interpret-mode tests cannot see what the Mosaic
 lowering refuses (block shapes, tiling, VMEM); these can, at about two
-seconds each and no chip time.
+seconds each and no chip time. The last one compiles a whole decode tick
+and reads its temporaries: the kernel must leave the pool where it is.
 
 Only one process may load libtpu, and it keeps it until it exits: the
 topology is described inside a fixture of THIS file (never at import, in a
@@ -97,3 +98,114 @@ def test_paged_attention_compiles_for_v5e(one_chip, pool, kv_heads):
             lambda q, k, v, t, n: paged_attention_kernel(
                 q, k, v, t, n, interpret=False),
             q, pages, pages, tables, lens)
+
+
+LAYERS, POOL_PAGES = 24, 2721           # the serving benchmark's pool
+
+
+@pytest.mark.parametrize("kv_heads", [HEADS, HEADS // 4],
+                         ids=["mha", "gqa16-4"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_attention_over_the_stacked_pool_compiles_for_v5e(
+        one_chip, pool, kv_heads):
+    """The engine's signature: the whole ``[L, pages, ...]`` store and a
+    traced layer index, a mixed tick's 96 rows, nothing sliced or copied
+    beside the kernel."""
+    rows = 96
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    dtype = {"bf16": jnp.bfloat16, "int8": jnp.int8}[pool]
+    q = sds((rows, HEADS, HEAD_DIM), jnp.bfloat16)
+    pages = sds((LAYERS, POOL_PAGES, PAGE, kv_heads, HEAD_DIM), dtype)
+    tables = sds((rows, MAX_LEN // PAGE), jnp.int32)
+    lens = sds((rows,), jnp.int32)
+    layer = sds((), jnp.int32)
+    if pool == "int8":
+        scales = sds((LAYERS, POOL_PAGES, PAGE), jnp.float32)
+        compiled = _compiled(
+            lambda q, k, v, t, n, i, ks, vs: paged_attention_kernel(
+                q, k, v, t, n, layer=i, interpret=False, k_scales=ks,
+                v_scales=vs),
+            q, pages, pages, tables, lens, layer, scales, scales)
+        # the scale rows gathered beside the kernel: 4 bytes a table token
+        budget = 4 * rows * MAX_LEN * 4
+    else:
+        compiled = _compiled(
+            lambda q, k, v, t, n, i: paged_attention_kernel(
+                q, k, v, t, n, layer=i, interpret=False),
+            q, pages, pages, tables, lens, layer)
+        budget = rows * HEADS * HEAD_DIM * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= budget
+
+
+def _lowered_decode_tick(one_chip, monkeypatch, layers=2, rows=32):
+    """A ``decode_fn``-shaped program lowered for the described chip:
+    ``_PagedDecode`` at the 1.3B width, the benchmark's 32 rows over a
+    2,721-page bf16 pool, the kernel path."""
+    import importlib
+    from paddle_tpu.inference.llm import _PagedDecode
+    from paddle_tpu.models.gpt import GPTForCausalLM, gpt_config
+    from paddle_tpu.nn.layer import functional_call, split_state
+
+    # the suite's interpret switch off: the program compiles its kernels
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    cfg = gpt_config("gpt3-1.3b", num_layers=layers, vocab_size=1024,
+                     hidden_dropout=0.0, attention_dropout=0.0)
+    assert (cfg.num_heads, cfg.head_dim) == (HEADS, HEAD_DIM)
+    decode = _PagedDecode(GPTForCausalLM(cfg).eval(), "pallas")
+    params, buffers = split_state(decode)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: sds(a.shape, jnp.bfloat16 if jnp.issubdtype(
+                a.dtype, jnp.floating) else a.dtype), tree)
+
+    def decode_fn(params, buffers, tokens, positions, tables, lens, kp, vp,
+                  temps, nonces, key):
+        return functional_call(decode, params, buffers, tokens, positions,
+                               tables, lens, kp, vp, temps, nonces, key,
+                               training=False)[0]
+
+    pool = sds((layers, POOL_PAGES, PAGE, HEADS, HEAD_DIM), jnp.bfloat16)
+    ints = sds((rows,), jnp.int32)
+    return jax.jit(decode_fn, donate_argnums=(6, 7)).lower(
+        described(params), described(buffers), ints, ints,
+        sds((rows, MAX_LEN // PAGE), jnp.int32), ints, pool, pool,
+        sds((rows,), jnp.float32), ints, sds((2,), jnp.uint32))
+
+
+def test_decode_tick_keeps_no_copy_of_a_layers_pages(one_chip, monkeypatch):
+    """The decode tick's temporaries stay far under ONE layer's K slice,
+    which is what ``kv_layer`` used to copy out of the pool for every
+    layer."""
+    layers = 2
+    compiled = _lowered_decode_tick(one_chip, monkeypatch, layers).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= layers
+    layer_slice = POOL_PAGES * PAGE * HEADS * HEAD_DIM * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_slice // 8, (temp, layer_slice)
+
+
+def test_engine_compiler_options_halve_a_ticks_async_prefetches(
+        one_chip, monkeypatch):
+    """What ``LLMEngine`` compiles its programs with on a TPU: the
+    described v5e's compiler takes the options, and the decode tick then
+    starts under half the async copies and slices (each is three events
+    of a profiler trace: its start, its done and the async line's)."""
+    from paddle_tpu.inference.llm import _TPU_COMPILER_OPTIONS
+    lowered = _lowered_decode_tick(one_chip, monkeypatch, layers=4)
+
+    def starts(compiled):
+        text = compiled.as_text()
+        return text.count(" copy-start(") + text.count(" slice-start(")
+
+    plain = starts(lowered.compile())
+    held = starts(lowered.compile(compiler_options=_TPU_COMPILER_OPTIONS))
+    assert 0 < held <= plain // 2, (held, plain)

@@ -173,7 +173,7 @@ def test_quantization_is_deterministic():
 def run_engine(net, prompts, gen, *, mixed, n=1, kv=None,
                temperature=0.0, cache=True, page_size=4,
                num_pages=128, chunk=8, seed=3, eos=None,
-               max_seqs=4, warm_first=0):
+               max_seqs=4, warm_first=0, impl=None):
     """One engine pass. ``warm_first``: run that many head prompts to
     completion BEFORE the burst (their pages are registered, so the
     burst's shared prefixes genuinely hit the cache)."""
@@ -182,7 +182,7 @@ def run_engine(net, prompts, gen, *, mixed, n=1, kv=None,
                     prefix_cache=cache, prefill_chunk=chunk,
                     eos_token_id=eos, seed=seed,
                     decode_ticks_per_dispatch=n, mixed_tick=mixed,
-                    kv_dtype=kv)
+                    kv_dtype=kv, attention_impl=impl)
     with eng:
         outs = []
         if warm_first:
@@ -226,6 +226,115 @@ def test_mixed_tick_token_identity_vs_legacy(cache, temperature):
     if cache:
         assert eng.n_cached_tokens > 0, \
             "shared prefix never hit the cache through the mixed tick"
+
+
+@pytest.mark.parametrize("kv,temperature,n", [
+    (None, 0.0, 1), (None, 0.8, 1), ("int8", 0.0, 4)],
+    ids=["f32-pool-greedy", "f32-pool-seeded", "int8-pool-greedy-slab4"])
+def test_mixed_tick_kernel_streams_equal_gathered_path(kv, temperature, n):
+    """Mixed ticks through the paged-attention KERNEL (chunk rows of
+    one prompt sharing a table with rising limits, beside decode rows,
+    out of the stacked pool) give the token streams of the gathered
+    path: greedy and seeded, and four ticks a dispatch (the kernel
+    inside the slab's ``lax.scan``) over an int8 pool."""
+    net = tiny_gpt()
+    rng = np.random.RandomState(1)
+    prefix = rng.randint(0, 97, 8).tolist()
+    prompts = [prefix + rng.randint(0, 97, 5).tolist(),
+               prefix + rng.randint(0, 97, 3).tolist(),
+               rng.randint(0, 97, 21).tolist(),
+               rng.randint(0, 97, 4).tolist()]
+    ref, _, eng = run_engine(net, prompts, 8, mixed=True, n=n, impl="xla",
+                             kv=kv, temperature=temperature,
+                             warm_first=1)
+    assert eng.attention_impl == "xla"
+    got, _, eng = run_engine(net, prompts, 8, mixed=True, n=n,
+                             impl="pallas", kv=kv,
+                             temperature=temperature, warm_first=1)
+    assert eng.attention_impl == "pallas"
+    assert eng.n_mixed_slabs > 0, "mixed path never engaged"
+    assert got == ref, "kernel streams diverged from the gathered path's"
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("n", [1, 4], ids=["tick", "slab4"])
+def test_issue_phases_carry_the_pages_read_and_live(impl, n):
+    """``kv_pages_read`` / ``kv_pages_live`` on every ``llm.issue.*``
+    phase, from the limits the host packs: the kernel reads each row's
+    live pages (a decode dispatch reads every live page once; a prompt's
+    chunk rows read their sequence's pages again), the gathered path
+    every table entry of every row the program carries."""
+    from paddle_tpu.observability import tracing
+    net = tiny_gpt()
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, 97, m).tolist() for m in (21, 5, 13)]
+    tracing.clear()
+    tracing.enable()
+    try:
+        _, _, eng = run_engine(net, prompts, 9, mixed=True, n=n, impl=impl,
+                               cache=False)
+        issues = [s for s in tracing.finished_spans()
+                  if s["name"].startswith("llm.issue.")]
+    finally:
+        tracing.disable()
+        tracing.clear()
+    kinds = {s["name"] for s in issues}
+    assert "llm.issue.mixed" in kinds
+    assert kinds & {"llm.issue.decode", "llm.issue.slab"}
+    ps, table = eng.page_size, eng.pages_per_seq
+    for s in issues:
+        a = s["attrs"]
+        assert a["kv_pages_live"] > 0, s
+        if impl == "xla":
+            rows = eng.max_seqs + (eng.prefill_chunk
+                                   if s["name"] == "llm.issue.mixed" else 0)
+            assert a["kv_pages_read"] == rows * a["ticks"] * table, s
+        elif s["name"] == "llm.issue.decode":
+            assert a["kv_pages_read"] == a["kv_pages_live"], s
+        else:
+            assert a["kv_pages_read"] >= a["kv_pages_live"], s
+    if impl == "pallas":
+        # 21 prompt rows of one sequence, 8 a tick: the third chunk's rows
+        # attend 17..21 positions, ceil(limit / 4) pages each
+        mixed = [s["attrs"] for s in issues if s["name"] == "llm.issue.mixed"]
+        assert max(a["kv_pages_read"] / a["kv_pages_live"]
+                   for a in mixed) > 1.5
+        assert sum(-(-lim // ps) for lim in range(1, 22)) <= sum(
+            a["kv_pages_read"] for a in mixed)
+
+
+def test_attention_impl_follows_the_pools_platform(monkeypatch):
+    """Left unset, ``attention_impl`` is what the platform of the
+    pool's device calls for: the gathered path on the CPU, the kernel
+    on a TPU (a pool whose devices say so); an explicit value is
+    honoured wherever the pool lives. The programs' compiler options
+    follow the same platform: none off a TPU."""
+    from paddle_tpu.inference import llm as llm_mod
+    net = tiny_gpt()
+    small = dict(max_seqs=2, page_size=4, num_pages=16,
+                 prefill_buckets=(32,))
+    with LLMEngine(net, **small) as eng:
+        assert eng.attention_impl == "xla"
+        assert eng._jit_options == {}
+        assert {d.platform for d in eng.k_pages.devices()} == {"cpu"}
+    for impl in ("xla", "pallas"):
+        with LLMEngine(net, attention_impl=impl, **small) as eng:
+            assert eng.attention_impl == impl
+    with pytest.raises(ValueError, match="attention_impl"):
+        LLMEngine(net, attention_impl="cuda", **small)
+
+    class OnTPU:
+        platform = "tpu"
+
+    class Pool:
+        def devices(self):
+            return {OnTPU()}
+
+    monkeypatch.setattr(llm_mod, "_split_kv", lambda store: (Pool(), None))
+    with LLMEngine(net, **small) as eng:
+        assert eng.attention_impl == "pallas"
+        assert eng._jit_options == {
+            "compiler_options": llm_mod._TPU_COMPILER_OPTIONS}
 
 
 def test_mixed_slab_admits_prefill_without_host_dispatches():

@@ -1,0 +1,195 @@
+"""The paged-attention kernel over the STACKED pool, in interpret mode,
+against ``ragged_paged_attention_reference``: the layer index, ragged
+limits in one call, chunk rows sharing a table beside decode rows, GQA,
+int8 scales, and inside a ``lax.scan``. ``tests/test_chip_compile.py``
+compiles the same signature for a described v5e."""
+
+import importlib
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+# the module, not the op of the same name that ``paddle_tpu.ops`` exports
+pa = importlib.import_module("paddle_tpu.ops.paged_attention")
+QuantizedKV, quantize_kv = pa.QuantizedKV, pa.quantize_kv
+paged_attention_kernel = pa.paged_attention_kernel
+ragged_paged_attention = pa.ragged_paged_attention
+ragged_paged_attention_reference = pa.ragged_paged_attention_reference
+
+LAYERS, PAGES, PS, D = 3, 40, 4, 16
+PAGES_PER_SEQ = 8                       # a table of 32 tokens
+MAX_LEN = PAGES_PER_SEQ * PS
+
+
+@pytest.fixture(params=[2, None], ids=["blocks-of-2-pages", "one-block"])
+def block(request, monkeypatch):
+    """Pages the kernel moves a step: two (a table is then four blocks, so
+    block boundaries and the prefetch across rows are exercised), or what
+    the shapes give (the whole table of these tiny pages)."""
+    if request.param is not None:
+        monkeypatch.setattr(pa, "_pages_per_block",
+                            lambda *a: request.param)
+    return request.param or PAGES_PER_SEQ
+
+
+def pool(seed, kv_heads, dtype=jnp.float32, layers=LAYERS):
+    k, v = jax.random.normal(jax.random.PRNGKey(seed),
+                             (2, layers, PAGES, PS, kv_heads, D))
+    return k.astype(dtype), v.astype(dtype)
+
+
+def tables_for(rows, seed=0):
+    """Distinct pages for every row, none of them page 0."""
+    perm = np.random.RandomState(seed).permutation(np.arange(1, PAGES))
+    return jnp.asarray(np.resize(perm, (rows, PAGES_PER_SEQ)), jnp.int32)
+
+
+def queries(rows, heads, seed=1):
+    return jax.random.normal(jax.random.PRNGKey(seed), (rows, heads, D))
+
+
+def reference(q, k, v, tables, lens, layer):
+    return np.asarray(ragged_paged_attention_reference(
+        q, pa.kv_layer(k, layer), pa.kv_layer(v, layer), tables, lens))
+
+
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1],
+                         ids=["first", "middle", "last"])
+def test_kernel_attends_the_named_layer_of_the_stacked_pool(block, layer):
+    k, v = pool(0, kv_heads=2)
+    q = queries(5, 2)
+    tables = tables_for(5)
+    lens = jnp.asarray([9, MAX_LEN, 1, 0, 17], jnp.int32)
+    got = np.asarray(paged_attention_kernel(q, k, v, tables, lens,
+                                            layer=layer))
+    np.testing.assert_allclose(got, reference(q, k, v, tables, lens, layer),
+                               atol=2e-5, rtol=2e-5)
+    # a traced layer index reads the same pages
+    traced = jax.jit(lambda i: paged_attention_kernel(
+        q, k, v, tables, lens, layer=i))(jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(traced), got)
+    if layer:   # and they are not another layer's
+        other = reference(q, k, v, tables, lens, layer - 1)
+        assert np.abs(got - other).max() > 1e-2
+
+
+def test_ragged_limits_in_one_call(block):
+    """Limit 0, 1, exactly a page, one over a page boundary, a block
+    boundary and one over it, ``max_len``; rows with nothing to attend
+    first, in the middle and last (the prefetch across rows skips them)."""
+    span = block * PS
+    lens = [0, 1, PS, PS + 1, 0, 0, min(span, MAX_LEN),
+            min(span + 1, MAX_LEN), MAX_LEN, 3, 0]
+    k, v = pool(2, kv_heads=2)
+    q = queries(len(lens), 2)
+    tables = tables_for(len(lens), seed=2)
+    lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(paged_attention_kernel(q, k, v, tables, lens, layer=1))
+    np.testing.assert_allclose(got, reference(q, k, v, tables, lens, 1),
+                               atol=2e-5, rtol=2e-5)
+    assert not got[np.asarray(lens) == 0].any(), "limit 0 is a zero row"
+    # nothing live at all: every row zero, no page touched
+    none = np.asarray(paged_attention_kernel(
+        q, k, v, tables, jnp.zeros_like(lens), layer=1))
+    assert not none.any()
+
+
+def test_chunk_rows_share_a_table_beside_decode_rows(block):
+    """A mixed tick's batch: six rows of one prompt, each its sequence's
+    table and a limit one longer than the row before (causal inside the
+    chunk), three rows of another, then decode rows of their own."""
+    k, v = pool(3, kv_heads=2)
+    own = tables_for(6, seed=3)
+    tables = jnp.concatenate([jnp.repeat(own[:1], 6, axis=0),
+                              jnp.repeat(own[1:2], 3, axis=0), own[2:]])
+    lens = jnp.asarray(list(range(7, 13)) + [1, 2, 3] + [20, 0, 5, MAX_LEN],
+                       jnp.int32)
+    q = queries(len(lens), 2, seed=4)
+    got = np.asarray(ragged_paged_attention(q, k, v, tables, lens,
+                                            impl="pallas", layer=2))
+    np.testing.assert_allclose(got, reference(q, k, v, tables, lens, 2),
+                               atol=2e-5, rtol=2e-5)
+    # the entry point's gathered path takes the same layer of the same pool
+    np.testing.assert_allclose(
+        np.asarray(ragged_paged_attention(q, k, v, tables, lens, layer=2)),
+        got, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads,kv_heads,dtype", [
+    (4, 2, jnp.float32), (8, 2, jnp.bfloat16), (4, 4, jnp.bfloat16)],
+    ids=["gqa4-2-f32", "gqa8-2-bf16", "mha4-bf16"])
+def test_gqa_and_bf16_pages(block, heads, kv_heads, dtype):
+    k, v = pool(5, kv_heads, dtype)
+    q = queries(4, heads, seed=6)
+    tables = tables_for(4, seed=5)
+    lens = jnp.asarray([11, 0, MAX_LEN, 6], jnp.int32)
+    got = np.asarray(paged_attention_kernel(q, k, v, tables, lens, layer=1))
+    tol = 2e-5 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(got, reference(q, k, v, tables, lens, 1),
+                               atol=tol, rtol=tol)
+
+
+def test_int8_pages_with_scales_beside_them(block):
+    """The stacked int8 pool: pages cross as int8, the scale rows of the
+    call's layer dequantize them in the kernel."""
+    kf, vf = pool(7, kv_heads=2)
+    kq, ks = quantize_kv(kf)
+    vq, vs = quantize_kv(vf)
+    q = queries(5, 4, seed=8)
+    tables = tables_for(5, seed=7)
+    lens = jnp.asarray([13, 1, 0, MAX_LEN, PS], jnp.int32)
+    got = np.asarray(ragged_paged_attention(
+        q, QuantizedKV(kq, ks), QuantizedKV(vq, vs), tables, lens,
+        impl="pallas", layer=2))
+    want = np.asarray(ragged_paged_attention_reference(
+        q, QuantizedKV(kq[2], ks[2]), QuantizedKV(vq[2], vs[2]), tables,
+        lens))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    # and within the quantization tolerance of the float pool
+    exact = reference(q, kf, vf, tables, lens, 2)
+    assert np.abs(got - exact).max() < 0.05
+
+
+def test_inside_a_scan_over_layers_and_ticks(block):
+    """Callable from a ``lax.scan`` body with the pool in the carry and the
+    layer a traced value: every layer's output equals its own call."""
+    k, v = pool(9, kv_heads=2)
+    q = queries(3, 2, seed=10)
+    tables = tables_for(3, seed=9)
+    lens = jnp.asarray([10, 0, 25], jnp.int32)
+
+    def tick(carry, layer):
+        kk, vv = carry
+        return carry, paged_attention_kernel(q, kk, vv, tables, lens,
+                                             layer=layer)
+
+    _, outs = jax.jit(lambda k, v: jax.lax.scan(
+        tick, (k, v), jnp.arange(LAYERS)))(k, v)
+    for layer in range(LAYERS):
+        np.testing.assert_allclose(
+            np.asarray(outs[layer]), reference(q, k, v, tables, lens, layer),
+            atol=2e-5, rtol=2e-5)
+
+
+def test_a_single_layers_pages_are_the_one_layer_view():
+    """Four-dimensional pages (the pre-stacked signature) still attend."""
+    k, v = pool(11, kv_heads=2, layers=1)
+    q = queries(3, 2, seed=12)
+    tables = tables_for(3, seed=11)
+    lens = jnp.asarray([5, 0, 30], jnp.int32)
+    got = np.asarray(paged_attention_kernel(q, k[0], v[0], tables, lens))
+    np.testing.assert_allclose(got, reference(q, k, v, tables, lens, 0),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype,kv_heads,want", [
+    (jnp.bfloat16, 16, 16), (jnp.bfloat16, 4, 16), (jnp.int8, 16, 16),
+    (jnp.float32, 16, 8)], ids=["bf16-mha", "bf16-gqa4", "int8", "f32"])
+def test_block_follows_the_shapes(dtype, kv_heads, want):
+    """Pages a step at the 1.3B head shape (page 16, d 128): the four page
+    buffers of the VMEM plan over a page's tiled size, never more than the
+    table holds."""
+    assert pa._pages_per_block(16, kv_heads, 128, dtype, 128) == want
+    assert pa._pages_per_block(16, kv_heads, 128, dtype, 4) == 4
