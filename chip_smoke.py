@@ -19,6 +19,10 @@ paths through the entry points users call, at the full width of
   serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
            ``serve_llm``; HTTP ``POST /generate`` checked against
            ``net.generate``; once with attention_impl="xla", once "pallas"
+  serve_hybrid  the hybrid decoder (Mamba-2 state beside K/V pages, dropless
+           routed experts) at granite-4.0-h-small's widths, four layers, 8 of
+           72 experts held: served through both ``attention_impl`` values
+           and held to ``net.generate``
   train    ``Model.prepare(amp_configs="O1")`` + ``Model.fit`` at 1.3B width,
            flash attention and the fused loss on, AdamW; depth cut to what
            one chip holds (printed as ``reduced``)
@@ -364,6 +368,31 @@ def post_generate(url: str, prompt, new_tokens: int) -> dict:
         return json.loads(resp.read())
 
 
+def post_all(url: str, prompts, new_tokens: int, what: str,
+             first_alone: bool = False) -> list:
+    """POST every prompt from a thread of its own (the first one alone and
+    first, where it seeds the prefix cache); the replies, in order."""
+    outs = [None] * len(prompts)
+    errors = []
+
+    def ask(i):
+        try:
+            outs[i] = post_generate(url, prompts[i], new_tokens)
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(f"request {i}: {e!r}")
+
+    if first_alone:
+        ask(0)
+    threads = [threading.Thread(target=ask, args=(i,))
+               for i in range(int(first_alone), len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    check(not errors, f"[{what}] {errors}")
+    return outs
+
+
 def by_length(prompts):
     """Indices of the prompts, grouped by length. net.generate is an eager
     loop whose every op compiles once per shape (about 100 s a new prompt
@@ -375,10 +404,11 @@ def by_length(prompts):
     return list(groups.values())
 
 
-def check_streams(net, prompts, outs, refs) -> list:
+def check_streams(net, prompts, outs, refs, tie_tol=None) -> list:
     """Hold each greedy stream to net.generate's: identical, or every token
-    from the first difference on within TIE_TOL of the top of the
-    reference's own teacher-forced logits."""
+    from the first difference on within ``tie_tol`` (TIE_TOL unless given)
+    of the top of the reference's own teacher-forced logits."""
+    tie_tol = TIE_TOL if tie_tol is None else tie_tol
     import jax.numpy as jnp
     import numpy as np
     verdicts = [None] * len(prompts)
@@ -408,8 +438,8 @@ def check_streams(net, prompts, outs, refs) -> list:
                 "margin_at_first_diff": round(float(margins[first]), 4),
                 "max_margin_from_there": round(
                     float(margins[first:].max()), 4),
-                "tie_tol": TIE_TOL}
-            check(float(margins[first:].max()) <= TIE_TOL,
+                "tie_tol": tie_tol}
+            check(float(margins[first:].max()) <= tie_tol,
                   f"stream {i} leaves the reference beyond a tie: "
                   f"{verdicts[i]}")
     return verdicts
@@ -437,24 +467,8 @@ def serve_once(net, impl: str, prompts, refs, num_pages: int,
     srv = serve_llm(eng)
     try:
         url = "http://%s:%d" % srv.server_address[:2]
-        outs = [None] * len(prompts)
-        errors = []
-
-        def ask(i):
-            try:
-                outs[i] = post_generate(url, prompts[i], new_tokens)
-            except Exception as e:  # noqa: BLE001 — reported below
-                errors.append(f"request {i}: {e!r}")
-
-        ask(0)                      # seeds the prefix cache
-        threads = [threading.Thread(target=ask, args=(i,))
-                   for i in range(1, len(prompts))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
+        outs = post_all(url, prompts, new_tokens, impl, first_alone=True)
         wall = time.time() - t0
-        check(not errors, f"[{impl}] {errors}")
         streams = [o["output_ids"] for o in outs]
         programs = sorted(
             f"{h.kind}{list(h.sig)}" for h in perf.instance().programs()
@@ -552,6 +566,80 @@ def phase_serve(seed: int, cfg_overrides=None,
     pallas = serve_once(net, "pallas", prompts, refs, num_pages, new_tokens)
     emit({"phase": "serve", "xla_vs_pallas_identical_streams": sum(
         a == b for a, b in zip(xla, pallas)), "of": len(prompts)})
+    del net
+    free_device_memory()
+
+
+
+def phase_serve_hybrid(seed: int, layers=("mamba", "mamba", "attention",
+                                          "mamba"),
+                       lengths=(96, 300, 96, 300), new_tokens: int = 32,
+                       experts_held=(0, 8)) -> None:
+    """The hybrid decoder (models/granite_hybrid.py) at granite-4.0-h-small's
+    published widths and a small depth: Mamba-2 state beside K/V pages,
+    dropless top-10-of-72 routing over 8 held experts, served over HTTP by
+    ``LLMEngine`` + ``serve_llm`` through both ``attention_impl`` values on
+    the default path (mixed ticks), each stream held to ``net.generate``
+    (the whole-sequence forward): identical, or a tie within TIE_TOL / 16
+    (this model divides its logits by 16)."""
+    import jax.numpy as jnp
+    import paddle_tpu as pt
+    from paddle_tpu.inference.llm import LLMEngine, serve_llm
+    from paddle_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                  GraniteHybridForCausalLM)
+
+    cfg = GraniteHybridConfig(layer_types=layers, vocab_size=50176,
+                              experts_held=experts_held,
+                              max_position_embeddings=SEQ)
+    t0 = time.time()
+    pt.seed(seed)
+    net = GraniteHybridForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    n_params = sum(int(v.size) for v in net.state_dict().values())
+    prompts = make_prompts(seed, cfg.vocab_size, lengths, 0)
+    refs = [None] * len(prompts)
+    for group in by_length(prompts):
+        toks = net.generate(
+            jnp.asarray([prompts[i] for i in group], jnp.int32),
+            max_new_tokens=new_tokens)
+        for row, i in enumerate(group):
+            refs[i] = [int(t) for t in toks[row, len(prompts[i]):]]
+    emit({"phase": "serve_hybrid", "layers": list(layers),
+          "hidden": cfg.hidden_size, "experts_held": list(experts_held),
+          "of_experts": cfg.num_local_experts, "vocab": cfg.vocab_size,
+          "params": n_params,
+          "build_and_generate_seconds": round(time.time() - t0, 1)})
+    streams = {}
+    for impl in ("xla", "pallas"):
+        t0 = time.time()
+        eng = LLMEngine(net, max_seqs=4, page_size=PAGE, num_pages=256,
+                        max_len=SEQ, kv_dtype="bf16", attention_impl=impl,
+                        prefill_chunk=cfg.mamba_chunk_size)
+        srv = serve_llm(eng)
+        try:
+            url = "http://%s:%d" % srv.server_address[:2]
+            outs = post_all(url, prompts, new_tokens, f"hybrid {impl}")
+            check(eng.health == "healthy",
+                  f"[hybrid {impl}] engine health {eng.health}")
+            check("m" in eng.tick_history,
+                  f"[hybrid {impl}] no mixed tick was dispatched")
+            held_share = eng.n_moe_pairs_held / max(1, eng.n_moe_pairs)
+        finally:
+            srv.shutdown()
+            srv.server_close()
+            eng.close()
+        streams[impl] = [o["output_ids"] for o in outs]
+        verdicts = check_streams(net, prompts, streams[impl], refs,
+                                 TIE_TOL / cfg.logits_scaling)
+        emit({"phase": "serve_hybrid", "attention_impl": impl,
+              "wall_seconds_with_compile": round(time.time() - t0, 1),
+              "moe_held_pair_share": round(held_share, 4),
+              "vs_net_generate": verdicts,
+              "identical": sum(v["identical"] for v in verdicts)})
+        gc.collect()
+    emit({"phase": "serve_hybrid", "xla_vs_pallas_identical_streams": sum(
+        a == b for a, b in zip(streams["xla"], streams["pallas"])),
+        "of": len(prompts)})
     del net
     free_device_memory()
 
@@ -778,6 +866,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", default=None,
+                    choices=("kernels", "serve", "serve_hybrid", "train"),
+                    help="one chip: run this phase alone (default: all)")
     args = ap.parse_args(argv)
     t0 = time.time()
     try:
@@ -785,9 +876,12 @@ def main(argv=None) -> int:
         if args.chips == 4:
             phase_mesh(args.seed)
         else:
-            phase_kernels(args.seed)
-            phase_serve(args.seed)
-            phase_train(args.seed)
+            phases = {"kernels": phase_kernels, "serve": phase_serve,
+                      "serve_hybrid": phase_serve_hybrid,
+                      "train": phase_train}
+            for name, phase in phases.items():
+                if args.phase in (None, name):
+                    phase(args.seed)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)
         return 1

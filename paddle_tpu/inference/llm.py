@@ -197,6 +197,16 @@ def _engine_metrics():
         # cross-replica KV-page migration (disaggregated fleet): the
         # engine counts its own sides (export/import/rejected); the
         # router observes the end-to-end kv_migrate_seconds histogram
+        "moe_rows": reg.counter(
+            "llm_moe_rows_routed_total",
+            "(row, expert) pairs the router made for live rows, by "
+            "whether the expert is held here (held=1) or on another "
+            "chip of the expert-parallel stage (held=0)",
+            label_names=("held",)),
+        "state_rows": reg.gauge(
+            "llm_state_rows_in_use",
+            "slots whose recurrent-state row (conv + SSM) holds a live "
+            "sequence (0 for a model without recurrent state)"),
         "migrate_pages": reg.counter(
             "kv_migrate_pages_total",
             "KV pages migrated across replicas, by direction "
@@ -485,7 +495,22 @@ class DecodeCarry(NamedTuple):
       ``tokens``/``positions``/``budgets`` by the committed run
       length — rejected draft KV simply stays behind the position
       frontier and is overwritten before any later tick reads it
-      (the slab-boundary rollback; never a host round-trip)."""
+      (the slab-boundary rollback; never a host round-trip).
+
+    Recurrent-state lanes (a model whose ``state_cache_spec()`` is not
+    ``None``; ``None`` everywhere else, as the draft lanes are, so a
+    plain decoder's compiled programs are unchanged):
+
+    - ``conv_state``/``ssm_state`` — per SLOT and fixed in size
+      whatever the sequence's length: a tuple with one
+      ``[max_seqs + 1, ...]`` array a state-space layer (the
+      convolution's carried tail in the activations' type; the
+      float32 SSM state), row ``max_seqs`` the scratch row that padded
+      and unused rows write. A tick advances the rows of its active
+      slots by one token and the rows of the prompts in its chunk by
+      their chunk; an inactive slot's row holds. A sequence's row is
+      reset by the program where its position is 0, never by a
+      dispatch of its own."""
 
     tokens: jax.Array
     positions: jax.Array
@@ -494,13 +519,78 @@ class DecodeCarry(NamedTuple):
     v_pages: jax.Array
     draft_k_pages: Optional[jax.Array] = None
     draft_v_pages: Optional[jax.Array] = None
+    conv_state: Any = None
+    ssm_state: Any = None
+
+
+class RaggedRows(NamedTuple):
+    """The token rows of one engine program, as the model's
+    ``ragged_forward`` reads them: T rows drawn from any mix of
+    sequences, each with its own block table and causal limit
+    (``limits`` 0 = a padded or inactive row). The first ``n_chunk``
+    rows (a Python int) are PACKED PROMPT ROWS: the rows of one
+    sequence contiguous and in order, several sequences a run; the
+    others are one token a sequence, row ``i`` of them the sequence in
+    slot ``i``. A model whose rows are independent of each other given
+    the K/V pool (a plain decoder) reads nothing else; one with
+    recurrent state also reads ``chunk_seg`` [n_chunk] (the local index
+    of a prompt row's sequence; the number of sequences for a padded
+    row) and ``seg_rows`` [sequences] (the state row, = slot, of each
+    local sequence; the scratch row for an unused one)."""
+
+    tokens: jax.Array
+    positions: jax.Array
+    limits: jax.Array
+    tables: jax.Array
+    n_chunk: int = 0
+    chunk_seg: Optional[jax.Array] = None
+    seg_rows: Optional[jax.Array] = None
+
+
+class CacheView(NamedTuple):
+    """What the ENGINE owns and hands the model's forward: the stacked
+    paged K/V pool and, for a model with recurrent state, one
+    fixed-size ``conv_state`` / ``ssm_state`` row a slot (a tuple, one
+    ``[max_seqs + 1, ...]`` array a state-space layer: row ``max_seqs``
+    is the scratch row that padded rows write; ``None`` for a model
+    without them). ``attention_impl`` is how the pool is attended."""
+
+    k_pages: Any
+    v_pages: Any
+    conv_state: Any = None
+    ssm_state: Any = None
+    attention_impl: str = "xla"
+
+
+class RecurrentStateUnsupported(ValueError):
+    """An engine mode that assumes K/V pages are a sequence's whole
+    context was asked of a model with recurrent state. ``mechanism``
+    names it: ``"speculative_verify"`` (a rejected draft token cannot be
+    rolled out of the state), ``"kv_page_migration"`` (the
+    ``kv_pages/v1`` payload carries no state)."""
+
+    def __init__(self, mechanism: str, msg: str):
+        super().__init__(msg)
+        self.mechanism = mechanism
+
+
+def _engine_outputs(nxt, aux, cache: CacheView):
+    """What an engine program returns: ``(tokens, k_pages, v_pages)``
+    for a model without recurrent state (as ever), with the forward's
+    ``aux`` counts and the two state lanes behind them otherwise."""
+    if cache.ssm_state is None:
+        return nxt, cache.k_pages, cache.v_pages
+    return (nxt, aux, cache.k_pages, cache.v_pages, cache.conv_state,
+            cache.ssm_state)
 
 
 class _PagedDecode(Layer):
     """One batched decode step as a pure Layer (so functional_call
-    threads the GPT's params): feed each active slot's last token,
-    write its K/V into the pages, attend over the paged context,
-    sample the next token on device.
+    threads the model's params): feed each active slot's last token
+    through the model's ``ragged_forward`` (one row a slot: its K/V
+    written into the pages, attention over the paged context, a
+    recurrent state advanced one token), sample the next token on
+    device.
 
     ``return_logits``: also return the [B, V] logits the token was
     sampled from — the draft-probe mode of the on-device spec slab,
@@ -515,65 +605,21 @@ class _PagedDecode(Layer):
         self.attention_impl = attention_impl
         self.return_logits = return_logits
 
-    def _paged_attention(self, q, k_pages, v_pages, layer, tables,
-                         lens):
-        # the decode step IS the T=batch single-token case of the one
-        # ragged entry point (per-row table + limit — same contract)
-        return ragged_paged_attention(q, k_pages, v_pages, tables,
-                                      lens, impl=self.attention_impl,
-                                      layer=layer)
-
     def forward(self, tokens, positions, block_tables, context_lens,
-                k_pages, v_pages, temperature, nonces, key):
-        net, cfg = self.net, self.net.cfg
-        gpt = net.gpt
-        b = tokens.shape[0]
-        ps = kv_page_size(k_pages)
-        hd = cfg.head_dim
-
-        pos_ids = positions[:, None]                      # [B, 1]
-        x = gpt.embeddings(tokens[:, None], position_ids=pos_ids)
-        # where each slot's new token lands in the pool
-        page_slot = positions // ps                        # [B]
-        page_idx = jnp.take_along_axis(
-            block_tables, page_slot[:, None], axis=1)[:, 0]
-        offs = positions % ps
-        # inactive slots (context_len 0 sentinel) write to scratch 0
-        active = context_lens > 0
-        page_idx = jnp.where(active, page_idx, 0)
-
-        if cfg.use_rope:
-            from ..ops.rotary import apply_rotary_pos_emb, rope_tables
-            cos, sin = rope_tables(hd, cfg.max_position_embeddings,
-                                   cfg.rope_base)
-
-        for i, layer in enumerate(gpt.layers):
-            h = layer.ln_1(x)
-            qkv = layer.attn.qkv_proj(h)
-            q, k, v = jnp.split(
-                qkv, [cfg.hidden_size,
-                      cfg.hidden_size + cfg.num_kv_heads * hd], axis=-1)
-            q = q.reshape(b, 1, cfg.num_heads, hd)
-            k = k.reshape(b, 1, cfg.num_kv_heads, hd)
-            v = v.reshape(b, 1, cfg.num_kv_heads, hd)
-            if cfg.use_rope:
-                q, k = apply_rotary_pos_emb(q, k, cos, sin,
-                                            position_ids=pos_ids)
-            k_pages = kv_write(k_pages, i, page_idx, offs, k[:, 0])
-            v_pages = kv_write(v_pages, i, page_idx, offs, v[:, 0])
-            att = self._paged_attention(q[:, 0], k_pages, v_pages, i,
-                                        block_tables, context_lens)
-            x = x + layer.attn.out_proj(
-                att.reshape(b, 1, cfg.hidden_size))
-            x = x + layer.mlp(layer.ln_2(x))
-        x = gpt.ln_f(x)
-        from ..models.gpt import _lm_logits
-        logits = _lm_logits(cfg, gpt.embeddings, x,
-                            getattr(net, "lm_head", None))[:, 0]
+                k_pages, v_pages, temperature, nonces, key,
+                conv_state=None, ssm_state=None):
+        # the decode step IS the T=batch single-token case of the one
+        # ragged forward (per-row table + limit: same contract);
+        # inactive slots (context_len 0) write to scratch page 0
+        rows = RaggedRows(tokens, positions, context_lens, block_tables)
+        hidden, cache, aux = self.net.ragged_forward(
+            rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
+                            self.attention_impl))
+        logits = self.net.ragged_logits(hidden)
         nxt = _sample(logits, temperature, key, nonces, positions)
         if self.return_logits:
-            return nxt, logits, k_pages, v_pages
-        return nxt, k_pages, v_pages
+            return nxt, logits, cache.k_pages, cache.v_pages
+        return _engine_outputs(nxt, aux, cache)
 
 
 class _PagedVerify(Layer):
@@ -691,12 +737,14 @@ class _PagedPrefill(Layer):
 class _ChunkedPrefill(Layer):
     """One RAGGED prefill chunk: a fixed budget of T prompt tokens
     drawn from one or MORE requests' uncached suffixes, processed as a
-    single batched forward. Each token carries its own block-table row
+    single batched forward (the model's ``ragged_forward`` over T
+    packed prompt rows). Each token carries its own block-table row
     and position; attention runs per token over its sequence's already-
-    cached pages (shared prefix pages included) via
-    :func:`paged_attention_ragged` — causal inside the chunk because a
-    token's limit is its own position + 1 and earlier chunk tokens'
-    K/V are scattered into the pool before the attention reads it.
+    cached pages (shared prefix pages included): causal inside the
+    chunk because a token's limit is its own position + 1 and earlier
+    chunk tokens' K/V are scattered into the pool before the attention
+    reads it. A model with recurrent state carries each sequence's
+    state from row to row of the chunk (``seg`` / ``seg_rows``).
 
     Sampling: for each slot whose prompt COMPLETES inside this chunk,
     ``sample_idx`` points at its last prompt token's row; that row's
@@ -711,57 +759,19 @@ class _ChunkedPrefill(Layer):
 
     def forward(self, tokens, positions, limits, tables, sample_idx,
                 sample_pos, k_pages, v_pages, temperatures, nonces,
-                key):
-        net, cfg = self.net, self.net.cfg
-        gpt = net.gpt
-        t = tokens.shape[0]
-        ps = kv_page_size(k_pages)
-        hd = cfg.head_dim
-
-        pos_ids = positions[None, :]                       # [1, T]
-        x = gpt.embeddings(tokens[None, :], position_ids=pos_ids)
-        active = limits > 0
-        page_idx = jnp.take_along_axis(
-            jnp.clip(tables, 0), (positions // ps)[:, None],
-            axis=1)[:, 0]
-        page_idx = jnp.where(active, page_idx, 0)  # pads → scratch 0
-        offs = positions % ps
-
-        if cfg.use_rope:
-            from ..ops.rotary import apply_rotary_pos_emb, rope_tables
-            cos, sin = rope_tables(hd, cfg.max_position_embeddings,
-                                   cfg.rope_base)
-
-        for i, layer in enumerate(gpt.layers):
-            h = layer.ln_1(x)
-            qkv = layer.attn.qkv_proj(h)
-            q, k, v = jnp.split(
-                qkv, [cfg.hidden_size,
-                      cfg.hidden_size + cfg.num_kv_heads * hd], axis=-1)
-            q = q.reshape(1, t, cfg.num_heads, hd)
-            k = k.reshape(1, t, cfg.num_kv_heads, hd)
-            v = v.reshape(1, t, cfg.num_kv_heads, hd)
-            if cfg.use_rope:
-                q, k = apply_rotary_pos_emb(q, k, cos, sin,
-                                            position_ids=pos_ids)
-            k_pages = kv_write(k_pages, i, page_idx, offs, k[0])
-            v_pages = kv_write(v_pages, i, page_idx, offs, v[0])
-            att = ragged_paged_attention(q[0], k_pages, v_pages,
-                                         tables, limits,
-                                         impl=self.attention_impl,
-                                         layer=i)
-            x = x + layer.attn.out_proj(
-                att.reshape(1, t, cfg.hidden_size))
-            x = x + layer.mlp(layer.ln_2(x))
-        x = gpt.ln_f(x)
-        from ..models.gpt import _lm_logits
+                key, seg=None, seg_rows=None, conv_state=None,
+                ssm_state=None):
+        rows = RaggedRows(tokens, positions, limits, tables,
+                          tokens.shape[0], seg, seg_rows)
+        hidden, cache, aux = self.net.ragged_forward(
+            rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
+                            self.attention_impl))
         # only the finishing slots' last-token rows need the LM head:
         # [max_seqs, H] gathered rows, not [T, V] full logits
-        rows = jnp.take(x[0], sample_idx, axis=0)          # [B, H]
-        logits = _lm_logits(cfg, gpt.embeddings, rows[:, None],
-                            getattr(net, "lm_head", None))[:, 0]
+        logits = self.net.ragged_logits(
+            jnp.take(hidden, sample_idx, axis=0))
         nxt = _sample(logits, temperatures, key, nonces, sample_pos)
-        return nxt, k_pages, v_pages
+        return _engine_outputs(nxt, aux, cache)
 
 
 class _MixedTick(Layer):
@@ -769,16 +779,21 @@ class _MixedTick(Layer):
     (queued prompts' uncached suffixes, packed exactly like
     :class:`_ChunkedPrefill`) and B decode rows (each live slot's last
     token, exactly like :class:`_PagedDecode`) run as a SINGLE batched
-    forward of T = C + B token rows. Every row carries its own block
-    table and causal limit, so one :func:`ragged_paged_attention` call
-    serves both phases — the ragged formulation makes "mixed" a batch
+    forward of T = C + B token rows through the model's
+    ``ragged_forward``. Every row carries its own block table and
+    causal limit, so one :func:`ragged_paged_attention` call serves
+    both phases — the ragged formulation makes "mixed" a batch
     property, not a program property.
 
-    Exactness: each row's math is independent of the others (per-row
-    gather, per-row softmax, per-row LM-head dot), so the computed
-    KV, logits and sampling keys are IDENTICAL to the legacy two-op
-    path that dispatched the same rows as separate prefill and decode
-    programs (test-pinned token identity, greedy and seeded).
+    Exactness: given the K/V pool each row's math is independent of the
+    others (per-row gather, per-row softmax, per-row LM-head dot), so
+    the computed KV, logits and sampling keys are IDENTICAL to the
+    legacy two-op path that dispatched the same rows as separate
+    prefill and decode programs (test-pinned token identity, greedy
+    and seeded). The chunk rows of a model with recurrent state are
+    not independent: the rows of one prompt pass state to each other,
+    in order, and several prompts share a chunk (``pseg`` /
+    ``seg_rows``); a slot is never in both halves of one tick.
 
     Sampling: one [max_seqs] gathered-row LM head per tick — slot b's
     row is its finishing prompt token (``fin_row``) when its prompt
@@ -793,66 +808,27 @@ class _MixedTick(Layer):
 
     def forward(self, ptok, ppos, plim, ptbl, fin, fin_row, fin_pos,
                 dtok, dpos, dlens, tables, k_pages, v_pages, temps,
-                nonces, key):
-        net, cfg = self.net, self.net.cfg
-        gpt = net.gpt
+                nonces, key, pseg=None, seg_rows=None, conv_state=None,
+                ssm_state=None):
         c = ptok.shape[0]
         b = dtok.shape[0]
-        t = c + b
-        ps = kv_page_size(k_pages)
-        hd = cfg.head_dim
-
-        tok_all = jnp.concatenate([ptok, dtok])            # [T]
-        pos_all = jnp.concatenate([ppos, dpos])
-        lim_all = jnp.concatenate([plim, dlens])
-        tbl_all = jnp.concatenate([jnp.clip(ptbl, 0),
-                                   jnp.clip(tables, 0)], axis=0)
-        pos_ids = pos_all[None, :]                         # [1, T]
-        x = gpt.embeddings(tok_all[None, :], position_ids=pos_ids)
-        active = lim_all > 0
-        page_idx = jnp.take_along_axis(
-            tbl_all, (pos_all // ps)[:, None], axis=1)[:, 0]
-        page_idx = jnp.where(active, page_idx, 0)  # pads → scratch 0
-        offs = pos_all % ps
-
-        if cfg.use_rope:
-            from ..ops.rotary import apply_rotary_pos_emb, rope_tables
-            cos, sin = rope_tables(hd, cfg.max_position_embeddings,
-                                   cfg.rope_base)
-
-        for i, layer in enumerate(gpt.layers):
-            h = layer.ln_1(x)
-            qkv = layer.attn.qkv_proj(h)
-            q, k, v = jnp.split(
-                qkv, [cfg.hidden_size,
-                      cfg.hidden_size + cfg.num_kv_heads * hd], axis=-1)
-            q = q.reshape(1, t, cfg.num_heads, hd)
-            k = k.reshape(1, t, cfg.num_kv_heads, hd)
-            v = v.reshape(1, t, cfg.num_kv_heads, hd)
-            if cfg.use_rope:
-                q, k = apply_rotary_pos_emb(q, k, cos, sin,
-                                            position_ids=pos_ids)
-            k_pages = kv_write(k_pages, i, page_idx, offs, k[0])
-            v_pages = kv_write(v_pages, i, page_idx, offs, v[0])
-            att = ragged_paged_attention(q[0], k_pages, v_pages,
-                                         tbl_all, lim_all,
-                                         impl=self.attention_impl,
-                                         layer=i)
-            x = x + layer.attn.out_proj(
-                att.reshape(1, t, cfg.hidden_size))
-            x = x + layer.mlp(layer.ln_2(x))
-        x = gpt.ln_f(x)
-        from ..models.gpt import _lm_logits
+        rows = RaggedRows(jnp.concatenate([ptok, dtok]),
+                          jnp.concatenate([ppos, dpos]),
+                          jnp.concatenate([plim, dlens]),
+                          jnp.concatenate([ptbl, tables], axis=0),
+                          c, pseg, seg_rows)
+        hidden, cache, aux = self.net.ragged_forward(
+            rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
+                            self.attention_impl))
         # one gathered LM-head row per slot: the finishing prompt row
         # when the slot's prefill completes this tick, its decode row
         # otherwise ([max_seqs, H] rows, never [T, V] full logits)
         rows_idx = jnp.where(fin, fin_row, c + jnp.arange(b))
-        rows = jnp.take(x[0], rows_idx, axis=0)            # [B, H]
-        logits = _lm_logits(cfg, gpt.embeddings, rows[:, None],
-                            getattr(net, "lm_head", None))[:, 0]
+        logits = self.net.ragged_logits(
+            jnp.take(hidden, rows_idx, axis=0))
         sample_pos = jnp.where(fin, fin_pos, dpos)
         nxt = _sample(logits, temps, key, nonces, sample_pos)
-        return nxt, k_pages, v_pages
+        return _engine_outputs(nxt, aux, cache)
 
 
 class _Request:
@@ -1010,6 +986,20 @@ def _engine_memory_provider(ref):
                      "bytes": eng.num_pages * eng._draft_scale_bytes,
                      "detail": {"note": "int8 draft-pool per-token "
                                         "dequantization scales"}})
+        if eng._state_spec is not None:
+            # the second kind of cache: fixed-size rows a slot, whatever
+            # the sequence's length (+ the scratch row padded rows write)
+            live = sum(1 for r in eng._slots if r is not None)
+            for kind, per_row in eng._state_row_bytes.items():
+                rows.append(
+                    {"owner": kind, "kind": "rows",
+                     "bytes": (eng.max_seqs + 1) * per_row,
+                     "detail": {"rows": eng.max_seqs + 1,
+                                "rows_in_use": live,
+                                "row_bytes": per_row,
+                                "note": "per-slot recurrent state of "
+                                        "the state-space layers; row "
+                                        "max_seqs is scratch"}})
         return {"rows": rows,
                 "headroom_pages": eng._avail_pages(),
                 "page_bytes": pb}
@@ -1056,6 +1046,23 @@ def _engine_status_provider(ref):
                       "decode": eng.n_decode_ticks,
                       "mixed": eng.n_mixed_slabs},
         }
+        if eng._state_spec is not None:
+            out["recurrent_state"] = {
+                "rows": eng.max_seqs + 1,
+                "rows_in_use": live,
+                "row_bytes": dict(eng._state_row_bytes),
+                "unsupported": ["speculative_verify",
+                                "kv_page_migration"]}
+            out["prefix_cache"] = {
+                "enabled": False,
+                "reason": "recurrent state: a page hit would skip "
+                          "tokens the state needs"}
+        if eng._moe_spec is not None:
+            out["moe"] = {
+                "pairs_routed": eng.n_moe_pairs,
+                "pairs_held": eng.n_moe_pairs_held,
+                "rows_by_layer_and_held_expert":
+                    eng.moe_rows_by_expert.tolist()}
         cache = eng._cache
         if cache is not None:
             out["prefix_cache"] = {
@@ -1302,9 +1309,9 @@ class LLMEngine:
                 "default) runs a quantized draft pool — use it, or "
                 "drop int8")
         self.kv_dtype = kv_dtype
-        L = cfg.num_layers
+        kv_layers, kv_heads, kv_hd = net.kv_cache_spec()
         self.k_pages = kv_zeros(
-            (L, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim),
+            (kv_layers, num_pages, page_size, kv_heads, kv_hd),
             cache_dtype)
         self.v_pages = jax.tree_util.tree_map(jnp.zeros_like,
                                               self.k_pages)
@@ -1314,6 +1321,51 @@ class LLMEngine:
         self.context_lens = np.zeros((max_seqs,), np.int32)
         self.temperatures = np.zeros((max_seqs,), np.float32)
         self._free_pages = list(range(num_pages - 1, 0, -1))  # 0=scratch
+        # A SECOND KIND OF CACHE beside the page pool: a model with
+        # recurrent state (state-space layers) holds, per slot and
+        # whatever the sequence's length, one conv_state and one
+        # ssm_state row a layer (DecodeCarry documents the lanes). K/V
+        # pages are then NOT the sequence's whole context, so what
+        # assumes they are is refused here by name or switched off:
+        # speculative verify (no state rollback), page migration (no
+        # state in the payload; export_pages/import_pages raise), the
+        # prefix cache (a page hit would skip tokens the state needs).
+        spec = net.state_cache_spec()
+        self._state_spec = spec
+        self.conv_state = self.ssm_state = None
+        self._state_row_bytes = {"conv_state": 0, "ssm_state": 0}
+        # (layers, held experts) of the per-tick routed-row counts a
+        # model with routed experts returns beside its hidden states
+        self._moe_spec = net.moe_aux_spec()
+        self._n_aux = 0
+        if self._moe_spec is not None:
+            self._n_aux = self._moe_spec[0] * (self._moe_spec[1] + 1)
+            self.moe_rows_by_expert = np.zeros(self._moe_spec, np.int64)
+            self.n_moe_pairs = 0
+            self.n_moe_pairs_held = 0
+        if spec is not None:
+            if draft_net is not None:
+                raise RecurrentStateUnsupported(
+                    "speculative_verify",
+                    "a draft model does not compose with a model that "
+                    "holds recurrent state: a verify window advances "
+                    "the state past tokens it may reject, and there is "
+                    "no state rollback")
+            prefix_cache = False
+            n_rows = max_seqs + 1
+            self.conv_state = tuple(
+                jnp.zeros((n_rows,) + tuple(spec["conv_state"]),
+                          spec["conv_dtype"])
+                for _ in range(spec["layers"]))
+            self.ssm_state = tuple(
+                jnp.zeros((n_rows,) + tuple(spec["ssm_state"]),
+                          jnp.float32)
+                for _ in range(spec["layers"]))
+            self._state_row_bytes = {
+                "conv_state": sum(a.nbytes for a in self.conv_state)
+                // n_rows,
+                "ssm_state": sum(a.nbytes for a in self.ssm_state)
+                // n_rows}
         self._slots: List[Optional[_Request]] = [None] * max_seqs
         # device-chained last tokens (authoritative between fetches)
         self._tokens_dev = jnp.zeros((max_seqs,), jnp.int32)
@@ -1432,15 +1484,15 @@ class LLMEngine:
                     "draft and target models must share a vocabulary")
             self.spec_k = int(spec_tokens)
             draft_net.eval()
-            dcfg = draft_net.cfg
+            d_layers, d_heads, d_hd = draft_net.kv_cache_spec()
             # same kv_zeros entry point as the target pool: an int8
             # engine gets a QUANTIZED draft pool (int8 pages + its
             # own per-token scale table) with the same quantize-on-
             # write/dequantize-in-kernel discipline — the PR 15
             # deferred follow-on, distinct "draft_pool" ledger rows
             self.draft_k_pages = kv_zeros(
-                (dcfg.num_layers, num_pages, page_size,
-                 dcfg.num_kv_heads, dcfg.head_dim), cache_dtype)
+                (d_layers, num_pages, page_size, d_heads, d_hd),
+                cache_dtype)
             self.draft_v_pages = jax.tree_util.tree_map(
                 jnp.zeros_like, self.draft_k_pages)
             ddecode = _PagedDecode(draft_net, attention_impl)
@@ -1500,15 +1552,59 @@ class LLMEngine:
         self.flops_per_token = 2.0 * float(
             sum(int(np.prod(v.shape)) for v in self._params.values()))
 
+        has_state = spec is not None
+        n_aux = self._n_aux
+
+        def fetched(out):
+            """A state model's program returns ``(tokens, aux, pools,
+            state)``: put what the host fetches, the tokens and the
+            routed-row counts as ONE int32 vector, second."""
+            if not has_state:
+                return out
+            nxt, aux = out[:2]
+            return (nxt, jnp.concatenate([nxt, aux.reshape(-1)])) \
+                + tuple(out[2:])
+
         def decode_fn(params, buffers, tokens, positions, tables, lens,
-                      kp, vp, temps, nonces, key):
+                      kp, vp, temps, nonces, key, *state):
             (out, _) = functional_call(
                 decode, params, buffers, tokens, positions, tables,
-                lens, kp, vp, temps, nonces, key, training=False)
-            return out
+                lens, kp, vp, temps, nonces, key, *state,
+                training=False)
+            return fetched(out)
 
-        # donate the pools: XLA updates pages in place step to step
-        self._decode_fn = self._jit(decode_fn, donate_argnums=(6, 7))
+        # donate the pools (and the state rows): XLA updates them in
+        # place step to step
+        self._decode_fn = self._jit(
+            decode_fn, donate_argnums=(6, 7) + ((11, 12) if has_state
+                                                else ()))
+
+        def tick_outputs(out):
+            """``(tokens, aux or None, the carry's cache lanes)``."""
+            if has_state:
+                nxt, aux, kp, vp, conv, ssm = out
+                return nxt, aux.reshape(-1), dict(
+                    k_pages=kp, v_pages=vp, conv_state=conv,
+                    ssm_state=ssm)
+            nxt, kp, vp = out
+            return nxt, None, dict(k_pages=kp, v_pages=vp)
+
+        def carry_state(c):
+            return (c.conv_state, c.ssm_state) if has_state else ()
+
+        def scan_tick(live_step, run, c):
+            """One tick of a fused slab: ``live_step(c) -> (carry,
+            aux)`` under a cond that skips a tick with nothing to do;
+            what the scan stacks for the host is the carry's tokens
+            (with a state model's counts behind them)."""
+            if not has_state:
+                c = jax.lax.cond(run, lambda c: live_step(c)[0],
+                                 lambda c: c, c)
+                return c, c.tokens
+            c, aux = jax.lax.cond(
+                run, live_step,
+                lambda c: (c, jnp.zeros((n_aux,), jnp.int32)), c)
+            return c, jnp.concatenate([c.tokens, aux])
 
         # the fused slab: n_ticks chained decode ticks as ONE program.
         # Each tick is EXACTLY the per-tick body (same functional_call,
@@ -1527,10 +1623,11 @@ class LLMEngine:
                 def live_step(c):
                     active = c.budgets > 0
                     lens = jnp.where(active, c.positions + 1, 0)
-                    ((nxt, kp, vp), _) = functional_call(
+                    (out, _) = functional_call(
                         decode, params, buffers, c.tokens, c.positions,
                         tables, lens, c.k_pages, c.v_pages, temps,
-                        nonces, key, training=False)
+                        nonces, key, *carry_state(c), training=False)
+                    nxt, aux, lanes = tick_outputs(out)
                     nxt = jnp.where(active, nxt, c.tokens)
                     budgets = jnp.where(active, c.budgets - 1,
                                         c.budgets)
@@ -1540,11 +1637,9 @@ class LLMEngine:
                         tokens=nxt,
                         positions=jnp.where(active, c.positions + 1,
                                             c.positions),
-                        budgets=budgets, k_pages=kp, v_pages=vp)
+                        budgets=budgets, **lanes), aux
 
-                c = jax.lax.cond(jnp.any(c.budgets > 0), live_step,
-                                 lambda c: c, c)
-                return c, c.tokens
+                return scan_tick(live_step, jnp.any(c.budgets > 0), c)
 
             carry, toks = jax.lax.scan(tick, carry, None,
                                        length=n_ticks)
@@ -1599,14 +1694,16 @@ class LLMEngine:
 
             def chunk_fn(params, buffers, tokens, positions, limits,
                          tables, sample_idx, sample_pos, kp, vp, temps,
-                         nonces, key):
+                         nonces, key, *state):
                 (out, _) = functional_call(
                     chunked, params, buffers, tokens, positions,
                     limits, tables, sample_idx, sample_pos, kp, vp,
-                    temps, nonces, key, training=False)
-                return out
+                    temps, nonces, key, *state, training=False)
+                return fetched(out)
 
-            self._chunk_fn = self._jit(chunk_fn, donate_argnums=(8, 9))
+            self._chunk_fn = self._jit(
+                chunk_fn, donate_argnums=(8, 9) + ((15, 16) if has_state
+                                                   else ()))
             from .prefix_cache import PrefixCache
             self._cache = PrefixCache(page_size) if prefix_cache \
                 else None
@@ -1629,13 +1726,16 @@ class LLMEngine:
                     def live_step(c):
                         active = c.budgets > 0
                         lens = jnp.where(active, c.positions + 1, 0)
-                        ((nxt, kp, vp), _) = functional_call(
+                        (out, _) = functional_call(
                             mixed, params, buffers, x["tok"],
                             x["pos"], x["lim"], x["tbl"], x["fin"],
                             x["row"], x["fpos"], c.tokens,
                             c.positions, lens, tables, c.k_pages,
                             c.v_pages, temps, nonces, key,
+                            *((x["seg"], x["segrows"]) if has_state
+                              else ()), *carry_state(c),
                             training=False)
+                        nxt, aux, lanes = tick_outputs(out)
                         fin = x["fin"]
                         tokens = jnp.where(active | fin, nxt, c.tokens)
                         budgets = jnp.where(active, c.budgets - 1,
@@ -1655,11 +1755,10 @@ class LLMEngine:
                                               positions)
                         return DecodeCarry(
                             tokens=tokens, positions=positions,
-                            budgets=budgets, k_pages=kp, v_pages=vp)
+                            budgets=budgets, **lanes), aux
 
                     run = jnp.any(c.budgets > 0) | jnp.any(x["lim"] > 0)
-                    c = jax.lax.cond(run, live_step, lambda c: c, c)
-                    return c, c.tokens
+                    return scan_tick(live_step, run, c)
 
                 carry, toks = jax.lax.scan(tick, carry, xs,
                                            length=n_ticks)
@@ -2136,6 +2235,13 @@ class LLMEngine:
         read: exports never mutate the pool or the cache. Runs on the
         engine worker at a loop boundary (dispatch-quiescent), so it
         is safe against the donated-buffer step."""
+        if self._state_spec is not None:
+            raise RecurrentStateUnsupported(
+                "kv_page_migration",
+                "export_pages does not compose with a model that holds recurrent "
+                "state: the kv_pages/v1 payload carries K/V pages and "
+                "no conv/SSM state, and the pages alone are not the "
+                "sequence's context")
         if self._cache is None:
             raise RuntimeError(
                 "export_pages requires the prefix cache "
@@ -2156,6 +2262,13 @@ class LLMEngine:
         "rejected"}``. Geometry mismatches (kv_dtype / page_size /
         shape) raise ValueError — see docs/RELIABILITY.md on matching
         kv_dtype across disaggregated pools."""
+        if self._state_spec is not None:
+            raise RecurrentStateUnsupported(
+                "kv_page_migration",
+                "import_pages does not compose with a model that holds recurrent "
+                "state: the kv_pages/v1 payload carries K/V pages and "
+                "no conv/SSM state, and the pages alone are not the "
+                "sequence's context")
         if self._cache is None:
             raise RuntimeError(
                 "import_pages requires the prefix cache "
@@ -2351,6 +2464,9 @@ class LLMEngine:
     def _update_kv_gauge(self):
         usable = self.num_pages - 1
         self._m["kv_util"].set((usable - len(self._free_pages)) / usable)
+        if self._state_spec is not None:
+            self._m["state_rows"].set(
+                sum(1 for r in self._slots if r is not None))
         if self._cache is not None:
             self._m["shared_pages"].set(self._cache.shared_page_count)
 
@@ -2895,8 +3011,10 @@ class LLMEngine:
         finishing: List[_Request] = []
         touched: List[_Request] = []
         chunks: List[tuple] = []   # (slot, first position, tokens)
+        seg, segrows, max_segs = self._chunk_segments((T,))
         used = 0
-        while self._prefill_q and used < T:
+        while self._prefill_q and used < T \
+                and (max_segs is None or len(chunks) < max_segs):
             req = self._prefill_q[0]
             n = len(req.prompt)
             take = min(T - used, n - req.prefill_pos)
@@ -2907,6 +3025,9 @@ class LLMEngine:
                 pos[used + j] = p
                 lim[used + j] = p + 1
                 tbl[used + j] = row
+            if seg is not None:
+                seg[used:used + take] = len(chunks)
+                segrows[len(chunks)] = req.slot
             chunks.append((req.slot, req.prefill_pos, take))
             req.prefill_pos += take
             used += take
@@ -2931,12 +3052,16 @@ class LLMEngine:
                       self.k_pages, self.v_pages,
                       jnp.asarray(self.temperatures),
                       jnp.asarray(self._nonces), self._key)
+        if seg is not None:
+            chunk_args += (jnp.asarray(seg), jnp.asarray(segrows)) \
+                + self._state_args()
         if _perf.enabled():
             self._perf_program("prefill_chunk", (), self._chunk_fn,
                                chunk_args)
             self._perf_chunks_unattributed += 1
-        nxt, self.k_pages, self.v_pages = self._chunk_fn(*chunk_args)
+        nxt, fetch = self._take_outputs(self._chunk_fn(*chunk_args))
         self._count_dispatch()
+        self._stamp_state(ph, False, len(chunks), 0)
         if ph is not _trace.NOOP_SPAN:
             rows = self._chunk_limits(chunks)
             self._stamp_kv_pages(ph, (rows, T, self.attention_impl),
@@ -2968,7 +3093,7 @@ class LLMEngine:
                                          self._tokens_dev)
             self._issue_seq += 1
             self._inflight.append(
-                (self._issue_seq, [r.slot for r in finishing], nxt,
+                (self._issue_seq, [r.slot for r in finishing], fetch,
                  "p", None))
             for req in finishing:
                 req.prefill_done = True
@@ -3361,6 +3486,103 @@ class LLMEngine:
             self._begin_close(req.slot)
             self._maybe_finalize()
 
+    # -- recurrent state beside the pages -------------------------------
+    def _state_args(self) -> tuple:
+        """The state lanes a per-tick program takes after its key."""
+        return () if self._state_spec is None \
+            else (self.conv_state, self.ssm_state)
+
+    def _take_outputs(self, out):
+        """Keep the pools (and state rows) a per-tick program returned;
+        ``(tokens on the device, what the host will fetch)``: for a
+        model with routed experts the second holds the tick's routed-row
+        counts behind the tokens, one transfer for both."""
+        if self._state_spec is None:
+            tokens, self.k_pages, self.v_pages = out
+            return tokens, tokens
+        (tokens, fetch, self.k_pages, self.v_pages, self.conv_state,
+         self.ssm_state) = out
+        return tokens, fetch
+
+    def _new_carry(self, positions, budgets) -> DecodeCarry:
+        return DecodeCarry(
+            tokens=self._tokens_dev, positions=jnp.asarray(positions),
+            budgets=jnp.asarray(budgets), k_pages=self.k_pages,
+            v_pages=self.v_pages, conv_state=self.conv_state,
+            ssm_state=self.ssm_state)
+
+    def _take_carry(self, carry: DecodeCarry) -> None:
+        self._tokens_dev = carry.tokens
+        self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
+        self.conv_state, self.ssm_state = carry.conv_state, \
+            carry.ssm_state
+
+    def _chunk_segments(self, shape: tuple):
+        """``(seg, seg_rows, most sequences a chunk may hold)`` for the
+        packer of prompt rows: ``seg`` [*shape] the local index of each
+        prompt row's sequence (padded rows: the count), ``seg_rows``
+        [*shape[:-1], count] each local sequence's state row (unused:
+        the scratch row). ``(None, None, None)`` for a model whose rows
+        are independent."""
+        if self._state_spec is None:
+            return None, None, None
+        g = int(self._state_spec["max_chunk_sequences"])
+        return (np.full(shape, g, np.int32),
+                np.full(shape[:-1] + (g,), self.max_seqs, np.int32), g)
+
+    def _stamp_state(self, ph, decode_part: bool, chunk_seqs: int,
+                     live_rows: int) -> None:
+        """``state_rows`` and ``state_bytes`` of one dispatch on its issue
+        phase (only while tracing): the rows whose recurrent state the
+        tick advances (its live decode rows and the prompts in its
+        chunk), and the bytes its programs read and write for them: the
+        decode half steps every slot's row (an inactive row is read and
+        written back unchanged), the chunk half gathers and scatters as
+        many rows as a chunk may hold sequences."""
+        if ph is _trace.NOOP_SPAN:
+            return
+        rows = moved = 0
+        if self._state_spec is not None:
+            rows = live_rows + chunk_seqs
+            moved = (self.max_seqs if decode_part else 0) + (
+                int(self._state_spec["max_chunk_sequences"])
+                if chunk_seqs else 0)
+        ph.set_attr("state_rows", rows).set_attr(
+            "state_bytes", 2 * moved * sum(self._state_row_bytes.values()))
+
+    def _split_fetch(self, host):
+        """The fetched vector(s) of a state model: tokens, then the
+        routed-row counts ``[..., layers, held + 1]``."""
+        if not self._n_aux:
+            return host, None
+        b = self.max_seqs
+        layers, held = self._moe_spec
+        return host[..., :b], host[..., b:].reshape(
+            host.shape[:-1] + (layers, held + 1))
+
+    def _note_moe(self, aux, ph) -> None:
+        """Account one drained dispatch's routed-row counts: the
+        per-layer per-expert totals behind /statusz, the
+        ``llm_moe_rows_routed_total{held}`` counters, and on the drain
+        phase (joined to its issue phase by ``issue_seq``)
+        ``experts_touched`` (held experts that received a row, summed
+        over layers and ticks) and ``moe_rows_held``."""
+        if aux is None:
+            return
+        aux = aux.reshape((-1,) + aux.shape[-2:]).astype(np.int64)
+        rows = aux[:, :, :-1]
+        held = int(rows.sum())
+        pairs = int(aux[:, :, -1].sum())
+        self.moe_rows_by_expert += rows.sum(0)
+        self.n_moe_pairs += pairs
+        self.n_moe_pairs_held += held
+        if held:
+            self._m["moe_rows"].labels(held="1").inc(held)
+        if pairs - held:
+            self._m["moe_rows"].labels(held="0").inc(pairs - held)
+        ph.set_attr("experts_touched", int((rows > 0).sum())) \
+            .set_attr("moe_rows_held", held)
+
     def _stamp_kv_pages(self, ph, *calls) -> None:
         """``kv_pages_read`` and ``kv_pages_live`` of one dispatch, on
         its issue phase (so only while tracing is active). Each of
@@ -3464,17 +3686,19 @@ class LLMEngine:
                     jnp.asarray(self.block_tables), jnp.asarray(lens),
                     self.k_pages, self.v_pages,
                     jnp.asarray(self.temperatures),
-                    jnp.asarray(self._nonces), self._key)
+                    jnp.asarray(self._nonces), self._key) \
+                + self._state_args()
             if _perf.enabled():
                 self._perf_program("decode_step", (), self._decode_fn, args)
-            tokens, self.k_pages, self.v_pages = self._decode_fn(*args)
+            tokens, fetch = self._take_outputs(self._decode_fn(*args))
             self._count_dispatch()
             self._tokens_dev = tokens
             self._issue_seq += 1
-            self._inflight.append((self._issue_seq, list(live), tokens,
+            self._inflight.append((self._issue_seq, list(live), fetch,
                                    "d", None))
             ph.set_attr("issue_seq", self._issue_seq) \
                 .set_attr("live_rows", len(live)).set_attr("ticks", 1)
+            self._stamp_state(ph, True, 0, len(live))
             self._stamp_kv_pages(
                 ph, (((slot, lens[slot]) for slot in live),
                      self.max_seqs, self.attention_impl))
@@ -3583,10 +3807,7 @@ class LLMEngine:
             for slot in live:
                 pos_arr[slot] = plan[slot][0]
                 bud_arr[slot] = budgets[slot]
-            carry = DecodeCarry(
-                tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
-                budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
-                v_pages=self.v_pages)
+            carry = self._new_carry(pos_arr, bud_arr)
             slab_args = (self._params, self._buffers, carry,
                          jnp.asarray(self.block_tables),
                          jnp.asarray(self.temperatures),
@@ -3596,8 +3817,7 @@ class LLMEngine:
                                    slab_args, steps=n_eff)
             toks, carry = self._slab_fn(*slab_args)
             self._count_dispatch()
-            self._tokens_dev = carry.tokens
-            self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
+            self._take_carry(carry)
             self._issue_seq += 1
             # context_lens advances at the DRAIN (the device decides how
             # far each slot really went — mid-slab EOS stops its writes);
@@ -3607,6 +3827,7 @@ class LLMEngine:
                                     "pos0": {s: plan[s][0] for s in live}}))
             ph.set_attr("issue_seq", self._issue_seq) \
                 .set_attr("live_rows", len(live)).set_attr("ticks", n_eff)
+            self._stamp_state(ph, True, 0, len(live))
             self._stamp_kv_pages(
                 ph, (((slot, plan[slot][0] + j + 1) for slot in live
                       for j in range(budgets[slot])),
@@ -3664,6 +3885,9 @@ class LLMEngine:
             fin_row = np.zeros((n_eff, self.max_seqs), np.int32)
             fin_pos = np.zeros((n_eff, self.max_seqs), np.int32)
             grant = np.zeros((n_eff, self.max_seqs), np.int32)
+            # a model with recurrent state: which sequence each prompt
+            # row belongs to, and that sequence's state row
+            pseg, segrows, max_segs = self._chunk_segments((n_eff, C))
             touched: List[_Request] = []
             chunks: List[tuple] = []   # (slot, first position, tokens)
             n_prefill_tokens = 0
@@ -3676,8 +3900,9 @@ class LLMEngine:
                     # pure-decode slab serves the remainder at decode
                     # shapes (n_run below trims the schedule)
                     break
-                used = 0
-                while self._prefill_q and used < C:
+                used = nseg = 0
+                while self._prefill_q and used < C \
+                        and (max_segs is None or nseg < max_segs):
                     req = self._prefill_q[0]
                     n = len(req.prompt)
                     take = min(C - used, n - req.prefill_pos)
@@ -3688,6 +3913,10 @@ class LLMEngine:
                         ppos[j, used + t] = p
                         plim[j, used + t] = p + 1
                         ptbl[j, used + t] = row
+                    if pseg is not None:
+                        pseg[j, used:used + take] = nseg
+                        segrows[j, nseg] = req.slot
+                    nseg += 1
                     chunks.append((req.slot, req.prefill_pos, take))
                     req.prefill_pos += take
                     used += take
@@ -3769,10 +3998,7 @@ class LLMEngine:
             for slot in plan:
                 pos_arr[slot] = plan[slot][0]
                 bud_arr[slot] = min(entry_bud[slot], n_run)
-            carry = DecodeCarry(
-                tokens=self._tokens_dev, positions=jnp.asarray(pos_arr),
-                budgets=jnp.asarray(bud_arr), k_pages=self.k_pages,
-                v_pages=self.v_pages)
+            carry = self._new_carry(pos_arr, bud_arr)
             xs = {"tok": jnp.asarray(ptok[:n_run]),
                   "pos": jnp.asarray(ppos[:n_run]),
                   "lim": jnp.asarray(plim[:n_run]),
@@ -3781,6 +4007,9 @@ class LLMEngine:
                   "row": jnp.asarray(fin_row[:n_run]),
                   "fpos": jnp.asarray(fin_pos[:n_run]),
                   "grant": jnp.asarray(grant[:n_run])}
+            if pseg is not None:
+                xs["seg"] = jnp.asarray(pseg[:n_run])
+                xs["segrows"] = jnp.asarray(segrows[:n_run])
             mixed_args = (self._params, self._buffers, carry, xs,
                           jnp.asarray(self.block_tables),
                           jnp.asarray(self.temperatures),
@@ -3790,8 +4019,7 @@ class LLMEngine:
                                    mixed_args, steps=n_run)
             toks, carry = self._mixed_fn(*mixed_args)
             self._count_dispatch()
-            self._tokens_dev = carry.tokens
-            self.k_pages, self.v_pages = carry.k_pages, carry.v_pages
+            self._take_carry(carry)
             if self.spec_k and self.spec_slab:
                 # draft ride-along over the slab's WHOLE packed chunk
                 # schedule, flattened to one ragged chunk (padding rows
@@ -3824,6 +4052,8 @@ class LLMEngine:
                 .set_attr("chunk_rows", len(touched)) \
                 .set_attr("chunk_tokens", n_prefill_tokens) \
                 .set_attr("ticks", n_run)
+            self._stamp_state(ph, True, len(touched),
+                              len(slots_list) - len(start))
             if ph is not _trace.NOOP_SPAN:
                 chunk_rows = self._chunk_limits(chunks)
                 # a decode row of tick j attends pos0 + j + 1; a slot
@@ -4038,6 +4268,9 @@ class LLMEngine:
                 host = np.asarray(tokens)      # the only blocking fetch
         with _trace.phase("llm.drain.emit", {"issue_seq": seq}) as ph:
             self._fetch_seq = seq
+            if self._n_aux:
+                host, aux = self._split_fetch(host)
+                self._note_moe(aux, ph)
             if self._consec_device_errors:
                 # a successful fetch ends the error streak (draining is
                 # sticky until reset_health — see _update_health)

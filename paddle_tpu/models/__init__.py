@@ -6,6 +6,8 @@ from .bert import (BertConfig, BertForPretraining,  # noqa
                    BertPretrainingCriterion, bert_config, ernie_config)
 from .gpt import (GPTConfig, GPTForCausalLM, GPTModel,  # noqa
                   GPTPretrainingCriterion, gpt_config)
+from .granite_hybrid import (GraniteHybridConfig,  # noqa
+                             GraniteHybridForCausalLM)
 from .lenet import LeNet  # noqa
 from .mobilenet import (MobileNetV1, MobileNetV2,  # noqa
                         MobileNetV3Large, MobileNetV3Small,

@@ -477,6 +477,81 @@ class GPTForCausalLM(Layer):
             return out, self.gpt.embeddings.word_embeddings.weight
         return self._logits(out)
 
+    # -- the serving engine's forward over ragged rows ------------------
+    def kv_cache_spec(self):
+        """``(layers, kv_heads, head_dim)`` of the paged K/V pool."""
+        cfg = self.cfg
+        return cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+
+    def state_cache_spec(self):
+        """No recurrent state: K/V pages are the whole context."""
+        return None
+
+    def moe_aux_spec(self):
+        """No routed experts: ``ragged_forward`` returns no counts."""
+        return None
+
+    def ragged_logits(self, hidden):
+        """``hidden`` [R, H] (after the final norm) -> logits [R, V]."""
+        return self._logits(hidden[:, None])[:, 0]
+
+    def ragged_forward(self, rows, cache):
+        """The one walk every ``LLMEngine`` program makes (the model owns
+        the forward, the engine the cache view). ``rows``: ``tokens``,
+        ``positions``, ``limits`` [T] and ``tables`` [T, pages]: T token
+        rows drawn from any mix of sequences (a decode batch, a prompt's
+        chunk, both at once), each with its own block table and causal
+        limit (0 = a padded or inactive row, whose K/V lands on scratch
+        page 0). ``cache``: ``k_pages``, ``v_pages`` (the stacked pool),
+        ``attention_impl``. Each row's K/V is written into its page, then
+        the row attends its sequence's pages
+        (:func:`~paddle_tpu.ops.paged_attention.ragged_paged_attention`):
+        causal inside a chunk because a row's limit is its own position
+        + 1 and earlier rows' K/V are already in the pool. Returns
+        ``(hidden [T, H] after the final norm, cache, None)``."""
+        from ..ops.paged_attention import (kv_page_size, kv_write,
+                                           ragged_paged_attention)
+        cfg, gpt = self.cfg, self.gpt
+        hd = cfg.head_dim
+        t = rows.tokens.shape[0]
+        k_pages, v_pages = cache.k_pages, cache.v_pages
+        ps = kv_page_size(k_pages)
+        tables = jnp.clip(rows.tables, 0)
+        pos_ids = rows.positions[None, :]                  # [1, T]
+        x = gpt.embeddings(rows.tokens[None, :], position_ids=pos_ids)
+        page_idx = jnp.take_along_axis(
+            tables, (rows.positions // ps)[:, None], axis=1)[:, 0]
+        page_idx = jnp.where(rows.limits > 0, page_idx, 0)
+        offs = rows.positions % ps
+        if cfg.use_rope:
+            from ..ops.rotary import apply_rotary_pos_emb, rope_tables
+            cos, sin = rope_tables(hd, cfg.max_position_embeddings,
+                                   cfg.rope_base)
+        for i, layer in enumerate(gpt.layers):
+            h = layer.ln_1(x)
+            qkv = layer.attn.qkv_proj(h)
+            q, k, v = jnp.split(
+                qkv, [cfg.hidden_size,
+                      cfg.hidden_size + cfg.num_kv_heads * hd], axis=-1)
+            q = q.reshape(1, t, cfg.num_heads, hd)
+            k = k.reshape(1, t, cfg.num_kv_heads, hd)
+            v = v.reshape(1, t, cfg.num_kv_heads, hd)
+            if cfg.use_rope:
+                q, k = apply_rotary_pos_emb(q, k, cos, sin,
+                                            position_ids=pos_ids)
+            k_pages = kv_write(k_pages, i, page_idx, offs, k[0])
+            v_pages = kv_write(v_pages, i, page_idx, offs, v[0])
+            att = ragged_paged_attention(q[0], k_pages, v_pages, tables,
+                                         rows.limits,
+                                         impl=cache.attention_impl,
+                                         layer=i)
+            x = x + layer.attn.out_proj(
+                att.reshape(1, t, cfg.hidden_size))
+            x = x + layer.mlp(layer.ln_2(x))
+        x = gpt.ln_f(x)
+        return x[0], cache._replace(k_pages=k_pages, v_pages=v_pages), \
+            None
+
     # -- decode-time KV cache -------------------------------------------
     def init_caches(self, batch_size: int, max_len: int, dtype=jnp.float32):
         cfg = self.cfg

@@ -32,6 +32,7 @@ from .layers.pooling import (AdaptiveAvgPool1D, AdaptiveAvgPool3D,  # noqa
                              AdaptiveMaxPool1D, AvgPool3D, MaxPool3D)
 from .layers.pooling import (AdaptiveAvgPool2D, AdaptiveMaxPool2D,  # noqa
                              AvgPool1D, AvgPool2D, MaxPool1D, MaxPool2D)
+from .layers.dropless_moe import DroplessMoE  # noqa
 from .layers.moe import (GShardGate, MoELayer, NaiveGate,  # noqa
                          SwitchGate, collect_aux_losses)
 from .layers.sparse_embedding import (MultiSlotEmbedding,  # noqa

@@ -1,0 +1,95 @@
+"""nn/layers/dropless_moe.py (float32 on the CPU): no token is ever dropped,
+and the shares of an expert-parallel stage add up to the whole layer."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as pt
+from paddle_tpu.nn import DroplessMoE
+
+D, DE, E, K, T = 32, 16, 8, 2, 40
+# float32 sums of the same K products in another order
+TOL = 2e-6
+
+
+def _dense(layer, x, experts=None):
+    """The definition, an expert at a time, over ``experts`` (ids)."""
+    logits = x @ layer.router
+    top, idx = jax.lax.top_k(logits, layer.top_k)
+    gates = jax.nn.softmax(top, -1)
+    y = jnp.zeros_like(x)
+    for e in (range(layer.num_experts) if experts is None else experts):
+        le = e - layer.first
+        a, b = jnp.split(x @ layer.w_in[le], 2, -1)
+        gate = jnp.sum(jnp.where(idx == e, gates, 0.0), -1, keepdims=True)
+        y = y + gate * ((jax.nn.silu(a) * b) @ layer.w_out[le])
+    return y
+
+
+def _layer(held=None, seed=0):
+    pt.seed(seed)
+    return DroplessMoE(D, DE, E, K, held, initializer_range=0.3)
+
+
+def _x(seed=1, t=T):
+    return jnp.asarray(np.random.default_rng(seed).normal(size=(t, D)),
+                       jnp.float32)
+
+
+def test_matches_the_definition_and_counts_every_pair():
+    layer, x = _layer(), _x()
+    y, rows = layer(x)
+    np.testing.assert_allclose(y, _dense(layer, x), atol=TOL, rtol=TOL)
+    assert int(rows.sum()) == T * K
+
+
+def test_a_router_that_sends_every_row_to_one_expert_loses_no_token():
+    layer, x = _layer(), _x()
+    # expert 3 wins every row by a wide margin, expert 5 comes second
+    router = np.zeros((D, E), np.float32)
+    layer.router = jnp.asarray(router)
+    x = x.at[:, 0].set(1.0)
+    layer.router = layer.router.at[0, 3].set(50.0).at[0, 5].set(20.0)
+    y, rows = layer(x)
+    assert rows.tolist() == [0, 0, 0, T, 0, T, 0, 0]
+    np.testing.assert_allclose(y, _dense(layer, x), atol=TOL, rtol=TOL)
+    assert float(jnp.min(jnp.max(jnp.abs(y), -1))) > 0   # every row served
+
+
+def test_invalid_rows_are_not_counted_and_get_nothing():
+    layer, x = _layer(), _x()
+    valid = jnp.arange(T) % 3 != 0
+    y, rows = layer(x, valid)
+    assert int(rows.sum()) == int(valid.sum()) * K
+    np.testing.assert_allclose(y[valid], _dense(layer, x)[valid], atol=TOL,
+                               rtol=TOL)
+    assert float(jnp.max(jnp.abs(y[~valid]))) == 0.0
+
+
+@pytest.mark.parametrize("split", [4, 2, 7])
+def test_the_shares_of_a_stage_add_up_to_the_whole_layer(split):
+    """Experts 0..split-1 on one chip, the rest on the other: each routes
+    over all E and computes its own experts' part; the parts sum to the
+    uncut layer, and the pairs they count to every pair."""
+    whole, x = _layer(), _x()
+    parts, counted = [], 0
+    for first, count in ((0, split), (split, E - split)):
+        share = _layer((first, count))
+        share.router = whole.router
+        share.w_in = whole.w_in[first:first + count]
+        share.w_out = whole.w_out[first:first + count]
+        y, rows = share(x)
+        np.testing.assert_allclose(
+            y, _dense(whole, x, range(first, first + count)), atol=TOL,
+            rtol=TOL)
+        parts.append(y)
+        counted += int(rows.sum())
+    np.testing.assert_allclose(parts[0] + parts[1], whole(x)[0], atol=TOL,
+                               rtol=TOL)
+    assert counted == T * K
+
+
+def test_experts_held_outside_the_router_is_refused():
+    with pytest.raises(ValueError, match="experts_held"):
+        DroplessMoE(D, DE, E, K, (6, 4))
