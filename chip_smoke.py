@@ -260,6 +260,7 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
             check(float(jnp.abs(got[3].astype(jnp.float32)).max()) == 0.0,
                   "an empty context must give a zero row")
     time_paged_attention(seed, heads, d, seq)
+    time_ssd_step(seed)
     emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
 
 
@@ -338,6 +339,117 @@ def time_paged_attention(seed: int, heads: int = 16, d: int = 128,
               "max_abs_err": round(err, 5), "calls": calls})
         check(ok, f"paged attention at the {name} tick's shapes disagrees "
                   f"with _gathered_attention: {err}")
+
+
+def time_ssd_step(seed: int, slots: int = 64, heads: int = 128,
+                  d_head: int = 64, d_state: int = 128, layers: int = 3,
+                  calls: int = 9, head_blocks=(None,)) -> None:
+    """The state step's kernel beside ``ssd_step`` as ``ragged_forward``
+    calls it off the TPU (slice the slots' rows, step, write them back),
+    at the hybrid serving benchmark's shapes: 65 float32 state rows of
+    128 x 64 x 128 a layer, 64 decode rows of bf16 activations, all of
+    them live and with 40 of 64 live. A call's time is that of ``calls``
+    chained calls in one program that donates the state, over their
+    number; GB/s counts a LIVE row's state once read and once written,
+    so ``ssd_step``, which moves every row, reads lower on a part-full
+    batch. Both paths are checked against each other first. Smoke
+    readings of one layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.ssd import ssd_step, ssd_step_kernel
+
+    rng = np.random.RandomState(seed + 11)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 11), 8)
+    r = slots
+    x = jax.random.normal(keys[0], (r, heads, d_head), jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(keys[1], (r, heads)))
+    a = -jnp.exp(jax.random.normal(keys[2], (heads,)))
+    d = jax.random.normal(keys[3], (heads,))
+    b = jax.random.normal(keys[4], (r, d_state))
+    c = jax.random.normal(keys[5], (r, d_state))
+    row_bytes = heads * d_head * d_state * 4
+
+    def fresh_state():
+        return tuple(
+            jax.random.normal(k, (slots + 1, heads, d_head, d_state),
+                              jnp.float32)
+            for k in jax.random.split(keys[6], layers))
+
+    def plain(s, x, live, first):
+        old = s[:r]
+        y, new = ssd_step(
+            x, jnp.where(live[:, None], dt, 0.0), a, b, c, d,
+            jnp.where((first & live)[:, None, None, None], 0.0, old))
+        return y, s.at[:r].set(new)
+
+    def kernel(hb):
+        return lambda s, x, live, first: ssd_step_kernel(
+            x, dt, a, b, c, d, s, live, first, head_block=hb,
+            interpret=False)
+
+    def chained(step):
+        # the calls of a tick in ONE program: a layer's state goes from
+        # call to call, the outputs are summed (fed back as the next
+        # call's input they would grow without bound on a row whose
+        # dt B.C is large)
+        def run(state, x, live, first):
+            state = list(state)
+            total = jnp.zeros(x.shape, jnp.float32)
+            for i in range(calls):
+                y, state[i % layers] = step(state[i % layers], x, live,
+                                            first)
+                total = total + y
+            return total, tuple(state)
+        return jax.jit(run, donate_argnums=(0,))
+
+    part = np.ones((r,), bool)
+    part[rng.permutation(r)[:r - 40]] = False
+    first = np.zeros((r,), bool)
+    first[[3, 17]] = True
+    for live in (np.ones((r,), bool), part):
+        name = f"{int(live.sum())}_of_{r}_live"
+        lv, fs = jnp.asarray(live), jnp.asarray(first)
+        ref_x, ref_state = chained(plain)(fresh_state(), x, lv, fs)
+        steps = {"ssd_step": plain}
+        steps.update({f"kernel_hb{hb or 'auto'}": kernel(hb)
+                      for hb in head_blocks})
+        for what, step in steps.items():
+            fn = chained(step)
+            state = fresh_state()
+            got_x, state = fn(state, x, lv, fs)
+            if step is not plain:
+                # a row that is not live: the skip term from the kernel,
+                # whatever the plain step makes of a state it leaves alone
+                err, ok = _max_err(got_x[lv], ref_x[lv])
+                # float32 beside float32: a few units in the last place
+                # of the largest value a state holds
+                s_err = max(float(jnp.abs(g - w).max())
+                            for g, w in zip(state, ref_state))
+                s_max = max(float(jnp.abs(w).max()) for w in ref_state)
+                check(ok and s_err <= 1e-5 * max(s_max, 1.0),
+                      f"{what} ({name}) disagrees with ssd_step: y {err}, "
+                      f"state {s_err} of {s_max}")
+            else:
+                err = s_err = 0.0
+            jax.block_until_ready(state)
+            t1 = time.perf_counter()
+            for _ in range(3):
+                got_x, state = fn(state, x, lv, fs)
+            jax.block_until_ready((got_x, state))
+            ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+            del state
+            n_live = int(live.sum())
+            emit({"phase": "kernels", "kernel": "ssd_step", "timed": name,
+                  "path": what, "rows": r, "live_rows": n_live,
+                  "state_row_bytes": row_bytes,
+                  "ms_per_call": round(ms, 4),
+                  "live_state_gb_per_s": round(
+                      2 * n_live * row_bytes / ms / 1e6, 1),
+                  "max_abs_err_y": round(err, 6),
+                  "max_abs_err_state": round(s_err, 8), "calls": calls})
+        del ref_state
+        free_device_memory()
 
 
 # ---------------------------------------------------------------------------

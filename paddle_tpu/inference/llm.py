@@ -553,13 +553,27 @@ class CacheView(NamedTuple):
     fixed-size ``conv_state`` / ``ssm_state`` row a slot (a tuple, one
     ``[max_seqs + 1, ...]`` array a state-space layer: row ``max_seqs``
     is the scratch row that padded rows write; ``None`` for a model
-    without them). ``attention_impl`` is how the pool is attended."""
+    without them). ``attention_impl`` is how the pool is attended,
+    ``state_impl`` how the decode rows' ``ssm_state`` is stepped
+    (:func:`_state_impl`)."""
 
     k_pages: Any
     v_pages: Any
     conv_state: Any = None
     ssm_state: Any = None
     attention_impl: str = "xla"
+    state_impl: str = "xla"
+
+
+def _state_impl(ssm_state) -> str:
+    """How an engine's programs step the recurrent state of their decode
+    rows, by the platform of the state's device alone: ``"pallas"``
+    (``ops/ssd.py ssd_step_kernel``: the layer's whole state array in
+    place, live rows only) on a TPU, ``"xla"`` (``ssd_step`` over the
+    slots' rows) anywhere else and for a model without state."""
+    on_tpu = ssm_state is not None and all(
+        d.platform == "tpu" for a in ssm_state for d in a.devices())
+    return "pallas" if on_tpu else "xla"
 
 
 class RecurrentStateUnsupported(ValueError):
@@ -599,11 +613,12 @@ class _PagedDecode(Layer):
     program's output arity unchanged."""
 
     def __init__(self, net, attention_impl: str = "xla",
-                 return_logits: bool = False):
+                 return_logits: bool = False, state_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
         self.return_logits = return_logits
+        self.state_impl = state_impl
 
     def forward(self, tokens, positions, block_tables, context_lens,
                 k_pages, v_pages, temperature, nonces, key,
@@ -614,7 +629,7 @@ class _PagedDecode(Layer):
         rows = RaggedRows(tokens, positions, context_lens, block_tables)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl))
+                            self.attention_impl, self.state_impl))
         logits = self.net.ragged_logits(hidden)
         nxt = _sample(logits, temperature, key, nonces, positions)
         if self.return_logits:
@@ -801,10 +816,12 @@ class _MixedTick(Layer):
     position is ``fin_pos`` (= len(prompt) - 1) or its feed position
     — the same (nonce, position) key either phase would fold."""
 
-    def __init__(self, net, attention_impl: str = "xla"):
+    def __init__(self, net, attention_impl: str = "xla",
+                 state_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
+        self.state_impl = state_impl
 
     def forward(self, ptok, ppos, plim, ptbl, fin, fin_row, fin_pos,
                 dtok, dpos, dlens, tables, k_pages, v_pages, temps,
@@ -819,7 +836,7 @@ class _MixedTick(Layer):
                           c, pseg, seg_rows)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl))
+                            self.attention_impl, self.state_impl))
         # one gathered LM-head row per slot: the finishing prompt row
         # when the slot's prefill completes this tick, its decode row
         # otherwise ([max_seqs, H] rows, never [T, V] full logits)
@@ -1366,6 +1383,7 @@ class LLMEngine:
                 // n_rows,
                 "ssm_state": sum(a.nbytes for a in self.ssm_state)
                 // n_rows}
+        self.state_impl = _state_impl(self.ssm_state)
         self._slots: List[Optional[_Request]] = [None] * max_seqs
         # device-chained last tokens (authoritative between fetches)
         self._tokens_dev = jnp.zeros((max_seqs,), jnp.int32)
@@ -1538,7 +1556,8 @@ class LLMEngine:
         self.n_draft_steps = 0
         self.n_spec_proposed = 0   # draft tokens offered to verify
         self.n_spec_accepted = 0   # of those, committed to requests
-        decode = _PagedDecode(net, attention_impl)
+        decode = _PagedDecode(net, attention_impl,
+                              state_impl=self.state_impl)
         # all wrappers share `net` as their only sublayer, so one
         # "net."-prefixed param dict serves decode and prefill alike
         self._params, self._buffers = split_state(decode)
@@ -1718,7 +1737,7 @@ class LLMEngine:
             # Finished/inactive slots are masked no-ops exactly like
             # the pure-decode slab; a tick with neither budgets nor
             # prefill rows is skipped by the cond.
-            mixed = _MixedTick(net, attention_impl)
+            mixed = _MixedTick(net, attention_impl, self.state_impl)
 
             def mixed_fn(params, buffers, carry, xs, tables, temps,
                          nonces, key, n_ticks):
@@ -3535,20 +3554,25 @@ class LLMEngine:
         """``state_rows`` and ``state_bytes`` of one dispatch on its issue
         phase (only while tracing): the rows whose recurrent state the
         tick advances (its live decode rows and the prompts in its
-        chunk), and the bytes its programs read and write for them: the
-        decode half steps every slot's row (an inactive row is read and
-        written back unchanged), the chunk half gathers and scatters as
-        many rows as a chunk may hold sequences."""
+        chunk), and the bytes its programs read and write for them. The
+        chunk half gathers and scatters as many rows as a chunk may hold
+        sequences. The decode half steps every slot's ``conv_state`` row
+        (an inactive row is read and written back unchanged) and, through
+        the kernel (``state_impl`` ``"pallas"``), the ``ssm_state`` rows
+        of its live rows alone; ``ssd_step`` moves every slot's."""
         if ph is _trace.NOOP_SPAN:
             return
         rows = moved = 0
         if self._state_spec is not None:
             rows = live_rows + chunk_seqs
-            moved = (self.max_seqs if decode_part else 0) + (
-                int(self._state_spec["max_chunk_sequences"])
-                if chunk_seqs else 0)
-        ph.set_attr("state_rows", rows).set_attr(
-            "state_bytes", 2 * moved * sum(self._state_row_bytes.values()))
+            chunk = int(self._state_spec["max_chunk_sequences"]) \
+                if chunk_seqs else 0
+            slots = self.max_seqs if decode_part else 0
+            stepped = live_rows if self.state_impl == "pallas" else slots
+            row = self._state_row_bytes
+            moved = (slots + chunk) * row["conv_state"] \
+                + (stepped + chunk) * row["ssm_state"]
+        ph.set_attr("state_rows", rows).set_attr("state_bytes", 2 * moved)
 
     def _split_fetch(self, host):
         """The fetched vector(s) of a state model: tokens, then the

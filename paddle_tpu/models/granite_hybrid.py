@@ -408,8 +408,10 @@ class GraniteHybridForCausalLM(Layer):
         of them on state row ``i``. ``cache``: ``k_pages``, ``v_pages``,
         ``conv_state``, ``ssm_state`` (a tuple, one array a state-space
         layer, ``[rows + 1, ...]``: the last row takes what padded rows
-        write), ``attention_impl``. A sequence's state is reset where its
-        position is 0. Returns ``(hidden [T, H], cache, aux)``."""
+        write), ``attention_impl``, ``state_impl`` (``"pallas"``: the
+        decode rows' state through ``ssd.ssd_step_kernel``; else
+        ``ssd.ssd_step``). A sequence's state is reset where its position
+        is 0. Returns ``(hidden [T, H], cache, aux)``."""
         cfg = self.cfg
         r = cfg.residual_multiplier
         tokens, positions, limits = rows.tokens, rows.positions, rows.limits
@@ -482,12 +484,20 @@ class GraniteHybridForCausalLM(Layer):
                             jnp.where(live[:, None, None], tail, old))
                     with jax.named_scope("ssm"):
                         xs, b, cc = mixer.scan_inputs(conv, u.dtype)
-                        old = ssm_s[:n_dec]
-                        y, new = ssd.ssd_step(
-                            xs, dt[c:], mixer.A, b, cc, mixer.D,
-                            jnp.where((first & live)[:, None, None, None],
-                                      0.0, old))
-                        ssm_s = ssm_s.at[:n_dec].set(new)
+                        if cache.state_impl == "pallas":
+                            # the layer's whole array, in place: live
+                            # rows' tiles alone cross HBM
+                            y, ssm_s = ssd.ssd_step_kernel(
+                                xs, dt[c:], mixer.A, b, cc, mixer.D,
+                                ssm_s, live, first)
+                        else:
+                            old = ssm_s[:n_dec]
+                            y, new = ssd.ssd_step(
+                                xs, dt[c:], mixer.A, b, cc, mixer.D,
+                                jnp.where(
+                                    (first & live)[:, None, None, None],
+                                    0.0, old))
+                            ssm_s = ssm_s.at[:n_dec].set(new)
                         ys.append(y)
                 with jax.named_scope("ssm"):
                     out = mixer.finish(

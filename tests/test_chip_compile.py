@@ -2,8 +2,10 @@
 described (not attached) v5e at the GPT-3-1.3B head shape: 16 heads x 128,
 page 16, context 2048. Interpret-mode tests cannot see what the Mosaic
 lowering refuses (block shapes, tiling, VMEM); these can, at about two
-seconds each and no chip time. The last one compiles a whole decode tick
-and reads its temporaries: the kernel must leave the pool where it is.
+seconds each and no chip time. Two compile whole engine programs and read
+their temporaries: a decode tick of GPT (the kernel must leave the pool
+where it is) and the hybrid model's decode and mixed programs at the
+published state shape (no copy of a layer's whole recurrent state).
 
 Only one process may load libtpu, and it keeps it until it exits: the
 topology is described inside a fixture of THIS file (never at import, in a
@@ -17,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.ops.paged_attention import paged_attention_kernel
+from paddle_tpu.ops.ssd import ssd_step_kernel
 
 HEADS, HEAD_DIM, PAGE, MAX_LEN = 16, 128, 16, 2048
 
@@ -209,3 +212,122 @@ def test_engine_compiler_options_halve_a_ticks_async_prefetches(
     plain = starts(lowered.compile())
     held = starts(lowered.compile(compiler_options=_TPU_COMPILER_OPTIONS))
     assert 0 < held <= plain // 2, (held, plain)
+
+
+# the hybrid serving benchmark's state: 64 slots and a scratch row of
+# 128 heads x 64 x 128 float32 a state-space layer
+SLOTS, SSM_HEADS, SSM_HEAD_DIM, SSM_STATE = 64, 128, 64, 128
+STATE_ROW_BYTES = SSM_HEADS * SSM_HEAD_DIM * SSM_STATE * 4
+
+
+@pytest.mark.parametrize("head_block", [None, 16, 24],
+                         ids=["hb_from_shapes", "hb16", "hb24_ragged"])
+def test_ssd_step_kernel_compiles_for_v5e(one_chip, head_block):
+    """The state step at the cell's shapes, a layer's whole
+    ``[65, 128, 64, 128]`` array donated: aliased in place, no temporary."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(x, dt, a, b, c, d, state, live, first):
+        return ssd_step_kernel(x, dt, a, b, c, d, state, live, first,
+                               head_block=head_block, interpret=False)
+
+    state = sds((SLOTS + 1, SSM_HEADS, SSM_HEAD_DIM, SSM_STATE))
+    compiled = jax.jit(step, donate_argnums=(6,)).lower(
+        sds((SLOTS, SSM_HEADS, SSM_HEAD_DIM), jnp.bfloat16),
+        sds((SLOTS, SSM_HEADS)), sds((SSM_HEADS,)), sds((SLOTS, SSM_STATE)),
+        sds((SLOTS, SSM_STATE)), sds((SSM_HEADS,)), state,
+        sds((SLOTS,), jnp.bool_), sds((SLOTS,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == (SLOTS + 1) * STATE_ROW_BYTES
+    assert mem.temp_size_in_bytes < STATE_ROW_BYTES // 4
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    """The engine's own ``decode`` and ``mixed`` programs of a hybrid model
+    with the published state shape (three state-space layers and one that
+    attends; the other widths small), compiled for the described chip as a
+    TPU's engine builds them: kernels compiled, the decode rows' state
+    through ``ssd_step_kernel``. ``{name: compiled}``."""
+    import importlib
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.inference import llm
+    from paddle_tpu.models import (GraniteHybridConfig,
+                                   GraniteHybridForCausalLM)
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(importlib.import_module("paddle_tpu.ops.flash_attention"),
+               "INTERPRET", False)
+    # the engine here lies on the CPU: the test, not an option, says what
+    # the platform of a TPU's state would
+    mp.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    cfg = GraniteHybridConfig(
+        vocab_size=1024, hidden_size=1024,
+        layer_types=["mamba", "mamba", "mamba", "attention"],
+        num_attention_heads=8, num_key_value_heads=8, intermediate_size=128,
+        shared_intermediate_size=256, num_local_experts=8,
+        num_experts_per_tok=2, max_position_embeddings=2048)
+    assert (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state) == (
+        SSM_HEADS, SSM_HEAD_DIM, SSM_STATE)
+    pt.seed(0)
+    net = GraniteHybridForCausalLM(cfg)
+    net.eval()
+    chunk = 64
+    eng = llm.LLMEngine(net, max_seqs=SLOTS, page_size=PAGE,
+                        num_pages=SLOTS * 16 + 1, max_len=256,
+                        prefill_chunk=chunk, attention_impl="pallas")
+    try:
+        assert eng.state_impl == "pallas"
+
+        def described(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=one_chip), tree)
+
+        ints = np.zeros((SLOTS,), np.int32)
+        decode = eng._decode_fn.lower(*described((
+            eng._params, eng._buffers, eng._tokens_dev, ints,
+            eng.block_tables, ints, eng.k_pages, eng.v_pages,
+            eng.temperatures, eng._nonces, eng._key)
+            + eng._state_args())).compile()
+        seg, seg_rows, _ = eng._chunk_segments((1, chunk))
+        rows = np.zeros((1, chunk), np.int32)
+        slots = np.zeros((1, SLOTS), np.int32)
+        xs = {"tok": rows, "pos": rows, "lim": rows,
+              "tbl": np.zeros((1, chunk, eng.pages_per_seq), np.int32),
+              "fin": slots.astype(bool), "row": slots, "fpos": slots,
+              "grant": slots, "seg": seg, "segrows": seg_rows}
+        mixed = eng._mixed_fn.lower(*described((
+            eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+            eng.block_tables, eng.temperatures, eng._nonces, eng._key)),
+            1).compile()
+    finally:
+        eng.close()
+        mp.undo()
+    return {"decode": decode, "mixed": mixed}
+
+
+@pytest.mark.parametrize("program,rows", [("decode", 8), ("mixed", SLOTS)],
+                         ids=["decode", "mixed"])
+def test_hybrid_program_keeps_no_copy_of_a_layers_state(hybrid_programs,
+                                                        program, rows):
+    """Every state-space layer steps its state through the kernel, the
+    three layers' arrays are the program's own outputs (aliased), and the
+    temporaries stay under a few state rows: 8 for a decode tick (it read
+    6, none of them state), and under ONE layer's 65 for a mixed tick,
+    whose chunk half rightly holds the gathered and the new state of the 8
+    sequences a chunk may carry, besides the scan's products (it read 44).
+    A gather, a ``where`` or a scatter that XLA could not alias would add
+    a layer's whole array, 65 rows, to either."""
+    compiled = hybrid_programs[program]
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if " custom-call(" in ln and "%ssd_step" in ln.split(" = ")[0]]
+    assert len(calls) == 3, len(calls)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * (SLOTS + 1) * STATE_ROW_BYTES
+    assert mem.temp_size_in_bytes < rows * STATE_ROW_BYTES, (
+        mem.temp_size_in_bytes / STATE_ROW_BYTES)

@@ -186,6 +186,40 @@ def test_the_other_tick_paths_serve_the_same_tokens(model, knobs):
     assert [list(g) for g in got] == [list(w) for w in want]
 
 
+@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+                                   dict(decode_ticks_per_dispatch=4)],
+                         ids=["mixed_and_decode_ticks", "two_op_ticks",
+                              "slab"])
+def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
+                                                      monkeypatch):
+    """What a TPU's engine runs, here through the Pallas interpreter: the
+    decode rows' state stepped in place by ``ssd_step_kernel`` (live rows
+    only; 3 slots and 5 requests, so rows stand empty and are reused)
+    gives token for token what ``ssd_step`` gives. Off the TPU the engine
+    takes ``ssd_step``; the test, not an option, steers it."""
+    from paddle_tpu.inference import llm
+    net, params, d = model
+    prompts = prompts_of((12, 27, 1, 6, 19), seed=4)
+    kw = dict(max_seqs=3, page_size=8, num_pages=64, max_len=128,
+              prefill_chunk=16, kv_dtype="f32", **knobs)
+
+    def serve():
+        with LLMEngine(net, **kw) as eng:
+            futs = [eng.submit(p, max_new_tokens=9) for p in prompts[:2]]
+            outs = [f.result(timeout=600)["output_ids"] for f in futs]
+            futs = [eng.submit(p, max_new_tokens=9) for p in prompts[2:]]
+            outs += [f.result(timeout=600)["output_ids"] for f in futs]
+            return eng.state_impl, [list(o) for o in outs]
+
+    plain_impl, want = serve()
+    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    kernel_impl, got = serve()
+    assert (plain_impl, kernel_impl) == ("xla", "pallas")
+    assert got == want
+    for p, toks in zip(prompts, got):
+        assert served_gap(params, d, p, toks) <= TOL
+
+
 def test_what_assumes_pages_are_the_whole_context_is_refused_by_name(model):
     net, _, _ = model
     draft = GPTForCausalLM(gpt_config("gpt2-small", num_layers=1,
