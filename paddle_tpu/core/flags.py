@@ -183,25 +183,8 @@ define_flag("mixed_tick", True,
             "it. The legacy alternating loop stays one release behind "
             "this flag (set False / mixed_tick=False to get it back); "
             "engines that took the default silently fall back to it "
-            "when a conflicting knob (lookahead, legacy spec rounds) "
-            "is in play — only an EXPLICIT mixed_tick=True conflicts "
-            "loudly.")
-define_flag("spec_slab", True,
-            "Default for LLMEngine(spec_slab=...): run speculative "
-            "draft-K/verify-1 rounds ON DEVICE inside the DecodeCarry "
-            "lax.scan slab — K draft steps, one ragged verify window "
-            "and the accept/rollback masking all execute as scan "
-            "ticks in ONE XLA dispatch (up to K accepted tokens + "
-            "the bonus per tick per slot), instead of the legacy "
-            "host-orchestrated round (K draft dispatches + a verify "
-            "dispatch + a host sync each). Slab spec engines ride "
-            "the prefix cache, decode_ticks_per_dispatch=N, "
-            "mixed_tick prefill fusion, kv_dtype='int8' (quantized "
-            "draft pool) and temperature>0 (on-device rejection "
-            "sampling; keys still fold (nonce, position) only). "
-            "False keeps the legacy inline path one release for "
-            "rollback (greedy-only, inline prefill, no prefix "
-            "cache; see MIGRATION.md).")
+            "when lookahead is in play — only an EXPLICIT "
+            "mixed_tick=True conflicts loudly.")
 define_flag("kv_dtype", "",
             "Default storage dtype for LLMEngine's paged KV pool: "
             "'int8' (quantized pages + per-token scale table beside "
@@ -209,9 +192,8 @@ define_flag("kv_dtype", "",
             "and ~2x effective prefix cache at fixed HBM; greedy "
             "parity within a documented tolerance of the f32 "
             "reference path), 'bf16'/'f16'/'f32' (plain pools), or "
-            "empty to keep the engine's cache_dtype argument "
-            "(legacy default f32). LLMEngine(kv_dtype=...) overrides "
-            "per engine.")
+            "empty for 'f32'. LLMEngine(kv_dtype=...) overrides per "
+            "engine.")
 define_flag("numeric_guard", False,
             "Arm the on-device numeric guard (reliability/guard.py) "
             "with default GuardPolicy() in Model.prepare when no "

@@ -57,8 +57,7 @@ from ..observability import server as _dbgsrv
 from ..observability import tracing as _trace
 from ..ops.paged_attention import (KV_DTYPES, QuantizedKV, _split_kv,
                                    kv_nbytes, kv_page_size,
-                                   kv_scale_nbytes, kv_write, kv_zeros,
-                                   ragged_paged_attention)
+                                   kv_scale_nbytes, kv_zeros)
 from ..reliability import faults as _faults
 from ..reliability.retry import Deadline, DeadlineExceeded, as_deadline
 
@@ -253,14 +252,13 @@ def _engine_metrics():
             "XLA dispatches issued by the engine loop (prefill "
             "chunks, decode steps/slabs, speculative draft+verify "
             "passes) — the quantity fused slabs divide by N"),
-        # speculative decoding (draft-K/verify-1 rounds; both the
-        # legacy host-orchestrated path and the on-device spec slab
-        # feed these — the acceptance lens tools/llm_bench.py --spec
-        # sweeps over draft K)
+        # speculative decoding (draft-K/verify-1 rounds on device:
+        # the acceptance lens tools/llm_bench.py --spec sweeps over
+        # draft K)
         "spec_rounds": reg.counter(
             "llm_spec_rounds_total",
-            "speculative draft+verify rounds executed (slab engines: "
-            "realized scan ticks; legacy engines: host rounds)"),
+            "speculative draft+verify rounds executed (realized scan "
+            "ticks)"),
         "spec_draft_tokens": reg.counter(
             "llm_spec_draft_tokens_total",
             "draft tokens proposed to the verifier (spec_tokens - 1 "
@@ -481,7 +479,7 @@ class DecodeCarry(NamedTuple):
     installed INTO the carry at that tick, so it decodes from tick
     j+1 onward without ever surfacing to the host.
 
-    Speculative lanes (``spec_slab`` engines; ``None`` — an empty
+    Speculative lanes (engines with a draft model; ``None`` — an empty
     pytree node — everywhere else, so non-speculative compiled
     programs are unchanged):
 
@@ -639,15 +637,16 @@ class _PagedDecode(Layer):
 
 class _PagedVerify(Layer):
     """Speculative-verify step: feed K tokens per slot (the committed
-    last token + K-1 draft proposals), write their K/V into the pages,
-    attend with per-token causal limits, and return the TARGET model's
+    last token + K-1 draft proposals) through the model's
+    ``ragged_forward`` as B x K rows, row (b, j) at position
+    ``base_lens[b] + j`` with causal limit ``base_lens[b] + j + 1``
+    over slot b's block table, and return the TARGET model's
     [B, K, V] logits after each — one pass instead of K decode steps.
     Exactness: position j's logits see precisely the same cached
     context as the j-th sequential decode step would, so greedy
     acceptance (argmax of these logits) and T>0 rejection sampling
-    are exact by construction (pinned by test). Callers that only
-    need the greedy choice argmax outside (the legacy round's
-    ``_verify_fn`` wrapper keeps its old [B, K] token contract)."""
+    are exact by construction (pinned by test). The window takes the
+    gathered attention path on every platform."""
 
     def __init__(self, net):
         super().__init__()
@@ -655,98 +654,23 @@ class _PagedVerify(Layer):
 
     def forward(self, tokens, base_lens, block_tables, k_pages,
                 v_pages):
-        net, cfg = self.net, self.net.cfg
-        gpt = net.gpt
         b, kq = tokens.shape
-        ps = kv_page_size(k_pages)
-        hd = cfg.head_dim
-        # per-token causal limits of the verify window, flattened to
-        # the ONE ragged entry point's [T] contract (query j of slot b
-        # attends base_lens[b]+j+1 positions; inactive slots 0)
-        rag_limits = jnp.where(
-            base_lens[:, None] > 0,
-            base_lens[:, None] + jnp.arange(kq)[None, :] + 1,
-            0).reshape(-1)
-        rag_tables = jnp.repeat(block_tables, kq, axis=0)
-
-        pos_ids = base_lens[:, None] + jnp.arange(kq)[None, :]  # [B,K]
-        x = gpt.embeddings(tokens, position_ids=pos_ids)
-        active = base_lens > 0
+        positions = base_lens[:, None] + jnp.arange(kq)[None, :]
         # a window straddling the table's end (base within K-1 of
-        # max_len) must scratch its overflow writes, not let the
-        # gather's index clamp land them on the sequence's LAST page
-        page_slot = pos_ids // ps
-        page_idx = jnp.take_along_axis(
-            jnp.clip(block_tables, 0),
-            jnp.minimum(page_slot, block_tables.shape[1] - 1), axis=1)
-        page_idx = jnp.where(
-            active[:, None] & (page_slot < block_tables.shape[1]),
-            page_idx, 0)
-        offs = pos_ids % ps
-
-        if cfg.use_rope:
-            from ..ops.rotary import apply_rotary_pos_emb, rope_tables
-            cos, sin = rope_tables(hd, cfg.max_position_embeddings,
-                                   cfg.rope_base)
-
-        for i, layer in enumerate(gpt.layers):
-            h = layer.ln_1(x)
-            qkv = layer.attn.qkv_proj(h)
-            q, k, v = jnp.split(
-                qkv, [cfg.hidden_size,
-                      cfg.hidden_size + cfg.num_kv_heads * hd], axis=-1)
-            q = q.reshape(b, kq, cfg.num_heads, hd)
-            k = k.reshape(b, kq, cfg.num_kv_heads, hd)
-            v = v.reshape(b, kq, cfg.num_kv_heads, hd)
-            if cfg.use_rope:
-                q, k = apply_rotary_pos_emb(q, k, cos, sin,
-                                            position_ids=pos_ids)
-            k_pages = kv_write(k_pages, i, page_idx, offs, k)
-            v_pages = kv_write(v_pages, i, page_idx, offs, v)
-            # no ``impl``: the verify window keeps the gathered path
-            att = ragged_paged_attention(
-                q.reshape(b * kq, cfg.num_heads, hd),
-                k_pages, v_pages, rag_tables, rag_limits, layer=i)
-            x = x + layer.attn.out_proj(
-                att.reshape(b, kq, cfg.hidden_size))
-            x = x + layer.mlp(layer.ln_2(x))
-        x = gpt.ln_f(x)
-        from ..models.gpt import _lm_logits
-        logits = _lm_logits(cfg, gpt.embeddings, x,
-                            getattr(net, "lm_head", None))  # [B,K,V]
-        return logits, k_pages, v_pages
-
-
-class _PagedPrefill(Layer):
-    """Prompt prefill for ONE sequence: dense causal forward (the
-    existing cache path computes per-layer K/V), scattered into the
-    sequence's pages. Padded to a bucket length; pad positions write
-    to scratch page 0."""
-
-    def __init__(self, net):
-        super().__init__()
-        self.net = net
-
-    def forward(self, ids, true_len, block_row, k_pages, v_pages,
-                temperature, nonce, key):
-        net, cfg = self.net, self.net.cfg
-        s = ids.shape[1]
-        ps = kv_page_size(k_pages)
-        compute_dtype = jnp.float32 if isinstance(k_pages, QuantizedKV) \
-            else k_pages.dtype
-        caches = net.init_caches(1, s, dtype=compute_dtype)
-        logits, caches = net(ids, caches=caches)
-        pos = jnp.arange(s)
-        valid = pos < true_len
-        page_idx = jnp.where(valid, block_row[pos // ps], 0)
-        offs = pos % ps
-        for i, (k_c, v_c, _) in enumerate(caches):
-            k_pages = kv_write(k_pages, i, page_idx, offs, k_c[0])
-            v_pages = kv_write(v_pages, i, page_idx, offs, v_c[0])
-        last = logits[0, true_len - 1][None]              # [1, V]
-        nxt = _sample(last, temperature[None], key, nonce[None],
-                      (true_len - 1)[None])[0]
-        return nxt, k_pages, v_pages
+        # max_len) sends its overflow rows to scratch page 0 as padded
+        # rows (limit 0, a position inside the table), never onto the
+        # sequence's LAST page; so do inactive slots (base_lens 0)
+        inside = (base_lens[:, None] > 0) & (
+            positions < block_tables.shape[1] * kv_page_size(k_pages))
+        rows = RaggedRows(
+            tokens.reshape(-1),
+            jnp.where(inside, positions, 0).reshape(-1),
+            jnp.where(inside, positions + 1, 0).reshape(-1),
+            jnp.repeat(block_tables, kq, axis=0))
+        hidden, cache, _ = self.net.ragged_forward(
+            rows, CacheView(k_pages, v_pages, attention_impl="xla"))
+        logits = self.net.ragged_logits(hidden)
+        return logits.reshape(b, kq, -1), cache.k_pages, cache.v_pages
 
 
 class _ChunkedPrefill(Layer):
@@ -1096,7 +1020,6 @@ def _engine_status_provider(ref):
             prop = eng.n_spec_proposed
             out["speculative"] = {
                 "spec_tokens": eng.spec_k,
-                "mode": "slab" if eng.spec_slab else "legacy",
                 "rounds": eng.n_spec_rounds,
                 "draft_steps": eng.n_draft_steps,
                 "draft_tokens_proposed": prop,
@@ -1128,10 +1051,10 @@ class LLMEngine:
     ``draft_net``/``spec_tokens``: SPECULATIVE DECODING — a small
     draft model proposes ``spec_tokens - 1`` tokens per round through
     its own paged cache (sharing the block tables), and ONE target
-    pass verifies them all (`_PagedVerify`). With the default
-    ``spec_slab=True`` the WHOLE round runs inside the fused
-    ``DecodeCarry`` scan: draft probes, the ragged verify window,
-    and masked accept/rollback are one device program, so a single
+    pass verifies them all (`_PagedVerify`). The WHOLE round runs
+    inside the fused ``DecodeCarry`` scan: draft probes, the ragged
+    verify window and masked accept/rollback are one device program,
+    so a single
     dispatch advances up to ``decode_ticks_per_dispatch`` rounds ×
     (K+1) tokens per slot with zero host round-trips. Greedy outputs
     are EXACTLY equal to plain decoding (argmax prefix acceptance);
@@ -1139,14 +1062,11 @@ class LLMEngine:
     accept ``u·q ≤ p``, resample the normalized residual — which is
     distributionally exact (the speculative-sampling theorem,
     test-pinned by Monte-Carlo), with keys folding (nonce, position)
-    only so streams stay failover-deterministic. Slab mode composes
-    with the prefix cache, chunked/mixed prefill, fused slabs and
+    only so streams stay failover-deterministic. It composes with
+    the prefix cache, chunked/mixed prefill, fused slabs and
     ``kv_dtype="int8"`` (the draft pool quantizes too, under its own
-    ``draft_pool`` ledger owner). ``spec_slab=False`` keeps the
-    LEGACY host-paced inline path for one release (greedy-only,
-    one-shot bucketized prefill, no cache, ticks clamped to 1 — the
-    ≥2× dispatch-reduction baseline; see docs/MIGRATION.md). Neither
-    mode composes with lookahead (the round is its own chain).
+    ``draft_pool`` ledger owner), not with ``lookahead`` (the round is
+    its own chain) nor with a model that holds recurrent state.
 
     ``attention_impl``: how the engine programs attend the paged pool
     (:func:`~paddle_tpu.ops.paged_attention.ragged_paged_attention`).
@@ -1185,9 +1105,8 @@ class LLMEngine:
     body is the per-tick program; sampling keys fold (nonce,
     position) only — test-pinned), and N=1 keeps the per-tick path:
     its compiled program carries no scan op. Does not compose with
-    ``lookahead`` (the slab must drain at its boundary). Slab-mode
-    speculative engines fuse N ROUNDS per dispatch; only the legacy
-    inline path (``spec_slab=False``) still clamps N to 1.
+    ``lookahead`` (the slab must drain at its boundary). Speculative
+    engines fuse N ROUNDS per dispatch.
 
     ``mixed_tick``: ONE RAGGED MIXED TICK (default
     ``FLAGS.mixed_tick``) — serve the prefill queue's chunk rows AND
@@ -1206,12 +1125,11 @@ class LLMEngine:
     test-pinned greedy AND seeded, cache on/off). Composes with
     ``decode_ticks_per_dispatch`` (a mixed slab runs N mixed ticks);
     conflicts with ``lookahead`` (drain-at-boundary, like the slab).
-    Slab-mode speculative engines RIDE the mixed tick (prompt chunks
-    prefill both models' pools inside the slab); only the legacy
-    inline path (``spec_slab=False``) clamps it off.
+    Speculative engines RIDE the mixed tick (a draft chunk follows
+    each target chunk, so both models' pools cover every position).
 
-    ``kv_dtype``: KV POOL STORAGE DTYPE (default ``FLAGS.kv_dtype``,
-    falling back to the legacy ``cache_dtype`` argument).
+    ``kv_dtype``: KV POOL STORAGE DTYPE (one of ``KV_DTYPES``; default
+    ``FLAGS.kv_dtype``, and ``"f32"`` where that is empty).
     ``"int8"`` stores QUANTIZED pages with per-token f32 scales
     beside the pool (quantize-on-write in every prefill/decode page
     write, dequantize-in-kernel at every read): ~2x page capacity at
@@ -1224,9 +1142,8 @@ class LLMEngine:
     PERF.md "Ragged mixed tick + int8 KV"). A quantized page rides
     the SAME CoW/digest/refcount discipline as a plain one — the
     prefix cache keys pages by prompt-token digests, not bytes.
-    Composes with ``draft_net`` on the slab path (the draft pool
-    quantizes alongside, with its own ``scale_table`` ledger rows);
-    only the legacy inline path (``spec_slab=False``) still raises.
+    Composes with ``draft_net`` (the draft pool quantizes alongside,
+    with its own ``scale_table`` ledger rows).
 
     ``prefix_cache`` + ``prefill_chunk``: PREFIX CACHING over the page
     pool (full prompt pages become immutable, refcounted, and keyed by
@@ -1241,18 +1158,16 @@ class LLMEngine:
     tokens). Generations are token-identical with the cache on or off
     (shared pages hold bitwise-identical KV; sampling keys depend only
     on request nonce + position — test-pinned). ``prefill_chunk``
-    defaults to the smallest prefill bucket. Slab-mode speculative
-    engines take this chunked path like any other engine (a draft
-    chunk rides along each target chunk so the draft pool covers
-    every position); only LEGACY inline engines (``spec_slab=False``)
-    keep the one-shot prefill and force the cache off.
+    defaults to the smallest prefill bucket. Speculative engines take
+    this chunked path like any other engine (a draft chunk rides
+    along each target chunk so the draft pool covers every position).
     """
 
     def __init__(self, net, max_seqs: int = 8, page_size: int = 16,
                  num_pages: int = 512, max_len: Optional[int] = None,
                  prefill_buckets: Sequence[int] = (64, 256, 1024),
                  eos_token_id: Optional[int] = None,
-                 cache_dtype=jnp.float32, seed: int = 0,
+                 seed: int = 0,
                  lookahead: int = 0,
                  attention_impl: Optional[str] = None,
                  draft_net=None, spec_tokens: int = 4,
@@ -1265,8 +1180,7 @@ class LLMEngine:
                  drain_after: int = 8,
                  decode_ticks_per_dispatch: Optional[int] = None,
                  kv_dtype: Optional[str] = None,
-                 mixed_tick: Optional[bool] = None,
-                 spec_slab: Optional[bool] = None):
+                 mixed_tick: Optional[bool] = None):
         cfg = net.cfg
         self.cfg = cfg
         self.max_seqs = max_seqs
@@ -1280,56 +1194,18 @@ class LLMEngine:
             b for b in prefill_buckets if b <= self.max_len) or \
             [self.max_len]
         net.eval()
-        # KV pool storage dtype: the ``kv_dtype`` knob ("int8" →
-        # quantized pages + per-token scale tables beside the pool,
-        # ~2x page capacity at fixed HBM; "bf16"/"f16"/"f32" → plain
-        # pools) defaults from FLAGS.kv_dtype and falls back to the
-        # legacy ``cache_dtype`` argument when unset.
-        if kv_dtype is None:
-            kv_dtype = _flags.get_flag("kv_dtype") or None
-        legacy_dtype = kv_dtype is None
-        if legacy_dtype:
-            # legacy cache_dtype argument: normalize into the SAME
-            # validation path (cache_dtype=jnp.int8 is the quantized
-            # pool too — it must hit the same guards, not silently
-            # build a QuantizedKV a draft engine can't share)
-            name = jnp.dtype(cache_dtype).name
-            kv_dtype = {"float32": "f32", "bfloat16": "bf16",
-                        "float16": "f16"}.get(name, name)
-        kv_dtype = str(kv_dtype)
-        if kv_dtype in KV_DTYPES:
-            cache_dtype = KV_DTYPES[kv_dtype]
-        elif not legacy_dtype:
+        # KV pool storage dtype: "int8" → quantized pages + per-token
+        # scale tables beside the pool (~2x page capacity at fixed
+        # HBM); "bf16"/"f16"/"f32" → plain pools
+        kv_dtype = str(kv_dtype or _flags.get_flag("kv_dtype") or "f32")
+        if kv_dtype not in KV_DTYPES:
             raise ValueError(
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
                 f"{sorted(KV_DTYPES)}")
-        # else: an exotic legacy cache_dtype (e.g. float64) keeps the
-        # old plain-pool behavior, labeled by its dtype name
-        # ON-DEVICE SPECULATIVE SLAB (default FLAGS.spec_slab): run
-        # draft-K/verify-1 rounds as DecodeCarry scan ticks — K draft
-        # probes, one ragged verify window and the accept/rollback
-        # masking in ONE dispatch per slab. Slab engines ride the
-        # prefix cache, fused slabs, mixed_tick, int8 (quantized
-        # draft pool) and temperature>0 (on-device rejection
-        # sampling); spec_slab=False keeps the legacy host-
-        # orchestrated round one release for rollback (MIGRATION.md).
-        if spec_slab is None:
-            spec_slab = _flags.get_flag("spec_slab")
-        self.spec_slab = bool(spec_slab) and draft_net is not None
-        if kv_dtype == "int8" and draft_net is not None \
-                and not self.spec_slab:
-            raise ValueError(
-                "kv_dtype='int8' does not compose with the LEGACY "
-                "inline speculative path (spec_slab=False): its "
-                "draft pool is a plain array with no scale tables. "
-                "The on-device slab path (spec_slab=True, the "
-                "default) runs a quantized draft pool — use it, or "
-                "drop int8")
         self.kv_dtype = kv_dtype
         kv_layers, kv_heads, kv_hd = net.kv_cache_spec()
         self.k_pages = kv_zeros(
-            (kv_layers, num_pages, page_size, kv_heads, kv_hd),
-            cache_dtype)
+            (kv_layers, num_pages, page_size, kv_heads, kv_hd), kv_dtype)
         self.v_pages = jax.tree_util.tree_map(jnp.zeros_like,
                                               self.k_pages)
         # host-side control plane (numpy: mutated by the allocator)
@@ -1390,17 +1266,14 @@ class LLMEngine:
         self.lookahead = int(lookahead)
         # DEVICE-RESIDENT DECODE LOOP: fuse N decode ticks into one
         # lax.scan dispatch (DecodeCarry docs the on-device state).
-        # Defaults from FLAGS.decode_ticks_per_dispatch. Slab-mode
-        # speculative engines COMPOSE: a spec slab runs N whole
-        # draft+verify rounds per dispatch (up to N*K tokens); only
-        # the legacy host-orchestrated round structure clamps to 1.
+        # Defaults from FLAGS.decode_ticks_per_dispatch. Speculative
+        # engines COMPOSE: a spec slab runs N whole draft+verify
+        # rounds per dispatch (up to N*K tokens).
         if decode_ticks_per_dispatch is None:
             decode_ticks_per_dispatch = _flags.get_flag(
                 "decode_ticks_per_dispatch")
         self.decode_ticks_per_dispatch = max(
             1, int(decode_ticks_per_dispatch))
-        if draft_net is not None and not self.spec_slab:
-            self.decode_ticks_per_dispatch = 1
         if self.decode_ticks_per_dispatch > 1 and self.lookahead:
             raise ValueError(
                 "decode_ticks_per_dispatch > 1 does not compose with "
@@ -1413,18 +1286,16 @@ class LLMEngine:
         # alternating prefill/decode tick loop; the ragged entry
         # point makes "mixed" a batch property). Default ON
         # (FLAGS.mixed_tick): the flip is safe because token streams
-        # are pinned identical to the legacy two-op path. LEGACY
-        # speculative engines keep their own round structure (clamped
-        # off); slab-mode spec engines ride mixed slabs for prefill.
-        # lookahead conflicts for the same drain-at-boundary reason
+        # are pinned identical to the two-op path. Speculative
+        # engines ride mixed slabs for prefill. lookahead conflicts
+        # for the same drain-at-boundary reason
         # as the slab — but only an EXPLICIT mixed_tick=True raises:
         # the flag DEFAULT silently yields to lookahead, so the flip
         # cannot break existing lookahead deployments.
         mixed_explicit = mixed_tick is not None
         if mixed_tick is None:
             mixed_tick = _flags.get_flag("mixed_tick")
-        self.mixed_tick = bool(mixed_tick) and \
-            (draft_net is None or self.spec_slab)
+        self.mixed_tick = bool(mixed_tick)
         if self.mixed_tick and self.lookahead:
             if mixed_explicit:
                 raise ValueError(
@@ -1483,12 +1354,11 @@ class LLMEngine:
         if attention_impl not in ("xla", "pallas"):
             raise ValueError(f"unknown attention_impl {attention_impl!r}")
         self.attention_impl = attention_impl
-        # speculative decoding (greedy-only v1): a draft model proposes
-        # spec_tokens-1 tokens per round, ONE target pass verifies them
-        # (prefix acceptance is exact for greedy — test-pinned), so the
-        # big model runs once per accepted run instead of once per
-        # token. The draft shares the target's page allocator/block
-        # tables; its pools have its own kv dims.
+        # speculative decoding: a draft model proposes spec_tokens-1
+        # tokens per round, ONE target pass verifies them, so the big
+        # model runs once per accepted run instead of once per token.
+        # The draft shares the target's page allocator/block tables;
+        # its pools have its own kv dims.
         self.spec_k = 0
         if draft_net is not None:
             if lookahead:
@@ -1510,48 +1380,14 @@ class LLMEngine:
             # deferred follow-on, distinct "draft_pool" ledger rows
             self.draft_k_pages = kv_zeros(
                 (d_layers, num_pages, page_size, d_heads, d_hd),
-                cache_dtype)
+                kv_dtype)
             self.draft_v_pages = jax.tree_util.tree_map(
                 jnp.zeros_like, self.draft_k_pages)
-            ddecode = _PagedDecode(draft_net, attention_impl)
-            dprefill = _PagedPrefill(draft_net)
+            dprobe = _PagedDecode(draft_net, attention_impl,
+                                  return_logits=True)
             self._draft_params, self._draft_buffers = \
-                split_state(ddecode)
-
-            def draft_decode_fn(params, buffers, tokens, positions,
-                                tables, lens, kp, vp, temps, nonces,
-                                key):
-                (out, _) = functional_call(
-                    ddecode, params, buffers, tokens, positions,
-                    tables, lens, kp, vp, temps, nonces, key,
-                    training=False)
-                return out
-
-            def draft_prefill_fn(params, buffers, ids, true_len, row,
-                                 kp, vp, temp, nonce, key):
-                (out, _) = functional_call(
-                    dprefill, params, buffers, ids, true_len, row, kp,
-                    vp, temp, nonce, key, training=False)
-                return out
-
+                split_state(dprobe)
             verify = _PagedVerify(net)
-
-            def verify_fn(params, buffers, tokens, base_lens, tables,
-                          kp, vp):
-                # legacy round contract: the greedy choice per window
-                # position (argmax applied HERE — _PagedVerify itself
-                # now returns the [B, K, V] logits the slab's
-                # rejection sampler needs)
-                ((lg, kp, vp), _) = functional_call(
-                    verify, params, buffers, tokens, base_lens,
-                    tables, kp, vp, training=False)
-                return jnp.argmax(lg, axis=-1), kp, vp
-
-            self._draft_decode_fn = self._jit(draft_decode_fn,
-                                              donate_argnums=(6, 7))
-            self._draft_prefill_fn = self._jit(draft_prefill_fn,
-                                               donate_argnums=(5, 6))
-            self._verify_fn = self._jit(verify_fn, donate_argnums=(5, 6))
         self.n_spec_rounds = 0
         self.n_draft_steps = 0
         self.n_spec_proposed = 0   # draft tokens offered to verify
@@ -1686,107 +1522,89 @@ class LLMEngine:
             draft_hash = fh.hexdigest()
         self.knob_fingerprint = {
             "kv_dtype": self.kv_dtype, "spec_k": self.spec_k,
-            "spec_slab": bool(self.spec_slab), "draft": draft_hash}
+            "draft": draft_hash}
         # scope the drift table files this engine's verdicts under
         # (replica_main overrides it with the replica's fleet name)
         self.audit_scope = "engine"
 
-        if self.spec_k and not self.spec_slab:
-            # LEGACY speculative engines keep the inline one-shot
-            # prefill (round-synced anyway) and run without a prefix
-            # cache; slab-mode spec engines take the chunked branch
-            # below like any other engine
-            prefill = _PagedPrefill(net)
+        chunked = _ChunkedPrefill(net, attention_impl)
 
-            def prefill_fn(params, buffers, ids, true_len, row, kp, vp,
-                           temp, nonce, key):
-                (out, _) = functional_call(
-                    prefill, params, buffers, ids, true_len, row, kp,
-                    vp, temp, nonce, key, training=False)
-                return out
+        def chunk_fn(params, buffers, tokens, positions, limits,
+                     tables, sample_idx, sample_pos, kp, vp, temps,
+                     nonces, key, *state):
+            (out, _) = functional_call(
+                chunked, params, buffers, tokens, positions,
+                limits, tables, sample_idx, sample_pos, kp, vp,
+                temps, nonces, key, *state, training=False)
+            return fetched(out)
 
-            self._prefill_fn = self._jit(prefill_fn,
-                                         donate_argnums=(5, 6))
-            self._cache = None
-        else:
-            chunked = _ChunkedPrefill(net, attention_impl)
+        self._chunk_fn = self._jit(
+            chunk_fn, donate_argnums=(8, 9) + ((15, 16) if has_state
+                                               else ()))
+        from .prefix_cache import PrefixCache
+        self._cache = PrefixCache(page_size) if prefix_cache \
+            else None
 
-            def chunk_fn(params, buffers, tokens, positions, limits,
-                         tables, sample_idx, sample_pos, kp, vp, temps,
-                         nonces, key, *state):
-                (out, _) = functional_call(
-                    chunked, params, buffers, tokens, positions,
-                    limits, tables, sample_idx, sample_pos, kp, vp,
-                    temps, nonces, key, *state, training=False)
-                return fetched(out)
+        # THE MIXED SLAB: n_ticks ragged mixed prefill+decode
+        # ticks as ONE program. Each tick consumes its slice of
+        # the pre-packed prefill schedule (xs) and the decode
+        # carry; a slot whose prompt COMPLETES at tick j gets its
+        # sampled first token, start position and emission budget
+        # installed into the carry — from tick j+1 it decodes on
+        # device, with zero host dispatches between the phases.
+        # Finished/inactive slots are masked no-ops exactly like
+        # the pure-decode slab; a tick with neither budgets nor
+        # prefill rows is skipped by the cond.
+        mixed = _MixedTick(net, attention_impl, self.state_impl)
 
-            self._chunk_fn = self._jit(
-                chunk_fn, donate_argnums=(8, 9) + ((15, 16) if has_state
-                                                   else ()))
-            from .prefix_cache import PrefixCache
-            self._cache = PrefixCache(page_size) if prefix_cache \
-                else None
+        def mixed_fn(params, buffers, carry, xs, tables, temps,
+                     nonces, key, n_ticks):
+            def tick(c, x):
+                def live_step(c):
+                    active = c.budgets > 0
+                    lens = jnp.where(active, c.positions + 1, 0)
+                    (out, _) = functional_call(
+                        mixed, params, buffers, x["tok"],
+                        x["pos"], x["lim"], x["tbl"], x["fin"],
+                        x["row"], x["fpos"], c.tokens,
+                        c.positions, lens, tables, c.k_pages,
+                        c.v_pages, temps, nonces, key,
+                        *((x["seg"], x["segrows"]) if has_state
+                          else ()), *carry_state(c),
+                        training=False)
+                    nxt, aux, lanes = tick_outputs(out)
+                    fin = x["fin"]
+                    tokens = jnp.where(active | fin, nxt, c.tokens)
+                    budgets = jnp.where(active, c.budgets - 1,
+                                        c.budgets)
+                    # prompt completed this tick: install the
+                    # slab-entry grant (first token just emitted,
+                    # so grant - 1 remain)
+                    budgets = jnp.where(fin, x["grant"] - 1,
+                                        budgets)
+                    budgets = jnp.where(
+                        (active | fin) & (nxt == eos_tok), 0,
+                        budgets)
+                    positions = jnp.where(active, c.positions + 1,
+                                          c.positions)
+                    # next write position = len(prompt)
+                    positions = jnp.where(fin, x["fpos"] + 1,
+                                          positions)
+                    return DecodeCarry(
+                        tokens=tokens, positions=positions,
+                        budgets=budgets, **lanes), aux
 
-            # THE MIXED SLAB: n_ticks ragged mixed prefill+decode
-            # ticks as ONE program. Each tick consumes its slice of
-            # the pre-packed prefill schedule (xs) and the decode
-            # carry; a slot whose prompt COMPLETES at tick j gets its
-            # sampled first token, start position and emission budget
-            # installed into the carry — from tick j+1 it decodes on
-            # device, with zero host dispatches between the phases.
-            # Finished/inactive slots are masked no-ops exactly like
-            # the pure-decode slab; a tick with neither budgets nor
-            # prefill rows is skipped by the cond.
-            mixed = _MixedTick(net, attention_impl, self.state_impl)
+                run = jnp.any(c.budgets > 0) | jnp.any(x["lim"] > 0)
+                return scan_tick(live_step, run, c)
 
-            def mixed_fn(params, buffers, carry, xs, tables, temps,
-                         nonces, key, n_ticks):
-                def tick(c, x):
-                    def live_step(c):
-                        active = c.budgets > 0
-                        lens = jnp.where(active, c.positions + 1, 0)
-                        (out, _) = functional_call(
-                            mixed, params, buffers, x["tok"],
-                            x["pos"], x["lim"], x["tbl"], x["fin"],
-                            x["row"], x["fpos"], c.tokens,
-                            c.positions, lens, tables, c.k_pages,
-                            c.v_pages, temps, nonces, key,
-                            *((x["seg"], x["segrows"]) if has_state
-                              else ()), *carry_state(c),
-                            training=False)
-                        nxt, aux, lanes = tick_outputs(out)
-                        fin = x["fin"]
-                        tokens = jnp.where(active | fin, nxt, c.tokens)
-                        budgets = jnp.where(active, c.budgets - 1,
-                                            c.budgets)
-                        # prompt completed this tick: install the
-                        # slab-entry grant (first token just emitted,
-                        # so grant - 1 remain)
-                        budgets = jnp.where(fin, x["grant"] - 1,
-                                            budgets)
-                        budgets = jnp.where(
-                            (active | fin) & (nxt == eos_tok), 0,
-                            budgets)
-                        positions = jnp.where(active, c.positions + 1,
-                                              c.positions)
-                        # next write position = len(prompt)
-                        positions = jnp.where(fin, x["fpos"] + 1,
-                                              positions)
-                        return DecodeCarry(
-                            tokens=tokens, positions=positions,
-                            budgets=budgets, **lanes), aux
+            carry, toks = jax.lax.scan(tick, carry, xs,
+                                       length=n_ticks)
+            return toks, carry
 
-                    run = jnp.any(c.budgets > 0) | jnp.any(x["lim"] > 0)
-                    return scan_tick(live_step, run, c)
+        self._mixed_fn = self._jit(mixed_fn, static_argnums=(8,),
+                                   donate_argnums=(2,))
 
-                carry, toks = jax.lax.scan(tick, carry, xs,
-                                           length=n_ticks)
-                return toks, carry
-
-            self._mixed_fn = self._jit(mixed_fn, static_argnums=(8,),
-                                       donate_argnums=(2,))
-
-        if self.spec_slab:
+        if draft_net is not None:
             # draft-side chunked prefill: every prompt chunk row ALSO
             # runs through the draft model into ITS pool (same token/
             # position/limit/table schedule; the sampled token is
@@ -1818,14 +1636,11 @@ class LLMEngine:
             # round-trips. `cov` [B] is the page-covered position
             # frontier the host pre-reserved: a window straddling it
             # has its overflow writes routed to scratch (table entry
-            # 0) and its acceptance clamped by cap, exactly the
-            # legacy round's cache-capacity rule. Rejected draft KV
+            # 0) and its acceptance clamped by cap. Rejected draft KV
             # needs no host rollback — it sits beyond the position
             # frontier and every later tick overwrites it before any
             # read. Masked no-ops (budget 0) and on-device EOS follow
             # the pure-decode slab discipline.
-            dprobe = _PagedDecode(draft_net, attention_impl,
-                                  return_logits=True)
             spec_K = self.spec_k
 
             def spec_slab_fn(params, buffers, dparams, dbuffers,
@@ -2103,24 +1918,8 @@ class LLMEngine:
             raise ValueError(
                 f"prompt {len(prompt_ids)} + max_new_tokens "
                 f"{max_new_tokens} exceeds engine max_len {self.max_len}")
-        if self.spec_k and not self.spec_slab \
-                and len(prompt_ids) > self.prefill_buckets[-1]:
-            # only the LEGACY speculative INLINE prefill is bucket-
-            # shaped; the chunked ragged path (all other engines,
-            # slab-mode spec included) handles any length up to
-            # max_len
-            raise ValueError(
-                f"prompt {len(prompt_ids)} exceeds the largest prefill "
-                f"bucket {self.prefill_buckets[-1]}; raise "
-                f"prefill_buckets")
         if not prompt_ids:
             raise ValueError("empty prompt")
-        if self.spec_k and not self.spec_slab and temperature > 0.0:
-            raise ValueError(
-                "the LEGACY speculative path (spec_slab=False) is "
-                "greedy-only; slab engines (spec_slab=True, the "
-                "default) serve temperature>0 via on-device "
-                "rejection sampling")
         if nonce is not None and not 0 <= int(nonce) < 2 ** 31:
             raise ValueError(f"nonce {nonce} out of int32 range")
         req = _Request(prompt_ids, max_new_tokens, temperature)
@@ -2676,12 +2475,6 @@ class LLMEngine:
                 self._health = "healthy"
         self._m["health"].set(_HEALTH_CODE[self._health])
 
-    def _bucket(self, n: int) -> int:
-        for b in self.prefill_buckets:
-            if n <= b:
-                return b
-        return self.prefill_buckets[-1]
-
     def _guard_recompiles(self, kind: str, sig=()) -> bool:
         """Engine analog of ``Model._guard_recompiles`` (PR 3's
         step-vs-loop discipline): one signature per distinct compiled
@@ -2692,8 +2485,7 @@ class LLMEngine:
         ``"mixed_tick"`` (the ragged mixed prefill+decode slab, one
         per realized length — the kind decode_step/decode_loop/
         prefill signatures collapse into when mixed_tick serves both
-        phases), ``"prefill"`` (chunk or inline bucket). Bounded at
-        4096 like
+        phases), ``"prefill"`` (a chunk). Bounded at 4096 like
         the Model guard; FLAGS.recompile_warn_threshold 0 disables.
         Returns True when the signature is new (a compile is
         coming)."""
@@ -2834,8 +2626,6 @@ class LLMEngine:
         in ``_drain_one`` like any decode token."""
         if self._health == "draining":
             return "shed"
-        if self.spec_k and not self.spec_slab:
-            return self._admit_inline(req)
         n = len(req.prompt)
         need_total = -(-n // self.page_size)
         if need_total > min(self.num_pages - 1, self.pages_per_seq):
@@ -2909,93 +2699,6 @@ class LLMEngine:
             req.spans["root"].add_event(
                 "admitted", {"slot": slot,
                              "cache_hit_tokens": req.n_cached}, ts=tp)
-        return "ok"
-
-    def _admit_inline(self, req: _Request) -> str:
-        """Legacy inline one-shot prefill (speculative engines only:
-        the draft pool shares block tables and would need the same
-        prefix treatment; rounds are host-synced anyway)."""
-        n = len(req.prompt)
-        need = -(-n // self.page_size)
-        if need > min(self.num_pages - 1, self.pages_per_seq):
-            return "never"
-        slot = next((i for i, s in enumerate(self._slots) if s is None),
-                    None)
-        if slot is None:
-            return "retry"
-        if need > len(self._free_pages):
-            active = any(s is not None for s in self._slots)
-            return "retry" if active else "never"
-        qdt = time.monotonic() - req.t_enqueued
-        self._m["queue_wait"].observe(qdt)
-        if _goodput.enabled():
-            # wall-clock queue residency (the ledger sweep unions
-            # overlapping requests: N queued seconds over one wall
-            # second is one second of queue_wait)
-            _goodput.note("queue_wait", qdt)
-        if req.spans is not None:
-            tp = time.perf_counter()
-            req.spans["queue"].end(tp)
-            req.spans["prefill"] = _trace.start_span(
-                "llm.prefill", parent=req.spans["root"], t0=tp,
-                attrs={"slot": slot, "prompt_tokens": n,
-                       "inline": True})
-        # the slot table owns the request BEFORE any page allocation
-        # or device call: if the blocking prefill below raises, the
-        # loop handler's slot scan reclaims the allocated pages and
-        # applies the device-retry budget (otherwise an inline prefill
-        # error would leak its pages and retry budget-free)
-        req.slot = slot
-        self._slots[slot] = req
-        self._dequeue_accounting(req)
-        for idx in range(need):
-            self.block_tables[slot, idx] = self._alloc_page()
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-        bucket = self._bucket(n)
-        self._guard_recompiles("prefill", (bucket,))
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :n] = req.prompt
-        nxt, self.k_pages, self.v_pages = self._prefill_fn(
-            self._params, self._buffers, jnp.asarray(ids),
-            jnp.int32(n), jnp.asarray(self.block_tables[slot]),
-            self.k_pages, self.v_pages, jnp.float32(req.temperature),
-            jnp.int32(req.nonce), self._key)
-        # the draft needs the prompt's KV too (its own cache dims,
-        # SAME block table); its prefill token is discarded — the
-        # target owns sampling
-        _, self.draft_k_pages, self.draft_v_pages = \
-            self._draft_prefill_fn(
-                self._draft_params, self._draft_buffers,
-                jnp.asarray(ids), jnp.int32(n),
-                jnp.asarray(self.block_tables[slot]),
-                self.draft_k_pages, self.draft_v_pages,
-                jnp.float32(0.0), jnp.int32(req.nonce), self._key)
-        self._count_dispatch(2)
-        # NO host sync here (this was the last admission-path blocking
-        # fetch): the first token chains into _tokens_dev on device
-        # and is harvested by the async drain like any decode token —
-        # TTFT is observed at the fetch on every admission path
-        self._tokens_dev = self._tokens_dev.at[slot].set(nxt)
-        self._issue_seq += 1
-        self._inflight.append((self._issue_seq, [slot],
-                               self._tokens_dev, "p", None))
-        req.prefill_done = True
-        if req.spans is not None:
-            # the prompt is computed (dispatched); what remains before
-            # the first token reaches the host is the async drain —
-            # its own phase, exactly like the chunked path
-            tp = time.perf_counter()
-            req.spans["prefill"].end(tp)
-            req.spans["first_token"] = _trace.start_span(
-                "llm.first_token", parent=req.spans["root"], t0=tp)
-        self.context_lens[slot] = n
-        self.temperatures[slot] = req.temperature
-        self._nonces[slot] = req.nonce
-        self.n_prompt_tokens += n
-        self._m["prompt_tokens"].inc(n)
-        self._m["prefills"].inc()
-        self._update_kv_gauge()
         return "ok"
 
     def _harvest(self, slot: int) -> bool:
@@ -3085,7 +2788,7 @@ class LLMEngine:
             rows = self._chunk_limits(chunks)
             self._stamp_kv_pages(ph, (rows, T, self.attention_impl),
                                  *self._draft_chunk_call(rows, T))
-        if self.spec_k and self.spec_slab:
+        if self.spec_k:
             # draft ride-along: the SAME packed chunk schedule runs
             # through the draft net so the draft pool holds valid KV
             # for every prompt position a later verify window attends
@@ -3185,15 +2888,14 @@ class LLMEngine:
                     self._police_slots()
                 self._m["queue_depth"].set(self._n_queued)
                 busy = False
-                mixed = self.mixed_tick and bool(self._prefill_q) \
-                    and (not self.spec_k or self.spec_slab)
+                mixed = self.mixed_tick and bool(self._prefill_q)
                 if mixed:
                     # ONE fused mixed slab: the prefill queue's chunk
                     # rows AND the live slots' decode ticks ride one
                     # ragged dispatch — a prompt completing at tick j
                     # starts decoding at tick j+1 on device, with
-                    # zero host dispatches between the phases
-                    # spec-slab engines ride the mixed dispatch for
+                    # zero host dispatches between the phases.
+                    # Speculative engines ride the mixed dispatch for
                     # prompt completion only (live=[]): their decode
                     # advances through _issue_spec_slab, whose rounds
                     # keep the draft pool position-complete (a mixed
@@ -3203,7 +2905,7 @@ class LLMEngine:
                         [] if self.spec_k else self._live_slots())
                     busy = True
                 elif self._prefill_q:
-                    # LEGACY two-op tick (mixed_tick off — kept as
+                    # two-op tick (mixed_tick off — kept as
                     # the parity baseline): ONE chunk of prefill,
                     # then (below) ONE decode step for the live
                     # batch: a long prompt's chunks interleave with
@@ -3216,21 +2918,16 @@ class LLMEngine:
                 live = self._live_slots() if self.spec_k or not mixed \
                     else []
                 if live and self.spec_k:
-                    # both speculative paths plan from realized state:
+                    # the speculative slab plans from realized state:
                     # a mixed/prefill record's async first token must
                     # land in req.tokens (in issue order, TTFT at the
                     # fetch) before budgets are computed, and it may
-                    # already close the slot (they re-filter `live`)
+                    # already close the slot (it re-filters `live`).
+                    # Then on-device rounds: draft-K + verify + accept
+                    # all inside ONE scan slab dispatch of N rounds
                     while self._inflight:
                         self._drain_one()
-                if live and self.spec_k and self.spec_slab:
-                    # on-device rounds: draft-K + verify + accept all
-                    # inside ONE scan slab dispatch of N rounds
                     self._issue_spec_slab(live)
-                    busy = True
-                elif live and self.spec_k:
-                    with _trace.phase("llm.issue.spec") as ph:
-                        self._spec_round(live, ph)
                     busy = True
                 elif live and self.decode_ticks_per_dispatch > 1:
                     # device-resident decode loop: N ticks, ONE
@@ -3647,8 +3344,8 @@ class LLMEngine:
 
     def _draft_chunk_call(self, rows, padded_rows) -> List[tuple]:
         """The draft model's ride-along over the same chunk rows (a
-        spec-slab engine), as a call of :meth:`_stamp_kv_pages`."""
-        if not (self.spec_k and self.spec_slab):
+        speculative engine), as a call of :meth:`_stamp_kv_pages`."""
+        if not self.spec_k:
             return []
         return [([(("draft", slot), limit) for slot, limit in rows],
                  padded_rows, self.attention_impl)]
@@ -4044,7 +3741,7 @@ class LLMEngine:
             toks, carry = self._mixed_fn(*mixed_args)
             self._count_dispatch()
             self._take_carry(carry)
-            if self.spec_k and self.spec_slab:
+            if self.spec_k:
                 # draft ride-along over the slab's WHOLE packed chunk
                 # schedule, flattened to one ragged chunk (padding rows
                 # carry zero tables → scratch page 0): same coverage
@@ -4121,17 +3818,15 @@ class LLMEngine:
         draft-K/verify-1 ROUNDS for the live slots as ONE fused-scan
         program (``_spec_slab_fn``): one dispatch advances each slot
         by up to (K-1)+1 committed tokens PER ROUND with zero host
-        round-trips inside the slab — vs the legacy path's K+1
-        dispatches per single round.
+        round-trips inside the slab.
 
         Host work at slab entry mirrors :meth:`_issue_slab`: per-slot
         emission budgets (length completion provable here) and
         KV-page pre-reservation for every position the slab could
         commit (up to N*K tokens). ``cov[slot]`` carries the covered
         position frontier to the device, which clamps each round's
-        acceptance by ``cap = cov - position`` — the legacy round's
-        cache-capacity rule, computed once at entry instead of per
-        round. The invariant ``budget <= covered`` keeps ``cap >= 1``
+        acceptance by ``cap = cov - position``, computed once at entry
+        instead of per round. The invariant ``budget <= covered`` keeps ``cap >= 1``
         for every active slot, so no slab shrink is needed and the
         program length stays N for a stable compile signature.
         Over-reserved pages (low acceptance) stay with their slots
@@ -4248,9 +3943,8 @@ class LLMEngine:
                                       len(req.tokens) - 1, tok)
         self.n_tokens += 1
         if req.t_first is None:
-            # async first token (chunked or inline prefill): admission
-            # never blocked on the device; TTFT lands here, at the
-            # fetch
+            # async first token: admission never blocked on the
+            # device; TTFT lands here, at the fetch
             req.t_first = time.monotonic()
             self._m["ttft"].observe(req.t_first - req.t_submit)
             if req.spans is not None:
@@ -4393,8 +4087,7 @@ class LLMEngine:
         the entry budgets, exactly the :meth:`_drain_slab` discipline
         with a K-wide token lane per round. Tokens past a slot's EOS
         or a cancelled request's close are masked no-ops and never
-        surfaced. Accounts the round/proposal/acceptance counters the
-        legacy host round keeps per dispatch."""
+        surfaced. Accounts the round/proposal/acceptance counters."""
         remaining = dict(meta["budgets"])
         pos0 = meta["pos0"]
         K = self.spec_k
@@ -4482,125 +4175,6 @@ class LLMEngine:
         if emitted:
             self._m["tokens"].inc(emitted)
         self._last_fetch_t = now
-
-    def _spec_round(self, live: List[int], ph=_trace.NOOP_SPAN):
-        """One speculative round: K draft steps propose, ONE target pass
-        verifies; the greedy prefix-acceptance commits 1..K tokens. The
-        K-th draft step exists for cache coverage (it writes d_{K-1}'s KV
-        so a fully-accepted round leaves no draft-cache gap); its output
-        is discarded."""
-        # the loop drained first: a just-admitted request's async
-        # first token has landed in req.tokens BEFORE this round's
-        # accepted tokens are appended — and that first token's
-        # EOS/length may already have closed the slot, so the live set
-        # is re-filtered here
-        live = [s for s in live if self._slots[s] is not None
-                and not self._slots[s].closing]
-        if not live:
-            self._maybe_finalize()
-            return
-        K = self.spec_k
-        # per-slot CACHE CAPACITY this round: how many of positions
-        # base..base+K-1 are actually writable (max_len + pages).
-        # cap < K does NOT close the slot — acceptance is clamped to
-        # cap on the host instead, so a request near its length/page
-        # limit still advances exactly like plain decode (parity);
-        # only cap == 0 (the NEXT token can't be cached — the same
-        # condition plain decode closes on) truncates
-        caps = {}
-        for slot in list(live):
-            req = self._slots[slot]
-            base = int(self.context_lens[slot])
-            cap = 0
-            for pos in range(base, base + K):
-                if pos >= self.max_len or not self._ensure_page(slot,
-                                                                pos):
-                    break
-                cap += 1
-            if cap == 0:
-                req.truncated = len(req.tokens) < req.max_new_tokens
-                self._begin_close(slot)
-                live.remove(slot)
-            else:
-                caps[slot] = cap
-        if not live:
-            self._maybe_finalize()
-            return
-
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-        base_arr = np.zeros((self.max_seqs,), np.int32)
-        for slot in live:
-            base_arr[slot] = self.context_lens[slot]
-        tables = jnp.asarray(self.block_tables)
-        zeros_temp = jnp.zeros((self.max_seqs,), jnp.float32)
-        cur = self._tokens_dev
-        tok_cols = [cur]
-        for j in range(K):
-            pos = np.where(base_arr > 0, base_arr + j, 0).astype(np.int32)
-            lens = np.where(base_arr > 0, base_arr + j + 1,
-                            0).astype(np.int32)
-            cur, self.draft_k_pages, self.draft_v_pages = \
-                self._draft_decode_fn(
-                    self._draft_params, self._draft_buffers, cur,
-                    jnp.asarray(pos), tables, jnp.asarray(lens),
-                    self.draft_k_pages, self.draft_v_pages, zeros_temp,
-                    jnp.asarray(self._nonces), self._key)
-            self.n_draft_steps += 1
-            self._count_dispatch()
-            if j < K - 1:
-                tok_cols.append(cur)
-        tokens_mat = jnp.stack(tok_cols, axis=1)            # [B, K]
-        greedy, self.k_pages, self.v_pages = self._verify_fn(
-            self._params, self._buffers, tokens_mat,
-            jnp.asarray(base_arr), tables, self.k_pages, self.v_pages)
-        self._count_dispatch()
-        self._stamp_spec_kv_pages(ph, live, base_arr, 1)
-        self.n_steps += 1
-        self.n_spec_rounds += 1
-        self._m["spec_rounds"].inc()
-        self._m["occupancy"].observe(len(live) / self.max_seqs)
-        self._update_kv_gauge()
-        host_g = np.asarray(greedy)                         # the round sync
-        host_d = np.asarray(tokens_mat)
-        emitted = 0
-        new_last = np.asarray(self._tokens_dev).copy()
-        for slot in live:
-            g, d = host_g[slot], host_d[slot]
-            # accept within cache capacity: positions >= base+cap were
-            # scattered to the scratch page, so tokens there (and the
-            # queries after them) are not backed by real KV
-            i = 0
-            while i < min(K - 1, caps[slot] - 1) and d[i + 1] == g[i]:
-                i += 1
-            self.n_spec_proposed += K - 1
-            self.n_spec_accepted += i
-            req = self._slots[slot]
-            for tok in list(d[1:i + 1]) + [int(g[i])]:
-                req.tokens.append(int(tok))
-                if _audit.enabled():
-                    # legacy inline spec emits accepted runs here,
-                    # not through _deliver_token — same chain rule
-                    req.chain = _audit.extend(req.chain, req.nonce,
-                                              len(req.tokens) - 1,
-                                              int(tok))
-                self.n_tokens += 1
-                emitted += 1
-                if self._harvest(slot):
-                    break
-            # cached-valid count advances over t0..d_i only; the bonus
-            # g_i is next round's input (cached when fed)
-            self.context_lens[slot] = int(base_arr[slot]) + i + 1
-            new_last[slot] = int(g[i])
-            if self._harvest(slot):
-                self._begin_close(slot)
-        self._tokens_dev = jnp.asarray(new_last)
-        self._m["spec_draft_tokens"].inc(len(live) * (K - 1))
-        if self.n_spec_proposed:
-            self._m["spec_accept_rate"].set(
-                self.n_spec_accepted / self.n_spec_proposed)
-        self._observe_step(emitted)
-        self._maybe_finalize()
 
 
 def serve_llm(engine, host: str = "127.0.0.1", port: int = 0):

@@ -225,8 +225,7 @@ def test_engine_result_digest_is_the_chain_of_its_stream():
                          temperature=0.8).result(timeout=300)
     assert out["stream_digest"] == \
         audit.chain_of(out["nonce"], out["output_ids"]).hex()
-    assert set(out["knobs"]) == {"kv_dtype", "spec_k", "spec_slab",
-                                 "draft"}
+    assert set(out["knobs"]) == {"kv_dtype", "spec_k", "draft"}
 
 
 def test_disabled_audit_adds_no_result_keys_and_no_ops():

@@ -334,7 +334,7 @@ def test_serve_llm_response_carries_stream_integrity_headers(llm_http):
         audit.chain_of(99, out["output_ids"]).hex()
     knobs = _json.loads(hdrs["X-Engine-Knobs"])
     assert knobs == out["knobs"]
-    assert set(knobs) == {"kv_dtype", "spec_k", "spec_slab", "draft"}
+    assert set(knobs) == {"kv_dtype", "spec_k", "draft"}
 
 
 # ---- real-plugin concurrency (skip-on-busy, like test_inference_native)

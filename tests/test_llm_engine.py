@@ -155,14 +155,14 @@ def test_http_serving_concurrent_clients():
 def test_engine_rejects_impossible_requests_cleanly():
     """Failure paths resolve, never hang: a prompt that can NEVER fit
     the page pool fails its future (the chunked path accepts ANY
-    prompt length up to max_len — prefill buckets only bound the
-    speculative inline path); a device-side error mid-serving fails
+    prompt length up to max_len, whatever the prefill buckets); a
+    device-side error mid-serving fails
     in-flight requests but leaves the engine serving."""
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=4,
                    prefill_buckets=(16,)) as eng:
-        # 20 tokens clear the (spec-only) bucket bound on the chunked
-        # path, but need 5 pages where only 3 exist -> future fails
+        # 20 tokens exceed the largest bucket, which bounds nothing,
+        # but need 5 pages where only 3 exist -> future fails
         fut = eng.submit(list(range(20)), max_new_tokens=2)
         with pytest.raises(ValueError, match="cannot fit"):
             fut.result(timeout=60)

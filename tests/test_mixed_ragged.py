@@ -496,36 +496,19 @@ def test_kv_dtype_and_mixed_knob_validation():
                       max_position_embeddings=96, hidden_dropout=0.0,
                       attention_dropout=0.0)
     draft = GPTForCausalLM(dcfg)
-    # int8 + draft_net composes on the slab path (the quantized draft
-    # pool); ONLY the legacy inline path still raises its typed error
-    with pytest.raises(ValueError, match="spec_slab"):
-        LLMEngine(net, max_seqs=2, page_size=4, num_pages=16,
-                  prefill_buckets=(16,), draft_net=draft,
-                  kv_dtype="int8", spec_slab=False)
+    # int8 + draft_net composes (the quantized draft pool), and a
+    # speculative engine keeps its slab width
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
                     prefill_buckets=(16,), draft_net=draft,
-                    kv_dtype="int8")
-    assert eng.spec_slab and isinstance(eng.draft_k_pages, QuantizedKV)
-    assert eng.decode_ticks_per_dispatch >= 1   # no legacy ticks clamp
+                    kv_dtype="int8", decode_ticks_per_dispatch=4)
+    assert eng.spec_k and isinstance(eng.draft_k_pages, QuantizedKV)
+    assert eng.decode_ticks_per_dispatch == 4
     eng.close()
-    # a slab spec engine RIDES mixed_tick; a LEGACY spec engine
-    # silently clamps it off (its rounds are their own fusion),
-    # mirroring the slab-knob clamp
+    # a speculative engine RIDES mixed_tick
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
                     prefill_buckets=(16,), draft_net=draft,
                     mixed_tick=True)
     assert eng.mixed_tick is True
-    eng.close()
-    eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
-                    prefill_buckets=(16,), draft_net=draft,
-                    mixed_tick=True, spec_slab=False)
-    assert eng.mixed_tick is False
-    eng.close()
-    # the legacy path ALSO still clamps decode_ticks_per_dispatch
-    eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
-                    prefill_buckets=(16,), draft_net=draft,
-                    decode_ticks_per_dispatch=4, spec_slab=False)
-    assert eng.decode_ticks_per_dispatch == 1
     eng.close()
     # flags feed the defaults
     from paddle_tpu.core import flags

@@ -553,11 +553,11 @@ def run_clean(net, prompt, n_new):
                               timeout=120)["output_ids"]
 
 
-def test_spec_engine_inline_prefill_error_reclaims_pages_and_budgets():
-    """Inline (speculative) prefill errors must reclaim the pages
-    allocated before the device call raised AND consume the request's
-    device-retry budget (review finding: the slot table owns the
-    request before allocation)."""
+def test_spec_engine_mixed_dispatch_error_reclaims_pages_and_budgets():
+    """A device error in a speculative engine's mixed dispatch (the
+    prompt's chunks through both models) must reclaim the pages
+    allocated at admission AND consume the request's device-retry
+    budget (the slot table owns the request before allocation)."""
     from paddle_tpu.inference.llm import LLMEngine
     pt.seed(0)
     from paddle_tpu.models.gpt import GPTForCausalLM, gpt_config
@@ -572,14 +572,11 @@ def test_spec_engine_inline_prefill_error_reclaims_pages_and_budgets():
                       max_position_embeddings=64, hidden_dropout=0.0,
                       attention_dropout=0.0)
     draft = GPTForCausalLM(dcfg)
-    # spec_slab=False: only the LEGACY inline path still one-shots
-    # prefill inside the round (slab engines chunk like everyone)
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
                     prefill_buckets=(16,), draft_net=draft,
-                    spec_tokens=2, device_retry_budget=1,
-                    spec_slab=False)
+                    spec_tokens=2, device_retry_budget=1)
     try:
-        real = eng._prefill_fn
+        real = eng._mixed_fn
         state = {"n": 0}
 
         def flaky(*a, **kw):
@@ -588,20 +585,20 @@ def test_spec_engine_inline_prefill_error_reclaims_pages_and_budgets():
                 raise RuntimeError("transient PJRT failure")
             return real(*a, **kw)
 
-        eng._prefill_fn = flaky
+        eng._mixed_fn = flaky
         out = eng.submit([1, 2, 3, 4, 5], max_new_tokens=4).result(
             timeout=120)
         assert out["output_ids"]           # retried and completed
         # a budget-0 engine propagates the error instead
         state["n"] = 0
         eng.device_retry_budget = 0
-        eng._prefill_fn = flaky
+        eng._mixed_fn = flaky
         with pytest.raises(RuntimeError, match="transient"):
             eng.submit([6, 7, 8], max_new_tokens=2).result(timeout=120)
     finally:
         eng.close()
     assert len(eng._free_pages) == eng.num_pages - 1, \
-        "inline prefill error leaked KV pages"
+        "mixed dispatch error leaked KV pages"
     assert eng._n_queued == 0
 
 

@@ -12,12 +12,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.inference.llm import (_PagedDecode, _PagedPrefill,
+from paddle_tpu.inference.llm import (_ChunkedPrefill, _PagedDecode,
                                       _PagedVerify)
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_config, llama_config
 from paddle_tpu.nn.layer import functional_call, split_state
-
-pytestmark = pytest.mark.slow  # smoke tier skips (tools/ci.sh --smoke)
 
 PS, NP, P = 4, 32, 8  # page size, pool pages, pages/seq
 
@@ -46,16 +44,22 @@ def _seed_pages(net, prompt):
     tables = np.zeros((1, P), np.int32)
     for i in range(P):
         tables[0, i] = i + 1
-    prefill = _PagedPrefill(net)
+    prefill = _ChunkedPrefill(net)
     params, buffers = split_state(prefill)
-    ids = np.zeros((1, 16), np.int32)
-    ids[0, :len(prompt)] = prompt
+    n, T = len(prompt), 16
+    ids = np.zeros((T,), np.int32)
+    ids[:n] = prompt
+    pos = np.arange(T, dtype=np.int32)
+    valid = pos < n
+    last = jnp.asarray([n - 1], jnp.int32)
     (t0, kp, vp), _ = functional_call(
         prefill, params, buffers, jnp.asarray(ids),
-        jnp.int32(len(prompt)), jnp.asarray(tables[0]), kp, vp,
-        jnp.float32(0.0), jnp.int32(0), jax.random.PRNGKey(0),
-        training=False)
-    return kp, vp, jnp.asarray(tables), len(prompt), int(t0)
+        jnp.asarray(np.where(valid, pos, 0)),
+        jnp.asarray(np.where(valid, pos + 1, 0)),
+        jnp.asarray(np.repeat(tables, T, axis=0)), last, last, kp, vp,
+        jnp.asarray([0.0], jnp.float32), jnp.asarray([0], jnp.int32),
+        jax.random.PRNGKey(0), training=False)
+    return kp, vp, jnp.asarray(tables), n, int(t0[0])
 
 
 @pytest.mark.parametrize("gqa", [False, True], ids=["mha", "gqa"])
@@ -187,22 +191,8 @@ def test_speculative_engine_exact_with_imperfect_draft():
 def test_speculative_engine_eos_and_guards():
     from paddle_tpu.inference.llm import LLMEngine
     net = _build(False)
-    # the LEGACY inline path (spec_slab=False) keeps its guards:
-    # greedy-only sampling and the bucketized prefill bound
-    with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), draft_net=net,
-                   spec_tokens=3, eos_token_id=7,
-                   spec_slab=False) as eng:
-        with pytest.raises(ValueError, match="greedy-only"):
-            eng.submit([1, 2], max_new_tokens=4, temperature=0.9)
-        with pytest.raises(ValueError, match="prefill bucket"):
-            eng.submit(list(range(20)), max_new_tokens=2)
-        out = eng.generate([[3, 1, 4]], max_new_tokens=40)[0]
-        if 7 in out["output_ids"]:
-            assert out["output_ids"][-1] == 7
-        assert len(out["output_ids"]) <= 40
-    # the slab path (the default) lifts BOTH guards: chunked ragged
-    # prefill takes any length, rejection sampling serves temp>0
+    # chunked ragged prefill takes a prompt longer than any bucket,
+    # rejection sampling serves temp>0
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
                    prefill_buckets=(8,), draft_net=net,
                    spec_tokens=3, eos_token_id=7) as eng:
@@ -234,3 +224,18 @@ def test_speculative_tight_max_len_parity():
         out = eng.generate([prompt], max_new_tokens=3)[0]
     assert out["output_ids"] == want
     assert not out["truncated"]
+
+
+def test_engine_names_no_model_internals():
+    """The engine owns the cache and calls ``ragged_forward``; the walk
+    over a model's layers lives in the model (so a new model can be a
+    target or a draft with no edit here). And the two constructor
+    arguments that went with the inline round stay gone."""
+    import inspect
+    from paddle_tpu.inference import llm
+    src = inspect.getsource(llm)
+    for name in (".gpt.", "qkv_proj", "ln_1", "ln_2", "out_proj",
+                 "_lm_logits", "init_caches", "rope_tables"):
+        assert name not in src, name
+    params = inspect.signature(llm.LLMEngine).parameters
+    assert "spec_slab" not in params and "cache_dtype" not in params

@@ -441,8 +441,8 @@ def slab_soak(seed: int, mixed: bool = False,
 def spec_slab_soak(seed: int) -> dict:
     """ISSUE 17 rider (rides --slab): the SAME kill/cancel/deadline
     storm with a DRAFT ENGINE running on-device speculative rounds
-    (``spec_slab``, prefix cache + int8 quantized draft pool + fused
-    N=8 slabs all on). Asserts: every future resolves under an
+    (prefix cache + int8 quantized draft pool + fused N=8 slabs all
+    on). Asserts: every future resolves under an
     ``engine.slab`` storm at the spec dispatch within
     ``device_retry_budget``; retried streams — greedy AND
     temperature>0 — are TOKEN-IDENTICAL to a fault-free spec
@@ -2610,7 +2610,7 @@ def main(argv=None) -> int:
             out["page_pressure_int8"] = page_pressure_soak(
                 seed, kv_dtype="int8")
             # ISSUE 17: the storm again with on-device speculative
-            # rounds (spec_slab + int8 draft pool + cache + N=8) —
+            # rounds (int8 draft pool + cache + N=8) —
             # nonce-pinned identity incl. temperature>0 rejection
             # sampling, rejected-draft pages leak-free
             out["slab_spec"] = spec_slab_soak(seed)

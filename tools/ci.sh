@@ -61,12 +61,10 @@ echo "== kv-dtype bench (bf16 vs int8 KV pool at fixed HBM)"
 python tools/llm_bench.py --ci --kv-dtype
 
 echo "== speculative slab bench (on-device draft-K/verify-1 rounds)"
-# tentpole gate: the spec slab sweep (K x kv_dtype x prefix cache)
-# must emit greedy tokens identical to a target-only engine in every
-# combination and pay >=2x fewer host dispatches per emitted token
-# than the legacy inline spec path at K=4; per-combination
-# bench_ledger/v1 rows key draft K + cache state into the series so
-# K=2 never gates against K=8
+# the spec slab sweep (K x kv_dtype x prefix cache) must emit greedy
+# tokens identical to a target-only engine in every combination;
+# per-combination bench_ledger/v1 rows key draft K + cache state into
+# the series so K=2 never gates against K=8
 python tools/llm_bench.py --ci --spec
 
 echo "== chaos soak (seeded fault injection -> hardened semantics)"
