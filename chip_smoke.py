@@ -15,7 +15,9 @@ paths through the entry points users call, at the full width of
            (a layer of a stacked bf16 and int8 pool, MHA and GQA 16/4),
            compiled (interpret=False), against their jnp references; then
            the kernel's time a call beside the gathered path's at the
-           serving benchmark's decode and mixed shapes
+           serving benchmark's decode and mixed shapes, the state step's
+           kernel beside ``ssd_step`` and the routed experts' grouped
+           product beside ``jax.lax.ragged_dot`` at the hybrid cell's shapes
   serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
            ``serve_llm``; HTTP ``POST /generate`` checked against
            ``net.generate``; once with attention_impl="xla", once "pallas"
@@ -261,6 +263,7 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
                   "an empty context must give a zero row")
     time_paged_attention(seed, heads, d, seq)
     time_ssd_step(seed)
+    time_grouped_matmul(seed)
     emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
 
 
@@ -449,6 +452,70 @@ def time_ssd_step(seed: int, slots: int = 64, heads: int = 128,
                   "max_abs_err_y": round(err, 6),
                   "max_abs_err_state": round(s_err, 8), "calls": calls})
         del ref_state
+        free_device_memory()
+
+
+def time_grouped_matmul(seed: int, held: int = 36, layers: int = 4) -> None:
+    """The routed experts' grouped product (``ops/grouped_matmul.py``)
+    beside ``jax.lax.ragged_dot`` at the hybrid serving benchmark's shapes:
+    36 held experts of ``[4096, 1536]`` and ``[768, 4096]`` bf16, a decode
+    tick's 640 (row, expert) pairs and a mixed tick's 3,200, half of them
+    held, unevenly, the even experts alone once more (an expert without
+    rows is not read). A call's time is that of ``layers`` calls in one
+    program, each on its own weights, over their number; GB/s counts the
+    weights of the experts that received a row, once. Smoke readings of one
+    layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.grouped_matmul import grouped_matmul
+
+    rng = np.random.default_rng(seed + 13)
+    key = jax.random.PRNGKey(seed + 13)
+
+    def many(product):
+        return jax.jit(lambda x, sizes, *ws: [product(x, w, sizes)
+                                              for w in ws])
+
+    paths = {"ragged_dot": many(jax.lax.ragged_dot),
+             "kernel": many(lambda x, w, sizes: grouped_matmul(
+                 x, w, sizes, interpret=False))}
+    for k, n in ((4096, 1536), (768, 4096)):
+        ws = [jax.random.normal(jax.random.fold_in(key, k + i), (held, k, n),
+                                jnp.bfloat16) * 0.02 for i in range(layers)]
+        for pairs in (640, 3200):
+            x = jax.random.normal(jax.random.fold_in(key, pairs), (pairs, k),
+                                  jnp.bfloat16)
+            sizes = rng.multinomial(pairs // 2,
+                                    rng.dirichlet(np.full(held, 3.0)))
+            for name, gs in (("every_expert", sizes),
+                             ("even_experts", np.where(
+                                 np.arange(held) % 2 == 0, sizes, 0))):
+                rows, live = int(gs.sum()), int((gs > 0).sum())
+                gs = jnp.asarray(gs, jnp.int32)
+                outs, ms = {}, {}
+                for what, fn in paths.items():
+                    outs[what] = jax.block_until_ready(fn(x, gs, *ws))
+                    t1 = time.perf_counter()
+                    for _ in range(10):
+                        out = fn(x, gs, *ws)
+                    jax.block_until_ready(out)
+                    ms[what] = (time.perf_counter() - t1) * 1e3 / (
+                        10 * layers)
+                errs = [_max_err(g[:rows], r[:rows]) for g, r in zip(
+                    outs["kernel"], outs["ragged_dot"])]
+                err, ok = max(e for e, _ in errs), all(o for _, o in errs)
+                emit({"phase": "kernels", "kernel": "grouped_matmul",
+                      "timed": name, "weights": [held, k, n], "pairs": pairs,
+                      "rows_held": rows, "experts_with_rows": live,
+                      "kernel_ms_per_call": round(ms["kernel"], 4),
+                      "ragged_dot_ms_per_call": round(ms["ragged_dot"], 4),
+                      "kernel_weight_gb_per_s": round(
+                          live * k * n * 2 / ms["kernel"] / 1e6, 1),
+                      "max_abs_err": round(err, 6), "calls": layers})
+                check(ok, f"grouped_matmul ({k} x {n}, {pairs} pairs, "
+                          f"{name}) disagrees with ragged_dot: {err}")
+        del ws
         free_device_memory()
 
 

@@ -553,7 +553,8 @@ class CacheView(NamedTuple):
     is the scratch row that padded rows write; ``None`` for a model
     without them). ``attention_impl`` is how the pool is attended,
     ``state_impl`` how the decode rows' ``ssm_state`` is stepped
-    (:func:`_state_impl`)."""
+    (:func:`_state_impl`), ``moe_impl`` how routed experts multiply
+    their groups of rows (:func:`_moe_impl`)."""
 
     k_pages: Any
     v_pages: Any
@@ -561,6 +562,11 @@ class CacheView(NamedTuple):
     ssm_state: Any = None
     attention_impl: str = "xla"
     state_impl: str = "xla"
+    moe_impl: str = "xla"
+
+
+def _all_on_tpu(arrays) -> bool:
+    return all(d.platform == "tpu" for a in arrays for d in a.devices())
 
 
 def _state_impl(ssm_state) -> str:
@@ -569,8 +575,19 @@ def _state_impl(ssm_state) -> str:
     (``ops/ssd.py ssd_step_kernel``: the layer's whole state array in
     place, live rows only) on a TPU, ``"xla"`` (``ssd_step`` over the
     slots' rows) anywhere else and for a model without state."""
-    on_tpu = ssm_state is not None and all(
-        d.platform == "tpu" for a in ssm_state for d in a.devices())
+    on_tpu = ssm_state is not None and _all_on_tpu(ssm_state)
+    return "pallas" if on_tpu else "xla"
+
+
+def _moe_impl(net) -> str:
+    """How an engine's programs multiply each routed expert's group of
+    rows, by the platform of the model's weights alone: ``"pallas"``
+    (``ops/grouped_matmul.py``: every held expert's weights read once,
+    none of an expert without rows) where they live on a TPU, ``"xla"``
+    (``jax.lax.ragged_dot``) anywhere else and for a model without routed
+    experts."""
+    on_tpu = net.moe_aux_spec() is not None and _all_on_tpu(
+        net.parameters())
     return "pallas" if on_tpu else "xla"
 
 
@@ -611,12 +628,14 @@ class _PagedDecode(Layer):
     program's output arity unchanged."""
 
     def __init__(self, net, attention_impl: str = "xla",
-                 return_logits: bool = False, state_impl: str = "xla"):
+                 return_logits: bool = False, state_impl: str = "xla",
+                 moe_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
         self.return_logits = return_logits
         self.state_impl = state_impl
+        self.moe_impl = moe_impl
 
     def forward(self, tokens, positions, block_tables, context_lens,
                 k_pages, v_pages, temperature, nonces, key,
@@ -627,7 +646,8 @@ class _PagedDecode(Layer):
         rows = RaggedRows(tokens, positions, context_lens, block_tables)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl, self.state_impl))
+                            self.attention_impl, self.state_impl,
+                            self.moe_impl))
         logits = self.net.ragged_logits(hidden)
         nxt = _sample(logits, temperature, key, nonces, positions)
         if self.return_logits:
@@ -691,10 +711,12 @@ class _ChunkedPrefill(Layer):
     of non-finishing slots are ignored by the host). Everything stays
     on device — admission never fetches."""
 
-    def __init__(self, net, attention_impl: str = "xla"):
+    def __init__(self, net, attention_impl: str = "xla",
+                 moe_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
+        self.moe_impl = moe_impl
 
     def forward(self, tokens, positions, limits, tables, sample_idx,
                 sample_pos, k_pages, v_pages, temperatures, nonces,
@@ -704,7 +726,7 @@ class _ChunkedPrefill(Layer):
                           tokens.shape[0], seg, seg_rows)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl))
+                            self.attention_impl, moe_impl=self.moe_impl))
         # only the finishing slots' last-token rows need the LM head:
         # [max_seqs, H] gathered rows, not [T, V] full logits
         logits = self.net.ragged_logits(
@@ -741,11 +763,12 @@ class _MixedTick(Layer):
     — the same (nonce, position) key either phase would fold."""
 
     def __init__(self, net, attention_impl: str = "xla",
-                 state_impl: str = "xla"):
+                 state_impl: str = "xla", moe_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
         self.state_impl = state_impl
+        self.moe_impl = moe_impl
 
     def forward(self, ptok, ppos, plim, ptbl, fin, fin_row, fin_pos,
                 dtok, dpos, dlens, tables, k_pages, v_pages, temps,
@@ -760,7 +783,8 @@ class _MixedTick(Layer):
                           c, pseg, seg_rows)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl, self.state_impl))
+                            self.attention_impl, self.state_impl,
+                            self.moe_impl))
         # one gathered LM-head row per slot: the finishing prompt row
         # when the slot's prefill completes this tick, its decode row
         # otherwise ([max_seqs, H] rows, never [T, V] full logits)
@@ -1000,6 +1024,7 @@ def _engine_status_provider(ref):
                           "tokens the state needs"}
         if eng._moe_spec is not None:
             out["moe"] = {
+                "moe_impl": eng.moe_impl,
                 "pairs_routed": eng.n_moe_pairs,
                 "pairs_held": eng.n_moe_pairs_held,
                 "rows_by_layer_and_held_expert":
@@ -1230,6 +1255,7 @@ class LLMEngine:
         # (layers, held experts) of the per-tick routed-row counts a
         # model with routed experts returns beside its hidden states
         self._moe_spec = net.moe_aux_spec()
+        self.moe_impl = _moe_impl(net)
         self._n_aux = 0
         if self._moe_spec is not None:
             self._n_aux = self._moe_spec[0] * (self._moe_spec[1] + 1)
@@ -1393,7 +1419,8 @@ class LLMEngine:
         self.n_spec_proposed = 0   # draft tokens offered to verify
         self.n_spec_accepted = 0   # of those, committed to requests
         decode = _PagedDecode(net, attention_impl,
-                              state_impl=self.state_impl)
+                              state_impl=self.state_impl,
+                              moe_impl=self.moe_impl)
         # all wrappers share `net` as their only sublayer, so one
         # "net."-prefixed param dict serves decode and prefill alike
         self._params, self._buffers = split_state(decode)
@@ -1527,7 +1554,7 @@ class LLMEngine:
         # (replica_main overrides it with the replica's fleet name)
         self.audit_scope = "engine"
 
-        chunked = _ChunkedPrefill(net, attention_impl)
+        chunked = _ChunkedPrefill(net, attention_impl, self.moe_impl)
 
         def chunk_fn(params, buffers, tokens, positions, limits,
                      tables, sample_idx, sample_pos, kp, vp, temps,
@@ -1555,7 +1582,8 @@ class LLMEngine:
         # Finished/inactive slots are masked no-ops exactly like
         # the pure-decode slab; a tick with neither budgets nor
         # prefill rows is skipped by the cond.
-        mixed = _MixedTick(net, attention_impl, self.state_impl)
+        mixed = _MixedTick(net, attention_impl, self.state_impl,
+                           self.moe_impl)
 
         def mixed_fn(params, buffers, carry, xs, tables, temps,
                      nonces, key, n_ticks):
@@ -3287,7 +3315,8 @@ class LLMEngine:
         ``llm_moe_rows_routed_total{held}`` counters, and on the drain
         phase (joined to its issue phase by ``issue_seq``)
         ``experts_touched`` (held experts that received a row, summed
-        over layers and ticks) and ``moe_rows_held``."""
+        over layers and ticks), ``moe_rows_held`` and ``moe_impl`` (the
+        grouped product the engine's programs were built with)."""
         if aux is None:
             return
         aux = aux.reshape((-1,) + aux.shape[-2:]).astype(np.int64)
@@ -3302,7 +3331,8 @@ class LLMEngine:
         if pairs - held:
             self._m["moe_rows"].labels(held="0").inc(pairs - held)
         ph.set_attr("experts_touched", int((rows > 0).sum())) \
-            .set_attr("moe_rows_held", held)
+            .set_attr("moe_rows_held", held) \
+            .set_attr("moe_impl", self.moe_impl)
 
     def _stamp_kv_pages(self, ph, *calls) -> None:
         """``kv_pages_read`` and ``kv_pages_live`` of one dispatch, on

@@ -295,11 +295,11 @@ class GraniteHybridLayer(Layer):
         for norm in (self.input_norm, self.post_norm):
             norm._scope = "ln"
 
-    def experts(self, x, r, valid=None):
+    def experts(self, x, r, valid=None, moe_impl: str = "xla"):
         """The second half of the layer; ``(h, rows each held expert
         received)``."""
         v = self.post_norm(x)
-        routed, rows_held = self.moe(v, valid)
+        routed, rows_held = self.moe(v, valid, moe_impl)
         return x + r * (routed + self.shared(v)), rows_held
 
 
@@ -410,8 +410,10 @@ class GraniteHybridForCausalLM(Layer):
         layer, ``[rows + 1, ...]``: the last row takes what padded rows
         write), ``attention_impl``, ``state_impl`` (``"pallas"``: the
         decode rows' state through ``ssd.ssd_step_kernel``; else
-        ``ssd.ssd_step``). A sequence's state is reset where its position
-        is 0. Returns ``(hidden [T, H], cache, aux)``."""
+        ``ssd.ssd_step``), ``moe_impl`` (``"pallas"``: the routed experts'
+        grouped products through ``ops/grouped_matmul.py``; else
+        ``jax.lax.ragged_dot``). A sequence's state is reset where its
+        position is 0. Returns ``(hidden [T, H], cache, aux)``."""
         cfg = self.cfg
         r = cfg.residual_multiplier
         tokens, positions, limits = rows.tokens, rows.positions, rows.limits
@@ -505,7 +507,7 @@ class GraniteHybridForCausalLM(Layer):
                 conv_state[i_st], ssm_state[i_st] = conv_s, ssm_s
                 i_st += 1
             x = x + r * out
-            x, rows_held = layer.experts(x, r, valid)
+            x, rows_held = layer.experts(x, r, valid, cache.moe_impl)
             aux.append(rows_held)
         pairs = jnp.sum(valid).astype(jnp.int32) * cfg.num_experts_per_tok
         aux = jnp.concatenate(

@@ -12,9 +12,15 @@ absent experts would add is left out here (their chip adds it, after an
 exchange this layer does not stand in for): the shares of all chips sum to
 the whole layer (tests/test_dropless_moe.py).
 
-Rows are sorted by expert and each expert's group goes through
-``jax.lax.ragged_dot``: the work follows the routed load, there is no
-capacity and no row is dropped, however uneven the routing.
+Rows are sorted by expert and each expert's group is multiplied by that
+expert's weights, one grouped product a weight matrix: the work follows the
+routed load, there is no capacity and no row is dropped, however uneven the
+routing. The grouped product is one algorithm with two implementations:
+``jax.lax.ragged_dot`` (``impl="xla"``: the path off the TPU and the
+other's oracle) and the Pallas kernel ``ops/grouped_matmul.py``
+(``impl="pallas"``: what an engine's programs run where the weights live on
+a TPU; it reads each held expert's weights once and none of an expert
+without rows).
 
 An expert is ``W_out (silu(a) * b)`` with ``[a | b] = W_in x``.
 """
@@ -26,6 +32,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...ops.grouped_matmul import grouped_matmul
 from .. import initializer as I
 from ..layer import Layer
 
@@ -41,9 +48,10 @@ def route_top_k(x, router_weight, top_k: int):
 
 
 class DroplessMoE(Layer):
-    """``forward(x [T, d], valid [T] bool or None)`` ->
+    """``forward(x [T, d], valid [T] bool or None, impl)`` ->
     ``(y [T, d], rows_held [count] int32)``; ``rows_held[e]`` is how many
-    valid rows expert ``first + e`` received."""
+    valid rows expert ``first + e`` received. ``impl`` names the grouped
+    product: ``"xla"`` or ``"pallas"``."""
 
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int,
@@ -69,7 +77,10 @@ class DroplessMoE(Layer):
         self.w_out = self.create_parameter(
             [count, d_expert, d_model], initializer=init)
 
-    def forward(self, x, valid=None):
+    def forward(self, x, valid=None, impl: str = "xla"):
+        if impl not in ("xla", "pallas"):
+            raise ValueError(f"unknown impl {impl!r}")
+        product = grouped_matmul if impl == "pallas" else jax.lax.ragged_dot
         t, _ = x.shape
         k, count = self.top_k, self.count
         with jax.named_scope("router"):
@@ -86,10 +97,9 @@ class DroplessMoE(Layer):
             rows_held = sizes[:count]
         with jax.named_scope("moe"):
             xs = jnp.take(x, rows, axis=0)                         # [T*k, d]
-            h = jax.lax.ragged_dot(xs, self.w_in, rows_held)
+            h = product(xs, self.w_in, rows_held)
             a, b = jnp.split(h, 2, axis=-1)
-            out = jax.lax.ragged_dot(jax.nn.silu(a) * b, self.w_out,
-                                     rows_held)
+            out = product(jax.nn.silu(a) * b, self.w_out, rows_held)
             g = jnp.where(held, gates, 0.0).reshape(-1)[order]
             ours = jnp.arange(t * k) < jnp.sum(rows_held)
             out = jnp.where(ours[:, None],

@@ -5,7 +5,8 @@ lowering refuses (block shapes, tiling, VMEM); these can, at about two
 seconds each and no chip time. Two compile whole engine programs and read
 their temporaries: a decode tick of GPT (the kernel must leave the pool
 where it is) and the hybrid model's decode and mixed programs at the
-published state shape (no copy of a layer's whole recurrent state).
+published state shape (no copy of a layer's whole recurrent state; every
+routed layer's two grouped products through the kernel).
 
 Only one process may load libtpu, and it keeps it until it exits: the
 topology is described inside a fixture of THIS file (never at import, in a
@@ -18,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.flash_attention import flash_attention
+from paddle_tpu.ops.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.paged_attention import paged_attention_kernel
 from paddle_tpu.ops.ssd import ssd_step_kernel
 
@@ -244,13 +246,35 @@ def test_ssd_step_kernel_compiles_for_v5e(one_chip, head_block):
     assert mem.temp_size_in_bytes < STATE_ROW_BYTES // 4
 
 
+# the hybrid serving benchmark's routed experts: 36 held, hidden 4096,
+# width 768; a decode tick's 64 rows x 10 experts and a mixed tick's 320
+@pytest.mark.parametrize("pairs", [640, 3200], ids=["decode", "mixed"])
+@pytest.mark.parametrize("weights", [(4096, 1536), (768, 4096)],
+                         ids=["w_in", "w_out"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, weights, pairs):
+    """The grouped product at the cell's shapes with the tiles it picks
+    from them: a ring of full-``K`` weight tiles of megabytes in VMEM
+    (over the compiler's default limit: the kernel raises it), nothing
+    beside the kernel but the few small vectors of its plan."""
+    k, n = weights
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compiled(
+        lambda x, w, sizes: grouped_matmul(x, w, sizes, interpret=False),
+        sds((pairs, k)), sds((36, k, n)), sds((36,), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.fixture(scope="module")
 def hybrid_programs(one_chip):
     """The engine's own ``decode`` and ``mixed`` programs of a hybrid model
     with the published state shape (three state-space layers and one that
     attends; the other widths small), compiled for the described chip as a
     TPU's engine builds them: kernels compiled, the decode rows' state
-    through ``ssd_step_kernel``. ``{name: compiled}``."""
+    through ``ssd_step_kernel``, the routed experts' groups through
+    ``grouped_matmul``. ``{name: compiled}``."""
     import importlib
     import numpy as np
     import paddle_tpu as pt
@@ -264,6 +288,7 @@ def hybrid_programs(one_chip):
     # the engine here lies on the CPU: the test, not an option, says what
     # the platform of a TPU's state would
     mp.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    mp.setattr(llm, "_moe_impl", lambda net: "pallas")
     cfg = GraniteHybridConfig(
         vocab_size=1024, hidden_size=1024,
         layer_types=["mamba", "mamba", "mamba", "attention"],
@@ -280,7 +305,7 @@ def hybrid_programs(one_chip):
                         num_pages=SLOTS * 16 + 1, max_len=256,
                         prefill_chunk=chunk, attention_impl="pallas")
     try:
-        assert eng.state_impl == "pallas"
+        assert (eng.state_impl, eng.moe_impl) == ("pallas", "pallas")
 
         def described(tree):
             return jax.tree_util.tree_map(
@@ -324,9 +349,13 @@ def test_hybrid_program_keeps_no_copy_of_a_layers_state(hybrid_programs,
     a layer's whole array, 65 rows, to either."""
     compiled = hybrid_programs[program]
     text = compiled.as_text()
-    calls = [ln for ln in text.splitlines()
-             if " custom-call(" in ln and "%ssd_step" in ln.split(" = ")[0]]
-    assert len(calls) == 3, len(calls)
+    def calls(kernel):
+        return [ln for ln in text.splitlines() if " custom-call(" in ln
+                and "%" + kernel in ln.split(" = ")[0]]
+
+    assert len(calls("ssd_step")) == 3
+    # four layers, two grouped products each, and no ragged-dot left
+    assert len(calls("grouped_matmul")) == 8 and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 3 * (SLOTS + 1) * STATE_ROW_BYTES
     assert mem.temp_size_in_bytes < rows * STATE_ROW_BYTES, (

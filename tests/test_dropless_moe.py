@@ -1,5 +1,7 @@
 """nn/layers/dropless_moe.py (float32 on the CPU): no token is ever dropped,
-and the shares of an expert-parallel stage add up to the whole layer."""
+and the shares of an expert-parallel stage add up to the whole layer, with
+either grouped product: ``jax.lax.ragged_dot`` (``"xla"``) and the Pallas
+kernel ``ops/grouped_matmul.py`` through the interpreter (``"pallas"``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,38 +39,45 @@ def _x(seed=1, t=T):
                        jnp.float32)
 
 
-def test_matches_the_definition_and_counts_every_pair():
+IMPLS = pytest.mark.parametrize("impl", ["xla", "pallas"])
+
+
+@IMPLS
+def test_matches_the_definition_and_counts_every_pair(impl):
     layer, x = _layer(), _x()
-    y, rows = layer(x)
+    y, rows = layer(x, impl=impl)
     np.testing.assert_allclose(y, _dense(layer, x), atol=TOL, rtol=TOL)
     assert int(rows.sum()) == T * K
 
 
-def test_a_router_that_sends_every_row_to_one_expert_loses_no_token():
+@IMPLS
+def test_a_router_that_sends_every_row_to_one_expert_loses_no_token(impl):
     layer, x = _layer(), _x()
     # expert 3 wins every row by a wide margin, expert 5 comes second
     router = np.zeros((D, E), np.float32)
     layer.router = jnp.asarray(router)
     x = x.at[:, 0].set(1.0)
     layer.router = layer.router.at[0, 3].set(50.0).at[0, 5].set(20.0)
-    y, rows = layer(x)
+    y, rows = layer(x, impl=impl)
     assert rows.tolist() == [0, 0, 0, T, 0, T, 0, 0]
     np.testing.assert_allclose(y, _dense(layer, x), atol=TOL, rtol=TOL)
     assert float(jnp.min(jnp.max(jnp.abs(y), -1))) > 0   # every row served
 
 
-def test_invalid_rows_are_not_counted_and_get_nothing():
+@IMPLS
+def test_invalid_rows_are_not_counted_and_get_nothing(impl):
     layer, x = _layer(), _x()
     valid = jnp.arange(T) % 3 != 0
-    y, rows = layer(x, valid)
+    y, rows = layer(x, valid, impl)
     assert int(rows.sum()) == int(valid.sum()) * K
     np.testing.assert_allclose(y[valid], _dense(layer, x)[valid], atol=TOL,
                                rtol=TOL)
     assert float(jnp.max(jnp.abs(y[~valid]))) == 0.0
 
 
+@IMPLS
 @pytest.mark.parametrize("split", [4, 2, 7])
-def test_the_shares_of_a_stage_add_up_to_the_whole_layer(split):
+def test_the_shares_of_a_stage_add_up_to_the_whole_layer(split, impl):
     """Experts 0..split-1 on one chip, the rest on the other: each routes
     over all E and computes its own experts' part; the parts sum to the
     uncut layer, and the pairs they count to every pair."""
@@ -79,17 +88,22 @@ def test_the_shares_of_a_stage_add_up_to_the_whole_layer(split):
         share.router = whole.router
         share.w_in = whole.w_in[first:first + count]
         share.w_out = whole.w_out[first:first + count]
-        y, rows = share(x)
+        y, rows = share(x, impl=impl)
         np.testing.assert_allclose(
             y, _dense(whole, x, range(first, first + count)), atol=TOL,
             rtol=TOL)
         parts.append(y)
         counted += int(rows.sum())
-    np.testing.assert_allclose(parts[0] + parts[1], whole(x)[0], atol=TOL,
-                               rtol=TOL)
+    np.testing.assert_allclose(parts[0] + parts[1], whole(x, impl=impl)[0],
+                               atol=TOL, rtol=TOL)
     assert counted == T * K
 
 
 def test_experts_held_outside_the_router_is_refused():
     with pytest.raises(ValueError, match="experts_held"):
         DroplessMoE(D, DE, E, K, (6, 4))
+
+
+def test_an_unknown_grouped_product_is_refused():
+    with pytest.raises(ValueError, match="unknown impl"):
+        _layer()(_x(), impl="cuda")

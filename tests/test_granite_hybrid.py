@@ -220,6 +220,51 @@ def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
         assert served_gap(params, d, p, toks) <= TOL
 
 
+@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False)],
+                         ids=["mixed_and_decode_ticks", "two_op_ticks"])
+def test_the_grouped_product_kernel_serves_what_ragged_dot_serves(
+        model, knobs, monkeypatch):
+    """What a TPU's engine runs, here through the Pallas interpreter: every
+    routed layer's two grouped products through ``ops/grouped_matmul.py``
+    (the decode, the mixed and, with mixed ticks off, the chunked-prefill
+    program) give token for token what ``jax.lax.ragged_dot`` gives, and
+    ``/statusz`` and the drain phase name which one the programs were
+    built with. Off the TPU the engine takes ``ragged_dot``; the test, not
+    an option, steers it."""
+    from paddle_tpu.inference import llm
+    from paddle_tpu.observability import tracing
+    net, params, d = model
+    prompts = prompts_of((12, 27, 1, 6, 19), seed=4)
+    kw = dict(max_seqs=3, page_size=8, num_pages=64, max_len=128,
+              prefill_chunk=16, kv_dtype="f32", **knobs)
+    was_trace = tracing.enabled()
+
+    def serve():
+        tracing.enable()
+        tracing.clear()
+        try:
+            with LLMEngine(net, **kw) as eng:
+                futs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+                outs = [list(f.result(timeout=600)["output_ids"])
+                        for f in futs]
+                status = dbgsrv._collect_status()[eng._status_name]
+            named = {s["attrs"]["moe_impl"]
+                     for s in tracing.finished_spans()
+                     if s["name"] == "llm.drain.emit"}
+        finally:
+            (tracing.enable if was_trace else tracing.disable)()
+        assert named == {eng.moe_impl} == {status["moe"]["moe_impl"]}
+        return eng.moe_impl, outs
+
+    plain_impl, want = serve()
+    monkeypatch.setattr(llm, "_moe_impl", lambda net: "pallas")
+    kernel_impl, got = serve()
+    assert (plain_impl, kernel_impl) == ("xla", "pallas")
+    assert got == want
+    for p, toks in zip(prompts, got):
+        assert served_gap(params, d, p, toks) <= TOL
+
+
 def test_what_assumes_pages_are_the_whole_context_is_refused_by_name(model):
     net, _, _ = model
     draft = GPTForCausalLM(gpt_config("gpt2-small", num_layers=1,
