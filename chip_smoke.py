@@ -25,6 +25,10 @@ paths through the entry points users call, at the full width of
            routed experts) at granite-4.0-h-small's widths, four layers, 8 of
            72 experts held: served through both ``attention_impl`` values
            and held to ``net.generate``
+  serve_looped  the looped decoder (models/ouro.py) at Ouro-2.6B's published
+           widths and depth, 48 layers run four times a token over 192 cache
+           layers: served over HTTP on the default path, each served token
+           held to the float32 reference computed a layer at a time
   train    ``Model.prepare(amp_configs="O1")`` + ``Model.fit`` at 1.3B width,
            flash attention and the fused loss on, AdamW; depth cut to what
            one chip holds (printed as ``reduced``)
@@ -823,6 +827,95 @@ def phase_serve_hybrid(seed: int, layers=("mamba", "mamba", "attention",
     free_device_memory()
 
 
+def phase_serve_looped(seed: int, lengths=(150, 70, 33),
+                       new_tokens: int = 12) -> None:
+    """The looped decoder (models/ouro.py) as the benchmark's configuration
+    builds it (``benchmark/configs/ouro-2.6b-serve-bf16.json``: every
+    published size, bf16, the pool sized from what the weights leave),
+    served over HTTP by ``LLMEngine`` + ``serve_llm`` on the default path: a
+    prompt that crosses the 128-row chunk and two that share one, then a
+    few decode ticks. Every served token is held to the plain float32
+    reference (``benchmark/reference/ouro_looped.py``, a layer's weights at
+    a time) by the cell's own measure and limit."""
+    import os
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark import weights_looped
+    from benchmark.reference import ouro_looped
+    from benchmark.systems import serve_looped
+    from paddle_tpu.inference.llm import LLMEngine, serve_llm
+
+    root = os.path.dirname(os.path.abspath(__file__))
+
+    def load(*parts):
+        with open(os.path.join(root, "benchmark", *parts)) as f:
+            return json.load(f)
+
+    cfg = load("configs", "ouro-2.6b-serve-bf16.json")
+    limit_gap = load("checks", "reason_closed_looped.json")["worst_gap_limit"]
+    d = weights_looped.dims_of(cfg)
+    t0 = time.time()
+    params = weights_looped.make(d, seed, jnp.bfloat16)
+    jax.block_until_ready(params)
+    net = serve_looped.build_net(cfg, params)
+    net.eval()
+    limit, in_use = hbm(jax.devices()[0])
+    plan = serve_looped.plan_pages(d, cfg["engine"], cfg["pool"], limit,
+                                   in_use)
+    emit({"phase": "serve_looped", "layers": d["L"], "passes": d["steps"],
+          "hidden": d["H"], "vocab": d["V"],
+          "params": weights_looped.n_params(d),
+          "build_seconds": round(time.time() - t0, 1),
+          "bytes_limit": limit, "weights_bytes_in_use": in_use, **plan})
+    prompts = make_prompts(seed, d["V"], lengths, 0)
+    t0 = time.time()
+    eng = LLMEngine(net, num_pages=plan["num_pages"], **cfg["engine"])
+    srv = serve_llm(eng)
+    try:
+        url = "http://%s:%d" % srv.server_address[:2]
+        outs = post_all(url, prompts, new_tokens, "looped")
+        check(eng.health == "healthy", f"[looped] engine health {eng.health}")
+        check("m" in eng.tick_history and "d" in eng.tick_history,
+              "[looped] no mixed or no decode tick was dispatched")
+        exits = eng.loop_exit_step_rows.tolist()
+        check(exits == [0] * (d["steps"] - 1) + [eng.n_tokens],
+              f"[looped] exit steps {exits} of {eng.n_tokens} tokens")
+        impl, page_bytes = eng.attention_impl, eng._page_bytes
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        eng.close()
+    wall = time.time() - t0
+    check(not any(o["truncated"] for o in outs), "[looped] truncated")
+    del eng, srv, net
+    gc.collect()
+    t0 = time.time()
+    pad = max(lengths) + new_tokens
+    ids = np.zeros((len(prompts), pad), np.int32)
+    served = np.zeros_like(ids)
+    for b, (p, o) in enumerate(zip(prompts, outs)):
+        seq = list(p) + list(o["output_ids"])
+        ids[b, :len(seq)] = seq
+        served[b, len(p) - 1:len(seq) - 1] = o["output_ids"]
+    got = jax.device_get(ouro_looped.served_gaps(
+        params, ids, np.asarray([len(p) - 1 for p in prompts], np.int32),
+        np.full(len(prompts), new_tokens, np.int32), served, d))
+    gaps = got["gap"][got["mask"]]
+    emit({"phase": "serve_looped", "attention_impl": impl,
+          "num_pages": plan["num_pages"], "engine_page_bytes": page_bytes,
+          "wall_seconds_with_compile": round(wall, 1),
+          "served_tokens": int(got["mask"].sum()),
+          "argmax_share": float((gaps == 0).mean()),
+          "worst_gap": float(gaps.max()), "limit": limit_gap,
+          "reference_seconds": round(time.time() - t0, 1)})
+    check(float(gaps.max()) <= limit_gap,
+          f"[looped] a served token lies {float(gaps.max())} below the "
+          f"reference's best, over the cell's limit {limit_gap}")
+    del params
+    free_device_memory()
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -1046,7 +1139,8 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", default=None,
-                    choices=("kernels", "serve", "serve_hybrid", "train"),
+                    choices=("kernels", "serve", "serve_hybrid",
+                             "serve_looped", "train"),
                     help="one chip: run this phase alone (default: all)")
     args = ap.parse_args(argv)
     t0 = time.time()
@@ -1057,6 +1151,7 @@ def main(argv=None) -> int:
         else:
             phases = {"kernels": phase_kernels, "serve": phase_serve,
                       "serve_hybrid": phase_serve_hybrid,
+                      "serve_looped": phase_serve_looped,
                       "train": phase_train}
             for name, phase in phases.items():
                 if args.phase in (None, name):

@@ -202,6 +202,15 @@ def _engine_metrics():
             "whether the expert is held here (held=1) or on another "
             "chip of the expert-parallel stage (held=0)",
             label_names=("held",)),
+        "loop_exits": reg.counter(
+            "llm_loop_exit_step_total",
+            "tokens delivered by a looped model, by the pass (0-based) "
+            "at which the exit gate would have let them leave",
+            label_names=("step",)),
+        "loop_steps": reg.counter(
+            "llm_loop_steps_total",
+            "passes of a looped model's stack run for the tokens it "
+            "delivered (exit step + 1 each)"),
         "state_rows": reg.gauge(
             "llm_state_rows_in_use",
             "slots whose recurrent-state row (conv + SSM) holds a live "
@@ -605,12 +614,23 @@ class RecurrentStateUnsupported(ValueError):
 
 def _engine_outputs(nxt, aux, cache: CacheView):
     """What an engine program returns: ``(tokens, k_pages, v_pages)``
-    for a model without recurrent state (as ever), with the forward's
-    ``aux`` counts and the two state lanes behind them otherwise."""
-    if cache.ssm_state is None:
-        return nxt, cache.k_pages, cache.v_pages
-    return (nxt, aux, cache.k_pages, cache.v_pages, cache.conv_state,
-            cache.ssm_state)
+    for a model whose forward has no ``aux`` (as ever); the ``aux``
+    behind the tokens where it has one, and the two state lanes last
+    for a model with recurrent state."""
+    out = (nxt,) if aux is None else (nxt, aux)
+    out += (cache.k_pages, cache.v_pages)
+    if cache.ssm_state is not None:
+        out += (cache.conv_state, cache.ssm_state)
+    return out
+
+
+def _slot_aux(net, aux, rows_idx):
+    """A looped model's ``aux`` is one value a ROW (``loop_aux_spec()``):
+    keep each slot's sampled row's, as the logits are. Any other
+    model's ``aux`` passes through."""
+    if net.loop_aux_spec() is None:
+        return aux
+    return jnp.take(aux, rows_idx, axis=0)
 
 
 class _PagedDecode(Layer):
@@ -732,7 +752,8 @@ class _ChunkedPrefill(Layer):
         logits = self.net.ragged_logits(
             jnp.take(hidden, sample_idx, axis=0))
         nxt = _sample(logits, temperatures, key, nonces, sample_pos)
-        return _engine_outputs(nxt, aux, cache)
+        return _engine_outputs(
+            nxt, _slot_aux(self.net, aux, sample_idx), cache)
 
 
 class _MixedTick(Layer):
@@ -793,7 +814,8 @@ class _MixedTick(Layer):
             jnp.take(hidden, rows_idx, axis=0))
         sample_pos = jnp.where(fin, fin_pos, dpos)
         nxt = _sample(logits, temps, key, nonces, sample_pos)
-        return _engine_outputs(nxt, aux, cache)
+        return _engine_outputs(
+            nxt, _slot_aux(self.net, aux, rows_idx), cache)
 
 
 class _Request:
@@ -1002,6 +1024,8 @@ def _engine_status_provider(ref):
             "decode_ticks_per_dispatch": eng.decode_ticks_per_dispatch,
             "mixed_tick": eng.mixed_tick,
             "kv_dtype": eng.kv_dtype,
+            "kv_cache_layers": eng._kv_cache_layers,
+            "page_bytes": eng._page_bytes,
             "host_dispatches": eng.n_host_dispatches,
             "flops_per_token": eng.flops_per_token,
             "n_steps": eng.n_steps,
@@ -1029,6 +1053,10 @@ def _engine_status_provider(ref):
                 "pairs_held": eng.n_moe_pairs_held,
                 "rows_by_layer_and_held_expert":
                     eng.moe_rows_by_expert.tolist()}
+        if eng._loop_steps is not None:
+            out["loop"] = {
+                "total_ut_steps": eng._loop_steps,
+                "exit_step_rows": eng.loop_exit_step_rows.tolist()}
         cache = eng._cache
         if cache is not None:
             out["prefix_cache"] = {
@@ -1262,6 +1290,21 @@ class LLMEngine:
             self.moe_rows_by_expert = np.zeros(self._moe_spec, np.int64)
             self.n_moe_pairs = 0
             self.n_moe_pairs_held = 0
+        # a looped model (the same stack run several times a token):
+        # the number of passes; its programs return each slot's sampled
+        # row's exit step behind the tokens, and the drain counts the
+        # delivered tokens by it (``loop_exit_step_rows``)
+        self._loop_steps = net.loop_aux_spec()
+        if self._loop_steps is not None:
+            if draft_net is not None:
+                raise NotImplementedError(
+                    "a looped model with a draft model: the speculative "
+                    "slab returns no exit steps, so the loop's counters "
+                    "would miss every token it commits")
+            self._n_aux = max_seqs
+            self.loop_exit_step_rows = np.zeros(self._loop_steps,
+                                                np.int64)
+        self._kv_cache_layers = kv_layers
         if spec is not None:
             if draft_net is not None:
                 raise RecurrentStateUnsupported(
@@ -1438,10 +1481,10 @@ class LLMEngine:
         n_aux = self._n_aux
 
         def fetched(out):
-            """A state model's program returns ``(tokens, aux, pools,
-            state)``: put what the host fetches, the tokens and the
-            routed-row counts as ONE int32 vector, second."""
-            if not has_state:
+            """A program of a model with ``aux`` returns ``(tokens, aux,
+            pools[, state])``: put what the host fetches, the tokens and
+            the ``aux`` counts as ONE int32 vector, second."""
+            if not n_aux:
                 return out
             nxt, aux = out[:2]
             return (nxt, jnp.concatenate([nxt, aux.reshape(-1)])) \
@@ -1463,13 +1506,11 @@ class LLMEngine:
 
         def tick_outputs(out):
             """``(tokens, aux or None, the carry's cache lanes)``."""
-            if has_state:
-                nxt, aux, kp, vp, conv, ssm = out
-                return nxt, aux.reshape(-1), dict(
-                    k_pages=kp, v_pages=vp, conv_state=conv,
-                    ssm_state=ssm)
-            nxt, kp, vp = out
-            return nxt, None, dict(k_pages=kp, v_pages=vp)
+            nxt, aux, lanes = out[0], None, out[1:]
+            if n_aux:
+                aux, lanes = lanes[0].reshape(-1), lanes[1:]
+            return nxt, aux, dict(zip(
+                ("k_pages", "v_pages", "conv_state", "ssm_state"), lanes))
 
         def carry_state(c):
             return (c.conv_state, c.ssm_state) if has_state else ()
@@ -1478,8 +1519,8 @@ class LLMEngine:
             """One tick of a fused slab: ``live_step(c) -> (carry,
             aux)`` under a cond that skips a tick with nothing to do;
             what the scan stacks for the host is the carry's tokens
-            (with a state model's counts behind them)."""
-            if not has_state:
+            (with the ``aux`` of a model that has one behind them)."""
+            if not n_aux:
                 c = jax.lax.cond(run, lambda c: live_step(c)[0],
                                  lambda c: c, c)
                 return c, c.tokens
@@ -3241,11 +3282,12 @@ class LLMEngine:
         ``(tokens on the device, what the host will fetch)``: for a
         model with routed experts the second holds the tick's routed-row
         counts behind the tokens, one transfer for both."""
-        if self._state_spec is None:
+        if not self._n_aux:
             tokens, self.k_pages, self.v_pages = out
             return tokens, tokens
-        (tokens, fetch, self.k_pages, self.v_pages, self.conv_state,
-         self.ssm_state) = out
+        tokens, fetch, self.k_pages, self.v_pages = out[:4]
+        if self._state_spec is not None:
+            self.conv_state, self.ssm_state = out[4:]
         return tokens, fetch
 
     def _new_carry(self, positions, budgets) -> DecodeCarry:
@@ -3300,11 +3342,12 @@ class LLMEngine:
         ph.set_attr("state_rows", rows).set_attr("state_bytes", 2 * moved)
 
     def _split_fetch(self, host):
-        """The fetched vector(s) of a state model: tokens, then the
-        routed-row counts ``[..., layers, held + 1]``."""
-        if not self._n_aux:
-            return host, None
+        """The fetched vector(s) of a model with ``aux``: tokens, then
+        the routed-row counts ``[..., layers, held + 1]`` or, of a
+        looped model, each slot's exit step ``[..., max_seqs]``."""
         b = self.max_seqs
+        if self._moe_spec is None:
+            return host[..., :b], host[..., b:]
         layers, held = self._moe_spec
         return host[..., :b], host[..., b:].reshape(
             host.shape[:-1] + (layers, held + 1))
@@ -3334,6 +3377,25 @@ class LLMEngine:
             .set_attr("moe_rows_held", held) \
             .set_attr("moe_impl", self.moe_impl)
 
+    def _note_loop(self, exits: List[int], ph) -> None:
+        """Account the exit steps of one drained dispatch's DELIVERED
+        tokens (a looped model): ``loop_exit_step_rows`` behind
+        /statusz, ``llm_loop_exit_step_total{step}``,
+        ``llm_loop_steps_total`` (the passes those tokens ran: exit step
+        + 1 each) and, on the drain phase, ``loop_steps`` and
+        ``kv_cache_layers``."""
+        counts = np.bincount(np.asarray(exits, np.int64),
+                             minlength=self._loop_steps)
+        self.loop_exit_step_rows += counts
+        passes = int(counts @ np.arange(1, self._loop_steps + 1))
+        for step in np.flatnonzero(counts):
+            self._m["loop_exits"].labels(step=str(step)).inc(
+                int(counts[step]))
+        if passes:
+            self._m["loop_steps"].inc(passes)
+        ph.set_attr("loop_steps", passes) \
+            .set_attr("kv_cache_layers", self._kv_cache_layers)
+
     def _stamp_kv_pages(self, ph, *calls) -> None:
         """``kv_pages_read`` and ``kv_pages_live`` of one dispatch, on
         its issue phase (so only while tracing is active). Each of
@@ -3347,9 +3409,16 @@ class LLMEngine:
         is the distinct pages those sequences hold: each sequence's
         longest limit, counted once. Limits the device decides alone
         (an EOS inside a slab, how far a speculative round moves)
-        count as planned at the slab's entry."""
+        count as planned at the slab's entry. A page is counted once
+        whatever the pool's cache layers (``kv_cache_layers``, beside
+        ``loop_steps``, the passes a looped model's programs run a row,
+        on the same phase): its bytes are ``/statusz``'s
+        ``page_bytes``."""
         if ph is _trace.NOOP_SPAN:
             return
+        if self._loop_steps is not None:
+            ph.set_attr("loop_steps", self._loop_steps) \
+                .set_attr("kv_cache_layers", self._kv_cache_layers)
         ps = self.page_size
         read = 0
         live: Dict[Any, int] = {}
@@ -4016,7 +4085,15 @@ class LLMEngine:
                 host = np.asarray(tokens)      # the only blocking fetch
         with _trace.phase("llm.drain.emit", {"issue_seq": seq}) as ph:
             self._fetch_seq = seq
-            if self._n_aux:
+            exits = delivered = None
+            if self._loop_steps is not None:
+                # ``delivered(slot)`` / ``(tick, slot)``: the exit step
+                # of a token the drain below surfaces
+                host, slot_exits = self._split_fetch(host)
+                exits = []
+                delivered = lambda *at: exits.append(  # noqa: E731
+                    slot_exits[at])
+            elif self._n_aux:
                 host, aux = self._split_fetch(host)
                 self._note_moe(aux, ph)
             if self._consec_device_errors:
@@ -4028,7 +4105,8 @@ class LLMEngine:
                 emitted = self._drain_spec_slab(seq, slots_list, host,
                                                 host_acc, meta)
             elif kind in ("D", "M"):
-                emitted = self._drain_slab(seq, slots_list, host, meta)
+                emitted = self._drain_slab(seq, slots_list, host, meta,
+                                           delivered)
             else:
                 if kind == "d":
                     self.n_steps += 1
@@ -4043,6 +4121,10 @@ class LLMEngine:
                         continue  # overrun token of a finished request
                     self._deliver_token(slot, req, int(host[slot]), seq)
                     emitted += 1
+                    if delivered is not None:
+                        delivered(slot)
+            if exits is not None:
+                self._note_loop(exits, ph)
             if _perf.enabled() or _goodput.enabled():
                 self._perf_attribute(kind, host.shape[0]
                                      if kind in ("D", "M", "S") else 0,
@@ -4052,7 +4134,7 @@ class LLMEngine:
             ph.set_attr("tokens", emitted)
 
     def _drain_slab(self, seq: int, slots_list: List[int], host,
-                    meta: dict) -> int:
+                    meta: dict, delivered=None) -> int:
         """Drain one fused-slab record ([n_ticks, max_seqs] host
         tokens) by replaying the device's masking decisions from the
         host copy of the slab-entry budgets: row j delivers a token
@@ -4063,7 +4145,8 @@ class LLMEngine:
         masked no-ops and are never surfaced). Advances each slot's
         context length by its realized emission count, counts the
         realized ticks, and marks the slab boundary on each decode
-        span."""
+        span. ``delivered(j, slot)`` is called for every token
+        surfaced."""
         remaining = dict(meta["budgets"])
         pos0 = meta["pos0"]
         # mixed slabs: a slot whose prompt completed at tick j emits
@@ -4090,6 +4173,8 @@ class LLMEngine:
                         tok == self.eos_token_id:
                     remaining[slot] = 0  # the device zeroed it too
                 self._deliver_token(slot, req, tok, seq)
+                if delivered is not None:
+                    delivered(j, slot)
                 emitted_per[slot] += 1
                 emitted += 1
         ticks = max(emitted_per.values(), default=0)

@@ -491,6 +491,11 @@ class GPTForCausalLM(Layer):
         """No routed experts: ``ragged_forward`` returns no counts."""
         return None
 
+    def loop_aux_spec(self):
+        """The stack runs once: ``ragged_forward`` returns no exit
+        steps."""
+        return None
+
     def ragged_logits(self, hidden):
         """``hidden`` [R, H] (after the final norm) -> logits [R, V]."""
         return self._logits(hidden[:, None])[:, 0]

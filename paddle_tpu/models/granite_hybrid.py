@@ -58,6 +58,7 @@ from ..nn.layers.dropless_moe import DroplessMoE
 from ..ops import ssd
 from ..ops.paged_attention import (kv_page_size, kv_write,
                                    ragged_paged_attention)
+from .generation import greedy_by_forward
 
 # sequences one packed run of prompt rows may hold: the scan gathers this
 # many carried states (ops/ssd.py), so the engine packs no more into a chunk
@@ -350,29 +351,10 @@ class GraniteHybridForCausalLM(Layer):
         return jnp.stack([self._sequence(row) for row in input_ids])
 
     def generate(self, input_ids, max_new_tokens: int = 20):
-        """Greedy decoding by the whole-sequence forward over a buffer of
-        the final length (causal: what lies after a position cannot move
-        it), one compiled program for every step. The serving path is
-        ``LLMEngine``; this is what it is held to."""
-        from ..nn.layer import functional_call, split_state
-        self.eval()
-        b, s = input_ids.shape
-        buf = jnp.zeros((b, s + max_new_tokens), jnp.int32) \
-            .at[:, :s].set(input_ids)
-        params, buffers = split_state(self)
-
-        @jax.jit
-        def step(params, buf, n):
-            logits, _ = functional_call(self, params, buffers, buf,
-                                        training=False)
-            nxt = jnp.argmax(
-                jnp.take_along_axis(
-                    logits, jnp.full((b, 1, 1), n - 1), axis=1)[:, 0], -1)
-            return buf.at[:, n].set(nxt.astype(jnp.int32))
-
-        for n in range(s, s + max_new_tokens):
-            buf = step(params, buf, n)
-        return buf
+        """Greedy decoding by the whole-sequence forward
+        (:func:`~paddle_tpu.models.generation.greedy_by_forward`). The
+        serving path is ``LLMEngine``; this is what it is held to."""
+        return greedy_by_forward(self, input_ids, max_new_tokens)
 
     # -- the engine's forward over ragged rows ---------------------------
     def kv_cache_spec(self):
@@ -398,6 +380,10 @@ class GraniteHybridForCausalLM(Layer):
         int32 ``[layers, held + 1]``, the rows each held expert received
         and, last, every (row, expert) pair the router made."""
         return self.cfg.num_layers, self.layers[0].moe.count
+
+    def loop_aux_spec(self):
+        """The stack runs once: ``aux`` is :meth:`moe_aux_spec`'s."""
+        return None
 
     def ragged_forward(self, rows, cache):
         """``rows``: ``tokens``, ``positions``, ``limits`` [T] (0 = a

@@ -46,6 +46,19 @@ def rope_tables(head_dim: int, max_len: int, base: float = 10000.0,
             sin.astype(ml_dtypes.bfloat16))
 
 
+def rope_at(positions, head_dim: int, base: float = 10000.0):
+    """cos/sin [T, head_dim] (half-split convention, float32) AT the given
+    ``positions`` [T], computed in the program: what a model whose
+    ``max_position_embeddings`` would make :func:`rope_tables` a constant of
+    tens of megabytes takes instead. :func:`apply_rotary_pos_emb` reads
+    them as tables already gathered (``position_ids`` None)."""
+    inv = 1.0 / (base ** (np.arange(0, head_dim, 2,
+                                    dtype=np.float32) / head_dim))
+    freqs = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return jnp.cos(emb), jnp.sin(emb)
+
+
 def _rotate_half(x):
     half = x.shape[-1] // 2
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)

@@ -360,3 +360,69 @@ def test_hybrid_program_keeps_no_copy_of_a_layers_state(hybrid_programs,
     assert mem.alias_size_in_bytes >= 3 * (SLOTS + 1) * STATE_ROW_BYTES
     assert mem.temp_size_in_bytes < rows * STATE_ROW_BYTES, (
         mem.temp_size_in_bytes / STATE_ROW_BYTES)
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_looped_program_keeps_the_pool_in_place_through_its_passes(
+        one_chip, monkeypatch, program):
+    """The looped model's engine programs at Ouro-2.6B's widths (two layers,
+    four passes, the cell's 8 slots and 128-row chunk): the passes are ONE
+    ``while`` whose carry holds the pool, each layer's kernel call stands
+    once in the text (not once a pass), both pools are the program's own
+    outputs (aliased: the scatter at a traced cache layer and the kernel's
+    read leave them where they are) and the temporaries stay under a
+    twentieth of one pool (the decode tick read 2.9 MB). Unrolled or copied, a
+    pass would add its kernel calls or a pool's 168 MB."""
+    import importlib
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.inference import llm
+    from paddle_tpu.models import OuroConfig, OuroForCausalLM
+
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    layers, slots, chunk = 2, 8, 128
+    cfg = OuroConfig(num_hidden_layers=layers, vocab_size=1024)
+    assert (cfg.num_heads, cfg.head_dim, cfg.total_ut_steps) == (
+        HEADS, HEAD_DIM, 4)
+    pt.seed(0)
+    net = OuroForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    eng = llm.LLMEngine(net, max_seqs=slots, page_size=PAGE, num_pages=321,
+                        max_len=640, prefill_chunk=chunk, kv_dtype="bf16",
+                        attention_impl="pallas")
+    try:
+        def described(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=one_chip), tree)
+
+        ints = np.zeros((slots,), np.int32)
+        if program == "decode":
+            lowered = eng._decode_fn.lower(*described((
+                eng._params, eng._buffers, eng._tokens_dev, ints,
+                eng.block_tables, ints, eng.k_pages, eng.v_pages,
+                eng.temperatures, eng._nonces, eng._key)))
+        else:
+            rows = np.zeros((1, chunk), np.int32)
+            per_slot = np.zeros((1, slots), np.int32)
+            xs = {"tok": rows, "pos": rows, "lim": rows,
+                  "tbl": np.zeros((1, chunk, eng.pages_per_seq), np.int32),
+                  "fin": per_slot.astype(bool), "row": per_slot,
+                  "fpos": per_slot, "grant": per_slot}
+            lowered = eng._mixed_fn.lower(*described((
+                eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+                eng.block_tables, eng.temperatures, eng._nonces,
+                eng._key)), 1)
+        pool = eng.k_pages.nbytes
+        assert pool == 4 * layers * 321 * PAGE * HEADS * HEAD_DIM * 2
+    finally:
+        eng.close()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == layers and " while(" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool
+    assert mem.temp_size_in_bytes < pool // 20, (
+        mem.temp_size_in_bytes, pool)
