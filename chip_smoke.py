@@ -267,6 +267,7 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
                   "an empty context must give a zero row")
     time_paged_attention(seed, heads, d, seq)
     time_ssd_step(seed)
+    time_ssd_chunk(seed)
     time_grouped_matmul(seed)
     emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
 
@@ -454,6 +455,115 @@ def time_ssd_step(seed: int, slots: int = 64, heads: int = 128,
                   "live_state_gb_per_s": round(
                       2 * n_live * row_bytes / ms / 1e6, 1),
                   "max_abs_err_y": round(err, 6),
+                  "max_abs_err_state": round(s_err, 8), "calls": calls})
+        del ref_state
+        free_device_memory()
+
+
+def time_ssd_chunk(seed: int, rows: int = 256, slots: int = 64,
+                   heads: int = 128, d_head: int = 64, d_state: int = 128,
+                   max_seqs: int = 8, layers: int = 3, calls: int = 9,
+                   head_blocks=(None,)) -> None:
+    """The chunk scan's kernel beside ``ssd_chunked`` as ``ragged_forward``
+    calls it off the TPU (gather the chunk's state rows, zero the fresh
+    ones, scan, scatter them back), at the hybrid serving benchmark's
+    shapes: a chunk of 256 rows of bf16 activations, 65 float32 state rows
+    of 128 x 64 x 128 a layer, up to 8 sequences a chunk; with 1, 2 and 8
+    sequences in it (the last with padded rows, the second with a fresh
+    sequence). A call's time is that of ``calls`` chained calls in one
+    program that donates the state, over their number. Whole state arrays
+    are compared: a row without a sequence in the chunk must hold. Smoke
+    readings of one layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.ssd import ssd_chunk_gathered, ssd_chunk_kernel
+
+    rng = np.random.RandomState(seed + 13)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 13), 8)
+    t = rows
+    x = jax.random.normal(keys[0], (t, heads, d_head), jnp.bfloat16)
+    # the model's own ranges: dt in [1e-3, 1e-1], A in -[1, 16]
+    dt = jnp.exp(jax.random.uniform(keys[1], (t, heads), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    a = -jax.random.uniform(keys[2], (heads,), jnp.float32, 1.0, 16.0)
+    d = jax.random.normal(keys[3], (heads,))
+    b = jax.random.normal(keys[4], (t, d_state), jnp.bfloat16)
+    c = jax.random.normal(keys[5], (t, d_state), jnp.bfloat16)
+    row_bytes = heads * d_head * d_state * 4
+
+    def fresh_state():
+        return tuple(
+            jax.random.normal(k, (slots + 1, heads, d_head, d_state),
+                              jnp.float32)
+            for k in jax.random.split(keys[6], layers))
+
+    def plain(s, x, seg, seg_rows, fresh):
+        return ssd_chunk_gathered(x, dt, a, b, c, d, s, seg, seg_rows,
+                                  fresh, chunk=rows)
+
+    def kernel(hb):
+        return lambda s, x, seg, seg_rows, fresh: ssd_chunk_kernel(
+            x, dt, a, b, c, d, s, seg, seg_rows, fresh, chunk=rows,
+            head_block=hb, interpret=False)
+
+    def chained(step):
+        def run(state, x, seg, seg_rows, fresh):
+            state = list(state)
+            total = jnp.zeros(x.shape, jnp.float32)
+            for i in range(calls):
+                y, state[i % layers] = step(state[i % layers], x, seg,
+                                            seg_rows, fresh)
+                total = total + y
+            return total, tuple(state)
+        return jax.jit(run, donate_argnums=(0,))
+
+    # (lengths of the sequences in the chunk, which of them start here)
+    cases = {"1_seq": ((t,), ()), "2_seqs": ((t - 96, 96), (1,)),
+             "8_seqs": ((57, 9, 40, 1, 64, 23, 31, 17), (2, 5))}
+    for name, (lens, starts) in cases.items():
+        seg = np.full((t,), max_seqs, np.int32)
+        seg[:sum(lens)] = np.repeat(np.arange(len(lens)), lens)
+        seg_rows = np.full((max_seqs,), slots, np.int32)
+        seg_rows[:len(lens)] = rng.permutation(slots)[:len(lens)]
+        fresh = np.zeros((max_seqs,), bool)
+        fresh[list(starts)] = True
+        valid = jnp.asarray(seg < max_seqs)
+        args = (jnp.asarray(seg), jnp.asarray(seg_rows), jnp.asarray(fresh))
+        ref_y, ref_state = chained(plain)(fresh_state(), x, *args)
+        steps = {"ssd_chunked": plain}
+        steps.update({f"kernel_hb{hb or 'auto'}": kernel(hb)
+                      for hb in head_blocks})
+        for what, step in steps.items():
+            fn = chained(step)
+            got_y, state = fn(fresh_state(), x, *args)
+            if step is not plain:
+                check(bool(jnp.isfinite(got_y[valid]).all()),
+                      f"{what} ({name}): y is not finite")
+                # float32 beside float32, the same sums in another order
+                y_err = float(jnp.abs(got_y - ref_y)[valid].max())
+                y_max = float(jnp.abs(ref_y[valid]).max())
+                s_err = max(float(jnp.abs(g - w).max())
+                            for g, w in zip(state, ref_state))
+                s_max = max(float(jnp.abs(w).max()) for w in ref_state)
+                check(y_err <= 2e-5 * max(y_max, 1.0)
+                      and s_err <= 2e-5 * max(s_max, 1.0),
+                      f"{what} ({name}) disagrees with ssd_chunked: y "
+                      f"{y_err} of {y_max}, state {s_err} of {s_max}")
+            else:
+                y_err = s_err = 0.0
+            jax.block_until_ready(state)
+            t1 = time.perf_counter()
+            for _ in range(3):
+                got_y, state = fn(state, x, *args)
+            jax.block_until_ready((got_y, state))
+            ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+            del state
+            emit({"phase": "kernels", "kernel": "ssd_chunk", "timed": name,
+                  "path": what, "rows": t, "sequences": len(lens),
+                  "state_row_bytes": row_bytes,
+                  "ms_per_call": round(ms, 4),
+                  "max_abs_err_y": round(y_err, 7),
                   "max_abs_err_state": round(s_err, 8), "calls": calls})
         del ref_state
         free_device_memory()

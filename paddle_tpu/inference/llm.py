@@ -561,7 +561,7 @@ class CacheView(NamedTuple):
     ``[max_seqs + 1, ...]`` array a state-space layer: row ``max_seqs``
     is the scratch row that padded rows write; ``None`` for a model
     without them). ``attention_impl`` is how the pool is attended,
-    ``state_impl`` how the decode rows' ``ssm_state`` is stepped
+    ``state_impl`` how the rows' ``ssm_state`` is advanced
     (:func:`_state_impl`), ``moe_impl`` how routed experts multiply
     their groups of rows (:func:`_moe_impl`)."""
 
@@ -579,11 +579,14 @@ def _all_on_tpu(arrays) -> bool:
 
 
 def _state_impl(ssm_state) -> str:
-    """How an engine's programs step the recurrent state of their decode
+    """How an engine's programs advance the recurrent state of their
     rows, by the platform of the state's device alone: ``"pallas"``
-    (``ops/ssd.py ssd_step_kernel``: the layer's whole state array in
-    place, live rows only) on a TPU, ``"xla"`` (``ssd_step`` over the
-    slots' rows) anywhere else and for a model without state."""
+    (``ops/ssd.py ssd_step_kernel`` for the decode rows and
+    ``ssd_chunk_kernel`` for a chunk of prompt rows: the layer's whole
+    state array in place, the rows of the sequences at work only) on a
+    TPU, ``"xla"`` (``ssd_step`` over the slots' rows, ``ssd_chunked``
+    over the gathered rows of as many sequences as a chunk may hold)
+    anywhere else and for a model without state."""
     on_tpu = ssm_state is not None and _all_on_tpu(ssm_state)
     return "pallas" if on_tpu else "xla"
 
@@ -732,10 +735,11 @@ class _ChunkedPrefill(Layer):
     on device — admission never fetches."""
 
     def __init__(self, net, attention_impl: str = "xla",
-                 moe_impl: str = "xla"):
+                 state_impl: str = "xla", moe_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
+        self.state_impl = state_impl
         self.moe_impl = moe_impl
 
     def forward(self, tokens, positions, limits, tables, sample_idx,
@@ -746,7 +750,8 @@ class _ChunkedPrefill(Layer):
                           tokens.shape[0], seg, seg_rows)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl, moe_impl=self.moe_impl))
+                            self.attention_impl, self.state_impl,
+                            self.moe_impl))
         # only the finishing slots' last-token rows need the LM head:
         # [max_seqs, H] gathered rows, not [T, V] full logits
         logits = self.net.ragged_logits(
@@ -1595,7 +1600,8 @@ class LLMEngine:
         # (replica_main overrides it with the replica's fleet name)
         self.audit_scope = "engine"
 
-        chunked = _ChunkedPrefill(net, attention_impl, self.moe_impl)
+        chunked = _ChunkedPrefill(net, attention_impl, self.state_impl,
+                                  self.moe_impl)
 
         def chunk_fn(params, buffers, tokens, positions, limits,
                      tables, sample_idx, sample_pos, kp, vp, temps,
@@ -3322,11 +3328,13 @@ class LLMEngine:
         phase (only while tracing): the rows whose recurrent state the
         tick advances (its live decode rows and the prompts in its
         chunk), and the bytes its programs read and write for them. The
-        chunk half gathers and scatters as many rows as a chunk may hold
-        sequences. The decode half steps every slot's ``conv_state`` row
-        (an inactive row is read and written back unchanged) and, through
-        the kernel (``state_impl`` ``"pallas"``), the ``ssm_state`` rows
-        of its live rows alone; ``ssd_step`` moves every slot's."""
+        chunk half gathers and scatters as many ``conv_state`` rows as a
+        chunk may hold sequences; the decode half steps every slot's (an
+        inactive row is read and written back unchanged). Through the
+        kernels (``state_impl`` ``"pallas"``) the ``ssm_state`` rows that
+        move are those of the sequences in the chunk and of the live
+        decode rows alone; ``ssd_chunked`` moves as many as a chunk may
+        hold, ``ssd_step`` every slot's."""
         if ph is _trace.NOOP_SPAN:
             return
         rows = moved = 0
@@ -3335,10 +3343,10 @@ class LLMEngine:
             chunk = int(self._state_spec["max_chunk_sequences"]) \
                 if chunk_seqs else 0
             slots = self.max_seqs if decode_part else 0
-            stepped = live_rows if self.state_impl == "pallas" else slots
             row = self._state_row_bytes
-            moved = (slots + chunk) * row["conv_state"] \
-                + (stepped + chunk) * row["ssm_state"]
+            moved = (slots + chunk) * row["conv_state"] + (
+                rows if self.state_impl == "pallas"
+                else slots + chunk) * row["ssm_state"]
         ph.set_attr("state_rows", rows).set_attr("state_bytes", 2 * moved)
 
     def _split_fetch(self, host):

@@ -395,8 +395,10 @@ class GraniteHybridForCausalLM(Layer):
         ``conv_state``, ``ssm_state`` (a tuple, one array a state-space
         layer, ``[rows + 1, ...]``: the last row takes what padded rows
         write), ``attention_impl``, ``state_impl`` (``"pallas"``: the
-        decode rows' state through ``ssd.ssd_step_kernel``; else
-        ``ssd.ssd_step``), ``moe_impl`` (``"pallas"``: the routed experts'
+        decode rows' state through ``ssd.ssd_step_kernel`` and the chunk's
+        scan through ``ssd.ssd_chunk_kernel``, both over the layer's whole
+        array in place; else ``ssd.ssd_step`` and
+        ``ssd.ssd_chunk_gathered``), ``moe_impl`` (``"pallas"``: the routed experts'
         grouped products through ``ops/grouped_matmul.py``; else
         ``jax.lax.ragged_dot``). A sequence's state is reset where its
         position is 0. Returns ``(hidden [T, H], cache, aux)``."""
@@ -453,12 +455,16 @@ class GraniteHybridForCausalLM(Layer):
                         conv_s = conv_s.at[rows.seg_rows].set(tail)
                     with jax.named_scope("ssm"):
                         xs, b, cc = mixer.scan_inputs(conv, u.dtype)
-                        state = jnp.where(fresh[:, None, None, None], 0.0,
-                                          ssm_s[rows.seg_rows])
-                        y, state = ssd.ssd_chunked(
-                            xs, dt[:c], mixer.A, b, cc, mixer.D, state,
-                            rows.chunk_seg, cfg.mamba_chunk_size)
-                        ssm_s = ssm_s.at[rows.seg_rows].set(state)
+                        # the kernel: the layer's whole array in place,
+                        # the tiles of the chunk's own sequences alone
+                        # cross HBM; else gather, scan, scatter
+                        scan = ssd.ssd_chunk_kernel \
+                            if cache.state_impl == "pallas" \
+                            else ssd.ssd_chunk_gathered
+                        y, ssm_s = scan(
+                            xs, dt[:c], mixer.A, b, cc, mixer.D, ssm_s,
+                            rows.chunk_seg, rows.seg_rows, fresh,
+                            cfg.mamba_chunk_size)
                         ys.append(y)
                 if n_dec:
                     live = valid[c:]

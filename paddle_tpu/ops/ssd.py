@@ -19,15 +19,20 @@ The recurrence, a head at a time (``x_t`` in R^P, ``B_t``, ``C_t`` in R^N,
   from its own carried state and leaves its final state. The run is walked
   in blocks of ``chunk`` rows; inside a block the quadratic (attention-like)
   form is masked across sequences, between blocks the ``G`` states carry.
+  Plain ``jax.numpy`` over the sequences' GATHERED states
+  (:func:`ssd_chunk_gathered` gathers and scatters them): the path off the
+  TPU, the whole-sequence forward's, and the oracle of
+  :func:`ssd_chunk_kernel`, the same scan as a Pallas kernel over a layer's
+  whole state array, in place, that moves the tiles of the sequences
+  present in the run only (what an engine's programs run on a TPU).
 
 :func:`causal_conv_chunk` / :func:`causal_conv_step` are the depthwise causal
 convolution that precedes the scan, with the carried ``d_conv - 1`` position
 tail of each sequence.
 
 The decays, their cumulative sums and the state are float32 whatever the
-activations are (a recurrence rounds at every token). The chunked scan is
-plain ``jax.numpy`` (no Pallas kernel yet); on the TPU its float32
-products ask for ``Precision.HIGHEST``.
+activations are (a recurrence rounds at every token), and on the TPU every
+float32 product of either chunked form asks for ``Precision.HIGHEST``.
 """
 
 from __future__ import annotations
@@ -46,6 +51,9 @@ _HI = jax.lax.Precision.HIGHEST
 # one state tile of the step kernel in VMEM (the pipeline holds four: two
 # coming in, two going out)
 _STATE_TILE_BYTES = 1 << 20
+# what the chunk kernel may hold in VMEM (the compiler's default is 16 MiB
+# of a v5e's 128)
+_CHUNK_VMEM_BYTES = 48 << 20
 
 
 def _onehot(tok_seg, n_seg: int):
@@ -297,6 +305,265 @@ def _ssd_step_call(x, dt, A, B, C, D, state, live, first, *, head_block,
             new_state)
 
 
+def _chunk_head_block(n_heads: int) -> int:
+    """Heads a grid step of :func:`ssd_chunk_kernel` holds: the largest
+    divisor of ``n_heads`` up to 16 (what is kept a head block in VMEM,
+    chiefly the chunk's ``dt x`` and the lane-padded decay columns, grows
+    with it; 16 heads of the published 64 x 128 are a 512 KB state tile)."""
+    return next(hb for hb in range(min(16, n_heads), 0, -1)
+                if n_heads % hb == 0)
+
+
+def ssd_chunk_kernel(x, dt, A, B, C, D, state, tok_seg, seg_rows, fresh,
+                     chunk: int = 256, head_block: Optional[int] = None,
+                     interpret: Optional[bool] = None):
+    """:func:`ssd_chunked` as a Pallas kernel over ONE layer's whole state
+    array, updated in place. ``x`` [T, H, P]; ``dt`` [T, H]; ``A``, ``D``
+    [H]; ``B``, ``C`` [T, N]; ``state`` [S, H, P, N] float32 (the engine's
+    ``slots + 1`` rows); ``tok_seg`` [T] in ``0..G`` as in
+    :func:`ssd_chunked`; ``seg_rows`` [G] the state row of each of the
+    run's sequences; ``fresh`` [G] bool, a sequence that starts here (from
+    zeros, whatever its row holds). Returns ``(y [T, H, P] float32,
+    state)``; the returned state IS the argument's buffer
+    (``input_output_aliases``) wherever the caller donates it.
+
+    The grid is (block of heads, sequence), the sequence innermost. The
+    first step of a head block computes, in VMEM and for every row of the
+    run at once, what ``_ssd_block`` computes inside a block: the
+    cumulative log-decays ``cs`` (a product with the same-sequence causal
+    mask, as there), ``C B^T`` under that mask, and a head at a time
+    ``exp(cs_t - cs_s)`` times it times ``dt x``. Then each sequence WITH
+    rows in the run brings its ``[head_block, P, N]`` state tile in, adds
+    ``exp(cs_t) C_t . S`` to its own rows, and sends back the state it
+    leaves, ``exp(total) S + sum_s exp(total - cs_s) dt_s x_s B_s^T``. A
+    ``fresh`` sequence starts from zeros and its old tile is never read. A
+    sequence WITHOUT rows moves nothing: its steps name the tile the
+    pipeline already holds, which is neither fetched nor written again, so
+    its state row (and the scratch row) keeps its bytes. Nothing of shape
+    ``[T, T, H]`` exists: the decay matrix of one head lives in VMEM and
+    dies there. The bytes a call moves follow the sequences present.
+
+    Everything per head lies with the run's rows along LANES (``x`` and
+    ``y`` cross the kernel's boundary transposed, ``[H, P, T]``): a head is
+    then an index into a leading axis, which a loop may take dynamically,
+    and a head's ``[P, T]`` output tile is stored whole. ``cs`` is needed
+    both along lanes (``cs_t``) and along sublanes (``cs_s``, kept a head a
+    ``[T, 1]`` column in scratch). The decays, their sums and the state
+    are float32 and every product asks the MXU for float32 accuracy
+    (``Precision.HIGHEST``), as :func:`ssd_chunked`'s do. ``T`` above
+    ``chunk`` is walked ``chunk`` rows a call. ``interpret`` defaults to
+    the module switch ``flash_attention.INTERPRET``.
+    """
+    if interpret is None:
+        interpret = _default_interpret()
+    t, n_heads, d_head = x.shape
+    if d_head % 8:
+        raise ValueError(f"head size {d_head} is not a multiple of 8")
+    if head_block is None:
+        head_block = _chunk_head_block(n_heads)
+    if n_heads % head_block:
+        raise ValueError(f"{n_heads} heads do not split into blocks of "
+                         f"{head_block}")
+    fresh = fresh.astype(bool)
+    ys = []
+    for lo in range(0, t, chunk):
+        hi = min(lo + chunk, t)
+        seg = tok_seg[lo:hi]
+        # a sequence is fresh in the call that holds its first row
+        starts = fresh if not lo else fresh & ~jnp.any(
+            _onehot(tok_seg[:lo], seg_rows.shape[0]), axis=0)
+        y, state = _ssd_chunk_call(
+            x[lo:hi], dt[lo:hi], A, B[lo:hi], C[lo:hi], state, seg,
+            seg_rows, starts, head_block=int(head_block),
+            interpret=bool(interpret))
+        ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    f32 = jnp.float32
+    return y + D.astype(f32)[None, :, None] * x.astype(f32), state
+
+
+@functools.partial(jax.jit, static_argnames=("head_block", "interpret"))
+def _ssd_chunk_call(x, dt, A, B, C, state, tok_seg, seg_rows, fresh, *,
+                    head_block, interpret):
+    """One block of rows; jitted so that an engine program, which calls it
+    once a state-space layer with the same shapes, traces and lowers the
+    kernel once. Returns ``y`` without the skip term."""
+    f32, i32 = jnp.float32, jnp.int32
+    t, n_heads, d_head = x.shape
+    slots, _, _, d_state = state.shape
+    n_seg = seg_rows.shape[0]
+    hb = head_block
+    nb = n_heads // hb
+    # lanes a step of the causal walk covers: row tile k needs the rows up
+    # to its own end only
+    tb = 128 if t % 128 == 0 else t
+    hi_dot = functools.partial(jax.lax.dot_general, precision=_HI,
+                               preferred_element_type=f32)
+    nn = (((1,), (0,)), ((), ()))
+    nt = (((1,), (1,)), ((), ()))
+
+    oh = _onehot(tok_seg, n_seg)
+    count = jnp.sum(oh, axis=0).astype(i32)
+    start = jnp.argmax(oh, axis=0).astype(i32)
+    present = count > 0
+    reads = present & ~fresh
+    flags = present.astype(i32) + 2 * reads.astype(i32)
+    any_present = jnp.any(present).astype(i32)[None]
+
+    # where each grid step's state tile lies, step k = block * G + sequence.
+    # A sequence that moves its tile names its own row and the step's
+    # block; any other step names the tile the pipeline holds (the last one
+    # moved, or before the first the one to come), so nothing is copied for
+    # it. With nothing to move at all, every step names block 0 of the last
+    # row (the scratch row) and copies it onto itself.
+    steps = nb * n_seg
+    ids = jnp.arange(steps, dtype=i32)
+    seq_of, blk_of = ids % n_seg, ids // n_seg
+
+    def held(moves):
+        moves = moves[seq_of]
+        before = jax.lax.cummax(jnp.where(moves, ids, -1))
+        after = jax.lax.cummin(jnp.where(moves, ids, steps), reverse=True)
+        src = jnp.where(before >= 0, before, jnp.minimum(after, steps - 1))
+        some = (before >= 0) | (after < steps)
+        row = jnp.where(some, seg_rows.astype(i32)[seq_of[src]], slots - 1)
+        return row.astype(i32), jnp.where(some, blk_of[src], 0).astype(i32)
+
+    in_row, in_blk = held(reads)
+    out_row, out_blk = held(present)
+
+    def tile(row_ref, blk_ref):
+        def index(b, g, *refs):
+            k = b * n_seg + g
+            return refs[row_ref][k], refs[blk_ref][k], 0, 0
+        return pl.BlockSpec((1, hb, d_head, d_state), index)
+
+    def kernel(in_row_ref, in_blk_ref, out_row_ref, out_blk_ref, start_ref,
+               count_ref, flag_ref, any_ref, segr_ref, segc_ref, at_ref,
+               acol_ref, dtt_ref, xt_ref, b_ref, c_ref, s_ref, yt_ref,
+               o_ref, m_ref, cb_ref, cs_ref, col_ref, dx_ref, lane_ref,
+               tot_ref):
+        b, g = pl.program_id(0), pl.program_id(1)
+        flag = flag_ref[g]
+        some = any_ref[0] > 0
+
+        @pl.when(jnp.logical_not(some) & (b == 0) & (g == 0))
+        def _nothing_present():           # the held tile, onto itself
+            o_ref[...] = s_ref[...]
+
+        @pl.when(jnp.logical_not(some) & (g == 0))
+        def _no_rows():
+            yt_ref[...] = jnp.zeros_like(yt_ref)
+
+        @pl.when(some & (g == 0))
+        def _inside_the_block():
+            @pl.when(b == 0)
+            def _masks():                 # the same for every head
+                segr, segc = segr_ref[...], segc_ref[...]
+                same = (segc == segr) & (segc < n_seg)
+                sub = jax.lax.broadcasted_iota(i32, (t, t), 0)
+                lane = jax.lax.broadcasted_iota(i32, (t, t), 1)
+                # m_ref[0][s, t]: row t sees row s; m_ref[1] its transpose
+                m_ref[0] = (same & (sub <= lane)).astype(f32)
+                m_ref[1] = (same & (sub >= lane)).astype(f32)
+                cb_ref[...] = hi_dot(b_ref[...], c_ref[...], nt) * m_ref[0]
+
+            # cumulative log-decay since the row's sequence entered the
+            # block, along lanes (cs_t) and as columns (cs_s)
+            cs = hi_dot(at_ref[...], m_ref[0], nn)               # [hb, T]
+            cs_col = hi_dot(m_ref[1], acol_ref[0], nn)           # [T, hb]
+            cs_ref[...] = cs
+            for j in range(hb):           # a head: a leading index
+                lane_ref[0, j] = cs[j:j + 1, :]
+                col_ref[j] = cs_col[:, j:j + 1]
+
+            def head(j, carry):
+                dx = xt_ref[j].astype(f32) * dtt_ref[j]
+                dx_ref[j] = dx                                   # [P, T]
+                for lo in range(0, t, tb):
+                    hi = lo + tb
+                    # (C_t . B_s) exp(cs_t - cs_s) for s <= t, [s, t]
+                    gap = lane_ref[0, j, :, lo:hi] - col_ref[j, :hi, :]
+                    w = cb_ref[:hi, lo:hi] * jnp.exp(
+                        gap * m_ref[0, :hi, lo:hi])
+                    yt_ref[j, :, lo:hi] = hi_dot(dx[:, :hi], w, nn)
+                return carry
+
+            jax.lax.fori_loop(0, hb, head, 0)
+
+        @pl.when(flag > 0)
+        def _sequence():
+            lane = jax.lax.broadcasted_iota(i32, (1, t), 1)
+            first = start_ref[g]
+            inside = (lane >= first) & (lane < first + count_ref[g])
+            cs = cs_ref[...]
+            total = jnp.sum(jnp.where(inside, at_ref[...], 0.0), axis=1,
+                            keepdims=True)                       # [hb, 1]
+            # exp(cs_t) into the rows' outputs, exp(total - cs_s) into the
+            # state the sequence leaves
+            into = jnp.where(inside, jnp.exp(cs), 0.0)
+            left = jnp.where(inside, jnp.exp(total - cs), 0.0)
+            held = jnp.broadcast_to(jnp.exp(total), (hb, d_state))
+            for j in range(hb):
+                lane_ref[1, j] = into[j:j + 1, :]
+                lane_ref[2, j] = left[j:j + 1, :]
+                tot_ref[j] = held[j:j + 1, :]
+            carried = flag >= 2
+
+            def head(j, carry):
+                old = jnp.where(carried, s_ref[0, j], 0.0)       # [P, N]
+                yt_ref[j] += lane_ref[1, j] * hi_dot(old, c_ref[...], nt)
+                o_ref[0, j] = tot_ref[j] * old + hi_dot(
+                    dx_ref[j] * lane_ref[2, j], b_ref[...], nn)
+                return carry
+
+            jax.lax.fori_loop(0, hb, head, 0)
+
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda b, g, *_: (0,) * len(shape))
+
+    def per_block(*shape):
+        return pl.BlockSpec(
+            shape, lambda b, g, *_: (b,) + (0,) * (len(shape) - 1))
+
+    live = tok_seg < n_seg                 # a padded row moves nothing
+    dtf = jnp.where(live[:, None], dt.astype(f32), 0.0)
+    a = dtf * A.astype(f32)[None, :]                             # [T, H]
+    seg = tok_seg.astype(i32)
+    y_t, new_state = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=8,
+            grid=(nb, n_seg),
+            in_specs=[whole(1, t), whole(t, 1), per_block(hb, t),
+                      per_block(1, t, hb), per_block(hb, 1, t),
+                      per_block(hb, d_head, t), whole(t, d_state),
+                      whole(t, d_state), tile(0, 1)],
+            out_specs=[per_block(hb, d_head, t), tile(2, 3)],
+            scratch_shapes=[pltpu.VMEM((2, t, t), f32),
+                            pltpu.VMEM((t, t), f32),
+                            pltpu.VMEM((hb, t), f32),
+                            pltpu.VMEM((hb, t, 1), f32),
+                            pltpu.VMEM((hb, d_head, t), f32),
+                            pltpu.VMEM((3, hb, 1, t), f32),
+                            pltpu.VMEM((hb, 1, d_state), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((n_heads, d_head, t), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # operand 16 (8 prefetched + 8 small ones) is the state
+        input_output_aliases={16: 1},
+        # sequential: a tile stays in VMEM across the steps that name it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_CHUNK_VMEM_BYTES),
+        interpret=interpret,
+        name="ssd_chunk",
+    )(in_row, in_blk, out_row, out_blk, start, count, flags, any_present,
+      seg[None, :], seg[:, None], a.T,
+      a.reshape(t, nb, hb).transpose(1, 0, 2), dtf.T[:, None, :],
+      x.transpose(1, 2, 0), B.astype(f32), C.astype(f32), state)
+    return y_t.transpose(2, 0, 1), new_state
+
+
 def _ssd_block(x, dt, A, B, C, oh, state):
     """One block of ``Q`` rows against the ``G`` carried states."""
     f32 = jnp.float32
@@ -353,6 +620,18 @@ def ssd_chunked(x, dt, A, B, C, D, state, tok_seg, chunk: int = 256):
         ys.append(y)
     y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
     return y + D.astype(f32)[None, :, None] * xf, state
+
+
+def ssd_chunk_gathered(x, dt, A, B, C, D, state, tok_seg, seg_rows, fresh,
+                       chunk: int = 256):
+    """:func:`ssd_chunked` over ONE layer's whole state array ``state``
+    [S, H, P, N], with :func:`ssd_chunk_kernel`'s arguments and results:
+    the rows ``seg_rows`` [G] of the run's sequences are gathered, those
+    of a ``fresh`` [G] sequence zeroed, scanned and scattered back. What
+    an engine's programs run off the TPU, and the kernel's oracle."""
+    carried = jnp.where(fresh[:, None, None, None], 0.0, state[seg_rows])
+    y, new = ssd_chunked(x, dt, A, B, C, D, carried, tok_seg, chunk)
+    return y, state.at[seg_rows].set(new)
 
 
 def ssd_recurrence(x, dt, A, B, C, D, state):

@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.ops.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.paged_attention import paged_attention_kernel
-from paddle_tpu.ops.ssd import ssd_step_kernel
+from paddle_tpu.ops.ssd import ssd_chunk_kernel, ssd_step_kernel
 
 HEADS, HEAD_DIM, PAGE, MAX_LEN = 16, 128, 16, 2048
 
@@ -246,6 +246,35 @@ def test_ssd_step_kernel_compiles_for_v5e(one_chip, head_block):
     assert mem.temp_size_in_bytes < STATE_ROW_BYTES // 4
 
 
+@pytest.mark.parametrize("rows", [256, 64], ids=["chunk256", "chunk64"])
+@pytest.mark.parametrize("head_block", [None, 8, 32],
+                         ids=["hb_from_shapes", "hb8", "hb32"])
+def test_ssd_chunk_kernel_compiles_for_v5e(one_chip, head_block, rows):
+    """The chunk scan at the cell's shapes (256 prompt rows of bf16
+    activations, up to 8 sequences) and at a shorter chunk, a layer's whole
+    ``[65, 128, 64, 128]`` array donated: aliased in place, and beside the
+    kernel only the transposed activations and a few small vectors."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scan(x, dt, a, b, c, d, state, seg, seg_rows, fresh):
+        return ssd_chunk_kernel(x, dt, a, b, c, d, state, seg, seg_rows,
+                                fresh, head_block=head_block,
+                                interpret=False)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    state = sds((SLOTS + 1, SSM_HEADS, SSM_HEAD_DIM, SSM_STATE))
+    compiled = jax.jit(scan, donate_argnums=(6,)).lower(
+        sds((rows, SSM_HEADS, SSM_HEAD_DIM), bf16), sds((rows, SSM_HEADS)),
+        sds((SSM_HEADS,)), sds((rows, SSM_STATE), bf16),
+        sds((rows, SSM_STATE), bf16), sds((SSM_HEADS,)), state,
+        sds((rows,), i32), sds((8,), i32), sds((8,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == (SLOTS + 1) * STATE_ROW_BYTES
+    assert mem.temp_size_in_bytes < STATE_ROW_BYTES // 2
+
+
 # the hybrid serving benchmark's routed experts: 36 held, hidden 4096,
 # width 768; a decode tick's 64 rows x 10 experts and a mixed tick's 320
 @pytest.mark.parametrize("pairs", [640, 3200], ids=["decode", "mixed"])
@@ -335,18 +364,18 @@ def hybrid_programs(one_chip):
     return {"decode": decode, "mixed": mixed}
 
 
-@pytest.mark.parametrize("program,rows", [("decode", 8), ("mixed", SLOTS)],
+@pytest.mark.parametrize("program,rows", [("decode", 8), ("mixed", 16)],
                          ids=["decode", "mixed"])
 def test_hybrid_program_keeps_no_copy_of_a_layers_state(hybrid_programs,
                                                         program, rows):
-    """Every state-space layer steps its state through the kernel, the
-    three layers' arrays are the program's own outputs (aliased), and the
-    temporaries stay under a few state rows: 8 for a decode tick (it read
-    6, none of them state), and under ONE layer's 65 for a mixed tick,
-    whose chunk half rightly holds the gathered and the new state of the 8
-    sequences a chunk may carry, besides the scan's products (it read 44).
-    A gather, a ``where`` or a scatter that XLA could not alias would add
-    a layer's whole array, 65 rows, to either."""
+    """Every state-space layer steps its state through the kernel (a mixed
+    tick's chunk half through ``ssd_chunk`` too), the three layers' arrays
+    are the program's own outputs (aliased), and the temporaries stay
+    under a few state rows: 8 for a decode tick (it read 2.1, none of them
+    state) and 16 for a mixed tick (it read 6.5; 44 while its chunk half
+    gathered and scattered the state of the 8 sequences a chunk may carry
+    around ``ssd_chunked``). A gather, a ``where`` or a scatter that XLA
+    could not alias would add a layer's whole array, 65 rows, to either."""
     compiled = hybrid_programs[program]
     text = compiled.as_text()
     def calls(kernel):
@@ -354,6 +383,7 @@ def test_hybrid_program_keeps_no_copy_of_a_layers_state(hybrid_programs,
                 and "%" + kernel in ln.split(" = ")[0]]
 
     assert len(calls("ssd_step")) == 3
+    assert len(calls("ssd_chunk")) == (3 if program == "mixed" else 0)
     # four layers, two grouped products each, and no ragged-dot left
     assert len(calls("grouped_matmul")) == 8 and "ragged-dot" not in text
     mem = compiled.memory_analysis()

@@ -220,6 +220,62 @@ def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
         assert served_gap(params, d, p, toks) <= TOL
 
 
+@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+                                   dict(decode_ticks_per_dispatch=4)],
+                         ids=["mixed_and_decode_ticks", "two_op_ticks",
+                              "slab"])
+def test_the_chunk_kernel_serves_what_ssd_chunked_serves(knobs,
+                                                         monkeypatch):
+    """The chunk half of a TPU's engine, here through the Pallas
+    interpreter, on ``rehearsal-tiny-hybrid``'s shapes and engine options
+    (4 slots, chunks of 16 rows = the model's ``mamba_chunk_size``): seven
+    prompts, so a chunk holds several sequences (lengths 3, 5, 2), a
+    prompt runs over three chunks (40 rows) and slots are reused; greedy
+    tokens through ``ssd_chunk_kernel`` are those through ``ssd_chunked``.
+    The decode half takes the step kernel in both (its own test is above):
+    what differs between the two engines is the chunk's scan alone."""
+    from paddle_tpu.inference import llm
+    from paddle_tpu.ops import ssd
+    with open("benchmark/configs/rehearsal-tiny-hybrid.json") as f:
+        conf = json.load(f)
+    assert {k: conf[k] for k in ("mamba_n_heads", "mamba_d_head",
+                                 "mamba_d_state")} \
+        == {k: TINY[k] for k in ("mamba_n_heads", "mamba_d_head",
+                                 "mamba_d_state")}
+    model = dict(TINY, mamba_chunk_size=conf["mamba_chunk_size"])
+    cfg = GraniteHybridConfig(
+        **{k: v for k, v in model.items() if k != "num_layers"},
+        max_position_embeddings=conf["max_position_embeddings"])
+    pt.seed(3)
+    net = GraniteHybridForCausalLM(cfg)
+    net.eval()
+    net.set_state_dict({k: (v * 6.0 if v.ndim >= 2 and "conv" not in k
+                            else v) for k, v in net.state_dict().items()})
+    prompts = prompts_of((3, 5, 2, 40, 16, 1, 23), seed=8)
+    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    scans = []
+    kernel = ssd.ssd_chunk_kernel
+
+    def serve():
+        with LLMEngine(net, **conf["engine"], **knobs) as eng:
+            assert eng.state_impl == "pallas"
+            futs = [eng.submit(p, max_new_tokens=7) for p in prompts]
+            return [list(f.result(timeout=600)["output_ids"]) for f in futs]
+
+    def spied(*a, **kw):
+        scans.append(a[0].shape[0])
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(ssd, "ssd_chunk_kernel", spied)
+    got = serve()
+    assert scans and set(scans) == {conf["engine"]["prefill_chunk"]}
+
+    monkeypatch.setattr(ssd, "ssd_chunk_kernel", ssd.ssd_chunk_gathered)
+    want = serve()
+    assert got == want
+    assert len({tuple(w) for w in want}) > 1      # the tokens vary
+
+
 @pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False)],
                          ids=["mixed_and_decode_ticks", "two_op_ticks"])
 def test_the_grouped_product_kernel_serves_what_ragged_dot_serves(
@@ -361,3 +417,42 @@ def test_state_and_routing_are_on_the_spans_the_ledger_and_the_metrics(model):
     assert joined and all("experts_touched" in a for a in joined)
     assert sum(a["moe_rows_held"] for a in emits.values()) == held
     assert 0 < held < pairs
+
+
+def test_through_the_kernels_state_bytes_counts_the_sequences_present(
+        model, monkeypatch):
+    """``state_bytes`` of a mixed dispatch on a TPU's engine: the chunk
+    half moves the ``ssm_state`` rows of the sequences in its chunk alone
+    (``ssd_chunk_kernel``), not the 8 a chunk may hold; the ``conv_state``
+    rows are still gathered 8 at a time and stepped a slot at a time."""
+    from paddle_tpu.inference import llm
+    from paddle_tpu.observability import tracing
+    net, _, _ = model
+    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    was = tracing.enabled()
+    tracing.enable()
+    tracing.clear()
+    try:
+        with LLMEngine(net, max_seqs=2, page_size=8, num_pages=32,
+                       max_len=64, prefill_chunk=16,
+                       kv_dtype="f32") as eng:
+            # the second prompt arrives while the first decodes
+            eng.submit(prompts_of((20,), seed=6)[0],
+                       max_new_tokens=12).result(timeout=600)
+            futs = [eng.submit(p, max_new_tokens=4)
+                    for p in prompts_of((5, 3), seed=7)]
+            for f in futs:
+                f.result(timeout=600)
+            per_row = eng._state_row_bytes
+        spans = tracing.finished_spans()
+    finally:
+        (tracing.enable if was else tracing.disable)()
+    mixed = [s["attrs"] for s in spans if s["name"] == "llm.issue.mixed"]
+    assert mixed
+    for attrs in mixed:
+        seqs, rows = attrs["chunk_rows"], attrs["state_rows"]
+        assert 1 <= seqs <= 2 and rows >= seqs
+        assert attrs["state_bytes"] == 2 * (
+            (2 + 8) * per_row["conv_state"] + rows * per_row["ssm_state"])
+    # two short prompts shared one chunk
+    assert max(a["chunk_rows"] for a in mixed) == 2
