@@ -1026,6 +1026,98 @@ def phase_serve_looped(seed: int, lengths=(150, 70, 33),
     free_device_memory()
 
 
+def phase_serve_swa(seed: int, lengths=(900, 300, 40),
+                    new_tokens: int = 24) -> None:
+    """The window / full attention decoder (models/laguna.py) at the widths
+    of the benchmark's configuration
+    (``benchmark/configs/laguna-s-2.1-serve-ep8.json``) and TWO layers of
+    each kind (full, sliding, sliding, full: the dense layer and three
+    routed ones), served over HTTP by ``LLMEngine`` + ``serve_llm`` on the
+    default path: a prompt longer than the window + a chunk (its window
+    pages are released behind it while it is prefilled), two that share a
+    chunk, then decode ticks. Every served token is held to the plain
+    float32 reference (``benchmark/reference/laguna_swa.py``) by the cell's
+    own measure and limit."""
+    import os
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark import weights_swa
+    from benchmark.reference import laguna_swa
+    from benchmark.systems import serve_swa
+    from paddle_tpu.inference.llm import LLMEngine, serve_llm
+
+    root = os.path.dirname(os.path.abspath(__file__))
+
+    def load(*parts):
+        with open(os.path.join(root, "benchmark", *parts)) as f:
+            return json.load(f)
+
+    cfg = load("configs", "laguna-s-2.1-serve-ep8.json")
+    cfg.update(num_layers=4, layer_types=[
+        "full_attention", "sliding_attention", "sliding_attention",
+        "full_attention"], num_attention_heads_per_layer=[48, 72, 72, 48])
+    limit_gap = load("checks", "agent_closed_swa.json")["worst_gap_limit"]
+    d = weights_swa.dims_of(cfg)
+    t0 = time.time()
+    params = weights_swa.make(d, seed, jnp.bfloat16)
+    jax.block_until_ready(params)
+    net = serve_swa.build_net(cfg, params)
+    net.eval()
+    emit({"phase": "serve_swa", "layers": d["kinds"], "heads": d["heads"],
+          "hidden": d["H"], "vocab": d["V"], "experts_held": d["count"],
+          "params": weights_swa.n_params(d),
+          "build_seconds": round(time.time() - t0, 1)})
+    prompts = make_prompts(seed, d["V"], lengths, 0)
+    t0 = time.time()
+    eng = LLMEngine(net, **dict(cfg["engine"], max_seqs=4, max_len=1024,
+                                num_pages=257))
+    srv = serve_llm(eng)
+    try:
+        url = "http://%s:%d" % srv.server_address[:2]
+        outs = post_all(url, prompts, new_tokens, "swa")
+        check(eng.health == "healthy", f"[swa] engine health {eng.health}")
+        check("m" in eng.tick_history and "d" in eng.tick_history,
+              "[swa] no mixed or no decode tick was dispatched")
+        groups = [g.status() for g in eng._pool.groups]
+        window = eng._pool.groups[1]
+        check(window.n_released > 0 and window.in_use == 0,
+              f"[swa] window group: {groups[1]}")
+        impl, moe_impl = eng.attention_impl, eng.moe_impl
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        eng.close()
+    wall = time.time() - t0
+    check(not any(o["truncated"] for o in outs), "[swa] truncated")
+    del eng, srv, net
+    gc.collect()
+    t0 = time.time()
+    pad = 1024
+    ids = np.zeros((len(prompts), pad), np.int32)
+    served = np.zeros_like(ids)
+    for b, (p, o) in enumerate(zip(prompts, outs)):
+        seq = list(p) + list(o["output_ids"])
+        ids[b, :len(seq)] = seq
+        served[b, len(p) - 1:len(seq) - 1] = o["output_ids"]
+    got = jax.device_get(laguna_swa.served_gaps(
+        params, ids, np.asarray([len(p) - 1 for p in prompts], np.int32),
+        np.full(len(prompts), new_tokens, np.int32), served, d))
+    gaps = got["gap"][got["mask"]]
+    emit({"phase": "serve_swa", "attention_impl": impl, "moe_impl": moe_impl,
+          "cache_groups": groups,
+          "wall_seconds_with_compile": round(wall, 1),
+          "served_tokens": int(got["mask"].sum()),
+          "argmax_share": float((gaps == 0).mean()),
+          "worst_gap": float(gaps.max()), "limit": limit_gap,
+          "reference_seconds": round(time.time() - t0, 1)})
+    check(float(gaps.max()) <= limit_gap,
+          f"[swa] a served token lies {float(gaps.max())} below the "
+          f"reference's best, over the cell's limit {limit_gap}")
+    del params
+    free_device_memory()
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -1250,7 +1342,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", default=None,
                     choices=("kernels", "serve", "serve_hybrid",
-                             "serve_looped", "train"),
+                             "serve_looped", "serve_swa", "train"),
                     help="one chip: run this phase alone (default: all)")
     args = ap.parse_args(argv)
     t0 = time.time()
@@ -1262,6 +1354,7 @@ def main(argv=None) -> int:
             phases = {"kernels": phase_kernels, "serve": phase_serve,
                       "serve_hybrid": phase_serve_hybrid,
                       "serve_looped": phase_serve_looped,
+                      "serve_swa": phase_serve_swa,
                       "train": phase_train}
             for name, phase in phases.items():
                 if args.phase in (None, name):

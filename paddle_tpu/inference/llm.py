@@ -59,6 +59,7 @@ from ..ops.paged_attention import (KV_DTYPES, QuantizedKV, _split_kv,
                                    kv_nbytes, kv_page_size,
                                    kv_scale_nbytes, kv_zeros)
 from ..reliability import faults as _faults
+from .page_pool import PagePool
 from ..reliability.retry import Deadline, DeadlineExceeded, as_deadline
 
 # How every engine program is compiled for a TPU. XLA:TPU's memory-space
@@ -211,6 +212,15 @@ def _engine_metrics():
             "llm_loop_steps_total",
             "passes of a looped model's stack run for the tokens it "
             "delivered (exit step + 1 each)"),
+        "kv_pages_in_use": reg.gauge(
+            "llm_kv_pages_in_use",
+            "allocated K/V pages, by cache group (page_pool.py)",
+            label_names=("group",)),
+        "kv_pages_released": reg.counter(
+            "llm_kv_pages_released_total",
+            "pages a window cache group freed behind its window while "
+            "their sequence was still live, by cache group",
+            label_names=("group",)),
         "state_rows": reg.gauge(
             "llm_state_rows_in_use",
             "slots whose recurrent-state row (conv + SSM) holds a live "
@@ -548,7 +558,8 @@ class RaggedRows(NamedTuple):
     tokens: jax.Array
     positions: jax.Array
     limits: jax.Array
-    tables: jax.Array
+    tables: Any     # [T, pages]; a tuple of them, one a cache group, for
+    #                 a model whose kv_cache_spec() is a list
     n_chunk: int = 0
     chunk_seg: Optional[jax.Array] = None
     seg_rows: Optional[jax.Array] = None
@@ -556,7 +567,9 @@ class RaggedRows(NamedTuple):
 
 class CacheView(NamedTuple):
     """What the ENGINE owns and hands the model's forward: the stacked
-    paged K/V pool and, for a model with recurrent state, one
+    paged K/V pool (a tuple of pools, one a cache group, for a model whose
+    ``kv_cache_spec()`` is a list: ``page_pool.py``) and, for a model with
+    recurrent state, one
     fixed-size ``conv_state`` / ``ssm_state`` row a slot (a tuple, one
     ``[max_seqs + 1, ...]`` array a state-space layer: row ``max_seqs``
     is the scratch row that padded rows write; ``None`` for a model
@@ -609,6 +622,24 @@ class RecurrentStateUnsupported(ValueError):
     names it: ``"speculative_verify"`` (a rejected draft token cannot be
     rolled out of the state), ``"kv_page_migration"`` (the
     ``kv_pages/v1`` payload carries no state)."""
+
+    def __init__(self, mechanism: str, msg: str):
+        super().__init__(msg)
+        self.mechanism = mechanism
+
+
+class CacheGroupUnsupported(ValueError):
+    """An engine mode that assumes one block table and one page lifetime
+    was asked of a model with a WINDOW cache group (``page_pool.py``).
+    ``mechanism`` names it: ``"speculative_verify"`` (a rejected draft
+    row may already have pushed pages out of the window),
+    ``"kv_page_migration"`` (the ``kv_pages/v1`` payload is one group's),
+    ``"fused_slab"`` and ``"lookahead"`` (pages are released between
+    ticks, by the host); ``"prefix_reuse"`` is switched off instead
+    (``/statusz``): a page keyed by its tokens may be gone."""
+
+    WINDOW_MODES = ("prefix_reuse", "kv_page_migration",
+                    "speculative_verify", "fused_slab", "lookahead")
 
     def __init__(self, mechanism: str, msg: str):
         super().__init__(msg)
@@ -805,7 +836,9 @@ class _MixedTick(Layer):
         rows = RaggedRows(jnp.concatenate([ptok, dtok]),
                           jnp.concatenate([ppos, dpos]),
                           jnp.concatenate([plim, dlens]),
-                          jnp.concatenate([ptbl, tables], axis=0),
+                          jax.tree_util.tree_map(
+                              lambda a, b: jnp.concatenate([a, b], axis=0),
+                              ptbl, tables),
                           c, pseg, seg_rows)
         hidden, cache, aux = self.net.ragged_forward(
             rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
@@ -1051,6 +1084,14 @@ def _engine_status_provider(ref):
                 "enabled": False,
                 "reason": "recurrent state: a page hit would skip "
                           "tokens the state needs"}
+        out["cache_groups"] = [g.status() for g in eng._pool.groups]
+        if eng._pool.windowed:
+            out["cache_groups_unsupported"] = list(
+                CacheGroupUnsupported.WINDOW_MODES)
+            out["prefix_cache"] = {
+                "enabled": False,
+                "reason": "a window cache group: a page keyed by its "
+                          "tokens may have been freed behind the window"}
         if eng._moe_spec is not None:
             out["moe"] = {
                 "moe_impl": eng.moe_impl,
@@ -1261,17 +1302,33 @@ class LLMEngine:
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
                 f"{sorted(KV_DTYPES)}")
         self.kv_dtype = kv_dtype
-        kv_layers, kv_heads, kv_hd = net.kv_cache_spec()
-        self.k_pages = kv_zeros(
-            (kv_layers, num_pages, page_size, kv_heads, kv_hd), kv_dtype)
-        self.v_pages = jax.tree_util.tree_map(jnp.zeros_like,
-                                              self.k_pages)
-        # host-side control plane (numpy: mutated by the allocator)
-        self.block_tables = np.zeros((max_seqs, self.pages_per_seq),
-                                     np.int32)
+        self.prefill_chunk = int(prefill_chunk or
+                                 self.prefill_buckets[0])
+        # the paged K/V pool: one group of cache layers a page shape and
+        # lifetime (page_pool.py); block tables and free lists are host
+        # control plane, mutated by the allocator there
+        self._pool = PagePool(net.kv_cache_spec(), num_pages, page_size,
+                              max_seqs, self.pages_per_seq, kv_dtype,
+                              self.prefill_chunk)
         self.context_lens = np.zeros((max_seqs,), np.int32)
         self.temperatures = np.zeros((max_seqs,), np.float32)
-        self._free_pages = list(range(num_pages - 1, 0, -1))  # 0=scratch
+        if self._pool.windowed:
+            # pages of a window group go back to the free list behind
+            # the window: what assumes a page lives as long as its
+            # sequence is refused by name, or switched off (/statusz)
+            for mechanism, asked in (
+                    ("speculative_verify", draft_net is not None),
+                    ("lookahead", bool(lookahead)),
+                    ("fused_slab", int(
+                        decode_ticks_per_dispatch or _flags.get_flag(
+                            "decode_ticks_per_dispatch")) > 1)):
+                if asked:
+                    raise CacheGroupUnsupported(
+                        mechanism,
+                        f"{mechanism} does not compose with a model that "
+                        f"has a window cache group: the host releases "
+                        f"pages behind the window after every tick")
+            prefix_cache = False
         # A SECOND KIND OF CACHE beside the page pool: a model with
         # recurrent state (state-space layers) holds, per slot and
         # whatever the sequence's length, one conv_state and one
@@ -1309,7 +1366,8 @@ class LLMEngine:
             self._n_aux = max_seqs
             self.loop_exit_step_rows = np.zeros(self._loop_steps,
                                                 np.int64)
-        self._kv_cache_layers = kv_layers
+        self._kv_cache_layers = sum(g.group.layers
+                                    for g in self._pool.groups)
         if spec is not None:
             if draft_net is not None:
                 raise RecurrentStateUnsupported(
@@ -1415,12 +1473,10 @@ class LLMEngine:
         self._nonce_seq = 0
         # chunked-prefill work queue (admitted, suffix not yet computed)
         self._prefill_q: deque = deque()
-        self.prefill_chunk = int(prefill_chunk or
-                                 self.prefill_buckets[0])
 
         # what the platform of the pool's device calls for
-        pool, _ = _split_kv(self.k_pages)
-        on_tpu = all(d.platform == "tpu" for d in pool.devices())
+        on_tpu = _all_on_tpu(_split_kv(g.k_pages)[0]
+                             for g in self._pool.groups)
         self._jit_options = {"compiler_options": _TPU_COMPILER_OPTIONS} \
             if on_tpu else {}
         if attention_impl is None:
@@ -1618,6 +1674,7 @@ class LLMEngine:
         from .prefix_cache import PrefixCache
         self._cache = PrefixCache(page_size) if prefix_cache \
             else None
+        self._pool.prefix_cache = self._cache
 
         # THE MIXED SLAB: n_ticks ragged mixed prefill+decode
         # ticks as ONE program. Each tick consumes its slice of
@@ -1862,14 +1919,12 @@ class LLMEngine:
         # are denominated in. Registered ONCE here — the live
         # free/private/shared split is computed by the read, and the
         # DecodeCarry control-plane arrays are a static scratch row.
-        self._tgt_page_bytes = (kv_nbytes(self.k_pages) +
-                                kv_nbytes(self.v_pages)) // num_pages
+        self._tgt_page_bytes = self._pool.page_bytes
         # of which: bytes the int8 scale tables contribute per page
         # (0 for plain pools) — the ledger's distinct "scale_table"
         # row, so "KV pages addable" stays exact under quantization
-        self._tgt_scale_bytes = (kv_scale_nbytes(self.k_pages) +
-                                 kv_scale_nbytes(self.v_pages)) \
-            // num_pages
+        self._tgt_scale_bytes = sum(g.scale_bytes
+                                    for g in self._pool.groups)
         # speculative draft pool: SAME allocator, so its per-page
         # bytes fold into the marginal cost of a page — but the
         # ledger reports it under its own "draft_pool" owner (kv_
@@ -2135,6 +2190,7 @@ class LLMEngine:
                 "state: the kv_pages/v1 payload carries K/V pages and "
                 "no conv/SSM state, and the pages alone are not the "
                 "sequence's context")
+        self._refuse_migration_of_a_window("export_pages")
         if self._cache is None:
             raise RuntimeError(
                 "export_pages requires the prefix cache "
@@ -2144,6 +2200,14 @@ class LLMEngine:
         hexes = [d if isinstance(d, str) else d.hex() for d in digests]
         return self._post_ctl(
             lambda: self._do_export_pages(hexes)).result(timeout=timeout)
+
+    def _refuse_migration_of_a_window(self, what: str) -> None:
+        if self._pool.windowed:
+            raise CacheGroupUnsupported(
+                "kv_page_migration",
+                f"{what} does not compose with a model that has a window "
+                f"cache group: the kv_pages/v1 payload carries one "
+                f"group's pages, and a window group's are not all there")
 
     def import_pages(self, payload: dict, timeout: float = 60.0) -> dict:
         """Verify and install a ``kv_pages/v1`` payload as shared,
@@ -2162,6 +2226,7 @@ class LLMEngine:
                 "state: the kv_pages/v1 payload carries K/V pages and "
                 "no conv/SSM state, and the pages alone are not the "
                 "sequence's context")
+        self._refuse_migration_of_a_window("import_pages")
         if self._cache is None:
             raise RuntimeError(
                 "import_pages requires the prefix cache "
@@ -2243,7 +2308,7 @@ class LLMEngine:
             if cache.page_of(rec.digest) is not None:
                 dups += 1
                 continue
-            pg = self._alloc_page()
+            pg = self._pool.alloc()
             if pg is None:
                 # pool exhausted: the rest of the chain cannot install
                 # (and would be unmatchable behind the gap anyway) —
@@ -2324,39 +2389,22 @@ class LLMEngine:
         self.close()
 
     # -- scheduler ----------------------------------------------------------
-    def _alloc_page(self) -> Optional[int]:
-        if self._free_pages:
-            return self._free_pages.pop()
-        if self._cache is not None and self._cache.evictable_count:
-            # LRU eviction over refcount-zero cached pages; pages
-            # mapped by a live sequence (ref > 0) are never candidates
-            return self._cache.evict_one()
-        return None
+    # the pool's arrays and the first group's host state under the names
+    # they had when the engine held them inline (page_pool.py owns them)
+    k_pages = property(lambda self: self._pool.k_pages,
+                       lambda self, v: setattr(self._pool, "k_pages", v))
+    v_pages = property(lambda self: self._pool.v_pages,
+                       lambda self, v: setattr(self._pool, "v_pages", v))
+    block_tables = property(lambda self: self._pool.groups[0].tables)
+    _free_pages = property(lambda self: self._pool.groups[0].free)
 
     def _avail_pages(self) -> int:
-        """Pages the allocator could produce right now (free pool +
-        evictable refcount-zero cache residents)."""
-        n = len(self._free_pages)
-        if self._cache is not None:
-            n += self._cache.evictable_count
-        return n
-
-    def _ensure_page(self, slot: int, pos: int) -> bool:
-        """Page for token position ``pos`` allocated? Allocate on
-        demand; False → pool exhausted."""
-        idx = pos // self.page_size
-        if idx >= self.pages_per_seq:
-            return False
-        if self.block_tables[slot, idx] == 0:
-            page = self._alloc_page()
-            if page is None:
-                return False
-            self.block_tables[slot, idx] = page
-        return True
+        return self._pool.avail()
 
     def _update_kv_gauge(self):
-        usable = self.num_pages - 1
-        self._m["kv_util"].set((usable - len(self._free_pages)) / usable)
+        self._m["kv_util"].set(self._pool.utilization())
+        for g in self._pool.groups:
+            self._m["kv_pages_in_use"].labels(group=g.name).set(g.in_use)
         if self._state_spec is not None:
             self._m["state_rows"].set(
                 sum(1 for r in self._slots if r is not None))
@@ -2364,18 +2412,7 @@ class LLMEngine:
             self._m["shared_pages"].set(self._cache.shared_page_count)
 
     def _free_slot(self, slot: int):
-        for idx in range(self.pages_per_seq):
-            page = int(self.block_tables[slot, idx])
-            if page > 0:
-                if self._cache is not None and \
-                        self._cache.is_shared(page):
-                    # shared page: drop this sequence's reference; at
-                    # zero it stays CACHED (evictable) — its KV is the
-                    # whole point of the prefix cache
-                    self._cache.release(page)
-                else:
-                    self._free_pages.append(page)
-        self.block_tables[slot] = 0
+        self._pool.free_slot(slot)
         self.context_lens[slot] = 0
         self._slots[slot] = None
         self._update_kv_gauge()
@@ -2702,8 +2739,8 @@ class LLMEngine:
         if self._health == "draining":
             return "shed"
         n = len(req.prompt)
-        need_total = -(-n // self.page_size)
-        if need_total > min(self.num_pages - 1, self.pages_per_seq):
+        n_total = min(n + req.max_new_tokens, self.max_len)
+        if not self._pool.fits(n, n_total):
             return "never"
         slot = next((i for i, s in enumerate(self._slots) if s is None),
                     None)
@@ -2724,7 +2761,7 @@ class LLMEngine:
         # acquired — don't count them as allocatable too
         reserved = sum(1 for p in matched if self._cache.is_evictable(p)
                        ) if self._cache is not None else 0
-        if need_total - m > self._avail_pages() - reserved:
+        if self._pool.admission(n, n_total, m, reserved) != "ok":
             # pages held by running sequences will free; a pool this
             # empty while IDLE can never satisfy the request
             active = any(s is not None for s in self._slots)
@@ -2738,11 +2775,9 @@ class LLMEngine:
             # overlapping requests: N queued seconds over one wall
             # second is one second of queue_wait)
             _goodput.note("queue_wait", qdt)
-        for idx, page in enumerate(matched):
+        for page in matched:
             self._cache.acquire(page)
-            self.block_tables[slot, idx] = page
-        for idx in range(m, need_total):
-            self.block_tables[slot, idx] = self._alloc_page()
+        self._pool.admit(slot, n, n_total, matched)
         req.slot = slot
         req.n_cached = m * self.page_size
         req.prefill_pos = req.n_cached
@@ -2802,7 +2837,7 @@ class LLMEngine:
         tok = np.zeros((T,), np.int32)
         pos = np.zeros((T,), np.int32)
         lim = np.zeros((T,), np.int32)
-        tbl = np.zeros((T, self.pages_per_seq), np.int32)
+        row_slot = np.full((T,), -1, np.int64)   # padded rows: scratch
         sample_idx = np.zeros((self.max_seqs,), np.int32)
         sample_pos = np.zeros((self.max_seqs,), np.int32)
         finishing: List[_Request] = []
@@ -2815,13 +2850,13 @@ class LLMEngine:
             req = self._prefill_q[0]
             n = len(req.prompt)
             take = min(T - used, n - req.prefill_pos)
-            row = self.block_tables[req.slot]
+            self._pool.ensure_range(req.slot, req.prefill_pos, take)
             for j in range(take):
                 p = req.prefill_pos + j
                 tok[used + j] = req.prompt[p]
                 pos[used + j] = p
                 lim[used + j] = p + 1
-                tbl[used + j] = row
+            row_slot[used:used + take] = req.slot
             if seg is not None:
                 seg[used:used + take] = len(chunks)
                 segrows[len(chunks)] = req.slot
@@ -2844,7 +2879,8 @@ class LLMEngine:
         self._guard_recompiles("prefill")
         chunk_args = (self._params, self._buffers, jnp.asarray(tok),
                       jnp.asarray(pos), jnp.asarray(lim),
-                      jnp.asarray(tbl), jnp.asarray(sample_idx),
+                      self._pool.row_tables(row_slot),
+                      jnp.asarray(sample_idx),
                       jnp.asarray(sample_pos),
                       self.k_pages, self.v_pages,
                       jnp.asarray(self.temperatures),
@@ -2859,10 +2895,13 @@ class LLMEngine:
         nxt, fetch = self._take_outputs(self._chunk_fn(*chunk_args))
         self._count_dispatch()
         self._stamp_state(ph, False, len(chunks), 0)
+        released = self._release_behind(
+            (slot, p0 + take) for slot, p0, take in chunks)
         if ph is not _trace.NOOP_SPAN:
             rows = self._chunk_limits(chunks)
             self._stamp_kv_pages(ph, (rows, T, self.attention_impl),
-                                 *self._draft_chunk_call(rows, T))
+                                 *self._draft_chunk_call(rows, T),
+                                 released=released)
         if self.spec_k:
             # draft ride-along: the SAME packed chunk schedule runs
             # through the draft net so the draft pool holds valid KV
@@ -3404,7 +3443,22 @@ class LLMEngine:
         ph.set_attr("loop_steps", passes) \
             .set_attr("kv_cache_layers", self._kv_cache_layers)
 
-    def _stamp_kv_pages(self, ph, *calls) -> None:
+    def _release_behind(self, next_positions) -> int:
+        """After a dispatch: each of ``(slot, next position)`` gives its
+        window groups' pages behind the window back (none for a pool
+        without a window group). Returns the pages released."""
+        if not self._pool.windowed:
+            return 0
+        before = [g.n_released for g in self._pool.groups]
+        n = sum(self._pool.release_behind(slot, int(nxt))
+                for slot, nxt in next_positions)
+        for g, was in zip(self._pool.groups, before):
+            if g.n_released > was:
+                self._m["kv_pages_released"].labels(group=g.name).inc(
+                    g.n_released - was)
+        return n
+
+    def _stamp_kv_pages(self, ph, *calls, released: int = 0) -> None:
         """``kv_pages_read`` and ``kv_pages_live`` of one dispatch, on
         its issue phase (so only while tracing is active). Each of
         ``calls`` is one attention call site of the dispatch's
@@ -3427,19 +3481,25 @@ class LLMEngine:
         if self._loop_steps is not None:
             ph.set_attr("loop_steps", self._loop_steps) \
                 .set_attr("kv_cache_layers", self._kv_cache_layers)
-        ps = self.page_size
-        read = 0
-        live: Dict[Any, int] = {}
-        for rows, padded_rows, impl in calls:
-            for seq, limit in rows:
-                pages = -(-int(limit) // ps)
-                live[seq] = max(live.get(seq, 0), pages)
-                if impl == "pallas":
-                    read += pages
-            if impl != "pallas":
-                read += padded_rows * self.pages_per_seq
-        ph.set_attr("kv_pages_read", read) \
-            .set_attr("kv_pages_live", sum(live.values()))
+        groups = self._pool.pages_touched(calls)
+        ph.set_attr("kv_pages_read",
+                    sum(g["read"] for g in groups.values())) \
+            .set_attr("kv_pages_live",
+                      sum(g["live"] for g in groups.values()))
+        if len(groups) > 1:
+            # a pool of several cache groups: the sums above, by group
+            # (a group's page has its own bytes), what the live slots
+            # hold and how long their contexts are
+            slots = [i for i, r in enumerate(self._slots) if r is not None]
+            for g in self._pool.groups:
+                groups[g.name].update(
+                    bytes_held=int(g.held[slots].sum()) * g.page_bytes,
+                    page_bytes=g.page_bytes)
+            ph.set_attr("kv_groups", groups) \
+                .set_attr("context_tokens",
+                          int(self.context_lens[slots].sum())
+                          + sum(r.prefill_pos for r in self._prefill_q)) \
+                .set_attr("window_pages_released", released)
 
     @staticmethod
     def _chunk_limits(chunks) -> List[tuple]:
@@ -3492,7 +3552,7 @@ class LLMEngine:
                     live.remove(slot)
                     continue
                 pos = int(self.context_lens[slot])
-                if pos >= self.max_len or not self._ensure_page(slot, pos):
+                if pos >= self.max_len or not self._pool.ensure(slot, pos):
                     # in-flight steps cannot cover the remainder (checked
                     # above), so this IS a truncation; the in-flight tokens
                     # are still wanted and delivered by the drain
@@ -3511,7 +3571,7 @@ class LLMEngine:
             self._guard_recompiles("decode_step")
             args = (self._params, self._buffers,
                     self._tokens_dev, jnp.asarray(positions),
-                    jnp.asarray(self.block_tables), jnp.asarray(lens),
+                    self._pool.device_tables(), jnp.asarray(lens),
                     self.k_pages, self.v_pages,
                     jnp.asarray(self.temperatures),
                     jnp.asarray(self._nonces), self._key) \
@@ -3527,11 +3587,13 @@ class LLMEngine:
             ph.set_attr("issue_seq", self._issue_seq) \
                 .set_attr("live_rows", len(live)).set_attr("ticks", 1)
             self._stamp_state(ph, True, 0, len(live))
-            self._stamp_kv_pages(
-                ph, (((slot, lens[slot]) for slot in live),
-                     self.max_seqs, self.attention_impl))
             for slot in live:
                 self.context_lens[slot] += 1
+            self._stamp_kv_pages(
+                ph, ([(slot, lens[slot]) for slot in live],
+                     self.max_seqs, self.attention_impl),
+                released=self._release_behind(
+                    (slot, lens[slot]) for slot in live))
             self.n_decode_ticks += 1
             self.tick_history.append("d")
             self._m["decode_ticks"].inc()
@@ -3554,7 +3616,7 @@ class LLMEngine:
         ``entry_bud[slot]`` the slab-entry emission budget."""
         ps = self.page_size
         plan: Dict[int, tuple] = {}   # slot -> (pos0, covered, want)
-        new_pages: List[tuple] = []   # (slot, idx) allocated here
+        new_pages: Dict[int, list] = {}  # slot -> (group, idx) from here
         for slot in list(live):
             req = self._slots[slot]
             in_flight = self._inflight_tokens(slot)
@@ -3567,15 +3629,9 @@ class LLMEngine:
             covered = 0
             for j in range(min(N, want)):
                 pos = pos0 + j
-                if pos >= self.max_len:
+                if pos >= self.max_len or not self._pool.ensure(
+                        slot, pos, new_pages.setdefault(slot, [])):
                     break
-                idx = pos // ps
-                if self.block_tables[slot, idx] == 0:
-                    page = self._alloc_page()
-                    if page is None:
-                        break
-                    self.block_tables[slot, idx] = page
-                    new_pages.append((slot, idx))
                 covered += 1
             if covered == 0:
                 # the NEXT token can't be cached — the same condition
@@ -3592,12 +3648,13 @@ class LLMEngine:
                 n_eff = min(n_eff, covered)
         entry_bud = {slot: min(n_eff, want, covered)
                      for slot, (pos0, covered, want) in plan.items()}
-        for slot, idx in new_pages:
-            pos0 = plan[slot][0]
-            if idx > (pos0 + entry_bud[slot] - 1) // ps:
-                self._free_pages.append(
-                    int(self.block_tables[slot, idx]))
-                self.block_tables[slot, idx] = 0
+        for slot, pages in new_pages.items():
+            if slot not in plan:
+                continue
+            last = (plan[slot][0] + entry_bud[slot] - 1) // ps
+            for group, idx in pages:
+                if idx > last:
+                    self._pool.unmap(group, slot, idx)
         return plan, entry_bud, n_eff
 
     def _issue_slab(self, live: List[int]):
@@ -3637,7 +3694,7 @@ class LLMEngine:
                 bud_arr[slot] = budgets[slot]
             carry = self._new_carry(pos_arr, bud_arr)
             slab_args = (self._params, self._buffers, carry,
-                         jnp.asarray(self.block_tables),
+                         self._pool.device_tables(),
                          jnp.asarray(self.temperatures),
                          jnp.asarray(self._nonces), self._key, n_eff)
             if _perf.enabled():
@@ -3708,7 +3765,7 @@ class LLMEngine:
             ptok = np.zeros((n_eff, C), np.int32)
             ppos = np.zeros((n_eff, C), np.int32)
             plim = np.zeros((n_eff, C), np.int32)
-            ptbl = np.zeros((n_eff, C, self.pages_per_seq), np.int32)
+            pslot = np.full((n_eff, C), -1, np.int64)  # padded: scratch
             fin = np.zeros((n_eff, self.max_seqs), bool)
             fin_row = np.zeros((n_eff, self.max_seqs), np.int32)
             fin_pos = np.zeros((n_eff, self.max_seqs), np.int32)
@@ -3734,13 +3791,14 @@ class LLMEngine:
                     req = self._prefill_q[0]
                     n = len(req.prompt)
                     take = min(C - used, n - req.prefill_pos)
-                    row = self.block_tables[req.slot]
+                    self._pool.ensure_range(req.slot, req.prefill_pos,
+                                            take)
                     for t in range(take):
                         p = req.prefill_pos + t
                         ptok[j, used + t] = req.prompt[p]
                         ppos[j, used + t] = p
                         plim[j, used + t] = p + 1
-                        ptbl[j, used + t] = row
+                    pslot[j, used:used + take] = req.slot
                     if pseg is not None:
                         pseg[j, used:used + take] = nseg
                         segrows[j, nseg] = req.slot
@@ -3771,14 +3829,9 @@ class LLMEngine:
                         g = 1
                         for tt in range(1, g_want):
                             pos = n + tt - 1
-                            if pos >= self.max_len:
+                            if pos >= self.max_len or \
+                                    not self._pool.ensure(req.slot, pos):
                                 break
-                            idx = pos // ps
-                            if self.block_tables[req.slot, idx] == 0:
-                                page = self._alloc_page()
-                                if page is None:
-                                    break
-                                self.block_tables[req.slot, idx] = page
                             g += 1
                         fin[j, req.slot] = True
                         fin_row[j, req.slot] = used - 1
@@ -3830,7 +3883,7 @@ class LLMEngine:
             xs = {"tok": jnp.asarray(ptok[:n_run]),
                   "pos": jnp.asarray(ppos[:n_run]),
                   "lim": jnp.asarray(plim[:n_run]),
-                  "tbl": jnp.asarray(ptbl[:n_run]),
+                  "tbl": self._pool.row_tables(pslot[:n_run]),
                   "fin": jnp.asarray(fin[:n_run]),
                   "row": jnp.asarray(fin_row[:n_run]),
                   "fpos": jnp.asarray(fin_pos[:n_run]),
@@ -3839,7 +3892,7 @@ class LLMEngine:
                 xs["seg"] = jnp.asarray(pseg[:n_run])
                 xs["segrows"] = jnp.asarray(segrows[:n_run])
             mixed_args = (self._params, self._buffers, carry, xs,
-                          jnp.asarray(self.block_tables),
+                          self._pool.device_tables(),
                           jnp.asarray(self.temperatures),
                           jnp.asarray(self._nonces), self._key, n_run)
             if _perf.enabled():
@@ -3860,8 +3913,8 @@ class LLMEngine:
                         jnp.asarray(ptok[:n_run].reshape(-1)),
                         jnp.asarray(ppos[:n_run].reshape(-1)),
                         jnp.asarray(plim[:n_run].reshape(-1)),
-                        jnp.asarray(ptbl[:n_run].reshape(
-                            -1, self.pages_per_seq)),
+                        self._pool.row_tables(
+                            pslot[:n_run].reshape(-1)),
                         zeros, zeros,
                         self.draft_k_pages, self.draft_v_pages,
                         jnp.asarray(self.temperatures),
@@ -3882,6 +3935,10 @@ class LLMEngine:
                 .set_attr("ticks", n_run)
             self._stamp_state(ph, True, len(touched),
                               len(slots_list) - len(start))
+            released = self._release_behind(
+                [(slot, p0 + take) for slot, p0, take in chunks]
+                + [(slot, meta_pos0[slot] + meta_bud[slot])
+                   for slot in plan])
             if ph is not _trace.NOOP_SPAN:
                 chunk_rows = self._chunk_limits(chunks)
                 # a decode row of tick j attends pos0 + j + 1; a slot
@@ -3895,7 +3952,8 @@ class LLMEngine:
                     ph, (chunk_rows + decode_rows,
                          (C + self.max_seqs) * n_run,
                          self.attention_impl),
-                    *self._draft_chunk_call(chunk_rows, C * n_run))
+                    *self._draft_chunk_call(chunk_rows, C * n_run),
+                    released=released)
             if self._cache is not None:
                 for req in touched:
                     # promote freshly-written FULL prompt pages to shared
@@ -3966,7 +4024,7 @@ class LLMEngine:
                 for j in range(min(N * K, want)):
                     pos = pos0 + j
                     if pos >= self.max_len or \
-                            not self._ensure_page(slot, pos):
+                            not self._pool.ensure(slot, pos):
                         break
                     covered += 1
                 if covered == 0:
@@ -3999,7 +4057,7 @@ class LLMEngine:
                 draft_v_pages=self.draft_v_pages)
             args = (self._params, self._buffers, self._draft_params,
                     self._draft_buffers, carry,
-                    jnp.asarray(self.block_tables),
+                    self._pool.device_tables(),
                     jnp.asarray(self.temperatures),
                     jnp.asarray(self._nonces), jnp.asarray(cov),
                     self._key, N)

@@ -51,12 +51,14 @@ class DroplessMoE(Layer):
     """``forward(x [T, d], valid [T] bool or None, impl)`` ->
     ``(y [T, d], rows_held [count] int32)``; ``rows_held[e]`` is how many
     valid rows expert ``first + e`` received. ``impl`` names the grouped
-    product: ``"xla"`` or ``"pallas"``."""
+    product: ``"xla"`` or ``"pallas"``. ``routed_scaling_factor``
+    multiplies every gate (a model's ``moe_routed_scaling_factor``)."""
 
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int,
                  experts_held: Optional[Tuple[int, int]] = None,
-                 initializer_range: float = 0.02):
+                 initializer_range: float = 0.02,
+                 routed_scaling_factor: float = 1.0):
         super().__init__()
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1
@@ -69,6 +71,7 @@ class DroplessMoE(Layer):
         self.num_experts, self.top_k = num_experts, top_k
         self.first, self.count = first, count
         self.d_expert = d_expert
+        self.routed_scaling_factor = float(routed_scaling_factor)
         init = I.Normal(0.0, initializer_range)
         self.router = self.create_parameter([d_model, num_experts],
                                             initializer=init)
@@ -85,6 +88,8 @@ class DroplessMoE(Layer):
         k, count = self.top_k, self.count
         with jax.named_scope("router"):
             idx, gates = route_top_k(x, self.router, k)
+            if self.routed_scaling_factor != 1.0:
+                gates = gates * self.routed_scaling_factor
             local = idx - self.first
             held = (local >= 0) & (local < count)
             if valid is not None:

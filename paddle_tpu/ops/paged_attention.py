@@ -228,6 +228,8 @@ class PagedKVCache:
 _PAGE_BUFFER_BYTES = 4 * 1024 * 1024
 # tokens folded into the softmax state at a time
 _GROUP_TOKENS = 64
+# query heads a K/V head up to which the fold stays on the VPU
+_VPU_GROUP_ROWS = 4
 
 
 def _pages_per_block(page_size: int, kv_heads: int, d: int, dtype,
@@ -249,7 +251,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
                            context_lens, layer=None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, starts=None):
     """Fused Pallas attention over the paged KV pool (Ragged-Paged-
     Attention lineage): every row of ``q`` attends the first
     ``context_lens[row]`` cached positions of the sequence whose block
@@ -285,13 +287,25 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     rest a page at a time): enough independent work a step to keep the
     VPU busy, few enough that the float32 copies stay small. GQA is
     native: q arrives as [group, kv_heads, d] and each group row reuses
-    the pages in VMEM.
+    the pages in VMEM. Over ``_VPU_GROUP_ROWS`` query heads a K/V head
+    that per-row work is the kernel's time (a page costs every group row
+    a multiply and a lane reduction), and the fold becomes two MXU
+    products over the pages as they lie (``attend_pages_on_mxu``): the
+    pool's type for the operands, float32 for the sums and the softmax
+    state.
 
     int8 KV (``k_scales``/``v_scales`` ``[L, num_pages, page_size]``, or
     without the layer axis beside a four-dimensional store): the pages
     cross HBM as int8 and are dequantized in VMEM. The scale rows of a
     row's table are gathered beside the kernel (4 bytes a token where a
     page row has ``kv_heads * d``) and ride into SMEM with the row.
+
+    ``starts`` [rows] (a sliding window's lower bound; None = 0, and
+    the program of today): row ``r`` attends positions ``starts[r] <= j
+    < context_lens[r]``. Its walk begins at page ``starts[r] //
+    page_size``: no page before that one is fetched, and the tokens of
+    that page below ``starts[r]`` are masked, so the bytes a windowed
+    row moves follow its window and not its length.
 
     ``interpret`` defaults to the module switch
     ``flash_attention.INTERPRET`` (False: the kernel compiles for the
@@ -309,7 +323,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
                              block_tables.shape[1])
     return _paged_attention_call(
         q, k_pages, v_pages, block_tables, context_lens,
-        jnp.asarray(layer, jnp.int32), k_scales, v_scales,
+        jnp.asarray(layer, jnp.int32), k_scales, v_scales, starts,
         scale=scale if scale is not None else 1.0 / math.sqrt(d),
         interpret=bool(interpret), block=block,
         group_pages=max(1, min(block, _GROUP_TOKENS // page_size)))
@@ -318,16 +332,22 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
                                              "group_pages"))
 def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
-                          layer, k_scales, v_scales, *, scale, interpret,
-                          block, group_pages):
+                          layer, k_scales, v_scales, starts=None, *, scale,
+                          interpret, block, group_pages):
     """:func:`paged_attention_kernel` on the stacked pool with a traced
     ``layer``. Jitted so that an engine program, which calls it once a
     layer with the same shapes, traces and lowers the kernel once."""
     quantized = k_scales is not None
+    windowed = starts is not None
     rows, n_heads, d = q.shape
     _, _, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     group = n_heads // kv_heads
+    # few query heads a K/V head: a broadcast-multiply and a lane
+    # reduction a group row on the VPU (decode attention is matrix-vector
+    # work). Many (the per-page cost grows with every group row: 1.1 us a
+    # page at 9, my chip run, PR 35): the fold as two MXU products
+    on_mxu = group > _VPU_GROUP_ROWS and not quantized
 
     # [rows, group, kv_heads, d]: a group row is one (kv_heads, d) tile
     qg = q.reshape(rows, kv_heads, group, d).transpose(0, 2, 1, 3)
@@ -342,9 +362,14 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
     next_live = jnp.concatenate(
         [live_from[1:], jnp.full((1,), rows, jnp.int32)])
     meta = jnp.stack([layer, live_from[0]])
+    if windowed:
+        starts = jnp.clip(starts.astype(jnp.int32), 0, lens)
 
-    def kernel(len_ref, tbl_ref, next_ref, meta_ref, q_ref, k_hbm,
-               v_hbm, *rest):
+    def kernel(len_ref, tbl_ref, next_ref, meta_ref, *rest):
+        if windowed:
+            start_ref, rest = rest[0], rest[1:]
+        q_ref, k_hbm, v_hbm = rest[:3]
+        rest = rest[3:]
         if quantized:
             ks_ref, vs_ref = rest[:2]
             rest = rest[2:]
@@ -352,13 +377,21 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
          l_ref) = rest
         r = pl.program_id(0)
         ctx = len_ref[r]
-        n_pages = pl.cdiv(ctx, page_size)
+
+        def first_page_of(row):
+            """The page a row's walk begins at: that of its lower
+            bound."""
+            return start_ref[row] // page_size if windowed else 0
+
+        lo = start_ref[r] if windowed else 0
+        page0 = first_page_of(r)
+        n_pages = pl.cdiv(ctx, page_size) - page0
         n_blocks = pl.cdiv(n_pages, block)
 
         def block_copies(row, blk, slot, do):
             """``do`` (start or wait) the K and V copy of each live page
             of block ``blk`` of ``row`` into ``slot``."""
-            first_page = blk * block
+            first_page = first_page_of(row) + blk * block
             here = jnp.minimum(
                 block, pl.cdiv(len_ref[row], page_size) - first_page)
 
@@ -392,9 +425,13 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                                for t in range(tokens)])
                 v = jnp.stack([v[t] * vs_ref[0, 0, first_token + t]
                                for t in range(tokens)])
+            if on_mxu:
+                return attend_pages_on_mxu(k, v, tokens, first_token)
             token = jax.lax.broadcasted_iota(
                 jnp.int32, (tokens, kv_heads, 1), 0)
             valid = token < ctx - first_token
+            if windowed:
+                valid = valid & (token >= lo - first_token)
             for g in range(group):
                 qb = q_ref[0, g].astype(jnp.float32)  # [kvh, d]
                 s = jnp.sum(qb[None] * k, axis=-1,
@@ -410,6 +447,50 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                                                           axis=0)
                 m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
                 l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        def attend_pages_on_mxu(k, v, tokens, first_token):
+            """The same fold as two products on the MXU, for many query
+            heads a K/V head: the rows' ``group x kv_heads`` query rows
+            against ALL ``tokens x kv_heads`` key rows of the pages as
+            they lie (one product, the entries of another K/V head
+            masked: an eighth of it is used, and the MXU has it to
+            spare), the softmax state ``[group x kv_heads]`` rows wide,
+            and the probabilities against the value rows. Operands in the
+            pool's type (bf16 pages: one pass each, float32 sums), the
+            probabilities rounded to it for the second product."""
+            rows = group * kv_heads
+            cols = tokens * kv_heads
+            exact = jax.lax.Precision.HIGHEST \
+                if k_pages.dtype == jnp.float32 else None
+            qb = q_ref[0].astype(jnp.float32).reshape(rows, d) \
+                .astype(k_pages.dtype)
+            kb = k.reshape(cols, d).astype(k_pages.dtype)
+            vb = v.reshape(cols, d).astype(k_pages.dtype)
+            s = jax.lax.dot_general(
+                qb, kb, (((1,), (1,)), ((), ())), precision=exact,
+                preferred_element_type=jnp.float32) * scale  # [rows, cols]
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+            token = col // kv_heads
+            valid = (row % kv_heads == col % kv_heads) \
+                & (token < ctx - first_token)
+            if windowed:
+                valid = valid & (token >= lo - first_token)
+            s = jnp.where(valid, s, _MASK_VALUE)
+            m_prev = m_ref[...].reshape(rows, _LANES)[:, :1]
+            l_prev = l_ref[...].reshape(rows, _LANES)[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p_ = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p_, axis=1, keepdims=True)
+            acc = acc_ref[...].reshape(rows, d) * alpha + jax.lax.dot_general(
+                p_.astype(k_pages.dtype), vb, (((1,), (0,)), ((), ())),
+                precision=exact, preferred_element_type=jnp.float32)
+            acc_ref[...] = acc.reshape(group, kv_heads, d)
+            m_ref[...] = jnp.broadcast_to(m_new, (rows, _LANES)).reshape(
+                m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, (rows, _LANES)).reshape(
+                l_ref.shape)
 
         @pl.when(ctx == 0)
         def _empty():                     # empty slot → a zero row
@@ -446,7 +527,7 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                 def fold(n):
                     def body(i, p):
                         attend_pages(slot, p, n,
-                                     (first_page + p) * page_size)
+                                     (page0 + first_page + p) * page_size)
                         return p + n
                     return body
 
@@ -466,7 +547,9 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                           lambda r, *_: (r, 0, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, hbm_spec, hbm_spec]
-    operands = [lens, tables, next_live, meta, qg, k_pages, v_pages]
+    prefetch = [lens, tables, next_live, meta] \
+        + ([starts] if windowed else [])
+    operands = prefetch + [qg, k_pages, v_pages]
     if quantized:
         # the scale rows of each row's table, [rows, 1, max tokens]: a
         # gather of 4 bytes a token done by XLA beside the kernel, one
@@ -485,7 +568,7 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
     page_buffer = pltpu.VMEM((2, block, page_size, kv_heads, d),
                              k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=len(prefetch),
         grid=(rows,),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -515,7 +598,7 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
 def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
                            token_tables, token_lens,
                            scale: Optional[float] = None,
-                           impl: str = "xla", layer=None):
+                           impl: str = "xla", layer=None, starts=None):
     """THE ragged paged-attention entry point: ONE op serving every
     attention shape the engine dispatches — single-token decodes,
     chunked-prefill suffixes, speculative-verify windows, and a MIXED
@@ -554,6 +637,11 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     stacked pool; the gathered paths take the layer's view first
     (:func:`kv_layer`).
 
+    ``starts`` [T] (None = no lower bound, today's behaviour bit for
+    bit): token t attends positions ``starts[t] <= j < token_lens[t]``,
+    a sliding window's rows. All three paths take it; the kernel fetches
+    no page that lies wholly before a row's ``starts``.
+
     ``impl``: ``"xla"`` (gather of every table entry + dense masked
     softmax, f32 accumulate: the path off the TPU), ``"pallas"``
     (:func:`paged_attention_kernel`: a row's live pages streamed out of
@@ -569,11 +657,11 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
         return paged_attention_kernel(q, kp, vp, token_tables,
                                       token_lens, layer=layer,
                                       scale=scale, k_scales=ks,
-                                      v_scales=vs)
+                                      v_scales=vs, starts=starts)
     if impl == "reference":
         return ragged_paged_attention_reference(
             q, kv_k, kv_v, token_tables, token_lens,
-            scale=scale).astype(q.dtype)
+            scale=scale, starts=starts).astype(q.dtype)
     if impl != "xla":
         raise ValueError(f"unknown impl {impl!r}")
     d = q.shape[-1]
@@ -582,13 +670,19 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     # DIRECTLY (a single cached token — limit 1 — still attends)
     out = _gathered_attention(q[:, None], kp, vp, token_tables,
                               token_lens[:, None], scale,
-                              k_scales=ks, v_scales=vs)
+                              k_scales=ks, v_scales=vs,
+                              start=_column(starts))
     return out[:, 0]
+
+
+def _column(starts):
+    return None if starts is None else starts[:, None]
 
 
 def ragged_paged_attention_reference(q, kv_k: KVStore, kv_v: KVStore,
                                      token_tables, token_lens,
-                                     scale: Optional[float] = None):
+                                     scale: Optional[float] = None,
+                                     starts=None):
     """f32-accumulate reference path (the exactness baseline): same
     contract as :func:`ragged_paged_attention`, but q, the
     (dequantized) pages, and every intermediate are f32 end to end
@@ -603,7 +697,8 @@ def ragged_paged_attention_reference(q, kv_k: KVStore, kv_v: KVStore,
     out = _gathered_attention(q.astype(jnp.float32)[:, None],
                               kp, vp, token_tables,
                               token_lens[:, None], scale,
-                              k_scales=ks, v_scales=vs)
+                              k_scales=ks, v_scales=vs,
+                              start=_column(starts))
     return out[:, 0]
 
 
@@ -665,11 +760,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 
 def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
-                        scale, k_scales=None, v_scales=None):
+                        scale, k_scales=None, v_scales=None, start=None):
     """Shared decode-attention core: gather the block table's pages,
     dequantize (optional per-row scales), expand GQA, masked fp32
     softmax. q [B, K, H, d]; limit [B, K] = attendable cached
-    positions per query (0 → zero output row)."""
+    positions per query (0 → zero output row); ``start`` [B, K] their
+    lower bound (None: 0)."""
     b, kq, n_heads, d = q.shape
     _, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
@@ -697,6 +793,9 @@ def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
         logits = jnp.einsum("bqhd,blhd->bhql", q.astype(jnp.float32),
                             k.astype(jnp.float32)) * scale   # [B,H,K,L]
         mask = jnp.arange(L)[None, None, :] < limit[:, :, None]  # [B,K,L]
+        if start is not None:
+            mask = mask & (jnp.arange(L)[None, None, :]
+                           >= start[:, :, None])
         logits = jnp.where(mask[:, None], logits, -jnp.inf)
         p = jax.nn.softmax(logits, axis=-1)
         # fully-masked rows (limit 0, e.g. a freed slot): zeros, not NaN

@@ -456,3 +456,87 @@ def test_looped_program_keeps_the_pool_in_place_through_its_passes(
     assert mem.alias_size_in_bytes >= 2 * pool
     assert mem.temp_size_in_bytes < pool // 20, (
         mem.temp_size_in_bytes, pool)
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_windowed_program_keeps_both_groups_pools_in_place(
+        one_chip, monkeypatch, program):
+    """The window / full attention model's engine programs at Laguna-S-2.1's
+    widths (hidden 3072, 8 K/V heads of 128), two layers of each kind (48
+    heads on a full layer, 72 on a sliding one: 6 and 9 a K/V head), four of
+    the 256 experts held, the cell's 32 slots, 256-row chunk and 576-column
+    tables: one kernel call a layer, the sliding layers' with a lower bound
+    a row (a fifth scalar-prefetch operand), every routed layer's two
+    grouped products through the kernel, BOTH groups' pools the program's
+    own outputs (aliased) and the temporaries under a quarter of the pools (a copy of one group's K or V
+    would be a quarter; the mixed tick read 28 MB of 168). The
+    scalar-prefetched tables of a mixed tick, [288, 576] int32 a call, must
+    fit SMEM: a compile that passes says they do."""
+    import importlib
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.inference import llm
+    from paddle_tpu.models import LagunaConfig, LagunaForCausalLM
+
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    monkeypatch.setattr(llm, "_moe_impl", lambda net: "pallas")
+    slots, chunk, max_len = 32, 256, 9216
+    cfg = LagunaConfig(
+        num_hidden_layers=4, vocab_size=1024, experts_held=(0, 4),
+        layer_types=["full_attention", "sliding_attention",
+                     "sliding_attention", "full_attention"],
+        num_attention_heads_per_layer=[48, 72, 72, 48])
+    assert (cfg.hidden_size, cfg.num_kv_heads, cfg.head_dim,
+            cfg.sliding_window, cfg.num_experts) == (3072, 8, 128, 512, 256)
+    pt.seed(0)
+    net = LagunaForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    eng = llm.LLMEngine(net, max_seqs=slots, page_size=PAGE, num_pages=1281,
+                        max_len=max_len, prefill_chunk=chunk,
+                        kv_dtype="bf16", attention_impl="pallas")
+    try:
+        def described(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=one_chip), tree)
+
+        full, window = eng._pool.groups
+        assert (full.num_pages, window.num_pages, window.ring) == (
+            1281, 1281, 49)
+        assert eng.pages_per_seq == 576
+        ints = np.zeros((slots,), np.int32)
+        tables = eng._pool.device_tables()
+        if program == "decode":
+            lowered = eng._decode_fn.lower(*described((
+                eng._params, eng._buffers, eng._tokens_dev, ints, tables,
+                ints, eng.k_pages, eng.v_pages, eng.temperatures,
+                eng._nonces, eng._key)))
+        else:
+            rows = np.zeros((1, chunk), np.int32)
+            per_slot = np.zeros((1, slots), np.int32)
+            xs = {"tok": rows, "pos": rows, "lim": rows,
+                  "tbl": eng._pool.row_tables(np.full((1, chunk), -1)),
+                  "fin": per_slot.astype(bool), "row": per_slot,
+                  "fpos": per_slot, "grant": per_slot}
+            lowered = eng._mixed_fn.lower(*described((
+                eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+                tables, eng.temperatures, eng._nonces, eng._key)), 1)
+        pools = sum(a.nbytes for a in eng.k_pages)
+        assert pools == 4 * 1281 * PAGE * 8 * 128 * 2
+    finally:
+        eng.close()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    def calls(kernel):
+        return [ln for ln in text.splitlines() if " custom-call(" in ln
+                and "%" + kernel in ln.split(" = ")[0]]
+
+    assert len(calls("paged_attention")) == 4
+    assert len(calls("grouped_matmul")) == 6 and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pools
+    assert mem.temp_size_in_bytes < pools // 4, (
+        mem.temp_size_in_bytes, pools)

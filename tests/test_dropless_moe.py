@@ -107,3 +107,34 @@ def test_experts_held_outside_the_router_is_refused():
 def test_an_unknown_grouped_product_is_refused():
     with pytest.raises(ValueError, match="unknown impl"):
         _layer()(_x(), impl="cuda")
+
+
+@IMPLS
+def test_eight_shares_and_one_shared_expert_sum_to_the_uncut_layer(impl):
+    """A stage of eight chips, one expert each here, gates times a routed
+    scaling factor of 2.5; what every chip computes alike (a shared expert)
+    is counted ONCE. The parts add up to the uncut layer's ``shared(x) +
+    2.5 * sum_e g_e E_e(x)``, and the pairs they count to every pair."""
+    pt.seed(0)
+    whole = DroplessMoE(D, DE, E, K, initializer_range=0.3,
+                        routed_scaling_factor=2.5)
+    x = _x()
+    w_shared = jnp.asarray(
+        np.random.default_rng(3).normal(size=(D, D)) * 0.1, jnp.float32)
+    shared = jnp.tanh(x @ w_shared)
+    total, counted = shared, 0
+    for first in range(E):
+        share = DroplessMoE(D, DE, E, K, (first, 1), initializer_range=0.3,
+                            routed_scaling_factor=2.5)
+        share.router = whole.router
+        share.w_in = whole.w_in[first:first + 1]
+        share.w_out = whole.w_out[first:first + 1]
+        y, rows = share(x, impl=impl)
+        total = total + y
+        counted += int(rows.sum())
+    np.testing.assert_allclose(total, shared + 2.5 * _dense(whole, x),
+                               atol=5 * TOL, rtol=5 * TOL)
+    np.testing.assert_allclose(whole(x, impl=impl)[0],
+                               2.5 * _dense(whole, x), atol=5 * TOL,
+                               rtol=5 * TOL)
+    assert counted == T * K

@@ -1,0 +1,161 @@
+"""inference/page_pool.py: the allocator written once, over cache groups. A
+bare triple is the list of one; a group with a window holds a ring of pages
+a slot and frees what lies behind the window; every page comes back."""
+import numpy as np
+import pytest
+
+from paddle_tpu.inference.page_pool import (CacheGroup, PagePool,
+                                            cache_groups)
+from paddle_tpu.inference.prefix_cache import PrefixCache
+
+PS, SLOTS, PAGES_PER_SEQ, CHUNK, WINDOW = 4, 3, 32, 8, 12
+RING = -(-(WINDOW + CHUNK) // PS) + 1                      # 6
+TWO = [CacheGroup("full", 2, 2, 8), CacheGroup("window", 3, 2, 8, WINDOW)]
+
+
+def pool_of(spec, num_pages=40):
+    return PagePool(spec, num_pages, PS, SLOTS, PAGES_PER_SEQ, "f32", CHUNK)
+
+
+def serve(pool, slot, n_prompt, n_new, on_tick=lambda: None):
+    """What the engine does for one sequence: admission, the prompt a chunk
+    at a time, then a token a tick; releases after every dispatch."""
+    n_total = n_prompt + n_new
+    assert pool.admission(n_prompt, n_total) == "ok"
+    pool.admit(slot, n_prompt, n_total)
+    for first in range(0, n_prompt, CHUNK):
+        take = min(CHUNK, n_prompt - first)
+        pool.ensure_range(slot, first, take)
+        on_tick()
+        pool.release_behind(slot, first + take)
+    for pos in range(n_prompt, n_total):
+        assert pool.ensure(slot, pos)
+        on_tick()
+        pool.release_behind(slot, pos + 1)
+
+
+def test_a_bare_triple_is_the_list_of_one():
+    assert cache_groups((4, 2, 8)) == [CacheGroup("kv", 4, 2, 8, None)]
+    assert cache_groups(TWO) == TWO
+    assert cache_groups([("a", 1, 2, 8, 5)]) == [CacheGroup("a", 1, 2, 8, 5)]
+    pool = pool_of((4, 2, 8))
+    (g,) = pool.groups
+    assert not pool.windowed and g.ring is None and g.num_pages == 40
+    # the arrays and tables keep the form of the spec: an array, not a tuple
+    assert pool.k_pages.shape == (4, 40, PS, 2, 8)
+    assert pool.device_tables().shape == (SLOTS, PAGES_PER_SEQ)
+    assert pool.row_tables(np.asarray([0, -1, 2])).shape == (
+        3, PAGES_PER_SEQ)
+    assert pool.page_bytes == g.page_bytes == 2 * 4 * PS * 2 * 8 * 4
+    pool.k_pages = pool.k_pages + 1
+    assert float(g.k_pages[0, 0, 0, 0, 0]) == 1.0
+
+
+def test_groups_have_their_own_arrays_tables_and_sizes():
+    pool = pool_of(TWO)
+    full, window = pool.groups
+    assert pool.windowed and full.ring is None and window.ring == RING
+    # a window group is given what it can ever hold, no more
+    assert (full.num_pages, window.num_pages) == (40, SLOTS * RING + 1)
+    assert [a.shape for a in pool.k_pages] == [
+        (2, 40, PS, 2, 8), (3, SLOTS * RING + 1, PS, 2, 8)]
+    assert window.page_bytes == full.page_bytes * 3 // 2
+    tables = pool.device_tables()
+    assert isinstance(tables, tuple) and len(tables) == 2
+    new = tuple(a + 1 for a in pool.v_pages)
+    pool.v_pages = new
+    assert window.v_pages is new[1]
+
+
+def test_one_lifetime_keeps_every_page_until_the_slot_is_freed():
+    pool = pool_of((4, 2, 8))
+    (g,) = pool.groups
+    serve(pool, 1, n_prompt=21, n_new=30)
+    assert int(g.held[1]) == -(-51 // PS) and g.n_released == 0
+    assert pool.utilization() == pytest.approx(13 / 39)
+    pool.free_slot(1)
+    assert len(g.free) == 39 and not g.tables.any()
+
+
+def test_a_window_group_holds_a_ring_and_frees_behind_the_window():
+    pool = pool_of(TWO)
+    full, window = pool.groups
+    peak = []
+    serve(pool, 0, n_prompt=50, n_new=40,
+          on_tick=lambda: peak.append(int(window.held[0])))
+    assert max(peak) <= RING
+    assert int(full.held[0]) == -(-90 // PS)
+    # what is left is what the next row (position 90) can attend
+    live = np.flatnonzero(window.tables[0])
+    assert live.min() == (90 - WINDOW + 1) // PS and live.max() == 89 // PS
+    assert window.n_released == live.min() and full.n_released == 0
+    assert int(window.held[0]) == len(live)
+    pool.free_slot(0)
+    for g in pool.groups:
+        assert sorted(g.free) == list(range(1, g.num_pages))
+        assert not g.tables.any() and not g.held.any()
+
+
+def test_admission_reserves_the_longest_a_windowed_sequence_can_hold():
+    """Every slot's ring is set aside when it is admitted, so a chunk's
+    pages are always there; a sequence no pool this size holds is never
+    admitted, one that must wait for pages is told to wait."""
+    pool = pool_of(TWO, num_pages=30)         # full: 29 usable pages
+    assert pool.admission(40, 200) == "never"          # 50 pages of full
+    assert pool.admission(40, 100) == "ok"             # 25
+    pool.admit(0, 40, 100)
+    assert pool.avail() == 29 - 25
+    assert pool.admission(8, 40) == "wait"             # 10 > 4 left
+    assert pool.admission(8, 16) == "ok"
+    pool.admit(1, 8, 16)
+    # the promise holds although nothing of it is allocated yet
+    window = pool.groups[1]
+    assert int(window.held.sum()) == 0
+    assert pool._avail(window) == SLOTS * RING - RING - 4
+    pool.free_slot(0)
+    pool.free_slot(1)
+    assert pool.avail() == min(29, SLOTS * RING)
+
+
+def test_ensure_logs_what_it_allocated_and_unmap_takes_it_back():
+    pool = pool_of(TWO)
+    pool.admit(2, 4, 40)
+    log = []
+    assert pool.ensure(2, 4, log) and pool.ensure(2, 5, log)
+    assert [(g.name, idx) for g, idx in log] == [("full", 1), ("window", 1)]
+    for g, idx in log:
+        pool.unmap(g, 2, idx)
+    assert [int(g.held[2]) for g in pool.groups] == [1, 0]
+    assert not pool.ensure(2, PAGES_PER_SEQ * PS)      # past the table
+
+
+def test_the_prefix_cache_serves_the_one_group_of_a_pool_without_a_window():
+    pool = pool_of((2, 2, 8), num_pages=4)
+    cache = pool.prefix_cache = PrefixCache(PS)
+    pool.admit(0, 8, 8)
+    cache.register(b"a" * 16, int(pool.groups[0].tables[0, 0]), [1, 2, 3, 4])
+    pool.free_slot(0)          # the shared page stays cached, one is free
+    (g,) = pool.groups
+    assert len(g.free) == 2 and cache.evictable_count == 1
+    assert pool.avail() == 3
+    assert pool.alloc() and pool.alloc() and pool.alloc()   # the last evicts
+    assert pool.alloc() is None and cache.evictable_count == 0
+
+
+def test_pages_touched_by_group():
+    """A decode row of a long sequence reads its whole context from the
+    full group and its window from the window group; a chunk's rows each
+    walk their own; the gathered path reads every table entry."""
+    pool = pool_of(TWO)
+    got = pool.pages_touched([([(0, 90)], SLOTS, "pallas")])
+    first = (90 - WINDOW) // PS
+    assert got == {"full": {"read": 23, "live": 23},
+                   "window": {"read": 23 - first, "live": 23 - first}}
+    chunk = [(1, p + 1) for p in range(16, 24)]
+    got = pool.pages_touched([(iter(chunk), CHUNK, "pallas")])
+    assert got["full"] == {"read": sum(-(-(p + 1) // PS)
+                                       for p in range(16, 24)), "live": 6}
+    assert got["window"]["live"] == 6 - (17 - WINDOW) // PS
+    got = pool.pages_touched([(chunk, CHUNK, "xla")])
+    assert got["full"]["read"] == got["window"]["read"] \
+        == CHUNK * PAGES_PER_SEQ

@@ -193,3 +193,125 @@ def test_block_follows_the_shapes(dtype, kv_heads, want):
     table holds."""
     assert pa._pages_per_block(16, kv_heads, 128, dtype, 128) == want
     assert pa._pages_per_block(16, kv_heads, 128, dtype, 4) == 4
+
+
+# -- a lower bound a row (``starts=``): a sliding window's rows ---------------
+
+def dense_window(q, k, v, tables, lens, starts, layer):
+    """The definition, a row at a time: softmax over positions ``starts[r]
+    <= j < lens[r]`` of the row's own pages."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    tables, out = np.asarray(tables), np.zeros(q.shape)
+    group = q.shape[1] // k.shape[3]
+    for r in range(q.shape[0]):
+        lo, hi = int(starts[r]), int(lens[r])
+        if hi == 0:
+            continue
+        kk = k[layer, tables[r]].reshape(-1, k.shape[3], D)[lo:hi]
+        vv = v[layer, tables[r]].reshape(-1, k.shape[3], D)[lo:hi]
+        for h in range(q.shape[1]):
+            s = kk[:, h // group] @ q[r, h] / np.sqrt(D)
+            p = np.exp(s - s.max())
+            out[r, h] = (p / p.sum()) @ vv[:, h // group]
+    return out
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (12, 2), (18, 2)],
+                         ids=["mha", "6-a-kv-head", "9-a-kv-head"])
+def test_a_lower_bound_a_row_in_all_three_paths(block, heads, kv_heads):
+    """Windows that begin at a page's first token, inside a page, in the
+    limit's own page and at 0; a window of one token; a row with nothing to
+    attend. The kernel, the gathered path and the f32 reference agree with
+    the definition."""
+    k, v = pool(3, kv_heads)
+    q = queries(8, heads)
+    tables = tables_for(8, seed=5)
+    lens = jnp.asarray([MAX_LEN, 30, 17, 9, 1, 0, 13, 24], jnp.int32)
+    starts = jnp.asarray([MAX_LEN - 8, 11, 16, 0, 0, 0, 12, 3], jnp.int32)
+    want = dense_window(q, k, v, tables, lens, starts, layer=1)
+    for impl in ("pallas", "xla", "reference"):
+        got = ragged_paged_attention(q, k, v, tables, lens, impl=impl,
+                                     layer=1, starts=starts)
+        np.testing.assert_allclose(np.asarray(got), want, atol=2e-5,
+                                   rtol=2e-5, err_msg=impl)
+    # a bound that cuts nothing is no bound; and the bound matters
+    whole = ragged_paged_attention(q, k, v, tables, lens, impl="pallas",
+                                   layer=1)
+    np.testing.assert_allclose(
+        np.asarray(ragged_paged_attention(
+            q, k, v, tables, lens, impl="pallas", layer=1,
+            starts=jnp.zeros_like(lens))), np.asarray(whole), atol=1e-6)
+    assert np.abs(np.asarray(whole)[:3] - want[:3]).max() > 1e-2
+
+
+def test_no_lower_bound_is_todays_program_bit_for_bit(block):
+    """``starts=None`` lowers to the same kernel as before the argument
+    existed: the same jaxpr, so the same program and the same bits."""
+    k, v = pool(0, kv_heads=2)
+    q = queries(5, 4)
+    tables = tables_for(5)
+    lens = jnp.asarray([9, MAX_LEN, 1, 0, 17], jnp.int32)
+    with_none = jax.make_jaxpr(lambda *a: ragged_paged_attention(
+        *a, impl="pallas", layer=2, starts=None))(q, k, v, tables, lens)
+    without = jax.make_jaxpr(lambda *a: ragged_paged_attention(
+        *a, impl="pallas", layer=2))(q, k, v, tables, lens)
+    import re
+
+    def text(jaxpr):                 # without the addresses of functions
+        return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+
+    assert text(with_none) == text(without)
+    bounded = jax.make_jaxpr(lambda *a: ragged_paged_attention(
+        *a, impl="pallas", layer=2, starts=jnp.zeros_like(lens)))(
+        q, k, v, tables, lens)
+    assert text(bounded) != text(without)
+    for impl in ("pallas", "xla"):
+        np.testing.assert_array_equal(
+            np.asarray(ragged_paged_attention(q, k, v, tables, lens,
+                                              impl=impl, layer=2,
+                                              starts=None)),
+            np.asarray(ragged_paged_attention(q, k, v, tables, lens,
+                                              impl=impl, layer=2)))
+
+
+def test_a_windowed_row_fetches_no_page_before_its_window(block):
+    """Pages wholly before ``starts`` are never read: their table entries
+    point at pages full of NaN, and the result is finite and right."""
+    k, v = pool(4, kv_heads=2)
+    q = queries(3, 12)
+    tables = np.asarray(tables_for(3, seed=7)).copy()
+    lens = jnp.asarray([MAX_LEN, 22, 15], jnp.int32)
+    starts = jnp.asarray([MAX_LEN - 6, 9, 12], jnp.int32)
+    want = dense_window(q, k, v, tables, lens, starts, layer=0)
+    poisoned = PAGES - 1
+    k = k.at[:, poisoned].set(jnp.nan)
+    v = v.at[:, poisoned].set(jnp.nan)
+    for r in range(3):
+        tables[r, :int(starts[r]) // PS] = poisoned
+    got = ragged_paged_attention(q, k, v, jnp.asarray(tables), lens,
+                                 impl="pallas", layer=0, starts=starts)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads", [12, 18], ids=["6-a-kv-head", "9-a-kv-head"])
+def test_many_heads_a_kv_head_fold_on_the_mxu_in_the_pools_type(block,
+                                                                heads):
+    """Over ``_VPU_GROUP_ROWS`` query heads a K/V head the fold is two
+    products in the pool's type: with bf16 pages the queries and the
+    probabilities are rounded to bf16 (float32 sums), so the result lies
+    within bf16's rounding of the float32 reference over the same pages;
+    with a lower bound too. Few heads a K/V head stay on the VPU path."""
+    assert heads // 2 > pa._VPU_GROUP_ROWS >= 4
+    k, v = pool(7, 2, jnp.bfloat16)
+    q = queries(5, heads, seed=8).astype(jnp.bfloat16)
+    tables = tables_for(5, seed=9)
+    lens = jnp.asarray([11, 0, MAX_LEN, 6, 29], jnp.int32)
+    starts = jnp.asarray([0, 0, MAX_LEN - 9, 2, 13], jnp.int32)
+    for bound in (None, starts):
+        got = np.asarray(paged_attention_kernel(
+            q, k, v, tables, lens, layer=1, starts=bound), np.float32)
+        want = np.asarray(ragged_paged_attention_reference(
+            q, pa.kv_layer(k, 1), pa.kv_layer(v, 1), tables, lens,
+            starts=bound))
+        np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+        assert np.abs(got - want).max() < 2e-2
