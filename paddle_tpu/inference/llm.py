@@ -59,7 +59,7 @@ from ..ops.paged_attention import (KV_DTYPES, QuantizedKV, _split_kv,
                                    kv_nbytes, kv_page_size,
                                    kv_scale_nbytes, kv_zeros)
 from ..reliability import faults as _faults
-from .page_pool import PagePool
+from .page_pool import ChunkRows, PagePool
 from ..reliability.retry import Deadline, DeadlineExceeded, as_deadline
 
 # How every engine program is compiled for a TPU. XLA:TPU's memory-space
@@ -2898,9 +2898,9 @@ class LLMEngine:
         released = self._release_behind(
             (slot, p0 + take) for slot, p0, take in chunks)
         if ph is not _trace.NOOP_SPAN:
-            rows = self._chunk_limits(chunks)
-            self._stamp_kv_pages(ph, (rows, T, self.attention_impl),
-                                 *self._draft_chunk_call(rows, T),
+            chunk = ChunkRows(row_slot[None], lim[None])
+            self._stamp_kv_pages(ph, ([], T, self.attention_impl, chunk),
+                                 *self._draft_chunk_call(chunk, T),
                                  released=released)
         if self.spec_k:
             # draft ride-along: the SAME packed chunk schedule runs
@@ -3463,11 +3463,15 @@ class LLMEngine:
         its issue phase (so only while tracing is active). Each of
         ``calls`` is one attention call site of the dispatch's
         programs: ``(rows, padded_rows, impl)`` with ``rows`` the
-        ``(sequence, limit)`` pairs it packs (limit > 0) and
-        ``padded_rows`` the rows the program carries, padding
-        included. READ is what the attention path takes out of the
-        pool: the kernel a row's ``ceil(limit / page_size)`` live
-        pages, the gathered path every table entry of every row. LIVE
+        ``(sequence, limit)`` pairs of its one-token rows (limit > 0)
+        and ``padded_rows`` the rows the program carries, padding
+        included; a call with packed prompt rows (``RaggedRows.n_chunk``)
+        says which they are in a fourth entry, ``ChunkRows``. READ is
+        what the attention path takes out of the pool: the kernel a
+        one-token row's ``ceil(limit / page_size)`` live pages and a
+        TILE of prompt rows its sequence's pages once
+        (``PagePool.pages_touched``), the gathered path every table
+        entry of every row. LIVE
         is the distinct pages those sequences hold: each sequence's
         longest limit, counted once. Limits the device decides alone
         (an EOS inside a slab, how far a speculative round moves)
@@ -3501,21 +3505,14 @@ class LLMEngine:
                           + sum(r.prefill_pos for r in self._prefill_q)) \
                 .set_attr("window_pages_released", released)
 
-    @staticmethod
-    def _chunk_limits(chunks) -> List[tuple]:
-        """``(slot, limit)`` of every prompt row packed from ``chunks``
-        = ``(slot, first position, tokens)``: a row attends its own
-        position inclusive."""
-        return [(slot, p0 + t + 1) for slot, p0, take in chunks
-                for t in range(take)]
-
-    def _draft_chunk_call(self, rows, padded_rows) -> List[tuple]:
+    def _draft_chunk_call(self, chunk: ChunkRows,
+                          padded_rows: int) -> List[tuple]:
         """The draft model's ride-along over the same chunk rows (a
         speculative engine), as a call of :meth:`_stamp_kv_pages`."""
         if not self.spec_k:
             return []
-        return [([(("draft", slot), limit) for slot, limit in rows],
-                 padded_rows, self.attention_impl)]
+        return [([], padded_rows, self.attention_impl,
+                 chunk._replace(pool="draft"))]
 
     def _stamp_spec_kv_pages(self, ph, live, pos0s, rounds) -> None:
         """A speculative dispatch: K draft probes a round through the
@@ -3940,7 +3937,7 @@ class LLMEngine:
                 + [(slot, meta_pos0[slot] + meta_bud[slot])
                    for slot in plan])
             if ph is not _trace.NOOP_SPAN:
-                chunk_rows = self._chunk_limits(chunks)
+                chunk = ChunkRows(pslot[:n_run], plim[:n_run])
                 # a decode row of tick j attends pos0 + j + 1; a slot
                 # whose prompt completes at tick j0 decodes from j0 + 1
                 decode_rows = [
@@ -3949,10 +3946,12 @@ class LLMEngine:
                     for j in range(1 if slot in start else 0,
                                    meta_bud[slot])]
                 self._stamp_kv_pages(
-                    ph, (chunk_rows + decode_rows,
-                         (C + self.max_seqs) * n_run,
-                         self.attention_impl),
-                    *self._draft_chunk_call(chunk_rows, C * n_run),
+                    ph, (decode_rows, (C + self.max_seqs) * n_run,
+                         self.attention_impl, chunk),
+                    *self._draft_chunk_call(
+                        ChunkRows(chunk.seqs.reshape(1, -1),
+                                  chunk.limits.reshape(1, -1)),
+                        C * n_run),
                     released=released)
             if self._cache is not None:
                 for req in touched:

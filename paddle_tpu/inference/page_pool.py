@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.paged_attention import kv_nbytes, kv_scale_nbytes, kv_zeros
+from ..ops.paged_attention import (QuantizedKV, chunk_tile_rows,
+                                   chunk_tiles, kv_nbytes, kv_scale_nbytes,
+                                   kv_zeros)
 
 
 class CacheGroup(NamedTuple):
@@ -44,6 +46,25 @@ class CacheGroup(NamedTuple):
     kv_heads: int
     head_dim: int
     window: Optional[int] = None
+
+
+class ChunkRows(NamedTuple):
+    """The PACKED PROMPT ROWS of one attention call, as its programs carry
+    them (``RaggedRows.n_chunk``): ``seqs`` / ``limits`` ``[programs,
+    rows]``, a row's sequence and limit (a padded row: -1 and 0). ``pool``
+    names another pool's sequences (the draft's), so that they are not
+    taken for this one's."""
+
+    seqs: np.ndarray
+    limits: np.ndarray
+    pool: Any = None
+
+    def live_rows(self) -> List[tuple]:
+        """``(sequence, limit)`` of every row that is not padding."""
+        return [(int(s) if self.pool is None else (self.pool, int(s)),
+                 int(n))
+                for s, n in zip(self.seqs.ravel(), self.limits.ravel())
+                if n > 0]
 
 
 def is_bare(spec) -> bool:
@@ -330,32 +351,62 @@ class PagePool:
 
     def pages_touched(self, calls) -> dict:
         """What the attention calls of one dispatch read and keep live, a
-        group: ``{name: {"read", "live"}}``. Each of ``calls`` is
-        ``(rows, padded_rows, impl)`` with ``rows`` the ``(sequence,
-        limit)`` pairs the call packs (limit > 0: the row at position
-        ``limit - 1``) and ``padded_rows`` the rows the program carries.
-        READ: the kernel a row's pages from its window's first to its
-        limit's; the gathered path every table entry of every row. LIVE:
-        the distinct pages those rows can attend, a sequence's counted
-        once."""
+        group: ``{name: {"read", "live"}}``. Each of ``calls`` is ``(rows,
+        padded_rows, impl)`` or ``(rows, padded_rows, impl, chunk)``:
+        ``rows`` the ``(sequence, limit)`` pairs of the call's ONE-TOKEN
+        rows (limit > 0: the row at position ``limit - 1``), ``chunk`` its
+        packed prompt rows (:class:`ChunkRows`; None: it has none) and
+        ``padded_rows`` the rows the programs carry, of both kinds. READ:
+        the kernel a one-token row's pages from its window's first to its
+        limit's, and a TILE of prompt rows its pages once (the kernel's own
+        arithmetic, ``ops/paged_attention.py chunk_tiles``; an int8 pool's
+        prompt rows a row each, as its kernel walks them); the gathered
+        path every table entry of every row. LIVE: the distinct pages
+        those rows can attend, a sequence's counted once."""
         ps = self.page_size
-        calls = [(list(rows), padded, impl) for rows, padded, impl in calls]
+        calls = [(list(c[0]), c[1], c[2], c[3] if len(c) > 3 else None)
+                 for c in calls]
         out = {}
         for g in self.groups:
+            tiled = not isinstance(g.k_pages, QuantizedKV)
+
+            def pages_of(limit):
+                first = 0 if g.window is None \
+                    else max(0, int(limit) - g.window) // ps
+                return first, -(-int(limit) // ps)
+
             read = 0
             span: Dict[Any, tuple] = {}
-            for rows, padded_rows, impl in calls:
-                for seq, limit in rows:
-                    limit = int(limit)
-                    last = -(-limit // ps)
-                    first = 0 if g.window is None \
-                        else max(0, limit - g.window) // ps
+            for rows, padded_rows, impl, chunk in calls:
+                prompt = [] if chunk is None else chunk.live_rows()
+                for seq, limit in rows + prompt:
+                    first, last = pages_of(limit)
                     lo, hi = span.get(seq, (first, last))
                     span[seq] = (min(lo, first), max(hi, last))
-                    if impl == "pallas":
-                        read += last - first
                 if impl != "pallas":
                     read += padded_rows * self.pages_per_seq
+                    continue
+                if prompt and tiled:
+                    read += self._tile_pages(g, chunk)
+                    prompt = []
+                for _, limit in rows + prompt:      # walked a row each
+                    first, last = pages_of(limit)
+                    read += last - first
             out[g.name] = {"read": read,
                            "live": sum(hi - lo for lo, hi in span.values())}
         return out
+
+    def _tile_pages(self, g: GroupPool, chunk: ChunkRows) -> int:
+        """Pages the kernel's query tiles fetch for ``chunk`` in group
+        ``g``: each program's rows cut as the kernel's plan cuts them."""
+        programs, n = chunk.limits.shape
+        qb = chunk_tile_rows(n)
+        pad = ((0, 0), (0, -n % qb))
+        limits = np.pad(chunk.limits.astype(np.int64), pad).ravel()
+        seqs = np.pad(chunk.seqs, pad, constant_values=-1).ravel()
+        starts = np.zeros_like(limits) if g.window is None \
+            else np.maximum(limits - g.window, 0)
+        new_seq = np.concatenate([[True], seqs[1:] != seqs[:-1]])
+        head, _, first, last = chunk_tiles(new_seq, limits, starts,
+                                           self.page_size, qb, xp=np)
+        return int((last - first)[head].sum())

@@ -549,7 +549,7 @@ class GPTForCausalLM(Layer):
             att = ragged_paged_attention(q[0], k_pages, v_pages, tables,
                                          rows.limits,
                                          impl=cache.attention_impl,
-                                         layer=i)
+                                         layer=i, n_chunk=rows.n_chunk)
             x = x + layer.attn.out_proj(
                 att.reshape(1, t, cfg.hidden_size))
             x = x + layer.mlp(layer.ln_2(x))

@@ -435,7 +435,8 @@ class GraniteHybridForCausalLM(Layer):
                 att = ragged_paged_attention(
                     q, k_pages, v_pages, tables, limits,
                     scale=cfg.attention_multiplier,
-                    impl=cache.attention_impl, layer=i_kv)
+                    impl=cache.attention_impl, layer=i_kv,
+                    n_chunk=rows.n_chunk)
                 with jax.named_scope("attn"):
                     out = mixer.o_proj(
                         att.reshape(-1, cfg.hidden_size).astype(u.dtype))

@@ -400,7 +400,8 @@ class LagunaForCausalLM(Layer):
             att = ragged_paged_attention(
                 q, k_pools[gi], v_pools[gi], tables[gi], limits,
                 impl=cache.attention_impl, layer=li,
-                starts=starts if layer.kind == SLIDING else None)
+                starts=starts if layer.kind == SLIDING else None,
+                n_chunk=rows.n_chunk)
             x = x + layer.attn.gate_and_project(att, u)
             x, rows_held = layer.feed_forward(x, self._dtype, valid,
                                               cache.moe_impl)

@@ -331,7 +331,8 @@ class OuroForCausalLM(Layer):
                 v_pages = kv_write(v_pages, at, page_idx, offs, v)
                 att = ragged_paged_attention(
                     q, k_pages, v_pages, tables, limits,
-                    impl=cache.attention_impl, layer=at)
+                    impl=cache.attention_impl, layer=at,
+                    n_chunk=rows.n_chunk)
                 with jax.named_scope("attn"):
                     a = layer.attn.o_proj(
                         att.reshape(-1, cfg.q_size).astype(u.dtype))

@@ -13,7 +13,10 @@ Two paths behind one entry point (:func:`ragged_paged_attention`).
 On a TPU the engine takes :func:`paged_attention_kernel`: a Pallas
 kernel that streams each row's LIVE pages straight out of the stacked
 ``[L, num_pages, ...]`` pool, a block of pages a step, with the online
-softmax in float32 — the bytes a tick moves follow the live context.
+softmax in float32 — the bytes a tick moves follow the live context. A
+program's packed prompt rows (``n_chunk``) take the same walk a TILE of
+rows at a time (:func:`_paged_attention_chunk_call`): a sequence's pages
+are fetched once for up to 32 of its rows, not once a row.
 Anywhere else it takes the gathered path
 (:func:`_gathered_attention`): every entry of a row's block table
 gathered with one take() at page granularity (contiguous
@@ -247,11 +250,31 @@ def _pages_per_block(page_size: int, kv_heads: int, d: int, dtype,
                       _PAGE_BUFFER_BYTES // (4 * page_bytes)))
 
 
+def _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf, sems):
+    """Both walks' page traffic: ``copies(row, first_page, n, slot, do)``
+    does ``do`` (start or wait) to the K and the V copy of the ``n`` pages
+    from column ``first_page`` of ``row``'s table, out of layer
+    ``meta_ref[0]`` of the stacked pool into buffer slot ``slot``."""
+    def copies(row, first_page, n, slot, do):
+        def page(p, carry):
+            src = (meta_ref[0], tbl_ref[row, first_page + p])
+            do(pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, p],
+                                     sems.at[0, slot]))
+            do(pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, p],
+                                     sems.at[1, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, n, page, 0)
+
+    return copies
+
+
 def paged_attention_kernel(q, k_pages, v_pages, block_tables,
                            context_lens, layer=None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           k_scales=None, v_scales=None, starts=None):
+                           k_scales=None, v_scales=None, starts=None,
+                           n_chunk: int = 0):
     """Fused Pallas attention over the paged KV pool (Ragged-Paged-
     Attention lineage): every row of ``q`` attends the first
     ``context_lens[row]`` cached positions of the sequence whose block
@@ -307,6 +330,16 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     that page below ``starts[r]`` are masked, so the bytes a windowed
     row moves follow its window and not its length.
 
+    ``n_chunk`` (a Python int; 0 = none, and the program of today): the
+    first ``n_chunk`` rows are PACKED PROMPT ROWS, the rows of one
+    sequence contiguous and in order, and go through QUERY TILES
+    (:func:`_paged_attention_chunk_call`): up to ``chunk_tile_rows`` of
+    them against each page of their sequence ONCE, where the row walk
+    fetches it once a row. Every row attends exactly the positions it
+    attends in the row walk. The rows after them take the row walk above.
+    An int8 pool keeps the row walk for all its rows (its scale rows ride
+    in SMEM a row; no cell runs one).
+
     ``interpret`` defaults to the module switch
     ``flash_attention.INTERPRET`` (False: the kernel compiles for the
     TPU or raises).
@@ -321,12 +354,31 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     page_size, kv_heads, d = k_pages.shape[2:]
     block = _pages_per_block(page_size, kv_heads, d, k_pages.dtype,
                              block_tables.shape[1])
-    return _paged_attention_call(
-        q, k_pages, v_pages, block_tables, context_lens,
-        jnp.asarray(layer, jnp.int32), k_scales, v_scales, starts,
-        scale=scale if scale is not None else 1.0 / math.sqrt(d),
-        interpret=bool(interpret), block=block,
-        group_pages=max(1, min(block, _GROUP_TOKENS // page_size)))
+    layer = jnp.asarray(layer, jnp.int32)
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
+
+    def rows_of(part):
+        return (q[part], block_tables[part], context_lens[part],
+                None if starts is None else starts[part])
+
+    def row_walk(q, block_tables, context_lens, starts):
+        return _paged_attention_call(
+            q, k_pages, v_pages, block_tables, context_lens, layer,
+            k_scales, v_scales, starts, scale=scale,
+            interpret=bool(interpret), block=block,
+            group_pages=max(1, min(block, _GROUP_TOKENS // page_size)))
+
+    n_chunk = min(int(n_chunk), q.shape[0])
+    if n_chunk <= 0 or k_scales is not None:
+        return row_walk(q, block_tables, context_lens, starts)
+    cq, ctables, clens, cstarts = rows_of(slice(0, n_chunk))
+    tiled = _paged_attention_chunk_call(
+        cq, k_pages, v_pages, ctables, clens, layer, cstarts, scale=scale,
+        interpret=bool(interpret), block=block, qb=chunk_tile_rows(n_chunk))
+    if n_chunk == q.shape[0]:
+        return tiled
+    return jnp.concatenate(
+        [tiled, row_walk(*rows_of(slice(n_chunk, None)))])
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
@@ -388,22 +440,16 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
         n_pages = pl.cdiv(ctx, page_size) - page0
         n_blocks = pl.cdiv(n_pages, block)
 
+        copies = _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf,
+                              sems)
+
         def block_copies(row, blk, slot, do):
             """``do`` (start or wait) the K and V copy of each live page
             of block ``blk`` of ``row`` into ``slot``."""
             first_page = first_page_of(row) + blk * block
-            here = jnp.minimum(
-                block, pl.cdiv(len_ref[row], page_size) - first_page)
-
-            def page(p, carry):
-                src = (meta_ref[0], tbl_ref[row, first_page + p])
-                do(pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, p],
-                                         sems.at[0, slot]))
-                do(pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, p],
-                                         sems.at[1, slot]))
-                return carry
-
-            jax.lax.fori_loop(0, here, page, 0)
+            copies(row, first_page, jnp.minimum(
+                block, pl.cdiv(len_ref[row], page_size) - first_page),
+                slot, do)
 
         def start(row, blk, slot):
             block_copies(row, blk, slot, lambda c: c.start())
@@ -595,10 +641,297 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
     return out.transpose(0, 2, 1, 3).reshape(rows, n_heads, d)
 
 
+# rows of a query tile. Not from the query heads: the pool's counter
+# (``PagePool.pages_touched``) reckons the same tiles from what a pool
+# knows, and that is the rows alone
+_TILE_ROWS = 32
+
+
+def chunk_tile_rows(n_chunk: int) -> int:
+    """``QB``, the rows of a query tile, for a chunk of ``n_chunk`` packed
+    prompt rows: ``_TILE_ROWS``, or the chunk rounded up to whole sublanes
+    where it is shorter. At 32 rows a tile's float32 state (sums and
+    softmax state, ``QB x heads x (d + 2 x 128)``) is 3.5 MB for 72 heads
+    of 128, beside 4 MB of page buffers."""
+    return min(_TILE_ROWS, -(-n_chunk // 8) * 8)
+
+
+def chunk_tiles(new_seq, limits, starts, page_size: int, qb: int, xp=jnp):
+    """THE arithmetic of query tiles, for the kernel's plan (``xp=jnp``, in
+    the program) and for the pool's counter (``xp=numpy``, on the host).
+
+    ``limits`` / ``starts`` [n] (n a multiple of ``qb``): a chunk's packed
+    prompt rows, row r attending positions ``starts[r] <= j < limits[r]``
+    (limit 0: a padded row); ``new_seq`` [n] bool: row r is not of the
+    sequence of row r - 1. A TILE is a run of live rows of one sequence
+    inside one window of ``qb`` rows (rows ``w * qb .. (w + 1) * qb - 1``):
+    a sequence boundary, a padded row and a window's edge each end one. It
+    walks the pages from that of its lowest ``starts`` to that of its
+    highest limit once.
+
+    Returns ``(head, count, first_page, last_page)``, each [n] and read at
+    a tile's FIRST row (``head``): how many rows it has and the pages
+    ``first_page <= p < last_page`` it walks."""
+    n = limits.shape[0]
+    live = limits > 0
+    after_live = xp.concatenate([xp.zeros((1,), bool), live[:-1]])
+    head = live & (new_seq | ~after_live | (xp.arange(n) % qb == 0))
+    tile = xp.where(live, xp.cumsum(head), 0).reshape(-1, qb)
+    # [windows, qb, qb]: row i and row j of a window are of one tile
+    mates = (tile[:, :, None] == tile[:, None, :]) & (tile[:, :, None] > 0)
+    top = xp.where(mates, limits.reshape(-1, 1, qb), 0).max(-1)
+    low = xp.where(mates, starts.reshape(-1, 1, qb),
+                   xp.iinfo(xp.int32).max).min(-1)
+    return (head, mates.sum(-1).reshape(n),
+            (low // page_size).reshape(n), (-(-top // page_size)).reshape(n))
+
+
+def _head_rows(buf, slot, kv_heads: int, tokens: int):
+    """The rows of each K/V head out of a page buffer slot, as the pages
+    lie (``[pages, page_size, kv_heads, d]``: a head's rows are every
+    ``kv_heads``-th of the flattened ``[tokens x kv_heads, d]``): a
+    strided load a head. bf16 rows lie two to a 32-bit sublane, so an
+    even number of bf16 heads is loaded a PAIR at a time as 32-bit words
+    and taken apart (a bf16 is the upper half of its float32). Yields
+    ``(head, rows [tokens, d] in the pool's type)``."""
+    d = buf.shape[-1]
+    flat = buf.at[slot].reshape(tokens * kv_heads, d)
+    if buf.dtype == jnp.bfloat16 and kv_heads % 2 == 0:
+        words = flat.bitcast(jnp.uint32)         # [tokens x kv_heads / 2, d]
+        for pair in range(kv_heads // 2):
+            w = words[pair::kv_heads // 2, :]
+            for half, bits in ((0, w << 16), (1, w & jnp.uint32(0xFFFF0000))):
+                yield 2 * pair + half, pltpu.bitcast(
+                    bits, jnp.float32).astype(jnp.bfloat16)
+    elif jnp.dtype(buf.dtype).itemsize == 4:
+        for h in range(kv_heads):
+            yield h, flat[h::kv_heads, :]
+    else:   # a type with no strided load of its own: by way of float32
+        rows = buf[slot].astype(jnp.float32).reshape(tokens, kv_heads, d)
+        for h in range(kv_heads):
+            yield h, rows[:, h].astype(buf.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
+                                             "qb"))
+def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
+                                context_lens, layer, starts=None, *, scale,
+                                interpret, block, qb):
+    """The QUERY-TILE path of :func:`paged_attention_kernel`: ``q`` holds
+    packed prompt rows only (the rows of one sequence contiguous and in
+    order), over the stacked unquantized pool with a traced ``layer``.
+    Jitted for the same reason as the row walk: one trace a program.
+
+    The same algorithm as the row walk (online-softmax attention over the
+    live pages, a block of pages a step through two VMEM slots, the next
+    block in flight while one is folded) with a block of QUERIES where the
+    row walk has one row. The plan (:func:`chunk_tiles`, a few small
+    fusions beside the kernel) cuts the rows into tiles of up to ``qb``
+    rows of one sequence. The grid is the windows of ``qb`` rows; a window
+    walks each of its tiles (one, unless a sequence ends inside it). A
+    tile fetches each page from its lowest ``starts`` to its highest limit
+    ONCE and folds a block for all its rows: a K/V head's rows are taken
+    out of the pages as they lie (:func:`_head_rows`) and multiplied with
+    the ``qb x group`` query rows of that head on the MXU, the mask a row
+    (``starts[row] <= token < limits[row]``: causal inside the chunk as in
+    the row walk), float32 scores and softmax state a query row, the
+    probabilities rounded to the pool's type for the second product,
+    float32 sums. A row outside the tile at work (another sequence's, a
+    padded one) is masked whole and its output left alone; a padded row's
+    output is zero."""
+    windowed = starts is not None
+    rows, n_heads, d = q.shape
+    _, _, page_size, kv_heads, _ = k_pages.shape
+    pages_per_seq = block_tables.shape[1]
+    group = n_heads // kv_heads
+    n_win = -(-rows // qb)
+    tile_rows = qb * group            # query rows a K/V head a tile
+    tokens = block * page_size
+    exact = jax.lax.Precision.HIGHEST \
+        if k_pages.dtype == jnp.float32 else None
+
+    pad = n_win * qb - rows
+    tables = jnp.clip(block_tables, 0).astype(jnp.int32)
+    lens = jnp.pad(jnp.clip(context_lens.astype(jnp.int32), 0,
+                            pages_per_seq * page_size), (0, pad))
+    lows = jnp.zeros_like(lens) if not windowed else jnp.pad(
+        jnp.clip(starts.astype(jnp.int32), 0, lens[:rows]), (0, pad))
+    new_seq = jnp.pad(jnp.any(tables[1:] != tables[:-1], axis=1), (1, pad),
+                      constant_values=True)
+    head, count, first_page, last_page = chunk_tiles(
+        new_seq, lens, lows, page_size, qb)
+    # next_tile[r]: the first row of the first tile after row r (``rows``
+    # padded when there is none); the prefetch across tiles follows it
+    row_ids = jnp.arange(n_win * qb, dtype=jnp.int32)
+    tile_from = jax.lax.cummin(
+        jnp.where(head, row_ids, n_win * qb), reverse=True)
+    next_tile = jnp.concatenate(
+        [tile_from[1:], jnp.full((1,), n_win * qb, jnp.int32)])
+    meta = jnp.stack([layer, tile_from[0]])
+
+    # [windows, kv_heads, group x qb, d]: a K/V head's query rows of a
+    # window together, a group row's qb rows contiguous
+    qt = jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(
+        n_win, qb, kv_heads, group, d).transpose(0, 2, 3, 1, 4).reshape(
+        n_win, kv_heads, tile_rows, d)
+
+    def kernel(head_ref, count_ref, lo_ref, hi_ref, next_ref, meta_ref,
+               tbl_ref, q_ref, lim_ref, low_ref, k_hbm, v_hbm, o_ref, k_buf,
+               v_buf, sems, slot_ref, acc_ref, m_ref, l_ref):
+        w = pl.program_id(0)
+
+        copies = _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf,
+                              sems)
+
+        def block_copies(row, blk, slot, do):
+            """``do`` (start or wait) the K and V copy of each live page
+            of block ``blk`` of the tile whose first row is ``row``."""
+            first_page = lo_ref[row] + blk * block
+            copies(row, first_page,
+                   jnp.minimum(block, hi_ref[row] - first_page), slot, do)
+
+        def start(row, blk, slot):
+            block_copies(row, blk, slot, lambda c: c.start())
+
+        def wait(row, blk, slot):
+            block_copies(row, blk, slot, lambda c: c.wait())
+
+        def per_row(column):
+            """[qb, 1] a row of the window -> [tile_rows, 1] a query row of
+            a K/V head (group rows of qb)."""
+            return jnp.concatenate([column] * group, axis=0)
+
+        def tile(row):
+            n_pages = hi_ref[row] - lo_ref[row]
+            n_blocks = pl.cdiv(n_pages, block)
+            at = jax.lax.broadcasted_iota(jnp.int32, (qb, 1), 0) + w * qb
+            mine = (at >= row) & (at < row + count_ref[row])
+            limit = per_row(jnp.where(mine, lim_ref[0], 0))
+            lower = per_row(low_ref[0]) if windowed else None
+
+            @pl.when(row == meta_ref[1])
+            def _first_tile():            # nobody prefetched for it
+                slot_ref[0] = 0
+                start(row, 0, 0)
+
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+            def attend_block(blk, slot):
+                @pl.when(blk + 1 < n_blocks)
+                def _next_block():
+                    start(row, blk + 1, 1 - slot)
+
+                @pl.when(blk + 1 == n_blocks)
+                def _next_tile():
+                    nxt = next_ref[row]
+
+                    @pl.when(nxt < n_win * qb)
+                    def _():
+                        start(nxt, 0, 1 - slot)
+
+                wait(row, blk, slot)
+                first_token = (lo_ref[row] + blk * block) * page_size
+                token = first_token + jax.lax.broadcasted_iota(
+                    jnp.int32, (tile_rows, tokens), 1)
+                valid = token < limit
+                if windowed:
+                    valid = valid & (token >= lower)
+                # what the copies of this block did not write is whatever
+                # the slot held: keep it out of the second product
+                fetched = jax.lax.broadcasted_iota(
+                    jnp.int32, (tokens, 1), 0) < jnp.minimum(
+                    block, n_pages - blk * block) * page_size
+                for (h, kh), (_, vh) in zip(
+                        _head_rows(k_buf, slot, kv_heads, tokens),
+                        _head_rows(v_buf, slot, kv_heads, tokens)):
+                    qh = q_ref[0, h].astype(k_pages.dtype)   # [tile_rows, d]
+                    s = jax.lax.dot_general(
+                        qh, kh, (((1,), (1,)), ((), ())), precision=exact,
+                        preferred_element_type=jnp.float32) * scale
+                    s = jnp.where(valid, s, _MASK_VALUE)
+                    m_prev = m_ref[h][:, :1]
+                    l_prev = l_ref[h][:, :1]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    p_ = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                    l_new = alpha * l_prev + jnp.sum(p_, axis=1,
+                                                     keepdims=True)
+                    vh = jnp.where(fetched, vh, jnp.zeros_like(vh))
+                    acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                        p_.astype(k_pages.dtype), vh,
+                        (((1,), (0,)), ((), ())), precision=exact,
+                        preferred_element_type=jnp.float32)
+                    m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                    l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+                return 1 - slot
+
+            slot_ref[0] = jax.lax.fori_loop(0, n_blocks, attend_block,
+                                            slot_ref[0])
+            live = limit > 0              # [tile_rows, 1]: this tile's rows
+            total = jnp.where(live, l_ref[...][:, :, :1], 1.0)
+            o_ref[0] = jnp.where(live, acc_ref[...] / total,
+                                 o_ref[0].astype(jnp.float32)).astype(
+                o_ref.dtype)
+
+        o_ref[...] = jnp.zeros_like(o_ref)     # a padded row: a zero row
+
+        def row_of_window(i, carry):
+            row = w * qb + i
+
+            @pl.when(head_ref[row] == 1)
+            def _():
+                tile(row)
+
+            return carry
+
+        jax.lax.fori_loop(0, qb, row_of_window, 0)
+
+    q_spec = pl.BlockSpec((1, kv_heads, tile_rows, d),
+                          lambda w, *_: (w, 0, 0, 0))
+    row_spec = pl.BlockSpec((1, qb, 1), lambda w, *_: (w, 0, 0))
+    hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
+    prefetch = [head.astype(jnp.int32), count.astype(jnp.int32),
+                first_page.astype(jnp.int32), last_page.astype(jnp.int32),
+                next_tile, meta, tables]
+    page_buffer = pltpu.VMEM((2, block, page_size, kv_heads, d),
+                             k_pages.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(n_win,),
+        in_specs=[q_spec, row_spec, row_spec, hbm_spec, hbm_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            page_buffer, page_buffer,
+            pltpu.SemaphoreType.DMA((2, 2)),      # (K | V, slot)
+            pltpu.SMEM((1,), jnp.int32),          # slot of the block due
+            pltpu.VMEM((kv_heads, tile_rows, d), jnp.float32),
+            pltpu.VMEM((kv_heads, tile_rows, _LANES), jnp.float32),
+            pltpu.VMEM((kv_heads, tile_rows, _LANES), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        # sequential: the page buffers, their semaphores and the slot
+        # carry a prefetched block from one tile into the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention_chunk",
+    )(*prefetch, qt, lens.reshape(n_win, qb, 1), lows.reshape(n_win, qb, 1),
+      k_pages, v_pages)
+    return out.reshape(n_win, kv_heads, group, qb, d).transpose(
+        0, 3, 1, 2, 4).reshape(n_win * qb, n_heads, d)[:rows]
+
+
 def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
                            token_tables, token_lens,
                            scale: Optional[float] = None,
-                           impl: str = "xla", layer=None, starts=None):
+                           impl: str = "xla", layer=None, starts=None,
+                           n_chunk: int = 0):
     """THE ragged paged-attention entry point: ONE op serving every
     attention shape the engine dispatches — single-token decodes,
     chunked-prefill suffixes, speculative-verify windows, and a MIXED
@@ -642,6 +975,14 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     a sliding window's rows. All three paths take it; the kernel fetches
     no page that lies wholly before a row's ``starts``.
 
+    ``n_chunk`` (a static Python int; 0 = none, today's program text):
+    the first ``n_chunk`` tokens are PACKED PROMPT ROWS (``RaggedRows``:
+    the rows of one sequence contiguous and in order). Only the kernel
+    reads it: it sends them through query tiles, each page of a sequence
+    fetched once a tile of rows and not once a row
+    (:func:`paged_attention_kernel`). The result is that of the row walk
+    to rounding; the gathered paths ignore it.
+
     ``impl``: ``"xla"`` (gather of every table entry + dense masked
     softmax, f32 accumulate: the path off the TPU), ``"pallas"``
     (:func:`paged_attention_kernel`: a row's live pages streamed out of
@@ -657,7 +998,8 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
         return paged_attention_kernel(q, kp, vp, token_tables,
                                       token_lens, layer=layer,
                                       scale=scale, k_scales=ks,
-                                      v_scales=vs, starts=starts)
+                                      v_scales=vs, starts=starts,
+                                      n_chunk=n_chunk)
     if impl == "reference":
         return ragged_paged_attention_reference(
             q, kv_k, kv_v, token_tables, token_lens,

@@ -145,6 +145,69 @@ def test_paged_attention_over_the_stacked_pool_compiles_for_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes <= budget
 
 
+# (chunk, slots, heads, K/V heads, table columns, cache layers, pool pages,
+# a lower bound a row) of a mixed tick's attention call in each serving cell
+MIXED_CALLS = {
+    "chat_closed": (64, 32, 16, 16, 128, 24, 2721, False),
+    "chat_closed_hybrid": (256, 64, 32, 8, 128, 4, 8193, False),
+    "reason_closed_looped": (128, 8, 16, 16, 40, 192, 321, False),
+    "agent_closed_swa-full": (256, 32, 48, 8, 576, 3, 18433, False),
+    "agent_closed_swa-window": (256, 32, 72, 8, 576, 9, 1569, True),
+}
+
+
+@pytest.mark.parametrize("cell", list(MIXED_CALLS))
+def test_mixed_ticks_attention_call_compiles_for_v5e(one_chip, monkeypatch,
+                                                     cell):
+    """The mixed program's attention call at each serving cell's shapes:
+    the chunk's rows through query tiles (``paged_attention_chunk``: the
+    plan's scalars and the chunk's tables in SMEM, a tile's state, query
+    block and the page buffers inside the scoped VMEM limit), the decode
+    rows through the row walk, over the stacked pool with a traced layer;
+    the window group's with a lower bound a row and its [288, 576] tables.
+    Beside the two kernels the program keeps the plan and the query
+    block's two transposes, nothing of the pool's size."""
+    import importlib
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    chunk, slots, heads, kv_heads, columns, layers, pages, bound = \
+        MIXED_CALLS[cell]
+    rows = chunk + slots
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((rows, heads, HEAD_DIM), jnp.bfloat16)
+    pool = sds((layers, pages, PAGE, kv_heads, HEAD_DIM), jnp.bfloat16)
+    ints = sds((rows,), jnp.int32)
+
+    def call(q, k, v, tables, lens, starts, layer):
+        return ragged_paged_attention(
+            q, k, v, tables, lens, impl="pallas", layer=layer,
+            starts=starts if bound else None, n_chunk=chunk)
+
+    compiled = _compiled(call, q, pool, pool,
+                         sds((rows, columns), jnp.int32), ints, ints,
+                         sds((), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "paged_attention_chunk" in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 6 * rows * heads * HEAD_DIM * 2
+
+
+def test_a_decode_tick_lowers_without_the_tile_kernel(one_chip, monkeypatch):
+    """A ``decode_fn`` has no prompt rows (``n_chunk`` 0): its lowered text
+    names the row walk and not the tile kernel
+    (``tests/test_paged_kernel.py`` pins that call's jaxpr to the one
+    before the argument existed)."""
+    text = _lowered_decode_tick(one_chip, monkeypatch).as_text()
+    assert "paged_attention" in text
+    assert "paged_attention_chunk" not in text
+
+
 def _lowered_decode_tick(one_chip, monkeypatch, layers=2, rows=32):
     """A ``decode_fn``-shaped program lowered for the described chip:
     ``_PagedDecode`` at the 1.3B width, the benchmark's 32 rows over a
@@ -398,7 +461,8 @@ def test_looped_program_keeps_the_pool_in_place_through_its_passes(
     """The looped model's engine programs at Ouro-2.6B's widths (two layers,
     four passes, the cell's 8 slots and 128-row chunk): the passes are ONE
     ``while`` whose carry holds the pool, each layer's kernel call stands
-    once in the text (not once a pass), both pools are the program's own
+    once in the text (not once a pass; a mixed tick's twice: the chunk's
+    query tiles and the decode rows' row walk), both pools are the program's own
     outputs (aliased: the scatter at a traced cache layer and the kernel's
     read leave them where they are) and the temporaries stay under a
     twentieth of one pool (the decode tick read 2.9 MB). Unrolled or copied, a
@@ -451,7 +515,12 @@ def test_looped_program_keeps_the_pool_in_place_through_its_passes(
         eng.close()
     compiled = lowered.compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == layers and " while(" in text
+    # a mixed tick's layer calls the kernel twice: its chunk's rows through
+    # query tiles, its decode rows through the row walk
+    per_layer = 2 if program == "mixed" else 1
+    assert text.count("tpu_custom_call") == per_layer * layers
+    assert ("paged_attention_chunk" in text) == (program == "mixed")
+    assert " while(" in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * pool
     assert mem.temp_size_in_bytes < pool // 20, (
@@ -465,8 +534,9 @@ def test_windowed_program_keeps_both_groups_pools_in_place(
     widths (hidden 3072, 8 K/V heads of 128), two layers of each kind (48
     heads on a full layer, 72 on a sliding one: 6 and 9 a K/V head), four of
     the 256 experts held, the cell's 32 slots, 256-row chunk and 576-column
-    tables: one kernel call a layer, the sliding layers' with a lower bound
-    a row (a fifth scalar-prefetch operand), every routed layer's two
+    tables: one kernel call a layer (two in a mixed tick: the chunk's rows
+    through query tiles, the decode rows through the row walk), the sliding
+    layers' with a lower bound a row, every routed layer's two
     grouped products through the kernel, BOTH groups' pools the program's
     own outputs (aliased) and the temporaries under a quarter of the pools (a copy of one group's K or V
     would be a quarter; the mixed tick read 28 MB of 168). The
@@ -534,7 +604,10 @@ def test_windowed_program_keeps_both_groups_pools_in_place(
         return [ln for ln in text.splitlines() if " custom-call(" in ln
                 and "%" + kernel in ln.split(" = ")[0]]
 
-    assert len(calls("paged_attention")) == 4
+    # a layer's row walk, and in a mixed tick its chunk's query tiles too
+    assert len(calls("paged_attention.")) == 4
+    assert len(calls("paged_attention_chunk")) \
+        == (4 if program == "mixed" else 0)
     assert len(calls("grouped_matmul")) == 6 and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * pools

@@ -315,3 +315,284 @@ def test_many_heads_a_kv_head_fold_on_the_mxu_in_the_pools_type(block,
             starts=bound))
         np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
         assert np.abs(got - want).max() < 2e-2
+
+
+# -- query tiles (``n_chunk=``): a chunk of packed prompt rows ---------------
+
+@pytest.fixture(params=[8, None], ids=["tiles-of-8-rows", "one-tile"])
+def tile_rows(request, monkeypatch):
+    """Rows of a query tile: eight (the 20-row chunk below is then three
+    windows, one of them cut twice by a sequence boundary), or what the
+    chunk gives (one window of 24)."""
+    if request.param is not None:
+        monkeypatch.setattr(pa, "_TILE_ROWS", request.param)
+    return request.param
+
+
+def packing(decode=(20, 0, 5, MAX_LEN)):
+    """A mixed tick's rows. The chunk: eleven rows of sequence A from
+    position 5 on (mid-prompt, not a page's first), ONE row of B, six rows
+    of C from its first position, two padded rows; then ``decode`` rows of
+    their own. With tiles of eight rows, A's run is cut by a window's edge
+    and B and C begin inside a tile's width."""
+    runs = [(6, 11), (9, 1), (1, 6), (0, 2)]       # (first limit, rows)
+    own = np.asarray(tables_for(len(runs) + len(decode), seed=13))
+    tables, lens = [], []
+    for i, (first, n) in enumerate(runs):
+        for j in range(n):
+            tables.append(own[i] if first else np.zeros_like(own[i]))
+            lens.append(first + j if first else 0)
+    n_chunk = len(lens)
+    for i, limit in enumerate(decode):
+        tables.append(own[len(runs) + i])
+        lens.append(limit)
+    return (jnp.asarray(np.stack(tables), jnp.int32),
+            jnp.asarray(lens, jnp.int32), n_chunk)
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["whole", "window-6"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("group", [1, 4, 6, 9])
+def test_query_tiles_attend_what_the_rows_attend(block, tile_rows, group,
+                                                 dtype, window):
+    """Prompt rows through query tiles, decode rows through the row walk,
+    against the float32 reference: 1, 4, 6 and 9 query heads a K/V head,
+    float32 and bf16 pools, with and without a lower bound a row (a window
+    of 6 over contexts past it, the walk beginning mid-page). Padded rows
+    are zero rows; the decode rows are the row walk's bit for bit."""
+    kv_heads = 2
+    k, v = pool(group, kv_heads, dtype)
+    tables, lens, n_chunk = packing()
+    q = queries(len(lens), group * kv_heads, seed=14).astype(dtype)
+    starts = None if window is None else jnp.maximum(lens - window, 0)
+    got = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=1, starts=starts,
+        n_chunk=n_chunk), np.float32)
+    want = np.asarray(ragged_paged_attention_reference(
+        q, pa.kv_layer(k, 1), pa.kv_layer(v, 1), tables, lens,
+        starts=starts))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    assert not got[np.asarray(lens) == 0].any(), "limit 0 is a zero row"
+    by_row = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=1, starts=starts),
+        np.float32)
+    np.testing.assert_array_equal(got[n_chunk:], by_row[n_chunk:])
+    np.testing.assert_allclose(got[:n_chunk], by_row[:n_chunk], atol=tol,
+                               rtol=tol)
+
+
+@pytest.mark.parametrize("kv_heads,dtype", [
+    (1, jnp.bfloat16), (3, jnp.bfloat16), (3, jnp.float32),
+    (2, jnp.float16)], ids=["1-bf16", "3-bf16", "3-f32", "2-f16"])
+def test_a_prefill_program_is_all_tiles(block, tile_rows, kv_heads, dtype):
+    """``n_chunk`` = all rows (a prefill program: no row walk at all), an
+    odd number of K/V heads and a type without a strided load of its
+    own."""
+    k, v = pool(21, kv_heads, dtype)
+    tables, lens, n_chunk = packing(decode=())
+    assert n_chunk == len(lens)
+    q = queries(n_chunk, 2 * kv_heads, seed=22).astype(dtype)
+    got = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=0, n_chunk=n_chunk),
+        np.float32)
+    want = np.asarray(ragged_paged_attention_reference(
+        q, pa.kv_layer(k, 0), pa.kv_layer(v, 0), tables, lens))
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+def jaxpr_text(fn, *args):
+    import re
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+
+
+def test_no_chunk_is_todays_program_bit_for_bit(block):
+    """``n_chunk=0`` lowers to the same kernel as before the argument
+    existed (every ``decode_fn``): the same jaxpr, the same bits, with and
+    without a lower bound; with a chunk the program has both kernels."""
+    k, v = pool(0, kv_heads=2)
+    tables, lens, n_chunk = packing()
+    q = queries(len(lens), 4)
+    for starts in (None, jnp.maximum(lens - 6, 0)):
+        def call(*a, **kw):
+            return ragged_paged_attention(*a, impl="pallas", layer=2,
+                                          starts=starts, **kw)
+        without = jaxpr_text(call, q, k, v, tables, lens)
+        assert jaxpr_text(lambda *a: call(*a, n_chunk=0),
+                          q, k, v, tables, lens) == without
+        assert "paged_attention_chunk" not in without
+        tiled = jaxpr_text(lambda *a: call(*a, n_chunk=n_chunk),
+                           q, k, v, tables, lens)
+        assert "paged_attention_chunk" in tiled
+        np.testing.assert_array_equal(
+            np.asarray(call(q, k, v, tables, lens, n_chunk=0)),
+            np.asarray(call(q, k, v, tables, lens)))
+    # the gathered paths take the argument and ignore it
+    for impl in ("xla", "reference"):
+        np.testing.assert_array_equal(
+            np.asarray(ragged_paged_attention(
+                q, k, v, tables, lens, impl=impl, layer=2,
+                n_chunk=n_chunk)),
+            np.asarray(ragged_paged_attention(
+                q, k, v, tables, lens, impl=impl, layer=2)))
+
+
+def test_an_int8_pool_keeps_the_row_walk_under_a_chunk(block):
+    """An int8 pool's prompt rows still answer, through the row walk: the
+    program with ``n_chunk`` is the program without."""
+    kf, vf = pool(7, kv_heads=2)
+    kq, ks = quantize_kv(kf)
+    vq, vs = quantize_kv(vf)
+    tables, lens, n_chunk = packing()
+    q = queries(len(lens), 4, seed=8)
+
+    def call(*a, **kw):
+        return ragged_paged_attention(
+            a[0], QuantizedKV(a[1], a[2]), QuantizedKV(a[3], a[4]), a[5],
+            a[6], impl="pallas", layer=2, **kw)
+
+    args = (q, kq, ks, vq, vs, tables, lens)
+    assert jaxpr_text(lambda *a: call(*a, n_chunk=n_chunk), *args) \
+        == jaxpr_text(call, *args)
+    got = np.asarray(call(*args, n_chunk=n_chunk))
+    want = np.asarray(ragged_paged_attention_reference(
+        q, QuantizedKV(kq[2], ks[2]), QuantizedKV(vq[2], vs[2]), tables,
+        lens))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_a_tile_fetches_no_page_outside_its_rows_windows(block, tile_rows):
+    """A windowed tile walks from its lowest ``starts`` to its highest
+    limit: table entries wholly before or after point at pages full of NaN,
+    and the result is finite and right."""
+    k, v = pool(4, kv_heads=2)
+    tables, lens, n_chunk = packing(decode=())
+    lens = jnp.where(lens > 0, lens + 12, 0)       # contexts past the window
+    q = queries(n_chunk, 12)
+    starts = jnp.maximum(lens - 6, 0)
+    want = dense_window(q, k, v, tables, lens, starts, layer=0)
+    poisoned = PAGES - 1
+    k = k.at[:, poisoned].set(jnp.nan)
+    v = v.at[:, poisoned].set(jnp.nan)
+    tables, lo, hi = np.asarray(tables).copy(), {}, {}
+    for r in range(n_chunk):                       # a sequence's rows
+        key = tuple(tables[r])
+        lo[key] = min(lo.get(key, MAX_LEN), int(starts[r]))
+        hi[key] = max(hi.get(key, 0), int(lens[r]))
+    for r in range(n_chunk):
+        key = tuple(tables[r])
+        if hi[key]:
+            tables[r, :lo[key] // PS] = poisoned
+            tables[r, -(-hi[key] // PS):] = poisoned
+    got = ragged_paged_attention(q, k, v, jnp.asarray(tables), lens,
+                                 impl="pallas", layer=0, starts=starts,
+                                 n_chunk=n_chunk)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+# -- the fetch count: the kernel's plan and the pool's counter ---------------
+
+def plan_pages(tables, lens, starts, qb):
+    """Pages the kernel's plan fetches for a chunk: the sum over its tiles,
+    from the arrays the program sees (sequences told apart by their
+    tables, as :func:`_paged_attention_chunk_call` does)."""
+    pad = -len(lens) % qb
+    lens = jnp.pad(lens, (0, pad))
+    starts = jnp.pad(starts, (0, pad))
+    new_seq = jnp.pad(jnp.any(tables[1:] != tables[:-1], axis=1), (1, pad),
+                      constant_values=True)
+    head, _, first, last = pa.chunk_tiles(new_seq, lens, starts, PS, qb)
+    return int(jnp.sum(jnp.where(head, last - first, 0)))
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["plain", "window-6"])
+def test_the_pools_counter_reads_what_the_plan_fetches(tile_rows, window):
+    """``PagePool.pages_touched`` reckons a call's prompt rows by tile with
+    the kernel's own arithmetic (``chunk_tiles``): its READ for a packing
+    is what the plan fetches for it plus a decode row's pages each, for a
+    plain group and a window group; the gathered path's READ stands."""
+    from paddle_tpu.inference.page_pool import (CacheGroup, ChunkRows,
+                                                PagePool)
+    tables, lens, n_chunk = packing()
+    lens = jnp.where(lens > 0, lens + 12, 0)
+    starts = jnp.maximum(lens - (window or MAX_LEN + 12), 0)
+    pool_ = PagePool([CacheGroup("g", LAYERS, 2, D, window)], PAGES, PS,
+                     max_seqs=8, pages_per_seq=PAGES_PER_SEQ + 3,
+                     kv_dtype="f32", prefill_chunk=n_chunk)
+    host_lens = np.asarray(lens)
+    seqs = np.asarray([0] * 11 + [1] + [2] * 6 + [-1] * 2)[None]
+    chunk = ChunkRows(seqs, host_lens[None, :n_chunk])
+    decode = [(3 + i, int(n)) for i, n in enumerate(host_lens[n_chunk:])
+              if n]
+    rows = len(host_lens)
+    got = pool_.pages_touched([(decode, rows, "pallas", chunk)])["g"]
+    qb = pa.chunk_tile_rows(n_chunk)
+    fetched = plan_pages(tables[:n_chunk], lens[:n_chunk], starts[:n_chunk],
+                         qb)
+    walked = sum(-(-n // PS) - int(s) // PS
+                 for _, n in decode
+                 for s in [max(n - window, 0) if window else 0])
+    assert got["read"] == fetched + walked
+    by_row = pool_.pages_touched([(decode + chunk.live_rows(), rows,
+                                   "pallas")])["g"]
+    assert by_row["live"] == got["live"]
+    assert by_row["read"] > got["read"]
+    gathered = pool_.pages_touched([(decode, rows, "xla", chunk)])["g"]
+    assert gathered == {"read": rows * (PAGES_PER_SEQ + 3),
+                        "live": got["live"]}
+
+
+def test_a_mixed_ticks_chunk_reads_its_sequence_once_a_tile():
+    """A ``_MixedTick``-shaped packing at ``agent_closed_swa``'s size: 256
+    prompt rows of one 4k-token sequence and 32 decode rows. By tile the
+    kernel reads under 1.5 x the live pages, in a plain group and in a
+    window group; a walk a row read the chunk's sequence 256 times over
+    (the ledger's 3.59 x in the cell)."""
+    from paddle_tpu.inference.page_pool import (CacheGroup, ChunkRows,
+                                                PagePool)
+    pool_ = PagePool([CacheGroup("full", 1, 1, 8), CacheGroup("window", 1,
+                                                              1, 8, 512)],
+                     64, 16, max_seqs=33, pages_per_seq=576,
+                     kv_dtype="f32", prefill_chunk=256)
+    limits = np.arange(3841, 4097)[None]
+    chunk = ChunkRows(np.zeros_like(limits), limits)
+    decode = [(1 + i, int(n))
+              for i, n in enumerate(np.linspace(700, 8000, 32))]
+    by_tile = pool_.pages_touched([(decode, 288, "pallas", chunk)])
+    by_row = pool_.pages_touched([(decode + chunk.live_rows(), 288,
+                                   "pallas")])
+    for name in ("full", "window"):
+        assert by_tile[name]["live"] == by_row[name]["live"]
+        assert by_tile[name]["read"] < 1.5 * by_tile[name]["live"]
+    read, live = (sum(g[key] for g in by_row.values())
+                  for key in ("read", "live"))
+    assert read > 3.5 * live
+    # eight tiles of 32 rows, each to its own highest limit's page: 242,
+    # 244, ... 256 pages, where the sequence's 256 are live
+    assert by_tile["full"]["read"] - by_tile["full"]["live"] \
+        == sum(range(242, 257, 2)) - 256
+
+
+@pytest.mark.parametrize("heads,kv_heads,bound,digest", [
+    (4, 4, False, "377f69c93ec7f687"), (12, 2, True, "ed082b9229dd3334")],
+    ids=["vpu-fold", "mxu-fold-with-a-lower-bound"])
+def test_a_call_without_prompt_rows_is_the_program_of_pr_35(
+        monkeypatch, heads, kv_heads, bound, digest):
+    """Every ``decode_fn`` calls the kernel with ``n_chunk`` 0: the jaxpr of
+    that call (the row walk, compiled, not interpreted) is, to the letter,
+    the one the commit before the tile path traced (PR 36's parent, digests
+    taken from a checkout of it). A later edit to the row walk moves these
+    on purpose and names itself here."""
+    import hashlib
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    k, v = pool(0, kv_heads)
+    lens = jnp.asarray([9, MAX_LEN, 1, 0, 17], jnp.int32)
+    text = jaxpr_text(
+        lambda *a: ragged_paged_attention(
+            *a[:5], impl="pallas", layer=2, starts=a[5] if bound else None,
+            n_chunk=0), queries(5, heads), k, v, tables_for(5), lens, lens)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
