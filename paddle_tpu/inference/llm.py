@@ -2963,21 +2963,22 @@ class LLMEngine:
         self._m["prefill_ticks"].inc()
         self._update_kv_gauge()
 
+    def _admit_arrivals(self, pending: list, ph) -> None:
+        """Admission's part of an iteration, inside its
+        ``llm.loop.admit`` phase ``ph``."""
+        ph.set_attr("pending", len(pending))
+        # higher priority admits first; FIFO (by submission order)
+        # within a priority class — retries re-enter the next drain and
+        # re-sort with new arrivals
+        pending.sort(key=lambda r: (-r.priority, r.req_id))
+        for req in pending:
+            self._harvest_admit(req)
+        self._police_slots()
+        self._m["queue_depth"].set(self._n_queued)
+
     def _loop(self):
         while True:
             try:
-                with self._mu:
-                    closed = self._closed
-                    pending = self._pending
-                    self._pending = []
-                    ctl = self._ctl
-                    self._ctl = []
-                # control ops run HERE: the previous iteration drained
-                # its dispatches to the lag boundary, so the pool
-                # arrays are settled outputs (no donated input buffer
-                # is still feeding a queued program). Each op resolves
-                # its own future and never raises into the loop.
-                #
                 # PHASES: the iteration below is tiled by flat leaf
                 # phases (llm.loop.* / llm.issue.* / llm.drain.*) that
                 # are recorded only while tracing is active: the span
@@ -2985,22 +2986,31 @@ class LLMEngine:
                 # their names, which is how a device idle gap finds
                 # the host work that covered it. An issue path whose
                 # phase carries the dispatch's attrs opens it itself.
+                # The host's turn begins HERE, so the loop's head (the
+                # lock, what arrived) is admission's.
+                with _trace.phase("llm.loop.admit") as ph:
+                    with self._mu:
+                        closed = self._closed
+                        pending = self._pending
+                        self._pending = []
+                        ctl = self._ctl
+                        self._ctl = []
+                    if not ctl:
+                        self._admit_arrivals(pending, ph)
                 if ctl:
+                    # control ops run HERE, before admission: the
+                    # previous iteration drained its dispatches to the
+                    # lag boundary, so the pool arrays are settled
+                    # outputs (no donated input buffer is still feeding
+                    # a queued program). Each op resolves its own future
+                    # and never raises into the loop. The phases stay
+                    # flat, so admission gets a second one after them.
                     with _trace.phase("llm.loop.control",
                                       {"ops": len(ctl)}):
                         for op, _fut in ctl:
                             op()
-                with _trace.phase("llm.loop.admit",
-                                  {"pending": len(pending)}):
-                    # higher priority admits first; FIFO (by
-                    # submission order) within a priority class —
-                    # retries re-enter the next drain and re-sort with
-                    # new arrivals
-                    pending.sort(key=lambda r: (-r.priority, r.req_id))
-                    for req in pending:
-                        self._harvest_admit(req)
-                    self._police_slots()
-                self._m["queue_depth"].set(self._n_queued)
+                    with _trace.phase("llm.loop.admit") as ph:
+                        self._admit_arrivals(pending, ph)
                 busy = False
                 mixed = self.mixed_tick and bool(self._prefill_q)
                 if mixed:
@@ -3535,7 +3545,18 @@ class LLMEngine:
         """Dispatch ONE decode step for the live slots; tokens chain
         from the previous step ON DEVICE (no fetch here). The body
         is one ``llm.issue.*`` leaf phase (here and in the other
-        issue paths) that takes the dispatch's attrs."""
+        issue paths) that takes the dispatch's attrs.
+
+        MARKS: here and in :meth:`_issue_mixed` the phase carries four
+        events, in this order, every dispatch: ``packed`` (the plan and
+        the host arrays are complete: Python planning and packing lie
+        before it), ``staged`` (every argument of the program is a
+        device array: host-to-device staging), ``launched`` (the jitted
+        call has returned: argument flattening and the runtime's
+        enqueue; the device works from here on), ``booked`` (what the
+        engine does after a launch whether or not anyone is tracing).
+        From ``booked`` to the phase's end runs only what exists for
+        the trace: the attrs and the ``_stamp_*`` arithmetic."""
         with _trace.phase("llm.issue.decode") as ph:
             for slot in list(live):
                 req = self._slots[slot]
@@ -3566,6 +3587,7 @@ class LLMEngine:
             if _faults.enabled():
                 _faults.check("device.dispatch")
             self._guard_recompiles("decode_step")
+            ph.add_event("packed")
             args = (self._params, self._buffers,
                     self._tokens_dev, jnp.asarray(positions),
                     self._pool.device_tables(), jnp.asarray(lens),
@@ -3575,27 +3597,33 @@ class LLMEngine:
                 + self._state_args()
             if _perf.enabled():
                 self._perf_program("decode_step", (), self._decode_fn, args)
-            tokens, fetch = self._take_outputs(self._decode_fn(*args))
+            ph.add_event("staged")
+            out = self._decode_fn(*args)
+            ph.add_event("launched")
+            tokens, fetch = self._take_outputs(out)
             self._count_dispatch()
             self._tokens_dev = tokens
             self._issue_seq += 1
             self._inflight.append((self._issue_seq, list(live), fetch,
                                    "d", None))
-            ph.set_attr("issue_seq", self._issue_seq) \
-                .set_attr("live_rows", len(live)).set_attr("ticks", 1)
-            self._stamp_state(ph, True, 0, len(live))
             for slot in live:
                 self.context_lens[slot] += 1
-            self._stamp_kv_pages(
-                ph, ([(slot, lens[slot]) for slot in live],
-                     self.max_seqs, self.attention_impl),
-                released=self._release_behind(
-                    (slot, lens[slot]) for slot in live))
+            released = self._release_behind(
+                (slot, lens[slot]) for slot in live)
             self.n_decode_ticks += 1
             self.tick_history.append("d")
             self._m["decode_ticks"].inc()
             self._m["occupancy"].observe(len(live) / self.max_seqs)
             self._update_kv_gauge()
+            ph.add_event("booked")
+            if ph is not _trace.NOOP_SPAN:
+                ph.set_attr("issue_seq", self._issue_seq) \
+                    .set_attr("live_rows", len(live)).set_attr("ticks", 1)
+                self._stamp_state(ph, True, 0, len(live))
+                self._stamp_kv_pages(
+                    ph, ([(slot, lens[slot]) for slot in live],
+                         self.max_seqs, self.attention_impl),
+                    released=released)
 
     def _plan_slab(self, live: List[int], N: int):
         """The decode-side slab plan, shared by the pure-decode slab
@@ -3737,7 +3765,8 @@ class LLMEngine:
         so the request decodes from tick j+1 with no host dispatch
         between its phases. The drain replays the device's masking
         from the host copy of (budgets, start tick, start position),
-        sharing :meth:`_drain_slab`."""
+        sharing :meth:`_drain_slab`. The phase carries the marks of
+        :meth:`_issue`."""
         with _trace.phase("llm.issue.mixed") as ph:
             N = self.decode_ticks_per_dispatch
             ps = self.page_size
@@ -3876,6 +3905,7 @@ class LLMEngine:
             for slot in plan:
                 pos_arr[slot] = plan[slot][0]
                 bud_arr[slot] = min(entry_bud[slot], n_run)
+            ph.add_event("packed")
             carry = self._new_carry(pos_arr, bud_arr)
             xs = {"tok": jnp.asarray(ptok[:n_run]),
                   "pos": jnp.asarray(ppos[:n_run]),
@@ -3895,7 +3925,9 @@ class LLMEngine:
             if _perf.enabled():
                 self._perf_program("mixed_tick", (n_run,), self._mixed_fn,
                                    mixed_args, steps=n_run)
+            ph.add_event("staged")
             toks, carry = self._mixed_fn(*mixed_args)
+            ph.add_event("launched")
             self._count_dispatch()
             self._take_carry(carry)
             if self.spec_k:
@@ -3922,37 +3954,10 @@ class LLMEngine:
             self._inflight.append(
                 (self._issue_seq, slots_list, toks, "M",
                  {"budgets": meta_bud, "pos0": meta_pos0, "start": start}))
-            # live_rows: slots that can emit in this dispatch (decoding
-            # ones and prompts completing in it); chunk_rows: prompts
-            # that got a chunk; chunk_tokens: their prompt tokens
-            ph.set_attr("issue_seq", self._issue_seq) \
-                .set_attr("live_rows", len(slots_list)) \
-                .set_attr("chunk_rows", len(touched)) \
-                .set_attr("chunk_tokens", n_prefill_tokens) \
-                .set_attr("ticks", n_run)
-            self._stamp_state(ph, True, len(touched),
-                              len(slots_list) - len(start))
             released = self._release_behind(
                 [(slot, p0 + take) for slot, p0, take in chunks]
                 + [(slot, meta_pos0[slot] + meta_bud[slot])
                    for slot in plan])
-            if ph is not _trace.NOOP_SPAN:
-                chunk = ChunkRows(pslot[:n_run], plim[:n_run])
-                # a decode row of tick j attends pos0 + j + 1; a slot
-                # whose prompt completes at tick j0 decodes from j0 + 1
-                decode_rows = [
-                    (slot, meta_pos0[slot] + j + 1)
-                    for slot in slots_list
-                    for j in range(1 if slot in start else 0,
-                                   meta_bud[slot])]
-                self._stamp_kv_pages(
-                    ph, (decode_rows, (C + self.max_seqs) * n_run,
-                         self.attention_impl, chunk),
-                    *self._draft_chunk_call(
-                        ChunkRows(chunk.seqs.reshape(1, -1),
-                                  chunk.limits.reshape(1, -1)),
-                        C * n_run),
-                    released=released)
             if self._cache is not None:
                 for req in touched:
                     # promote freshly-written FULL prompt pages to shared
@@ -3976,6 +3981,35 @@ class LLMEngine:
             self.tick_history.append("m")
             self._m["occupancy"].observe(len(slots_list) / self.max_seqs)
             self._update_kv_gauge()
+            ph.add_event("booked")
+            if ph is not _trace.NOOP_SPAN:
+                # live_rows: slots that can emit in this dispatch
+                # (decoding ones and prompts completing in it);
+                # chunk_rows: prompts that got a chunk; chunk_tokens:
+                # their prompt tokens
+                ph.set_attr("issue_seq", self._issue_seq) \
+                    .set_attr("live_rows", len(slots_list)) \
+                    .set_attr("chunk_rows", len(touched)) \
+                    .set_attr("chunk_tokens", n_prefill_tokens) \
+                    .set_attr("ticks", n_run)
+                self._stamp_state(ph, True, len(touched),
+                                  len(slots_list) - len(start))
+                chunk = ChunkRows(pslot[:n_run], plim[:n_run])
+                # a decode row of tick j attends pos0 + j + 1; a slot
+                # whose prompt completes at tick j0 decodes from j0 + 1
+                decode_rows = [
+                    (slot, meta_pos0[slot] + j + 1)
+                    for slot in slots_list
+                    for j in range(1 if slot in start else 0,
+                                   meta_bud[slot])]
+                self._stamp_kv_pages(
+                    ph, (decode_rows, (C + self.max_seqs) * n_run,
+                         self.attention_impl, chunk),
+                    *self._draft_chunk_call(
+                        ChunkRows(chunk.seqs.reshape(1, -1),
+                                  chunk.limits.reshape(1, -1)),
+                        C * n_run),
+                    released=released)
 
     def _issue_spec_slab(self, live: List[int]):
         """Dispatch up to ``decode_ticks_per_dispatch`` speculative

@@ -16,7 +16,9 @@ profiler window):
   from the tracing table (``?limit=N`` newest first, 0 = uncapped;
   ``?trace_id=`` filters to one request's spans — the cross-process
   query the fleet trace merge and operators use). Spans carry
-  ``ts_wall`` so snapshots from different processes align.
+  ``ts_wall`` so snapshots from different processes align;
+  ``finished_dropped`` counts what the ring has evicted since it was
+  cleared (``tracing.dropped_spans()``).
 - ``GET /perfz``    — live roofline view (observability.perf): MFU /
   HBM-bandwidth-utilization / FLOPs-rate over a sliding window, the
   per-program cost table (XLA FLOPs + bytes per compiled signature),
@@ -543,7 +545,8 @@ class DebugServer:
                 "finished": [dict(s, ts_wall=wall(s["ts"]))
                              for s in fin],
                 "finished_matched": matched,
-                "finished_total": total})
+                "finished_total": total,
+                "finished_dropped": tracing.dropped_spans()})
         elif url.path == "/perfz":
             # live roofline view: program cost registry (FLOPs/bytes
             # per compiled signature, resolved at most once each —

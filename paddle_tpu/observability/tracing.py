@@ -82,6 +82,10 @@ _ids = itertools.count(1)
 _SPAN_ID_PREFIX = os.urandom(4).hex()      # 8 hex + 8-hex counter
 _TRACE_ID_PREFIX = os.urandom(8).hex()     # 16 hex + the span id
 _table: deque = deque(maxlen=DEFAULT_TABLE_CAP)
+# finished spans the ring has evicted since clear(): a full deque drops
+# its oldest without a word, and a reader of the table then sees a
+# shorter window than it thinks (dropped_spans())
+_dropped = 0
 _live: Dict[str, "Span"] = {}
 _tls = threading.local()
 
@@ -197,8 +201,11 @@ class Span:
         if self.t1 is not None:
             return
         self.t1 = time.perf_counter() if t1 is None else t1
+        global _dropped
         with _lock:
             _live.pop(self.span_id, None)
+            if len(_table) == _table.maxlen:
+                _dropped += 1
             _table.append(self.to_dict())
         # while a profiler is recording, span durations feed its
         # summary() aggregates (stats ONLY — the chrome-trace row is
@@ -383,15 +390,19 @@ def active() -> bool:
 
 def set_capacity(n: int) -> None:
     """Resize the finished-span ring, keeping the newest entries."""
-    global _table
+    global _table, _dropped
     with _lock:
-        _table = deque(_table, maxlen=max(int(n), 1))
+        n = max(int(n), 1)
+        _dropped += max(len(_table) - n, 0)
+        _table = deque(_table, maxlen=n)
 
 
 def clear() -> None:
+    global _dropped
     with _lock:
         _table.clear()
         _live.clear()
+        _dropped = 0
 
 
 def _new_id() -> str:
@@ -469,6 +480,14 @@ def current_span() -> Optional[Span]:
 def finished_spans() -> List[dict]:
     with _lock:
         return list(_table)
+
+
+def dropped_spans() -> int:
+    """Finished spans the ring has evicted since ``clear()``. Anything
+    but 0 means ``finished_spans()`` starts later than the window the
+    spans were recorded over."""
+    with _lock:
+        return _dropped
 
 
 def live_spans() -> List[dict]:

@@ -51,3 +51,83 @@ def _seed_everything():
     paddle_tpu.seed(42)
     np.random.seed(42)
     yield
+
+
+
+# -- the dispatches of an engine run, for the tests of their span attrs ------
+
+ISSUE_MARKS = ("packed", "staged", "launched", "booked")
+
+
+class _Dispatches:
+    """What ``issue_phases`` hands a test."""
+    MARKS = ISSUE_MARKS
+
+    @staticmethod
+    def serve(eng, jobs, timeout=600):
+        """``jobs`` (``(prompt, max_new_tokens)`` pairs) through ``eng`` so
+        that its dispatches repeat run for run: a control op holds the
+        engine thread while every job is submitted, the loop then admits
+        them all in ONE iteration, and nothing outside feeds it again
+        (plain ``submit`` calls race the loop). Returns the outputs."""
+        import threading
+        gate = threading.Event()
+        held = eng._post_ctl(lambda: gate.wait(timeout))
+        try:
+            futs = [eng.submit(p, max_new_tokens=n) for p, n in jobs]
+        finally:
+            gate.set()
+        assert held.result(timeout)
+        return [f.result(timeout) for f in futs]
+
+    @staticmethod
+    def launched(spans):
+        """The issue phases that launched a program, in issue order."""
+        out = [s for s in spans if s["name"].startswith("llm.issue.")
+               and "issue_seq" in s["attrs"]]
+        return sorted(out, key=lambda s: s["attrs"]["issue_seq"])
+
+    @classmethod
+    def digest(cls, spans):
+        """Of every dispatch's name and attrs (``issue_seq``, ``live_rows``,
+        ``chunk_rows``, ``chunk_tokens``, ``ticks``, ``kv_pages_read``,
+        ``kv_pages_live``, ``state_rows``, ``state_bytes`` and, where the
+        model has them, ``kv_groups``, ``context_tokens``,
+        ``window_pages_released``, ``loop_steps``, ``kv_cache_layers``)."""
+        import hashlib
+        import json
+        rows = [[s["name"], s["attrs"]] for s in cls.launched(spans)]
+        text = json.dumps(rows, sort_keys=True, default=int)
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    @classmethod
+    def check_marks(cls, spans,
+                    kinds=("llm.issue.mixed", "llm.issue.decode")):
+        """Every dispatch carries the four marks once each, in order,
+        without attrs, between its phase's start and end."""
+        found = cls.launched(spans)
+        assert {s["name"] for s in found} == set(kinds)
+        for s in found:
+            marks = [e for e in s["events"] if e["name"] in ISSUE_MARKS]
+            assert [e["name"] for e in marks] == list(ISSUE_MARKS), s
+            assert all("attrs" not in e for e in marks)
+            times = [s["ts"]] + [e["ts"] for e in marks] \
+                + [s["ts"] + s["dur"]]
+            assert times == sorted(times)
+        # a phase that found nothing to launch carries no mark and no attr
+        for s in spans:
+            if s["name"].startswith("llm.issue.") and s not in found:
+                assert s["attrs"] == {} and s["events"] == []
+
+
+@pytest.fixture
+def issue_phases():
+    """Helpers for a test of the ``llm.issue.*`` phases: ``serve`` (a
+    repeatable run), ``launched``, ``digest``, ``check_marks``; tracing is
+    off and the table empty before and after."""
+    from paddle_tpu.observability import tracing
+    tracing.disable()
+    tracing.clear()
+    yield _Dispatches
+    tracing.disable()
+    tracing.clear()

@@ -456,3 +456,21 @@ def test_through_the_kernels_state_bytes_counts_the_sequences_present(
             (2 + 8) * per_row["conv_state"] + rows * per_row["ssm_state"])
     # two short prompts shared one chunk
     assert max(a["chunk_rows"] for a in mixed) == 2
+
+
+def test_the_issue_marks_leave_the_state_attrs_where_the_parent_wrote_them(
+        model, issue_phases):
+    """ISSUE 37: ``packed`` / ``staged`` / ``launched`` / ``booked`` on every
+    dispatch, and ``state_rows`` / ``state_bytes`` (stamped at the phase's
+    end now) equal to the same run's on the parent's ordering."""
+    from paddle_tpu.observability import tracing
+    net, _, _ = model
+    tracing.enable()
+    with LLMEngine(net, max_seqs=2, page_size=8, num_pages=32, max_len=64,
+                   prefill_chunk=16, kv_dtype="f32") as eng:
+        issue_phases.serve(eng, list(zip(prompts_of((20, 7, 5), seed=6),
+                                         (5, 4, 6))))
+    spans = tracing.finished_spans()
+    issue_phases.check_marks(spans)
+    assert any(s["attrs"]["state_bytes"] for s in issue_phases.launched(spans))
+    assert issue_phases.digest(spans) == "19499d95a16bde94"

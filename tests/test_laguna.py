@@ -248,6 +248,26 @@ def test_the_issue_phases_say_what_each_group_read_and_holds(model):
     assert sum(s["attrs"]["window_pages_released"] for s in spans) > 0
 
 
+def test_the_issue_marks_leave_the_groups_attrs_where_the_parent_wrote_them(
+        model, issue_phases):
+    """ISSUE 37: ``packed`` / ``staged`` / ``launched`` / ``booked`` on every
+    dispatch, and ``kv_groups`` with the sums over it (stamped at the
+    phase's end now, after the window's pages were released, as before)
+    equal to the same run's on the parent's ordering."""
+    from paddle_tpu.observability import tracing
+    net, _, _ = model
+    tracing.enable()
+    with LLMEngine(net, max_seqs=2, **ENGINE,
+                   attention_impl="pallas") as eng:
+        issue_phases.serve(eng, list(zip(prompts_of((70, 20, 9)),
+                                         (30, 8, 5))))
+    spans = tracing.finished_spans()
+    issue_phases.check_marks(spans)
+    launched = issue_phases.launched(spans)
+    assert sum(s["attrs"]["window_pages_released"] for s in launched) > 0
+    assert issue_phases.digest(spans) == "0afefd0483cde46f"
+
+
 # -- the rotary schemes against a direct transcription ----------------------
 
 def test_yarn_inverse_frequencies_are_the_formulas():

@@ -308,3 +308,21 @@ def test_the_loop_is_on_the_spans_the_status_page_and_the_metrics(model):
         and s["attrs"]["kv_cache_layers"] == 9 for s in issues)
     assert sum(s["attrs"]["loop_steps"] for s in drains) == 30
     assert sum(s["attrs"]["tokens"] for s in drains) == 10
+
+
+def test_the_issue_marks_leave_the_loops_attrs_where_the_parent_wrote_them(
+        model, issue_phases):
+    """ISSUE 37: ``packed`` / ``staged`` / ``launched`` / ``booked`` on every
+    dispatch, and ``loop_steps`` with the page counts (stamped at the
+    phase's end now) equal to the same run's on the parent's ordering."""
+    from paddle_tpu.observability import tracing
+    net, _, _ = model
+    tracing.enable()
+    with LLMEngine(net, max_seqs=2, **ENGINE) as eng:
+        issue_phases.serve(eng, list(zip(prompts_of((20, 7, 5), seed=6),
+                                         (5, 4, 6))))
+    spans = tracing.finished_spans()
+    issue_phases.check_marks(spans)
+    assert all(s["attrs"]["loop_steps"] == 3
+               for s in issue_phases.launched(spans))
+    assert issue_phases.digest(spans) == "792898c9ebf0d505"
