@@ -23,6 +23,28 @@ a TPU; it reads each held expert's weights once and none of an expert
 without rows).
 
 An expert is ``W_out (silu(a) * b)`` with ``[a | b] = W_in x``.
+
+The steps of ``forward``, and the scope each stands under in a trace:
+
+1. ``router``: scores, ``top_k``, gates; ``group [T, k]`` (a pair that is
+   not ours, or of an invalid row, takes the group behind every held
+   expert); the ONE sort (``order``: pairs by group, stable) and, with no
+   second sort and no scatter, its inverse ``pos [T, k]`` and the groups'
+   sizes (:func:`sorted_places`: cumulative sums of a one-hot).
+2. ``moe``: ``x``'s rows gathered into sorted order, the two grouped
+   products (kernel ``grouped_matmul`` on a TPU) around ``silu(a) * b``:
+   ``out [T*k, d]`` in the operands' dtype, sorted by expert, rows past
+   ``sum(rows_held)`` unspecified.
+3. ``moe/moe_combine`` (:func:`combine`): every row sums its own pairs
+   where the row is: ``y[t] = sum_j held[t, j] ? gates[t, j] *
+   float32(out[pos[t, j]]) : 0``: a gather of the row's ``k`` rows of
+   ``out`` and a float32 sum over them, cast to ``x``'s dtype once. Read
+   from the row's side because the same sum from the expert's side (a
+   gate-weighted float32 copy of all ``T x k`` sorted rows, ``segment_sum``
+   by row) is a scatter-add of ``d``-wide rows into computed addresses,
+   which does not stream on the TPU; tests/test_dropless_moe.py keeps that
+   form as the oracle: the same float32 products, added by expert there
+   and by rank here.
 """
 
 from __future__ import annotations
@@ -45,6 +67,43 @@ def route_top_k(x, router_weight, top_k: int):
                         precision=jax.lax.Precision.HIGHEST)
     top, idx = jax.lax.top_k(logits, top_k)
     return idx, jax.nn.softmax(top, axis=-1)
+
+
+def sorted_places(group, n_groups: int):
+    """``(pos [T, k], sizes [n_groups])``: the place of pair ``(t, j)`` in
+    the stable sort of ``group.reshape(-1)`` and every group's size, with no
+    second sort and no scatter: a pair stands behind every smaller group
+    (``offsets``), behind the pairs of its own group in the rows before its
+    own and behind those before it in its row. Cumulative sums of a one-hot
+    over ``[T, n_groups]`` and over the ``k`` pairs of a row."""
+    onehot = (group[..., None] == jnp.arange(n_groups, dtype=group.dtype)
+              ).astype(jnp.int32)                          # [T, k, n_groups]
+    in_row = jnp.sum(onehot, axis=1)                       # [T, n_groups]
+    upto_row = jnp.cumsum(in_row, axis=0)
+    sizes = upto_row[-1]
+    before = (jnp.cumsum(sizes) - sizes)[None, None, :] \
+        + (upto_row - in_row)[:, None, :] \
+        + jnp.cumsum(onehot, axis=1) - onehot
+    return jnp.sum(onehot * before, axis=-1), sizes
+
+
+def combine(out, pos, held, gates):
+    """``y [T, d]`` float32: row ``t``'s held pairs' rows of ``out [T*k,
+    d]`` (the second grouped product, sorted by expert; ``pos [T, k]`` says
+    where each pair's row lies) times their gates, summed over the row's
+    ``k`` pairs in float32. The mask stands in front of the product: a pair
+    that is not held names a row past ``sum(rows_held)``, which the grouped
+    product leaves unspecified, and ``0 * NaN`` must not reach ``y``.
+
+    Rank-major, ``[k, T, d]``, so the sum runs over the leading axis, and
+    the mask in ``out``'s dtype: in this form XLA:TPU makes the gather and
+    ONE fusion that converts, multiplies and adds; with the mask behind the
+    product it writes a float32 ``[T*k, d]`` between the two
+    (tests/test_chip_compile.py)."""
+    picked = out.at[pos.T].get(mode="promise_in_bounds",
+                               unique_indices=True)            # [k, T, d]
+    picked = jnp.where(held.T[..., None], picked, jnp.zeros((), out.dtype))
+    return jnp.sum(picked.astype(jnp.float32) * gates.T[..., None], axis=0)
 
 
 class DroplessMoE(Layer):
@@ -84,7 +143,6 @@ class DroplessMoE(Layer):
         if impl not in ("xla", "pallas"):
             raise ValueError(f"unknown impl {impl!r}")
         product = grouped_matmul if impl == "pallas" else jax.lax.ragged_dot
-        t, _ = x.shape
         k, count = self.top_k, self.count
         with jax.named_scope("router"):
             idx, gates = route_top_k(x, self.router, k)
@@ -95,19 +153,16 @@ class DroplessMoE(Layer):
             if valid is not None:
                 held = held & valid[:, None]
             # a pair that is not ours sorts behind every group
-            group = jnp.where(held, local, count).reshape(-1)      # [T*k]
-            order = jnp.argsort(group, stable=True)
+            group = jnp.where(held, local, count)                  # [T, k]
+            order = jnp.argsort(group.reshape(-1), stable=True)
             rows = order // k
-            sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)
+            pos, sizes = sorted_places(group, count + 1)
             rows_held = sizes[:count]
         with jax.named_scope("moe"):
             xs = jnp.take(x, rows, axis=0)                         # [T*k, d]
             h = product(xs, self.w_in, rows_held)
             a, b = jnp.split(h, 2, axis=-1)
             out = product(jax.nn.silu(a) * b, self.w_out, rows_held)
-            g = jnp.where(held, gates, 0.0).reshape(-1)[order]
-            ours = jnp.arange(t * k) < jnp.sum(rows_held)
-            out = jnp.where(ours[:, None],
-                            out.astype(jnp.float32) * g[:, None], 0.0)
-            y = jax.ops.segment_sum(out, rows, num_segments=t)
+            with jax.named_scope("moe_combine"):
+                y = combine(out, pos, held, gates)
         return y.astype(x.dtype), rows_held

@@ -57,6 +57,7 @@ docs/OBSERVABILITY.md "Perf surfaces".
 
 from __future__ import annotations
 
+import collections
 import itertools
 import threading
 import time
@@ -184,11 +185,18 @@ def next_scope() -> str:
     return f"s{next(_scope_counter)}"
 
 
+# scopes whose owner the garbage collector has finalized, waiting for the
+# registry to drop them (as ``memory._finalized``): a finalizer runs wherever
+# an allocation happened to trigger a collection, possibly on a thread that
+# already holds the registry's lock (``register_program`` builds its handle
+# under it), and the lock is not reentrant. So the finalizer takes no lock:
+# it appends here (atomic), and the process-wide registry removes the scopes
+# at its next registration or listing.
+_finalized: collections.deque = collections.deque()
+
+
 def _cleanup_scope(scope: str) -> None:
-    try:
-        instance().remove_scope(scope)
-    except Exception:  # noqa: BLE001 — interpreter-shutdown tolerance
-        pass
+    _finalized.append(scope)
 
 
 def finalize_scope(owner, scope: str):
@@ -343,6 +351,7 @@ class PerfRegistry:
         costs; each owner passes a stable per-instance token so its
         flops are never read off a sibling's cache entry."""
         key = (component, kind, scope) + tuple(sig)
+        self._drop_finalized()
         with self._mu:
             h = self._programs.get(key)
             if h is not None:
@@ -359,6 +368,16 @@ class PerfRegistry:
             # lands in the "compile" phase, never in MFU busy time
             self._resolve(h)
         return h
+
+    def _drop_finalized(self) -> None:
+        """Remove the scopes whose owners were collected since the last
+        call; the process-wide registry only (a private one has no
+        finalizers)."""
+        while _finalized and self is _instance:
+            try:
+                self.remove_scope(_finalized.popleft())
+            except IndexError:      # another thread took the last one
+                break
 
     def remove_scope(self, scope: str) -> int:
         """Drop every program registered under ``scope`` — called by
@@ -544,6 +563,7 @@ class PerfRegistry:
         return out
 
     def programs(self) -> List[ProgramHandle]:
+        self._drop_finalized()
         with self._mu:
             return list(self._programs.values())
 

@@ -359,6 +359,61 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, weights, pairs):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+# a mixed tick's routed layer in the two cells that run one: rows, hidden,
+# expert width, experts scored, experts held (top 10 in both)
+@pytest.mark.parametrize("rows,hidden,width,experts,held", [
+    (320, 4096, 768, 72, 36), (288, 3072, 1024, 256, 32)],
+    ids=["hybrid-mixed", "window-mixed"])
+def test_routed_layer_keeps_no_float32_copy_of_its_pairs(
+        one_chip, monkeypatch, rows, hidden, width, experts, held):
+    """``DroplessMoE.forward`` on the kernel path, compiled for the
+    described chip at the cells' shapes: between the second grouped product
+    and ``y`` stand ONE gather (a row's ten pairs' rows, bf16) and one
+    fusion that converts, weighs and adds them: no operation of the program
+    writes a float32 array of the ``T x k`` pairs' size and nothing
+    scatters (before PR 38: a float32 ``[T*k, d]`` under a mask and a
+    scatter-add of it by row, and a scatter-add of ones for the groups'
+    sizes)."""
+    import importlib
+    import math
+    import re
+    from paddle_tpu.nn import DroplessMoE
+
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    k = 10
+    layer = DroplessMoE(8, 8, experts, k, (0, held))
+
+    def forward(x, router, w_in, w_out):
+        layer.router, layer.w_in, layer.w_out = router, w_in, w_out
+        return layer(x, None, "pallas")
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compiled(forward, sds((rows, hidden)),
+                     sds((hidden, experts), jnp.float32),
+                     sds((held, hidden, 2 * width)),
+                     sds((held, width, hidden))).as_text()
+    entry = text[text.index("ENTRY"):]
+    made = [ln.split(" = ", 1)[1] for ln in entry.splitlines()
+            if " = " in ln]
+    assert sum(" custom-call(" in op and "grouped_matmul" in op
+               for op in made) == 2
+    assert not any("scatter" in op for op in made)
+    pairs = rows * k
+    wide = re.compile(r"^\(?f32\[(\d+(?:,\d+)*)\]")
+    for op in made:
+        m = wide.match(op)
+        if m:
+            size = math.prod(int(n) for n in m.group(1).split(","))
+            assert size < pairs * hidden, op[:160]
+    combine = [op for op in made if "moe/moe_combine/" in op
+               and re.match(rf"\w+\[(\d+,)+{hidden}\]", op)]
+    assert len(combine) == 2, combine          # the gather and the fusion
+
+
 @pytest.fixture(scope="module")
 def hybrid_programs(one_chip):
     """The engine's own ``decode`` and ``mixed`` programs of a hybrid model

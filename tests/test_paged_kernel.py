@@ -596,3 +596,63 @@ def test_a_call_without_prompt_rows_is_the_program_of_pr_35(
             *a[:5], impl="pallas", layer=2, starts=a[5] if bound else None,
             n_chunk=0), queries(5, heads), k, v, tables_for(5), lens, lens)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def _engine_program_texts(net, slots=4, chunk=8):
+    """The jaxpr text of an engine's decode and mixed programs at a tiny
+    size, addresses blanked."""
+    import re
+    from paddle_tpu.inference import llm
+    eng = llm.LLMEngine(net, max_seqs=slots, page_size=4, num_pages=33,
+                        max_len=32, prefill_chunk=chunk)
+    try:
+        ints = np.zeros((slots,), np.int32)
+        decode = jax.make_jaxpr(eng._decode_fn)(
+            eng._params, eng._buffers, eng._tokens_dev, ints,
+            eng.block_tables, ints, eng.k_pages, eng.v_pages,
+            eng.temperatures, eng._nonces, eng._key, *eng._state_args())
+        rows = np.zeros((1, chunk), np.int32)
+        per_slot = np.zeros((1, slots), np.int32)
+        xs = {"tok": rows, "pos": rows, "lim": rows,
+              "tbl": np.zeros((1, chunk, eng.pages_per_seq), np.int32),
+              "fin": per_slot.astype(bool), "row": per_slot,
+              "fpos": per_slot, "grant": per_slot}
+        mixed = jax.make_jaxpr(eng._mixed_fn, static_argnums=8)(
+            eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+            eng.block_tables, eng.temperatures, eng._nonces, eng._key, 1)
+    finally:
+        eng.close()
+    return {name: re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+            for name, jaxpr in (("decode", decode), ("mixed", mixed))}
+
+
+@pytest.mark.parametrize("model,program,digest", [
+    ("gpt", "decode", "95ac620de13a804d"), ("gpt", "mixed", "95bc8fdf50841c06"),
+    ("ouro", "decode", "1095c805cff6e079"),
+    ("ouro", "mixed", "6b661ad679cb8065")])
+def test_a_model_without_routed_experts_traces_to_the_program_of_pr_37(
+        model, program, digest):
+    """PR 38 rewrote the routed layer's combine (``nn/layers/
+    dropless_moe.py``). ``models/gpt.py`` and ``models/ouro.py`` hold no
+    routed layer: their engine programs trace, to the letter, to what the
+    commit before traced (digests taken from a checkout of it). A later edit
+    to the engine's programs or to these models moves them on purpose and
+    names itself here."""
+    import hashlib
+    import paddle_tpu as pt
+    from paddle_tpu.models import (GPTConfig, GPTForCausalLM, OuroConfig,
+                                   OuroForCausalLM)
+    pt.seed(0)
+    if model == "gpt":
+        net = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+            max_position_embeddings=32))
+    else:
+        net = OuroForCausalLM(OuroConfig(
+            num_hidden_layers=2, vocab_size=64, hidden_size=32,
+            num_attention_heads=2, num_key_value_heads=2,
+            intermediate_size=64, max_position_embeddings=32))
+    net.eval()
+    text = _engine_program_texts(net)[program]
+    assert "moe" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

@@ -279,6 +279,53 @@ def test_discarded_model_releases_registry_entries():
         "collected Model left perf-registry entries behind"
 
 
+def test_finalizer_inside_a_locked_registration_does_not_deadlock():
+    """``register_program`` builds its handle under the registry's
+    (non-reentrant) lock; an allocation there can trigger a collection, and
+    a finalizer that took the lock again hung a whole tier-1 run (PR 38,
+    ``test_program_cap_discipline`` after an engine's owner had died in a
+    cycle). The finalizer takes no lock: the scope goes at the next
+    registration or listing."""
+    import gc
+    import threading
+
+    class Owner:
+        pass
+
+    class CollectingLock:
+        """The registry's own lock, forcing a collection inside every
+        section it guards: what an unlucky allocation does, made certain."""
+
+        def __init__(self, inner):
+            self.inner = inner
+
+        def __enter__(self):
+            self.inner.acquire()
+            gc.collect()
+
+        def __exit__(self, *exc):
+            self.inner.release()
+
+    reg = perf.instance()
+    owner, dead = Owner(), perf.next_scope()
+    assert reg.register_program("llm", "decode_step", scope=dead) is not None
+    perf.finalize_scope(owner, dead)
+    gc.disable()
+    try:
+        owner.me = owner            # a cycle: only the collector frees it
+        del owner
+        reg._mu = CollectingLock(reg._mu)
+        t = threading.Thread(
+            target=lambda: reg.register_program("llm", "decode_step",
+                                                scope="live"), daemon=True)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive(), "finalizer deadlocked on the registry lock"
+    finally:
+        gc.enable()
+    assert {h.scope for h in reg.programs()} == {"live"}
+
+
 def test_prepare_resets_perf_programs():
     """Re-prepare rebuilds the compiled step (different optimizer →
     different FLOPs): the new program must not accumulate under the
