@@ -59,7 +59,7 @@ from ..ops.paged_attention import (KV_DTYPES, QuantizedKV, _split_kv,
                                    kv_nbytes, kv_page_size,
                                    kv_scale_nbytes, kv_zeros)
 from ..reliability import faults as _faults
-from .page_pool import ChunkRows, PagePool
+from .page_pool import ChunkRows, PagePool, cache_groups
 from ..reliability.retry import Deadline, DeadlineExceeded, as_deadline
 
 # How every engine program is compiled for a TPU. XLA:TPU's memory-space
@@ -568,7 +568,8 @@ class RaggedRows(NamedTuple):
 class CacheView(NamedTuple):
     """What the ENGINE owns and hands the model's forward: the stacked
     paged K/V pool (a tuple of pools, one a cache group, for a model whose
-    ``kv_cache_spec()`` is a list: ``page_pool.py``) and, for a model with
+    ``kv_cache_spec()`` is a list: ``page_pool.py``; a LATENT group's entry
+    of ``v_pages`` is None: it keeps one array and no V) and, for a model with
     recurrent state, one
     fixed-size ``conv_state`` / ``ssm_state`` row a slot (a tuple, one
     ``[max_seqs + 1, ...]`` array a state-space layer: row ``max_seqs``
@@ -591,17 +592,21 @@ def _all_on_tpu(arrays) -> bool:
     return all(d.platform == "tpu" for a in arrays for d in a.devices())
 
 
-def _state_impl(ssm_state) -> str:
+def _state_impl(ssm_state, impls=("xla", "pallas")) -> str:
     """How an engine's programs advance the recurrent state of their
-    rows, by the platform of the state's device alone: ``"pallas"``
-    (``ops/ssd.py ssd_step_kernel`` for the decode rows and
+    rows, by the platform of the state's device and by the
+    implementations the model's recurrence has (``impls``:
+    ``state_cache_spec()["impls"]``, both where it names none):
+    ``"pallas"`` (``ops/ssd.py ssd_step_kernel`` for the decode rows and
     ``ssd_chunk_kernel`` for a chunk of prompt rows: the layer's whole
     state array in place, the rows of the sequences at work only) on a
     TPU, ``"xla"`` (``ssd_step`` over the slots' rows, ``ssd_chunked``
     over the gathered rows of as many sequences as a chunk may hold)
-    anywhere else and for a model without state."""
+    anywhere else, for a model without state and for a recurrence
+    without a kernel (a delta rule exists in plain ``jax.numpy`` alone:
+    ``"xla"`` on every platform)."""
     on_tpu = ssm_state is not None and _all_on_tpu(ssm_state)
-    return "pallas" if on_tpu else "xla"
+    return "pallas" if on_tpu and "pallas" in impls else "xla"
 
 
 def _moe_impl(net) -> str:
@@ -636,10 +641,21 @@ class CacheGroupUnsupported(ValueError):
     ``"kv_page_migration"`` (the ``kv_pages/v1`` payload is one group's),
     ``"fused_slab"`` and ``"lookahead"`` (pages are released between
     ticks, by the host); ``"prefix_reuse"`` is switched off instead
-    (``/statusz``): a page keyed by its tokens may be gone."""
+    (``/statusz``): a page keyed by its tokens may be gone.
+
+    Or a mode that assumes a page holds K AND V rows of ``kv_heads x
+    head_dim`` was asked of a model with a LATENT cache group (one row a
+    token, no V: ``CacheGroup.value_dim``): ``"int8_pages"`` (the scale
+    a token is reckoned over K/V heads), ``"kv_page_migration"`` (the
+    payload's geometry is a K and a V block a page),
+    ``"speculative_verify"`` (the verify window attends through the
+    K/V form of the gathered path); ``"prefix_reuse"`` is switched off
+    (``/statusz``)."""
 
     WINDOW_MODES = ("prefix_reuse", "kv_page_migration",
                     "speculative_verify", "fused_slab", "lookahead")
+    LATENT_MODES = ("prefix_reuse", "kv_page_migration",
+                    "speculative_verify", "int8_pages")
 
     def __init__(self, mechanism: str, msg: str):
         super().__init__(msg)
@@ -1078,6 +1094,7 @@ def _engine_status_provider(ref):
                 "rows": eng.max_seqs + 1,
                 "rows_in_use": live,
                 "row_bytes": dict(eng._state_row_bytes),
+                "state_impl": eng.state_impl,
                 "unsupported": ["speculative_verify",
                                 "kv_page_migration"]}
             out["prefix_cache"] = {
@@ -1092,6 +1109,13 @@ def _engine_status_provider(ref):
                 "enabled": False,
                 "reason": "a window cache group: a page keyed by its "
                           "tokens may have been freed behind the window"}
+        if eng._pool.latent:
+            out["cache_groups_unsupported"] = list(
+                CacheGroupUnsupported.LATENT_MODES)
+            out.setdefault("prefix_cache", {
+                "enabled": False,
+                "reason": "a latent cache group: the prefix cache keys "
+                          "and shares pages of K and V rows"})
         if eng._moe_spec is not None:
             out["moe"] = {
                 "moe_impl": eng.moe_impl,
@@ -1307,6 +1331,23 @@ class LLMEngine:
         # the paged K/V pool: one group of cache layers a page shape and
         # lifetime (page_pool.py); block tables and free lists are host
         # control plane, mutated by the allocator there
+        if any(g.value_dim is not None
+               for g in cache_groups(net.kv_cache_spec())):
+            # a latent group's page is one row a token, no V: what assumes
+            # K and V rows of kv_heads x head_dim is refused by name, or
+            # switched off (/statusz)
+            for mechanism, asked, why in (
+                    ("int8_pages", kv_dtype == "int8",
+                     "the scale a token is reckoned over K/V heads"),
+                    ("speculative_verify", draft_net is not None,
+                     "the verify window attends K and V pages")):
+                if asked:
+                    raise CacheGroupUnsupported(
+                        mechanism,
+                        f"{mechanism} does not compose with a model that "
+                        f"has a latent cache group (one row a token, no "
+                        f"V): {why}")
+            prefix_cache = False
         self._pool = PagePool(net.kv_cache_spec(), num_pages, page_size,
                               max_seqs, self.pages_per_seq, kv_dtype,
                               self.prefill_chunk)
@@ -1391,7 +1432,8 @@ class LLMEngine:
                 // n_rows,
                 "ssm_state": sum(a.nbytes for a in self.ssm_state)
                 // n_rows}
-        self.state_impl = _state_impl(self.ssm_state)
+        self.state_impl = _state_impl(
+            self.ssm_state, (spec or {}).get("impls", ("xla", "pallas")))
         self._slots: List[Optional[_Request]] = [None] * max_seqs
         # device-chained last tokens (authoritative between fetches)
         self._tokens_dev = jnp.zeros((max_seqs,), jnp.int32)
@@ -2208,6 +2250,12 @@ class LLMEngine:
                 f"{what} does not compose with a model that has a window "
                 f"cache group: the kv_pages/v1 payload carries one "
                 f"group's pages, and a window group's are not all there")
+        if self._pool.latent:
+            raise CacheGroupUnsupported(
+                "kv_page_migration",
+                f"{what} does not compose with a model that has a latent "
+                f"cache group: the kv_pages/v1 payload is a K and a V "
+                f"block a page, and a latent page has one block and no V")
 
     def import_pages(self, payload: dict, timeout: float = 60.0) -> dict:
         """Verify and install a ``kv_pages/v1`` payload as shared,
@@ -3500,8 +3548,8 @@ class LLMEngine:
                     sum(g["read"] for g in groups.values())) \
             .set_attr("kv_pages_live",
                       sum(g["live"] for g in groups.values()))
-        if len(groups) > 1:
-            # a pool of several cache groups: the sums above, by group
+        if not self._pool.bare:
+            # a pool of named cache groups: the sums above, by group
             # (a group's page has its own bytes), what the live slots
             # hold and how long their contexts are
             slots = [i for i, r in enumerate(self._slots) if r is not None]

@@ -6,7 +6,11 @@ mixed window and full attention), or a list of :class:`CacheGroup`. A group
 is a set of cache layers that share a page shape AND a page lifetime: its
 own stacked ``[layers, pages, page_size, kv_heads, head_dim]`` K and V
 arrays, its own free list, its own block table ``[slots, pages_per_seq]``
-(page 0 = the scratch page / "not allocated"). A group WITH A WINDOW keeps,
+(page 0 = the scratch page / "not allocated"). A LATENT group
+(``CacheGroup.value_dim``) keeps ONE array and no V: a token's row
+``[head_dim]`` is the key of every query head and its first ``value_dim``
+columns are the value, so its pages are ``[layers, pages, page_size,
+head_dim]`` and ``v_pages`` is None. A group WITH A WINDOW keeps,
 a sequence, only the pages a row can still attend: once a page lies wholly
 behind ``next position - window`` it goes back to the free list
 (:meth:`PagePool.release_behind`), so a slot never holds more than
@@ -39,13 +43,19 @@ from ..ops.paged_attention import (QuantizedKV, chunk_tile_rows,
 class CacheGroup(NamedTuple):
     """One entry of a model's ``kv_cache_spec()`` list. ``window``: a row
     at position ``p`` attends positions ``j`` with ``p - j < window`` only
-    (None: the whole sequence)."""
+    (None: the whole sequence). ``value_dim`` (None: a group of K and V
+    pages, ``kv_heads x head_dim`` each a token): a group WITHOUT A V and
+    without a head axis. A token's cached row is ``head_dim`` values wide
+    (as stored, padding included), every query head attends the same row,
+    and the value is the row's first ``value_dim`` columns: what a latent
+    (compressed) attention caches. ``kv_heads`` is then 1."""
 
     name: str
     layers: int
     kv_heads: int
     head_dim: int
     window: Optional[int] = None
+    value_dim: Optional[int] = None
 
 
 class ChunkRows(NamedTuple):
@@ -87,6 +97,7 @@ class GroupPool:
                  max_seqs: int, pages_per_seq: int, kv_dtype,
                  prefill_chunk: int):
         self.group = group
+        self.page_size = page_size
         self.name, self.window = group.name, group.window
         self.ring: Optional[int] = None
         if group.window is not None:
@@ -96,13 +107,22 @@ class GroupPool:
                                              // page_size) + 1)
             num_pages = min(num_pages, max_seqs * self.ring + 1)
         self.num_pages = num_pages
-        self.k_pages = kv_zeros((group.layers, num_pages, page_size,
-                                 group.kv_heads, group.head_dim), kv_dtype)
-        self.v_pages = jax.tree_util.tree_map(jnp.zeros_like, self.k_pages)
-        self.page_bytes = (kv_nbytes(self.k_pages)
-                           + kv_nbytes(self.v_pages)) // num_pages
-        self.scale_bytes = (kv_scale_nbytes(self.k_pages)
-                            + kv_scale_nbytes(self.v_pages)) // num_pages
+        self.latent = group.value_dim is not None
+        # a latent group: one array, no head axis, no V
+        heads = () if self.latent else (group.kv_heads,)
+        self.k_pages = kv_zeros((group.layers, num_pages, page_size)
+                                + heads + (group.head_dim,), kv_dtype)
+        if self.latent and (group.kv_heads != 1
+                            or isinstance(self.k_pages, QuantizedKV)):
+            raise ValueError(
+                f"cache group {group.name!r}: a group without a V has "
+                f"kv_heads 1 and no int8 form (a scale a row of "
+                f"kv_heads x head_dim is not a scale a latent row)")
+        self.v_pages = None if self.latent else jax.tree_util.tree_map(
+            jnp.zeros_like, self.k_pages)
+        stores = [self.k_pages] + ([] if self.latent else [self.v_pages])
+        self.page_bytes = sum(map(kv_nbytes, stores)) // num_pages
+        self.scale_bytes = sum(map(kv_scale_nbytes, stores)) // num_pages
         self.free: List[int] = list(range(num_pages - 1, 0, -1))
         self.tables = np.zeros((max_seqs, pages_per_seq), np.int32)
         self.held = np.zeros((max_seqs,), np.int64)
@@ -118,7 +138,9 @@ class GroupPool:
 
     def status(self) -> dict:
         return {"name": self.name, "layers": self.group.layers,
-                "window": self.window, "page_bytes": self.page_bytes,
+                "window": self.window, "value_dim": self.group.value_dim,
+                "row_bytes": self.page_bytes // self.page_size,
+                "page_bytes": self.page_bytes,
                 "pages": self.num_pages, "in_use": self.in_use,
                 "ring_pages": self.ring, "released": self.n_released}
 
@@ -132,22 +154,23 @@ class PagePool:
     def __init__(self, spec, num_pages: int, page_size: int, max_seqs: int,
                  pages_per_seq: int, kv_dtype, prefill_chunk: int):
         groups = cache_groups(spec)
-        self._bare = is_bare(spec)
+        self.bare = is_bare(spec)
         self.page_size, self.pages_per_seq = page_size, pages_per_seq
         self.groups = [GroupPool(g, num_pages, page_size, max_seqs,
                                  pages_per_seq, kv_dtype, prefill_chunk)
                        for g in groups]
         self.windowed = any(g.window is not None for g in self.groups)
+        self.latent = any(g.latent for g in self.groups)
         # the engine's prefix cache, over the pages of the one group of a
         # model without a window; None otherwise
         self.prefix_cache = None
 
     # -- the arrays, in the form of the spec ------------------------------
     def _form(self, per_group: Sequence[Any]):
-        return per_group[0] if self._bare else tuple(per_group)
+        return per_group[0] if self.bare else tuple(per_group)
 
     def _set(self, attr: str, value) -> None:
-        for g, v in zip(self.groups, [value] if self._bare else value):
+        for g, v in zip(self.groups, [value] if self.bare else value):
             setattr(g, attr, v)
 
     @property

@@ -9,6 +9,7 @@ from .gpt import (GPTConfig, GPTForCausalLM, GPTModel,  # noqa
 from .granite_hybrid import (GraniteHybridConfig,  # noqa
                              GraniteHybridForCausalLM)
 from .laguna import LagunaConfig, LagunaForCausalLM  # noqa
+from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa
 from .lenet import LeNet  # noqa
 from .ouro import OuroConfig, OuroForCausalLM  # noqa
 from .mobilenet import (MobileNetV1, MobileNetV2,  # noqa

@@ -358,7 +358,10 @@ class GraniteHybridForCausalLM(Layer):
 
     # -- the engine's forward over ragged rows ---------------------------
     def kv_cache_spec(self):
-        """``(layers, kv_heads, head_dim)`` of the paged K/V pool."""
+        """``(layers, kv_heads, head_dim)`` of the paged K/V pool: a bare
+        triple, one cache group of K and V pages
+        (``inference/page_pool.py``; a group without a V is a
+        ``CacheGroup`` with ``value_dim``)."""
         cfg = self.cfg
         return len(cfg.kv_layers), cfg.num_kv_heads, cfg.head_dim
 

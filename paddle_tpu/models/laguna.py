@@ -350,7 +350,9 @@ class LagunaForCausalLM(Layer):
     def kv_cache_spec(self):
         """A LIST of cache groups (``inference/page_pool.py``): the full
         layers' K/V, kept as long as the sequence, and the sliding
-        layers', kept for ``sliding_window`` positions."""
+        layers', kept for ``sliding_window`` positions. Both hold K AND
+        V pages (``value_dim`` None; a group without a V names the columns
+        of its one row that are the value)."""
         return list(self._groups)
 
     def state_cache_spec(self):

@@ -59,14 +59,23 @@ from .. import initializer as I
 from ..layer import Layer
 
 
-def route_top_k(x, router_weight, top_k: int):
-    """``(expert ids [T, k], gates [T, k] float32)``: the router product and
-    the softmax over the chosen scores in float32."""
+def route_top_k(x, router_weight, top_k: int, select_bias=None):
+    """``(expert ids [T, k], gates [T, k] float32)``: the router product in
+    float32 and, without ``select_bias``, the softmax over the chosen
+    scores. With ``select_bias`` [num_experts] the SIGMOID form: scores
+    ``s = sigmoid(logits)``, chosen the ``top_k`` largest of ``s +
+    select_bias`` (the bias balances load and is no part of a gate), gates
+    ``s`` of the chosen divided by their sum."""
     logits = jnp.matmul(x.astype(jnp.float32),
                         router_weight.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    top, idx = jax.lax.top_k(logits, top_k)
-    return idx, jax.nn.softmax(top, axis=-1)
+    if select_bias is None:
+        top, idx = jax.lax.top_k(logits, top_k)
+        return idx, jax.nn.softmax(top, axis=-1)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32), top_k)
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, top / jnp.sum(top, axis=-1, keepdims=True)
 
 
 def sorted_places(group, n_groups: int):
@@ -111,14 +120,20 @@ class DroplessMoE(Layer):
     ``(y [T, d], rows_held [count] int32)``; ``rows_held[e]`` is how many
     valid rows expert ``first + e`` received. ``impl`` names the grouped
     product: ``"xla"`` or ``"pallas"``. ``routed_scaling_factor``
-    multiplies every gate (a model's ``moe_routed_scaling_factor``)."""
+    multiplies every gate (a model's ``moe_routed_scaling_factor``).
+    ``scoring``: ``"softmax"`` (over the chosen scores) or ``"sigmoid"``
+    (:func:`route_top_k`'s second form; the layer then holds ``e_bias``
+    [num_experts], float32, the selection bias)."""
 
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int,
                  experts_held: Optional[Tuple[int, int]] = None,
                  initializer_range: float = 0.02,
-                 routed_scaling_factor: float = 1.0):
+                 routed_scaling_factor: float = 1.0,
+                 scoring: str = "softmax"):
         super().__init__()
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {scoring!r}")
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1
                 and first + count <= num_experts):
@@ -134,6 +149,11 @@ class DroplessMoE(Layer):
         init = I.Normal(0.0, initializer_range)
         self.router = self.create_parameter([d_model, num_experts],
                                             initializer=init)
+        self.e_bias = None
+        if scoring == "sigmoid":
+            self.e_bias = self.create_parameter(
+                [num_experts], dtype="float32",
+                initializer=I.Normal(0.0, 0.01))
         self.w_in = self.create_parameter(
             [count, d_model, 2 * d_expert], initializer=init)
         self.w_out = self.create_parameter(
@@ -145,7 +165,7 @@ class DroplessMoE(Layer):
         product = grouped_matmul if impl == "pallas" else jax.lax.ragged_dot
         k, count = self.top_k, self.count
         with jax.named_scope("router"):
-            idx, gates = route_top_k(x, self.router, k)
+            idx, gates = route_top_k(x, self.router, k, self.e_bias)
             if self.routed_scaling_factor != 1.0:
                 gates = gates * self.routed_scaling_factor
             local = idx - self.first

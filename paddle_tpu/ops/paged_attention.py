@@ -145,7 +145,9 @@ def kv_write(store: KVStore, layer, page_idx, offs, rows) -> KVStore:
     lands beside the page row). ``rows`` [..., kv_heads, head_dim]
     with ``page_idx``/``offs`` broadcast over the leading dims —
     exactly the ``.at[i, page_idx, offs].set`` contract the engine's
-    layers already use, made dtype-aware in ONE place."""
+    layers already use, made dtype-aware in ONE place. A latent pool
+    (``[L, num_pages, page_size, width]``, no head axis) takes ``rows``
+    [..., width] through the same line, once: it has no V to write."""
     with jax.named_scope("kv_write"):
         if isinstance(store, QuantizedKV):
             q, s = quantize_kv(rows)
@@ -233,35 +235,50 @@ _PAGE_BUFFER_BYTES = 4 * 1024 * 1024
 _GROUP_TOKENS = 64
 # query heads a K/V head up to which the fold stays on the VPU
 _VPU_GROUP_ROWS = 4
+# tokens of a latent pool folded at a time: one MXU product a group
+_LATENT_GROUP_TOKENS = 256
+# what the query tiles over a latent pool may hold in VMEM: a tile's query,
+# result and sums are ``32 rows x heads`` rows of the latent width
+_LATENT_TILE_VMEM_BYTES = 64 << 20
 
 
-def _pages_per_block(page_size: int, kv_heads: int, d: int, dtype,
-                     pages_per_seq: int) -> int:
-    """Pages the kernel moves a step: as many as the four page buffers
-    (K and V, double-buffered) hold in ``_PAGE_BUFFER_BYTES``, a page
-    counted at its tiled size in VMEM (kv heads padded to the dtype's
-    sublane packing, d to the lane width). Follows the shapes: 16 pages
-    of 16 tokens for a bf16 pool with 16 heads of 128."""
+def _pages_per_block(page_size: int, kv_heads: Optional[int], d: int, dtype,
+                     pages_per_seq: int, pools: int = 2) -> int:
+    """Pages the kernel moves a step: as many as the page buffers (one a
+    pool the walk reads, ``pools``: K and V, or the one pool of a latent
+    group; each double-buffered) hold in ``_PAGE_BUFFER_BYTES``, a page
+    counted at its tiled size in VMEM as it is STORED (kv heads padded to
+    the dtype's sublane packing, d to the lane width; ``kv_heads`` None: a
+    page without a head axis, ``[page_size, d]``, its tokens on the
+    sublanes). Follows the shapes: 16 pages of 16 tokens for a bf16 pool
+    with 16 heads of 128."""
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * max(1, 4 // itemsize)
-    page_bytes = (page_size * -(-kv_heads // sublanes) * sublanes
-                  * -(-d // _LANES) * _LANES * itemsize)
+    lanes = -(-d // _LANES) * _LANES
+    if kv_heads is None:
+        page_bytes = -(-page_size // sublanes) * sublanes * lanes * itemsize
+    else:
+        page_bytes = (page_size * -(-kv_heads // sublanes) * sublanes
+                      * lanes * itemsize)
     return max(1, min(pages_per_seq,
-                      _PAGE_BUFFER_BYTES // (4 * page_bytes)))
+                      _PAGE_BUFFER_BYTES // (2 * pools * page_bytes)))
 
 
 def _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf, sems):
     """Both walks' page traffic: ``copies(row, first_page, n, slot, do)``
     does ``do`` (start or wait) to the K and the V copy of the ``n`` pages
     from column ``first_page`` of ``row``'s table, out of layer
-    ``meta_ref[0]`` of the stacked pool into buffer slot ``slot``."""
+    ``meta_ref[0]`` of the stacked pool into buffer slot ``slot``. A latent
+    group has ONE pool (``v_hbm`` None): its page is copied once and read
+    for the scores and for the values."""
     def copies(row, first_page, n, slot, do):
         def page(p, carry):
             src = (meta_ref[0], tbl_ref[row, first_page + p])
             do(pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, p],
                                      sems.at[0, slot]))
-            do(pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, p],
-                                     sems.at[1, slot]))
+            if v_hbm is not None:
+                do(pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, p],
+                                         sems.at[1, slot]))
             return carry
 
         jax.lax.fori_loop(0, n, page, 0)
@@ -274,7 +291,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            k_scales=None, v_scales=None, starts=None,
-                           n_chunk: int = 0):
+                           n_chunk: int = 0,
+                           value_dim: Optional[int] = None):
     """Fused Pallas attention over the paged KV pool (Ragged-Paged-
     Attention lineage): every row of ``q`` attends the first
     ``context_lens[row]`` cached positions of the sequence whose block
@@ -340,20 +358,43 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     An int8 pool keeps the row walk for all its rows (its scale rows ride
     in SMEM a row; no cell runs one).
 
+    A LATENT pool (``v_pages`` None, ``value_dim`` given): ONE stacked
+    store ``[L, num_pages, page_size, width]`` whose row is a token's key
+    for every query head and whose first ``value_dim`` columns are its
+    value (``page_pool.CacheGroup.value_dim``). ``q`` is ``[rows, heads,
+    width]``, the result ``[rows, heads, value_dim]``. Both walks copy a
+    page ONCE into one buffer and read it for both products, on the MXU
+    (every query head against the rows as they lie: tokens on sublanes,
+    no head axis to pad).
+
     ``interpret`` defaults to the module switch
     ``flash_attention.INTERPRET`` (False: the kernel compiles for the
     TPU or raises).
     """
     if interpret is None:
         interpret = _default_interpret()
-    if k_pages.ndim == 4:
-        k_pages, v_pages = k_pages[None], v_pages[None]
+    latent = v_pages is None
+    if latent and (value_dim is None or k_scales is not None):
+        raise ValueError("a pool without V pages is a latent pool: it "
+                         "needs value_dim and has no int8 form")
+    if k_pages.ndim == (3 if latent else 4):
+        k_pages = k_pages[None]
+        v_pages = None if latent else v_pages[None]
         if k_scales is not None:
             k_scales, v_scales = k_scales[None], v_scales[None]
         layer = 0
-    page_size, kv_heads, d = k_pages.shape[2:]
-    block = _pages_per_block(page_size, kv_heads, d, k_pages.dtype,
-                             block_tables.shape[1])
+    if latent:
+        page_size, d = k_pages.shape[2:]
+        block = _pages_per_block(page_size, None, d, k_pages.dtype,
+                                 block_tables.shape[1], 1)
+        # whole groups of _LATENT_GROUP_TOKENS are folded, never a rest
+        fold = max(1, min(block, _LATENT_GROUP_TOKENS // page_size))
+        block = block // fold * fold
+    else:
+        page_size, kv_heads, d = k_pages.shape[2:]
+        block = _pages_per_block(page_size, kv_heads, d, k_pages.dtype,
+                                 block_tables.shape[1])
+        fold = max(1, min(block, _GROUP_TOKENS // page_size))
     layer = jnp.asarray(layer, jnp.int32)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
 
@@ -365,8 +406,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
         return _paged_attention_call(
             q, k_pages, v_pages, block_tables, context_lens, layer,
             k_scales, v_scales, starts, scale=scale,
-            interpret=bool(interpret), block=block,
-            group_pages=max(1, min(block, _GROUP_TOKENS // page_size)))
+            interpret=bool(interpret), block=block, group_pages=fold,
+            value_dim=value_dim)
 
     n_chunk = min(int(n_chunk), q.shape[0])
     if n_chunk <= 0 or k_scales is not None:
@@ -374,7 +415,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     cq, ctables, clens, cstarts = rows_of(slice(0, n_chunk))
     tiled = _paged_attention_chunk_call(
         cq, k_pages, v_pages, ctables, clens, layer, cstarts, scale=scale,
-        interpret=bool(interpret), block=block, qb=chunk_tile_rows(n_chunk))
+        interpret=bool(interpret), block=fold if latent else block,
+        qb=chunk_tile_rows(n_chunk), value_dim=value_dim)
     if n_chunk == q.shape[0]:
         return tiled
     return jnp.concatenate(
@@ -382,17 +424,22 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
-                                             "group_pages"))
+                                             "group_pages", "value_dim"))
 def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                           layer, k_scales, v_scales, starts=None, *, scale,
-                          interpret, block, group_pages):
+                          interpret, block, group_pages, value_dim=None):
     """:func:`paged_attention_kernel` on the stacked pool with a traced
     ``layer``. Jitted so that an engine program, which calls it once a
     layer with the same shapes, traces and lowers the kernel once."""
     quantized = k_scales is not None
     windowed = starts is not None
+    latent = v_pages is None
     rows, n_heads, d = q.shape
-    _, _, page_size, kv_heads, _ = k_pages.shape
+    if latent:
+        page_size, kv_heads, dv = k_pages.shape[2], 1, value_dim
+    else:
+        _, _, page_size, kv_heads, _ = k_pages.shape
+        dv = d
     pages_per_seq = block_tables.shape[1]
     group = n_heads // kv_heads
     # few query heads a K/V head: a broadcast-multiply and a lane
@@ -402,7 +449,9 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
     on_mxu = group > _VPU_GROUP_ROWS and not quantized
 
     # [rows, group, kv_heads, d]: a group row is one (kv_heads, d) tile
-    qg = q.reshape(rows, kv_heads, group, d).transpose(0, 2, 1, 3)
+    # (a latent pool: the heads' rows as they come, [rows, heads, d])
+    qg = q if latent else \
+        q.reshape(rows, kv_heads, group, d).transpose(0, 2, 1, 3)
     tables = jnp.clip(block_tables, 0).astype(jnp.int32)
     lens = jnp.clip(context_lens.astype(jnp.int32), 0,
                     pages_per_seq * page_size)
@@ -420,13 +469,18 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
     def kernel(len_ref, tbl_ref, next_ref, meta_ref, *rest):
         if windowed:
             start_ref, rest = rest[0], rest[1:]
-        q_ref, k_hbm, v_hbm = rest[:3]
-        rest = rest[3:]
-        if quantized:
-            ks_ref, vs_ref = rest[:2]
-            rest = rest[2:]
-        (o_ref, k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
-         l_ref) = rest
+        if latent:
+            (q_ref, k_hbm, o_ref, k_buf, sems, slot_ref, acc_ref, m_ref,
+             l_ref) = rest
+            v_hbm = v_buf = None
+        else:
+            q_ref, k_hbm, v_hbm = rest[:3]
+            rest = rest[3:]
+            if quantized:
+                ks_ref, vs_ref = rest[:2]
+                rest = rest[2:]
+            (o_ref, k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
+             l_ref) = rest
         r = pl.program_id(0)
         ctx = len_ref[r]
 
@@ -493,6 +547,40 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                                                           axis=0)
                 m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
                 l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+        def attend_latent(slot, p, n, first_token, fetched):
+            """Fold ``n`` (static) pages of ``slot`` from page ``p`` on, of
+            which the first ``fetched`` tokens were copied: every head's
+            query row against the rows as they lie, then the probabilities
+            against the first ``dv`` columns of THE SAME rows."""
+            tokens = n * page_size
+            exact = jax.lax.Precision.HIGHEST \
+                if k_pages.dtype == jnp.float32 else None
+            k = k_buf[slot, pl.ds(p, n)].reshape(tokens, d)
+            # what this block's copies did not write is whatever the slot
+            # held: keep it out of both products
+            k = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (tokens, 1), 0) < fetched, k, jnp.zeros_like(k))
+            s = jax.lax.dot_general(
+                q_ref[0].astype(k_pages.dtype), k, (((1,), (1,)), ((), ())),
+                precision=exact, preferred_element_type=jnp.float32) * scale
+            token = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            valid = token < ctx - first_token
+            if windowed:
+                valid = valid & (token >= lo - first_token)
+            s = jnp.where(valid, s, _MASK_VALUE)
+            m_prev = m_ref[:, :1]
+            l_prev = l_ref[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p_ = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p_, axis=1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p_.astype(k_pages.dtype), k[:, :dv],
+                (((1,), (0,)), ((), ())), precision=exact,
+                preferred_element_type=jnp.float32)
+            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
         def attend_pages_on_mxu(k, v, tokens, first_token):
             """The same fold as two products on the MXU, for many query
@@ -577,6 +665,18 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                         return p + n
                     return body
 
+                def fold_latent(i, p):
+                    attend_latent(slot, p, group_pages,
+                                  (page0 + first_page + p) * page_size,
+                                  (here - p) * page_size)
+                    return p + group_pages
+
+                if latent:
+                    # whole groups only (``block`` is a multiple of one):
+                    # the last one's unfetched pages are masked
+                    jax.lax.fori_loop(0, pl.cdiv(here, group_pages),
+                                      fold_latent, 0)
+                    return 1 - slot
                 # whole groups of pages first, the rest a page at a time
                 p = jax.lax.fori_loop(0, here // group_pages,
                                       fold(group_pages), 0)
@@ -586,15 +686,22 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
 
             slot_ref[0] = jax.lax.fori_loop(0, n_blocks, attend_block,
                                             slot_ref[0])
-            o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(
-                o_ref.dtype)
+            if latent:
+                o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+            else:
+                o_ref[0] = (acc_ref[...] / l_ref[:, :, :1]).astype(
+                    o_ref.dtype)
 
-    q_spec = pl.BlockSpec((1, group, kv_heads, d),
-                          lambda r, *_: (r, 0, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [q_spec, hbm_spec, hbm_spec]
     prefetch = [lens, tables, next_live, meta] \
         + ([starts] if windowed else [])
+    if latent:
+        return _latent_row_walk(
+            kernel, prefetch, qg, k_pages, block=block, dv=dv,
+            interpret=interpret)
+    q_spec = pl.BlockSpec((1, group, kv_heads, d),
+                          lambda r, *_: (r, 0, 0, 0))
+    in_specs = [q_spec, hbm_spec, hbm_spec]
     operands = prefetch + [qg, k_pages, v_pages]
     if quantized:
         # the scale rows of each row's table, [rows, 1, max tokens]: a
@@ -639,6 +746,36 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
         name="paged_attention",
     )(*operands)
     return out.transpose(0, 2, 1, 3).reshape(rows, n_heads, d)
+
+
+def _latent_row_walk(kernel, prefetch, q, pages, *, block, dv, interpret):
+    """The row walk's ``pallas_call`` over a latent pool: ONE page buffer
+    (two slots), the softmax state a query head."""
+    rows, n_heads, d = q.shape
+    page_size = pages.shape[2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(rows,),
+        in_specs=[pl.BlockSpec((1, n_heads, d), lambda r, *_: (r, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n_heads, dv), lambda r, *_: (r, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block, page_size, d), pages.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),          # slot of the block due
+            pltpu.VMEM((n_heads, dv), jnp.float32),
+            pltpu.VMEM((n_heads, _LANES), jnp.float32),
+            pltpu.VMEM((n_heads, _LANES), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows, n_heads, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attention",
+    )(*prefetch, q, pages)
 
 
 # rows of a query tile. Not from the query heads: the pool's counter
@@ -713,10 +850,10 @@ def _head_rows(buf, slot, kv_heads: int, tokens: int):
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
-                                             "qb"))
+                                             "qb", "value_dim"))
 def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
                                 context_lens, layer, starts=None, *, scale,
-                                interpret, block, qb):
+                                interpret, block, qb, value_dim=None):
     """The QUERY-TILE path of :func:`paged_attention_kernel`: ``q`` holds
     packed prompt rows only (the rows of one sequence contiguous and in
     order), over the stacked unquantized pool with a traced ``layer``.
@@ -738,10 +875,21 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
     probabilities rounded to the pool's type for the second product,
     float32 sums. A row outside the tile at work (another sequence's, a
     padded one) is masked whole and its output left alone; a padded row's
-    output is zero."""
+    output is zero.
+
+    A latent pool (``v_pages`` None): the one "K/V head" is the page's rows
+    as they lie, all ``qb x heads`` query rows of a tile against them, the
+    values their first ``value_dim`` columns; ``block`` is then one group of
+    ``_LATENT_GROUP_TOKENS`` (a tile's scores are ``qb x heads`` rows
+    wide)."""
     windowed = starts is not None
+    latent = v_pages is None
     rows, n_heads, d = q.shape
-    _, _, page_size, kv_heads, _ = k_pages.shape
+    if latent:
+        page_size, kv_heads, dv = k_pages.shape[2], 1, value_dim
+    else:
+        _, _, page_size, kv_heads, _ = k_pages.shape
+        dv = d
     pages_per_seq = block_tables.shape[1]
     group = n_heads // kv_heads
     n_win = -(-rows // qb)
@@ -776,9 +924,25 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
         n_win, kv_heads, tile_rows, d)
 
     def kernel(head_ref, count_ref, lo_ref, hi_ref, next_ref, meta_ref,
-               tbl_ref, q_ref, lim_ref, low_ref, k_hbm, v_hbm, o_ref, k_buf,
-               v_buf, sems, slot_ref, acc_ref, m_ref, l_ref):
+               tbl_ref, q_ref, lim_ref, low_ref, k_hbm, *rest):
+        if latent:
+            v_hbm = v_buf = None
+            o_ref, k_buf, sems, slot_ref, acc_ref, m_ref, l_ref = rest
+        else:
+            (v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
+             l_ref) = rest
         w = pl.program_id(0)
+
+        def head_rows(slot):
+            """``(head, its key rows, its value rows)`` of a buffer slot."""
+            if latent:
+                rows_ = k_buf[slot].reshape(tokens, d)
+                yield 0, rows_, rows_[:, :dv]
+                return
+            for (h, kh), (_, vh) in zip(
+                    _head_rows(k_buf, slot, kv_heads, tokens),
+                    _head_rows(v_buf, slot, kv_heads, tokens)):
+                yield h, kh, vh
 
         copies = _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf,
                               sems)
@@ -843,9 +1007,7 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
                 fetched = jax.lax.broadcasted_iota(
                     jnp.int32, (tokens, 1), 0) < jnp.minimum(
                     block, n_pages - blk * block) * page_size
-                for (h, kh), (_, vh) in zip(
-                        _head_rows(k_buf, slot, kv_heads, tokens),
-                        _head_rows(v_buf, slot, kv_heads, tokens)):
+                for h, kh, vh in head_rows(slot):
                     qh = q_ref[0, h].astype(k_pages.dtype)   # [tile_rows, d]
                     s = jax.lax.dot_general(
                         qh, kh, (((1,), (1,)), ((), ())), precision=exact,
@@ -891,47 +1053,56 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
 
     q_spec = pl.BlockSpec((1, kv_heads, tile_rows, d),
                           lambda w, *_: (w, 0, 0, 0))
+    o_spec = pl.BlockSpec((1, kv_heads, tile_rows, dv),
+                          lambda w, *_: (w, 0, 0, 0))
     row_spec = pl.BlockSpec((1, qb, 1), lambda w, *_: (w, 0, 0))
     hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
     prefetch = [head.astype(jnp.int32), count.astype(jnp.int32),
                 first_page.astype(jnp.int32), last_page.astype(jnp.int32),
                 next_tile, meta, tables]
-    page_buffer = pltpu.VMEM((2, block, page_size, kv_heads, d),
-                             k_pages.dtype)
+    if latent:
+        pools = [k_pages]
+        page_buffers = [pltpu.VMEM((2, block, page_size, d), k_pages.dtype)]
+    else:
+        pools = [k_pages, v_pages]
+        page_buffers = [pltpu.VMEM((2, block, page_size, kv_heads, d),
+                                   k_pages.dtype)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(n_win,),
-        in_specs=[q_spec, row_spec, row_spec, hbm_spec, hbm_spec],
-        out_specs=q_spec,
-        scratch_shapes=[
-            page_buffer, page_buffer,
-            pltpu.SemaphoreType.DMA((2, 2)),      # (K | V, slot)
+        in_specs=[q_spec, row_spec, row_spec] + [hbm_spec] * len(pools),
+        out_specs=o_spec,
+        scratch_shapes=page_buffers + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),     # (K | V, slot)
             pltpu.SMEM((1,), jnp.int32),          # slot of the block due
-            pltpu.VMEM((kv_heads, tile_rows, d), jnp.float32),
+            pltpu.VMEM((kv_heads, tile_rows, dv), jnp.float32),
             pltpu.VMEM((kv_heads, tile_rows, _LANES), jnp.float32),
             pltpu.VMEM((kv_heads, tile_rows, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape[:-1] + (dv,), q.dtype),
         # sequential: the page buffers, their semaphores and the slot
         # carry a prefetched block from one tile into the next
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            **({"vmem_limit_bytes": _LATENT_TILE_VMEM_BYTES} if latent
+               else {})),
         interpret=interpret,
         name="paged_attention_chunk",
     )(*prefetch, qt, lens.reshape(n_win, qb, 1), lows.reshape(n_win, qb, 1),
-      k_pages, v_pages)
-    return out.reshape(n_win, kv_heads, group, qb, d).transpose(
-        0, 3, 1, 2, 4).reshape(n_win * qb, n_heads, d)[:rows]
+      *pools)
+    return out.reshape(n_win, kv_heads, group, qb, dv).transpose(
+        0, 3, 1, 2, 4).reshape(n_win * qb, n_heads, dv)[:rows]
 
 
 def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
                            token_tables, token_lens,
                            scale: Optional[float] = None,
                            impl: str = "xla", layer=None, starts=None,
-                           n_chunk: int = 0):
+                           n_chunk: int = 0,
+                           value_dim: Optional[int] = None):
     """THE ragged paged-attention entry point: ONE op serving every
     attention shape the engine dispatches — single-token decodes,
     chunked-prefill suffixes, speculative-verify windows, and a MIXED
@@ -989,7 +1160,17 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     the pool a block a step, int8 dequantized in VMEM), or
     ``"reference"`` (:func:`ragged_paged_attention_reference` —
     full-f32 exactness baseline, kept callable for the int8 tolerance
-    tests)."""
+    tests).
+
+    A LATENT pool (``kv_v`` None, ``value_dim`` given; ``page_pool.
+    CacheGroup.value_dim``): ``kv_k`` is ONE store ``[(L,) num_pages,
+    page_size, width]``, a token's row the key of every query head and its
+    first ``value_dim`` columns the value: ``q`` [T, heads, width] ->
+    [T, heads, value_dim]. All three paths take it; none reads a second
+    pool."""
+    if kv_v is None:
+        return _latent_attention(q, kv_k, token_tables, token_lens, scale,
+                                 impl, layer, starts, n_chunk, value_dim)
     if layer is not None and impl != "pallas":
         kv_k, kv_v = kv_layer(kv_k, layer), kv_layer(kv_v, layer)
     kp, ks = _split_kv(kv_k)
@@ -1019,6 +1200,31 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
 
 def _column(starts):
     return None if starts is None else starts[:, None]
+
+
+def _latent_attention(q, pages, token_tables, token_lens, scale, impl,
+                      layer, starts, n_chunk, value_dim):
+    """:func:`ragged_paged_attention` over a latent pool (no V pages)."""
+    if isinstance(pages, QuantizedKV) or value_dim is None:
+        raise ValueError("a pool without V pages is a latent pool: it "
+                         "needs value_dim and has no int8 form")
+    if impl == "pallas":
+        return paged_attention_kernel(
+            q, pages, None, token_tables, token_lens, layer=layer,
+            scale=scale, starts=starts, n_chunk=n_chunk,
+            value_dim=value_dim)
+    if impl not in ("xla", "reference"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if layer is not None:
+        pages = kv_layer(pages, layer)
+    scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    # the gathered core with one K/V "head": the rows, and their first
+    # value_dim columns (a slice of the gathered rows, not a second pool)
+    out = _gathered_attention(
+        (q.astype(jnp.float32) if impl == "reference" else q)[:, None],
+        pages[:, :, None], None, token_tables, token_lens[:, None], scale,
+        start=_column(starts), value_dim=value_dim)
+    return out[:, 0].astype(q.dtype)
 
 
 def ragged_paged_attention_reference(q, kv_k: KVStore, kv_v: KVStore,
@@ -1102,12 +1308,14 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 
 def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
-                        scale, k_scales=None, v_scales=None, start=None):
+                        scale, k_scales=None, v_scales=None, start=None,
+                        value_dim=None):
     """Shared decode-attention core: gather the block table's pages,
     dequantize (optional per-row scales), expand GQA, masked fp32
     softmax. q [B, K, H, d]; limit [B, K] = attendable cached
     positions per query (0 → zero output row); ``start`` [B, K] their
-    lower bound (None: 0)."""
+    lower bound (None: 0). ``v_pages`` None: the values are the first
+    ``value_dim`` columns of the gathered key rows."""
     b, kq, n_heads, d = q.shape
     _, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
@@ -1116,7 +1324,8 @@ def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
     with jax.named_scope("kv_gather"):
         tables = jnp.clip(block_tables, 0)             # [B, P]
         k = jnp.take(k_pages, tables, axis=0)          # [B, P, ps, KVH, d]
-        v = jnp.take(v_pages, tables, axis=0)
+        v = k[..., :value_dim] if v_pages is None \
+            else jnp.take(v_pages, tables, axis=0)
         if k_scales is not None:
             # int8 pool: dequantize the gathered rows (scale per page
             # row)
@@ -1125,7 +1334,7 @@ def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
             v = v.astype(jnp.float32) * \
                 jnp.take(v_scales, tables, axis=0)[..., None, None]
         k = k.reshape(b, L, kv_heads, d)
-        v = v.reshape(b, L, kv_heads, d)
+        v = v.reshape(b, L, kv_heads, v.shape[-1])
         if n_heads != kv_heads:
             rep = n_heads // kv_heads
             k = jnp.repeat(k, rep, axis=2)

@@ -434,7 +434,7 @@ def hybrid_programs(one_chip):
                "INTERPRET", False)
     # the engine here lies on the CPU: the test, not an option, says what
     # the platform of a TPU's state would
-    mp.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    mp.setattr(llm, "_state_impl", lambda ssm_state, impls=None: "pallas")
     mp.setattr(llm, "_moe_impl", lambda net: "pallas")
     cfg = GraniteHybridConfig(
         vocab_size=1024, hidden_size=1024,
@@ -668,3 +668,175 @@ def test_windowed_program_keeps_both_groups_pools_in_place(
     assert mem.alias_size_in_bytes >= 2 * pools
     assert mem.temp_size_in_bytes < pools // 4, (
         mem.temp_size_in_bytes, pools)
+
+
+# -- a latent cache group and a delta-rule state (Kimi Linear's widths) -------
+
+KDA_CELL = dict(slots=48, chunk=256, max_len=7680, width=640, lora=512,
+                heads=32, mla_layers=7, pages=48 * 480 + 1)
+
+
+@pytest.mark.parametrize("rows,n_chunk", [(48, 0), (304, 256)],
+                         ids=["decode", "mixed"])
+def test_latent_attention_compiles_for_v5e_and_copies_a_page_once(
+        one_chip, rows, n_chunk):
+    """The row walk and the query tiles over ``reason_closed_kda``'s latent
+    pool (7 layers x 23,041 pages of 16 rows of 640 bf16 values), 32 heads
+    a row, a 480-column table: no copy of the pool beside the kernel, and
+    ONE copy a page in the kernel's text where a K/V pool's has two (the
+    same rows serve the scores and the values)."""
+    c = KDA_CELL
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((c["mla_layers"], c["pages"], PAGE, c["width"]),
+               jnp.bfloat16)
+    q = sds((rows, c["heads"], c["width"]), jnp.bfloat16)
+    tables = sds((rows, c["max_len"] // PAGE), jnp.int32)
+    lens, layer = sds((rows,), jnp.int32), sds((), jnp.int32)
+
+    def latent(q, p, t, n, i):
+        return paged_attention_kernel(q, p, None, t, n, layer=i,
+                                      interpret=False, n_chunk=n_chunk,
+                                      value_dim=c["lora"])
+
+    compiled = _compiled(latent, q, pool, tables, lens, layer)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 7 * c["pages"] * PAGE * 640 * 2
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes >= rows * 32 * 512 * 2
+
+    # in the kernels' own text: one page buffer, one copy a page
+    def copies(fn, *args):
+        return str(jax.make_jaxpr(fn)(*args)).count("dma_start")
+
+    kv = sds((c["mla_layers"], 64, PAGE, 1, 128), jnp.bfloat16)
+    q_kv = sds((rows, c["heads"], 128), jnp.bfloat16)
+    both = copies(lambda q, k, v, t, n, i: paged_attention_kernel(
+        q, k, v, t, n, layer=i, interpret=False, n_chunk=n_chunk),
+        q_kv, kv, kv, tables, lens, layer)
+    once = copies(latent, q, pool, tables, lens, layer)
+    assert once > 0 and 2 * once == both
+
+
+def test_a_latent_row_of_576_is_refused_by_the_compiler(one_chip):
+    """Why the row is stored 640 wide: the pool's last axis is tiled to
+    whole lanes of 128 in HBM whatever its logical width, and Mosaic
+    refuses a page slice that is not."""
+    c = KDA_CELL
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda q, p, t, n, i: paged_attention_kernel(
+            q, p, None, t, n, layer=i, interpret=False,
+            value_dim=c["lora"])).lower(
+            sds((48, 32, 576), jnp.bfloat16),
+            sds((7, 257, PAGE, 576), jnp.bfloat16),
+            sds((48, 16), jnp.int32), sds((48,), jnp.int32),
+            sds((), jnp.int32)).compile()
+
+
+@pytest.fixture(scope="module")
+def kda_programs(one_chip):
+    """``reason_closed_kda``'s two engine programs at the published widths
+    (hidden 2304; KDA 32 heads of 128 with a conv of 4; MLA 32 heads of
+    128 + 64 / 128 over a latent of 512; 16 of 256 experts of 1024 held),
+    the cell's 48 slots, 256-row chunk and 480-column table, at a depth of
+    FIVE (the dense KDA layer, two routed KDA layers, a routed MLA layer,
+    a routed KDA layer) over a pool of 257 pages: what grows with depth
+    and with the pool is arguments, reckoned from the shapes below.
+    ``{name: (compiled, state bytes, pool bytes)}``."""
+    import importlib
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.inference import llm
+    from paddle_tpu.models import KimiLinearConfig, KimiLinearForCausalLM
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(importlib.import_module("paddle_tpu.ops.flash_attention"),
+               "INTERPRET", False)
+    mp.setattr(llm, "_moe_impl", lambda net: "pallas")
+    c = KDA_CELL
+    cfg = KimiLinearConfig(num_hidden_layers=5, vocab_size=1024,
+                           experts_held=(0, 16))
+    assert (cfg.hidden_size, cfg.kda_heads, cfg.kda_head_dim,
+            cfg.latent_width, cfg.kv_lora_rank, cfg.num_experts) == (
+        2304, 32, 128, 640, 512, 256)
+    assert cfg.layer_kinds == ("kda", "kda", "kda", "mla", "kda")
+    pt.seed(0)
+    net = KimiLinearForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    eng = llm.LLMEngine(net, max_seqs=c["slots"], page_size=PAGE,
+                        num_pages=257, max_len=c["max_len"],
+                        prefill_chunk=c["chunk"], kv_dtype="bf16",
+                        attention_impl="pallas")
+    try:
+        assert (eng.state_impl, eng.moe_impl) == ("xla", "pallas")
+
+        def described(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=one_chip), tree)
+
+        slots, chunk = c["slots"], c["chunk"]
+        ints = np.zeros((slots,), np.int32)
+        tables = eng._pool.device_tables()
+        assert eng.pages_per_seq == 480 and eng.v_pages == (None,)
+        lowered = {"decode": eng._decode_fn.lower(*described((
+            eng._params, eng._buffers, eng._tokens_dev, ints, tables, ints,
+            eng.k_pages, eng.v_pages, eng.temperatures, eng._nonces,
+            eng._key) + eng._state_args()))}
+        seg, seg_rows, _ = eng._chunk_segments((1, chunk))
+        rows = np.zeros((1, chunk), np.int32)
+        per_slot = np.zeros((1, slots), np.int32)
+        xs = {"tok": rows, "pos": rows, "lim": rows,
+              "tbl": eng._pool.row_tables(np.full((1, chunk), -1)),
+              "fin": per_slot.astype(bool), "row": per_slot,
+              "fpos": per_slot, "grant": per_slot, "seg": seg,
+              "segrows": seg_rows}
+        lowered["mixed"] = eng._mixed_fn.lower(*described((
+            eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+            tables, eng.temperatures, eng._nonces, eng._key)), 1)
+        state = sum(a.nbytes for a in eng.conv_state + eng.ssm_state)
+        pool = eng.k_pages[0].nbytes
+        assert state == 49 * 4 * (2_097_152 + 73_728)
+        assert pool == 257 * PAGE * 640 * 2
+    finally:
+        eng.close()
+        mp.undo()
+    return {name: (low.compile(), state, pool)
+            for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_kda_program_fits_the_chip_at_full_depth_and_keeps_state_and_pool_in_place(
+        kda_programs, program):
+    """One kernel call an MLA layer (two in a mixed tick: the chunk's rows
+    through query tiles, the decode rows through the row walk), every
+    routed layer's two grouped products through the kernel, the state rows
+    and the latent pool the program's own outputs (aliased), and the
+    temporaries (which do not grow with depth: the layers run one after
+    another) small enough that the cell's arguments at ALL 27 layers,
+    8.59 GB of weights + 2.13 GB of state + 3.30 GB of pool, stay under
+    0.90 x ``bytes_limit`` beside them."""
+    compiled, state, pool = kda_programs[program]
+    text = compiled.as_text()
+
+    def calls(kernel):
+        return [ln for ln in text.splitlines() if " custom-call(" in ln
+                and "%" + kernel in ln.split(" = ")[0]]
+
+    assert len(calls("paged_attention.")) == 1
+    assert len(calls("paged_attention_chunk")) == (program == "mixed")
+    assert len(calls("grouped_matmul")) == 8 and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state + pool
+    # a copy of ONE layer's state rows would be 103 MB; the chunk form's
+    # largest temporaries are two [16, 256, 32, 128] float32 arrays
+    budget = {"decode": 64 << 20, "mixed": 400 << 20}[program]
+    assert mem.temp_size_in_bytes < budget, mem.temp_size_in_bytes
+    full_depth = 8.592e9 + 49 * 43_417_600 + 23_041 * 143_360
+    assert full_depth + mem.temp_size_in_bytes < 0.90 * 16_909_336_064

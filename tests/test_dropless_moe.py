@@ -325,3 +325,86 @@ def test_forward_holds_no_scatter_and_no_float32_copy_of_the_pairs(impl):
         lambda x, v: _scatter_form(layer, x, v, impl))(x, valid))
     lowered = jax.jit(lambda x, v: layer(x, v, impl)).lower(x, valid)
     assert "moe/moe_combine" in lowered.as_text(debug_info=True)
+
+
+# -- the sigmoid form: scores, a selection bias outside the gates (PR 39) ----
+
+
+def _sigmoid_layer(held=None, experts=16, k=4, scale=2.446):
+    pt.seed(0)
+    layer = DroplessMoE(D, DE, experts, k, held, initializer_range=0.3,
+                        routed_scaling_factor=scale, scoring="sigmoid")
+    # a bias large enough to change who is chosen
+    layer.e_bias = jnp.asarray(
+        np.random.default_rng(7).normal(size=(experts,)) * 0.5, jnp.float32)
+    return layer
+
+
+def _dense_sigmoid(layer, x, experts=None):
+    """The definition: scores sigmoid over all experts, the ``k`` largest
+    of score + bias, gates the chosen SCORES over their sum, times the
+    scale; an expert at a time."""
+    scores = jax.nn.sigmoid(x @ layer.router)
+    _, idx = jax.lax.top_k(scores + layer.e_bias, layer.top_k)
+    top = jnp.take_along_axis(scores, idx, -1)
+    gates = top / top.sum(-1, keepdims=True) * layer.routed_scaling_factor
+    y = jnp.zeros_like(x)
+    for e in (range(layer.num_experts) if experts is None else experts):
+        le = e - layer.first
+        a, b = jnp.split(x @ layer.w_in[le], 2, -1)
+        gate = jnp.sum(jnp.where(idx == e, gates, 0.0), -1, keepdims=True)
+        y = y + gate * ((jax.nn.silu(a) * b) @ layer.w_out[le])
+    return y
+
+
+@IMPLS
+def test_sigmoid_scores_with_a_bias_that_selects_and_does_not_weigh(impl):
+    layer, x = _sigmoid_layer(), _x()
+    y, rows = layer(x, impl=impl)
+    np.testing.assert_allclose(y, _dense_sigmoid(layer, x), atol=5 * TOL,
+                               rtol=5 * TOL)
+    assert int(rows.sum()) == T * 4
+    idx, gates = dm.route_top_k(x, layer.router, 4, layer.e_bias)
+    # renormalised: a row's gates sum to one before the scale
+    np.testing.assert_allclose(gates.sum(-1), 1.0, atol=1e-6)
+    # the bias chooses: without it other experts win somewhere
+    plain, _ = dm.route_top_k(x, layer.router, 4, jnp.zeros((16,)))
+    assert not np.array_equal(np.sort(idx, -1), np.sort(plain, -1))
+    # and it is in no gate: the chosen scores alone weigh
+    scores = jax.nn.sigmoid(x @ layer.router)
+    top = jnp.take_along_axis(scores, idx, -1)
+    np.testing.assert_allclose(gates, top / top.sum(-1, keepdims=True),
+                               atol=1e-6)
+    # the softmax form is what it was: no bias, no parameter for one
+    assert _layer().e_bias is None
+    assert "e_bias" not in _layer().state_dict()
+    assert layer.state_dict()["e_bias"].dtype == jnp.float32
+
+
+@IMPLS
+def test_sixteen_shares_and_one_shared_expert_sum_to_the_uncut_layer(impl):
+    """A slice of sixteen chips that share a sigmoid-routed layer, one
+    expert each here, the shared expert computed alike by all and counted
+    ONCE: the parts add up to the uncut layer's ``shared(x) + 2.446 *
+    sum_e g_e E_e(x)`` and the pairs they count to every pair."""
+    whole, x = _sigmoid_layer(), _x()
+    w_shared = jnp.asarray(
+        np.random.default_rng(3).normal(size=(D, D)) * 0.1, jnp.float32)
+    shared = jnp.tanh(x @ w_shared)
+    total, counted = shared, 0
+    for first in range(16):
+        share = _sigmoid_layer((first, 1))
+        share.router, share.e_bias = whole.router, whole.e_bias
+        share.w_in = whole.w_in[first:first + 1]
+        share.w_out = whole.w_out[first:first + 1]
+        y, rows = share(x, impl=impl)
+        total = total + y
+        counted += int(rows.sum())
+    np.testing.assert_allclose(total, shared + _dense_sigmoid(whole, x),
+                               atol=5 * TOL, rtol=5 * TOL)
+    assert counted == T * 4
+
+
+def test_an_unknown_scoring_is_refused():
+    with pytest.raises(ValueError, match="unknown scoring"):
+        DroplessMoE(D, DE, E, K, scoring="tanh")

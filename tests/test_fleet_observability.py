@@ -359,7 +359,11 @@ def obs_router():
         stubs, health_poll_interval=0.05, page_size=16,
         slo_classes={"gold": SLOClass("gold", deadline_s=30.0,
                                       target=0.9)},
-        slo_windows=(5.0, 50.0), slo_min_samples=4,
+        # a short window of minutes, not of 5 s: on a loaded host a stall of
+        # seconds between a storm's last miss and the read of /sloz emptied
+        # the window and the burn rate read 0 (the driver's tier-1 run of
+        # PR 39's tree; PR 36 saw it too)
+        slo_windows=(60.0, 600.0), slo_min_samples=4,
         slo_breach_threshold=5.0)
     srv = DebugServer(port=0).start()
     yield stubs, router, f"http://127.0.0.1:{srv.port}"

@@ -212,7 +212,7 @@ def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
             return eng.state_impl, [list(o) for o in outs]
 
     plain_impl, want = serve()
-    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state, impls=None: "pallas")
     kernel_impl, got = serve()
     assert (plain_impl, kernel_impl) == ("xla", "pallas")
     assert got == want
@@ -252,7 +252,7 @@ def test_the_chunk_kernel_serves_what_ssd_chunked_serves(knobs,
     net.set_state_dict({k: (v * 6.0 if v.ndim >= 2 and "conv" not in k
                             else v) for k, v in net.state_dict().items()})
     prompts = prompts_of((3, 5, 2, 40, 16, 1, 23), seed=8)
-    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state, impls=None: "pallas")
     scans = []
     kernel = ssd.ssd_chunk_kernel
 
@@ -428,7 +428,7 @@ def test_through_the_kernels_state_bytes_counts_the_sequences_present(
     from paddle_tpu.inference import llm
     from paddle_tpu.observability import tracing
     net, _, _ = model
-    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state: "pallas")
+    monkeypatch.setattr(llm, "_state_impl", lambda ssm_state, impls=None: "pallas")
     was = tracing.enabled()
     tracing.enable()
     tracing.clear()

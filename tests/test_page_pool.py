@@ -159,3 +159,54 @@ def test_pages_touched_by_group():
     got = pool.pages_touched([(chunk, CHUNK, "xla")])
     assert got["full"]["read"] == got["window"]["read"] \
         == CHUNK * PAGES_PER_SEQ
+
+
+# -- a latent group: a row a token, no V, no head axis ------------------------
+
+LATENT = [CacheGroup("latent", 3, 1, 128, None, 96)]
+
+
+def test_a_latent_group_keeps_one_array_and_no_v():
+    pool = pool_of(LATENT)
+    (g,) = pool.groups
+    assert pool.latent and g.latent and not pool.windowed and not pool.bare
+    # [layers, pages, page_size, row width]: no head axis, and no V at all
+    assert g.k_pages.shape == (3, 40, PS, 128) and g.v_pages is None
+    assert pool.k_pages[0] is g.k_pages and pool.v_pages == (None,)
+    # what a page costs is what is stored: one row a token a layer
+    assert g.page_bytes == 3 * PS * 128 * 4 == pool.page_bytes
+    assert g.status()["row_bytes"] == 3 * 128 * 4
+    assert g.status()["value_dim"] == 96
+    assert not pool_of(TWO).latent and pool_of(TWO).groups[0].status()[
+        "value_dim"] is None
+    # the programs hand the arrays back in the form they took them in
+    pool.k_pages = (g.k_pages + 1,)
+    pool.v_pages = (None,)
+    assert float(g.k_pages[0, 0, 0, 0]) == 1.0 and g.v_pages is None
+    # the allocator does not know the difference
+    serve(pool, 0, 19, 9)
+    assert g.held[0] == -(-28 // PS)
+    pool.free_slot(0)
+    assert len(g.free) == g.num_pages - 1
+
+
+@pytest.mark.parametrize("spec,kv_dtype", [
+    (LATENT, "int8"), ([CacheGroup("latent", 3, 2, 128, None, 96)], "f32")],
+    ids=["int8", "two-kv-heads"])
+def test_a_latent_group_has_one_head_and_no_int8_form(spec, kv_dtype):
+    with pytest.raises(ValueError, match="without a V"):
+        PagePool(spec, 40, PS, SLOTS, PAGES_PER_SEQ, kv_dtype, CHUNK)
+
+
+def test_pages_touched_by_a_latent_group_are_the_pages_of_one_array():
+    """The row walk reads a row's live pages, a tile of prompt rows its
+    sequence's pages once: the same counts as a K/V group's (a page is a
+    page), priced at the latent page's bytes by the readers."""
+    from paddle_tpu.inference.page_pool import ChunkRows
+    pool, kv = pool_of(LATENT), pool_of([CacheGroup("kv", 3, 2, 8)])
+    rows = [(0, 9), (1, 30)]
+    chunk = ChunkRows(np.asarray([[2] * 8]),
+                      np.asarray([np.arange(20, 28) + 1]))
+    got = pool.pages_touched([(rows, 10, "pallas", chunk)])["latent"]
+    assert got == kv.pages_touched([(rows, 10, "pallas", chunk)])["kv"]
+    assert got == {"read": 3 + 8 + 7, "live": 3 + 8 + 7}

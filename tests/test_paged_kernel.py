@@ -656,3 +656,144 @@ def test_a_model_without_routed_experts_traces_to_the_program_of_pr_37(
     text = _engine_program_texts(net)[program]
     assert "moe" not in text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# -- a latent pool: one row a token, no V, every head attends the same row ----
+
+LW, LV, LHEADS = 24, 16, 4          # row width, value columns, query heads
+
+
+def latent_pool(seed, dtype=jnp.float32):
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             (LAYERS, PAGES, PS, LW)).astype(dtype)
+
+
+def dense_latent(q, pages, tables, lens, layer, starts=None):
+    """The definition: every head's row against the sequence's rows, the
+    probabilities over the rows' first ``LV`` columns."""
+    tables, out = np.asarray(tables), np.zeros(q.shape[:2] + (LV,))
+    pages = np.asarray(pages, np.float64)
+    for r in range(q.shape[0]):
+        lo = 0 if starts is None else int(starts[r])
+        hi = int(lens[r])
+        if hi <= lo:
+            continue
+        rows = pages[layer, tables[r]].reshape(-1, LW)[lo:hi]
+        s = np.asarray(q[r], np.float64) @ rows.T / np.sqrt(LW)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[r] = (p / p.sum(-1, keepdims=True)) @ rows[:, :LV]
+    return out
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla", "reference"])
+def test_a_latent_pool_through_the_row_walk_and_the_gathered_paths(block,
+                                                                   impl):
+    """Ragged limits in one call, over the stacked pool with no V: limit 0,
+    1, a page, a block and one over, ``max_len``."""
+    span = block * PS
+    lens = [0, 1, PS, PS + 1, 0, min(span + 1, MAX_LEN), MAX_LEN, 3, 0]
+    pages = latent_pool(3)
+    q = jax.random.normal(jax.random.PRNGKey(4), (len(lens), LHEADS, LW))
+    tables = tables_for(len(lens), seed=5)
+    lens = jnp.asarray(lens, jnp.int32)
+    got = np.asarray(ragged_paged_attention(
+        q, pages, None, tables, lens, impl=impl, layer=1, value_dim=LV))
+    assert got.shape == (len(lens), LHEADS, LV)
+    np.testing.assert_allclose(got, dense_latent(q, pages, tables, lens, 1),
+                               atol=2e-5, rtol=2e-5)
+    assert not got[np.asarray(lens) == 0].any(), "limit 0 is a zero row"
+    if impl == "pallas":        # and they are not another layer's rows
+        other = dense_latent(q, pages, tables, lens, 0)
+        assert np.abs(got - other).max() > 1e-2
+
+
+@pytest.mark.parametrize("bound", [False, True], ids=["whole", "windowed"])
+def test_a_latent_pools_prompt_rows_through_query_tiles(block, bound):
+    """A mixed tick's batch: 14 rows of one prompt at positions 11..24 (a
+    tile and a half of 8 rows), 3 of another from position 0, padded rows,
+    then decode rows; each page fetched once a tile, for the scores and for
+    the values."""
+    chunk_lens = list(range(12, 26)) + [1, 2, 3] + [0, 0, 0]
+    tables = np.asarray(tables_for(5, seed=7))
+    rows = np.concatenate([np.repeat(tables[:1], 14, 0),
+                           np.repeat(tables[1:2], 3, 0),
+                           np.zeros((3, PAGES_PER_SEQ), np.int32),
+                           tables[2:5]])
+    lens = jnp.asarray(chunk_lens + [9, MAX_LEN, 0], jnp.int32)
+    starts = jnp.maximum(lens - 10, 0) if bound else None
+    pages = latent_pool(8)
+    q = jax.random.normal(jax.random.PRNGKey(9),
+                          (len(lens), LHEADS, LW))
+    got = np.asarray(ragged_paged_attention(
+        q, pages, None, jnp.asarray(rows), lens, impl="pallas", layer=2,
+        starts=starts, n_chunk=len(chunk_lens), value_dim=LV))
+    np.testing.assert_allclose(
+        got, dense_latent(q, pages, rows, lens, 2, starts), atol=2e-5,
+        rtol=2e-5)
+    walked = np.asarray(ragged_paged_attention(
+        q, pages, None, jnp.asarray(rows), lens, impl="pallas", layer=2,
+        starts=starts, value_dim=LV))
+    np.testing.assert_allclose(got, walked, atol=2e-5, rtol=2e-5)
+
+
+def test_a_bf16_latent_pool_rounds_as_a_bf16_pool_does():
+    pages = latent_pool(11, jnp.bfloat16)
+    q = jax.random.normal(jax.random.PRNGKey(12), (4, LHEADS, LW)) \
+        .astype(jnp.bfloat16)
+    tables = tables_for(4, seed=13)
+    lens = jnp.asarray([MAX_LEN, 7, 19, 1], jnp.int32)
+    got = np.asarray(ragged_paged_attention(
+        q, pages, None, tables, lens, impl="pallas", layer=0,
+        n_chunk=0, value_dim=LV), np.float32)
+    want = dense_latent(np.asarray(q, np.float32), pages, tables, lens, 0)
+    np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
+
+
+def test_a_latent_pool_is_copied_once_a_page_not_twice(monkeypatch):
+    """The kernels' own text: one page buffer and one copy a page (a K/V
+    pool's has two of each), so ``kv_pages_read x page_bytes`` is what
+    crosses HBM."""
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    lens = jnp.asarray([9, MAX_LEN, 1, 0, 17], jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(1), (5, LHEADS, LW))
+
+    def copies(text):
+        return text.count("dma_start")
+
+    for n_chunk in (0, 5):
+        latent = jaxpr_text(
+            lambda q, p, t, n: ragged_paged_attention(
+                q, p, None, t, n, impl="pallas", layer=2, n_chunk=n_chunk,
+                value_dim=LV), q, latent_pool(0), tables_for(5), lens)
+        k, v = pool(0, 1)
+        both = jaxpr_text(
+            lambda q, k, v, t, n: ragged_paged_attention(
+                q, k, v, t, n, impl="pallas", layer=2, n_chunk=n_chunk),
+            queries(5, LHEADS), k, v, tables_for(5), lens)
+        assert copies(latent) > 0 and 2 * copies(latent) == copies(both)
+
+
+def test_a_latent_pool_needs_its_value_width_and_has_no_int8_form():
+    q = jax.random.normal(jax.random.PRNGKey(1), (2, LHEADS, LW))
+    lens = jnp.asarray([3, 4], jnp.int32)
+    for impl in ("pallas", "xla"):
+        with pytest.raises(ValueError, match="latent pool"):
+            ragged_paged_attention(q, latent_pool(0), None, tables_for(2),
+                                   lens, impl=impl, layer=0)
+    quantized = pa.kv_zeros((LAYERS, PAGES, PS, 1, LW), "int8")
+    with pytest.raises(ValueError, match="latent pool"):
+        ragged_paged_attention(q, quantized, None, tables_for(2), lens,
+                               layer=0, value_dim=LV)
+
+
+def test_block_of_a_latent_pool_follows_the_stored_row():
+    """A page without a head axis is ``[page_size, width]`` in VMEM: its
+    tokens on the sublanes, its width padded to whole lanes; ONE pool's
+    buffers, so twice the pages a K/V pool of the same bytes moves."""
+    assert pa._pages_per_block(16, None, 640, jnp.bfloat16, 480, 1) == 102
+    assert pa._pages_per_block(16, None, 576, jnp.bfloat16, 480, 1) == 102
+    assert pa._pages_per_block(16, None, 640, jnp.bfloat16, 8, 1) == 8
+    assert pa._pages_per_block(16, 5, 128, jnp.bfloat16, 480, 2) \
+        == pa._pages_per_block(16, 5, 128, jnp.bfloat16, 480)
