@@ -15,7 +15,8 @@ paths through the entry points users call, at the full width of
            (a layer of a stacked bf16 and int8 pool, MHA and GQA 16/4),
            compiled (interpret=False), against their jnp references; then
            the kernel's time a call beside the gathered path's at the
-           serving benchmark's decode and mixed shapes, the state step's
+           serving benchmark's decode and mixed shapes and at the window /
+           full attention cell's two decode calls, the state step's
            kernel beside ``ssd_step`` and the routed experts' grouped
            product beside ``jax.lax.ragged_dot`` at the hybrid cell's shapes
   serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
@@ -280,13 +281,14 @@ def time_paged_attention(seed: int, heads: int = 16, d: int = 128,
     a layer under a 2048-token table; a decode tick's 32 rows (contexts
     of 16 to 896 tokens, lognormal around 200) and a mixed tick's 96 (the
     same 32 after 64 chunk rows of two prompts, each row its sequence's
-    table and a limit one longer than the row before). A call's time is
+    table and a limit one longer than the row before). Then the window /
+    full attention cell's two decode calls (:func:`swa_decode_calls`: 6
+    and 9 query heads a K/V head, the fold on the MXU). A call's time is
     that of ``calls`` chained calls in one program, over their number.
     Smoke readings of one layer's call, not a benchmark."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.ops.paged_attention import ragged_paged_attention
 
     rng = np.random.RandomState(seed + 7)
     pages_per_seq = seq // PAGE
@@ -314,39 +316,120 @@ def time_paged_attention(seed: int, heads: int = 16, d: int = 128,
         "mixed": (np.concatenate([chunk_tables, decode_tables]),
                   np.concatenate([chunk_lens, decode_lens]))}
     for name, (tables, lens) in shapes.items():
-        rows = len(lens)
-        qq = jax.random.normal(keys[2], (rows, heads, d), jnp.bfloat16)
-        tb, ln = jnp.asarray(tables), jnp.asarray(lens)
-        ms, outs = {}, {}
-        for impl in ("pallas", "xla"):
-            one = jax.jit(lambda q, k, v, impl=impl: ragged_paged_attention(
-                q, k, v, tb, ln, impl=impl, layer=layers // 2))
-            outs[impl] = one(qq, k_pool, v_pool).block_until_ready()
+        qq = jax.random.normal(keys[2], (len(lens), heads, d), jnp.bfloat16)
+        time_attention_call(name, qq, k_pool, v_pool, tables, lens, None,
+                            calls)
+    del k_pool, v_pool
+    for name, call in swa_decode_calls(seed):
+        # the gathered path moves the whole 9,216-token table a row and
+        # repeats it a query head: agreement four rows at a time, no time
+        time_attention_call(name, *call, calls, gather_rows=4)
 
-            # a tick's worth of calls in ONE program, each fed the one
-            # before: a dispatch from Python costs more than the kernel runs
-            def tick(q, k, v, impl=impl):
-                return jax.lax.fori_loop(
-                    0, calls, lambda i, q: ragged_paged_attention(
-                        q, k, v, tb, ln, impl=impl, layer=i % layers), q)
 
-            fn = jax.jit(tick)
-            fn(qq, k_pool, v_pool).block_until_ready()
-            t1 = time.perf_counter()
-            for _ in range(3):
-                out = fn(qq, k_pool, v_pool)
-            out.block_until_ready()
-            ms[impl] = (time.perf_counter() - t1) * 1e3 / (3 * calls)
-        err, ok = _max_err(outs["pallas"], outs["xla"])
-        live = int(sum(-(-int(n) // PAGE) for n in lens))
-        emit({"phase": "kernels", "kernel": "paged_attention",
-              "timed": name, "rows": rows, "live_pages_read": live,
-              "table_pages": rows * pages_per_seq,
-              "kernel_ms_per_call": round(ms["pallas"], 4),
-              "gathered_ms_per_call": round(ms["xla"], 4),
-              "max_abs_err": round(err, 5), "calls": calls})
-        check(ok, f"paged attention at the {name} tick's shapes disagrees "
-                  f"with _gathered_attention: {err}")
+def swa_decode_calls(seed: int, rows: int = 32, kv_heads: int = 8,
+                     d: int = 128, pages_per_seq: int = 576,
+                     window: int = 512):
+    """The row walk's two calls in a decode tick of ``agent_closed_swa``
+    (Laguna-S-2.1's widths: 8 K/V heads of 128, pages of 16 tokens, bf16,
+    tables of 576 pages), 32 rows with contexts lognormal around 2,300
+    (512 to 9,216): ``swa_full`` (F) 48 query heads over a full-attention
+    group's pool (3 layers of 18,433 pages), ``swa_window`` (W) 72 heads
+    with ``starts = limit - 512`` over a window group's (9 layers of 1,569
+    pages: a row holds the 33 pages of its window). Yields ``(name, (q,
+    k_pool, v_pool, tables, lens, starts))``, a call's pools made when it
+    is asked for."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.RandomState(seed + 41)
+    lens = np.clip(rng.lognormal(np.log(2300.0), 0.55, rows), 512,
+                   pages_per_seq * PAGE).astype(np.int32)
+    lens[0], lens[1] = pages_per_seq * PAGE, 512      # both ends of the range
+    keys = jax.random.split(jax.random.PRNGKey(seed + 41), 3)
+
+    for name, (heads, layers, num_pages, bound) in {
+            "swa_full": (48, 3, 18433, False),
+            "swa_window": (72, 9, 1569, True)}.items():
+        starts = np.maximum(lens - window, 0) if bound else None
+        tables = np.zeros((rows, pages_per_seq), np.int32)
+        free = rng.permutation(np.arange(1, num_pages))
+        for r, n in enumerate(lens):
+            first = int(starts[r]) // PAGE if bound else 0
+            need = -(-int(n) // PAGE) - first
+            tables[r, first:first + need] = free[:need]
+            free = free[need:]
+        shape = (layers, num_pages, PAGE, kv_heads, d)
+        yield name, (
+            jax.random.normal(keys[2], (rows, heads, d), jnp.bfloat16),
+            jax.random.normal(keys[0], shape, jnp.bfloat16),
+            jax.random.normal(keys[1], shape, jnp.bfloat16),
+            tables, lens, starts)
+
+
+def time_attention_call(name, qq, k_pool, v_pool, tables, lens, starts,
+                        calls: int, gather_rows=None) -> None:
+    """One line of ``time_paged_attention``: the kernel held to the
+    gathered path, then ms a call of ``calls`` chained calls in one program
+    (the layer of the stacked pool going round), the live pages a call
+    reads and the GB/s that makes of them (K and V, as stored).
+    ``gather_rows``: where the gathered path cannot hold all the rows at
+    once it is asked for agreement that many rows at a time, and not
+    timed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+
+    rows, layers = len(lens), k_pool.shape[0]
+    tb, ln = jnp.asarray(tables), jnp.asarray(lens)
+    st = None if starts is None else jnp.asarray(starts)
+    ms, outs = {}, {}
+    for impl in ("pallas", "xla"):
+        one = jax.jit(lambda q, k, v, tb, ln, st, impl=impl:
+                      ragged_paged_attention(q, k, v, tb, ln, impl=impl,
+                                             layer=layers // 2, starts=st))
+        step = gather_rows if impl == "xla" and gather_rows else rows
+        outs[impl] = jnp.concatenate([
+            one(qq[i:i + step], k_pool, v_pool, tb[i:i + step],
+                ln[i:i + step], None if st is None else st[i:i + step])
+            for i in range(0, rows, step)]).block_until_ready()
+        if step < rows:
+            continue
+
+        # a tick's worth of calls in ONE program, each fed the one
+        # before: a dispatch from Python costs more than the kernel runs
+        def tick(q, k, v, impl=impl):
+            return jax.lax.fori_loop(
+                0, calls, lambda i, q: ragged_paged_attention(
+                    q, k, v, tb, ln, impl=impl, layer=i % layers,
+                    starts=st), q)
+
+        fn = jax.jit(tick)
+        fn(qq, k_pool, v_pool).block_until_ready()
+        t1 = time.perf_counter()
+        for _ in range(3):
+            out = fn(qq, k_pool, v_pool)
+        out.block_until_ready()
+        ms[impl] = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+    err, ok = _max_err(outs["pallas"], outs["xla"])
+    first = np.zeros_like(lens) if starts is None else np.asarray(starts)
+    live = int(sum(-(-int(n) // PAGE) - int(lo) // PAGE
+                   for n, lo in zip(lens, first)))
+    page_bytes = 2 * k_pool[0, 0].nbytes
+    line = {"phase": "kernels", "kernel": "paged_attention",
+            "timed": name, "rows": rows, "heads": qq.shape[1],
+            "kv_heads": k_pool.shape[3], "live_pages_read": live,
+            "table_pages": rows * tables.shape[1],
+            "kernel_ms_per_call": round(ms["pallas"], 4),
+            "kernel_gb_per_s_of_live_pages": round(
+                live * page_bytes / ms["pallas"] / 1e6, 1),
+            "max_abs_err": round(err, 5), "calls": calls}
+    if "xla" in ms:
+        line["gathered_ms_per_call"] = round(ms["xla"], 4)
+    emit(line)
+    check(ok, f"paged attention at the {name} tick's shapes disagrees "
+              f"with _gathered_attention: {err}")
 
 
 def time_ssd_step(seed: int, slots: int = 64, heads: int = 128,

@@ -235,6 +235,9 @@ _PAGE_BUFFER_BYTES = 4 * 1024 * 1024
 _GROUP_TOKENS = 64
 # query heads a K/V head up to which the fold stays on the VPU
 _VPU_GROUP_ROWS = 4
+# float32 scores of one fold on the MXU (every query head against every K/V
+# head's rows of the fold's tokens): a fold is as long as this lets it be
+_MXU_SCORE_BYTES = 1024 * 1024
 # tokens of a latent pool folded at a time: one MXU product a group
 _LATENT_GROUP_TOKENS = 256
 # what the query tiles over a latent pool may hold in VMEM: a tile's query,
@@ -331,9 +334,18 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     the pages in VMEM. Over ``_VPU_GROUP_ROWS`` query heads a K/V head
     that per-row work is the kernel's time (a page costs every group row
     a multiply and a lane reduction), and the fold becomes two MXU
-    products over the pages as they lie (``attend_pages_on_mxu``): the
-    pool's type for the operands, float32 for the sums and the softmax
-    state.
+    products over the pages as they lie (``attend_pages_on_mxu``): all
+    query rows against all K/V heads' rows of the fold's tokens, read
+    from the page buffer in the pool's type (no float32 copy of a page on
+    this path), float32 for the scores, the sums and the softmax state.
+    Such a fold is one chain of product, softmax and product, and what
+    it costs is mostly the chain's latency: it takes as many tokens as
+    its float32 scores may (``_MXU_SCORE_BYTES``; a whole block of 16
+    pages at 8 K/V heads of 128), whole groups only, the pages of a
+    block's last group that no copy wrote masked out of the second
+    product. One fold for many heads: a K/V head at a time (eight chains
+    of 6-row products a block where this is one) was measured beside it
+    and is slower at both of the shapes that take this path (PR 41).
 
     int8 KV (``k_scales``/``v_scales`` ``[L, num_pages, page_size]``, or
     without the layer axis beside a four-dimensional store): the pages
@@ -403,11 +415,19 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
                 None if starts is None else starts[part])
 
     def row_walk(q, block_tables, context_lens, starts):
+        walk_block, walk_fold = block, fold
+        if not latent and k_scales is None \
+                and q.shape[1] // kv_heads > _VPU_GROUP_ROWS:
+            # the fold on the MXU keeps no float32 copy of a page: as many
+            # tokens as its scores allow, whole groups and never a rest
+            walk_fold = max(1, min(block, _MXU_SCORE_BYTES // (
+                4 * q.shape[1] * kv_heads * page_size)))
+            walk_block = block // walk_fold * walk_fold
         return _paged_attention_call(
             q, k_pages, v_pages, block_tables, context_lens, layer,
             k_scales, v_scales, starts, scale=scale,
-            interpret=bool(interpret), block=block, group_pages=fold,
-            value_dim=value_dim)
+            interpret=bool(interpret), block=walk_block,
+            group_pages=walk_fold, value_dim=value_dim)
 
     n_chunk = min(int(n_chunk), q.shape[0])
     if n_chunk <= 0 or k_scales is not None:
@@ -525,8 +545,6 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                                for t in range(tokens)])
                 v = jnp.stack([v[t] * vs_ref[0, 0, first_token + t]
                                for t in range(tokens)])
-            if on_mxu:
-                return attend_pages_on_mxu(k, v, tokens, first_token)
             token = jax.lax.broadcasted_iota(
                 jnp.int32, (tokens, kv_heads, 1), 0)
             valid = token < ctx - first_token
@@ -582,24 +600,36 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
             m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
             l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-        def attend_pages_on_mxu(k, v, tokens, first_token):
+        def attend_pages_on_mxu(slot, p, n, first_token, fetched):
             """The same fold as two products on the MXU, for many query
-            heads a K/V head: the rows' ``group x kv_heads`` query rows
-            against ALL ``tokens x kv_heads`` key rows of the pages as
-            they lie (one product, the entries of another K/V head
-            masked: an eighth of it is used, and the MXU has it to
-            spare), the softmax state ``[group x kv_heads]`` rows wide,
-            and the probabilities against the value rows. Operands in the
-            pool's type (bf16 pages: one pass each, float32 sums), the
-            probabilities rounded to it for the second product."""
+            heads a K/V head: ``n`` (static) pages of ``slot`` from page
+            ``p`` on, of which the first ``fetched`` tokens were copied. The
+            rows' ``group x kv_heads`` query rows against ALL ``tokens x
+            kv_heads`` key rows of the pages as they lie, read in the pool's
+            type (one product, the entries of another K/V head masked: an
+            eighth of it is used at 8 K/V heads, and the MXU has it to
+            spare: what it charges is a key row loaded, once either way),
+            the softmax state ``[group x kv_heads]`` rows wide, and the
+            probabilities, rounded to the pool's type, against the value
+            rows (bf16 pages: one pass each, float32 sums). A fold is one
+            chain of product, softmax and product, each waiting for the one
+            before: at 64 tokens a fold that latency was the walk's time,
+            and a K/V head at a time is eight such chains (my chip runs,
+            PR 41), so a fold is as long as ``_MXU_SCORE_BYTES`` lets it."""
+            tokens = n * page_size
             rows = group * kv_heads
             cols = tokens * kv_heads
             exact = jax.lax.Precision.HIGHEST \
                 if k_pages.dtype == jnp.float32 else None
             qb = q_ref[0].astype(jnp.float32).reshape(rows, d) \
                 .astype(k_pages.dtype)
-            kb = k.reshape(cols, d).astype(k_pages.dtype)
-            vb = v.reshape(cols, d).astype(k_pages.dtype)
+            kb = k_buf.at[slot, pl.ds(p, n)].reshape(cols, d)[...]
+            vb = v_buf.at[slot, pl.ds(p, n)].reshape(cols, d)[...]
+            # what this block's copies did not write is whatever the slot
+            # held: keep it out of the second product
+            vb = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, (cols, 1), 0) < fetched * kv_heads, vb,
+                jnp.zeros_like(vb))
             s = jax.lax.dot_general(
                 qb, kb, (((1,), (1,)), ((), ())), precision=exact,
                 preferred_element_type=jnp.float32) * scale  # [rows, cols]
@@ -665,17 +695,18 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                         return p + n
                     return body
 
-                def fold_latent(i, p):
-                    attend_latent(slot, p, group_pages,
-                                  (page0 + first_page + p) * page_size,
-                                  (here - p) * page_size)
+                def fold_whole(i, p):
+                    (attend_latent if latent else attend_pages_on_mxu)(
+                        slot, p, group_pages,
+                        (page0 + first_page + p) * page_size,
+                        (here - p) * page_size)
                     return p + group_pages
 
-                if latent:
+                if latent or on_mxu:
                     # whole groups only (``block`` is a multiple of one):
                     # the last one's unfetched pages are masked
                     jax.lax.fori_loop(0, pl.cdiv(here, group_pages),
-                                      fold_latent, 0)
+                                      fold_whole, 0)
                     return 1 - slot
                 # whole groups of pages first, the rest a page at a time
                 p = jax.lax.fori_loop(0, here // group_pages,

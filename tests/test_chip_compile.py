@@ -198,6 +198,37 @@ def test_mixed_ticks_attention_call_compiles_for_v5e(one_chip, monkeypatch,
         <= 6 * rows * heads * HEAD_DIM * 2
 
 
+@pytest.mark.parametrize("bound", [False, True], ids=["whole", "windowed"])
+@pytest.mark.parametrize("heads,layers,pages", [(48, 3, 18433),
+                                                (72, 9, 1569)],
+                         ids=["full-6-a-kv-head", "window-9-a-kv-head"])
+def test_decode_rows_of_many_heads_a_kv_head_compile_for_v5e(
+        one_chip, heads, layers, pages, bound):
+    """A decode tick's attention call in ``agent_closed_swa`` (32 rows, 48
+    or 72 query heads over 8 K/V heads of 128, bf16 pages of 16 tokens,
+    tables of 576 pages, with and without a lower bound a row): more than
+    ``_VPU_GROUP_ROWS`` heads a K/V head, so the row walk folds on the MXU,
+    a block of 16 pages at a time: the operands a view of some pages of a
+    buffer slot, at a traced page, read as ``[tokens x kv_heads, d]`` in the
+    pool's type, the scores ``[48 | 72, 2048]`` float32. One kernel, and
+    beside it no more than the query's and the result's transposes."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, kv_heads, columns = 32, 8, 576
+    pool = sds((layers, pages, PAGE, kv_heads, HEAD_DIM), jnp.bfloat16)
+    ints = sds((rows,), jnp.int32)
+    compiled = _compiled(
+        lambda q, k, v, t, n, s, i: paged_attention_kernel(
+            q, k, v, t, n, layer=i, interpret=False,
+            starts=s if bound else None),
+        sds((rows, heads, HEAD_DIM), jnp.bfloat16), pool, pool,
+        sds((rows, columns), jnp.int32), ints, ints, sds((), jnp.int32))
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 2 * rows * heads * HEAD_DIM * 2
+
+
 def test_a_decode_tick_lowers_without_the_tile_kernel(one_chip, monkeypatch):
     """A ``decode_fn`` has no prompt rows (``n_chunk`` 0): its lowered text
     names the row walk and not the tile kernel

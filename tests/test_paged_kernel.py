@@ -293,28 +293,93 @@ def test_a_windowed_row_fetches_no_page_before_its_window(block):
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("heads", [12, 18], ids=["6-a-kv-head", "9-a-kv-head"])
-def test_many_heads_a_kv_head_fold_on_the_mxu_in_the_pools_type(block,
-                                                                heads):
+@pytest.fixture(params=[None, 3], ids=["fold-a-block", "fold-3-pages"])
+def mxu_fold(request, monkeypatch, block):
+    """Pages of a fold on the MXU: what the shapes give (these tiny blocks
+    whole), or three, so that the one-block table is two folds and its last
+    one is a page short. ``mxu_fold(heads, kv_heads)`` sets the scores'
+    budget for such a call and returns the tokens of a fold."""
+    if request.param is not None and block <= request.param:
+        pytest.skip("a block of two pages is one fold either way")
+
+    def tokens_of_a_fold(heads, kv_heads):
+        if request.param is not None:
+            monkeypatch.setattr(pa, "_MXU_SCORE_BYTES",
+                                request.param * 4 * heads * kv_heads * PS)
+        return (request.param or block) * PS
+    yield tokens_of_a_fold
+    # each case is an interpreted kernel of its own shapes that no other
+    # test runs again: let the executables go (with them all kept, a process
+    # that runs this file whole dies in XLA:CPU's compiler ~60 tests later)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("bound", [False, True], ids=["whole", "windowed"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2),
+                                       (jnp.float32, 2e-5)],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("kv_heads", [2, 8])
+@pytest.mark.parametrize("group", [6, 9], ids=["6-a-kv-head", "9-a-kv-head"])
+def test_many_heads_a_kv_head_fold_on_the_mxu_in_the_pools_type(
+        block, mxu_fold, group, kv_heads, dtype, tol, bound):
     """Over ``_VPU_GROUP_ROWS`` query heads a K/V head the fold is two
-    products in the pool's type: with bf16 pages the queries and the
-    probabilities are rounded to bf16 (float32 sums), so the result lies
-    within bf16's rounding of the float32 reference over the same pages;
-    with a lower bound too. Few heads a K/V head stay on the VPU path."""
-    assert heads // 2 > pa._VPU_GROUP_ROWS >= 4
-    k, v = pool(7, 2, jnp.bfloat16)
-    q = queries(5, heads, seed=8).astype(jnp.bfloat16)
-    tables = tables_for(5, seed=9)
-    lens = jnp.asarray([11, 0, MAX_LEN, 6, 29], jnp.int32)
-    starts = jnp.asarray([0, 0, MAX_LEN - 9, 2, 13], jnp.int32)
-    for bound in (None, starts):
-        got = np.asarray(paged_attention_kernel(
-            q, k, v, tables, lens, layer=1, starts=bound), np.float32)
-        want = np.asarray(ragged_paged_attention_reference(
-            q, pa.kv_layer(k, 1), pa.kv_layer(v, 1), tables, lens,
-            starts=bound))
-        np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
-        assert np.abs(got - want).max() < 2e-2
+    products in the pool's type: with bf16 pages the queries and
+    the probabilities are rounded to bf16 (float32 sums), so the result lies
+    within bf16's rounding of the float32 reference over the same pages; a
+    float32 pool is exact. Rows of no token, one, one short of a page,
+    exactly a fold, one over, a window that begins inside a page, and the
+    whole table; with a lower bound too. Few heads a K/V head stay on the
+    VPU path."""
+    assert group > pa._VPU_GROUP_ROWS >= 4
+    fold = mxu_fold(group * kv_heads, kv_heads)
+    k, v = pool(7, kv_heads, dtype)
+    lens = jnp.asarray([0, 1, PS - 1, fold, min(fold + 1, MAX_LEN), 22,
+                        MAX_LEN], jnp.int32)
+    starts = jnp.asarray([0, 0, 1, 0, 2, 9, MAX_LEN - 9], jnp.int32) \
+        if bound else None
+    rows = lens.shape[0]
+    q = queries(rows, group * kv_heads, seed=8).astype(dtype)
+    tables = tables_for(rows, seed=9)
+    got = np.asarray(paged_attention_kernel(
+        q, k, v, tables, lens, layer=1, starts=starts), np.float32)
+    want = np.asarray(ragged_paged_attention_reference(
+        q, pa.kv_layer(k, 1), pa.kv_layer(v, 1), tables, lens,
+        starts=starts))
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    assert np.abs(got - want).max() < tol
+    assert np.abs(got[0]).max() == 0.0          # nothing to attend: zeros
+
+
+def test_a_fold_on_the_mxu_reads_nothing_its_row_did_not_fetch(block,
+                                                               mxu_fold):
+    """A fold takes whole groups of pages, so the last one of a row reaches
+    past what the row's copies wrote: into the STALE TAIL of the buffer
+    slot, here the pages of a row before it whose every value is NaN, and
+    never into a page beyond the row's limit (those table entries name a
+    page of NaN too). Neither changes a bit of the other rows' output."""
+    kv_heads, group = 2, 6
+    mxu_fold(group * kv_heads, kv_heads)
+    k, v = pool(11, kv_heads)
+    q = queries(6, group * kv_heads, seed=12)
+    lens = np.asarray([MAX_LEN, 5, 1, MAX_LEN - 3, 2, 9], np.int32)
+    # rows 0 and 3 fill both slots with NaN; every entry past a limit too
+    healthy, poisoned = [1, 2, 4, 5], list(range(1, 17)) + [PAGES - 1]
+    tables = np.full((6, PAGES_PER_SEQ), PAGES - 1, np.int32)
+    tables[0], tables[3] = np.arange(1, 9), np.arange(9, 17)
+    for r in healthy:
+        live = -(-lens[r] // PS)
+        tables[r, :live] = 17 + 3 * r + np.arange(live)
+    want = reference(q, k, v, jnp.asarray(tables), jnp.asarray(lens), layer=2)
+    clean = np.asarray(paged_attention_kernel(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), layer=2))
+    k = k.at[:, np.asarray(poisoned)].set(jnp.nan)
+    v = v.at[:, np.asarray(poisoned)].set(jnp.nan)
+    got = np.asarray(paged_attention_kernel(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), layer=2))
+    assert np.isnan(got[0]).all() and np.isnan(got[3]).all()
+    np.testing.assert_array_equal(got[healthy], clean[healthy])
+    np.testing.assert_allclose(got[healthy], want[healthy], atol=2e-5,
+                               rtol=2e-5)
 
 
 # -- query tiles (``n_chunk=``): a chunk of packed prompt rows ---------------
@@ -576,7 +641,7 @@ def test_a_mixed_ticks_chunk_reads_its_sequence_once_a_tile():
 
 
 @pytest.mark.parametrize("heads,kv_heads,bound,digest", [
-    (4, 4, False, "377f69c93ec7f687"), (12, 2, True, "ed082b9229dd3334")],
+    (4, 4, False, "377f69c93ec7f687"), (12, 2, True, "dcc9b006dfd50196")],
     ids=["vpu-fold", "mxu-fold-with-a-lower-bound"])
 def test_a_call_without_prompt_rows_is_the_program_of_pr_35(
         monkeypatch, heads, kv_heads, bound, digest):
@@ -584,7 +649,10 @@ def test_a_call_without_prompt_rows_is_the_program_of_pr_35(
     that call (the row walk, compiled, not interpreted) is, to the letter,
     the one the commit before the tile path traced (PR 36's parent, digests
     taken from a checkout of it). A later edit to the row walk moves these
-    on purpose and names itself here."""
+    on purpose and names itself here: PR 41 moved the second (from
+    ``ed082b9229dd3334``), the fold for more than ``_VPU_GROUP_ROWS`` query
+    heads a K/V head, which now reads its operands in the pool's type and
+    takes a block at a time; the VPU fold's is PR 36's parent's still."""
     import hashlib
     monkeypatch.setattr(
         importlib.import_module("paddle_tpu.ops.flash_attention"),
