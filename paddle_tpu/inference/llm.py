@@ -59,7 +59,7 @@ from ..ops.paged_attention import (KV_DTYPES, QuantizedKV, _split_kv,
                                    kv_nbytes, kv_page_size,
                                    kv_scale_nbytes, kv_zeros)
 from ..reliability import faults as _faults
-from .page_pool import ChunkRows, PagePool, cache_groups
+from .page_pool import ChunkRows, NoInt8Form, PagePool, cache_groups
 from ..reliability.retry import Deadline, DeadlineExceeded, as_deadline
 
 # How every engine program is compiled for a TPU. XLA:TPU's memory-space
@@ -650,7 +650,14 @@ class CacheGroupUnsupported(ValueError):
     payload's geometry is a K and a V block a page),
     ``"speculative_verify"`` (the verify window attends through the
     K/V form of the gathered path); ``"prefix_reuse"`` is switched off
-    (``/statusz``)."""
+    (``/statusz``).
+
+    Or of a group whose values are of another width than its keys
+    (``CacheGroup.v_head_dim``) or whose layers carry a softmax sink
+    (``CacheGroup.sink``): ``"int8_pages"`` (no attention path serves
+    either quantized; the pool's refusal, ``page_pool.NoInt8Form``, said
+    by name), and for unequal widths ``"kv_page_migration"`` (the
+    payload's geometry is one ``head_dim``)."""
 
     WINDOW_MODES = ("prefix_reuse", "kv_page_migration",
                     "speculative_verify", "fused_slab", "lookahead")
@@ -1348,9 +1355,13 @@ class LLMEngine:
                         f"has a latent cache group (one row a token, no "
                         f"V): {why}")
             prefix_cache = False
-        self._pool = PagePool(net.kv_cache_spec(), num_pages, page_size,
-                              max_seqs, self.pages_per_seq, kv_dtype,
-                              self.prefill_chunk)
+        try:
+            self._pool = PagePool(net.kv_cache_spec(), num_pages, page_size,
+                                  max_seqs, self.pages_per_seq, kv_dtype,
+                                  self.prefill_chunk)
+        except NoInt8Form as e:
+            raise CacheGroupUnsupported(
+                "int8_pages", f"int8_pages does not compose with {e}") from e
         self.context_lens = np.zeros((max_seqs,), np.int32)
         self.temperatures = np.zeros((max_seqs,), np.float32)
         if self._pool.windowed:
@@ -2256,6 +2267,14 @@ class LLMEngine:
                 f"{what} does not compose with a model that has a latent "
                 f"cache group: the kv_pages/v1 payload is a K and a V "
                 f"block a page, and a latent page has one block and no V")
+        for g in self._pool.groups:
+            if g.v_head_dim != g.group.head_dim:
+                raise CacheGroupUnsupported(
+                    "kv_page_migration",
+                    f"{what} does not compose with cache group {g.name!r}, "
+                    f"whose values are of another width than its keys "
+                    f"({g.group.head_dim} / {g.v_head_dim}): the "
+                    f"kv_pages/v1 payload's geometry is one head_dim")
 
     def import_pages(self, payload: dict, timeout: float = 60.0) -> dict:
         """Verify and install a ``kv_pages/v1`` payload as shared,
@@ -3556,7 +3575,9 @@ class LLMEngine:
             for g in self._pool.groups:
                 groups[g.name].update(
                     bytes_held=int(g.held[slots].sum()) * g.page_bytes,
-                    page_bytes=g.page_bytes)
+                    page_bytes=g.page_bytes,
+                    k_row_bytes=g.k_page_bytes // g.page_size,
+                    v_row_bytes=g.v_page_bytes // g.page_size)
             ph.set_attr("kv_groups", groups) \
                 .set_attr("context_tokens",
                           int(self.context_lens[slots].sum())

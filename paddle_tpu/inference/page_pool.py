@@ -10,7 +10,10 @@ arrays, its own free list, its own block table ``[slots, pages_per_seq]``
 (``CacheGroup.value_dim``) keeps ONE array and no V: a token's row
 ``[head_dim]`` is the key of every query head and its first ``value_dim``
 columns are the value, so its pages are ``[layers, pages, page_size,
-head_dim]`` and ``v_pages`` is None. A group WITH A WINDOW keeps,
+head_dim]`` and ``v_pages`` is None. A group whose VALUES ARE OF ANOTHER
+WIDTH than its keys (``CacheGroup.v_head_dim``) keeps its V array ``[...,
+kv_heads, v_head_dim]`` beside K's ``[..., kv_heads, head_dim]``; its bytes
+are counted as stored, K and V each at its own width. A group WITH A WINDOW keeps,
 a sequence, only the pages a row can still attend: once a page lies wholly
 behind ``next position - window`` it goes back to the free list
 (:meth:`PagePool.release_behind`), so a slot never holds more than
@@ -31,7 +34,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,7 +50,13 @@ class CacheGroup(NamedTuple):
     without a head axis. A token's cached row is ``head_dim`` values wide
     (as stored, padding included), every query head attends the same row,
     and the value is the row's first ``value_dim`` columns: what a latent
-    (compressed) attention caches. ``kv_heads`` is then 1."""
+    (compressed) attention caches. ``kv_heads`` is then 1. ``v_head_dim``
+    (None: as wide as ``head_dim``): the last dimension of the V array where
+    a head's value is of another width than its key; ``head_dim`` is then
+    the key's, AS STORED (a model pads it to whole lanes itself). ``sink``:
+    the group's layers carry a softmax sink a query head
+    (``ragged_paged_attention(sinks=)``); the pool stores nothing for it,
+    ``/statusz`` says so and an int8 pool is refused (:class:`NoInt8Form`)."""
 
     name: str
     layers: int
@@ -56,6 +64,15 @@ class CacheGroup(NamedTuple):
     head_dim: int
     window: Optional[int] = None
     value_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    sink: bool = False
+
+
+class NoInt8Form(ValueError):
+    """An int8 pool was asked of a cache group no attention path serves
+    quantized (a softmax sink, values of another width than the keys). The
+    decision is the pool's; the engine says it by name
+    (``CacheGroupUnsupported``, mechanism ``int8_pages``)."""
 
 
 class ChunkRows(NamedTuple):
@@ -118,10 +135,27 @@ class GroupPool:
                 f"cache group {group.name!r}: a group without a V has "
                 f"kv_heads 1 and no int8 form (a scale a row of "
                 f"kv_heads x head_dim is not a scale a latent row)")
-        self.v_pages = None if self.latent else jax.tree_util.tree_map(
-            jnp.zeros_like, self.k_pages)
+        if self.latent and group.v_head_dim is not None:
+            raise ValueError(
+                f"cache group {group.name!r}: a group without a V has no "
+                f"v_head_dim (its value is value_dim columns of its row)")
+        self.v_head_dim = group.v_head_dim or group.head_dim
+        if isinstance(self.k_pages, QuantizedKV) and (
+                group.sink or self.v_head_dim != group.head_dim):
+            raise NoInt8Form(
+                f"cache group {group.name!r}: no int8 form of a group with "
+                f"a softmax sink or with values of another width than its "
+                f"keys ({group.head_dim} / {self.v_head_dim}): no attention "
+                f"path serves one quantized")
+        self.v_pages = None if self.latent else kv_zeros(
+            (group.layers, num_pages, page_size, group.kv_heads,
+             self.v_head_dim), kv_dtype)
         stores = [self.k_pages] + ([] if self.latent else [self.v_pages])
-        self.page_bytes = sum(map(kv_nbytes, stores)) // num_pages
+        # what ONE page stores, K and V each at its own width
+        self.k_page_bytes = kv_nbytes(self.k_pages) // num_pages
+        self.v_page_bytes = 0 if self.latent \
+            else kv_nbytes(self.v_pages) // num_pages
+        self.page_bytes = self.k_page_bytes + self.v_page_bytes
         self.scale_bytes = sum(map(kv_scale_nbytes, stores)) // num_pages
         self.free: List[int] = list(range(num_pages - 1, 0, -1))
         self.tables = np.zeros((max_seqs, pages_per_seq), np.int32)
@@ -139,7 +173,13 @@ class GroupPool:
     def status(self) -> dict:
         return {"name": self.name, "layers": self.group.layers,
                 "window": self.window, "value_dim": self.group.value_dim,
+                "kv_heads": self.group.kv_heads,
+                "head_dim": self.group.head_dim,
+                "v_head_dim": None if self.latent else self.v_head_dim,
+                "sink": self.group.sink,
                 "row_bytes": self.page_bytes // self.page_size,
+                "k_row_bytes": self.k_page_bytes // self.page_size,
+                "v_row_bytes": self.v_page_bytes // self.page_size,
                 "page_bytes": self.page_bytes,
                 "pages": self.num_pages, "in_use": self.in_use,
                 "ring_pages": self.ring, "released": self.n_released}

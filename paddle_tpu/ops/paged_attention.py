@@ -246,25 +246,30 @@ _LATENT_TILE_VMEM_BYTES = 64 << 20
 
 
 def _pages_per_block(page_size: int, kv_heads: Optional[int], d: int, dtype,
-                     pages_per_seq: int, pools: int = 2) -> int:
+                     pages_per_seq: int, pools: int = 2,
+                     dv: Optional[int] = None) -> int:
     """Pages the kernel moves a step: as many as the page buffers (one a
     pool the walk reads, ``pools``: K and V, or the one pool of a latent
     group; each double-buffered) hold in ``_PAGE_BUFFER_BYTES``, a page
     counted at its tiled size in VMEM as it is STORED (kv heads padded to
     the dtype's sublane packing, d to the lane width; ``kv_heads`` None: a
     page without a head axis, ``[page_size, d]``, its tokens on the
-    sublanes). Follows the shapes: 16 pages of 16 tokens for a bf16 pool
-    with 16 heads of 128."""
+    sublanes). ``dv``: the V pool's last dimension where it is not the K
+    pool's (a page of each is then counted at its own width). Follows the
+    shapes: 16 pages of 16 tokens for a bf16 pool with 16 heads of 128."""
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * max(1, 4 // itemsize)
-    lanes = -(-d // _LANES) * _LANES
-    if kv_heads is None:
-        page_bytes = -(-page_size // sublanes) * sublanes * lanes * itemsize
-    else:
-        page_bytes = (page_size * -(-kv_heads // sublanes) * sublanes
-                      * lanes * itemsize)
-    return max(1, min(pages_per_seq,
-                      _PAGE_BUFFER_BYTES // (2 * pools * page_bytes)))
+
+    def page_bytes(width):
+        lanes = -(-width // _LANES) * _LANES
+        if kv_heads is None:
+            return -(-page_size // sublanes) * sublanes * lanes * itemsize
+        return (page_size * -(-kv_heads // sublanes) * sublanes
+                * lanes * itemsize)
+
+    widths = [d] * pools if dv is None else [d, dv]
+    return max(1, min(pages_per_seq, _PAGE_BUFFER_BYTES
+                      // (2 * sum(map(page_bytes, widths)))))
 
 
 def _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf, sems):
@@ -273,12 +278,21 @@ def _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf, sems):
     from column ``first_page`` of ``row``'s table, out of layer
     ``meta_ref[0]`` of the stacked pool into buffer slot ``slot``. A latent
     group has ONE pool (``v_hbm`` None): its page is copied once and read
-    for the scores and for the values."""
+    for the scores and for the values. ``k_buf`` a TUPLE of buffers: the
+    key's lane tiles one buffer each (keys wider than 128 lanes under the
+    query tiles, whose strided loads want a buffer one lane tile wide), a
+    copy a lane tile a page, out of the same rows of the pool."""
     def copies(row, first_page, n, slot, do):
         def page(p, carry):
             src = (meta_ref[0], tbl_ref[row, first_page + p])
-            do(pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, p],
-                                     sems.at[0, slot]))
+            if isinstance(k_buf, tuple):
+                for c, column in enumerate(k_buf):
+                    do(pltpu.make_async_copy(
+                        k_hbm.at[src].at[:, :, pl.ds(c * _LANES, _LANES)],
+                        column.at[slot, p], sems.at[0, slot]))
+            else:
+                do(pltpu.make_async_copy(k_hbm.at[src], k_buf.at[slot, p],
+                                         sems.at[0, slot]))
             if v_hbm is not None:
                 do(pltpu.make_async_copy(v_hbm.at[src], v_buf.at[slot, p],
                                          sems.at[1, slot]))
@@ -289,13 +303,28 @@ def _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf, sems):
     return copies
 
 
+def _refuse_unserved(latent: bool, quantized: bool, sinks, widths) -> None:
+    """What no path serves, refused by name and never half-served: a sink
+    or unequal K and V widths over an int8 pool (its scale a row is of ONE
+    width, and its fold has no start state), a sink over a latent pool (no
+    model asks for one)."""
+    if sinks is not None and (quantized or latent):
+        raise ValueError("sinks over " + ("an int8" if quantized else
+                                          "a latent")
+                         + " pool: no path serves a softmax sink there")
+    if quantized and widths is not None and widths[0] != widths[1]:
+        raise ValueError(f"an int8 pool with keys of {widths[0]} beside "
+                         f"values of {widths[1]}: no path serves unequal "
+                         f"widths quantized")
+
+
 def paged_attention_kernel(q, k_pages, v_pages, block_tables,
                            context_lens, layer=None,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
                            k_scales=None, v_scales=None, starts=None,
                            n_chunk: int = 0,
-                           value_dim: Optional[int] = None):
+                           value_dim: Optional[int] = None, sinks=None):
     """Fused Pallas attention over the paged KV pool (Ragged-Paged-
     Attention lineage): every row of ``q`` attends the first
     ``context_lens[row]`` cached positions of the sequence whose block
@@ -379,6 +408,19 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     (every query head against the rows as they lie: tokens on sublanes,
     no head axis to pad).
 
+    K AND V OF DIFFERENT WIDTHS: ``v_pages`` ``[L, num_pages, page_size,
+    kv_heads, dv]`` beside keys of ``d``: ``q`` [rows, heads, d] -> [rows,
+    heads, dv]. Each pool has its own page buffer and the sums are ``dv``
+    wide; nothing else differs (equal widths: the program of before).
+
+    ``sinks`` [heads] float32 (None: none, and the program of today): query
+    head ``h`` has a logit ``sinks[h]`` that joins its softmax's
+    denominator and carries no value, ``o_h = sum_j e^{s_hj - m} v_j /
+    (e^{sinks[h] - m} + sum_j e^{s_hj - m})``. It is the START STATE of the
+    online softmax in both walks (``m = sinks[h], l = 1, acc = 0`` where
+    ``-inf, 0, 0`` stand), so every fold after it is the fold of before. A
+    row with nothing to attend (limit 0) is still a zero row.
+
     ``interpret`` defaults to the module switch
     ``flash_attention.INTERPRET`` (False: the kernel compiles for the
     TPU or raises).
@@ -389,6 +431,9 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     if latent and (value_dim is None or k_scales is not None):
         raise ValueError("a pool without V pages is a latent pool: it "
                          "needs value_dim and has no int8 form")
+    _refuse_unserved(latent, k_scales is not None, sinks,
+                     None if latent else (k_pages.shape[-1],
+                                          v_pages.shape[-1]))
     if k_pages.ndim == (3 if latent else 4):
         k_pages = k_pages[None]
         v_pages = None if latent else v_pages[None]
@@ -405,7 +450,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
     else:
         page_size, kv_heads, d = k_pages.shape[2:]
         block = _pages_per_block(page_size, kv_heads, d, k_pages.dtype,
-                                 block_tables.shape[1])
+                                 block_tables.shape[1], 2,
+                                 v_pages.shape[-1])
         fold = max(1, min(block, _GROUP_TOKENS // page_size))
     layer = jnp.asarray(layer, jnp.int32)
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -425,7 +471,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
             walk_block = block // walk_fold * walk_fold
         return _paged_attention_call(
             q, k_pages, v_pages, block_tables, context_lens, layer,
-            k_scales, v_scales, starts, scale=scale,
+            k_scales, v_scales, starts, sinks, scale=scale,
             interpret=bool(interpret), block=walk_block,
             group_pages=walk_fold, value_dim=value_dim)
 
@@ -434,8 +480,9 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
         return row_walk(q, block_tables, context_lens, starts)
     cq, ctables, clens, cstarts = rows_of(slice(0, n_chunk))
     tiled = _paged_attention_chunk_call(
-        cq, k_pages, v_pages, ctables, clens, layer, cstarts, scale=scale,
-        interpret=bool(interpret), block=fold if latent else block,
+        cq, k_pages, v_pages, ctables, clens, layer, cstarts, sinks,
+        scale=scale, interpret=bool(interpret),
+        block=fold if latent else block,
         qb=chunk_tile_rows(n_chunk), value_dim=value_dim)
     if n_chunk == q.shape[0]:
         return tiled
@@ -446,20 +493,22 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
                                              "group_pages", "value_dim"))
 def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
-                          layer, k_scales, v_scales, starts=None, *, scale,
-                          interpret, block, group_pages, value_dim=None):
+                          layer, k_scales, v_scales, starts=None, sinks=None,
+                          *, scale, interpret, block, group_pages,
+                          value_dim=None):
     """:func:`paged_attention_kernel` on the stacked pool with a traced
     ``layer``. Jitted so that an engine program, which calls it once a
     layer with the same shapes, traces and lowers the kernel once."""
     quantized = k_scales is not None
     windowed = starts is not None
+    sunk = sinks is not None
     latent = v_pages is None
     rows, n_heads, d = q.shape
     if latent:
         page_size, kv_heads, dv = k_pages.shape[2], 1, value_dim
     else:
         _, _, page_size, kv_heads, _ = k_pages.shape
-        dv = d
+        dv = v_pages.shape[-1]
     pages_per_seq = block_tables.shape[1]
     group = n_heads // kv_heads
     # few query heads a K/V head: a broadcast-multiply and a lane
@@ -499,6 +548,8 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
             if quantized:
                 ks_ref, vs_ref = rest[:2]
                 rest = rest[2:]
+            if sunk:
+                sink_ref, rest = rest[0], rest[1:]
             (o_ref, k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
              l_ref) = rest
         r = pl.program_id(0)
@@ -538,7 +589,7 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
             k = k_buf[slot, pl.ds(p, n)].astype(jnp.float32).reshape(
                 tokens, kv_heads, d)
             v = v_buf[slot, pl.ds(p, n)].astype(jnp.float32).reshape(
-                tokens, kv_heads, d)
+                tokens, kv_heads, dv)
             if quantized:
                 # dequantize in VMEM: one SMEM scalar per page row
                 k = jnp.stack([k[t] * ks_ref[0, 0, first_token + t]
@@ -624,7 +675,7 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
             qb = q_ref[0].astype(jnp.float32).reshape(rows, d) \
                 .astype(k_pages.dtype)
             kb = k_buf.at[slot, pl.ds(p, n)].reshape(cols, d)[...]
-            vb = v_buf.at[slot, pl.ds(p, n)].reshape(cols, d)[...]
+            vb = v_buf.at[slot, pl.ds(p, n)].reshape(cols, dv)[...]
             # what this block's copies did not write is whatever the slot
             # held: keep it out of the second product
             vb = jnp.where(jax.lax.broadcasted_iota(
@@ -647,10 +698,10 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
             alpha = jnp.exp(m_prev - m_new)
             p_ = jnp.where(valid, jnp.exp(s - m_new), 0.0)
             l_new = alpha * l_prev + jnp.sum(p_, axis=1, keepdims=True)
-            acc = acc_ref[...].reshape(rows, d) * alpha + jax.lax.dot_general(
+            acc = acc_ref[...].reshape(rows, dv) * alpha + jax.lax.dot_general(
                 p_.astype(k_pages.dtype), vb, (((1,), (0,)), ((), ())),
                 precision=exact, preferred_element_type=jnp.float32)
-            acc_ref[...] = acc.reshape(group, kv_heads, d)
+            acc_ref[...] = acc.reshape(group, kv_heads, dv)
             m_ref[...] = jnp.broadcast_to(m_new, (rows, _LANES)).reshape(
                 m_ref.shape)
             l_ref[...] = jnp.broadcast_to(l_new, (rows, _LANES)).reshape(
@@ -668,8 +719,12 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
                 start(r, 0, 0)
 
             acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[...] = jnp.zeros_like(l_ref)
+            if sunk:    # the sink is the softmax's start state
+                m_ref[...] = sink_ref[...]
+                l_ref[...] = jnp.ones_like(l_ref)
+            else:
+                m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+                l_ref[...] = jnp.zeros_like(l_ref)
 
             def attend_block(blk, slot):
                 @pl.when(blk + 1 < n_blocks)
@@ -749,25 +804,37 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
 
         in_specs += [scale_spec, scale_spec]
         operands += [row_scales(k_scales), row_scales(v_scales)]
-    page_buffer = pltpu.VMEM((2, block, page_size, kv_heads, d),
-                             k_pages.dtype)
+    if sunk:
+        # [group, kv_heads, lanes], as the softmax state lies: the logit of
+        # query head ``kv * group + g`` at ``[g, kv]``
+        in_specs.append(pl.BlockSpec((group, kv_heads, _LANES),
+                                     lambda r, *_: (0, 0, 0)))
+        operands.append(jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(kv_heads, group).T[..., None],
+            (group, kv_heads, _LANES)))
+
+    def page_buffer(width):
+        return pltpu.VMEM((2, block, page_size, kv_heads, width),
+                          k_pages.dtype)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(rows,),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=pl.BlockSpec((1, group, kv_heads, dv),
+                               lambda r, *_: (r, 0, 0, 0)),
         scratch_shapes=[
-            page_buffer, page_buffer,
+            page_buffer(d), page_buffer(dv),
             pltpu.SemaphoreType.DMA((2, 2)),      # (K | V, slot)
             pltpu.SMEM((1,), jnp.int32),          # slot of the block due
-            pltpu.VMEM((group, kv_heads, d), jnp.float32),
+            pltpu.VMEM((group, kv_heads, dv), jnp.float32),
             pltpu.VMEM((group, kv_heads, _LANES), jnp.float32),
             pltpu.VMEM((group, kv_heads, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, group, kv_heads, d),
+        out_shape=jax.ShapeDtypeStruct((rows, group, kv_heads, dv),
                                        q.dtype),
         # sequential: the page buffers, their semaphores and the slot
         # carry a prefetched block from one row's step into the next
@@ -776,7 +843,7 @@ def _paged_attention_call(q, k_pages, v_pages, block_tables, context_lens,
         interpret=interpret,
         name="paged_attention",
     )(*operands)
-    return out.transpose(0, 2, 1, 3).reshape(rows, n_heads, d)
+    return out.transpose(0, 2, 1, 3).reshape(rows, n_heads, dv)
 
 
 def _latent_row_walk(kernel, prefetch, q, pages, *, block, dv, interpret):
@@ -883,8 +950,9 @@ def _head_rows(buf, slot, kv_heads: int, tokens: int):
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "block",
                                              "qb", "value_dim"))
 def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
-                                context_lens, layer, starts=None, *, scale,
-                                interpret, block, qb, value_dim=None):
+                                context_lens, layer, starts=None, sinks=None,
+                                *, scale, interpret, block, qb,
+                                value_dim=None):
     """The QUERY-TILE path of :func:`paged_attention_kernel`: ``q`` holds
     packed prompt rows only (the rows of one sequence contiguous and in
     order), over the stacked unquantized pool with a traced ``layer``.
@@ -912,18 +980,30 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
     as they lie, all ``qb x heads`` query rows of a tile against them, the
     values their first ``value_dim`` columns; ``block`` is then one group of
     ``_LATENT_GROUP_TOKENS`` (a tile's scores are ``qb x heads`` rows
-    wide)."""
+    wide).
+
+    ``sinks`` [heads]: a tile's start state, as in the row walk (``m`` a
+    query row its head's logit, ``l`` 1). V pages of another width than K's:
+    a buffer each, the sums as wide as V. KEYS WIDER THAN A LANE TILE (whole
+    tiles: 256): :func:`_head_rows`' strided loads want a buffer whose rows
+    are one lane tile, so the K page buffer is one buffer a lane tile, a
+    page's copy one a tile out of the same rows, and a head's key rows are
+    its tiles side by side again."""
     windowed = starts is not None
+    sunk = sinks is not None
     latent = v_pages is None
     rows, n_heads, d = q.shape
     if latent:
         page_size, kv_heads, dv = k_pages.shape[2], 1, value_dim
     else:
         _, _, page_size, kv_heads, _ = k_pages.shape
-        dv = d
+        dv = v_pages.shape[-1]
     pages_per_seq = block_tables.shape[1]
     group = n_heads // kv_heads
     n_win = -(-rows // qb)
+    # lane tiles of a key, each with a page buffer of its own; 1: one buffer
+    k_cols = d // _LANES if not latent and d > _LANES and d % _LANES == 0 \
+        else 1
     tile_rows = qb * group            # query rows a K/V head a tile
     tokens = block * page_size
     exact = jax.lax.Precision.HIGHEST \
@@ -960,8 +1040,13 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
             v_hbm = v_buf = None
             o_ref, k_buf, sems, slot_ref, acc_ref, m_ref, l_ref = rest
         else:
-            (v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, acc_ref, m_ref,
-             l_ref) = rest
+            v_hbm, rest = rest[0], rest[1:]
+            if sunk:
+                sink_ref, rest = rest[0], rest[1:]
+            o_ref, rest = rest[0], rest[1:]
+            k_buf = rest[0] if k_cols == 1 else tuple(rest[:k_cols])
+            (v_buf, sems, slot_ref, acc_ref, m_ref,
+             l_ref) = rest[k_cols:]
         w = pl.program_id(0)
 
         def head_rows(slot):
@@ -970,10 +1055,13 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
                 rows_ = k_buf[slot].reshape(tokens, d)
                 yield 0, rows_, rows_[:, :dv]
                 return
-            for (h, kh), (_, vh) in zip(
-                    _head_rows(k_buf, slot, kv_heads, tokens),
+            columns = k_buf if k_cols > 1 else (k_buf,)
+            for tiles, (h, vh) in zip(
+                    zip(*(_head_rows(column, slot, kv_heads, tokens)
+                          for column in columns)),
                     _head_rows(v_buf, slot, kv_heads, tokens)):
-                yield h, kh, vh
+                yield h, (tiles[0][1] if k_cols == 1 else jnp.concatenate(
+                    [rows_ for _, rows_ in tiles], axis=1)), vh
 
         copies = _page_copier(meta_ref, tbl_ref, k_hbm, v_hbm, k_buf, v_buf,
                               sems)
@@ -1010,8 +1098,16 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
                 start(row, 0, 0)
 
             acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[...] = jnp.zeros_like(l_ref)
+            if sunk:    # the sink is the softmax's start state, a query row
+                for h in range(kv_heads):
+                    m_ref[h] = jnp.concatenate([
+                        jnp.broadcast_to(sink_ref[h, g:g + 1, :],
+                                         (qb, _LANES))
+                        for g in range(group)], axis=0)
+                l_ref[...] = jnp.ones_like(l_ref)
+            else:
+                m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+                l_ref[...] = jnp.zeros_like(l_ref)
 
             def attend_block(blk, slot):
                 @pl.when(blk + 1 < n_blocks)
@@ -1096,12 +1192,23 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
         page_buffers = [pltpu.VMEM((2, block, page_size, d), k_pages.dtype)]
     else:
         pools = [k_pages, v_pages]
-        page_buffers = [pltpu.VMEM((2, block, page_size, kv_heads, d),
-                                   k_pages.dtype)] * 2
+        page_buffers = [pltpu.VMEM((2, block, page_size, kv_heads, width),
+                                   k_pages.dtype)
+                        for width in [d // k_cols] * k_cols + [dv]]
+    sink_specs, sink_operands = [], []
+    if sunk:
+        # [kv_heads, group, lanes]: the logit of query head ``kv * group +
+        # g`` at ``[kv, g]``; a tile spreads it over its qb rows
+        sink_specs = [pl.BlockSpec((kv_heads, group, _LANES),
+                                   lambda w, *_: (0, 0, 0))]
+        sink_operands = [jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(kv_heads, group, 1),
+            (kv_heads, group, _LANES))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(n_win,),
-        in_specs=[q_spec, row_spec, row_spec] + [hbm_spec] * len(pools),
+        in_specs=[q_spec, row_spec, row_spec] + [hbm_spec] * len(pools)
+        + sink_specs,
         out_specs=o_spec,
         scratch_shapes=page_buffers + [
             pltpu.SemaphoreType.DMA((len(pools), 2)),     # (K | V, slot)
@@ -1123,7 +1230,7 @@ def _paged_attention_chunk_call(q, k_pages, v_pages, block_tables,
         interpret=interpret,
         name="paged_attention_chunk",
     )(*prefetch, qt, lens.reshape(n_win, qb, 1), lows.reshape(n_win, qb, 1),
-      *pools)
+      *pools, *sink_operands)
     return out.reshape(n_win, kv_heads, group, qb, dv).transpose(
         0, 3, 1, 2, 4).reshape(n_win * qb, n_heads, dv)[:rows]
 
@@ -1133,7 +1240,7 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
                            scale: Optional[float] = None,
                            impl: str = "xla", layer=None, starts=None,
                            n_chunk: int = 0,
-                           value_dim: Optional[int] = None):
+                           value_dim: Optional[int] = None, sinks=None):
     """THE ragged paged-attention entry point: ONE op serving every
     attention shape the engine dispatches — single-token decodes,
     chunked-prefill suffixes, speculative-verify windows, and a MIXED
@@ -1198,24 +1305,38 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     page_size, width]``, a token's row the key of every query head and its
     first ``value_dim`` columns the value: ``q`` [T, heads, width] ->
     [T, heads, value_dim]. All three paths take it; none reads a second
-    pool."""
+    pool.
+
+    K AND V OF DIFFERENT WIDTHS (``kv_v``'s last dimension is not
+    ``kv_k``'s): ``q`` [T, heads, dk] -> [T, heads, dv], on all three paths.
+
+    ``sinks`` [heads] float32 (None = none, today's program text): query
+    head ``h`` carries a logit ``sinks[h]`` that joins its softmax's
+    denominator and has no value: ``o_h = sum_j e^{s_hj - m} v_j /
+    (e^{sinks[h] - m} + sum_j e^{s_hj - m})``, ``m`` the largest of all of
+    them. All three paths take it, with ``starts`` and ``n_chunk`` or
+    without. An int8 pool with a sink or with unequal widths, and a latent
+    pool with a sink, are refused by name (:func:`_refuse_unserved`)."""
     if kv_v is None:
+        _refuse_unserved(True, False, sinks, None)
         return _latent_attention(q, kv_k, token_tables, token_lens, scale,
                                  impl, layer, starts, n_chunk, value_dim)
     if layer is not None and impl != "pallas":
         kv_k, kv_v = kv_layer(kv_k, layer), kv_layer(kv_v, layer)
     kp, ks = _split_kv(kv_k)
     vp, vs = _split_kv(kv_v)
+    _refuse_unserved(False, ks is not None, sinks,
+                     (kp.shape[-1], vp.shape[-1]))
     if impl == "pallas":
         return paged_attention_kernel(q, kp, vp, token_tables,
                                       token_lens, layer=layer,
                                       scale=scale, k_scales=ks,
                                       v_scales=vs, starts=starts,
-                                      n_chunk=n_chunk)
+                                      n_chunk=n_chunk, sinks=sinks)
     if impl == "reference":
         return ragged_paged_attention_reference(
             q, kv_k, kv_v, token_tables, token_lens,
-            scale=scale, starts=starts).astype(q.dtype)
+            scale=scale, starts=starts, sinks=sinks).astype(q.dtype)
     if impl != "xla":
         raise ValueError(f"unknown impl {impl!r}")
     d = q.shape[-1]
@@ -1225,7 +1346,7 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     out = _gathered_attention(q[:, None], kp, vp, token_tables,
                               token_lens[:, None], scale,
                               k_scales=ks, v_scales=vs,
-                              start=_column(starts))
+                              start=_column(starts), sinks=sinks)
     return out[:, 0]
 
 
@@ -1261,7 +1382,7 @@ def _latent_attention(q, pages, token_tables, token_lens, scale, impl,
 def ragged_paged_attention_reference(q, kv_k: KVStore, kv_v: KVStore,
                                      token_tables, token_lens,
                                      scale: Optional[float] = None,
-                                     starts=None):
+                                     starts=None, sinks=None):
     """f32-accumulate reference path (the exactness baseline): same
     contract as :func:`ragged_paged_attention`, but q, the
     (dequantized) pages, and every intermediate are f32 end to end
@@ -1277,7 +1398,7 @@ def ragged_paged_attention_reference(q, kv_k: KVStore, kv_v: KVStore,
                               kp, vp, token_tables,
                               token_lens[:, None], scale,
                               k_scales=ks, v_scales=vs,
-                              start=_column(starts))
+                              start=_column(starts), sinks=sinks)
     return out[:, 0]
 
 
@@ -1340,13 +1461,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
                         scale, k_scales=None, v_scales=None, start=None,
-                        value_dim=None):
+                        value_dim=None, sinks=None):
     """Shared decode-attention core: gather the block table's pages,
     dequantize (optional per-row scales), expand GQA, masked fp32
     softmax. q [B, K, H, d]; limit [B, K] = attendable cached
     positions per query (0 → zero output row); ``start`` [B, K] their
     lower bound (None: 0). ``v_pages`` None: the values are the first
-    ``value_dim`` columns of the gathered key rows."""
+    ``value_dim`` columns of the gathered key rows. The values may be of
+    another width than the keys; ``sinks`` [H]: one more logit a head in the
+    softmax's denominator, with no value."""
     b, kq, n_heads, d = q.shape
     _, page_size, kv_heads, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
@@ -1379,7 +1502,14 @@ def _gathered_attention(q, k_pages, v_pages, block_tables, limit,
             mask = mask & (jnp.arange(L)[None, None, :]
                            >= start[:, :, None])
         logits = jnp.where(mask[:, None], logits, -jnp.inf)
-        p = jax.nn.softmax(logits, axis=-1)
+        if sinks is None:
+            p = jax.nn.softmax(logits, axis=-1)
+        else:       # a column more in the softmax, dropped from the sum
+            sink = jnp.broadcast_to(
+                sinks.astype(jnp.float32)[None, :, None, None],
+                logits.shape[:-1] + (1,))
+            p = jax.nn.softmax(jnp.concatenate([logits, sink], -1),
+                               axis=-1)[..., :-1]
         # fully-masked rows (limit 0, e.g. a freed slot): zeros, not NaN
         p = jnp.where(limit[:, None, :, None] > 0, p, 0.0)
         out = jnp.einsum("bhql,blhd->bqhd", p, v.astype(jnp.float32))

@@ -871,3 +871,98 @@ def test_kda_program_fits_the_chip_at_full_depth_and_keeps_state_and_pool_in_pla
     assert mem.temp_size_in_bytes < budget, mem.temp_size_in_bytes
     full_depth = 8.592e9 + 49 * 43_417_600 + 23_041 * 143_360
     assert full_depth + mem.temp_size_in_bytes < 0.90 * 16_909_336_064
+
+
+# -- a sink, keys of 192 beside values of 128, a window under the chunk -------
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_sink_program_compiles_at_mimos_widths_and_keeps_its_pools_in_place(
+        one_chip, monkeypatch, program):
+    """The sink model's engine programs at MiMo-V2-Flash's widths (hidden
+    4096, 64 query heads of 192 over 4 K/V heads on a full layer and 8 on a
+    sliding one, values of 128, K pages stored 256 wide), a full layer and
+    two sliding ones, two of the 256 experts held, the cell's 48 slots,
+    256-row chunk over window 128 and 608-column tables: one kernel call a
+    layer (two in a mixed tick: the chunk's rows through query tiles, the
+    decode rows through the row walk, both on the MXU fold: 16 and 8 query
+    heads a K/V head), the sliding layers' with a lower bound a row and the
+    sink as an operand, every routed layer's two grouped products through
+    the kernel, BOTH groups' K AND V pools the program's own outputs
+    (aliased) and the temporaries under a quarter of the pools. What Mosaic
+    refuses of these shapes (a 4-row head axis, a 256-wide K buffer beside a
+    128-wide V buffer, the tile kernel's VMEM) it refuses here."""
+    import importlib
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.inference import llm
+    from paddle_tpu.models import MiMoV2Config, MiMoV2ForCausalLM
+
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    monkeypatch.setattr(llm, "_moe_impl", lambda net: "pallas")
+    slots, chunk, max_len = 48, 256, 9728
+    cfg = MiMoV2Config(num_hidden_layers=3, vocab_size=1024,
+                       experts_held=(0, 2), hybrid_layer_pattern=[0, 1, 1],
+                       moe_layer_freq=[0, 1, 1])
+    assert (cfg.hidden_size, cfg.sliding_window, cfg.n_routed_experts) == (
+        4096, 128, 256)
+    pt.seed(0)
+    net = MiMoV2ForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    eng = llm.LLMEngine(net, max_seqs=slots, page_size=PAGE, num_pages=1825,
+                        max_len=max_len, prefill_chunk=chunk,
+                        kv_dtype="bf16", attention_impl="pallas")
+    try:
+        def described(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=one_chip), tree)
+
+        full, window = eng._pool.groups
+        assert (full.num_pages, window.num_pages, window.ring) == (
+            1825, 48 * 25 + 1, 25)
+        assert eng.pages_per_seq == 608
+        assert full.k_pages.shape == (1, 1825, PAGE, 4, 256)
+        assert full.v_pages.shape == (1, 1825, PAGE, 4, 128)
+        assert window.k_pages.shape == (2, 1201, PAGE, 8, 256)
+        assert window.v_pages.shape == (2, 1201, PAGE, 8, 128)
+        ints = np.zeros((slots,), np.int32)
+        tables = eng._pool.device_tables()
+        if program == "decode":
+            lowered = eng._decode_fn.lower(*described((
+                eng._params, eng._buffers, eng._tokens_dev, ints, tables,
+                ints, eng.k_pages, eng.v_pages, eng.temperatures,
+                eng._nonces, eng._key)))
+        else:
+            rows = np.zeros((1, chunk), np.int32)
+            per_slot = np.zeros((1, slots), np.int32)
+            xs = {"tok": rows, "pos": rows, "lim": rows,
+                  "tbl": eng._pool.row_tables(np.full((1, chunk), -1)),
+                  "fin": per_slot.astype(bool), "row": per_slot,
+                  "fpos": per_slot, "grant": per_slot}
+            lowered = eng._mixed_fn.lower(*described((
+                eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+                tables, eng.temperatures, eng._nonces, eng._key)), 1)
+        pools = sum(a.nbytes for a in eng.k_pages + eng.v_pages)
+        assert pools == (1825 * 4 + 2 * 1201 * 8) * PAGE * (256 + 128) * 2
+    finally:
+        eng.close()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+
+    def calls(kernel):
+        return [ln for ln in text.splitlines() if " custom-call(" in ln
+                and "%" + kernel in ln.split(" = ")[0]]
+
+    assert len(calls("paged_attention.")) == 3
+    assert len(calls("paged_attention_chunk")) \
+        == (3 if program == "mixed" else 0)
+    assert len(calls("grouped_matmul")) == 4 and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    print("MIMO", program, "temp", mem.temp_size_in_bytes, "alias",
+          mem.alias_size_in_bytes, "args", mem.argument_size_in_bytes,
+          "pools", pools)
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < pools // 4, (
+        mem.temp_size_in_bytes, pools)

@@ -109,7 +109,9 @@ def test_one_latent_group_and_one_state_row_a_kda_layer(model):
     net, _, _ = model
     (group,) = net.kv_cache_spec()
     # a row of 32 + 8 values stored in 128 lanes, its first 32 the value
-    assert tuple(group) == ("latent", 1, 1, 128, None, 32)
+    assert tuple(group)[:6] == ("latent", 1, 1, 128, None, 32)
+    # (PR 42's two fields: no V of its own width, no sink)
+    assert (group.v_head_dim, group.sink) == (None, False)
     spec = net.state_cache_spec()
     assert (spec["layers"], spec["conv_state"], spec["ssm_state"],
             spec["impls"]) == (4, (3, 96), (2, 16, 16), ("xla",))

@@ -210,3 +210,92 @@ def test_pages_touched_by_a_latent_group_are_the_pages_of_one_array():
     got = pool.pages_touched([(rows, 10, "pallas", chunk)])["latent"]
     assert got == kv.pages_touched([(rows, 10, "pallas", chunk)])["kv"]
     assert got == {"read": 3 + 8 + 7, "live": 3 + 8 + 7}
+
+
+# -- values of another width than the keys; a sink; a window under the chunk --
+
+WIDE = [CacheGroup("full", 2, 1, 24, None, None, 16),
+        CacheGroup("window", 3, 2, 24, 4, None, 16, True)]
+
+
+def test_a_group_with_v_head_dim_keeps_a_v_array_of_its_own_width():
+    pool = pool_of(WIDE)
+    full, window = pool.groups
+    assert full.k_pages.shape == (2, 40, PS, 1, 24)
+    assert full.v_pages.shape == (2, 40, PS, 1, 16)
+    assert window.k_pages.shape[-2:] == (2, 24) \
+        and window.v_pages.shape[-2:] == (2, 16)
+    # the bytes are what is stored, K and V each at its own width
+    assert (full.k_page_bytes, full.v_page_bytes) == (
+        2 * PS * 24 * 4, 2 * PS * 16 * 4)
+    assert full.page_bytes == 2 * PS * (24 + 16) * 4
+    assert window.page_bytes == 3 * PS * 2 * (24 + 16) * 4
+    assert pool.page_bytes == full.page_bytes + window.page_bytes
+    st = window.status()
+    assert (st["head_dim"], st["v_head_dim"], st["sink"], st["kv_heads"]) \
+        == (24, 16, True, 2)
+    assert (st["k_row_bytes"], st["v_row_bytes"], st["row_bytes"]) == (
+        3 * 2 * 24 * 4, 3 * 2 * 16 * 4, 3 * 2 * 40 * 4)
+    assert full.status()["sink"] is False
+    # a group that names no v_head_dim: V as wide as K, as ever
+    same = pool_of(TWO).groups[0]
+    assert same.v_pages.shape == same.k_pages.shape
+    assert same.status()["v_head_dim"] == 8 \
+        and same.k_page_bytes == same.v_page_bytes
+    # the arrays keep the form of the spec, V beside K
+    assert [v.shape[-1] for v in pool.v_pages] == [16, 16]
+
+
+@pytest.mark.parametrize("group", [
+    CacheGroup("kv", 2, 2, 24, None, None, 16),
+    CacheGroup("kv", 2, 2, 24, None, None, None, True)],
+    ids=["unequal-widths", "sink"])
+def test_no_int8_form_of_unequal_widths_or_of_a_sink(group):
+    with pytest.raises(ValueError, match="no int8 form of a group with"):
+        PagePool([group], 40, PS, SLOTS, PAGES_PER_SEQ, "int8", CHUNK)
+    PagePool([group], 40, PS, SLOTS, PAGES_PER_SEQ, "f32", CHUNK)
+    with pytest.raises(ValueError, match="has no v_head_dim"):
+        PagePool([CacheGroup("latent", 1, 1, 128, None, 96, 64)], 40, PS,
+                 SLOTS, PAGES_PER_SEQ, "f32", CHUNK)
+
+
+def test_a_ring_under_a_chunk_longer_than_the_window():
+    """Window 4 under chunks of 8 (the cell: 128 under 256): the ring is
+    the window, the chunk in front of it and a page of misalignment; a slot
+    never holds more, whatever the sequence's length, a chunk's pages are
+    all there while its program runs, and a page a slot is freed every
+    page of tokens."""
+    pool = pool_of(WIDE)
+    window = pool.groups[1]
+    assert window.ring == -(-(4 + CHUNK) // PS) + 1 == 4
+    assert window.num_pages == SLOTS * 4 + 1
+    peak, held_at_tick = [0], []
+
+    def on_tick():
+        peak[0] = max(peak[0], int(window.held[0]))
+        held_at_tick.append(int(window.held[0]))
+
+    serve(pool, 0, 37, 30, on_tick)
+    assert peak[0] <= window.ring
+    # a chunk of 8 holds its own two pages and the window's page before
+    assert max(held_at_tick[:4]) == 3
+    # decode: the window's one or two pages, and a page goes every 4 tokens
+    assert set(held_at_tick[6:]) <= {1, 2}
+    assert window.n_released == (37 + 30 - 4 + 1) // PS
+    assert pool.groups[0].held[0] == -(-(37 + 30) // PS)
+    pool.free_slot(0)
+    assert len(window.free) == window.num_pages - 1
+
+
+def test_pages_touched_by_a_chunk_twice_the_window():
+    """A chunk of 8 rows over window 4 through the kernel: one tile walks
+    the pages from its first row's window to its last row's limit once."""
+    from paddle_tpu.inference.page_pool import ChunkRows
+    pool = pool_of(WIDE)
+    chunk = ChunkRows(np.zeros((1, 8), np.int32),
+                      np.arange(17, 25, dtype=np.int32)[None])
+    got = pool.pages_touched([([], 8, "pallas", chunk)])
+    # limits 17..24: the full group reads pages 0-5 once; the window group
+    # from position 13 (page 3) to position 23 (page 5)
+    assert got["full"] == {"read": 6, "live": 6}
+    assert got["window"] == {"read": 3, "live": 3}

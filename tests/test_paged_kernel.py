@@ -865,3 +865,210 @@ def test_block_of_a_latent_pool_follows_the_stored_row():
     assert pa._pages_per_block(16, None, 640, jnp.bfloat16, 8, 1) == 8
     assert pa._pages_per_block(16, 5, 128, jnp.bfloat16, 480, 2) \
         == pa._pages_per_block(16, 5, 128, jnp.bfloat16, 480)
+
+
+# -- a sink in the softmax, and values of another width than the keys --------
+
+DV, SINK_WINDOW = 8, 8      # a V row's width beside keys of D; a window
+
+
+@pytest.fixture
+def let_go():
+    """Each case below is an interpreted kernel of its own shapes that no
+    other test runs again: let the executables go (as ``mxu_fold`` does;
+    with them all kept, a process that runs this file whole dies in
+    XLA:CPU's compiler)."""
+    yield
+    jax.clear_caches()
+
+
+def sunk_pool(seed, kv_heads, dv, dtype=jnp.float32):
+    k = jax.random.normal(jax.random.PRNGKey(seed),
+                          (LAYERS, PAGES, PS, kv_heads, D))
+    v = jax.random.normal(jax.random.PRNGKey(seed + 100),
+                          (LAYERS, PAGES, PS, kv_heads, dv))
+    return k.astype(dtype), v.astype(dtype)
+
+
+def dense_sink(q, k, v, tables, lens, starts, sinks, layer):
+    """The definition, a row and a head at a time, in float64: ``o_h = sum_j
+    e^{s_hj - m} v_j / (e^{b_h - m} + sum_j e^{s_hj - m})`` over positions
+    ``starts[r] <= j < lens[r]`` (no sink: the plain softmax)."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    tables = np.asarray(tables)
+    out = np.zeros(q.shape[:2] + (v.shape[-1],))
+    group = q.shape[1] // k.shape[3]
+    for r in range(q.shape[0]):
+        lo = 0 if starts is None else int(starts[r])
+        hi = int(lens[r])
+        if hi == 0:
+            continue
+        kk = k[layer, tables[r]].reshape(-1, k.shape[3], k.shape[-1])[lo:hi]
+        vv = v[layer, tables[r]].reshape(-1, v.shape[3], v.shape[-1])[lo:hi]
+        for h in range(q.shape[1]):
+            s = kk[:, h // group] @ q[r, h] / np.sqrt(q.shape[-1])
+            b = -np.inf if sinks is None else float(sinks[h])
+            m = max(s.max(), b)
+            p = np.exp(s - m)
+            out[r, h] = (p / (p.sum() + np.exp(b - m))) @ vv[:, h // group]
+    return out
+
+
+SINK_LENS = [0, 1, PS - 1, SINK_WINDOW, SINK_WINDOW + 1, 22, MAX_LEN]
+
+
+@pytest.mark.parametrize("bound", [False, True], ids=["whole", "windowed"])
+@pytest.mark.parametrize("dv", [D, DV], ids=["dv=dk", "dv<dk"])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (12, 2)],
+                         ids=["vpu-fold", "mxu-fold"])
+@pytest.mark.parametrize("sunk", [True, False], ids=["sink", "no-sink"])
+def test_a_sink_and_unequal_widths_in_the_row_walk_and_both_gathered_paths(
+        block, let_go, heads, kv_heads, dv, bound, sunk):
+    """Rows of 0 / 1 / a page less one / a window / a window and one tokens
+    (and two longer ones), with a lower bound a row and without: the row
+    walk (the VPU fold at 2 query heads a K/V head, the MXU fold at 6), the
+    gathered path and the float32 reference agree with the definition, with
+    a sink a query head and without, with V as wide as K and narrower. A
+    sink as large as the scores takes a visible share: the row of one token
+    is NOT its value."""
+    if not sunk and dv == D:
+        pytest.skip("neither a sink nor unequal widths: the tests above")
+    k, v = sunk_pool(21, kv_heads, dv)
+    lens = jnp.asarray(SINK_LENS, jnp.int32)
+    starts = jnp.maximum(lens - SINK_WINDOW, 0) if bound else None
+    rows = lens.shape[0]
+    q = queries(rows, heads, seed=22)
+    sinks = jax.random.normal(jax.random.PRNGKey(23), (heads,)) \
+        if sunk else None
+    tables = tables_for(rows, seed=24)
+    want = dense_sink(q, k, v, tables, lens, starts, sinks, layer=1)
+    for impl in ("pallas", "xla", "reference"):
+        got = np.asarray(ragged_paged_attention(
+            q, k, v, tables, lens, impl=impl, layer=1, starts=starts,
+            sinks=sinks))
+        assert got.shape == (rows, heads, dv)
+        np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5,
+                                   err_msg=impl)
+        assert not got[0].any(), "limit 0 is a zero row, sink or none"
+    if sunk:
+        one = np.asarray(v[1, tables[1, 0], 0])        # row 1: one token
+        group = heads // kv_heads
+        assert np.abs(want[1] - np.repeat(one, group, 0)).max() > 0.05
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["whole", "window-6"])
+@pytest.mark.parametrize("dv", [D, DV], ids=["dv=dk", "dv<dk"])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (12, 2)],
+                         ids=["2-a-kv-head", "6-a-kv-head"])
+def test_a_sink_and_unequal_widths_in_the_query_tiles(
+        tile_rows, let_go, heads, kv_heads, dv, window):
+    """The mixed tick's packing of the tile tests (a run cut by a window's
+    edge, a run of one row, padded rows, decode rows behind them) with a
+    sink a head and V narrower than K: tiles and row walk together agree
+    with the definition, with a window shorter than the chunk's longest run
+    and without."""
+    tables, lens, n_chunk = packing()
+    k, v = sunk_pool(25, kv_heads, dv)
+    q = queries(lens.shape[0], heads, seed=26)
+    sinks = jax.random.normal(jax.random.PRNGKey(27), (heads,))
+    starts = None if window is None else jnp.maximum(lens - window, 0)
+    want = dense_sink(q, k, v, tables, lens, starts, sinks, layer=2)
+    got = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=2, starts=starts,
+        n_chunk=n_chunk, sinks=sinks))
+    np.testing.assert_allclose(got, want, atol=3e-5, rtol=3e-5)
+    walked = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=2, starts=starts,
+        sinks=sinks))
+    np.testing.assert_allclose(got, walked, atol=3e-5, rtol=3e-5)
+    assert not got[np.asarray(lens) == 0].any()
+
+
+def test_bf16_pages_with_a_sink_lie_within_bf16_of_the_reference(block,
+                                                                 let_go):
+    """The MXU fold and the tiles round queries and probabilities to the
+    pool's type; the sink stays float32 in the state."""
+    tables, lens, n_chunk = packing()
+    k, v = sunk_pool(28, 2, DV, jnp.bfloat16)
+    q = queries(lens.shape[0], 12, seed=29).astype(jnp.bfloat16)
+    sinks = jax.random.normal(jax.random.PRNGKey(30), (12,))
+    starts = jnp.maximum(lens - 6, 0)
+    want = np.asarray(ragged_paged_attention_reference(
+        q, pa.kv_layer(k, 0), pa.kv_layer(v, 0), tables, lens, starts=starts,
+        sinks=sinks))
+    got = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=0, starts=starts,
+        n_chunk=n_chunk, sinks=sinks), np.float32)
+    assert np.abs(got - want).max() < 2e-2
+
+
+def test_no_sink_and_equal_widths_is_todays_program_to_the_letter():
+    """``sinks=None`` over K and V of one width traces to the jaxpr of a
+    call that never heard of either, on the kernel path (row walk and tiles)
+    and the gathered one; a sink changes it."""
+    tables, lens, n_chunk = packing()
+    k, v = pool(0, kv_heads=2)
+    q = queries(lens.shape[0], 12)
+
+    def text(**kw):
+        return {impl: jaxpr_text(lambda *a: ragged_paged_attention(
+            *a, impl=impl, layer=1, n_chunk=n_chunk, **kw),
+            q, k, v, tables, lens) for impl in ("pallas", "xla")}
+
+    assert text(sinks=None) == text()
+    sunk = text(sinks=jnp.zeros((12,)))
+    assert all(sunk[impl] != text()[impl] for impl in sunk)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla", "reference"])
+def test_what_no_path_serves_is_refused_by_name(impl):
+    """An int8 pool with a sink or with unequal widths, a latent pool with a
+    sink: a ``ValueError`` that names it, on every path."""
+    k, v = sunk_pool(31, 2, D)
+    kq = QuantizedKV(*quantize_kv(k))
+    lens = jnp.asarray([5, 9], jnp.int32)
+    q, tables = queries(2, 4), tables_for(2)
+    with pytest.raises(ValueError, match="sinks over an int8 pool"):
+        ragged_paged_attention(q, kq, QuantizedKV(*quantize_kv(v)), tables,
+                               lens, impl=impl, layer=0,
+                               sinks=jnp.zeros((4,)))
+    narrow = QuantizedKV(*quantize_kv(sunk_pool(31, 2, DV)[1]))
+    with pytest.raises(ValueError, match="keys of 16 beside values of 8"):
+        ragged_paged_attention(q, kq, narrow, tables, lens, impl=impl,
+                               layer=0)
+    with pytest.raises(ValueError, match="sinks over a latent pool"):
+        ragged_paged_attention(
+            jnp.ones((2, LHEADS, LW)),
+            latent_pool(1), None, tables, lens, impl=impl, layer=0,
+            value_dim=LV, sinks=jnp.zeros((LHEADS,)))
+
+
+@pytest.mark.parametrize("kv_heads,group", [(2, 6), (1, 4)],
+                         ids=["2-kv-heads", "1-kv-head"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+def test_keys_of_two_lane_tiles_beside_values_of_one(tile_rows, let_go,
+                                                     kv_heads, group, dtype,
+                                                     tol):
+    """Keys 256 wide (192 stored in whole lanes: the sink model's) beside
+    values of 128: the query tiles keep one K page buffer a lane tile (their
+    strided loads want rows of one tile) and put a head's key rows together
+    again; the row walk keeps one buffer. With a sink and a window."""
+    dk, dv = 256, 128
+    tables, lens, n_chunk = packing()
+    k = jax.random.normal(jax.random.PRNGKey(40),
+                          (LAYERS, PAGES, PS, kv_heads, dk)).astype(dtype)
+    v = jax.random.normal(jax.random.PRNGKey(41),
+                          (LAYERS, PAGES, PS, kv_heads, dv)).astype(dtype)
+    heads = kv_heads * group
+    q = (jax.random.normal(jax.random.PRNGKey(42),
+                           (lens.shape[0], heads, dk)) / 4).astype(dtype)
+    sinks = jax.random.normal(jax.random.PRNGKey(43), (heads,))
+    starts = jnp.maximum(lens - 6, 0)
+    want = dense_sink(q, k, v, tables, lens, starts, sinks, layer=1)
+    got = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="pallas", layer=1, starts=starts,
+        n_chunk=n_chunk, sinks=sinks), np.float32)
+    assert got.shape == (lens.shape[0], heads, dv)
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
