@@ -18,7 +18,10 @@ paths through the entry points users call, at the full width of
            serving benchmark's decode and mixed shapes and at the window /
            full attention cell's two decode calls, the state step's
            kernel beside ``ssd_step`` and the routed experts' grouped
-           product beside ``jax.lax.ragged_dot`` at the hybrid cell's shapes
+           product beside ``jax.lax.ragged_dot`` at the hybrid cell's shapes,
+           the delta rule's chunk form (``ops/kda.py``, plain jax.numpy)
+           held to its recurrence and timed at ``reason_closed_kda``'s
+           shapes
   serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
            ``serve_llm``; HTTP ``POST /generate`` checked against
            ``net.generate``; once with attention_impl="xla", once "pallas"
@@ -269,6 +272,7 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
     time_paged_attention(seed, heads, d, seq)
     time_ssd_step(seed)
     time_ssd_chunk(seed)
+    time_kda_chunk(seed)
     time_grouped_matmul(seed)
     emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
 
@@ -649,6 +653,105 @@ def time_ssd_chunk(seed: int, rows: int = 256, slots: int = 64,
                   "max_abs_err_y": round(y_err, 7),
                   "max_abs_err_state": round(s_err, 8), "calls": calls})
         del ref_state
+        free_device_memory()
+
+
+def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
+                   heads: int = 32, d: int = 128, max_seqs: int = 8,
+                   layers: int = 3, calls: int = 20) -> None:
+    """The delta rule's chunk form (``ops/kda.py``, plain ``jax.numpy`` on
+    every platform) as a mixed tick of ``reason_closed_kda`` calls it a KDA
+    layer: 256 packed prompt rows of 32 heads of 128, float32, as the model
+    makes them (unit keys, ``q`` scaled, ``log a`` <= 0), over a layer's 48
+    + 1 state rows, up to 8 sequences a chunk; with 1, 2 and 8 sequences in
+    it (the second with a fresh one, the last with padded rows). One call's
+    ``o`` (live rows) and whole state array are held to ``kda_recurrence``,
+    a sequence at a time, on the chip (a row without a sequence in the
+    chunk must hold); then ms a call of ``calls`` chained calls in one
+    program that donates the state (a mixed tick runs twenty such layers),
+    ``layers`` state arrays going round, EACH CALL WITH ROWS OF ITS OWN:
+    what does not depend on the state, the inverse among it, is else
+    computed once for all the calls of the program. Smoke readings of one
+    layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.kda import (kda_chunk_gathered, kda_recurrence,
+                                    l2norm)
+
+    rng = np.random.RandomState(seed + 43)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 43), 6)
+    t = rows
+    shape = (calls, t, heads, d)
+    inputs = (l2norm(jax.random.normal(ks[0], shape)) * d ** -0.5,
+              l2norm(jax.random.normal(ks[1], shape)),
+              jax.random.normal(ks[2], shape),
+              -0.3 * jnp.exp(jax.random.normal(ks[3], shape)),
+              jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+    first = tuple(x[0] for x in inputs)
+
+    def fresh_state():
+        return tuple(jax.random.normal(k, (slots + 1, heads, d, d),
+                                       jnp.float32)
+                     for k in jax.random.split(ks[5], layers))
+
+    def run(state, inputs, seg, seg_rows, fresh):
+        state = list(state)
+        total = jnp.zeros(inputs[2].shape[1:], jnp.float32)
+        for i in range(calls):
+            o, state[i % layers] = kda_chunk_gathered(
+                *(x[i] for x in inputs), state[i % layers], seg, seg_rows,
+                fresh)
+            total = total + o
+        return total, tuple(state)
+
+    one = jax.jit(kda_chunk_gathered)
+    by_tokens = jax.jit(kda_recurrence)
+    chained = jax.jit(run, donate_argnums=(0,))
+    # (lengths of the sequences in the chunk, which of them start here)
+    cases = {"1_seq": ((t,), ()), "2_seqs": ((t - 96, 96), (1,)),
+             "8_seqs": ((57, 9, 40, 1, 64, 23, 31, 17), (2, 5))}
+    for name, (lens, starts) in cases.items():
+        seg = np.full((t,), max_seqs, np.int32)
+        seg[:sum(lens)] = np.repeat(np.arange(len(lens)), lens)
+        seg_rows = np.full((max_seqs,), slots, np.int32)
+        seg_rows[:len(lens)] = rng.permutation(slots)[:len(lens)]
+        fresh = np.zeros((max_seqs,), bool)
+        fresh[list(starts)] = True
+        args = (jnp.asarray(seg), jnp.asarray(seg_rows), jnp.asarray(fresh))
+        start = fresh_state()[0]
+        o, new = one(*first, start, *args)
+        want_new, o_err, at = start, 0.0, 0
+        for i, n in enumerate(lens):
+            row = int(seg_rows[i])
+            want_o, want_s = by_tokens(
+                *(x[at:at + n] for x in first),
+                jnp.zeros_like(start[row]) if fresh[i] else start[row])
+            o_err = max(o_err, float(jnp.abs(o[at:at + n] - want_o).max()))
+            want_new = want_new.at[row].set(want_s)
+            at += n
+        # the scratch row takes what the absent entries of seg_rows write
+        s_err = float(jnp.abs(new - want_new)[:slots].max())
+        check(bool(jnp.isfinite(o[:at]).all()),
+              f"kda_chunk_gathered ({name}): o is not finite")
+        # float32 beside float32, the same sums in another order
+        check(o_err <= 2e-5 and s_err <= 2e-5 * float(jnp.abs(start).max()),
+              f"kda_chunk_gathered ({name}) disagrees with kda_recurrence: "
+              f"o {o_err}, state {s_err}")
+        del start, new, want_new
+        total, state = chained(fresh_state(), inputs, *args)
+        jax.block_until_ready(state)
+        t1 = time.perf_counter()
+        for _ in range(3):
+            total, state = chained(state, inputs, *args)
+        jax.block_until_ready((total, state))
+        ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+        del state
+        emit({"phase": "kernels", "kernel": "kda_chunk", "timed": name,
+              "path": "kda_chunk_gathered", "rows": t,
+              "sequences": len(lens), "heads": heads, "head_dim": d,
+              "ms_per_call": round(ms, 4), "max_abs_err_o": round(o_err, 8),
+              "max_abs_err_state": round(s_err, 8), "calls": calls})
         free_device_memory()
 
 

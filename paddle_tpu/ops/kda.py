@@ -36,7 +36,10 @@ Three forms, all plain ``jax.numpy`` (no kernel exists yet: ROADMAP A):
   the later block's first row, ``e^{G_t - G_ref} e^{G_ref - G_i}`` with
   both factors <= 1, which makes the rest one product a block. ``(I +
   L)^{-1}`` is built by the block form of forward substitution, doubling
-  the block from one row to the whole run: no step walks the tokens.
+  the block from one row to the whole run: no step walks the tokens. The
+  blocks of ``L`` that the doubling reads are cut out by static slices,
+  halving ``L``'s diagonal blocks from the top down (no mask, no sum, each
+  element below the diagonal moved once).
 
 The decay stays in log space; the decays, their sums, ``L``, ``W`` and the
 state are float32 whatever the activations are (a recurrence rounds at every
@@ -99,19 +102,28 @@ def _unit_lower_inverse(low):
     ``T`` a power of two: block forward substitution, the block doubling.
     The inverse of a diagonal block of 1 is 1; ``[[A, 0], [C, B]]^{-1} =
     [[A^-1, 0], [-B^-1 C A^-1, B^-1]]`` joins every pair of neighbouring
-    blocks at once, ``log2 T`` times."""
+    blocks at once, ``log2 T`` times. The ``C`` of every level are cut out
+    of ``low`` beforehand, from the top down and by slices alone: a
+    diagonal block of ``2h`` rows gives its lower left ``[h, h]`` to level
+    ``h`` and its two diagonal blocks of ``h`` rows to the level below
+    (the levels' blocks tile the strict lower triangle once; nothing is
+    masked or summed, and each level halves what the next one reads)."""
     t = low.shape[-1]
     lead = low.shape[:-2]
+    lower = {}                              # level h: [..., T / 2h, h, h]
+    diag, h = low[..., None, :, :], t // 2  # [..., T / 2h, 2h, 2h]
+    while h:
+        lower[h] = diag[..., h:, :h]
+        if h > 1:
+            diag = jnp.stack([diag[..., :h, :h], diag[..., h:, h:]],
+                             -3).reshape(lead + (t // h, h, h))
+        h //= 2
     inv = jnp.ones(lead + (t, 1, 1), _F32)          # [..., blocks, s, s]
     s = 1
     while s < t:
-        n = t // (2 * s)
-        # the diagonal blocks of 2s rows: [..., n, 2s, 2s]
-        pairs = jnp.einsum("...iaib->...iab",
-                           low.reshape(lead + (n, 2 * s, n, 2 * s)))
         a, b = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
-        under = -jnp.matmul(b, jnp.matmul(pairs[..., s:, :s], a,
-                                          precision=_HI), precision=_HI)
+        under = -jnp.matmul(b, jnp.matmul(lower[s], a, precision=_HI),
+                            precision=_HI)
         inv = jnp.concatenate(
             [jnp.concatenate([a, jnp.zeros_like(a)], -1),
              jnp.concatenate([under, b], -1)], -2)
@@ -183,7 +195,9 @@ def _kda_block(q, k, v, log_a, b, oh, state):
     from_state = jnp.einsum("xgthk,ghkv->xhtv", grown[:, None] * by_seq,
                             state, precision=_HI)
     rhs = bh * (v.transpose(1, 0, 2) - from_state[0])              # [H, T, V]
-    w = jnp.matmul(_unit_lower_inverse(bh * a_kk), rhs, precision=_HI)
+    with jax.named_scope("inverse"):
+        inv = _unit_lower_inverse(bh * a_kk)
+    w = jnp.matmul(inv, rhs, precision=_HI)
     o = from_state[1] + jnp.matmul(a_qk, w, precision=_HI)         # [H, T, V]
     left = k * jnp.exp(jnp.minimum(to_end, 0.0))                   # [T, H, K]
     new = jnp.exp(total)[..., None] * state + jnp.einsum(

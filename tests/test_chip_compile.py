@@ -873,6 +873,45 @@ def test_kda_program_fits_the_chip_at_full_depth_and_keeps_state_and_pool_in_pla
     assert full_depth + mem.temp_size_in_bytes < 0.90 * 16_909_336_064
 
 
+def test_chunk_forms_inverse_takes_its_blocks_by_slices_on_the_chip(one_chip):
+    """One KDA layer's ``kda_chunk_gathered`` at ``reason_closed_kda``'s
+    shapes (256 packed rows of 32 heads of 128, float32, 8 sequences a
+    chunk, a layer's 48 + 1 state rows), compiled for the described v5e.
+    The blocks the inverse's doubling reads are cut out of ``L`` by slices:
+    no operation comes from the einsum with a repeated index that stood
+    there (``...iaib->...iab``: an ``iota``, a compare, a select against
+    zeros and a sum over all of ``L``, at each of eight levels), nothing of
+    a level's masked shape ``[32, n, 2s, n, 2s]`` is made, and the program
+    has no more fusions than the parent's text of the same function: 139
+    there (PR 42's tree, this compiler, counted as below), 119 here; a
+    fusion costs microseconds on the chip whatever it moves."""
+    import re
+    from paddle_tpu.ops.kda import kda_chunk_gathered
+
+    c = KDA_CELL
+    t, h, d, g = c["chunk"], c["heads"], 128, 8
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(kda_chunk_gathered).lower(
+        sds((t, h, d)), sds((t, h, d)), sds((t, h, d)), sds((t, h, d)),
+        sds((t, h)), sds((c["slots"] + 1, h, d, d)), sds((t,), jnp.int32),
+        sds((g,), jnp.int32), sds((g,), jnp.bool_)).compile().as_text()
+    assert "iaib" not in text
+    assert "kda_chunk_gathered)/inverse/" in text        # the scope's name
+    s = 1
+    while s < t:
+        n = t // (2 * s)
+        assert f"f32[{h},{n},{2 * s},{n},{2 * s}]" not in text, (n, s)
+        s *= 2
+    entry = text[text.index("ENTRY"):]
+    fusions = [ln for ln in entry.splitlines()
+               if re.search(r" = .* fusion\(", ln)]
+    # (the lower limit: the pattern still finds the program's fusions)
+    assert 80 < len(fusions) <= 139, len(fusions)
+
+
 # -- a sink, keys of 192 beside values of 128, a window under the chunk -------
 
 @pytest.mark.parametrize("program", ["decode", "mixed"])

@@ -166,3 +166,126 @@ def test_unit_lower_inverse_is_the_inverse():
     want = jnp.eye(64) - jnp.eye(64, k=-1)
     np.testing.assert_allclose(kda._unit_lower_inverse(ones), want,
                                atol=1e-6)
+
+
+# -- the chunk form's indexing against the parent's text, bit for bit ---------
+# (PR 43 changed how the inverse takes its blocks out of ``L`` and no
+# arithmetic: the form it replaced stays here as the oracle, under ``==``)
+
+def _einsum_inverse(low):
+    """``_unit_lower_inverse`` as it stood: the diagonal blocks of ``2s``
+    rows taken by an einsum with a repeated index (a mask and a sum over
+    the whole ``[T, T]``), their lower left ``[s, s]`` used."""
+    t = low.shape[-1]
+    lead = low.shape[:-2]
+    inv = jnp.ones(lead + (t, 1, 1), jnp.float32)
+    s = 1
+    while s < t:
+        n = t // (2 * s)
+        pairs = jnp.einsum("...iaib->...iab",
+                           low.reshape(lead + (n, 2 * s, n, 2 * s)))
+        a, b = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        under = -jnp.matmul(b, jnp.matmul(pairs[..., s:, :s], a,
+                                          precision=kda._HI),
+                            precision=kda._HI)
+        inv = jnp.concatenate(
+            [jnp.concatenate([a, jnp.zeros_like(a)], -1),
+             jnp.concatenate([under, b], -1)], -2)
+        s *= 2
+    return inv[..., 0, :, :]
+
+
+def _parent_kda_block(q, k, v, log_a, b, oh, state):
+    """``_kda_block`` as it stood, over the einsum form above."""
+    hi = kda._HI
+    t = q.shape[0]
+    ohf = oh.astype(jnp.float32)
+    same = jnp.matmul(ohf, ohf.T) > 0
+    upto = jnp.tril(jnp.ones((t, t), bool))
+    g = jnp.cumsum(log_a, axis=0)
+    first = jnp.argmax(oh, axis=0)
+    g_before = jnp.where((first > 0)[:, None, None],
+                         g[jnp.maximum(first - 1, 0)], 0.0)
+    last = t - 1 - jnp.argmax(oh[::-1], axis=0)
+    total = g[last] - g_before
+    g_seq = g - jnp.einsum("tg,ghk->thk", ohf, g_before, precision=hi)
+    to_end = jnp.einsum("tg,ghk->thk", ohf, total, precision=hi) - g_seq
+    prods = kda._decayed_products(jnp.stack([k, q]), k, g, same & upto)
+    a_kk = jnp.where(jnp.eye(t, dtype=bool), 0.0, prods[0])
+    a_qk = prods[1]
+    bh = b.T[:, :, None]
+    by_seq = ohf.T[:, :, None, None]
+    grown = jnp.stack([k, q]) * jnp.exp(jnp.minimum(g_seq, 0.0))
+    from_state = jnp.einsum("xgthk,ghkv->xhtv", grown[:, None] * by_seq,
+                            state, precision=hi)
+    rhs = bh * (v.transpose(1, 0, 2) - from_state[0])
+    w = jnp.matmul(_einsum_inverse(bh * a_kk), rhs, precision=hi)
+    o = from_state[1] + jnp.matmul(a_qk, w, precision=hi)
+    left = k * jnp.exp(jnp.minimum(to_end, 0.0))
+    new = jnp.exp(total)[..., None] * state + jnp.einsum(
+        "gthk,htv->ghkv", left[None] * by_seq, w, precision=hi)
+    present = jnp.any(oh, axis=0)
+    return o.transpose(1, 0, 2), jnp.where(
+        present[:, None, None, None], new, state)
+
+
+@pytest.mark.parametrize("lead", [(3,), (2, 2)], ids=["heads", "two-axes"])
+@pytest.mark.parametrize("t", [16, 32, 64, 128, 256])
+def test_inverse_takes_its_blocks_by_index_and_gives_the_einsums_numbers(
+        t, lead):
+    low = 0.2 * jnp.tril(jax.random.normal(jax.random.PRNGKey(t),
+                                           lead + (t, t)), -1)
+    got = jax.jit(kda._unit_lower_inverse)(low)
+    want = jax.jit(_einsum_inverse)(low)
+    assert got.shape == want.shape == lead + (t, t)
+    assert bool((got == want).all())
+    # nothing of the indexing is a mask and a sum
+    text = str(jax.make_jaxpr(kda._unit_lower_inverse)(low))
+    assert "reduce_sum" not in text and "select_n" not in text
+
+
+# (lengths, rows t, states g, seg_rows over 6 + 1 state rows, fresh)
+_PINNED = {
+    "one": ((64,), 64, 1, (3,), ()),
+    "two-meet-inside-a-block": ((21, 43), 64, 2, (5, 0), ()),
+    "eight": ((9, 30, 1, 17, 40, 5, 12, 14), 128, 8,
+              (0, 1, 2, 3, 4, 5, 6, 6), ()),
+    "a-fresh-one": ((20, 12), 32, 4, (4, 1, 6, 6), (1,)),
+    "an-absent-slot": ((16, 16), 32, 4, (2, 6, 5, 6), ()),
+    "padded-rows": ((37, 50, 13), 112, 4, (1, 4, 0, 6), (2,)),
+    "no-power-of-two": ((5, 1, 30), 37, 4, (0, 3, 5, 6), ()),
+    "two-pieces": ((200, 100), 300, 2, (2, 4), (0,)),
+}
+
+
+@pytest.mark.parametrize("strength", [1.0, 40.0], ids=["mild", "strong"])
+@pytest.mark.parametrize("case", list(_PINNED))
+def test_chunk_forms_return_the_parents_numbers_bit_for_bit(
+        case, strength, monkeypatch):
+    """``kda_chunked`` and ``kda_chunk_gathered`` against themselves over
+    the parent's ``_kda_block``: every live row's ``o`` and every state
+    row under ``==`` (a padded row's ``o`` means nothing)."""
+    lengths, t, g, seg_rows, fresh = _PINNED[case]
+    q, k, v, log_a, b = inputs(t, seed=len(lengths), strength=strength)
+    state = jax.random.normal(jax.random.PRNGKey(11), (7, H, K, V))
+    seg = packed(lengths, t, g)
+    rows_of = jnp.asarray(seg_rows)
+    is_fresh = jnp.zeros((g,), bool).at[jnp.asarray(fresh, int)].set(True)
+    # an absent slot of ``kda_chunked`` carries a state of its own
+    carried = jax.random.normal(jax.random.PRNGKey(12), (g, H, K, V))
+
+    def both():
+        # a new function a call: nothing traced over the other block
+        return (jax.jit(lambda *a: kda.kda_chunked(*a))(
+                    q, k, v, log_a, b, carried, seg),
+                jax.jit(lambda *a: kda.kda_chunk_gathered(*a))(
+                    q, k, v, log_a, b, state, seg, rows_of, is_fresh))
+
+    got = both()
+    monkeypatch.setattr(kda, "_kda_block", _parent_kda_block)
+    want = both()
+    live = sum(lengths)
+    for (o, new), (want_o, want_new) in zip(got, want):
+        assert bool(jnp.isfinite(o[:live]).all())
+        assert bool((o[:live] == want_o[:live]).all())
+        assert bool((new == want_new).all())
