@@ -22,6 +22,9 @@ paths through the entry points users call, at the full width of
            the delta rule's chunk form (``ops/kda.py``, plain jax.numpy)
            held to its recurrence and timed at ``reason_closed_kda``'s
            shapes
+  staging  a decode dispatch's host arrays sent one ``jnp.asarray`` each
+           beside the one packed vector of ``inference/staging.py``, timed
+           at ``chat_closed``'s and ``mixed_len_closed_sink``'s shapes
   serve    full-depth 1.3B, bf16 weights and KV pool, ``LLMEngine`` behind
            ``serve_llm``; HTTP ``POST /generate`` checked against
            ``net.generate``; once with attention_impl="xla", once "pallas"
@@ -823,6 +826,76 @@ def time_grouped_matmul(seed: int, held: int = 36, layers: int = 4) -> None:
 # serve
 # ---------------------------------------------------------------------------
 
+def time_staging(seed: int, reps: int = 300) -> None:
+    """What ``LLMEngine._issue`` stages for ONE decode dispatch, at two
+    cells' shapes (``chat_closed``: 32 slots, one block table of 128
+    columns; ``mixed_len_closed_sink``: 48 slots, two tables of 608): the
+    parent's ``jnp.asarray`` a host array (five and six calls, each table
+    copied first as ``PagePool.device_tables`` does) beside the one packed
+    vector of ``inference/staging.py``. ``host_ms``: until the calls have
+    returned, which is what the ``staged`` mark of ``llm.issue.decode``
+    reads; ``ready_ms``: until the arrays are on the device. Medians over
+    ``reps`` rounds, the two forms taking turns. The packed vector, cut by
+    a jitted ``unpack``, must give back every source bit for bit. Smoke
+    readings of a quiet process, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.inference.staging import StagedLayout
+
+    rng = np.random.RandomState(seed + 11)
+    for cell, slots, widths in (("chat_closed", 32, (128,)),
+                                ("mixed_len_closed_sink", 48, (608, 608))):
+        ints = [rng.randint(0, 2048, slots).astype(np.int32)
+                for _ in range(2)]
+        tables = [rng.randint(0, 29185, (slots, w)).astype(np.int32)
+                  for w in widths]
+        nonces = rng.randint(-2 ** 31, 2 ** 31 - 1, slots).astype(np.int32)
+        temps = rng.uniform(0, 1, slots).astype(np.float32)
+        temps[:4] = 0.0, -0.0, 1e-45, 1e-30
+        layout = StagedLayout(
+            [((slots,), np.int32)] * 2 + [(t.shape, np.int32) for t in tables]
+            + [((slots,), np.int32), ((slots,), np.float32)])
+        sources = ints + tables + [nonces, temps]
+        forms = {
+            "separate": lambda: [jnp.asarray(a) for a in ints]
+            + [jnp.asarray(t.copy()) for t in tables]
+            + [jnp.asarray(nonces), jnp.asarray(temps)],
+            "packed": lambda: [layout.stage(*sources)],
+            "packed_asarray": lambda: [jnp.asarray(layout.pack(*sources))]}
+        got = jax.jit(layout.unpack)(layout.stage(*sources))
+        check(all(np.asarray(g).tobytes() == a.tobytes()
+                  for g, a in zip(got, sources)),
+              f"staging ({cell}): an unpacked field is not its source's bits")
+        times = {name: ([], []) for name in forms}
+        for rep in range(reps + 20):
+            for name, form in forms.items():
+                t0 = time.perf_counter()
+                out = form()
+                t1 = time.perf_counter()
+                jax.block_until_ready(out)
+                t2 = time.perf_counter()
+                if rep >= 20:                       # the first rounds warm up
+                    times[name][0].append(t1 - t0)
+                    times[name][1].append(t2 - t0)
+        line = {"phase": "staging", "time_staging": cell, "slots": slots,
+                "table_columns": list(widths), "reps": reps,
+                "transfers": {"separate": len(sources), "packed": 1},
+                "packed_bytes": 4 * layout.size}
+        for name, (host, ready) in times.items():
+            line[name] = {"host_ms": round(1e3 * float(np.median(host)), 4),
+                          "ready_ms": round(1e3 * float(np.median(ready)), 4)}
+        line["separate"]["host_ms_a_transfer"] = round(
+            line["separate"]["host_ms"] / len(sources), 4)
+        emit(line)
+
+
+def phase_staging(seed: int) -> None:
+    t0 = time.time()
+    time_staging(seed)
+    emit({"phase": "staging", "seconds": round(time.time() - t0, 1)})
+
+
 def make_prompts(seed: int, vocab: int, lengths, shared_prefix: int):
     """Random prompts; the first two share a page-aligned prefix."""
     import numpy as np
@@ -1527,7 +1600,7 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", default=None,
-                    choices=("kernels", "serve", "serve_hybrid",
+                    choices=("kernels", "staging", "serve", "serve_hybrid",
                              "serve_looped", "serve_swa", "train"),
                     help="one chip: run this phase alone (default: all)")
     args = ap.parse_args(argv)
@@ -1537,7 +1610,8 @@ def main(argv=None) -> int:
         if args.chips == 4:
             phase_mesh(args.seed)
         else:
-            phases = {"kernels": phase_kernels, "serve": phase_serve,
+            phases = {"kernels": phase_kernels, "staging": phase_staging,
+                      "serve": phase_serve,
                       "serve_hybrid": phase_serve_hybrid,
                       "serve_looped": phase_serve_looped,
                       "serve_swa": phase_serve_swa,

@@ -60,6 +60,7 @@ from ..ops.paged_attention import (KV_DTYPES, QuantizedKV, _split_kv,
                                    kv_scale_nbytes, kv_zeros)
 from ..reliability import faults as _faults
 from .page_pool import ChunkRows, NoInt8Form, PagePool, cache_groups
+from .staging import StagedLayout
 from ..reliability.retry import Deadline, DeadlineExceeded, as_deadline
 
 # How every engine program is compiled for a TPU. XLA:TPU's memory-space
@@ -1604,8 +1605,20 @@ class LLMEngine:
             return (nxt, jnp.concatenate([nxt, aux.reshape(-1)])) \
                 + tuple(out[2:])
 
-        def decode_fn(params, buffers, tokens, positions, tables, lens,
-                      kp, vp, temps, nonces, key, *state):
+        # a decode dispatch's host arrays, in the order _issue packs them:
+        # positions, lens, a block table a group, nonces, temperatures; ONE
+        # transfer a dispatch (inference/staging.py)
+        slots = (max_seqs,)
+        self._decode_layout = layout = StagedLayout(
+            [(slots, np.int32), (slots, np.int32)]
+            + [(t.shape, np.int32) for t in self._pool.host_tables()]
+            + [(slots, np.int32), (slots, np.float32)])
+        bare = self._pool.bare
+
+        def decode_fn(params, buffers, tokens, staged, kp, vp, key, *state):
+            positions, lens, *tables, nonces, temps = layout.unpack(staged)
+            # the tables in the form of the model's spec, as the pools are
+            tables = tables[0] if bare else tuple(tables)
             (out, _) = functional_call(
                 decode, params, buffers, tokens, positions, tables,
                 lens, kp, vp, temps, nonces, key, *state,
@@ -1615,7 +1628,7 @@ class LLMEngine:
         # donate the pools (and the state rows): XLA updates them in
         # place step to step
         self._decode_fn = self._jit(
-            decode_fn, donate_argnums=(6, 7) + ((11, 12) if has_state
+            decode_fn, donate_argnums=(4, 5) + ((7, 8) if has_state
                                                 else ()))
 
         def tick_outputs(out):
@@ -3399,6 +3412,16 @@ class LLMEngine:
         return () if self._state_spec is None \
             else (self.conv_state, self.ssm_state)
 
+    def _stage_decode(self, positions: np.ndarray,
+                      lens: np.ndarray) -> jax.Array:
+        """The host arrays of one decode dispatch on the device, as the
+        ONE vector ``decode_fn`` cuts (``self._decode_layout``). The block
+        tables are copied into it here: the allocator goes on writing
+        them while the dispatch is queued."""
+        return self._decode_layout.stage(
+            positions, lens, *self._pool.host_tables(), self._nonces,
+            self.temperatures)
+
     def _take_outputs(self, out):
         """Keep the pools (and state rows) a per-tick program returned;
         ``(tokens on the device, what the host will fetch)``: for a
@@ -3620,10 +3643,13 @@ class LLMEngine:
         events, in this order, every dispatch: ``packed`` (the plan and
         the host arrays are complete: Python planning and packing lie
         before it), ``staged`` (every argument of the program is a
-        device array: host-to-device staging), ``launched`` (the jitted
-        call has returned: argument flattening and the runtime's
-        enqueue; the device works from here on), ``booked`` (what the
-        engine does after a launch whether or not anyone is tracing).
+        device array: host-to-device staging; here ONE transfer, the
+        packed vector of :meth:`_stage_decode`; the phase's attr
+        ``h2d_transfers`` counts the host arrays sent), ``launched``
+        (the jitted call has returned: argument flattening and the
+        runtime's enqueue; the device works from here on), ``booked``
+        (what the engine does after a launch whether or not anyone is
+        tracing).
         From ``booked`` to the phase's end runs only what exists for
         the trace: the attrs and the ``_stamp_*`` arithmetic."""
         with _trace.phase("llm.issue.decode") as ph:
@@ -3657,12 +3683,9 @@ class LLMEngine:
                 _faults.check("device.dispatch")
             self._guard_recompiles("decode_step")
             ph.add_event("packed")
-            args = (self._params, self._buffers,
-                    self._tokens_dev, jnp.asarray(positions),
-                    self._pool.device_tables(), jnp.asarray(lens),
-                    self.k_pages, self.v_pages,
-                    jnp.asarray(self.temperatures),
-                    jnp.asarray(self._nonces), self._key) \
+            args = (self._params, self._buffers, self._tokens_dev,
+                    self._stage_decode(positions, lens),
+                    self.k_pages, self.v_pages, self._key) \
                 + self._state_args()
             if _perf.enabled():
                 self._perf_program("decode_step", (), self._decode_fn, args)
@@ -3687,7 +3710,8 @@ class LLMEngine:
             ph.add_event("booked")
             if ph is not _trace.NOOP_SPAN:
                 ph.set_attr("issue_seq", self._issue_seq) \
-                    .set_attr("live_rows", len(live)).set_attr("ticks", 1)
+                    .set_attr("live_rows", len(live)).set_attr("ticks", 1) \
+                    .set_attr("h2d_transfers", 1)
                 self._stamp_state(ph, True, 0, len(live))
                 self._stamp_kv_pages(
                     ph, ([(slot, lens[slot]) for slot in live],
@@ -4055,12 +4079,17 @@ class LLMEngine:
                 # live_rows: slots that can emit in this dispatch
                 # (decoding ones and prompts completing in it);
                 # chunk_rows: prompts that got a chunk; chunk_tokens:
-                # their prompt tokens
+                # their prompt tokens; h2d_transfers: the host arrays
+                # sent between packed and staged (the carry's positions
+                # and budgets, the schedule, the tables, temperatures
+                # and nonces)
                 ph.set_attr("issue_seq", self._issue_seq) \
                     .set_attr("live_rows", len(slots_list)) \
                     .set_attr("chunk_rows", len(touched)) \
                     .set_attr("chunk_tokens", n_prefill_tokens) \
-                    .set_attr("ticks", n_run)
+                    .set_attr("ticks", n_run) \
+                    .set_attr("h2d_transfers", 2 + len(
+                        jax.tree_util.tree_leaves(mixed_args[3:7])))
                 self._stamp_state(ph, True, len(touched),
                                   len(slots_list) - len(start))
                 chunk = ChunkRows(pslot[:n_run], plim[:n_run])

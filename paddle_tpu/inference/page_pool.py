@@ -238,6 +238,13 @@ class PagePool:
         return self._form([jnp.asarray(g.tables.copy())
                            for g in self.groups])
 
+    def host_tables(self) -> List[np.ndarray]:
+        """The host block tables themselves, a group, in the order of
+        ``groups``: for a caller that copies them into a buffer of its own
+        before it sends anything (``StagedLayout.pack``). The allocator goes
+        on writing them: never hand one to ``jnp.asarray`` as it is."""
+        return [g.tables for g in self.groups]
+
     def row_tables(self, slots: np.ndarray):
         """The block table of each ROW's sequence, a group: ``slots``
         [...] holds a row's slot, -1 for a padded row (all zeros: the

@@ -12,6 +12,7 @@ them through the Pallas interpreter by the one switch below
 file that asks the TPU compiler.
 """
 
+import contextlib
 import os
 
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -153,12 +154,70 @@ class _Dispatches:
         ``chunk_rows``, ``chunk_tokens``, ``ticks``, ``kv_pages_read``,
         ``kv_pages_live``, ``state_rows``, ``state_bytes`` and, where the
         model has them, ``kv_groups``, ``context_tokens``,
-        ``window_pages_released``, ``loop_steps``, ``kv_cache_layers``)."""
+        ``window_pages_released``, ``loop_steps``, ``kv_cache_layers``).
+        ``h2d_transfers`` (ISSUE 44) is left out, so that the pins keep
+        saying the other attrs are what they were before it;
+        :meth:`transfers` reads it."""
         import hashlib
         import json
-        rows = [[s["name"], s["attrs"]] for s in cls.launched(spans)]
+        rows = [[s["name"], {k: v for k, v in s["attrs"].items()
+                             if k != "h2d_transfers"}]
+                for s in cls.launched(spans)]
         text = json.dumps(rows, sort_keys=True, default=int)
         return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    @staticmethod
+    @contextlib.contextmanager
+    def decode_staging(eng):
+        """While it is open, every decode dispatch of ``eng`` appends to the
+        list it yields ``(n, staged, copy)``: ``n`` host arrays handed to
+        ``jnp.asarray`` / ``jax.device_put`` from the entry of ``_issue`` to
+        the jitted call (which must find device arrays alone: a numpy
+        argument would be one more transfer, inside the call), the staged
+        vector the program was given and a copy of it taken at the launch."""
+        import jax.numpy as jnp
+        seen, counting = [], {"n": None}
+
+        def counted(real):
+            def call(x, *a, **kw):
+                if counting["n"] is not None and isinstance(x, np.ndarray):
+                    counting["n"] += 1
+                return real(x, *a, **kw)
+            return call
+
+        issue, decode_fn = eng._issue, eng._decode_fn
+
+        def issue_counted(live):
+            counting["n"] = 0
+            try:
+                return issue(live)
+            finally:
+                counting["n"] = None
+
+        def decode_spied(*args):
+            n, counting["n"] = counting["n"], None
+            assert all(isinstance(a, jax.Array)
+                       for a in jax.tree_util.tree_leaves(args))
+            seen.append((n, args[3], np.array(args[3])))
+            return decode_fn(*args)
+
+        mp = pytest.MonkeyPatch()
+        mp.setattr(jnp, "asarray", counted(jnp.asarray))
+        mp.setattr(jax, "device_put", counted(jax.device_put))
+        eng._issue, eng._decode_fn = issue_counted, decode_spied
+        try:
+            yield seen
+        finally:
+            mp.undo()
+            eng._issue, eng._decode_fn = issue, decode_fn
+
+    @classmethod
+    def transfers(cls, spans):
+        """``{phase name: the set of h2d_transfers its dispatches carry}``."""
+        out = {}
+        for s in cls.launched(spans):
+            out.setdefault(s["name"], set()).add(s["attrs"]["h2d_transfers"])
+        return out
 
     @classmethod
     def check_marks(cls, spans,
@@ -183,8 +242,9 @@ class _Dispatches:
 @pytest.fixture
 def issue_phases():
     """Helpers for a test of the ``llm.issue.*`` phases: ``serve`` (a
-    repeatable run), ``launched``, ``digest``, ``check_marks``; tracing is
-    off and the table empty before and after."""
+    repeatable run), ``launched``, ``digest``, ``check_marks``,
+    ``transfers``, ``decode_staging``; tracing is off and the table empty
+    before and after."""
     from paddle_tpu.observability import tracing
     tracing.disable()
     tracing.clear()

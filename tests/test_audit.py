@@ -235,12 +235,11 @@ def test_disabled_audit_adds_no_result_keys_and_no_ops():
     — the HLO pin that keeps it off the device forever)."""
     def tick_hlo(eng):
         b = eng.max_seqs
-        zeros = jnp.zeros((b,), jnp.int32)
+        zeros = np.zeros((b,), np.int32)
         return eng._decode_fn.lower(
-            eng._params, eng._buffers, zeros, zeros,
-            jnp.zeros((b, eng.pages_per_seq), jnp.int32), zeros,
-            eng.k_pages, eng.v_pages, jnp.zeros((b,), jnp.float32),
-            zeros, eng._key).as_text()
+            eng._params, eng._buffers, jnp.asarray(zeros),
+            eng._stage_decode(zeros, zeros), eng.k_pages, eng.v_pages,
+            eng._key).as_text()
 
     eng_on = _tiny_engine()
     with eng_on:

@@ -492,10 +492,9 @@ def hybrid_programs(one_chip):
 
         ints = np.zeros((SLOTS,), np.int32)
         decode = eng._decode_fn.lower(*described((
-            eng._params, eng._buffers, eng._tokens_dev, ints,
-            eng.block_tables, ints, eng.k_pages, eng.v_pages,
-            eng.temperatures, eng._nonces, eng._key)
-            + eng._state_args())).compile()
+            eng._params, eng._buffers, eng._tokens_dev,
+            eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
+            eng._key) + eng._state_args())).compile()
         seg, seg_rows, _ = eng._chunk_segments((1, chunk))
         rows = np.zeros((1, chunk), np.int32)
         slots = np.zeros((1, SLOTS), np.int32)
@@ -581,9 +580,9 @@ def test_looped_program_keeps_the_pool_in_place_through_its_passes(
         ints = np.zeros((slots,), np.int32)
         if program == "decode":
             lowered = eng._decode_fn.lower(*described((
-                eng._params, eng._buffers, eng._tokens_dev, ints,
-                eng.block_tables, ints, eng.k_pages, eng.v_pages,
-                eng.temperatures, eng._nonces, eng._key)))
+                eng._params, eng._buffers, eng._tokens_dev,
+                eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
+                eng._key)))
         else:
             rows = np.zeros((1, chunk), np.int32)
             per_slot = np.zeros((1, slots), np.int32)
@@ -666,9 +665,9 @@ def test_windowed_program_keeps_both_groups_pools_in_place(
         tables = eng._pool.device_tables()
         if program == "decode":
             lowered = eng._decode_fn.lower(*described((
-                eng._params, eng._buffers, eng._tokens_dev, ints, tables,
-                ints, eng.k_pages, eng.v_pages, eng.temperatures,
-                eng._nonces, eng._key)))
+                eng._params, eng._buffers, eng._tokens_dev,
+                eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
+                eng._key)))
         else:
             rows = np.zeros((1, chunk), np.int32)
             per_slot = np.zeros((1, slots), np.int32)
@@ -817,8 +816,8 @@ def kda_programs(one_chip):
         tables = eng._pool.device_tables()
         assert eng.pages_per_seq == 480 and eng.v_pages == (None,)
         lowered = {"decode": eng._decode_fn.lower(*described((
-            eng._params, eng._buffers, eng._tokens_dev, ints, tables, ints,
-            eng.k_pages, eng.v_pages, eng.temperatures, eng._nonces,
+            eng._params, eng._buffers, eng._tokens_dev,
+            eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
             eng._key) + eng._state_args()))}
         seg, seg_rows, _ = eng._chunk_segments((1, chunk))
         rows = np.zeros((1, chunk), np.int32)
@@ -970,9 +969,9 @@ def test_sink_program_compiles_at_mimos_widths_and_keeps_its_pools_in_place(
         tables = eng._pool.device_tables()
         if program == "decode":
             lowered = eng._decode_fn.lower(*described((
-                eng._params, eng._buffers, eng._tokens_dev, ints, tables,
-                ints, eng.k_pages, eng.v_pages, eng.temperatures,
-                eng._nonces, eng._key)))
+                eng._params, eng._buffers, eng._tokens_dev,
+                eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
+                eng._key)))
         else:
             rows = np.zeros((1, chunk), np.int32)
             per_slot = np.zeros((1, slots), np.int32)

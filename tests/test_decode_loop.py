@@ -228,11 +228,11 @@ def test_n1_compiles_zero_scan_ops():
         "N=1 engine compiled a slab program"
     b = eng1.max_seqs
     zeros = jnp.zeros((b,), jnp.int32)
+    host_zeros = np.zeros((b,), np.int32)
     tick_hlo = eng1._decode_fn.lower(
-        eng1._params, eng1._buffers, zeros, zeros,
-        jnp.zeros((b, eng1.pages_per_seq), jnp.int32), zeros,
-        eng1.k_pages, eng1.v_pages, jnp.zeros((b,), jnp.float32),
-        zeros, eng1._key).as_text()
+        eng1._params, eng1._buffers, zeros,
+        eng1._stage_decode(host_zeros, host_zeros),
+        eng1.k_pages, eng1.v_pages, eng1._key).as_text()
 
     _, eng4 = run(net, prompts, 8, 4)
     carry = DecodeCarry(

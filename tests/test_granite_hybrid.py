@@ -458,6 +458,28 @@ def test_through_the_kernels_state_bytes_counts_the_sequences_present(
     assert max(a["chunk_rows"] for a in mixed) == 2
 
 
+def test_a_model_with_state_lanes_stages_its_decode_in_one_transfer(
+        model, issue_phases):
+    """ISSUE 44: the state rows are device arrays already and follow the
+    key; the host arrays in front of them cross as one (five before), a
+    mixed dispatch's as the 15 it sends today (the 13 of a pool of one group
+    and the two arrays that say which sequence a prompt row belongs to)."""
+    from paddle_tpu.observability import tracing
+    net, _, _ = model
+    tracing.enable()
+    with LLMEngine(net, max_seqs=2, page_size=8, num_pages=32, max_len=64,
+                   prefill_chunk=16, kv_dtype="f32") as eng, \
+            issue_phases.decode_staging(eng) as seen:
+        issue_phases.serve(eng, list(zip(prompts_of((20, 7, 5), seed=6),
+                                         (5, 4, 6))))
+        assert len(eng._state_args()) == 2
+        size = eng._decode_layout.size
+    assert seen and {n for n, _, _ in seen} == {1}
+    assert {staged.shape for _, staged, _ in seen} == {(size,)}
+    assert issue_phases.transfers(tracing.finished_spans()) == {
+        "llm.issue.decode": {1}, "llm.issue.mixed": {15}}
+
+
 def test_the_issue_marks_leave_the_state_attrs_where_the_parent_wrote_them(
         model, issue_phases):
     """ISSUE 37: ``packed`` / ``staged`` / ``launched`` / ``booked`` on every

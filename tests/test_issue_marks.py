@@ -70,6 +70,35 @@ def test_every_dispatch_carries_the_four_marks_and_the_parents_attrs(
         == [o["output_ids"] for o in traced]
 
 
+def test_a_decode_dispatch_sends_its_host_arrays_in_one_transfer(
+        issue_phases):
+    """ISSUE 44, a pool of one group: from the entry of ``_issue`` to the
+    jitted call ONE host array goes to the device (positions, lens, the
+    block table, nonces and temperatures were five), the program finds no
+    numpy argument, and the spans say so: ``h2d_transfers`` 1 on every
+    decode dispatch, on a mixed one the thirteen it sends (the carry's two,
+    the schedule's eight, the table, temperatures, nonces). Greedy and
+    sampled rows get the tokens of an engine nobody spies on."""
+    jobs = _jobs()
+    tracing.enable()
+    with _engine() as eng, issue_phases.decode_staging(eng) as seen:
+        futs = [eng.submit(p, max_new_tokens=n, temperature=t)
+                for (p, n), t in zip(jobs, (0.0, 0.9, 0.0, 0.7, 0.0))]
+        outs = [f.result(600)["output_ids"] for f in futs]
+        layout = eng._decode_layout
+    spans = tracing.finished_spans()
+    assert len(seen) >= 6 and {n for n, _, _ in seen} == {1}
+    assert {staged.shape for _, staged, _ in seen} == {(layout.size,)}
+    assert layout.size == 4 * 4 + 4 * eng.pages_per_seq
+    assert issue_phases.transfers(spans) == {"llm.issue.decode": {1},
+                                             "llm.issue.mixed": {13}}
+    tracing.disable()
+    with _engine() as eng:
+        plain = [eng.submit(p, max_new_tokens=n, temperature=t)
+                 for (p, n), t in zip(jobs, (0.0, 0.9, 0.0, 0.7, 0.0))]
+        assert [f.result(600)["output_ids"] for f in plain] == outs
+
+
 def test_through_the_kernel_the_tiles_pages_are_the_parents(issue_phases):
     """``attention_impl="pallas"``: READ cuts the chunk's rows into the
     kernel's tiles (``PagePool.pages_touched``), at the phase's end now."""

@@ -271,6 +271,44 @@ def test_the_issue_marks_leave_the_groups_attrs_where_the_parent_wrote_them(
     assert issue_phases.digest(spans) == "13f0c1e81f095ef9"
 
 
+def test_two_groups_tables_cross_in_the_one_transfer_and_stay_as_sent(
+        model, issue_phases):
+    """ISSUE 44, a pool of two groups (six host arrays before): ONE transfer
+    a decode dispatch, both block tables inside it, 15 on a mixed one. And
+    the hazard ``PagePool.device_tables`` documents: the window group's
+    table is rewritten right after every launch (pages released behind the
+    window), by the end of the run every sequence's rows are zero again,
+    and each staged vector still reads what it read when it was launched,
+    its tables the host tables of that moment."""
+    from paddle_tpu.observability import tracing
+    net, _, _ = model
+    tracing.enable()
+    with LLMEngine(net, max_seqs=2, **ENGINE) as eng, \
+            issue_phases.decode_staging(eng) as seen:
+        issue_phases.serve(eng, list(zip(prompts_of((70, 20, 9)),
+                                         (30, 8, 5))))
+        layout, tables = eng._decode_layout, eng._pool.host_tables()
+        assert [t.shape for t in tables] == [(2, eng.pages_per_seq)] * 2
+        assert not any(t.any() for t in tables)     # every slot freed
+    spans = tracing.finished_spans()
+    assert issue_phases.transfers(spans) == {"llm.issue.decode": {1},
+                                             "llm.issue.mixed": {15}}
+    assert sum(s["attrs"]["window_pages_released"]
+               for s in issue_phases.launched(spans)) > 0
+    assert len(seen) >= 20 and {n for n, _, _ in seen} == {1}
+    assert layout.size == 4 * 2 + 2 * 2 * eng.pages_per_seq
+    for _, staged, at_launch in seen:
+        assert np.array_equal(np.asarray(staged), at_launch)
+        _, lens, full, window, _, _ = jax.jit(layout.unpack)(staged)
+        live = np.asarray(lens) > 0
+        # a live row holds a page in both groups, the window group fewer
+        assert live.any() and np.asarray(full)[live].any(axis=1).all()
+        assert (np.count_nonzero(np.asarray(window)[live], axis=1)
+                <= RING).all()
+    assert max(np.count_nonzero(at[2 * 2:2 * 2 + 2 * eng.pages_per_seq])
+               for _, _, at in seen) > RING
+
+
 # -- the rotary schemes against a direct transcription ----------------------
 
 def test_yarn_inverse_frequencies_are_the_formulas():

@@ -258,9 +258,9 @@ def test_a_program_holds_one_stacks_text_whatever_the_passes(model):
         with LLMEngine(net, max_seqs=2, **ENGINE) as eng:
             ints = np.zeros((2,), np.int32)
             return eng._decode_fn.lower(
-                eng._params, eng._buffers, eng._tokens_dev, ints,
-                eng.block_tables, ints, eng.k_pages, eng.v_pages,
-                eng.temperatures, eng._nonces, eng._key).as_text()
+                eng._params, eng._buffers, eng._tokens_dev,
+                eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
+                eng._key).as_text()
 
     three, one = lowered(model[0]), lowered(build(total_ut_steps=1)[0])
     assert three.count("dot_general") < one.count("dot_general") + 4
