@@ -320,8 +320,7 @@ def bench_widedeep(batch: int = 16384, warmup: int = 3, iters: int = 30,
 def bench_llm_decode(n_requests: int = 16, max_seqs: int = 8,
                      prompt_len: int = 128, gen_len: int = 128,
                      cpu_smoke: bool = False,
-                     model_name: str = "gpt2-small",
-                     lookahead: int = 0):
+                     model_name: str = "gpt2-small"):
     """Multi-client decode throughput through LLMEngine: n_requests
     greedy generations (prompt_len ctx, gen_len new tokens) share one
     engine with max_seqs slots. Metrics: aggregate generated tokens/sec
@@ -349,8 +348,7 @@ def bench_llm_decode(n_requests: int = 16, max_seqs: int = 8,
                for _ in range(n_requests)]
     with LLMEngine(net, max_seqs=max_seqs, page_size=16,
                    num_pages=pages, max_len=total,
-                   prefill_buckets=(prompt_len,),
-                   lookahead=lookahead) as eng:
+                   prefill_chunk=prompt_len) as eng:
         # warmup compiles prefill + decode
         eng.generate([prompts[0]], max_new_tokens=2)
         tracing.clear()
@@ -369,7 +367,7 @@ def bench_llm_decode(n_requests: int = 16, max_seqs: int = 8,
             "value": round(gen_tokens / dt, 1), "unit": "tokens/sec",
             "model": model_name, "n_requests": n_requests,
             "max_seqs": max_seqs, "prompt_len": prompt_len,
-            "gen_len": gen_len, "lookahead": lookahead,
+            "gen_len": gen_len,
             "mean_latency_s": round(float(np.mean(
                 [o["latency_s"] for o in outs])), 3),
             "mean_ttft_s": round(float(np.mean(

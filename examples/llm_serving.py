@@ -39,15 +39,14 @@ def main():
     # device), ~2x decode tokens/sec at small batch on CPU (PERF.md
     # "serving dispatch overhead"); watch llm_host_dispatches_total
     # vs llm_decode_ticks on /metrics to see the fusion.
-    # mixed_tick=True: prefill chunks ride INSIDE the slab as one
-    # ragged batch with the decode rows (llm_mixed_slabs_total).
+    # Prefill chunks ride INSIDE the slab as one ragged batch with
+    # the decode rows (llm_mixed_slabs_total).
     # kv_dtype="int8": quantized KV pages + per-token scales — ~2x
     # page capacity at fixed HBM (the /memz kv_pool rows show the
     # int8-page / scale_table split; PERF.md "Ragged mixed tick +
     # int8 KV" documents the greedy-parity tolerance).
     with LLMEngine(net, max_seqs=8, page_size=16, num_pages=256,
-                   prefill_buckets=(32, 128),
-                   decode_ticks_per_dispatch=8, mixed_tick=True,
+                   prefill_chunk=32, decode_ticks_per_dispatch=8,
                    kv_dtype="int8") as engine:
         srv = serve_llm(engine)
         host, port = srv.server_address

@@ -170,21 +170,6 @@ define_flag("decode_ticks_per_dispatch", 1,
             "LLMEngine(decode_ticks_per_dispatch=...) overrides per "
             "engine.",
             validator=lambda v: v >= 1)
-define_flag("mixed_tick", True,
-            "Default for LLMEngine(mixed_tick=...): serve prefill "
-            "chunk rows and decode rows as ONE ragged mixed batch "
-            "inside the fused DecodeCarry scan (ops ragged_paged_"
-            "attention) — a slab tick admits queued prefill work with "
-            "zero host dispatches between phases, collapsing the "
-            "alternating prefill/decode tick loop. Token streams are "
-            "identical to the legacy two-op tick path (sampling keys "
-            "fold (nonce, position) only; test-pinned), so ON is the "
-            "default since the speculative parity suite passes with "
-            "it. The legacy alternating loop stays one release behind "
-            "this flag (set False / mixed_tick=False to get it back); "
-            "engines that took the default silently fall back to it "
-            "when lookahead is in play — only an EXPLICIT "
-            "mixed_tick=True conflicts loudly.")
 define_flag("kv_dtype", "",
             "Default storage dtype for LLMEngine's paged KV pool: "
             "'int8' (quantized pages + per-token scale table beside "

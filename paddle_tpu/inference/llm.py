@@ -248,7 +248,7 @@ def _engine_metrics():
             "llm_mixed_slabs_total",
             "fused MIXED prefill+decode slab dispatches (one ragged "
             "batch of chunk rows + decode rows per tick, inside the "
-            "DecodeCarry scan; mixed_tick engines only)"),
+            "DecodeCarry scan)"),
         "mixed_prefill_tokens": reg.counter(
             "llm_mixed_prefill_tokens_total",
             "prompt tokens computed INSIDE mixed slabs (admitted to "
@@ -340,8 +340,8 @@ def _sample(logits, temperature, key, nonces, positions):
     is the request's submission sequence number, position the prompt
     index of the token being fed. Keys therefore depend only on WHAT
     is sampled, never on HOW the scheduler got there — prefix-cache
-    hits, chunked prefill, and lookahead all change the device-call
-    stream but reproduce identical sampled tokens (test-pinned)."""
+    hits and chunked prefill change the device-call stream but
+    reproduce identical sampled tokens (test-pinned)."""
     def mk(n, p):
         return jax.random.fold_in(jax.random.fold_in(key, n), p)
 
@@ -492,7 +492,7 @@ class DecodeCarry(NamedTuple):
     the engine PRNG key) rides OUTSIDE the carry as ordinary arguments:
     the slab pre-reserves pages for up to N tokens at entry, so the
     body never grows the page table and stays shape-stable. A MIXED
-    slab (``mixed_tick=True``) additionally consumes a per-tick xs
+    slab additionally consumes a per-tick xs
     pytree of prefill chunk rows — the host packs the whole prefill
     schedule at slab entry, and a slot whose prompt completes at tick
     j has its sampled first token, start position and emission budget
@@ -640,8 +640,8 @@ class CacheGroupUnsupported(ValueError):
     ``mechanism`` names it: ``"speculative_verify"`` (a rejected draft
     row may already have pushed pages out of the window),
     ``"kv_page_migration"`` (the ``kv_pages/v1`` payload is one group's),
-    ``"fused_slab"`` and ``"lookahead"`` (pages are released between
-    ticks, by the host); ``"prefix_reuse"`` is switched off instead
+    ``"fused_slab"`` (pages are released between ticks, by the host);
+    ``"prefix_reuse"`` is switched off instead
     (``/statusz``): a page keyed by its tokens may be gone.
 
     Or a mode that assumes a page holds K AND V rows of ``kv_heads x
@@ -661,7 +661,7 @@ class CacheGroupUnsupported(ValueError):
     payload's geometry is one ``head_dim``)."""
 
     WINDOW_MODES = ("prefix_reuse", "kv_page_migration",
-                    "speculative_verify", "fused_slab", "lookahead")
+                    "speculative_verify", "fused_slab")
     LATENT_MODES = ("prefix_reuse", "kv_page_migration",
                     "speculative_verify", "int8_pages")
 
@@ -771,55 +771,36 @@ class _PagedVerify(Layer):
         return logits.reshape(b, kq, -1), cache.k_pages, cache.v_pages
 
 
-class _ChunkedPrefill(Layer):
-    """One RAGGED prefill chunk: a fixed budget of T prompt tokens
-    drawn from one or MORE requests' uncached suffixes, processed as a
-    single batched forward (the model's ``ragged_forward`` over T
-    packed prompt rows). Each token carries its own block-table row
-    and position; attention runs per token over its sequence's already-
-    cached pages (shared prefix pages included): causal inside the
-    chunk because a token's limit is its own position + 1 and earlier
-    chunk tokens' K/V are scattered into the pool before the attention
-    reads it. A model with recurrent state carries each sequence's
-    state from row to row of the chunk (``seg`` / ``seg_rows``).
+class _DraftChunk(Layer):
+    """A DRAFT model's ride-along over a packed schedule of prompt rows
+    (speculative engines): the T rows a mixed dispatch carried for the
+    target run through the draft's ``ragged_forward`` into ITS pool, so
+    that the draft holds K/V for every prompt position a later verify
+    window attends. Each row carries its own block-table row, position
+    and limit (0 = a padded row, written to scratch page 0); causal
+    inside the chunk because a row's limit is its own position + 1 and
+    earlier rows' K/V are scattered into the pool before the attention
+    reads it. Nothing is sampled: the target owns the first token."""
 
-    Sampling: for each slot whose prompt COMPLETES inside this chunk,
-    ``sample_idx`` points at its last prompt token's row; that row's
-    logits are sampled into the returned [max_seqs] token vector (rows
-    of non-finishing slots are ignored by the host). Everything stays
-    on device — admission never fetches."""
-
-    def __init__(self, net, attention_impl: str = "xla",
-                 state_impl: str = "xla", moe_impl: str = "xla"):
+    def __init__(self, net, attention_impl: str = "xla"):
         super().__init__()
         self.net = net
         self.attention_impl = attention_impl
-        self.state_impl = state_impl
-        self.moe_impl = moe_impl
 
-    def forward(self, tokens, positions, limits, tables, sample_idx,
-                sample_pos, k_pages, v_pages, temperatures, nonces,
-                key, seg=None, seg_rows=None, conv_state=None,
-                ssm_state=None):
+    def forward(self, tokens, positions, limits, tables, k_pages,
+                v_pages):
         rows = RaggedRows(tokens, positions, limits, tables,
-                          tokens.shape[0], seg, seg_rows)
-        hidden, cache, aux = self.net.ragged_forward(
-            rows, CacheView(k_pages, v_pages, conv_state, ssm_state,
-                            self.attention_impl, self.state_impl,
-                            self.moe_impl))
-        # only the finishing slots' last-token rows need the LM head:
-        # [max_seqs, H] gathered rows, not [T, V] full logits
-        logits = self.net.ragged_logits(
-            jnp.take(hidden, sample_idx, axis=0))
-        nxt = _sample(logits, temperatures, key, nonces, sample_pos)
-        return _engine_outputs(
-            nxt, _slot_aux(self.net, aux, sample_idx), cache)
+                          tokens.shape[0])
+        _, cache, _ = self.net.ragged_forward(
+            rows, CacheView(k_pages, v_pages,
+                            attention_impl=self.attention_impl))
+        return cache.k_pages, cache.v_pages
 
 
 class _MixedTick(Layer):
     """ONE ragged mixed prefill+decode tick: C prefill chunk rows
-    (queued prompts' uncached suffixes, packed exactly like
-    :class:`_ChunkedPrefill`) and B decode rows (each live slot's last
+    (a fixed budget of prompt tokens drawn from one or MORE queued
+    requests' uncached suffixes) and B decode rows (each live slot's last
     token, exactly like :class:`_PagedDecode`) run as a SINGLE batched
     forward of T = C + B token rows through the model's
     ``ragged_forward``. Every row carries its own block table and
@@ -829,13 +810,15 @@ class _MixedTick(Layer):
 
     Exactness: given the K/V pool each row's math is independent of the
     others (per-row gather, per-row softmax, per-row LM-head dot), so
-    the computed KV, logits and sampling keys are IDENTICAL to the
-    legacy two-op path that dispatched the same rows as separate
-    prefill and decode programs (test-pinned token identity, greedy
-    and seeded). The chunk rows of a model with recurrent state are
-    not independent: the rows of one prompt pass state to each other,
-    in order, and several prompts share a chunk (``pseg`` /
-    ``seg_rows``); a slot is never in both halves of one tick.
+    the computed KV, logits and sampling keys do not depend on which
+    rows share a tick (test-pinned token identity against one slot
+    serving the same prompts in turn, greedy and seeded): attention is
+    causal inside the chunk because a row's limit is its own position
+    + 1 and earlier chunk rows' K/V are scattered into the pool before
+    the attention reads it. The chunk rows of a model with recurrent
+    state are not independent: the rows of one prompt pass state to
+    each other, in order, and several prompts share a chunk (``pseg``
+    / ``seg_rows``); a slot is never in both halves of one tick.
 
     Sampling: one [max_seqs] gathered-row LM head per tick — slot b's
     row is its finishing prompt token (``fin_row``) when its prompt
@@ -901,10 +884,10 @@ class _Request:
         self.t_submit = time.monotonic()
         self.t_first = None
         self.t_done = None
-        # lifecycle under lookahead: a "closing" request is no longer
-        # issued new steps, but its pages stay held until every
-        # already-issued step referencing its slot has been fetched
-        # (drain_after = the issue seq it must drain past)
+        # a "closing" request is no longer issued new steps, but its
+        # pages stay held until every already-issued step referencing
+        # its slot has been fetched (drain_after = the issue seq it
+        # must drain past)
         self.closing = False
         self.drain_after = -1
         # a closer that still WANTS its in-flight tokens (closed for
@@ -1082,9 +1065,7 @@ def _engine_status_provider(ref):
             "admission_queue_depth": eng._n_queued,
             "health": eng.health,
             "consecutive_device_errors": eng._consec_device_errors,
-            "lookahead": eng.lookahead,
             "decode_ticks_per_dispatch": eng.decode_ticks_per_dispatch,
-            "mixed_tick": eng.mixed_tick,
             "kv_dtype": eng.kv_dtype,
             "kv_cache_layers": eng._kv_cache_layers,
             "page_bytes": eng._page_bytes,
@@ -1196,8 +1177,8 @@ class LLMEngine:
     only so streams stay failover-deterministic. It composes with
     the prefix cache, chunked/mixed prefill, fused slabs and
     ``kv_dtype="int8"`` (the draft pool quantizes too, under its own
-    ``draft_pool`` ledger owner), not with ``lookahead`` (the round is
-    its own chain) nor with a model that holds recurrent state.
+    ``draft_pool`` ledger owner), not with a model that holds
+    recurrent state.
 
     ``attention_impl``: how the engine programs attend the paged pool
     (:func:`~paddle_tpu.ops.paged_attention.ragged_paged_attention`).
@@ -1209,17 +1190,6 @@ class LLMEngine:
     the CPU path, and the exactness baseline). An explicit value is
     honoured on any platform. The speculative verify window
     (``_PagedVerify``) always takes the gathered path.
-
-    ``lookahead``: issue up to this many decode steps ahead of the
-    token fetch. Steps CHAIN on device (each step's sampled tokens
-    feed the next without a host round-trip), so per-step host
-    traffic drops from one blocking fetch to one fetch per
-    ``lookahead+1`` steps — the lever when dispatch latency rivals
-    step compute. Token streams are
-    IDENTICAL to lookahead=0 (the chain computes the same values);
-    the costs are admission/EOS reaction lagging by up to
-    ``lookahead`` steps and up to ``lookahead`` wasted step-slots of
-    compute after a sequence finishes.
 
     ``decode_ticks_per_dispatch``: DEVICE-RESIDENT DECODE LOOP — run
     N decode ticks as ONE ``lax.scan`` XLA dispatch (default
@@ -1235,29 +1205,27 @@ class LLMEngine:
     truncating early. Token streams are IDENTICAL to N=1 (the scan
     body is the per-tick program; sampling keys fold (nonce,
     position) only — test-pinned), and N=1 keeps the per-tick path:
-    its compiled program carries no scan op. Does not compose with
-    ``lookahead`` (the slab must drain at its boundary). Speculative
-    engines fuse N ROUNDS per dispatch.
+    its compiled program carries no scan op. Speculative engines fuse
+    N ROUNDS per dispatch.
 
-    ``mixed_tick``: ONE RAGGED MIXED TICK (default
-    ``FLAGS.mixed_tick``) — serve the prefill queue's chunk rows AND
-    the live slots' decode step as a single ragged batch per tick,
-    inside the fused ``DecodeCarry`` scan
+    THE PROMPT PATH: ONE RAGGED MIXED TICK. While the prefill queue
+    holds work the loop serves its chunk rows AND the live slots'
+    decode step as a single ragged batch per tick, inside the fused
+    ``DecodeCarry`` scan
     (:func:`~paddle_tpu.ops.paged_attention.ragged_paged_attention`
     makes "mixed" a batch property: every row carries its own block
     table and causal limit). A prompt that completes at tick j of a
     slab starts decoding at tick j+1 ON DEVICE — its sampled first
     token, start position and emission budget are installed into the
     carry by the scan body, so a slab admits prefill work with ZERO
-    host dispatches between the phases; the legacy alternating
-    prefill-tick/decode-tick loop collapses into one dispatch. Token
-    streams are IDENTICAL to the legacy two-op path (each row's math
-    is independent; sampling keys fold (nonce, position) only —
-    test-pinned greedy AND seeded, cache on/off). Composes with
-    ``decode_ticks_per_dispatch`` (a mixed slab runs N mixed ticks);
-    conflicts with ``lookahead`` (drain-at-boundary, like the slab).
-    Speculative engines RIDE the mixed tick (a draft chunk follows
-    each target chunk, so both models' pools cover every position).
+    host dispatches between the phases. Token streams do not depend
+    on which rows share a tick (each row's math is independent;
+    sampling keys fold (nonce, position) only — test-pinned greedy
+    AND seeded, cache on/off, against one slot serving the same
+    prompts in turn). A mixed slab runs up to
+    ``decode_ticks_per_dispatch`` mixed ticks. Speculative engines
+    RIDE the mixed tick for their prompts (a draft chunk follows each
+    target chunk, so both models' pools cover every position).
 
     ``kv_dtype``: KV POOL STORAGE DTYPE (one of ``KV_DTYPES``; default
     ``FLAGS.kv_dtype``, and ``"f32"`` where that is empty).
@@ -1282,24 +1250,20 @@ class LLMEngine:
     those pages read-only and prefills only the uncached suffix; LRU
     eviction reclaims refcount-zero pages under pressure) and CHUNKED
     RAGGED PREFILL (admission enqueues prefill work; ``_loop``
-    processes a fixed ``prefill_chunk``-token budget per tick,
-    interleaved with decode ticks, so a long prompt no longer stalls
-    in-flight decodes and admission performs no blocking device
+    processes a fixed ``prefill_chunk``-token budget per mixed tick,
+    beside the live slots' decode rows, so a long prompt does not
+    stall in-flight decodes and admission performs no blocking device
     fetch — the first token is harvested asynchronously like decode
     tokens). Generations are token-identical with the cache on or off
     (shared pages hold bitwise-identical KV; sampling keys depend only
     on request nonce + position — test-pinned). ``prefill_chunk``
-    defaults to the smallest prefill bucket. Speculative engines take
-    this chunked path like any other engine (a draft chunk rides
-    along each target chunk so the draft pool covers every position).
+    defaults to 64 tokens, or ``max_len`` where that is smaller.
     """
 
     def __init__(self, net, max_seqs: int = 8, page_size: int = 16,
                  num_pages: int = 512, max_len: Optional[int] = None,
-                 prefill_buckets: Sequence[int] = (64, 256, 1024),
                  eos_token_id: Optional[int] = None,
                  seed: int = 0,
-                 lookahead: int = 0,
                  attention_impl: Optional[str] = None,
                  draft_net=None, spec_tokens: int = 4,
                  prefix_cache: bool = True,
@@ -1310,8 +1274,7 @@ class LLMEngine:
                  degraded_after: int = 1,
                  drain_after: int = 8,
                  decode_ticks_per_dispatch: Optional[int] = None,
-                 kv_dtype: Optional[str] = None,
-                 mixed_tick: Optional[bool] = None):
+                 kv_dtype: Optional[str] = None):
         cfg = net.cfg
         self.cfg = cfg
         self.max_seqs = max_seqs
@@ -1321,9 +1284,6 @@ class LLMEngine:
                            cfg.max_position_embeddings)
         self.pages_per_seq = -(-self.max_len // page_size)
         self.eos_token_id = eos_token_id
-        self.prefill_buckets = sorted(
-            b for b in prefill_buckets if b <= self.max_len) or \
-            [self.max_len]
         net.eval()
         # KV pool storage dtype: "int8" → quantized pages + per-token
         # scale tables beside the pool (~2x page capacity at fixed
@@ -1334,8 +1294,7 @@ class LLMEngine:
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
                 f"{sorted(KV_DTYPES)}")
         self.kv_dtype = kv_dtype
-        self.prefill_chunk = int(prefill_chunk or
-                                 self.prefill_buckets[0])
+        self.prefill_chunk = int(prefill_chunk or min(64, self.max_len))
         # the paged K/V pool: one group of cache layers a page shape and
         # lifetime (page_pool.py); block tables and free lists are host
         # control plane, mutated by the allocator there
@@ -1371,7 +1330,6 @@ class LLMEngine:
             # sequence is refused by name, or switched off (/statusz)
             for mechanism, asked in (
                     ("speculative_verify", draft_net is not None),
-                    ("lookahead", bool(lookahead)),
                     ("fused_slab", int(
                         decode_ticks_per_dispatch or _flags.get_flag(
                             "decode_ticks_per_dispatch")) > 1)):
@@ -1449,7 +1407,6 @@ class LLMEngine:
         self._slots: List[Optional[_Request]] = [None] * max_seqs
         # device-chained last tokens (authoritative between fetches)
         self._tokens_dev = jnp.zeros((max_seqs,), jnp.int32)
-        self.lookahead = int(lookahead)
         # DEVICE-RESIDENT DECODE LOOP: fuse N decode ticks into one
         # lax.scan dispatch (DecodeCarry docs the on-device state).
         # Defaults from FLAGS.decode_ticks_per_dispatch. Speculative
@@ -1460,47 +1417,15 @@ class LLMEngine:
                 "decode_ticks_per_dispatch")
         self.decode_ticks_per_dispatch = max(
             1, int(decode_ticks_per_dispatch))
-        if self.decode_ticks_per_dispatch > 1 and self.lookahead:
-            raise ValueError(
-                "decode_ticks_per_dispatch > 1 does not compose with "
-                "lookahead: a fused slab must drain at its boundary "
-                "(on-device EOS decides how far positions advanced), "
-                "and the slab already keeps the device busy for N "
-                "ticks per fetch — use one knob or the other")
-        # MIXED TICK: serve prefill chunk rows and decode rows as ONE
-        # ragged batch inside the fused scan (collapses the
-        # alternating prefill/decode tick loop; the ragged entry
-        # point makes "mixed" a batch property). Default ON
-        # (FLAGS.mixed_tick): the flip is safe because token streams
-        # are pinned identical to the two-op path. Speculative
-        # engines ride mixed slabs for prefill. lookahead conflicts
-        # for the same drain-at-boundary reason
-        # as the slab — but only an EXPLICIT mixed_tick=True raises:
-        # the flag DEFAULT silently yields to lookahead, so the flip
-        # cannot break existing lookahead deployments.
-        mixed_explicit = mixed_tick is not None
-        if mixed_tick is None:
-            mixed_tick = _flags.get_flag("mixed_tick")
-        self.mixed_tick = bool(mixed_tick)
-        if self.mixed_tick and self.lookahead:
-            if mixed_explicit:
-                raise ValueError(
-                    "mixed_tick does not compose with lookahead: a "
-                    "mixed slab must drain at its boundary (the "
-                    "device decides which tick each slot's prompt "
-                    "completed and how far its decode advanced) — "
-                    "use one knob or the other")
-            self.mixed_tick = False
         # recompile-signature guard (same discipline as Model
         # _guard_recompiles): fused-slab programs ("decode_loop", one
         # per distinct realized slab length) are counted separately
-        # from per-tick ("decode_step") and prefill signatures, so an
-        # N-knob sweep can't silently blow the 4096 cap
+        # from per-tick ("decode_step") and mixed-slab ("mixed_tick")
+        # signatures, so an N-knob sweep can't silently blow the 4096 cap
         self._shape_signatures: set = set()
         # perf cost-registry handles (observability/perf.py), one per
-        # compiled engine program — decode tick, fused slab per
-        # realized length, prefill chunk (speculative engines skip:
-        # their round structure has no stable per-dispatch program).
+        # compiled engine program — decode tick, fused slab and mixed
+        # slab per realized length, speculative round.
         # _perf_skipped marks each program's first drained fetch (the
         # one that blocked on ITS XLA compile) so compile time lands
         # in the "compile" phase, not the program's MFU denominator.
@@ -1511,14 +1436,10 @@ class LLMEngine:
         # that are dropped without closing (idempotent — remove_scope
         # of an already-removed scope is a no-op)
         _perf.finalize_scope(self, self._perf_scope)
-        # chunk dispatches not yet attributed: a "p" record only
-        # exists for FINISHING chunks, so the drain scales that
-        # record's FLOPs by every chunk dispatched since the last one
-        self._perf_chunks_unattributed = 0
-        # (issue_seq, slots, tokens, kind, meta): kind "p" = prefill
-        # first-token record, "d" = one decode tick, "D" = fused slab
-        # ([n_ticks, max_seqs] tokens; meta carries the host copy of
-        # the slab-entry budgets + positions the drain replays)
+        # (issue_seq, slots, tokens, kind, meta): kind "d" = one decode
+        # tick, "D" = fused slab, "M" = mixed slab ([n_ticks, max_seqs]
+        # tokens; meta carries the host copy of the slab-entry budgets +
+        # positions the drain replays), "S" = speculative slab
         self._inflight = deque()
         self._issue_seq = 0
         self._fetch_seq = 0
@@ -1545,10 +1466,6 @@ class LLMEngine:
         # its pools have its own kv dims.
         self.spec_k = 0
         if draft_net is not None:
-            if lookahead:
-                raise ValueError(
-                    "speculative decoding does not compose with "
-                    "lookahead (the verify fetch is the round barrier)")
             if spec_tokens < 2:
                 raise ValueError("spec_tokens must be >= 2")
             if draft_net.cfg.vocab_size != cfg.vocab_size:
@@ -1722,21 +1639,6 @@ class LLMEngine:
         # (replica_main overrides it with the replica's fleet name)
         self.audit_scope = "engine"
 
-        chunked = _ChunkedPrefill(net, attention_impl, self.state_impl,
-                                  self.moe_impl)
-
-        def chunk_fn(params, buffers, tokens, positions, limits,
-                     tables, sample_idx, sample_pos, kp, vp, temps,
-                     nonces, key, *state):
-            (out, _) = functional_call(
-                chunked, params, buffers, tokens, positions,
-                limits, tables, sample_idx, sample_pos, kp, vp,
-                temps, nonces, key, *state, training=False)
-            return fetched(out)
-
-        self._chunk_fn = self._jit(
-            chunk_fn, donate_argnums=(8, 9) + ((15, 16) if has_state
-                                               else ()))
         from .prefix_cache import PrefixCache
         self._cache = PrefixCache(page_size) if prefix_cache \
             else None
@@ -1805,25 +1707,23 @@ class LLMEngine:
         if draft_net is not None:
             # draft-side chunked prefill: every prompt chunk row ALSO
             # runs through the draft model into ITS pool (same token/
-            # position/limit/table schedule; the sampled token is
-            # discarded — the target owns sampling). This is what
-            # makes the prefix cache valid for spec engines: prefill
-            # and quantize-on-write are deterministic, so a digest-
-            # matched shared page's draft bytes are exactly what
-            # recomputing the prefix would write.
-            dchunk = _ChunkedPrefill(draft_net, attention_impl)
+            # position/limit/table schedule; the target owns
+            # sampling). This is what makes the prefix cache valid for
+            # spec engines: prefill and quantize-on-write are
+            # deterministic, so a digest-matched shared page's draft
+            # bytes are exactly what recomputing the prefix would
+            # write.
+            dchunk = _DraftChunk(draft_net, attention_impl)
 
             def draft_chunk_fn(params, buffers, tokens, positions,
-                               limits, tables, sample_idx, sample_pos,
-                               kp, vp, temps, nonces, key):
+                               limits, tables, kp, vp):
                 (out, _) = functional_call(
                     dchunk, params, buffers, tokens, positions,
-                    limits, tables, sample_idx, sample_pos, kp, vp,
-                    temps, nonces, key, training=False)
+                    limits, tables, kp, vp, training=False)
                 return out
 
             self._draft_chunk_fn = self._jit(draft_chunk_fn,
-                                             donate_argnums=(8, 9))
+                                             donate_argnums=(6, 7))
 
             # THE SPEC SLAB: n_ticks draft-K/verify-1 rounds as ONE
             # scan program — each tick runs K chained draft probes
@@ -1969,8 +1869,9 @@ class LLMEngine:
         self.n_prefill_ticks = 0
         self.n_decode_ticks = 0
         self.n_mixed_slabs = 0   # mixed prefill+decode slab dispatches
-        # recent tick kinds ('p'refill / 'd'ecode): the interleaving
-        # witness — a long prompt's chunks must bracket decode ticks
+        # recent dispatch kinds ('m'ixed, 'd'ecode, 'D' / 'S' slabs): the
+        # interleaving witness — a long prompt's chunks ride 'm'
+        # dispatches that also carry the live slots' decode rows
         self.tick_history: deque = deque(maxlen=512)
         # recent decode-step wall times (fetch-to-fetch, the same
         # quantity the llm_decode_step_seconds histogram observes):
@@ -2675,10 +2576,9 @@ class LLMEngine:
         slab length, so a decode_ticks_per_dispatch sweep or a
         page-pressure shrink is counted as the recompile it is),
         ``"mixed_tick"`` (the ragged mixed prefill+decode slab, one
-        per realized length — the kind decode_step/decode_loop/
-        prefill signatures collapse into when mixed_tick serves both
-        phases), ``"prefill"`` (a chunk). Bounded at 4096 like
-        the Model guard; FLAGS.recompile_warn_threshold 0 disables.
+        per realized length), ``"spec_round"`` (the speculative slab).
+        Bounded at 4096 like the Model guard;
+        FLAGS.recompile_warn_threshold 0 disables.
         Returns True when the signature is new (a compile is
         coming)."""
         thresh = _flags.get_flag("recompile_warn_threshold")
@@ -2729,30 +2629,15 @@ class LLMEngine:
         the SAME quantity ``_observe_step`` measures (no added clocks
         or syncs); each program's first fetch — the one that blocked
         on its XLA compile — goes to the "compile" phase instead of
-        its MFU accounting. A "p" record covers EVERY chunk
-        dispatched since the last one (non-finishing chunks push no
-        record), so its FLOPs side scales by that count. Under
-        interleaved prefill+decode the phase split is approximate by
-        construction (a chunk issued between decode fetches folds
-        into the adjacent decode interval); the per-program FLOPs
-        accounting stays exact."""
-        n = 1
+        its MFU accounting."""
         if kind == "M":
             pkey = ("mixed_tick", host_shape0)
         elif kind == "S":
             pkey = ("spec_round", host_shape0)
         elif kind == "D":
             pkey = ("decode_loop", host_shape0)
-        elif kind == "d":
-            pkey = ("decode_step",)
         else:
-            pkey = ("prefill_chunk",)
-            # consume the chunk count even when the interval below is
-            # unmeasurable: dispatches drained across an idle gap are
-            # simply lost (their interval is too), never carried into
-            # a later record whose interval doesn't cover them
-            n = max(1, self._perf_chunks_unattributed)
-            self._perf_chunks_unattributed = 0
+            pkey = ("decode_step",)
         if pkey not in self._perf_skipped:
             # the program's first drained record blocked on ITS
             # compile — marked even when unmeasurable, so a post-idle
@@ -2772,12 +2657,10 @@ class LLMEngine:
         if _perf.enabled():
             h = self._perf_programs.get(pkey)
             if h is not None:
-                h.record(pdt, tokens=emitted, dispatches=n)
-            _perf.record_phase(
-                "llm", "prefill" if kind == "p" else "decode", pdt)
+                h.record(pdt, tokens=emitted)
+            _perf.record_phase("llm", "decode", pdt)
         if _goodput.enabled():
-            # prefill and decode intervals are both device compute:
-            # productive seconds on the time ledger
+            # device compute: productive seconds on the time ledger
             _goodput.note("productive", pdt)
 
     def _jit(self, fn, **kw):
@@ -2794,8 +2677,8 @@ class LLMEngine:
 
     def _inflight_tokens(self, slot: int) -> int:
         """Tokens already issued for ``slot`` and not yet fetched:
-        one per per-tick/prefill record naming it, its device budget
-        for a fused-slab record."""
+        one per per-tick record naming it, its device budget for a
+        fused-slab record."""
         n = 0
         for _, slots_list, _, kind, meta in self._inflight:
             if kind in ("D", "M", "S"):
@@ -2813,9 +2696,10 @@ class LLMEngine:
         Chunked path: admission only RESERVES — match the prefix
         cache, map shared pages read-only, allocate suffix pages, and
         enqueue the prefill work. No device call happens here; the
-        suffix is computed by ``_prefill_tick`` chunks interleaved
-        with decode, and the first token is harvested asynchronously
-        in ``_drain_one`` like any decode token."""
+        suffix is computed by the chunk rows of ``_issue_mixed``
+        dispatches, beside the live slots' decode rows, and the first
+        token is harvested asynchronously in ``_drain_one`` like any
+        decode token."""
         if self._health == "draining":
             return "shed"
         n = len(req.prompt)
@@ -2904,145 +2788,6 @@ class LLMEngine:
         return [i for i, s in enumerate(self._slots)
                 if s is not None and not s.closing and s.prefill_done]
 
-    def _prefill_tick(self, ph=_trace.NOOP_SPAN):
-        """Process ONE chunk of prefill work: up to ``prefill_chunk``
-        prompt tokens from the queue's head request(s), packed ragged
-        into a single batched forward. Requests whose prompt completes
-        inside the chunk transition to decode — their sampled first
-        token chains into ``_tokens_dev`` ON DEVICE and is pushed as an
-        in-flight record, so decode steps can follow immediately and
-        the host fetches it later like any decode token."""
-        T = self.prefill_chunk
-        ps = self.page_size
-        tok = np.zeros((T,), np.int32)
-        pos = np.zeros((T,), np.int32)
-        lim = np.zeros((T,), np.int32)
-        row_slot = np.full((T,), -1, np.int64)   # padded rows: scratch
-        sample_idx = np.zeros((self.max_seqs,), np.int32)
-        sample_pos = np.zeros((self.max_seqs,), np.int32)
-        finishing: List[_Request] = []
-        touched: List[_Request] = []
-        chunks: List[tuple] = []   # (slot, first position, tokens)
-        seg, segrows, max_segs = self._chunk_segments((T,))
-        used = 0
-        while self._prefill_q and used < T \
-                and (max_segs is None or len(chunks) < max_segs):
-            req = self._prefill_q[0]
-            n = len(req.prompt)
-            take = min(T - used, n - req.prefill_pos)
-            self._pool.ensure_range(req.slot, req.prefill_pos, take)
-            for j in range(take):
-                p = req.prefill_pos + j
-                tok[used + j] = req.prompt[p]
-                pos[used + j] = p
-                lim[used + j] = p + 1
-            row_slot[used:used + take] = req.slot
-            if seg is not None:
-                seg[used:used + take] = len(chunks)
-                segrows[len(chunks)] = req.slot
-            chunks.append((req.slot, req.prefill_pos, take))
-            req.prefill_pos += take
-            used += take
-            touched.append(req)
-            if req.spans is not None:
-                req.spans["prefill"].add_event(
-                    "chunk", {"tokens": take, "pos": req.prefill_pos})
-            if req.prefill_pos >= n:
-                self._prefill_q.popleft()
-                finishing.append(req)
-                sample_idx[req.slot] = used - 1
-                sample_pos[req.slot] = n - 1
-            else:
-                break   # chunk budget exhausted mid-prompt
-        if _faults.enabled():
-            _faults.check("device.dispatch")
-        self._guard_recompiles("prefill")
-        chunk_args = (self._params, self._buffers, jnp.asarray(tok),
-                      jnp.asarray(pos), jnp.asarray(lim),
-                      self._pool.row_tables(row_slot),
-                      jnp.asarray(sample_idx),
-                      jnp.asarray(sample_pos),
-                      self.k_pages, self.v_pages,
-                      jnp.asarray(self.temperatures),
-                      jnp.asarray(self._nonces), self._key)
-        if seg is not None:
-            chunk_args += (jnp.asarray(seg), jnp.asarray(segrows)) \
-                + self._state_args()
-        if _perf.enabled():
-            self._perf_program("prefill_chunk", (), self._chunk_fn,
-                               chunk_args)
-            self._perf_chunks_unattributed += 1
-        nxt, fetch = self._take_outputs(self._chunk_fn(*chunk_args))
-        self._count_dispatch()
-        self._stamp_state(ph, False, len(chunks), 0)
-        released = self._release_behind(
-            (slot, p0 + take) for slot, p0, take in chunks)
-        if ph is not _trace.NOOP_SPAN:
-            chunk = ChunkRows(row_slot[None], lim[None])
-            self._stamp_kv_pages(ph, ([], T, self.attention_impl, chunk),
-                                 *self._draft_chunk_call(chunk, T),
-                                 released=released)
-        if self.spec_k:
-            # draft ride-along: the SAME packed chunk schedule runs
-            # through the draft net so the draft pool holds valid KV
-            # for every prompt position a later verify window attends
-            # to. Prefill + quantize-on-write are deterministic, so
-            # shared prefix pages carry identical draft KV across the
-            # requests that hit them — temperature>0 realized streams
-            # stay cache-on/off identical (greedy needs none of this:
-            # prefix acceptance reproduces the target chain exactly).
-            self.draft_k_pages, self.draft_v_pages = \
-                self._draft_chunk_fn(
-                    self._draft_params, self._draft_buffers,
-                    chunk_args[2], chunk_args[3], chunk_args[4],
-                    chunk_args[5], chunk_args[6], chunk_args[7],
-                    self.draft_k_pages, self.draft_v_pages,
-                    chunk_args[10], chunk_args[11], self._key)[1:]
-            self._count_dispatch()
-        if finishing:
-            mask = np.zeros((self.max_seqs,), bool)
-            for req in finishing:
-                mask[req.slot] = True
-            # first tokens chain on device; the host fetch happens in
-            # _drain_one, in issue order, like any decode step
-            self._tokens_dev = jnp.where(jnp.asarray(mask), nxt,
-                                         self._tokens_dev)
-            self._issue_seq += 1
-            self._inflight.append(
-                (self._issue_seq, [r.slot for r in finishing], fetch,
-                 "p", None))
-            for req in finishing:
-                req.prefill_done = True
-                self.context_lens[req.slot] = len(req.prompt)
-                if req.spans is not None:
-                    # the suffix is computed (last chunk issued); what
-                    # remains before the first token reaches the host
-                    # is the async drain — its own phase
-                    tp = time.perf_counter()
-                    req.spans["prefill"].end(tp)
-                    req.spans["first_token"] = _trace.start_span(
-                        "llm.first_token", parent=req.spans["root"],
-                        t0=tp)
-        if self._cache is not None:
-            for req in touched:
-                # promote freshly-written FULL prompt pages to shared
-                # as soon as their chunk is issued (immutable from
-                # here on: every later write for this sequence lands
-                # at positions >= len(prompt) > the page). Incremental
-                # registration lets a request admitted while a long
-                # shared prompt is still mid-prefill hit its pages.
-                for i in range(req.n_reg_pages, req.prefill_pos // ps):
-                    self._cache.register(
-                        req.digests[i],
-                        int(self.block_tables[req.slot, i]),
-                        req.prompt[i * ps:(i + 1) * ps])
-                req.n_reg_pages = max(req.n_reg_pages,
-                                      req.prefill_pos // ps)
-        self.n_prefill_ticks += 1
-        self.tick_history.append("p")
-        self._m["prefill_ticks"].inc()
-        self._update_kv_gauge()
-
     def _admit_arrivals(self, pending: list, ph) -> None:
         """Admission's part of an iteration, inside its
         ``llm.loop.admit`` phase ``ph``."""
@@ -3079,8 +2824,8 @@ class LLMEngine:
                         self._admit_arrivals(pending, ph)
                 if ctl:
                     # control ops run HERE, before admission: the
-                    # previous iteration drained its dispatches to the
-                    # lag boundary, so the pool arrays are settled
+                    # previous iteration drained its dispatches,
+                    # so the pool arrays are settled
                     # outputs (no donated input buffer is still feeding
                     # a queued program). Each op resolves its own future
                     # and never raises into the loop. The phases stay
@@ -3092,7 +2837,7 @@ class LLMEngine:
                     with _trace.phase("llm.loop.admit") as ph:
                         self._admit_arrivals(pending, ph)
                 busy = False
-                mixed = self.mixed_tick and bool(self._prefill_q)
+                mixed = bool(self._prefill_q)
                 if mixed:
                     # ONE fused mixed slab: the prefill queue's chunk
                     # rows AND the live slots' decode ticks ride one
@@ -3108,22 +2853,12 @@ class LLMEngine:
                     self._issue_mixed(
                         [] if self.spec_k else self._live_slots())
                     busy = True
-                elif self._prefill_q:
-                    # two-op tick (mixed_tick off — kept as
-                    # the parity baseline): ONE chunk of prefill,
-                    # then (below) ONE decode step for the live
-                    # batch: a long prompt's chunks interleave with
-                    # decode ticks instead of stalling in-flight
-                    # generations for its whole prefill
-                    with _trace.phase("llm.issue.prefill") as ph:
-                        self._prefill_tick(ph)
-                    busy = True
                 self._m["prefill_queue"].set(len(self._prefill_q))
                 live = self._live_slots() if self.spec_k or not mixed \
                     else []
                 if live and self.spec_k:
                     # the speculative slab plans from realized state:
-                    # a mixed/prefill record's async first token must
+                    # a mixed record's async first token must
                     # land in req.tokens (in issue order, TTFT at the
                     # fetch) before budgets are computed, and it may
                     # already close the slot (it re-filters `live`).
@@ -3149,20 +2884,11 @@ class LLMEngine:
                     self._m["tick_ratio"].set(
                         self.n_prefill_ticks /
                         max(1, self.n_decode_ticks))
-                if busy:
-                    # fetch with a lag: the chain keeps the device busy
-                    # (fused slabs — pure-decode AND mixed — always
-                    # drain to the boundary: the next slab's budgets/
-                    # positions need this one's realized EOS/length
-                    # outcome)
-                    lag = 0 if (self.decode_ticks_per_dispatch > 1
-                                or self.mixed_tick) \
-                        else self.lookahead
-                    while len(self._inflight) > lag:
-                        self._drain_one()
-                else:
-                    while self._inflight:   # nothing to issue: drain
-                        self._drain_one()
+                # drain what was issued: the next dispatch's budgets/
+                # positions need this one's realized EOS/length outcome
+                while self._inflight:
+                    self._drain_one()
+                if not busy:
                     self._maybe_finalize()
                     # idle gap ends here: without this reset the first
                     # fetch after a quiet period would record the whole
@@ -3461,8 +3187,7 @@ class LLMEngine:
         return (np.full(shape, g, np.int32),
                 np.full(shape[:-1] + (g,), self.max_seqs, np.int32), g)
 
-    def _stamp_state(self, ph, decode_part: bool, chunk_seqs: int,
-                     live_rows: int) -> None:
+    def _stamp_state(self, ph, chunk_seqs: int, live_rows: int) -> None:
         """``state_rows`` and ``state_bytes`` of one dispatch on its issue
         phase (only while tracing): the rows whose recurrent state the
         tick advances (its live decode rows and the prompts in its
@@ -3481,7 +3206,7 @@ class LLMEngine:
             rows = live_rows + chunk_seqs
             chunk = int(self._state_spec["max_chunk_sequences"]) \
                 if chunk_seqs else 0
-            slots = self.max_seqs if decode_part else 0
+            slots = self.max_seqs
             row = self._state_row_bytes
             moved = (slots + chunk) * row["conv_state"] + (
                 rows if self.state_impl == "pallas"
@@ -3712,7 +3437,7 @@ class LLMEngine:
                 ph.set_attr("issue_seq", self._issue_seq) \
                     .set_attr("live_rows", len(live)).set_attr("ticks", 1) \
                     .set_attr("h2d_transfers", 1)
-                self._stamp_state(ph, True, 0, len(live))
+                self._stamp_state(ph, 0, len(live))
                 self._stamp_kv_pages(
                     ph, ([(slot, lens[slot]) for slot in live],
                          self.max_seqs, self.attention_impl),
@@ -3721,7 +3446,7 @@ class LLMEngine:
     def _plan_slab(self, live: List[int], N: int):
         """The decode-side slab plan, shared by the pure-decode slab
         and the MIXED slab so their coverage/truncation/shrink rules
-        can never drift (the mixed-vs-legacy token-identity pin
+        can never drift (the N = 1 against N = 8 token-identity pin
         depends on it). Per live slot: provable emission ``want``
         (length completion decided on the host, like :meth:`_issue`),
         KV-page PRE-RESERVATION for up to N tokens, truncation when
@@ -3793,8 +3518,8 @@ class LLMEngine:
         returned to the pool before dispatch.
 
         EOS/limit detection, sampling, position advance and page
-        writes all happen on device; the drain (same loop iteration —
-        a slab is its own lookahead) replays the device's masking
+        writes all happen on device; the drain (same loop iteration)
+        replays the device's masking
         decisions from the host copy of the budgets."""
         with _trace.phase("llm.issue.slab") as ph:
             N = self.decode_ticks_per_dispatch
@@ -3830,7 +3555,7 @@ class LLMEngine:
                                     "pos0": {s: plan[s][0] for s in live}}))
             ph.set_attr("issue_seq", self._issue_seq) \
                 .set_attr("live_rows", len(live)).set_attr("ticks", n_eff)
-            self._stamp_state(ph, True, 0, len(live))
+            self._stamp_state(ph, 0, len(live))
             self._stamp_kv_pages(
                 ph, (((slot, plan[slot][0] + j + 1) for slot in live
                       for j in range(budgets[slot])),
@@ -4026,9 +3751,14 @@ class LLMEngine:
             if self.spec_k:
                 # draft ride-along over the slab's WHOLE packed chunk
                 # schedule, flattened to one ragged chunk (padding rows
-                # carry zero tables → scratch page 0): same coverage
-                # argument as _prefill_tick's ride-along
-                zeros = jnp.zeros((self.max_seqs,), jnp.int32)
+                # carry zero tables → scratch page 0), so that the draft
+                # pool holds valid KV for every prompt position a later
+                # verify window attends to. Prefill + quantize-on-write
+                # are deterministic, so shared prefix pages carry
+                # identical draft KV across the requests that hit them —
+                # temperature>0 realized streams stay cache-on/off
+                # identical (greedy needs none of this: prefix acceptance
+                # reproduces the target chain exactly)
                 self.draft_k_pages, self.draft_v_pages = \
                     self._draft_chunk_fn(
                         self._draft_params, self._draft_buffers,
@@ -4037,10 +3767,7 @@ class LLMEngine:
                         jnp.asarray(plim[:n_run].reshape(-1)),
                         self._pool.row_tables(
                             pslot[:n_run].reshape(-1)),
-                        zeros, zeros,
-                        self.draft_k_pages, self.draft_v_pages,
-                        jnp.asarray(self.temperatures),
-                        jnp.asarray(self._nonces), self._key)[1:]
+                        self.draft_k_pages, self.draft_v_pages)
                 self._count_dispatch()
             self._issue_seq += 1
             slots_list = sorted(meta_bud)
@@ -4054,9 +3781,13 @@ class LLMEngine:
             if self._cache is not None:
                 for req in touched:
                     # promote freshly-written FULL prompt pages to shared
-                    # (same incremental registration as the legacy chunk
-                    # tick — a quantized page shares by the same token
-                    # digests; the bytes it holds are deterministic)
+                    # as soon as their chunk is issued (immutable from
+                    # here on: every later write for this sequence lands
+                    # at positions >= len(prompt) > the page; a quantized
+                    # page shares by the same token digests, the bytes it
+                    # holds are deterministic). Incremental registration
+                    # lets a request admitted while a long shared prompt
+                    # is still mid-prefill hit its pages.
                     for i in range(req.n_reg_pages,
                                    req.prefill_pos // ps):
                         self._cache.register(
@@ -4090,7 +3821,7 @@ class LLMEngine:
                     .set_attr("ticks", n_run) \
                     .set_attr("h2d_transfers", 2 + len(
                         jax.tree_util.tree_leaves(mixed_args[3:7])))
-                self._stamp_state(ph, True, len(touched),
+                self._stamp_state(ph, len(touched),
                                   len(slots_list) - len(start))
                 chunk = ChunkRows(pslot[:n_run], plim[:n_run])
                 # a decode row of tick j attends pos0 + j + 1; a slot
@@ -4128,9 +3859,9 @@ class LLMEngine:
         Over-reserved pages (low acceptance) stay with their slots
         for the next slab — used or freed at close, never leaked.
 
-        The loop has drained all in-flight records FIRST (as for the
-        legacy round): a mixed/prefill record's async first token must
-        land before budgets are computed, and a mixed-finishing slot's
+        The loop has drained all in-flight records FIRST: a mixed
+        record's async first token must land before budgets are
+        computed, and a mixed-finishing slot's
         ``context_lens`` is only advanced by its drain."""
         with _trace.phase("llm.issue.spec") as ph:
             live = [s for s in live if self._slots[s] is not None
@@ -4305,8 +4036,7 @@ class LLMEngine:
                 emitted = self._drain_slab(seq, slots_list, host, meta,
                                            delivered)
             else:
-                if kind == "d":
-                    self.n_steps += 1
+                self.n_steps += 1
                 emitted = 0
                 for slot in slots_list:
                     req = self._slots[slot]
@@ -4326,7 +4056,7 @@ class LLMEngine:
                 self._perf_attribute(kind, host.shape[0]
                                      if kind in ("D", "M", "S") else 0,
                                      emitted)
-            self._observe_step(emitted, timed=(kind != "p"))
+            self._observe_step(emitted)
             self._maybe_finalize()
             ph.set_attr("tokens", emitted)
 
@@ -4469,16 +4199,12 @@ class LLMEngine:
         self._m["slab_ticks"].observe(rounds)
         return emitted
 
-    def _observe_step(self, emitted: int, timed: bool = True):
+    def _observe_step(self, emitted: int):
         """Per-fetch timing → step-time and tokens/sec histograms.
-        Fetch-to-fetch wall time is the honest denominator under
-        lookahead (the issue is async; the fetch is where the engine
-        actually pays). ``timed=False`` (chunked-prefill first-token
-        fetches): count the tokens but keep prefill wall time OUT of
-        the decode step/tps histograms — still advance the fetch
-        clock so the next decode interval starts here."""
+        Fetch-to-fetch wall time is the honest denominator (the issue
+        is async; the fetch is where the engine actually pays)."""
         now = time.monotonic()
-        if timed and self._last_fetch_t is not None:
+        if self._last_fetch_t is not None:
             dt = now - self._last_fetch_t
             self._m["step"].observe(dt)
             self.step_durations.append(dt)

@@ -6,8 +6,8 @@ resource — wall-clock time. A process-wide :class:`TimeLedger`
 attributes every second since arming to exactly one bucket:
 
 - ``productive`` — device compute: the same wall-time deltas the perf
-  registry already observes (train dispatch, llm decode/prefill
-  fetch intervals);
+  registry already observes (train dispatch, llm fetch
+  intervals);
 - ``compile`` — XLA compile waits (first-signature train steps, each
   engine program's first fetch);
 - ``input_wait`` — the dataloader/prefetch starvation the

@@ -7,7 +7,7 @@ analytic decompositions) left open: a process-wide registry that
 
 - captures **XLA cost analysis** (FLOPs, bytes accessed) once per
   compiled program signature — the train step/loop in ``hapi.Model``
-  and the decode tick/slab + prefill chunk programs in
+  and the decode tick/slab + mixed slab programs in
   ``inference.LLMEngine`` register here at compile/trace time (the
   same boundary ``_guard_recompiles`` already polices, same 4096-cap
   discipline, see :mod:`paddle_tpu.cost_model` for the cache);
@@ -20,8 +20,8 @@ analytic decompositions) left open: a process-wide registry that
   (``FLAGS.perf_peak_flops`` / ``FLAGS.perf_peak_hbm_gbps``) and a
   nominal CPU fallback;
 - accumulates a **step-time breakdown** per component (train: jit
-  dispatch vs compile vs metric-drain sync; llm: decode vs prefill
-  device time between fetches) derived from the existing span-phase
+  dispatch vs compile vs metric-drain sync; llm: compile vs device
+  time between fetches, ``decode``) derived from the existing span-phase
   measurement points, so /perfz can say WHERE wall time goes, not
   just that totals moved.
 
@@ -281,14 +281,10 @@ class ProgramHandle:
         self._lower = lower
         self._reg = reg
 
-    def record(self, seconds: float, tokens: int = 0,
-               dispatches: int = 1) -> None:
-        """Attribute ``seconds`` of measured busy wall time covering
-        ``dispatches`` executions of this program (a fetch interval
-        that drained M chunk dispatches passes M, so the FLOPs side
-        scales with the work actually done)."""
-        self._reg._record(self, float(seconds), int(tokens),
-                          int(dispatches))
+    def record(self, seconds: float, tokens: int = 0) -> None:
+        """Attribute ``seconds`` of measured busy wall time to one
+        execution of this program."""
+        self._reg._record(self, float(seconds), int(tokens))
 
     def to_dict(self) -> dict:
         fps = (self.flops / (self.seconds / self.dispatches)
@@ -401,27 +397,27 @@ class PerfRegistry:
 
     # -- hot-path accounting --------------------------------------------
     def _record(self, h: ProgramHandle, seconds: float,
-                tokens: int, dispatches: int = 1) -> None:
+                tokens: int) -> None:
         """Float adds under the registry lock — NOTHING else on the
         hot path (the cost resolved at registration). Programs whose
         backend reported no analysis are EXCLUDED from MFU (visible
         via the failure counter + /perfz cost_failed), never folded
         in as zero-FLOP busy time that would deflate the ratio."""
         with self._mu:
-            h.dispatches += dispatches
+            h.dispatches += 1
             h.seconds += seconds
             h.tokens += tokens
             if h.cost_resolved:
                 b = self._buckets.setdefault(
                     int(time.time()), [0.0, 0.0, 0.0])
-                b[0] += (h.flops or 0.0) * dispatches
-                b[1] += (h.bytes_accessed or 0.0) * dispatches
+                b[0] += h.flops or 0.0
+                b[1] += h.bytes_accessed or 0.0
                 b[2] += seconds
 
     def record_phase(self, component: str, phase: str,
                      seconds: float) -> None:
         """Accumulate one step-time-breakdown phase (train: dispatch /
-        compile / drain; llm: decode / prefill). Callers pass the SAME
+        compile / drain; llm: decode / compile). Callers pass the SAME
         wall-time deltas their existing histograms observe — the
         breakdown adds no clocks of its own."""
         with self._mu:

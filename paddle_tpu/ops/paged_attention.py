@@ -1265,7 +1265,7 @@ def ragged_paged_attention(q, kv_k: KVStore, kv_v: KVStore,
     scattered into the pool. A mixed prefill+decode tick is just a
     batch whose rows happen to come from both phases — nothing in
     the contract distinguishes them, which is what lets the engine
-    collapse its alternating tick loop into one dispatch.
+    serve both in one dispatch.
 
     Pure-functional and trace-safe by contract: every input may be a
     traced value, so the op is callable from inside a ``lax.scan``

@@ -315,7 +315,7 @@ def make_engine_from_spec(spec: dict):
     ekw.setdefault("max_seqs", 4)
     ekw.setdefault("page_size", 4)
     ekw.setdefault("num_pages", 96)
-    ekw.setdefault("prefill_buckets", (16,))
+    ekw.setdefault("prefill_chunk", 16)
     ekw.setdefault("seed", 0)
     return LLMEngine(net, **ekw)
 

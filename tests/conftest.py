@@ -211,6 +211,29 @@ class _Dispatches:
             mp.undo()
             eng._issue, eng._decode_fn = issue, decode_fn
 
+    @staticmethod
+    def tokens_beside_chunks(spans, prompt_tokens):
+        """``{issue_seq: tokens}`` of the dispatches that carried a chunk of
+        the request whose prompt has ``prompt_tokens`` tokens (its
+        ``llm.prefill`` span's ``chunk`` events) AND delivered tokens to
+        ANOTHER request already in decode (that one's ``slab`` events):
+        the witness that a long prompt's chunks ride beside live decode
+        rows and do not stall them."""
+        long = [s for s in spans if s["name"] == "llm.prefill"
+                and s["attrs"]["prompt_tokens"] == prompt_tokens]
+        assert len(long) == 1, long
+        chunks = {e["attrs"]["issue_seq"] for e in long[0]["events"]
+                  if e["name"] == "chunk"}
+        out = {}
+        for s in spans:
+            if s["name"] == "llm.decode" \
+                    and s["parent_id"] != long[0]["parent_id"]:
+                for e in s["events"]:
+                    if e["name"] == "slab" and e["attrs"]["tokens"] \
+                            and e["attrs"]["issue_seq"] in chunks:
+                        out[e["attrs"]["issue_seq"]] = e["attrs"]["tokens"]
+        return out
+
     @classmethod
     def transfers(cls, spans):
         """``{phase name: the set of h2d_transfers its dispatches carry}``."""
@@ -243,7 +266,7 @@ class _Dispatches:
 def issue_phases():
     """Helpers for a test of the ``llm.issue.*`` phases: ``serve`` (a
     repeatable run), ``launched``, ``digest``, ``check_marks``,
-    ``transfers``, ``decode_staging``; tracing is off and the table empty
+    ``transfers``, ``tokens_beside_chunks``, ``decode_staging``; tracing is off and the table empty
     before and after."""
     from paddle_tpu.observability import tracing
     tracing.disable()

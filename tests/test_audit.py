@@ -215,7 +215,7 @@ def _tiny_engine():
                      max_position_embeddings=96, hidden_dropout=0.0,
                      attention_dropout=0.0)
     return LLMEngine(GPTForCausalLM(cfg), max_seqs=2, page_size=4,
-                     num_pages=32, prefill_buckets=(16,), seed=0)
+                     num_pages=32, prefill_chunk=16, seed=0)
 
 
 def test_engine_result_digest_is_the_chain_of_its_stream():

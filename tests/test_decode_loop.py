@@ -33,10 +33,10 @@ def tiny_gpt():
 
 
 def run(net, prompts, gen, n, *, temperature=0.0, cache=True,
-        eos=None, page_size=4, num_pages=128, chunk=None, seed=0,
+        eos=None, page_size=4, num_pages=128, chunk=16, seed=0,
         max_seqs=4):
     eng = LLMEngine(net, max_seqs=max_seqs, page_size=page_size,
-                    num_pages=num_pages, prefill_buckets=(16,),
+                    num_pages=num_pages,
                     prefix_cache=cache, prefill_chunk=chunk,
                     eos_token_id=eos, seed=seed,
                     decode_ticks_per_dispatch=n)
@@ -152,7 +152,7 @@ def test_cancel_and_deadline_resolve_within_slab_boundary():
     boundary (not after the full generation) and free their pages."""
     net = tiny_gpt()
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,),
+                    prefill_chunk=16,
                     decode_ticks_per_dispatch=8)
     with eng:
         rng = np.random.RandomState(5)
@@ -178,38 +178,41 @@ def test_cancel_and_deadline_resolve_within_slab_boundary():
     assert len(eng._free_pages) == eng.num_pages - 1, "pages leaked"
 
 
-def test_fused_ticks_interleave_with_chunked_prefill():
-    """A long prompt admitted mid-decode prefills in chunks BETWEEN
-    slabs (tick history brackets 'p' with 'D'), and both requests'
-    streams match the per-tick run."""
+def test_fused_ticks_interleave_with_chunked_prefill(issue_phases):
+    """A long prompt admitted mid-decode prefills in chunks INSIDE mixed
+    slabs that carry the short request's decode rows: the short request
+    RECEIVES tokens from dispatches that carried the long prompt's chunks,
+    and both streams match the dense reference at N = 1 and 4."""
+    from paddle_tpu.observability import tracing
     net = tiny_gpt()
     rng = np.random.RandomState(6)
     short = rng.randint(0, 97, 4).tolist()
     long = rng.randint(0, 97, 40).tolist()
-
-    def interleaved(n):
-        # mixed_tick off: this test witnesses the two-op interleave
-        # ('p' chunks bracketed by 'D' slabs); the ragged mixed tick
-        # has its own gate in test_mixed_ragged.py
-        eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=128,
-                        prefill_buckets=(64,), prefill_chunk=8,
-                        decode_ticks_per_dispatch=n, mixed_tick=False)
-        with eng:
+    want = [np.asarray(net.generate(jnp.asarray([p]), max_new_tokens=g)
+                       )[0, len(p):].tolist()
+            for p, g in ((short, 24), (long, 8))]
+    for n in (1, 4):
+        tracing.clear()
+        tracing.enable()
+        with LLMEngine(net, max_seqs=2, page_size=4, num_pages=128,
+                       prefill_chunk=8,
+                       decode_ticks_per_dispatch=n) as eng:
             f1 = eng.submit(short, max_new_tokens=24)
             while not eng.n_decode_ticks:   # f1 decoding
                 time.sleep(0.002)
             f2 = eng.submit(long, max_new_tokens=8)
-            outs = [f1.result(timeout=120), f2.result(timeout=120)]
+            got = [f1.result(timeout=120), f2.result(timeout=120)]
             hist = "".join(eng.tick_history)
+            slabs, chunks = eng.n_mixed_slabs, eng.n_prefill_ticks
+        tracing.disable()
         assert len(eng._free_pages) == eng.num_pages - 1
-        return outs, hist
-
-    ref, _ = interleaved(1)
-    got, hist = interleaved(4)
-    assert [o["output_ids"] for o in got] == \
-        [o["output_ids"] for o in ref]
-    # witness: at least one prefill chunk ran between decode slabs
-    assert "DpD" in hist.replace("pp", "p") or "Dp" in hist, hist
+        assert [o["output_ids"] for o in got] == want
+        # the short prompt's chunk, then the long prompt's five (40 / 8);
+        # N = 1: a mixed dispatch a chunk; N = 4: a slab holds up to four
+        assert chunks >= 6 and slabs >= (6 if n == 1 else 3), (slabs, hist)
+        beside = issue_phases.tokens_beside_chunks(
+            tracing.finished_spans(), len(long))
+        assert len(beside) >= 2 and sum(beside.values()) >= 5, (beside, hist)
 
 
 def test_n1_compiles_zero_scan_ops():
@@ -265,21 +268,13 @@ def test_recompile_guard_counts_slab_kinds_separately():
     assert ("decode_loop", 8) in eng8._shape_signatures
 
 
-def test_lookahead_conflict_raises():
-    net = tiny_gpt()
-    with pytest.raises(ValueError, match="lookahead"):
-        LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                  prefill_buckets=(16,), lookahead=2,
-                  decode_ticks_per_dispatch=4)
-
-
 def test_flag_default_feeds_engine():
     from paddle_tpu.core import flags
     net = tiny_gpt()
     flags.set_flags({"decode_ticks_per_dispatch": 4})
     try:
         eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                        prefill_buckets=(16,))
+                        prefill_chunk=16)
         assert eng.decode_ticks_per_dispatch == 4
         eng.close()
     finally:
@@ -309,7 +304,7 @@ def test_inline_prefill_first_token_is_async():
                                     max_new_tokens=8))[0, len(p):]
             .tolist() for p in prompts]
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,), draft_net=draft,
+                    prefill_chunk=16, draft_net=draft,
                     spec_tokens=3)
     with eng:
         outs = eng.generate(prompts, max_new_tokens=8)

@@ -168,11 +168,13 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
         assert served_gap(params, d, p, list(got)) <= TOL
 
 
-@pytest.mark.parametrize("knobs", [dict(mixed_tick=False),
-                                   dict(decode_ticks_per_dispatch=4),
-                                   dict(lookahead=2, mixed_tick=False)],
-                         ids=["two_op_ticks", "slab", "lookahead"])
+@pytest.mark.parametrize("knobs", [dict(max_seqs=1),
+                                   dict(decode_ticks_per_dispatch=4)],
+                         ids=["one_slot", "slab"])
 def test_the_other_tick_paths_serve_the_same_tokens(model, knobs):
+    """``one_slot``: all prompts through ONE slot in turn: no decode row
+    ever beside a prompt row, the state row restarted for each, and the
+    state arrays at their smallest (the slot and the scratch row)."""
     net, params, d = model
     prompts = prompts_of((12, 27, 6), seed=4)
     kw = dict(max_seqs=2, page_size=8, num_pages=64, max_len=128,
@@ -180,15 +182,15 @@ def test_the_other_tick_paths_serve_the_same_tokens(model, knobs):
     with LLMEngine(net, **kw) as eng:
         want = [f.result(timeout=600)["output_ids"] for f in
                 [eng.submit(p, max_new_tokens=9) for p in prompts]]
-    with LLMEngine(net, **kw, **knobs) as eng:
+    with LLMEngine(net, **{**kw, **knobs}) as eng:
         got = [f.result(timeout=600)["output_ids"] for f in
                [eng.submit(p, max_new_tokens=9) for p in prompts]]
     assert [list(g) for g in got] == [list(w) for w in want]
 
 
-@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1),
                                    dict(decode_ticks_per_dispatch=4)],
-                         ids=["mixed_and_decode_ticks", "two_op_ticks",
+                         ids=["mixed_and_decode_ticks", "one_slot",
                               "slab"])
 def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
                                                       monkeypatch):
@@ -196,12 +198,14 @@ def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
     decode rows' state stepped in place by ``ssd_step_kernel`` (live rows
     only; 3 slots and 5 requests, so rows stand empty and are reused)
     gives token for token what ``ssd_step`` gives. Off the TPU the engine
-    takes ``ssd_step``; the test, not an option, steers it."""
+    takes ``ssd_step``; the test, not an option, steers it. ``one_slot``:
+    the kernel over the smallest state array, the slot and the scratch
+    row, restarted for each of the five requests in turn."""
     from paddle_tpu.inference import llm
     net, params, d = model
     prompts = prompts_of((12, 27, 1, 6, 19), seed=4)
-    kw = dict(max_seqs=3, page_size=8, num_pages=64, max_len=128,
-              prefill_chunk=16, kv_dtype="f32", **knobs)
+    kw = {**dict(max_seqs=3, page_size=8, num_pages=64, max_len=128,
+                 prefill_chunk=16, kv_dtype="f32"), **knobs}
 
     def serve():
         with LLMEngine(net, **kw) as eng:
@@ -220,9 +224,9 @@ def test_the_state_kernel_serves_what_ssd_step_serves(model, knobs,
         assert served_gap(params, d, p, toks) <= TOL
 
 
-@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1),
                                    dict(decode_ticks_per_dispatch=4)],
-                         ids=["mixed_and_decode_ticks", "two_op_ticks",
+                         ids=["mixed_and_decode_ticks", "one_slot",
                               "slab"])
 def test_the_chunk_kernel_serves_what_ssd_chunked_serves(knobs,
                                                          monkeypatch):
@@ -233,7 +237,9 @@ def test_the_chunk_kernel_serves_what_ssd_chunked_serves(knobs,
     prompt runs over three chunks (40 rows) and slots are reused; greedy
     tokens through ``ssd_chunk_kernel`` are those through ``ssd_chunked``.
     The decode half takes the step kernel in both (its own test is above):
-    what differs between the two engines is the chunk's scan alone."""
+    what differs between the two engines is the chunk's scan alone.
+    ``one_slot``: a chunk holds ONE sequence's rows and the kernel's state
+    array is the slot and the scratch row."""
     from paddle_tpu.inference import llm
     from paddle_tpu.ops import ssd
     with open("benchmark/configs/rehearsal-tiny-hybrid.json") as f:
@@ -257,7 +263,7 @@ def test_the_chunk_kernel_serves_what_ssd_chunked_serves(knobs,
     kernel = ssd.ssd_chunk_kernel
 
     def serve():
-        with LLMEngine(net, **conf["engine"], **knobs) as eng:
+        with LLMEngine(net, **{**conf["engine"], **knobs}) as eng:
             assert eng.state_impl == "pallas"
             futs = [eng.submit(p, max_new_tokens=7) for p in prompts]
             return [list(f.result(timeout=600)["output_ids"]) for f in futs]
@@ -276,14 +282,15 @@ def test_the_chunk_kernel_serves_what_ssd_chunked_serves(knobs,
     assert len({tuple(w) for w in want}) > 1      # the tokens vary
 
 
-@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False)],
-                         ids=["mixed_and_decode_ticks", "two_op_ticks"])
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1)],
+                         ids=["mixed_and_decode_ticks", "one_slot"])
 def test_the_grouped_product_kernel_serves_what_ragged_dot_serves(
         model, knobs, monkeypatch):
     """What a TPU's engine runs, here through the Pallas interpreter: every
     routed layer's two grouped products through ``ops/grouped_matmul.py``
-    (the decode, the mixed and, with mixed ticks off, the chunked-prefill
-    program) give token for token what ``jax.lax.ragged_dot`` gives, and
+    (the decode and the mixed program; ``one_slot``: a mixed program
+    whose decode rows are all inactive, then decode programs of one live
+    row) give token for token what ``jax.lax.ragged_dot`` gives, and
     ``/statusz`` and the drain phase name which one the programs were
     built with. Off the TPU the engine takes ``ragged_dot``; the test, not
     an option, steers it."""
@@ -291,8 +298,8 @@ def test_the_grouped_product_kernel_serves_what_ragged_dot_serves(
     from paddle_tpu.observability import tracing
     net, params, d = model
     prompts = prompts_of((12, 27, 1, 6, 19), seed=4)
-    kw = dict(max_seqs=3, page_size=8, num_pages=64, max_len=128,
-              prefill_chunk=16, kv_dtype="f32", **knobs)
+    kw = {**dict(max_seqs=3, page_size=8, num_pages=64, max_len=128,
+                 prefill_chunk=16, kv_dtype="f32"), **knobs}
     was_trace = tracing.enabled()
 
     def serve():

@@ -142,12 +142,11 @@ def test_absorbed_attention_is_the_expanded(model):
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1),
                                    dict(attention_impl="pallas"),
-                                   dict(decode_ticks_per_dispatch=2),
-                                   dict(lookahead=1)],
-                         ids=["mixed_ticks", "two_op_ticks", "kernel",
-                              "fused_slab", "lookahead"])
+                                   dict(decode_ticks_per_dispatch=2)],
+                         ids=["mixed_ticks", "one_slot", "kernel",
+                              "fused_slab"])
 def test_engine_holds_to_the_reference_through_chunks_and_decode(model,
                                                                  knobs):
     """Prompts of less and more than a chunk that share chunks and join at
@@ -156,11 +155,12 @@ def test_engine_holds_to_the_reference_through_chunks_and_decode(model,
     token's LOGIT within TOL of the reference's best, and the tokens those
     of ``generate`` (the whole-sequence forward). ``kernel``: the latent
     pages through the row walk and the query tiles, interpreted.
-    ``fused_slab`` / ``lookahead``: the scan's carry and the next tick's
-    issue take a pool without V pages as they take any other."""
+    ``fused_slab``: the scan's carry takes a pool without V pages as it
+    takes any other. ``one_slot``: all five through ONE slot in turn, its
+    state row restarted and its latent pages handed to the next."""
     net, params, d = model
     prompts = prompts_of((70, 45, 9, 30, 61))
-    with LLMEngine(net, max_seqs=3, **ENGINE, **knobs) as eng:
+    with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
         assert eng.state_impl == "xla"
         futs = [eng.submit(p, max_new_tokens=24) for p in prompts[:4]]
         outs = [f.result(timeout=900) for f in futs]

@@ -38,7 +38,7 @@ def tiny_gpt(max_pos=96):
 
 def mk_engine(kv_dtype="float32", num_pages=64, **kw):
     return LLMEngine(tiny_gpt(), max_seqs=4, page_size=4,
-                     num_pages=num_pages, prefill_buckets=(32,),
+                     num_pages=num_pages, prefill_chunk=32,
                      seed=0, kv_dtype=kv_dtype, **kw)
 
 

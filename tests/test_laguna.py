@@ -119,16 +119,18 @@ def test_two_cache_groups_with_their_own_lifetimes(model):
     assert net.moe_aux_spec() == (4, 8)
 
 
-@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1),
                                    dict(attention_impl="pallas")],
-                         ids=["mixed_ticks", "two_op_ticks", "kernel"])
+                         ids=["mixed_ticks", "one_slot", "kernel"])
 def test_engine_holds_to_the_reference_past_the_window(model, knobs):
     """Prompts shorter and longer than window + chunk that share chunks and
     join at different times (3 slots, 5 requests), then 40 tokens of decode:
     every sequence leaves the window behind. Every served token within TOL
     of the reference's best and what ``generate`` gives; the window group
     never holds more than its ring a slot, and released pages are POISONED
-    as they go back to the free list, so a read of one would show."""
+    as they go back to the free list, so a read of one would show.
+    ``one_slot``: all five through ONE slot in turn, the window's ring handed
+    from each sequence to the next."""
     net, params, d = model
     prompts = prompts_of((70, 45, 9, 30, 61))
     release = page_pool.PagePool.release_behind
@@ -149,7 +151,7 @@ def test_engine_holds_to_the_reference_past_the_window(model, knobs):
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(page_pool.PagePool, "release_behind", poisoning_release)
-        with LLMEngine(net, max_seqs=3, **ENGINE, **knobs) as eng:
+        with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
             futs = [eng.submit(p, max_new_tokens=40) for p in prompts[:4]]
             outs = [f.result(timeout=900) for f in futs]
             outs.append(eng.submit(prompts[4], max_new_tokens=40)
@@ -162,7 +164,7 @@ def test_engine_holds_to_the_reference_past_the_window(model, knobs):
             # the slots drained: every page is back on its free list
             assert len(full.free) == full.num_pages - 1
             assert len(window.free) == window.num_pages - 1
-            assert window.num_pages == 3 * RING + 1
+            assert window.num_pages == eng.max_seqs * RING + 1
             assert eng.moe_rows_by_expert.shape == (4, 8)
     for p, o in zip(prompts, outs):
         toks = list(o["output_ids"])
@@ -193,7 +195,6 @@ def test_modes_that_assume_one_lifetime_are_refused_by_name(model):
                                       hidden_size=32, num_heads=2,
                                       vocab_size=128))
     for knobs, mechanism in ((dict(draft_net=draft), "speculative_verify"),
-                             (dict(lookahead=1), "lookahead"),
                              (dict(decode_ticks_per_dispatch=2),
                               "fused_slab")):
         with pytest.raises(CacheGroupUnsupported) as e:
@@ -212,7 +213,7 @@ def test_modes_that_assume_one_lifetime_are_refused_by_name(model):
             ("full", 2, None, None), ("window", 3, WINDOW, RING)]
         assert set(status["cache_groups_unsupported"]) == {
             "prefix_reuse", "kv_page_migration", "speculative_verify",
-            "fused_slab", "lookahead"}
+            "fused_slab"}
         assert status["prefix_cache"]["enabled"] is False
 
 

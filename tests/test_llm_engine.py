@@ -35,18 +35,23 @@ def tiny_llama():
     return GPTForCausalLM(cfg)
 
 
-@pytest.mark.parametrize("lookahead", [0, 3], ids=["sync", "lookahead3"])
+@pytest.mark.parametrize("prefill_chunk", [16, 3], ids=["chunk16", "chunk3"])
 @pytest.mark.parametrize("build", [tiny_gpt, tiny_llama],
                          ids=["gpt2", "llama-gqa"])
-def test_engine_greedy_matches_dense_generate(build, lookahead):
+def test_engine_greedy_matches_dense_generate(build, prefill_chunk):
+    """``chunk3``: a chunk smaller than a page (4) and than two of the
+    three prompts, so a prompt crosses several mixed ticks and its chunk
+    boundaries lie off the page grid."""
     net = build()
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, 97, n).tolist() for n in (5, 11, 3)]
     want = [np.asarray(net.generate(jnp.asarray([p]), max_new_tokens=8)
                        )[0, len(p):].tolist() for p in prompts]
     with LLMEngine(net, max_seqs=4, page_size=4, num_pages=128,
-                   prefill_buckets=(16,), lookahead=lookahead) as eng:
+                   prefill_chunk=prefill_chunk) as eng:
         outs = eng.generate(prompts, max_new_tokens=8)
+        # 5 + 11 + 3 prompt tokens, packed: ceil(19 / chunk) chunks at least
+        assert eng.n_prefill_ticks >= -(-19 // prefill_chunk)
     for got, ref, p in zip(outs, want, prompts):
         assert got["output_ids"] == ref, (p, got["output_ids"], ref)
         assert not got["truncated"]
@@ -65,7 +70,7 @@ def test_engine_continuous_admission_and_page_reuse():
     ref1 = np.asarray(net.generate(jnp.asarray([p1]),
                                    max_new_tokens=6))[0, len(p1):]
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(8,))
+                    prefill_chunk=8)
     free0 = len(eng._free_pages)
     f0 = eng.submit(p0, max_new_tokens=12)
     # second request lands while the first decodes (token-level join)
@@ -84,7 +89,7 @@ def test_engine_more_requests_than_slots():
     prompts = [rng.randint(0, 97, 1 + (i % 5)).tolist()
                for i in range(8)]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,)) as eng:
+                   prefill_chunk=8) as eng:
         outs = eng.generate(prompts, max_new_tokens=4)
     assert all(len(o["output_ids"]) == 4 for o in outs)
 
@@ -96,7 +101,7 @@ def test_engine_pool_exhaustion_truncates_gracefully():
     net = tiny_gpt()
     # 3 usable pages of 4 tokens = 12 cached tokens max
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=4,
-                   prefill_buckets=(8,)) as eng:
+                   prefill_chunk=8) as eng:
         out = eng.generate([[1, 2, 3, 4, 5]], max_new_tokens=40)[0]
     assert out["truncated"]
     assert 0 < len(out["output_ids"]) < 40
@@ -106,7 +111,7 @@ def test_engine_pool_exhaustion_truncates_gracefully():
 def test_engine_sampling_temperature_and_eos():
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), eos_token_id=7) as eng:
+                   prefill_chunk=8, eos_token_id=7) as eng:
         out = eng.generate([[3, 1, 4]], max_new_tokens=64,
                            temperature=1.0)[0]
         assert len(out["output_ids"]) >= 1
@@ -127,7 +132,7 @@ def test_http_serving_concurrent_clients():
     refs = [np.asarray(net.generate(jnp.asarray([p]), max_new_tokens=5)
                        )[0, len(p):].tolist() for p in prompts]
     with LLMEngine(net, max_seqs=4, page_size=4, num_pages=128,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         srv = serve_llm(eng)
         host, port = srv.server_address
         results = {}
@@ -155,12 +160,12 @@ def test_http_serving_concurrent_clients():
 def test_engine_rejects_impossible_requests_cleanly():
     """Failure paths resolve, never hang: a prompt that can NEVER fit
     the page pool fails its future (the chunked path accepts ANY
-    prompt length up to max_len, whatever the prefill buckets); a
+    prompt length up to max_len, whatever the chunk); a
     device-side error mid-serving fails
     in-flight requests but leaves the engine serving."""
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=4,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         # 20 tokens exceed the largest bucket, which bounds nothing,
         # but need 5 pages where only 3 exist -> future fails
         fut = eng.submit(list(range(20)), max_new_tokens=2)
@@ -175,7 +180,7 @@ def test_engine_rejects_impossible_requests_cleanly():
 
     net2 = tiny_gpt()
     eng = LLMEngine(net2, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(8,))
+                    prefill_chunk=8)
     real_decode = eng._decode_fn
     calls = {"n": 0}
 
@@ -195,45 +200,27 @@ def test_engine_rejects_impossible_requests_cleanly():
     eng.close()
 
 
-
-def test_engine_lookahead_chains_and_discards_overrun():
-    """lookahead > 0: token streams are IDENTICAL to sync mode (the
-    chain computes the same values on device), finished requests never
-    exceed max_new_tokens despite overrun steps, pages all return, and
-    the host fetch count drops to ~1 per lookahead+1 steps."""
-    net = tiny_gpt()
-    rng = np.random.RandomState(4)
-    prompts = [rng.randint(0, 97, n).tolist() for n in (4, 7, 3, 9)]
-
-    def run(k):
-        pt.seed(0)
-        eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                        prefill_buckets=(16,), lookahead=k)
-        free0 = len(eng._free_pages)
-        outs = eng.generate(prompts, max_new_tokens=11)
-        eng.close()
-        assert len(eng._free_pages) == free0
-        return outs
-
-    sync = run(0)
-    la = run(4)
-    for a, b in zip(sync, la):
-        assert a["output_ids"] == b["output_ids"]
-        assert len(b["output_ids"]) == 11
+@pytest.mark.parametrize("name", ["mixed_tick", "lookahead",
+                                  "prefill_buckets"])
+def test_removed_engine_options(name):
+    """A queued prompt reaches the device one way: the options that chose
+    another (or sized it) are gone, not ignored."""
+    from paddle_tpu.core import flags
+    value = {"mixed_tick": True, "lookahead": 0,
+             "prefill_buckets": (16,)}[name]
+    with pytest.raises(TypeError, match=name):
+        LLMEngine(tiny_gpt(), **{name: value})
+    with pytest.raises(flags.FlagError):
+        flags.get_flag("mixed_tick")
 
 
-def test_engine_lookahead_eos_and_truncation():
-    net = tiny_gpt()
-    with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), eos_token_id=7,
-                   lookahead=3) as eng:
-        out = eng.generate([[3, 1, 4]], max_new_tokens=40,
-                           temperature=1.0)[0]
-        if 7 in out["output_ids"]:
-            assert out["output_ids"][-1] == 7    # nothing after EOS
-    # pool exhaustion under lookahead still truncates gracefully
-    with LLMEngine(net, max_seqs=1, page_size=4, num_pages=4,
-                   prefill_buckets=(8,), lookahead=3) as eng:
-        out = eng.generate([[1, 2, 3, 4, 5]], max_new_tokens=40)[0]
-    assert out["truncated"]
-    assert 0 < len(out["output_ids"]) < 40
+@pytest.mark.parametrize("max_len, want", [(None, 64), (40, 40)],
+                         ids=["default", "capped_by_max_len"])
+def test_prefill_chunk_default(max_len, want):
+    """``prefill_chunk`` left unset: 64 tokens, or ``max_len`` where that
+    is smaller; the mixed program's chunk rows have that width."""
+    with LLMEngine(tiny_gpt(), max_seqs=2, page_size=4, num_pages=64,
+                   max_len=max_len) as eng:
+        assert eng.prefill_chunk == want
+        out = eng.generate([[3, 1, 4, 1, 5]], max_new_tokens=3)[0]
+        assert len(out["output_ids"]) == 3

@@ -296,7 +296,7 @@ def test_disabled_is_one_flag_check(tmp_path):
         # a disabled engine registers nothing
         from paddle_tpu.inference.llm import LLMEngine
         with LLMEngine(tiny_gpt(), max_seqs=2, page_size=4,
-                       num_pages=16, prefill_buckets=(8,)) as eng:
+                       num_pages=16, prefill_chunk=8) as eng:
             assert memobs.instance().rows() == []
             assert memobs.instance().headroom() is None
             del eng
@@ -322,7 +322,7 @@ def test_engine_kv_attribution_tracks_page_table_exactly():
     base = rng.randint(0, 97, 8).tolist()       # 2 full pages of 4
     led = memobs.instance()
     with LLMEngine(net, max_seqs=4, page_size=4, num_pages=32,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         usable = eng.num_pages - 1
         pb = eng._page_bytes
 
@@ -375,7 +375,7 @@ def test_engine_close_removes_rows_and_unexports_headroom():
     from paddle_tpu.inference.llm import LLMEngine
     led = memobs.instance()
     eng = LLMEngine(tiny_gpt(), max_seqs=2, page_size=4, num_pages=16,
-                    prefill_buckets=(8,), decode_ticks_per_dispatch=4)
+                    prefill_chunk=8, decode_ticks_per_dispatch=4)
     led.update_gauges()
     assert default_registry().get("mem_headroom_pages") is not None
     assert any(r["owner"] == "decode_carry" for r in led.rows())
@@ -409,14 +409,14 @@ cfg = gpt_config("gpt2-small", num_layers=2, hidden_size=64,
                  max_position_embeddings=96, hidden_dropout=0.0,
                  attention_dropout=0.0)
 eng = LLMEngine(GPTForCausalLM(cfg), max_seqs=2, page_size=4,
-                num_pages=32, prefill_buckets=(8,))
+                num_pages=32, prefill_chunk=8)
 
 def oom(*a, **kw):
     raise RuntimeError(
         "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
         "9663676416 bytes.")
 
-eng._chunk_fn = oom
+eng._mixed_fn = oom
 eng._decode_fn = oom
 f = eng.submit(np.random.RandomState(0).randint(0, 97, 6).tolist(),
                max_new_tokens=4)
@@ -511,7 +511,7 @@ def test_memz_statusz_metrics_over_http(monkeypatch):
     try:
         base = f"http://127.0.0.1:{srv.port}"
         with LLMEngine(tiny_gpt(), max_seqs=2, page_size=4,
-                       num_pages=16, prefill_buckets=(8,)) as eng:
+                       num_pages=16, prefill_chunk=8) as eng:
             eng.generate([[1, 2, 3, 4, 5]], max_new_tokens=3)
             mz = _get_json(base, "/memz")
             assert mz["enabled"] is True

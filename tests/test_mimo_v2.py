@@ -130,9 +130,9 @@ def test_two_cache_groups_with_their_own_widths_lifetimes_and_a_sink(model):
                                                 41, 47)
 
 
-@pytest.mark.parametrize("knobs", [dict(), dict(mixed_tick=False),
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1),
                                    dict(attention_impl="pallas")],
-                         ids=["mixed_ticks", "two_op_ticks", "kernel"])
+                         ids=["mixed_ticks", "one_slot", "kernel"])
 def test_engine_holds_to_the_reference_with_a_window_under_the_chunk(
         model, knobs):
     """Prompts of several chunks, each chunk twice the window (so a chunk's
@@ -141,7 +141,8 @@ def test_engine_holds_to_the_reference_with_a_window_under_the_chunk(
     tokens of decode. Every served token within TOL of the reference's best
     and what ``generate`` gives; the window group never holds more than its
     ring a slot, and released pages are POISONED as they go back to the
-    free list, so a read of one would show."""
+    free list, so a read of one would show. ``one_slot``: all five through
+    ONE slot in turn, the window's ring handed from each to the next."""
     net, params, d = model
     prompts = prompts_of((70, 45, 9, 30, 61))
     release = page_pool.PagePool.release_behind
@@ -162,7 +163,7 @@ def test_engine_holds_to_the_reference_with_a_window_under_the_chunk(
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(page_pool.PagePool, "release_behind", poisoning_release)
-        with LLMEngine(net, max_seqs=3, **ENGINE, **knobs) as eng:
+        with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
             futs = [eng.submit(p, max_new_tokens=24) for p in prompts[:4]]
             outs = [f.result(timeout=900) for f in futs]
             outs.append(eng.submit(prompts[4], max_new_tokens=24)
@@ -175,7 +176,7 @@ def test_engine_holds_to_the_reference_with_a_window_under_the_chunk(
             # the slots drained: every page is back on its free list
             assert len(full.free) == full.num_pages - 1
             assert len(window.free) == window.num_pages - 1
-            assert window.num_pages == 3 * RING + 1
+            assert window.num_pages == eng.max_seqs * RING + 1
             assert eng.moe_rows_by_expert.shape == (5, 8)
             assert full.k_pages.shape[-2:] == (1, 128) \
                 and full.v_pages.shape[-2:] == (1, 16)
@@ -246,7 +247,6 @@ def test_the_refused_modes_refuse_by_name_and_statusz_says_widths_and_sink(
         model):
     net, _, _ = model
     for knobs, mechanism in ((dict(draft_net=_draft()), "speculative_verify"),
-                             (dict(lookahead=1), "lookahead"),
                              (dict(decode_ticks_per_dispatch=2),
                               "fused_slab"),
                              (dict(kv_dtype="int8"), "int8_pages")):
@@ -271,7 +271,7 @@ def test_the_refused_modes_refuse_by_name_and_statusz_says_widths_and_sink(
              4 * 2 * 128 * 4, 4 * 2 * 16 * 4, 4 * 2 * 144 * 4)]
         assert set(status["cache_groups_unsupported"]) == {
             "prefix_reuse", "kv_page_migration", "speculative_verify",
-            "fused_slab", "lookahead"}
+            "fused_slab"}
         assert status["prefix_cache"]["enabled"] is False
 
 

@@ -11,9 +11,10 @@ Contracts under test:
   f32-accumulate reference path at the op level, and quantization is
   DETERMINISTIC — cache on/off, fused slabs and the mixed tick all
   produce identical int8 streams.
-- ``mixed_tick=True`` collapses the alternating prefill/decode tick
-  loop into one fused dispatch whose streams are TOKEN-IDENTICAL to
-  the legacy two-op tick path (greedy AND seeded, cache on/off,
+- the mixed tick serves prompt chunks and decode rows in one fused
+  dispatch whose streams do not depend on which rows share a tick:
+  TOKEN-IDENTICAL to one slot serving the same prompts in turn and,
+  greedy, to ``net.generate`` (greedy AND seeded, cache on/off,
   N in {1, 8}), with a prompt admitted mid-slab decoding on device
   (zero host dispatches between its phases).
 - ~2x page capacity at fixed HBM: int8 page bytes (scale table
@@ -51,6 +52,13 @@ def tiny_gpt():
                      max_position_embeddings=96, hidden_dropout=0.0,
                      attention_dropout=0.0)
     return GPTForCausalLM(cfg)
+
+
+def dense_ref(net, prompt, n):
+    """``net.generate``'s ``n`` tokens after ``prompt``: the dense forward,
+    no engine."""
+    return np.asarray(net.generate(jnp.asarray([prompt]),
+                                   max_new_tokens=n))[0, len(prompt):].tolist()
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +178,7 @@ def test_quantization_is_deterministic():
 # ---------------------------------------------------------------------------
 
 
-def run_engine(net, prompts, gen, *, mixed, n=1, kv=None,
+def run_engine(net, prompts, gen, *, n=1, kv=None,
                temperature=0.0, cache=True, page_size=4,
                num_pages=128, chunk=8, seed=3, eos=None,
                max_seqs=4, warm_first=0, impl=None):
@@ -178,10 +186,10 @@ def run_engine(net, prompts, gen, *, mixed, n=1, kv=None,
     completion BEFORE the burst (their pages are registered, so the
     burst's shared prefixes genuinely hit the cache)."""
     eng = LLMEngine(net, max_seqs=max_seqs, page_size=page_size,
-                    num_pages=num_pages, prefill_buckets=(32,),
+                    num_pages=num_pages,
                     prefix_cache=cache, prefill_chunk=chunk,
                     eos_token_id=eos, seed=seed,
-                    decode_ticks_per_dispatch=n, mixed_tick=mixed,
+                    decode_ticks_per_dispatch=n,
                     kv_dtype=kv, attention_impl=impl)
     with eng:
         outs = []
@@ -201,11 +209,14 @@ def run_engine(net, prompts, gen, *, mixed, n=1, kv=None,
                          ids=["greedy", "seeded"])
 @pytest.mark.parametrize("cache", [True, False],
                          ids=["cache-on", "cache-off"])
-def test_mixed_tick_token_identity_vs_legacy(cache, temperature):
+def test_mixed_tick_token_identity_across_schedules(cache, temperature):
     """The ISSUE-15 acceptance pin: one batch mixing cache-hit
     prefill (shared prefix), cold prefill chunks and decodes through
-    the MIXED tick is token-identical to the legacy two-op tick path,
-    greedy and seeded, cache on/off, N in {1, 8}."""
+    the MIXED tick is token-identical to the same prompts served
+    through ONE slot in turn WITHOUT a prefix cache (no decode row ever
+    beside a prompt row, no shared page; ``generate`` submits in the
+    same order, so the nonces are the same), at N in {1, 8}, with the
+    cache on and off; greedy, all of them are ``net.generate``'s."""
     net = tiny_gpt()
     rng = np.random.RandomState(0)
     prefix = rng.randint(0, 97, 8).tolist()          # 2 full pages
@@ -213,11 +224,14 @@ def test_mixed_tick_token_identity_vs_legacy(cache, temperature):
                prefix + rng.randint(0, 97, 3).tolist(),   # cache hit
                rng.randint(0, 97, 21).tolist(),           # cold, long
                rng.randint(0, 97, 4).tolist()]            # cold, short
-    ref, _, _ = run_engine(net, prompts, 10, mixed=False,
-                           temperature=temperature, cache=cache,
-                           warm_first=1)
+    ref, _, one = run_engine(net, prompts, 10, max_seqs=1,
+                             temperature=temperature, cache=False,
+                             warm_first=1)
+    assert one.n_mixed_slabs > 0 and one.n_decode_ticks > 0
+    if not temperature:
+        assert ref == [dense_ref(net, p, 10) for p in prompts]
     for n in (1, 8):
-        got, outs, eng = run_engine(net, prompts, 10, mixed=True, n=n,
+        got, outs, eng = run_engine(net, prompts, 10, n=n,
                                     temperature=temperature,
                                     cache=cache, warm_first=1)
         assert got == ref, f"mixed tick diverged at N={n}"
@@ -244,11 +258,11 @@ def test_mixed_tick_kernel_streams_equal_gathered_path(kv, temperature, n):
                prefix + rng.randint(0, 97, 3).tolist(),
                rng.randint(0, 97, 21).tolist(),
                rng.randint(0, 97, 4).tolist()]
-    ref, _, eng = run_engine(net, prompts, 8, mixed=True, n=n, impl="xla",
+    ref, _, eng = run_engine(net, prompts, 8, n=n, impl="xla",
                              kv=kv, temperature=temperature,
                              warm_first=1)
     assert eng.attention_impl == "xla"
-    got, _, eng = run_engine(net, prompts, 8, mixed=True, n=n,
+    got, _, eng = run_engine(net, prompts, 8, n=n,
                              impl="pallas", kv=kv,
                              temperature=temperature, warm_first=1)
     assert eng.attention_impl == "pallas"
@@ -271,7 +285,7 @@ def test_issue_phases_carry_the_pages_read_and_live(impl, n):
     tracing.clear()
     tracing.enable()
     try:
-        _, _, eng = run_engine(net, prompts, 9, mixed=True, n=n, impl=impl,
+        _, _, eng = run_engine(net, prompts, 9, n=n, impl=impl,
                                cache=False)
         issues = [s for s in tracing.finished_spans()
                   if s["name"].startswith("llm.issue.")]
@@ -315,7 +329,7 @@ def test_attention_impl_follows_the_pools_platform(monkeypatch):
     from paddle_tpu.inference import llm as llm_mod
     net = tiny_gpt()
     small = dict(max_seqs=2, page_size=4, num_pages=16,
-                 prefill_buckets=(32,))
+                 prefill_chunk=32)
     with LLMEngine(net, **small) as eng:
         assert eng.attention_impl == "xla"
         assert eng._jit_options == {}
@@ -342,19 +356,19 @@ def test_attention_impl_follows_the_pools_platform(monkeypatch):
 
 def test_mixed_slab_admits_prefill_without_host_dispatches():
     """A long prompt submitted mid-decode rides INTO the slab: the
-    tick history shows mixed slabs ('m'), the mixed-prefill counter
-    advances, and the combined streams still match the legacy run —
-    with strictly fewer host dispatches than the legacy alternating
-    loop needed."""
+    tick history shows mixed slabs ('m'), the chunks are counted, and
+    the combined streams are ``net.generate``'s — with
+    strictly fewer host dispatches at N = 4 than one tick a dispatch
+    needs."""
     net = tiny_gpt()
     rng = np.random.RandomState(6)
     short = rng.randint(0, 97, 4).tolist()
     long = rng.randint(0, 97, 40).tolist()
+    want = [dense_ref(net, short, 24), dense_ref(net, long, 8)]
 
-    def interleaved(mixed, n):
+    def interleaved(n):
         eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=128,
-                        prefill_buckets=(64,), prefill_chunk=8,
-                        decode_ticks_per_dispatch=n, mixed_tick=mixed)
+                        prefill_chunk=8, decode_ticks_per_dispatch=n)
         with eng:
             f1 = eng.submit(short, max_new_tokens=24)
             while not (eng.n_decode_ticks or eng.n_mixed_slabs):
@@ -363,52 +377,58 @@ def test_mixed_slab_admits_prefill_without_host_dispatches():
             outs = [f1.result(timeout=120), f2.result(timeout=120)]
             hist = "".join(eng.tick_history)
             dispatches = eng.n_host_dispatches
+            # 4 + 40 prompt tokens in chunks of 8, all inside mixed slabs
+            assert eng.n_prefill_ticks >= 6
         assert len(eng._free_pages) == eng.num_pages - 1
         return [o["output_ids"] for o in outs], hist, dispatches
 
-    ref, _, d_ref = interleaved(False, 4)
-    got, hist, d_mixed = interleaved(True, 4)
-    assert got == ref
+    ref, _, d_tick = interleaved(1)
+    got, hist, d_slab = interleaved(4)
+    assert got == ref == want
     assert "m" in hist, hist
-    assert d_mixed < d_ref, (d_mixed, d_ref)
+    assert d_slab < d_tick, (d_slab, d_tick)
 
 
-def test_mixed_eos_and_page_pressure_match_legacy():
-    """EOS landing mid-slab and a pool too small to cover the slab
-    both resolve exactly as the legacy path does (the shrink /
-    truncation decisions re-plan at slab entry)."""
+def test_mixed_eos_and_page_pressure():
+    """EOS landing mid-slab cuts the stream where ``net.generate``'s
+    has the EOS; a pool too small to cover the slab truncates (the
+    shrink / truncation decisions re-plan at slab entry, so N = 8
+    stops where N = 1 stops): the stream is a prefix of the
+    unpressured one, ``truncated`` is set, no page leaks
+    (``run_engine`` audits the pool after every run)."""
     net = tiny_gpt()
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, 97, 5).tolist(),
                rng.randint(0, 97, 7).tolist()]
-    base, _, _ = run_engine(net, prompts, 12, mixed=False)
-    eos = base[0][5]
-    ref, _, _ = run_engine(net, prompts, 12, mixed=False, eos=eos)
-    got, _, _ = run_engine(net, prompts, 12, mixed=True, n=8, eos=eos)
-    assert got == ref
-    assert len(got[0]) < 12 and got[0][-1] == eos
+    dense = [dense_ref(net, p, 12) for p in prompts]
+    eos = dense[0][5]
+    want = [d[:d.index(eos) + 1] if eos in d else d for d in dense]
+    for n in (1, 8):
+        got, _, _ = run_engine(net, prompts, 12, n=n, eos=eos)
+        assert got == want, n
+    assert len(want[0]) < 12 and want[0][-1] == eos
     # page pressure: tiny pool forces shrink/truncation decisions
     tight = [rng.randint(0, 97, 5).tolist()]
-    for pages in (9, 16):
-        r, routs, _ = run_engine(net, tight, 20, mixed=False, n=1,
-                                 page_size=2, num_pages=pages,
-                                 cache=False)
-        g, gouts, _ = run_engine(net, tight, 20, mixed=True, n=8,
-                                 page_size=2, num_pages=pages,
-                                 cache=False)
+    free = [dense_ref(net, tight[0], 20)]
+    for pages, cut_short in ((9, True), (16, False)):
+        r, routs, _ = run_engine(net, tight, 20, n=1, page_size=2,
+                                 num_pages=pages, cache=False)
+        g, gouts, _ = run_engine(net, tight, 20, n=8, page_size=2,
+                                 num_pages=pages, cache=False)
         assert g == r, pages
+        assert g[0] == free[0][:len(g[0])], pages
         assert [o["truncated"] for o in gouts] == \
-            [o["truncated"] for o in routs], pages
+            [o["truncated"] for o in routs] == [cut_short], pages
+        assert (0 < len(g[0]) < 20) == cut_short, pages
 
 
 def test_mixed_guard_kind_coherent():
     """Satellite: the mixed program registers under its own
-    ``mixed_tick`` recompile-guard kind (decode_step|decode_loop|
-    prefill collapse into it while the queue is served mixed)."""
+    ``mixed_tick`` recompile-guard kind."""
     net = tiny_gpt()
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, 97, 5).tolist()]
-    _, _, eng = run_engine(net, prompts, 8, mixed=True, n=8)
+    _, _, eng = run_engine(net, prompts, 8, n=8)
     kinds = {s[0] for s in eng._shape_signatures}
     assert "mixed_tick" in kinds, kinds
     # the realized mixed-slab length tracks the prefill schedule (a
@@ -417,8 +437,6 @@ def test_mixed_guard_kind_coherent():
     lengths = [s[1] for s in eng._shape_signatures
                if s[0] == "mixed_tick"]
     assert lengths and all(1 <= n <= 8 for n in lengths), lengths
-    # the legacy per-phase prefill program never compiled
-    assert "prefill" not in kinds, kinds
 
 
 def test_int8_engine_parity_and_tolerance():
@@ -432,15 +450,12 @@ def test_int8_engine_parity_and_tolerance():
     prompts = [prefix + rng.randint(0, 97, 5).tolist(),
                prefix + rng.randint(0, 97, 3).tolist(),
                rng.randint(0, 97, 11).tolist()]
-    base, _, eng = run_engine(net, prompts, 10, mixed=False,
-                              kv="int8")
+    base, _, eng = run_engine(net, prompts, 10, kv="int8")
     assert isinstance(eng.k_pages, QuantizedKV)
-    for kwargs in (dict(mixed=False, cache=False),
-                   dict(mixed=False, n=8),
-                   dict(mixed=True, n=8)):
+    for kwargs in (dict(cache=False), dict(n=8)):
         got, _, _ = run_engine(net, prompts, 10, kv="int8", **kwargs)
         assert got == base, f"int8 streams diverged under {kwargs}"
-    f32, _, _ = run_engine(net, prompts, 10, mixed=False)
+    f32, _, _ = run_engine(net, prompts, 10)
     agree = np.mean([np.mean([a == b for a, b in zip(x, y)])
                      for x, y in zip(base, f32)])
     assert agree >= INT8_GREEDY_AGREE, (
@@ -459,7 +474,7 @@ def test_int8_capacity_and_ledger_split():
     engines = {}
     for kv in ("bf16", "int8"):
         engines[kv] = LLMEngine(net, max_seqs=2, page_size=4,
-                                num_pages=32, prefill_buckets=(16,),
+                                num_pages=32, prefill_chunk=16,
                                 kv_dtype=kv)
     try:
         ratio = engines["bf16"]._page_bytes / \
@@ -485,14 +500,11 @@ def test_int8_capacity_and_ledger_split():
             e.close()
 
 
-def test_kv_dtype_and_mixed_knob_validation():
+def test_kv_dtype_knob_validation():
     net = tiny_gpt()
     with pytest.raises(ValueError, match="kv_dtype"):
         LLMEngine(net, max_seqs=2, page_size=4, num_pages=16,
-                  prefill_buckets=(16,), kv_dtype="int4")
-    with pytest.raises(ValueError, match="lookahead"):
-        LLMEngine(net, max_seqs=2, page_size=4, num_pages=16,
-                  prefill_buckets=(16,), mixed_tick=True, lookahead=2)
+                  prefill_chunk=16, kv_dtype="int4")
     pt.seed(1)
     dcfg = gpt_config("gpt2-small", num_layers=1, hidden_size=32,
                       num_heads=2, vocab_size=97,
@@ -502,38 +514,22 @@ def test_kv_dtype_and_mixed_knob_validation():
     # int8 + draft_net composes (the quantized draft pool), and a
     # speculative engine keeps its slab width
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
-                    prefill_buckets=(16,), draft_net=draft,
+                    prefill_chunk=16, draft_net=draft,
                     kv_dtype="int8", decode_ticks_per_dispatch=4)
     assert eng.spec_k and isinstance(eng.draft_k_pages, QuantizedKV)
     assert eng.decode_ticks_per_dispatch == 4
     eng.close()
-    # a speculative engine RIDES mixed_tick
-    eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
-                    prefill_buckets=(16,), draft_net=draft,
-                    mixed_tick=True)
-    assert eng.mixed_tick is True
-    eng.close()
     # flags feed the defaults
     from paddle_tpu.core import flags
-    flags.set_flags({"mixed_tick": True, "kv_dtype": "int8"})
+    flags.set_flags({"kv_dtype": "int8"})
     try:
         eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=16,
-                        prefill_buckets=(16,))
-        assert eng.mixed_tick is True
+                        prefill_chunk=16)
         assert eng.kv_dtype == "int8"
         assert isinstance(eng.k_pages, QuantizedKV)
         eng.close()
     finally:
-        flags.set_flags({"mixed_tick": True, "kv_dtype": ""})
-    # the flipped default: mixed_tick is ON unless opted out
-    eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=16,
-                    prefill_buckets=(16,))
-    assert eng.mixed_tick is True
-    eng.close()
-    eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=16,
-                    prefill_buckets=(16,), mixed_tick=False)
-    assert eng.mixed_tick is False
-    eng.close()
+        flags.set_flags({"kv_dtype": ""})
 
 
 def _tiny_model(name):
@@ -541,31 +537,33 @@ def _tiny_model(name):
     architecture the engine serves, as that model's own tests build it."""
     if name == "gpt":
         return tiny_gpt(), 97, dict(page_size=4, num_pages=128,
-                                    prefill_chunk=8, prefill_buckets=(32,))
+                                    prefill_chunk=32)
     import importlib
     net = importlib.import_module(f"test_{name}").build()[0]
     return net, 128, dict(page_size=8, num_pages=64, max_len=128,
                           prefill_chunk=16, kv_dtype="f32")
 
 
-@pytest.mark.parametrize("model", ["gpt", "granite_hybrid", "ouro", "laguna"])
+@pytest.mark.parametrize("model", ["gpt", "granite_hybrid", "ouro", "laguna",
+                                   "kimi_linear", "mimo_v2"])
 def test_kernel_mixed_ticks_equal_separate_prefill_and_decode(model):
     """The mixed-tick pin THROUGH THE KERNEL, for every architecture: a
-    mixed program (its chunk rows through query tiles, its decode rows
-    through the row walk, one call a layer) serves token for token what
-    separate prefill programs (all tiles) and decode programs (``n_chunk``
-    0: the row walk alone) serve. Prompts that share chunks, a prompt
-    longer than two chunks, a prompt of one token."""
+    mixed program with live decode rows (its chunk rows through query
+    tiles, its decode rows through the row walk, one call a layer) serves
+    token for token what ONE slot serves in turn: a mixed program without
+    a decode row (all tiles: the prefill program), then decode programs
+    (``n_chunk`` 0: the row walk alone). Prompts that share chunks, a
+    prompt longer than two chunks, a prompt of one token."""
     net, vocab, engine = _tiny_model(model)
     rng = np.random.RandomState(4)
     prompts = [rng.randint(0, vocab, m).tolist() for m in (21, 1, 37, 6)]
     streams = {}
-    for mixed in (False, True):
-        with LLMEngine(net, max_seqs=3, attention_impl="pallas",
-                       mixed_tick=mixed, **engine) as eng:
+    for slots in (1, 3):
+        with LLMEngine(net, max_seqs=slots, attention_impl="pallas",
+                       **engine) as eng:
             assert eng.attention_impl == "pallas"
             outs = eng.generate(prompts, max_new_tokens=8)
-            assert ("m" in eng.tick_history) == mixed
-        streams[mixed] = [o["output_ids"] for o in outs]
-        assert all(len(s) == 8 for s in streams[mixed])
-    assert streams[True] == streams[False]
+            assert "m" in eng.tick_history and "d" in eng.tick_history
+        streams[slots] = [o["output_ids"] for o in outs]
+        assert all(len(s) == 8 for s in streams[slots])
+    assert streams[3] == streams[1]

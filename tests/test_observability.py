@@ -439,7 +439,7 @@ def test_llm_engine_populates_metrics(clean_default_registry, tmp_path):
     jsonl = str(tmp_path / "llm.jsonl")
     with JSONLReporter(jsonl, interval=60):
         with LLMEngine(net, max_seqs=4, page_size=4, num_pages=64,
-                       prefill_buckets=(16,)) as eng:
+                       prefill_chunk=16) as eng:
             outs = eng.generate(prompts, max_new_tokens=6)
     assert all(len(o["output_ids"]) == 6 for o in outs)
 

@@ -188,14 +188,16 @@ def test_engine_mixed_ticks_hold_to_the_reference(model):
     assert counted.tolist() == want_steps.tolist() == [0, 0, 70]
 
 
-@pytest.mark.parametrize("knobs", [dict(mixed_tick=False),
-                                   dict(decode_ticks_per_dispatch=4),
-                                   dict(lookahead=2, mixed_tick=False)],
-                         ids=["two_op_ticks", "slab", "lookahead"])
+@pytest.mark.parametrize("knobs", [dict(max_seqs=1),
+                                   dict(decode_ticks_per_dispatch=4)],
+                         ids=["one_slot", "slab"])
 def test_the_other_tick_paths_serve_the_same_tokens(model, knobs):
+    """``one_slot``: all prompts through ONE slot in turn: no decode row
+    ever beside a prompt row, every pass's pages handed to the next
+    sequence."""
     net, params, d = model
     prompts = prompts_of((19, 6, 27), seed=11)
-    with LLMEngine(net, max_seqs=2, **ENGINE, **knobs) as eng:
+    with LLMEngine(net, **{"max_seqs": 2, **ENGINE, **knobs}) as eng:
         outs = [f.result(timeout=600) for f in
                 [eng.submit(p, max_new_tokens=9) for p in prompts]]
         assert eng.loop_exit_step_rows.tolist() == [0, 0, 27]

@@ -213,7 +213,7 @@ def test_engine_with_pallas_attention_matches_dense():
     want = [np.asarray(net.generate(jnp.asarray([p]), max_new_tokens=6)
                        )[0, len(p):].tolist() for p in prompts]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(16,),
+                   prefill_chunk=16,
                    attention_impl="pallas") as eng:
         outs = eng.generate(prompts, max_new_tokens=6)
     for got, ref in zip(outs, want):

@@ -432,7 +432,7 @@ def _tiny_engine(decode_ticks=4, **kw):
                      hidden_dropout=0.0, attention_dropout=0.0)
     net = GPTForCausalLM(cfg)
     return LLMEngine(net, max_seqs=4, page_size=8, num_pages=32,
-                     max_len=64, prefill_buckets=(8,),
+                     max_len=64, prefill_chunk=8,
                      decode_ticks_per_dispatch=decode_ticks, **kw)
 
 
@@ -495,27 +495,25 @@ def test_warming_process_exports_no_perf_gauges():
         "never-worked registry stomped the gauge with 0.0"
 
 
-def test_perf_attribute_idle_gap_consumes_chunk_count():
-    """A 'p' record drained across an idle gap (unmeasurable interval)
-    must still CONSUME the pending chunk-dispatch count and the
-    compile-skip marker — carrying either into a later record would
-    credit FLOPs to an interval that never covered them."""
+def test_perf_attribute_idle_gap_consumes_compile_skip():
+    """A record drained across an idle gap (unmeasurable interval) must
+    still CONSUME its program's compile-skip marker — carried into a
+    later record, it would book a real dispatch interval as compile time
+    and keep it out of the program's accounting."""
     import time as _time
     with _tiny_engine(decode_ticks=1) as eng:
-        eng._perf_chunks_unattributed = 3
         eng._last_fetch_t = None
-        eng._perf_attribute("p", 0, 1)
-        assert eng._perf_chunks_unattributed == 0
-        assert ("prefill_chunk",) in eng._perf_skipped
+        eng._perf_attribute("M", 1, 1)
+        assert ("mixed_tick", 1) in eng._perf_skipped
         h = perf.instance().register_program(
-            "llm", "prefill_chunk", lower=_probe_lower(),
+            "llm", "mixed_tick", sig=(1,), lower=_probe_lower(),
             scope=eng._perf_scope)
-        eng._perf_programs[("prefill_chunk",)] = h
-        eng._perf_chunks_unattributed = 2
+        eng._perf_programs[("mixed_tick", 1)] = h
         eng._last_fetch_t = _time.monotonic() - 0.01
-        eng._perf_attribute("p", 0, 1)
-        assert h.dispatches == 2, \
-            "measured interval must scale by ITS chunk count only"
+        eng._perf_attribute("M", 1, 3)
+        assert (h.dispatches, h.tokens) == (1, 3), \
+            "the measured interval after the gap is the program's"
+        assert h.seconds >= 0.01
 
 
 def test_served_flops_excludes_cached_prefix_tokens():

@@ -82,7 +82,6 @@ def run_engine(net, prompts, n_new, temperature=0.0, sequential=True,
     kw.setdefault("max_seqs", 2)
     kw.setdefault("page_size", 4)
     kw.setdefault("num_pages", 128)
-    kw.setdefault("prefill_buckets", (64,))
     with LLMEngine(net, **kw) as eng:
         if sequential:
             outs = [eng.submit(p, max_new_tokens=n_new,
@@ -152,7 +151,7 @@ def test_copy_on_write_divergence_mid_page():
     a = rng.randint(0, 97, 9).tolist()
     b = a[:6] + [(t + 1) % 97 for t in a[6:]]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         out_a = eng.submit(a, max_new_tokens=6).result(timeout=300)
         hits_after_a = eng.n_cached_tokens
         out_b = eng.submit(b, max_new_tokens=6).result(timeout=300)
@@ -179,7 +178,7 @@ def test_eviction_reclaims_dead_pages_never_live_ones():
 
     # phase 1: A completes; its 2 full pages stay cached at refcount 0
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=6,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         out_a = eng.submit(a, max_new_tokens=4).result(timeout=300)
         assert out_a["output_ids"] == dense_ref(net, a, 4)
         assert eng._cache.shared_page_count == 2
@@ -198,7 +197,7 @@ def test_eviction_reclaims_dead_pages_never_live_ones():
     # (or finishes short), A's tokens are NEVER corrupted
     net2 = tiny_gpt(max_pos=64)
     with LLMEngine(net2, max_seqs=2, page_size=4, num_pages=6,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         fa = eng.submit(a, max_new_tokens=4)
         fb = eng.submit(big, max_new_tokens=8)
         out_a = fa.result(timeout=300)
@@ -211,38 +210,43 @@ def test_eviction_reclaims_dead_pages_never_live_ones():
 # -- scheduling ---------------------------------------------------------
 
 
-def test_long_prompt_interleaves_with_decode():
-    """The acceptance pin: a prompt longer than one chunk no longer
-    blocks in-flight decodes — decode ticks land BETWEEN its prefill
-    chunks (tick history shows p..d..p), the tick-ratio metric is
-    populated, and admission performed no blocking device fetch (the
-    whole point of the async first-token harvest)."""
+def test_long_prompt_interleaves_with_decode(issue_phases):
+    """The acceptance pin: a prompt longer than one chunk does not
+    block in-flight decodes — its chunks ride mixed dispatches that
+    also carry the short request's decode row, which RECEIVES tokens
+    meanwhile; the tick-ratio metric is populated, and admission
+    performed no blocking device fetch (the whole point of the async
+    first-token harvest)."""
     from paddle_tpu.observability import metrics as obs
+    from paddle_tpu.observability import tracing
 
     net = tiny_gpt(max_pos=96)
     rng = np.random.RandomState(4)
     short = rng.randint(0, 97, 4).tolist()
     long_p = rng.randint(0, 97, 40).tolist()
-    # mixed_tick off: this pin witnesses the TWO-OP interleave
-    # (p..d..p); the fused ragged tick is gated in test_mixed_ragged
+    tracing.enable()
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=128,
-                   prefill_buckets=(64,), prefill_chunk=4,
-                   mixed_tick=False) as eng:
+                   prefill_chunk=4) as eng:
         fa = eng.submit(short, max_new_tokens=40)
-        time.sleep(0.3)      # let the short request enter decode
+        while not eng.n_decode_ticks:   # the short request in decode
+            time.sleep(0.002)
         fb = eng.submit(long_p, max_new_tokens=4)   # 10 prefill chunks
         out_a = fa.result(timeout=300)
         out_b = fb.result(timeout=300)
         hist = "".join(eng.tick_history)
-        assert eng.n_prefill_ticks >= 10
+        assert eng.n_prefill_ticks >= 11
+        assert eng.n_mixed_slabs >= 11
         assert eng.n_decode_ticks > 0
+    tracing.disable()
     assert out_a["output_ids"] == dense_ref(net, short, 40)
     assert out_b["output_ids"] == dense_ref(net, long_p, 4)
-    # a decode tick strictly between two prefill chunks
-    first_p = hist.index("p", hist.index("d"))  # a chunk after decode began
-    assert "d" in hist[first_p:hist.rindex("p")], hist
+    # a token for the short request out of (nearly) every dispatch that
+    # carried one of the long prompt's ten chunks
+    beside = issue_phases.tokens_beside_chunks(tracing.finished_spans(),
+                                               len(long_p))
+    assert len(beside) >= 8 and all(beside.values()), (beside, hist)
     snap = obs.default_registry().snapshot()
-    assert snap["llm_prefill_ticks"] >= 10
+    assert snap["llm_prefill_ticks"] >= 11
     assert snap["llm_decode_ticks"] > 0
     assert snap["llm_prefill_decode_tick_ratio"] > 0
     assert snap["llm_prefix_cache_hit_rate"] >= 0
@@ -250,10 +254,10 @@ def test_long_prompt_interleaves_with_decode():
 
 def test_submit_validates_total_length_against_max_len():
     """submit() must bound prompt + max_new_tokens by the page-table
-    horizon (max_len), independently of the prefill-bucket bound."""
+    horizon (max_len)."""
     net = tiny_gpt(max_pos=96)
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
-                   max_len=32, prefill_buckets=(64,)) as eng:
+                   max_len=32) as eng:
         with pytest.raises(ValueError, match="max_len"):
             eng.submit(list(range(20)), max_new_tokens=20)
         # fits the horizon exactly -> admitted and completes
@@ -263,30 +267,52 @@ def test_submit_validates_total_length_against_max_len():
         assert not out["truncated"]
 
 
-def test_prefill_queue_and_inflight_survive_device_error():
-    """A device error during a prefill chunk fails the queued request
-    cleanly (future resolves, pages reclaimed, cache flushed) and the
+@pytest.mark.parametrize("beside, budget", [(False, 0), (True, 0),
+                                            (True, 1)],
+                         ids=["alone", "beside_decode_row",
+                              "beside_decode_row_retried"])
+def test_prefill_queue_and_inflight_survive_device_error(beside, budget):
+    """A device error in the mixed dispatch that carries a prompt's chunk
+    (alone, or beside a live request's decode row) resolves every
+    request in it as docs/RELIABILITY.md says: failed with the error
+    where the retry budget is spent, re-admitted and token-identical
+    where it is not; pages reclaimed, no dangling queue entry, and the
     engine keeps serving."""
     net = tiny_gpt()
-    # mixed_tick off so the chunk lands on _chunk_fn (the patched
-    # site) rather than riding a mixed slab
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,), mixed_tick=False)
-    real = eng._chunk_fn
-    calls = {"n": 0}
+                    prefill_chunk=16, device_retry_budget=budget)
+    real = eng._mixed_fn
+    struck = []
 
-    def flaky(*a, **kw):
-        calls["n"] += 1
-        if calls["n"] == 1:
+    def flaky(params, buffers, carry, xs, *rest):
+        rows = (bool(np.asarray(carry.budgets).any()),
+                bool(np.asarray(xs["lim"]).any()))
+        if not struck and rows == (beside, True):
+            struck.append(rows)
             raise RuntimeError("transient PJRT failure")
-        return real(*a, **kw)
+        return real(params, buffers, carry, xs, *rest)
 
-    eng._chunk_fn = flaky
-    bad = eng.submit([1, 2, 3, 4, 5, 6], max_new_tokens=4)
-    with pytest.raises(RuntimeError, match="transient"):
-        bad.result(timeout=60)
+    jobs = [([1, 2, 3, 4, 5, 6], 4)]
+    futs = []
+    if beside:
+        # a live request in decode BEFORE the patched site is armed
+        jobs.insert(0, ([9, 8, 7, 6, 5], 40))
+        futs.append(eng.submit(jobs[0][0], max_new_tokens=jobs[0][1]))
+        while not eng.n_decode_ticks:
+            time.sleep(0.002)
+    eng._mixed_fn = flaky
+    futs.append(eng.submit(jobs[-1][0], max_new_tokens=jobs[-1][1]))
+    for f, (prompt, n) in zip(futs, jobs):
+        if budget:
+            assert f.result(timeout=60)["output_ids"] == \
+                dense_ref(net, prompt, n)
+        else:
+            with pytest.raises(RuntimeError, match="transient"):
+                f.result(timeout=60)
+    assert struck == [(beside, True)]
     assert not eng._prefill_q          # no dangling queue entry
     ok = eng.submit([7, 8, 9], max_new_tokens=3).result(timeout=60)
     assert ok["output_ids"] == dense_ref(net, [7, 8, 9], 3)
+    assert eng.health == "healthy"     # a successful fetch ended the streak
     eng.close()
     assert len(eng._free_pages) == eng.num_pages - 1

@@ -373,7 +373,7 @@ def test_engine_deadline_resolves_future_and_keeps_serving():
     from paddle_tpu.inference.llm import LLMEngine
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         doomed = eng.submit([1, 2, 3], max_new_tokens=8,
                             deadline=0.0005)
         with pytest.raises(DeadlineExceeded):
@@ -387,7 +387,7 @@ def test_engine_sheds_on_bounded_queue_overflow():
     from paddle_tpu.inference.llm import AdmissionShed, LLMEngine
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
-                   prefill_buckets=(16,), max_pending=2) as eng:
+                   prefill_chunk=16, max_pending=2) as eng:
         # the first submissions pin the loop in compile + decode; the
         # burst behind them overflows max_pending=2 and must shed
         futs = [eng.submit([i + 1, i + 2, i + 3], max_new_tokens=16)
@@ -414,7 +414,7 @@ def test_generate_batch_wider_than_max_pending_never_sheds():
     net = tiny_gpt()
     prompts = [[i + 1, i + 2, i + 3] for i in range(6)]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(16,), max_pending=2) as eng:
+                   prefill_chunk=16, max_pending=2) as eng:
         outs = eng.generate(prompts, max_new_tokens=2)
     assert len(outs) == 6
     for p, o in zip(prompts, outs):
@@ -429,7 +429,7 @@ def test_device_retry_starts_a_fresh_admission_cycle():
     from paddle_tpu.inference.llm import LLMEngine
     net = tiny_gpt()
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,), admit_timeout=0.3,
+                    prefill_chunk=16, admit_timeout=0.3,
                     device_retry_budget=1)
     try:
         real = eng._decode_fn
@@ -456,7 +456,7 @@ def test_engine_cancel_resolves_and_frees_pages():
     from paddle_tpu.inference.llm import LLMEngine, RequestCancelled
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=4, page_size=4, num_pages=64,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         futs = [eng.submit([i + 1, i + 2], max_new_tokens=64)
                 for i in range(4)]
         assert all(hasattr(f, "request_id") for f in futs)
@@ -481,7 +481,7 @@ def test_cancel_wins_over_a_simultaneous_device_error():
     from paddle_tpu.inference.llm import LLMEngine, RequestCancelled
     net = tiny_gpt()
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,))
+                    prefill_chunk=16)
     try:
         box = {}
 
@@ -504,7 +504,7 @@ def test_engine_admission_timeout_is_typed_not_an_infinite_spin():
     from paddle_tpu.inference.llm import AdmissionTimeout, LLMEngine
     net = tiny_gpt()
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
-                   prefill_buckets=(16,), admit_timeout=0.15) as eng:
+                   prefill_chunk=16, admit_timeout=0.15) as eng:
         hog = eng.submit([1, 2, 3], max_new_tokens=64)
         starved = eng.submit([4, 5, 6], max_new_tokens=4)
         with pytest.raises(AdmissionTimeout, match="admit_timeout"):
@@ -521,7 +521,7 @@ def test_engine_device_retry_budget_reproduces_token_stream():
     from paddle_tpu.inference.llm import LLMEngine
     net = tiny_gpt()
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,), device_retry_budget=2)
+                    prefill_chunk=16, device_retry_budget=2)
     try:
         real = eng._decode_fn
         state = {"n": 0}
@@ -547,7 +547,7 @@ def run_clean(net, prompt, n_new):
     """Reference stream from an un-faulted engine (seeded sampling)."""
     from paddle_tpu.inference.llm import LLMEngine
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         return eng.submit(prompt, max_new_tokens=n_new,
                           temperature=0.8).result(
                               timeout=120)["output_ids"]
@@ -573,7 +573,7 @@ def test_spec_engine_mixed_dispatch_error_reclaims_pages_and_budgets():
                       attention_dropout=0.0)
     draft = GPTForCausalLM(dcfg)
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=32,
-                    prefill_buckets=(16,), draft_net=draft,
+                    prefill_chunk=16, draft_net=draft,
                     spec_tokens=2, device_retry_budget=1)
     try:
         real = eng._mixed_fn
@@ -605,18 +605,17 @@ def test_spec_engine_mixed_dispatch_error_reclaims_pages_and_budgets():
 def test_engine_health_walks_to_draining_and_sheds():
     from paddle_tpu.inference.llm import AdmissionShed, LLMEngine
     net = tiny_gpt()
-    # mixed_tick off so prefill definitely routes through _chunk_fn
-    # (the patched site); the mixed path has its own chaos coverage
+    # a lone prompt's chunk rides the mixed dispatch: the patched site
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,), degraded_after=1,
-                    drain_after=2, mixed_tick=False)
+                    prefill_chunk=16, degraded_after=1,
+                    drain_after=2)
     try:
-        real = eng._chunk_fn
+        real = eng._mixed_fn
 
         def broken(*a, **kw):
             raise RuntimeError("device wedged")
 
-        eng._chunk_fn = broken
+        eng._mixed_fn = broken
         for i in range(2):                 # one error per submission
             with pytest.raises(RuntimeError, match="wedged"):
                 eng.submit([1, 2, 3], max_new_tokens=2).result(
@@ -629,7 +628,7 @@ def test_engine_health_walks_to_draining_and_sheds():
         with pytest.raises(AdmissionShed, match="draining"):
             eng.submit([4, 5], max_new_tokens=2).result(timeout=60)
         # operator recovery: reset + fixed device → serving again
-        eng._chunk_fn = real
+        eng._mixed_fn = real
         eng.reset_health()
         assert eng.health == "healthy"
         out = eng.submit([7, 8, 9], max_new_tokens=3).result(timeout=60)
@@ -645,7 +644,7 @@ def test_healthz_surfaces_engine_health_state():
     net = tiny_gpt()
     srv = DebugServer(port=0).start()
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(16,))
+                    prefill_chunk=16)
     try:
         base = f"http://127.0.0.1:{srv.port}"
         with urllib.request.urlopen(base + "/healthz", timeout=30) as r:

@@ -12,7 +12,7 @@ real requests).
 Engine-level: greedy slab output is token-identical to a
 target-only engine across prefix cache on/off × fused-slab width
 N∈{1,8} × kv_dtype, with all four previously-excluded knobs (cache,
-N>1 slabs, mixed_tick, int8) enabled SIMULTANEOUSLY on one spec
+N>1 slabs, mixed ticks, int8) enabled SIMULTANEOUSLY on one spec
 engine; temperature>0 realized streams are nonce-pinned deterministic
 across cache/slab/batch-shape configurations (the failover
 token-identity contract)."""
@@ -136,14 +136,14 @@ def test_greedy_slab_identity_vs_target_only(cache, n_ticks):
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, 97, n).tolist() for n in (4, 9, 3)]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,)) as ref:
+                   prefill_chunk=8) as ref:
         want = [o["output_ids"]
                 for o in ref.generate(prompts, max_new_tokens=10)]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), draft_net=draft,
+                   prefill_chunk=8, draft_net=draft,
                    spec_tokens=3, prefix_cache=cache,
                    decode_ticks_per_dispatch=n_ticks) as eng:
-        assert eng.spec_k and eng.mixed_tick
+        assert eng.spec_k
         free0 = len(eng._free_pages)
         outs = eng.generate(prompts, max_new_tokens=10)
     assert len(eng._free_pages) == eng.num_pages - 1  # close() flushed
@@ -153,24 +153,23 @@ def test_greedy_slab_identity_vs_target_only(cache, n_ticks):
 
 def test_greedy_slab_identity_int8_all_knobs():
     """int8 spec engine (quantized draft pool) + prefix cache + N=8
-    fused slabs + mixed_tick, all simultaneously: token-identical to
+    fused slabs + mixed ticks, all simultaneously: token-identical to
     the target-only int8 engine (quantization moves logits, so the
     reference is int8 too)."""
     net, draft = _target(), _draft()
     rng = np.random.RandomState(2)
     prompts = [rng.randint(0, 97, n).tolist() for n in (5, 11, 3)]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), kv_dtype="int8") as ref:
+                   prefill_chunk=8, kv_dtype="int8") as ref:
         want = [o["output_ids"]
                 for o in ref.generate(prompts, max_new_tokens=10)]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), draft_net=draft,
+                   prefill_chunk=8, draft_net=draft,
                    spec_tokens=3, kv_dtype="int8",
                    decode_ticks_per_dispatch=8) as eng:
-        assert eng.spec_k and eng.mixed_tick \
-            and eng._cache is not None
+        assert eng.spec_k and eng._cache is not None
         outs = eng.generate(prompts, max_new_tokens=10)
-        assert eng.n_spec_rounds > 0
+        assert eng.n_spec_rounds > 0 and eng.n_mixed_slabs > 0
     assert [o["output_ids"] for o in outs] == want
 
 
@@ -185,7 +184,7 @@ def test_temp_rejection_nonce_pinned_determinism():
     def run(**kw):
         ms = kw.pop("max_seqs", 2)
         with LLMEngine(net, max_seqs=ms, page_size=4, num_pages=64,
-                       prefill_buckets=(8,), draft_net=draft,
+                       prefill_chunk=8, draft_net=draft,
                        spec_tokens=3, **kw) as eng:
             futs = [eng.submit(p, max_new_tokens=10, temperature=0.8,
                                nonce=100 + i)
@@ -199,7 +198,7 @@ def test_temp_rejection_nonce_pinned_determinism():
     assert run(max_seqs=1) == base
     # a different nonce moves the stream (the lane is real)
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), draft_net=draft,
+                   prefill_chunk=8, draft_net=draft,
                    spec_tokens=3) as eng:
         other = eng.submit(prompts[0], max_new_tokens=10,
                            temperature=0.8,

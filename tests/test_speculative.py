@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as pt
-from paddle_tpu.inference.llm import (_ChunkedPrefill, _PagedDecode,
+from paddle_tpu.inference.llm import (_DraftChunk, _PagedDecode,
                                       _PagedVerify)
 from paddle_tpu.models.gpt import GPTForCausalLM, gpt_config, llama_config
 from paddle_tpu.nn.layer import functional_call, split_state
@@ -44,22 +44,23 @@ def _seed_pages(net, prompt):
     tables = np.zeros((1, P), np.int32)
     for i in range(P):
         tables[0, i] = i + 1
-    prefill = _ChunkedPrefill(net)
+    prefill = _DraftChunk(net)
     params, buffers = split_state(prefill)
     n, T = len(prompt), 16
     ids = np.zeros((T,), np.int32)
     ids[:n] = prompt
     pos = np.arange(T, dtype=np.int32)
     valid = pos < n
-    last = jnp.asarray([n - 1], jnp.int32)
-    (t0, kp, vp), _ = functional_call(
+    (kp, vp), _ = functional_call(
         prefill, params, buffers, jnp.asarray(ids),
         jnp.asarray(np.where(valid, pos, 0)),
         jnp.asarray(np.where(valid, pos + 1, 0)),
-        jnp.asarray(np.repeat(tables, T, axis=0)), last, last, kp, vp,
-        jnp.asarray([0.0], jnp.float32), jnp.asarray([0], jnp.int32),
-        jax.random.PRNGKey(0), training=False)
-    return kp, vp, jnp.asarray(tables), n, int(t0[0])
+        jnp.asarray(np.repeat(tables, T, axis=0)), kp, vp,
+        training=False)
+    # the first token after the prompt: the dense forward's
+    t0 = np.asarray(net.generate(jnp.asarray([prompt]),
+                                 max_new_tokens=1))[0, n]
+    return kp, vp, jnp.asarray(tables), n, int(t0)
 
 
 @pytest.mark.parametrize("gqa", [False, True], ids=["mha", "gqa"])
@@ -150,7 +151,7 @@ def test_speculative_engine_exact_with_perfect_draft():
     with pytest.raises(ValueError, match="spec_tokens"):
         LLMEngine(net, draft_net=net, spec_tokens=1)
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(16,), draft_net=net,
+                   prefill_chunk=16, draft_net=net,
                    spec_tokens=4) as eng:
         outs = eng.generate(prompts, max_new_tokens=12)
         rounds, toks = eng.n_spec_rounds, eng.n_tokens
@@ -178,7 +179,7 @@ def test_speculative_engine_exact_with_imperfect_draft():
                                     max_new_tokens=10))[0, len(p):]
             .tolist() for p in prompts]
     with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), draft_net=draft,
+                   prefill_chunk=8, draft_net=draft,
                    spec_tokens=3) as eng:
         free0 = len(eng._free_pages)
         outs = eng.generate(prompts, max_new_tokens=10)
@@ -194,7 +195,7 @@ def test_speculative_engine_eos_and_guards():
     # chunked ragged prefill takes a prompt longer than any bucket,
     # rejection sampling serves temp>0
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
-                   prefill_buckets=(8,), draft_net=net,
+                   prefill_chunk=8, draft_net=net,
                    spec_tokens=3, eos_token_id=7) as eng:
         out = eng.generate([list(range(20))], max_new_tokens=4,
                            temperature=0.9)[0]
@@ -203,8 +204,8 @@ def test_speculative_engine_eos_and_guards():
         if 7 in out["output_ids"]:
             assert out["output_ids"][-1] == 7
         assert len(out["output_ids"]) <= 40
-    with pytest.raises(ValueError, match="lookahead"):
-        LLMEngine(net, draft_net=net, lookahead=2)
+    with pytest.raises(ValueError, match="spec_tokens"):
+        LLMEngine(net, draft_net=net, spec_tokens=1)
 
 
 def test_speculative_tight_max_len_parity():
@@ -219,7 +220,7 @@ def test_speculative_tight_max_len_parity():
     want = np.asarray(net.generate(jnp.asarray([prompt]),
                                    max_new_tokens=3))[0, 13:].tolist()
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=64,
-                   prefill_buckets=(16,), max_len=16, draft_net=net,
+                   prefill_chunk=16, max_len=16, draft_net=net,
                    spec_tokens=4) as eng:
         out = eng.generate([prompt], max_new_tokens=3)[0]
     assert out["output_ids"] == want

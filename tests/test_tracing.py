@@ -345,7 +345,7 @@ def test_llm_request_span_tree_parents_and_latency_sum(tmp_path):
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, 97, n).tolist() for n in (5, 11, 3)]
     with LLMEngine(net, max_seqs=4, page_size=4, num_pages=128,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         outs = eng.generate(prompts, max_new_tokens=8)
     spans = tracing.finished_spans()
     roots = [s for s in spans if s["name"] == "llm.request"]
@@ -393,7 +393,7 @@ def test_llm_failed_admission_closes_span_tree_with_error():
     from paddle_tpu.inference.llm import LLMEngine
     net = _tiny_gpt()
     with LLMEngine(net, max_seqs=1, page_size=4, num_pages=4,
-                   prefill_buckets=(16,)) as eng:
+                   prefill_chunk=16) as eng:
         fut = eng.submit(list(range(20)), max_new_tokens=2)
         with pytest.raises(ValueError, match="cannot fit"):
             fut.result(timeout=120)
@@ -409,7 +409,7 @@ def test_llm_statusz_provider_lifecycle():
     from paddle_tpu.inference.llm import LLMEngine
     net = _tiny_gpt()
     eng = LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
-                    prefill_buckets=(8,))
+                    prefill_chunk=8)
     st = server._collect_status()
     mine = [v for k, v in st.items() if k.startswith("llm_engine_")]
     assert any(v["max_seqs"] == 2 and "prefix_cache" in v

@@ -221,7 +221,7 @@ def engine_soak(seed: int) -> dict:
 
     net = _tiny_gpt()
     eng = LLMEngine(net, max_seqs=4, page_size=4, num_pages=96,
-                    prefill_buckets=(16,), max_pending=8,
+                    prefill_chunk=16, max_pending=8,
                     admit_timeout=60.0, device_retry_budget=4,
                     drain_after=64)
     outcomes = {"ok": 0, "deadline": 0, "shed": 0, "cancelled": 0,
@@ -317,11 +317,11 @@ def engine_soak(seed: int) -> dict:
     return outcomes
 
 
-def slab_soak(seed: int, mixed: bool = False,
-              kv_dtype=None) -> dict:
+def slab_soak(seed: int, kv_dtype=None) -> dict:
     """ISSUE 10 phase: the engine invariants under FUSED DECODE SLABS
     (``decode_ticks_per_dispatch=8``) — an injected ``engine.slab``
-    kill storm at the slab dispatch, hopeless deadlines, and a
+    kill storm at the slab dispatch (pure-decode AND the ragged mixed
+    slab that carries the prompts), hopeless deadlines, and a
     cancellation storm landing mid-slab. Asserts: every future
     resolves; retried streams are TOKEN-IDENTICAL to a fault-free
     reference engine over the same prompts (device retries keep the
@@ -330,12 +330,10 @@ def slab_soak(seed: int, mixed: bool = False,
     pages leak and no ``llm.*`` span stays open after close; the
     injected sequence equals the pure seeded schedule.
 
-    ISSUE 15 rider (``mixed=True, kv_dtype="int8"``): the SAME storm
-    through the ragged MIXED tick on an int8-quantized pool —
-    ``engine.slab`` faults fire at the mixed dispatch too, and
-    nonce-pinned token identity must hold against an int8+mixed
-    reference (quantization is deterministic, so chaos stays
-    invisible in the streams)."""
+    ISSUE 15 rider (``kv_dtype="int8"``): the SAME storm on an
+    int8-quantized pool — nonce-pinned token identity must hold
+    against an int8 reference (quantization is deterministic, so
+    chaos stays invisible in the streams)."""
     from paddle_tpu.inference.llm import LLMEngine, RequestCancelled
     from paddle_tpu.observability import tracing
     from paddle_tpu.reliability import faults
@@ -349,9 +347,9 @@ def slab_soak(seed: int, mixed: bool = False,
 
     def build(**kw):
         return LLMEngine(net, max_seqs=4, page_size=4, num_pages=96,
-                         prefill_buckets=(16,), drain_after=64,
+                         prefill_chunk=16, drain_after=64,
                          decode_ticks_per_dispatch=8,
-                         mixed_tick=mixed, kv_dtype=kv_dtype, **kw)
+                         kv_dtype=kv_dtype, **kw)
 
     # fault-free reference streams: same engine seed, same submission
     # order => same nonces => the chaos run must reproduce these
@@ -435,7 +433,7 @@ def slab_soak(seed: int, mixed: bool = False,
     assert not open_llm, f"span trees left open: {open_llm}"
     return {"injected": n_injected, "cancelled": n_cancelled,
             "requests": len(futs) + len(dl) + len(storm),
-            "mixed_tick": mixed, "kv_dtype": kv_dtype or "f32"}
+            "kv_dtype": kv_dtype or "f32"}
 
 
 def spec_slab_soak(seed: int) -> dict:
@@ -474,7 +472,7 @@ def spec_slab_soak(seed: int) -> dict:
 
     def build(**kw):
         return LLMEngine(net, max_seqs=4, page_size=4, num_pages=96,
-                         prefill_buckets=(16,), drain_after=64,
+                         prefill_chunk=16, drain_after=64,
                          decode_ticks_per_dispatch=8,
                          draft_net=draft, spec_tokens=3,
                          kv_dtype="int8", **kw)
@@ -597,11 +595,11 @@ def page_pressure_soak(seed: int, kv_dtype=None) -> dict:
     num_pages, n_requests = 18, 8
     if kv_dtype is not None:
         probe = LLMEngine(net, max_seqs=2, page_size=4, num_pages=8,
-                          prefill_buckets=(16,), max_len=64)
+                          prefill_chunk=16, max_len=64)
         budget = 18 * probe._page_bytes
         probe.close()
         probe = LLMEngine(net, max_seqs=2, page_size=4, num_pages=8,
-                          prefill_buckets=(16,), max_len=64,
+                          prefill_chunk=16, max_len=64,
                           kv_dtype=kv_dtype)
         num_pages = int(budget // probe._page_bytes)
         probe.close()
@@ -616,7 +614,7 @@ def page_pressure_soak(seed: int, kv_dtype=None) -> dict:
     # pool, so slab-shrink engages at twice the occupancy
     max_seqs = 4 if kv_dtype is None else 8
     eng = LLMEngine(net, max_seqs=max_seqs, page_size=4,
-                    num_pages=num_pages, prefill_buckets=(16,),
+                    num_pages=num_pages, prefill_chunk=16,
                     max_len=64, decode_ticks_per_dispatch=N,
                     admit_timeout=120.0, kv_dtype=kv_dtype)
     led = memobs.instance()
@@ -864,7 +862,7 @@ def goodput_soak(seed: int, workdir: str) -> dict:
         faults.inject("device.dispatch", nth=(5, 12))
         net = _tiny_gpt()
         with LLMEngine(net, max_seqs=4, page_size=4, num_pages=96,
-                       prefill_buckets=(16,), device_retry_budget=4,
+                       prefill_chunk=16, device_retry_budget=4,
                        admit_timeout=60.0) as eng:
             futs = [eng.submit(rng.randint(0, 97, 8).tolist(),
                                max_new_tokens=8) for _ in range(6)]
@@ -1309,7 +1307,7 @@ def disagg_soak(seed: int, workdir: str) -> dict:
              "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0}
     engine_kw = {"page_size": 4, "num_pages": 96, "max_seqs": 4,
-                 "prefill_buckets": (32,), "seed": 0,
+                 "prefill_chunk": 32, "seed": 0,
                  "kv_dtype": "int8"}
     spec = dict(model, name="pre0", role="prefill",
                 engine=dict(engine_kw))
@@ -1482,7 +1480,7 @@ def drift_soak(seed: int, workdir: str) -> dict:
              "vocab": 97, "layers": 2, "hidden": 64, "heads": 4,
              "max_pos": 96, "model_seed": 0}
     engine_kw = {"max_seqs": 4, "page_size": 4, "num_pages": 64,
-                 "prefill_buckets": (32,), "seed": 0,
+                 "prefill_chunk": 32, "seed": 0,
                  "device_retry_budget": 2}
     engs = [make_engine_from_spec(dict(model, engine=dict(engine_kw)))
             for _ in range(2)]
@@ -1872,7 +1870,7 @@ def overload_soak(seed: int, workdir: str) -> dict:
 
     def build_engine():
         return LLMEngine(_tiny_gpt(), max_seqs=4, page_size=4,
-                         num_pages=96, prefill_buckets=(16,),
+                         num_pages=96, prefill_chunk=16,
                          max_pending=64, admit_timeout=60.0, seed=0)
 
     engines = [build_engine(), build_engine()]
@@ -2599,11 +2597,10 @@ def main(argv=None) -> int:
             out["train"] = train_soak(seed, workdir)
         elif args.slab:
             out["slab"] = slab_soak(seed)
-            # ISSUE 15: the same kill/cancel/deadline storm through
-            # the ragged MIXED tick on an int8-quantized pool —
-            # nonce-pinned identity vs an int8+mixed reference
-            out["slab_mixed_int8"] = slab_soak(seed, mixed=True,
-                                               kv_dtype="int8")
+            # ISSUE 15: the same kill/cancel/deadline storm on an
+            # int8-quantized pool — nonce-pinned identity vs an int8
+            # reference
+            out["slab_int8"] = slab_soak(seed, kv_dtype="int8")
             out["page_pressure"] = page_pressure_soak(seed)
             # ISSUE 15: same storm, same pool HBM, int8 pages —
             # >=1.8x usable pages, scale_table row, headroom re-pin

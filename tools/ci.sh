@@ -44,12 +44,10 @@ echo "   + one cross-process trace + disabled-tracing flag-check bound)"
 python tools/obs_smoke.py "$(mktemp -d)" --fleet
 
 echo "== llm serving smoke (prefix cache + chunked ragged prefill"
-echo "   + decode-ticks sweep + ragged MIXED-TICK gate)"
+echo "   + decode-ticks sweep)"
 # 4 shared-prefix prompts through the engine: asserts nonzero cache
-# hits, cache-on == cache-off generations, a clean shutdown, the
-# fused decode-slab sweep, and the mixed-tick gate (one ragged
-# prefill+decode slab token-identical to the legacy two-op tick loop
-# at strictly fewer host dispatches)
+# hits, cache-on == cache-off generations, a clean shutdown, and the
+# fused decode-slab sweep
 python tools/llm_bench.py --ci
 
 echo "== kv-dtype bench (bf16 vs int8 KV pool at fixed HBM)"
@@ -75,12 +73,12 @@ echo "== chaos soak (seeded fault injection -> hardened semantics)"
 python tools/chaos_soak.py --ci
 
 echo "== fused-slab chaos soak (decode_ticks_per_dispatch=8"
-echo "   + mixed-tick/int8 riders)"
+echo "   + int8 riders)"
 # engine.slab kill storm at the fused slab dispatch + cancel/deadline
 # storms landing mid-slab: every future resolves, retried streams are
 # token-identical to a fault-free reference engine, zero KV-page
 # leaks, fault schedule replays from seed. ISSUE-15 riders: the same
-# storm through the ragged MIXED tick on an int8 pool, and the
+# storm on an int8 pool, and the
 # page-pressure storm repeated at fixed HBM with kv_dtype=int8
 # (>=1.8x usable pages, 2x slots before slab-shrink engages,
 # scale_table ledger row, headroom gauge semantics re-pinned)

@@ -149,15 +149,15 @@ def phase_rollup():
 
 
 def run_mode(net, prompts, gen_len, prefix_cache, page_size=16,
-             prefill_chunk=64, max_seqs=4, mixed_tick=False,
-             kv_dtype=None, decode_ticks=1):
+             prefill_chunk=64, max_seqs=4, kv_dtype=None,
+             decode_ticks=1):
     """One engine pass over the workload. The FIRST request runs alone
     (it populates the cache — and doubles as compile warmup), the rest
     arrive as a concurrent burst, which is where prefix reuse pays.
     Tracing is ON for the pass (span bookkeeping is host-side dict
     ops, noise against a model forward) so the row carries the
-    per-phase breakdown. ``mixed_tick``/``kv_dtype``/``decode_ticks``
-    pass the ISSUE-15 knobs through (ragged mixed slab, int8 pool)."""
+    per-phase breakdown. ``kv_dtype``/``decode_ticks`` pass the
+    engine's knobs through (int8 pool, fused slabs)."""
     from paddle_tpu.inference.llm import LLMEngine
     from paddle_tpu.observability import tracing
 
@@ -168,10 +168,8 @@ def run_mode(net, prompts, gen_len, prefix_cache, page_size=16,
     pages = -(-total // page_size) * max_seqs + 8
     eng = LLMEngine(net, max_seqs=max_seqs, page_size=page_size,
                     num_pages=pages, max_len=total,
-                    prefill_buckets=(max(len(p) for p in prompts),),
                     prefill_chunk=prefill_chunk,
-                    prefix_cache=prefix_cache, mixed_tick=mixed_tick,
-                    kv_dtype=kv_dtype,
+                    prefix_cache=prefix_cache, kv_dtype=kv_dtype,
                     decode_ticks_per_dispatch=decode_ticks)
     with eng:
         outs = [eng.submit(prompts[0],
@@ -248,7 +246,6 @@ def run_fleet_mode(net_fn, prompts, gen_len, policy, n_replicas=3,
         LLMEngine(net_fn(), max_seqs=4, page_size=page_size,
                   num_pages=-(-total // page_size) * 4 + 24,
                   max_len=total,
-                  prefill_buckets=(max(len(p) for p in prompts),),
                   prefill_chunk=64, prefix_cache=True)
         for _ in range(n_replicas)]
     router = Router({f"r{i}": LocalReplica(e)
@@ -391,11 +388,11 @@ def run_disagg_mode(net_fn, storm, disagg, page_size=16,
     engines = [
         LLMEngine(net_fn(), max_seqs=ms, page_size=page_size,
                   num_pages=-(-total // page_size) * 6 + 32,
-                  max_len=total, prefill_buckets=(long_len,),
+                  max_len=total,
                   prefill_chunk=32, prefix_cache=True,
                   kv_dtype="int8")
         for ms in slots]
-    # warmup: the prefill bucket + the decode slab at a few batch
+    # warmup: the mixed program + the decode slab at a few batch
     # widths, identical shapes on every engine in both fleets
     warm_long = [(7 * i + 3) % vocab for i in range(long_len)]
     warm_short = [(5 * i + 1) % vocab for i in range(12)]
@@ -477,7 +474,6 @@ def run_decode_probe(net_fn, disagg, n_victims=4, n_long=6,
                          num_pages=-(-long_len // page_size)
                          * (n_long + 2) + 48,
                          max_len=long_len + victim_gen,
-                         prefill_buckets=(long_len,),
                          prefill_chunk=32, prefix_cache=True,
                          kv_dtype="int8")
 
@@ -503,7 +499,7 @@ def run_decode_probe(net_fn, disagg, n_victims=4, n_long=6,
 
     eng = mk()
     try:
-        # warmup: compile the decode slab and the prefill bucket,
+        # warmup: compile the decode slab and the mixed program,
         # and (disagg) pay the import path's one-time lazy-init cost
         # on a throwaway payload — both passes must enter the window
         # with their long-arrival path already hot
@@ -903,7 +899,7 @@ def storm_main(args):
         net = build_net(vocab=97, hidden=64, max_pos=96)
         return LLMEngine(net, max_seqs=2, page_size=16,
                          num_pages=3 * (-(-max_len // 16)) + 16,
-                         max_len=max_len, prefill_buckets=(40,),
+                         max_len=max_len,
                          prefill_chunk=64, prefix_cache=True,
                          max_pending=256, admit_timeout=120.0,
                          seed=0)
@@ -1121,7 +1117,7 @@ def overload_main(args):
         net = build_net(vocab=97, hidden=64, max_pos=96)
         return LLMEngine(net, max_seqs=2, page_size=16,
                          num_pages=3 * (-(-max_len // 16)) + 16,
-                         max_len=max_len, prefill_buckets=(40,),
+                         max_len=max_len,
                          prefill_chunk=64, prefix_cache=True,
                          max_pending=256, admit_timeout=120.0,
                          seed=0)
@@ -1216,7 +1212,7 @@ def run_decode_ticks(net, prompts, gen_len, n_ticks, temperature=0.0,
     eng = LLMEngine(net, max_seqs=max(4, len(prompts)),
                     page_size=page_size, num_pages=pages,
                     max_len=total,
-                    prefill_buckets=(max(len(p) for p in prompts),),
+                    prefill_chunk=max(len(p) for p in prompts),
                     decode_ticks_per_dispatch=n_ticks)
     with eng:
         # warmup: compile prefill + the slab program off the clock
@@ -1317,76 +1313,6 @@ def decode_ticks_main(args, net=None, assert_ci=False):
     return 0
 
 
-def mixed_tick_main(args, net=None, assert_ci=False):
-    """The MIXED-TICK gate (ISSUE 15): the shared-prefix workload
-    through the legacy alternating prefill-tick/decode-slab loop vs
-    ONE ragged mixed slab — BOTH at ``decode_ticks_per_dispatch=8``,
-    so the headline isolates what mixed-tick ADMISSION saves (the
-    prefill dispatches and the slab boundaries around them), not the
-    already-shipped PR-10 slab fusion. Token identity is the hard
-    gate."""
-    if net is None:
-        net = build_net(vocab=97, hidden=64, max_pos=256) if args.ci \
-            else build_net()
-    prompts = make_prompts(4, prefix_len=32, tail_len=8, vocab=97) \
-        if args.ci else make_prompts(args.n_requests, args.prefix_len,
-                                     args.tail_len, vocab=211)
-    gen_len = 16 if args.ci else args.gen_len
-    # prefill_chunk=16: the burst's uncached suffixes span SEVERAL
-    # chunks, so the legacy loop pays one dispatch per chunk (plus
-    # the slab boundaries around them) while the mixed slab folds
-    # them into its ticks — the quantity this gate isolates
-    legacy_outs, legacy = run_mode(net, prompts, gen_len,
-                                   prefix_cache=True, decode_ticks=8,
-                                   prefill_chunk=16)
-    mixed_outs, mixed = run_mode(net, prompts, gen_len,
-                                 prefix_cache=True, mixed_tick=True,
-                                 decode_ticks=8, prefill_chunk=16)
-    reduction = legacy["host_dispatches"] / max(
-        1, mixed["host_dispatches"])
-    row = {
-        "metric": "llm_mixed_tick_dispatch_reduction",
-        "value": round(reduction, 2),
-        "unit": "legacy_n8_host_dispatches_over_mixed_n8",
-        "device": "cpu",
-        "workload": {"n_requests": len(prompts),
-                     "prompt_len": len(prompts[0]),
-                     "gen_len": gen_len, "decode_ticks": 8},
-        "legacy": legacy,
-        "mixed": mixed,
-    }
-    print(json.dumps(row))
-    if args.out:
-        with open(args.out, "a") as f:
-            f.write(json.dumps(row) + "\n")
-    # no tokens_per_sec on this row: the tiny CI window is dominated
-    # by the mixed programs' one-time compile ladder (sizes 1/2/4/8),
-    # which would gate future runs on compiler wall clock, not the
-    # engine. Dispatch counts are deterministic — they are the metric.
-    _ledger.append("llm_bench", row["metric"], row["value"],
-                   row["unit"],
-                   dispatches=mixed["host_dispatches"],
-                   peak_mem_bytes=_peak_mem_bytes(),
-                   **_verdict_row_fields(),
-                   extra={"legacy_dispatches":
-                              legacy["host_dispatches"],
-                          "mixed_slabs": mixed["mixed_slabs"],
-                          "workload": row["workload"]})
-    if assert_ci:
-        assert [o["output_ids"] for o in mixed_outs] == \
-            [o["output_ids"] for o in legacy_outs], \
-            "mixed-tick generations diverged from the legacy " \
-            "two-op tick path"
-        assert mixed["mixed_slabs"] > 0, \
-            f"the mixed path never engaged: {mixed}"
-        assert mixed["host_dispatches"] < legacy["host_dispatches"], (
-            f"one mixed slab must dispatch less than the alternating "
-            f"loop: {mixed['host_dispatches']} vs "
-            f"{legacy['host_dispatches']}")
-        print("LLM MIXED-TICK SMOKE OK")
-    return 0
-
-
 def build_draft_net(vocab=211, hidden=32, heads=2, max_pos=512,
                     seed=123):
     import paddle_tpu as pt
@@ -1414,7 +1340,7 @@ def run_spec(net, draft, prompts, gen_len, spec_tokens,
     pages = -(-total // page_size) * max(4, len(prompts)) + 16
     eng = LLMEngine(net, max_seqs=4, page_size=page_size,
                     num_pages=pages, max_len=total,
-                    prefill_buckets=(max(len(p) for p in prompts),),
+                    prefill_chunk=max(len(p) for p in prompts),
                     draft_net=draft, spec_tokens=spec_tokens,
                     kv_dtype=kv_dtype, prefix_cache=prefix_cache,
                     decode_ticks_per_dispatch=decode_ticks)
@@ -1477,8 +1403,7 @@ def spec_main(args, net=None, assert_ci=False):
         pages = -(-total // 4) * max(4, len(prompts)) + 16
         with LLMEngine(net, max_seqs=4, page_size=4, num_pages=pages,
                        max_len=total,
-                       prefill_buckets=(max(len(p)
-                                            for p in prompts),),
+                       prefill_chunk=max(len(p) for p in prompts),
                        kv_dtype=kv) as ref:
             refs[kv or "f32"] = [
                 o["output_ids"]
@@ -1560,13 +1485,13 @@ def run_kv_capacity(net, kv_dtype, hbm_budget_bytes, prompts, gen_len,
     total = max(len(p) for p in prompts) + gen_len
     probe = LLMEngine(net, max_seqs=2, page_size=page_size,
                       num_pages=8, max_len=total,
-                      prefill_buckets=(64,), kv_dtype=kv_dtype)
+                      prefill_chunk=64, kv_dtype=kv_dtype)
     page_bytes = probe._page_bytes
     probe.close()
     num_pages = max(8, int(hbm_budget_bytes // page_bytes))
     eng = LLMEngine(net, max_seqs=2, page_size=page_size,
                     num_pages=num_pages, max_len=total,
-                    prefill_buckets=(64,), prefill_chunk=64,
+                    prefill_chunk=64,
                     prefix_cache=True, kv_dtype=kv_dtype)
     outs = []
     with eng:
@@ -1611,7 +1536,7 @@ def kv_dtype_main(args, net=None, assert_ci=False):
     # run eviction-bounded and the ratio reads pure capacity
     from paddle_tpu.inference.llm import LLMEngine
     probe = LLMEngine(net, max_seqs=2, page_size=page_size,
-                      num_pages=8, prefill_buckets=(64,),
+                      num_pages=8, prefill_chunk=64,
                       kv_dtype="bf16")
     budget = 24 * probe._page_bytes
     probe.close()
@@ -1721,10 +1646,6 @@ def main(argv=None):
                     help="bf16 vs int8 KV pools at fixed pool HBM: "
                          "resident prefix-cache pages (>=1.8x gate) "
                          "+ the quantized-tolerance token gate")
-    ap.add_argument("--mixed-tick", action="store_true",
-                    help="legacy alternating prefill/decode ticks vs "
-                         "ONE ragged mixed slab: token identity + "
-                         "host-dispatch reduction")
     ap.add_argument("--spec", action="store_true",
                     help="on-device speculative slab sweep: draft K "
                          "in {2,4,8} x kv_dtype {f32,int8} x prefix "
@@ -1752,8 +1673,6 @@ def main(argv=None):
         return decode_ticks_main(args, assert_ci=args.ci)
     if args.kv_dtype:
         return kv_dtype_main(args, assert_ci=args.ci)
-    if args.mixed_tick:
-        return mixed_tick_main(args, assert_ci=args.ci)
     if args.spec:
         return spec_main(args, assert_ci=args.ci)
 
@@ -1817,12 +1736,7 @@ def main(argv=None):
         # second half of the gate: the device-resident decode loop
         # sweep (N=8 >= 1.2x N=1 decode tokens/sec at batch 1 and 4,
         # streams token-identical across N, greedy and seeded)
-        rc = decode_ticks_main(args, net=net, assert_ci=True)
-        if rc:
-            return rc
-        # third: the ragged MIXED tick must be token-identical to the
-        # legacy two-op tick loop and strictly cheaper in dispatches
-        return mixed_tick_main(args, net=net, assert_ci=True)
+        return decode_ticks_main(args, net=net, assert_ci=True)
     return 0
 
 

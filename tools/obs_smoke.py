@@ -280,7 +280,7 @@ def _engine_perf_section(base: str) -> None:
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, 97, 8).tolist() for _ in range(3)]
     with LLMEngine(net, max_seqs=4, page_size=8, num_pages=32,
-                   max_len=64, prefill_buckets=(8,),
+                   max_len=64, prefill_chunk=8,
                    decode_ticks_per_dispatch=4) as eng:
         outs = [eng.submit(p, max_new_tokens=24,
                            tenant="smoke").result(timeout=240)
