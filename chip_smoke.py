@@ -276,6 +276,7 @@ def phase_kernels(seed: int, heads: int = 16, d: int = 128,
     time_ssd_step(seed)
     time_ssd_chunk(seed)
     time_kda_chunk(seed)
+    time_gdn_chunk(seed)
     time_grouped_matmul(seed)
     emit({"phase": "kernels", "seconds": round(time.time() - t0, 1)})
 
@@ -661,7 +662,9 @@ def time_ssd_chunk(seed: int, rows: int = 256, slots: int = 64,
 
 def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
                    heads: int = 32, d: int = 128, max_seqs: int = 8,
-                   layers: int = 3, calls: int = 20) -> None:
+                   layers: int = 3, calls: int = 20, v_dim: int = None,
+                   v_live: int = None, scalar_decay: bool = False,
+                   write_max: float = 1.0, kernel: str = "kda_chunk") -> None:
     """The delta rule's chunk form (``ops/kda.py``, plain ``jax.numpy`` on
     every platform) as a mixed tick of ``reason_closed_kda`` calls it a KDA
     layer: 256 packed prompt rows of 32 heads of 128, float32, as the model
@@ -675,7 +678,12 @@ def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
     ``layers`` state arrays going round, EACH CALL WITH ROWS OF ITS OWN:
     what does not depend on the state, the inverse among it, is else
     computed once for all the calls of the program. Smoke readings of one
-    layer's call, not a benchmark."""
+    layer's call, not a benchmark. :func:`time_gdn_chunk` runs the same
+    with the other model's shapes: a state ``[heads, d, v_dim]`` whose
+    last ``v_dim - v_live`` columns are the stored padding (zero values, a
+    zero state), ONE decay a head (``scalar_decay``: ``log a`` ``[T, heads,
+    1]``, the chunk form's scalar pair products) and write strengths up to
+    ``write_max``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -685,17 +693,21 @@ def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
     rng = np.random.RandomState(seed + 43)
     ks = jax.random.split(jax.random.PRNGKey(seed + 43), 6)
     t = rows
+    v_dim = v_dim or d
     shape = (calls, t, heads, d)
+    stored = jnp.arange(v_dim) < (v_live or v_dim)
     inputs = (l2norm(jax.random.normal(ks[0], shape)) * d ** -0.5,
               l2norm(jax.random.normal(ks[1], shape)),
-              jax.random.normal(ks[2], shape),
-              -0.3 * jnp.exp(jax.random.normal(ks[3], shape)),
-              jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+              jax.random.normal(ks[2], shape[:3] + (v_dim,)) * stored,
+              -0.3 * jnp.exp(jax.random.normal(
+                  ks[3], shape[:3] + (1,) if scalar_decay else shape)),
+              write_max * jax.nn.sigmoid(jax.random.normal(ks[4],
+                                                           shape[:3])))
     first = tuple(x[0] for x in inputs)
 
     def fresh_state():
-        return tuple(jax.random.normal(k, (slots + 1, heads, d, d),
-                                       jnp.float32)
+        return tuple(jax.random.normal(k, (slots + 1, heads, d, v_dim),
+                                       jnp.float32) * stored
                      for k in jax.random.split(ks[5], layers))
 
     def run(state, inputs, seg, seg_rows, fresh):
@@ -750,12 +762,110 @@ def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
         jax.block_until_ready((total, state))
         ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
         del state
-        emit({"phase": "kernels", "kernel": "kda_chunk", "timed": name,
+        emit({"phase": "kernels", "kernel": kernel, "timed": name,
               "path": "kda_chunk_gathered", "rows": t,
               "sequences": len(lens), "heads": heads, "head_dim": d,
+              "value_dim": v_dim, "scalar_decay": scalar_decay,
               "ms_per_call": round(ms, 4), "max_abs_err_o": round(o_err, 8),
               "max_abs_err_state": round(s_err, 8), "calls": calls})
         free_device_memory()
+
+
+def time_gdn_chunk(seed: int, slots: int = 64, heads: int = 30, dk: int = 96,
+                   v_live: int = 192, v_dim: int = 256, layers: int = 3,
+                   calls: int = 12) -> None:
+    """The delta rule with ONE decay a head as ``reason_closed_gdn`` calls
+    it a ``linear_attention`` layer: the chunk form over 256 packed prompt
+    rows of 30 heads of 96 x 192 (the state stored ``[30, 96, 256]``), write
+    strengths in (0, 2), held to ``kda_recurrence`` fed the broadcast decay
+    and timed as :func:`time_kda_chunk` does; then ONE STEP CALL over the 64
+    slots' rows (``kda_step``: a decode tick's call a layer), its ``o`` and
+    whole state array held to THE EQUATION written out here (``S' = a S + b
+    k (v - k^T a S)^T``, ``o = S'^T q`` at ``highest``; nothing of
+    ``ops/kda.py``: ``kda_recurrence`` is ``kda_step`` scanned and could not
+    hold it) and to the chunk form of one row a sequence, and timed in a
+    program of ``calls`` chained calls that donates the state.
+    GB/s counts the rows' state read once and written once AS STORED. Smoke
+    readings of one layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.kda import kda_chunked, kda_step, l2norm
+
+    time_kda_chunk(seed, slots=slots, heads=heads, d=dk, layers=layers,
+                   calls=calls, v_dim=v_dim, v_live=v_live,
+                   scalar_decay=True, write_max=2.0, kernel="gdn_chunk")
+    ks = jax.random.split(jax.random.PRNGKey(seed + 47), 6)
+    stored = jnp.arange(v_dim) < v_live
+    shape = (calls, slots, heads, dk)
+    inputs = (l2norm(jax.random.normal(ks[0], shape)) * dk ** -0.5,
+              l2norm(jax.random.normal(ks[1], shape)),
+              jax.random.normal(ks[2], shape[:3] + (v_dim,)) * stored,
+              -0.3 * jnp.exp(jax.random.normal(ks[3], shape[:3] + (1,))),
+              2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+
+    def fresh_state():
+        return tuple(jax.random.normal(k, (slots + 1, heads, dk, v_dim),
+                                       jnp.float32) * stored
+                     for k in jax.random.split(ks[5], layers))
+
+    def run(state, inputs):
+        state = list(state)
+        total = jnp.zeros(inputs[2].shape[1:], jnp.float32)
+        for i in range(calls):
+            s = state[i % layers]
+            o, new = kda_step(*(x[i] for x in inputs), s[:slots])
+            state[i % layers] = s.at[:slots].set(new)
+            total = total + o
+        return total, tuple(state)
+
+    start = fresh_state()[0]
+    first = tuple(x[0] for x in inputs)
+    o, new = jax.jit(kda_step)(*first, start[:slots])
+    # the same rows as a packed run of one row a sequence
+    want_o, want_new = jax.jit(kda_chunked)(
+        *first, start[:slots], jnp.arange(slots, dtype=jnp.int32))
+    o_err = float(jnp.abs(o - want_o).max())
+    s_err = float(jnp.abs(new - want_new).max())
+    check(o_err <= 2e-5 and s_err <= 2e-5 * float(jnp.abs(start).max()),
+          f"kda_step (one decay a head) disagrees with the chunk form: "
+          f"o {o_err}, state {s_err}")
+
+    def definition(q, k, v, log_a, b, s):
+        hi = jax.lax.Precision.HIGHEST
+        s = jnp.exp(log_a)[..., None] * s
+        s = s + (b[..., None] * k)[..., None] * (
+            v - jnp.einsum("rhk,rhkv->rhv", k, s, precision=hi)
+        )[..., None, :]
+        return jnp.einsum("rhk,rhkv->rhv", q, s, precision=hi), s
+
+    def_o, def_new = jax.jit(definition)(*first, start[:slots])
+    def_o_err = float(jnp.abs(o - def_o).max())
+    def_s_err = float(jnp.abs(new - def_new).max())
+    check(def_o_err <= 2e-5
+          and def_s_err <= 2e-5 * float(jnp.abs(start).max()),
+          f"kda_step (one decay a head) disagrees with the equation: "
+          f"o {def_o_err}, state {def_s_err}")
+    del start, new, want_new, def_new
+    chained = jax.jit(run, donate_argnums=(0,))
+    total, state = chained(fresh_state(), inputs)
+    jax.block_until_ready(state)
+    t1 = time.perf_counter()
+    for _ in range(3):
+        total, state = chained(state, inputs)
+    jax.block_until_ready((total, state))
+    ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+    del state
+    moved = 2 * slots * heads * dk * v_dim * 4
+    emit({"phase": "kernels", "kernel": "gdn_step", "path": "kda_step",
+          "rows": slots, "heads": heads, "head_dim": dk, "value_dim": v_dim,
+          "ms_per_call": round(ms, 4),
+          "state_gb_per_s_as_stored": round(moved / ms / 1e6, 1),
+          "max_abs_err_o": round(o_err, 8),
+          "max_abs_err_state": round(s_err, 8),
+          "max_abs_err_o_to_equation": round(def_o_err, 8),
+          "max_abs_err_state_to_equation": round(def_s_err, 8),
+          "calls": calls})
+    free_device_memory()
 
 
 def time_grouped_matmul(seed: int, held: int = 36, layers: int = 4) -> None:
@@ -1600,8 +1710,9 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", default=None,
-                    choices=("kernels", "staging", "serve", "serve_hybrid",
-                             "serve_looped", "serve_swa", "train"),
+                    choices=("kernels", "gdn", "staging", "serve",
+                             "serve_hybrid", "serve_looped", "serve_swa",
+                             "train"),
                     help="one chip: run this phase alone (default: all)")
     args = ap.parse_args(argv)
     t0 = time.time()
@@ -1616,6 +1727,10 @@ def main(argv=None) -> int:
                       "serve_looped": phase_serve_looped,
                       "serve_swa": phase_serve_swa,
                       "train": phase_train}
+            if args.phase == "gdn":
+                # (part of the kernels phase; alone, the one-decay delta
+                # rule's chunk and step calls)
+                time_gdn_chunk(args.seed)
             for name, phase in phases.items():
                 if args.phase in (None, name):
                     phase(args.seed)

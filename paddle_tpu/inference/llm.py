@@ -1083,6 +1083,12 @@ def _engine_status_provider(ref):
                 "rows": eng.max_seqs + 1,
                 "rows_in_use": live,
                 "row_bytes": dict(eng._state_row_bytes),
+                # the recurrence where the model names it, and a row's
+                # shapes AS STORED (a model pads to whole lanes itself)
+                "rule": eng._state_spec.get("rule"),
+                "stored_shape": {
+                    k: list(eng._state_spec[k])
+                    for k in ("conv_state", "ssm_state")},
                 "state_impl": eng.state_impl,
                 "unsupported": ["speculative_verify",
                                 "kv_page_migration"]}
@@ -3153,12 +3159,12 @@ class LLMEngine:
         ``(tokens on the device, what the host will fetch)``: for a
         model with routed experts the second holds the tick's routed-row
         counts behind the tokens, one transfer for both."""
-        if not self._n_aux:
-            tokens, self.k_pages, self.v_pages = out
-            return tokens, tokens
-        tokens, fetch, self.k_pages, self.v_pages = out[:4]
+        tokens, *rest = out
+        # (``aux`` and the state lanes come apart: a model may have either)
+        fetch = rest.pop(0) if self._n_aux else tokens
+        self.k_pages, self.v_pages, *state = rest
         if self._state_spec is not None:
-            self.conv_state, self.ssm_state = out[4:]
+            self.conv_state, self.ssm_state = state
         return tokens, fetch
 
     def _new_carry(self, positions, budgets) -> DecodeCarry:

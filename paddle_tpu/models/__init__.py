@@ -11,6 +11,7 @@ from .granite_hybrid import (GraniteHybridConfig,  # noqa
 from .laguna import LagunaConfig, LagunaForCausalLM  # noqa
 from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa
 from .mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM  # noqa
+from .olmo_hybrid import OlmoHybridConfig, OlmoHybridForCausalLM  # noqa
 from .lenet import LeNet  # noqa
 from .ouro import OuroConfig, OuroForCausalLM  # noqa
 from .mobilenet import (MobileNetV1, MobileNetV2,  # noqa

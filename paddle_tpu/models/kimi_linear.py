@@ -207,6 +207,18 @@ def _f32_product(x, weight):
                       preferred_element_type=jnp.float32)
 
 
+# the state-space reference implementation's initialisers: dt = exp(U(log
+# 1e-3, log 1e-1)) through the inverse softplus; A ~ U(1, 16)
+def dt_bias_init(shape, dtype):
+    dt = jnp.exp(I.Uniform(math.log(1e-3), math.log(1e-1))(
+        shape, jnp.float32))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def a_log_init(shape, dtype):
+    return jnp.log(I.Uniform(1.0, 16.0)(shape, jnp.float32)).astype(dtype)
+
+
 class KDAMixer(Layer):
     def __init__(self, cfg: KimiLinearConfig):
         super().__init__()
@@ -219,22 +231,10 @@ class KDAMixer(Layer):
         self.f_a = _linear(cfg, cfg.hidden_size, cfg.decay_rank)
         self.f_b = _linear(cfg, cfg.decay_rank, inner)
 
-        # the state-space reference implementation's initialisers: dt =
-        # exp(U(log 1e-3, log 1e-1)) through the inverse softplus; A ~
-        # U(1, 16)
-        def dt_bias(shape, dtype):
-            dt = jnp.exp(I.Uniform(math.log(1e-3), math.log(1e-1))(
-                shape, jnp.float32))
-            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-        def a_log(shape, dtype):
-            return jnp.log(I.Uniform(1.0, 16.0)(shape, jnp.float32)) \
-                .astype(dtype)
-
         self.dt_bias = self.create_parameter([inner], dtype="float32",
-                                             initializer=dt_bias)
+                                             initializer=dt_bias_init)
         self.A_log = self.create_parameter([nh], dtype="float32",
-                                           initializer=a_log)
+                                           initializer=a_log_init)
         self.b_proj = _linear(cfg, cfg.hidden_size, nh)
         self.g_a = _linear(cfg, cfg.hidden_size, cfg.gate_rank)
         self.g_b = _linear(cfg, cfg.gate_rank, inner)
@@ -414,6 +414,59 @@ class KimiLinearLayer(Layer):
         return x + (routed + self.shared(u)), rows_held
 
 
+def delta_rule_rows(mixer, u, rows, valid, conv_s, ssm_s, fresh,
+                 scope: str = "kda"):
+    """A delta-rule layer (``mixer``: ``gates``, ``qkv_proj``,
+    ``conv_weight``, ``qkv``; this model's and ``models/olmo_hybrid.py``'s)
+    over ragged rows: the first ``rows.n_chunk`` packed prompt rows through
+    the chunk form (each sequence present carries its state row in and out
+    once), the others one token a sequence through the step (row ``i`` of
+    them on state row ``i``), under the scopes ``<scope>_chunk`` /
+    ``<scope>_step``. ``(o [T, heads, V] float32, conv_state,
+    ssm_state)``."""
+    c = rows.n_chunk
+    n_dec = u.shape[0] - c
+    log_a, b = mixer.gates(u, valid)
+    with jax.named_scope("conv"):
+        qkv = mixer.qkv_proj(u)
+    no_bias = jnp.zeros((), jnp.float32)
+    os = []
+    if c:
+        with jax.named_scope("conv"):
+            tail = jnp.where(fresh[:, None, None], 0,
+                             conv_s[rows.seg_rows])
+            conv, tail = ssd.causal_conv_chunk(
+                qkv[:c], mixer.conv_weight, no_bias, tail,
+                rows.chunk_seg)
+            conv_s = conv_s.at[rows.seg_rows].set(tail)
+        with jax.named_scope(scope + "_chunk"):
+            o, ssm_s = kda.kda_chunk_gathered(
+                *mixer.qkv(conv), log_a[:c], b[:c], ssm_s,
+                rows.chunk_seg, rows.seg_rows, fresh)
+            os.append(o)
+    if n_dec:
+        live = valid[c:]
+        first = rows.positions[c:] == 0
+        with jax.named_scope("conv"):
+            old = conv_s[:n_dec]
+            conv, tail = ssd.causal_conv_step(
+                qkv[c:], mixer.conv_weight, no_bias,
+                jnp.where(first[:, None, None], 0, old))
+            conv_s = conv_s.at[:n_dec].set(
+                jnp.where(live[:, None, None], tail, old))
+        with jax.named_scope(scope + "_step"):
+            # a row that is not live has log_a 0 and b 0: its state
+            # row is written back as it was
+            o, new = kda.kda_step(
+                *mixer.qkv(conv), log_a[c:], b[c:],
+                jnp.where((first & live)[:, None, None, None], 0.0,
+                          ssm_s[:n_dec]))
+            ssm_s = ssm_s.at[:n_dec].set(new)
+            os.append(o)
+    return (os[0] if len(os) == 1 else jnp.concatenate(os)), \
+        conv_s, ssm_s
+
+
 class KimiLinearForCausalLM(Layer):
     """The decoder with its untied head."""
 
@@ -513,54 +566,6 @@ class KimiLinearForCausalLM(Layer):
     def loop_aux_spec(self):
         return None
 
-    def _kda_rows(self, mixer, u, rows, valid, conv_s, ssm_s, fresh):
-        """A KDA layer over ragged rows: the first ``rows.n_chunk`` packed
-        prompt rows through the chunk form (each sequence present carries
-        its state row in and out once), the others one token a sequence
-        through the step (row ``i`` of them on state row ``i``).
-        ``(o [T, heads, d] float32, conv_state, ssm_state)``."""
-        c = rows.n_chunk
-        n_dec = u.shape[0] - c
-        log_a, b = mixer.gates(u, valid)
-        with jax.named_scope("conv"):
-            qkv = mixer.qkv_proj(u)
-        no_bias = jnp.zeros((), jnp.float32)
-        os = []
-        if c:
-            with jax.named_scope("conv"):
-                tail = jnp.where(fresh[:, None, None], 0,
-                                 conv_s[rows.seg_rows])
-                conv, tail = ssd.causal_conv_chunk(
-                    qkv[:c], mixer.conv_weight, no_bias, tail,
-                    rows.chunk_seg)
-                conv_s = conv_s.at[rows.seg_rows].set(tail)
-            with jax.named_scope("kda_chunk"):
-                o, ssm_s = kda.kda_chunk_gathered(
-                    *mixer.qkv(conv), log_a[:c], b[:c], ssm_s,
-                    rows.chunk_seg, rows.seg_rows, fresh)
-                os.append(o)
-        if n_dec:
-            live = valid[c:]
-            first = rows.positions[c:] == 0
-            with jax.named_scope("conv"):
-                old = conv_s[:n_dec]
-                conv, tail = ssd.causal_conv_step(
-                    qkv[c:], mixer.conv_weight, no_bias,
-                    jnp.where(first[:, None, None], 0, old))
-                conv_s = conv_s.at[:n_dec].set(
-                    jnp.where(live[:, None, None], tail, old))
-            with jax.named_scope("kda_step"):
-                # a row that is not live has log_a 0 and b 0: its state
-                # row is written back as it was
-                o, new = kda.kda_step(
-                    *mixer.qkv(conv), log_a[c:], b[c:],
-                    jnp.where((first & live)[:, None, None, None], 0.0,
-                              ssm_s[:n_dec]))
-                ssm_s = ssm_s.at[:n_dec].set(new)
-                os.append(o)
-        return (os[0] if len(os) == 1 else jnp.concatenate(os)), \
-            conv_s, ssm_s
-
     def ragged_forward(self, rows, cache):
         """``rows``: ``tokens``, ``positions``, ``limits`` [T] (0 = a
         padded or inactive row, whose latent row lands on scratch page 0)
@@ -611,7 +616,7 @@ class KimiLinearForCausalLM(Layer):
                 i_kv += 1
             else:
                 with jax.named_scope("kda"):
-                    o, conv_state[i_st], ssm_state[i_st] = self._kda_rows(
+                    o, conv_state[i_st], ssm_state[i_st] = delta_rule_rows(
                         mixer, u, rows, valid, conv_state[i_st],
                         ssm_state[i_st], fresh)
                     out = mixer.finish(o, u)

@@ -1,8 +1,13 @@
-"""The gated delta rule with a decay a CHANNEL (Kimi Delta Attention) over
-ragged rows.
+"""The gated delta rule over ragged rows, with a decay a CHANNEL (Kimi Delta
+Attention) or ONE decay a head (the gated delta rule of Yang et al.,
+arXiv:2412.06464), chosen by the decay's shape.
 
 The recurrence, a head at a time (``q_t``, ``k_t`` in R^K, L2-normed; ``v_t``
-in R^V; ``a_t`` in (0, 1)^K given as ``log a_t <= 0``; ``b_t`` in (0, 1))::
+in R^V, and ``V`` need not be ``K``: the state is ``K x V``, 96 x 192 in one
+model, 128 x 128 in another; ``a_t`` in (0, 1)^K given as ``log a_t <= 0``;
+``b_t`` in (0, 2): a write strength over 1 gives ``I - b k k^T`` a negative
+eigenvalue, so a key written twice can flip what the state answers; every
+form here holds on the whole range)::
 
     S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T      (K x V)
     o_t = S_t^T q_t
@@ -11,6 +16,16 @@ in R^V; ``a_t`` in (0, 1)^K given as ``log a_t <= 0``; ``b_t`` in (0, 1))::
 arXiv:2406.06484, with Mamba-2's scalar decay made a vector). ``q`` arrives
 already scaled. The erase term ``b k k^T`` is what ``ops/ssd.py`` does not
 have: a chunk of rows is a triangular solve where Mamba-2's is a product.
+
+``log_a`` of ``[.., H, K]`` is a decay a channel; ``[.., H, 1]`` is ONE
+decay a head, the same in every channel. The step takes the broadcast as it
+is. The chunk form does not: with a scalar decay the factor leaves the
+inner product, ``L[t, i] = b_t e^{g_t - g_i} <k_t, k_i>``, so the pair
+products are two plain ``[T, K] x [K, T]`` products a head times a ``[H, T,
+T]`` array of exponentials of differences (:func:`_scalar_decayed_products`:
+no ``[16, 16, H, K]`` operand a block, no second product through a
+reference row). Everything after the pair products (the solve, the inverse,
+the state products, the pieces, the padded rows) is the same code for both.
 
 Three forms, all plain ``jax.numpy`` (no kernel exists yet: ROADMAP A):
 
@@ -67,7 +82,8 @@ def l2norm(x, eps: float = 1e-6):
 
 
 def kda_step(q, k, v, log_a, b, state):
-    """One token a row. ``q``, ``k``, ``log_a`` [R, H, K]; ``v`` [R, H, V];
+    """One token a row. ``q``, ``k`` [R, H, K]; ``log_a`` [R, H, K] or, one
+    decay a head, [R, H, 1]; ``v`` [R, H, V];
     ``b`` [R, H]; ``state`` [R, H, K, V] float32. A row with ``log_a`` 0
     and ``b`` 0 leaves its state as it was. Returns ``(o [R, H, V] float32,
     new_state)``. One pass over the decayed state gives both ``k^T S`` and
@@ -165,6 +181,18 @@ def _decayed_products(rows, k, g, pair_ok):
     return jnp.where(pair_ok, jnp.where(earlier, far, inside), 0.0)
 
 
+def _scalar_decayed_products(rows, k, g, pair_ok):
+    """:func:`_decayed_products` for ONE decay a head: ``g`` [T, H, 1], so
+    ``out[x, h, t, i] = e^{g_t - g_i} <rows[x, t, h], k[i, h]>`` where
+    ``pair_ok[t, i]``, else 0. The exponent is formed a pair of rows, once
+    for the whole run (``[H, T, T]``, a difference that is <= 0 wherever the
+    pair counts), and multiplies a plain product over the channels."""
+    gh = g[..., 0].T                                               # [H, T]
+    decay = jnp.exp(jnp.minimum(gh[:, :, None] - gh[:, None, :], 0.0))
+    dots = jnp.einsum("xthk,ihk->xhti", rows, k, precision=_HI)
+    return jnp.where(pair_ok, dots * decay, 0.0)
+
+
 def _kda_block(q, k, v, log_a, b, oh, state):
     """One run of ``T`` rows (a power of two, ``_BLOCK`` or more) against the
     ``G`` carried states ``state`` [G, H, K, V]; ``oh`` [T, G] membership."""
@@ -182,7 +210,11 @@ def _kda_block(q, k, v, log_a, b, oh, state):
     g_seq = g - jnp.einsum("tg,ghk->thk", ohf, g_before, precision=_HI)
     to_end = jnp.einsum("tg,ghk->thk", ohf, total, precision=_HI) - g_seq
 
-    prods = _decayed_products(jnp.stack([k, q]), k, g, same & upto)
+    # one decay a head ([T, H, 1]) leaves the inner product; a decay a
+    # channel does not
+    pair_products = _scalar_decayed_products \
+        if g.shape[-1] == 1 and k.shape[-1] > 1 else _decayed_products
+    prods = pair_products(jnp.stack([k, q]), k, g, same & upto)
     a_kk = jnp.where(jnp.eye(t, dtype=bool), 0.0, prods[0])        # i < t
     a_qk = prods[1]                                                # i <= t
     bh = b.T[:, :, None]                                           # [H, T, 1]
@@ -214,8 +246,9 @@ def _piece_rows(n: int) -> int:
 
 
 def kda_chunked(q, k, v, log_a, b, state, tok_seg):
-    """A packed run of ``T`` rows over ``G`` sequences. ``q``, ``k``,
-    ``log_a`` [T, H, K]; ``v`` [T, H, V]; ``b`` [T, H]; ``state`` [G, H, K,
+    """A packed run of ``T`` rows over ``G`` sequences. ``q``, ``k`` [T, H,
+    K]; ``log_a`` [T, H, K] or, one decay a head, [T, H, 1]; ``v`` [T, H,
+    V]; ``b`` [T, H]; ``state`` [G, H, K,
     V] float32, each sequence's carried state (zeros for one that starts
     here); ``tok_seg`` [T] in ``0..G``, a sequence's rows contiguous and in
     order. Returns ``(o [T, H, V] float32, final [G, H, K, V])``; a sequence

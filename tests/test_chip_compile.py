@@ -1004,3 +1004,154 @@ def test_sink_program_compiles_at_mimos_widths_and_keeps_its_pools_in_place(
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < pools // 4, (
         mem.temp_size_in_bytes, pools)
+
+
+# -- a delta rule with one decay a head, thirty K/V heads, the whole vocabulary
+
+GDN_CELL = dict(slots=64, chunk=256, max_len=3584, pages=64 * 224 + 1)
+
+
+@pytest.fixture(scope="module")
+def gdn_programs(one_chip):
+    """``reason_closed_gdn``'s two engine programs at the published widths
+    (hidden 3840; the rule's 30 heads of 96 x 192 with a conv of 4, its
+    state stored ``[30, 96, 256]``; 30 heads of 128 over 30 K/V heads in
+    pages of 32 stored heads; SwiGLU 11008), the cell's 64 slots, 256-row
+    chunk and 224-column table, at a depth of FOUR (one period: three
+    ``linear_attention`` layers and a ``full_attention`` one) and a
+    vocabulary of 1,024 over a pool of 257 pages: what grows with depth,
+    with the vocabulary and with the pool is arguments and one array of
+    logits, reckoned from the shapes below. ``{name: (compiled, state
+    bytes, pool bytes)}``."""
+    import importlib
+    import numpy as np
+    import paddle_tpu as pt
+    from paddle_tpu.inference import llm
+    from paddle_tpu.models import OlmoHybridConfig, OlmoHybridForCausalLM
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(importlib.import_module("paddle_tpu.ops.flash_attention"),
+               "INTERPRET", False)
+    c = GDN_CELL
+    cfg = OlmoHybridConfig(num_layers=4, vocab_size=1024)
+    assert (cfg.hidden_size, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+            cfg.value_width, cfg.num_key_value_heads, cfg.head_dim) == (
+        3840, 30, 96, 192, 256, 30, 128)
+    assert cfg.layer_kinds == ("linear_attention",) * 3 + (
+        "full_attention",)
+    pt.seed(0)
+    net = OlmoHybridForCausalLM(cfg).astype("bfloat16")
+    net.eval()
+    eng = llm.LLMEngine(net, max_seqs=c["slots"], page_size=PAGE,
+                        num_pages=257, max_len=c["max_len"],
+                        prefill_chunk=c["chunk"], kv_dtype="bf16",
+                        attention_impl="pallas")
+    try:
+        assert eng.state_impl == "xla"
+
+        def described(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=one_chip), tree)
+
+        slots, chunk = c["slots"], c["chunk"]
+        ints = np.zeros((slots,), np.int32)
+        tables = eng._pool.device_tables()
+        (full,) = eng._pool.groups
+        assert eng.pages_per_seq == 224
+        # thirty heads in pages of 32 (the model's ``kv_cache_spec()``
+        # names the 32): what HBM stores, counted as stored
+        assert (cfg.num_key_value_heads, cfg.stored_kv_heads) == (30, 32)
+        assert full.k_pages.shape == full.v_pages.shape == (
+            1, 257, PAGE, 32, 128)
+        assert full.page_bytes == 2 * PAGE * 32 * 128 * 2
+        lowered = {"decode": eng._decode_fn.lower(*described((
+            eng._params, eng._buffers, eng._tokens_dev,
+            eng._stage_decode(ints, ints), eng.k_pages, eng.v_pages,
+            eng._key) + eng._state_args()))}
+        seg, seg_rows, _ = eng._chunk_segments((1, chunk))
+        rows = np.zeros((1, chunk), np.int32)
+        per_slot = np.zeros((1, slots), np.int32)
+        xs = {"tok": rows, "pos": rows, "lim": rows,
+              "tbl": eng._pool.row_tables(np.full((1, chunk), -1)),
+              "fin": per_slot.astype(bool), "row": per_slot,
+              "fpos": per_slot, "grant": per_slot, "seg": seg,
+              "segrows": seg_rows}
+        lowered["mixed"] = eng._mixed_fn.lower(*described((
+            eng._params, eng._buffers, eng._new_carry(ints, ints), xs,
+            tables, eng.temperatures, eng._nonces, eng._key)), 1)
+        state = sum(a.nbytes for a in eng.conv_state + eng.ssm_state)
+        pool = full.k_pages.nbytes + full.v_pages.nbytes
+        # a row as STORED: [30, 96, 256] float32 + a [3, 11520] bf16 tail
+        assert state == 65 * 3 * (2_949_120 + 69_120)
+    finally:
+        eng.close()
+        mp.undo()
+    return {name: (low.compile(), state, pool)
+            for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_gdn_program_fits_the_chip_at_eight_layers_and_keeps_state_and_pool_in_place(
+        gdn_programs, program):
+    """One kernel call a ``full_attention`` layer (two in a mixed tick: the
+    chunk's rows through query tiles, the decode rows through the row walk;
+    Mosaic takes a 32-row head axis where it refuses 30), the state rows and
+    the K and V pools the program's own outputs (aliased), and the
+    temporaries (which do not grow with depth: the layers run one after
+    another) small enough that the cell's arguments at ALL 8 layers and the
+    whole vocabulary, 4.87 GB of weights + 1.18 GB of state + 7.52 GB of
+    pool, and a tick's logits over 100,352 ids stay under 0.90 x
+    ``bytes_limit`` beside them."""
+    compiled, state, pool = gdn_programs[program]
+    text = compiled.as_text()
+
+    def calls(kernel):
+        return [ln for ln in text.splitlines() if " custom-call(" in ln
+                and "%" + kernel in ln.split(" = ")[0]]
+
+    assert len(calls("paged_attention.")) == 1
+    assert len(calls("paged_attention_chunk")) == (program == "mixed")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state + pool
+    # a copy of ONE layer's state rows would be 196 MB
+    budget = {"decode": 150 << 20, "mixed": 600 << 20}[program]
+    assert mem.temp_size_in_bytes < budget, mem.temp_size_in_bytes
+    c = GDN_CELL
+    logits = 2 * c["slots"] * 100_352 * 4         # float32, and a copy
+    eight_layers = 2_435_748_072 * 2 + 65 * 6 * (2_949_120 + 69_120) \
+        + c["pages"] * 2 * 2 * PAGE * 32 * 128 * 2
+    assert eight_layers + logits + mem.temp_size_in_bytes \
+        < 0.90 * 16_909_336_064
+
+
+@pytest.mark.parametrize("n_chunk", [0, 256], ids=["row-walk", "tiles"])
+def test_a_head_axis_of_thirty_is_refused_by_the_compiler(one_chip,
+                                                          monkeypatch,
+                                                          n_chunk):
+    """Why ``models/olmo_hybrid.py`` names 32 stored heads for its thirty
+    (``OlmoHybridConfig.stored_kv_heads``): a page's
+    heads lie on the sublanes, the array in HBM is tiled to whole tiles
+    whatever its logical shape (the memref Mosaic is handed is 32 deep), and
+    a page slice of 30 rows is refused, in the row walk and in the query
+    tiles alike; the same call over pages of 32 compiles (the programs
+    above)."""
+    import importlib
+    from paddle_tpu.ops.paged_attention import ragged_paged_attention
+    monkeypatch.setattr(
+        importlib.import_module("paddle_tpu.ops.flash_attention"),
+        "INTERPRET", False)
+    rows = n_chunk + GDN_CELL["slots"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((2, 257, PAGE, 30, HEAD_DIM), jnp.bfloat16)
+    with pytest.raises(Exception, match=r"aligned to tiling \(8\), but is "
+                                        r"30"):
+        jax.jit(lambda q, k, v, t, n, i: ragged_paged_attention(
+            q, k, v, t, n, impl="pallas", layer=i, n_chunk=n_chunk)).lower(
+            sds((rows, 30, HEAD_DIM), jnp.bfloat16), pool, pool,
+            sds((rows, 224), jnp.int32), sds((rows,), jnp.int32),
+            sds((), jnp.int32)).compile()

@@ -289,3 +289,192 @@ def test_chunk_forms_return_the_parents_numbers_bit_for_bit(
         assert bool(jnp.isfinite(o[:live]).all())
         assert bool((o[:live] == want_o[:live]).all())
         assert bool((new == want_new).all())
+
+
+# -- ONE decay a head: log_a [.., H, 1], keys of 96 beside values of 192, ------
+# -- write strengths over the whole of (0, 2) ----------------------------------
+
+SK, SV = 96, 192
+
+
+def scalar_inputs(t, seed=0, strength=1.0, heads=H):
+    """As :func:`inputs` at ``K`` 96 / ``V`` 192, with ``log_a`` [t, H, 1]
+    and ``b`` drawn over (0, 2)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + 100), 5)
+    q = kda.l2norm(jax.random.normal(ks[0], (t, heads, SK))) * SK ** -0.5
+    k = kda.l2norm(jax.random.normal(ks[1], (t, heads, SK)))
+    v = jax.random.normal(ks[2], (t, heads, SV))
+    log_a = -strength * 0.3 * jnp.exp(jax.random.normal(ks[3],
+                                                        (t, heads, 1)))
+    b = jax.random.uniform(ks[4], (t, heads), minval=0.02, maxval=1.98)
+    return q, k, v, log_a, b
+
+
+def widened(log_a, k):
+    return jnp.broadcast_to(log_a, k.shape)
+
+
+def test_step_takes_one_decay_a_head_as_the_broadcast():
+    q, k, v, log_a, b = scalar_inputs(5)
+    s0 = jax.random.normal(jax.random.PRNGKey(7), (5, H, SK, SV))
+    o, new = kda.kda_step(q, k, v, log_a, b, s0)
+    want_o, want_new = kda.kda_step(q, k, v, widened(log_a, k), b, s0)
+    assert bool((o == want_o).all()) and bool((new == want_new).all())
+    assert float(b.max()) > 1.5 and float(b.min()) < 1.0
+    for r in range(5):
+        ref_o, ref_s = kda.kda_recurrence(
+            *(x[r:r + 1] for x in (q, k, v, log_a, b)), s0[r])
+        np.testing.assert_allclose(o[r], ref_o[0], atol=TOL)
+        np.testing.assert_allclose(new[r], ref_s, atol=TOL)
+
+
+@pytest.mark.parametrize("lengths,t", [
+    ((16,), 16),                      # one block
+    ((5, 1, 30), 37),                 # no multiple of a block, no power of 2
+    ((37, 50, 13), 112),              # three sequences, 12 padded rows
+    ((100, 156), 256),                # the engine's chunk, one piece
+    ((300, 140, 60), 512)],           # two pieces, a sequence across them
+    ids=["16", "37", "112", "256", "512"])
+@pytest.mark.parametrize("strength", [1.0, 40.0], ids=["mild", "strong"])
+def test_scalar_chunk_form_is_the_recurrence(lengths, t, strength):
+    """The scalar-decay pair products through the shared solve, inverse and
+    state products: several sequences a run, each from its own carried
+    state, one absent, padded rows, ``b`` up to 2 (``(I + L)^-1`` grows
+    with it), a state that is not square."""
+    g = 4
+    q, k, v, log_a, b = scalar_inputs(t, seed=len(lengths),
+                                      strength=strength)
+    s0 = jax.random.normal(jax.random.PRNGKey(9), (g, H, SK, SV))
+    o, new = jax.jit(kda.kda_chunked)(q, k, v, log_a, b, s0,
+                                      packed(lengths, t, g))
+    assert o.shape == (t, H, SV) and new.shape == s0.shape
+    assert bool(jnp.isfinite(o[:sum(lengths)]).all())
+    for i, rows in by_sequence(lengths):
+        want_o, want_s = kda.kda_recurrence(
+            *(x[rows] for x in (q, k, v, log_a, b)), s0[i])
+        # 1e-5 of the largest entry: a state of carried normals reaches 4
+        # and a run of 300 tokens at strengths up to 2 sums 300 writes
+        np.testing.assert_allclose(
+            o[rows], want_o, atol=TOL * max(1.0, float(jnp.abs(want_o).max())))
+        np.testing.assert_allclose(
+            new[i], want_s, atol=TOL * max(1.0, float(jnp.abs(want_s).max())))
+    for i in range(len(lengths), g):          # no row in the run: kept
+        assert np.array_equal(new[i], s0[i])
+
+
+@pytest.mark.parametrize("lengths,t", [((21, 43), 64), ((300, 100), 400)],
+                         ids=["64", "400"])
+def test_scalar_chunk_form_is_the_per_channel_form_fed_the_broadcast(
+        lengths, t):
+    """The oracle of the new form: today's per-channel code given the same
+    decay in every channel computes the same numbers at another cost."""
+    g = 2
+    q, k, v, log_a, b = scalar_inputs(t, seed=2)
+    s0 = jax.random.normal(jax.random.PRNGKey(3), (g, H, SK, SV))
+    seg = packed(lengths, t, g)
+    o, new = kda.kda_chunked(q, k, v, log_a, b, s0, seg)
+    want_o, want_new = kda.kda_chunked(q, k, v, widened(log_a, k), b, s0,
+                                       seg)
+    np.testing.assert_allclose(o, want_o, atol=TOL)
+    np.testing.assert_allclose(new, want_new, atol=TOL)
+
+
+def test_scalar_form_builds_no_operand_a_pair_a_channel():
+    """The cost, not the numbers: a decay a head forms its exponentials a
+    PAIR (``[H, T, T]``); a decay a channel a pair AND a channel (``[nb,
+    16, 16, H, K]``), and between blocks a second product through a
+    reference row. The choice is by the decay's shape alone."""
+    t = 64
+    q, k, v, log_a, b = scalar_inputs(t)
+    s0 = jnp.zeros((1, H, SK, SV))
+    seg = jnp.zeros((t,), jnp.int32)
+    one = str(jax.make_jaxpr(kda.kda_chunked)(q, k, v, log_a, b, s0, seg))
+    each = str(jax.make_jaxpr(kda.kda_chunked)(
+        q, k, v, widened(log_a, k), b, s0, seg))
+    pair_and_channel = f"f32[{t // 16},16,16,{H},{SK}]"
+    assert pair_and_channel in each and pair_and_channel not in one
+    assert f"f32[{H},{t},{t}]" in one
+    # exponentials: T T H where 16 T H K stand (and the state's)
+    assert one.count(" exp ") < each.count(" exp ")
+
+
+def test_gathered_form_with_one_decay_a_head():
+    lengths, t, g, slots = (20, 12), 32, 4, 6
+    q, k, v, log_a, b = scalar_inputs(t, seed=3)
+    state = jax.random.normal(jax.random.PRNGKey(2), (slots + 1, H, SK, SV))
+    seg_rows = jnp.asarray([4, 1, slots, slots])
+    fresh = jnp.asarray([False, True, False, False])
+    o, new = kda.kda_chunk_gathered(q, k, v, log_a, b, state,
+                                    packed(lengths, t, g), seg_rows, fresh)
+    for (i, rows), row, start in zip(by_sequence(lengths), (4, 1),
+                                     (state[4], jnp.zeros((H, SK, SV)))):
+        want_o, want_s = kda.kda_recurrence(
+            *(x[rows] for x in (q, k, v, log_a, b)), start)
+        np.testing.assert_allclose(o[rows], want_o, atol=TOL)
+        np.testing.assert_allclose(new[row], want_s, atol=TOL)
+    for row in (0, 2, 3, 5):                  # the neighbours
+        assert np.array_equal(new[row], state[row])
+
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_a_unit_key_written_twice_at_strength_two_is_a_reflection(form):
+    """By hand: no decay (``a = 1``) and ``b = 2`` make ``I - b k k^T`` the
+    reflection across ``k``'s hyperplane, so the same unit key written
+    twice with the same value returns ``k^T S`` to where it was; at ``b =
+    1`` (a projection: what every test drew before) the first write
+    already sets ``k^T S = v`` and the second changes nothing. ``b`` in (1,
+    2) overshoots and comes back part of the way."""
+    kk = np.zeros((SK,), np.float32)
+    kk[3] = 1.0
+    s0 = np.asarray(jax.random.normal(jax.random.PRNGKey(5), (SK, SV)))
+    val = np.asarray(jax.random.normal(jax.random.PRNGKey(6), (SV,)))
+    before = kk @ s0
+
+    def twice(strength):
+        q = k = jnp.broadcast_to(jnp.asarray(kk), (2, 1, SK))
+        v = jnp.broadcast_to(jnp.asarray(val), (2, 1, SV))
+        log_a = jnp.zeros((2, 1, 1))
+        b = jnp.full((2, 1), strength)
+        state = jnp.asarray(s0)[None]
+        if form == "chunk":
+            o, new = kda.kda_chunked(q, k, v, log_a, b, state[None],
+                                     jnp.zeros((2,), jnp.int32))
+            return np.asarray(o[:, 0]), np.asarray(new[0, 0])
+        o, new = kda.kda_recurrence(q, k, v, log_a, b, state)
+        return np.asarray(o[:, 0]), np.asarray(new[0])
+
+    o, new = twice(2.0)
+    # after one write k^T S = 2 v - k^T S_0; after two, k^T S_0 again
+    np.testing.assert_allclose(o[0], 2 * val - before, atol=TOL)
+    np.testing.assert_allclose(o[1], before, atol=TOL)
+    np.testing.assert_allclose(kk @ new, before, atol=TOL)
+    np.testing.assert_allclose(new, s0, atol=TOL)     # the whole state
+    o, new = twice(1.0)
+    np.testing.assert_allclose(o[0], val, atol=TOL)
+    np.testing.assert_allclose(o[1], val, atol=TOL)
+    assert float(np.abs(kk @ new - before).max()) > 0.1
+
+
+@pytest.mark.parametrize("form,digest", [("chunk", "92f43e905aa100ad"),
+                                         ("step", "27d4f06f041026ef")])
+def test_a_decay_a_channel_lowers_to_the_text_of_the_parent_of_pr_47(form,
+                                                                     digest):
+    """The per-channel path is chosen by the decay's shape and nothing of
+    it moved when the scalar forms came: ``kda_chunk_gathered`` (256 rows,
+    4 heads of 32, 8 sequences over 12 state rows) and ``kda_step`` lower to
+    the text the commit before PR 47 lowered (digests read there,
+    4160782)."""
+    import hashlib
+    f, sds = jnp.float32, jax.ShapeDtypeStruct
+    t, h, k, g, s = 256, 4, 32, 8, 12
+    if form == "chunk":
+        text = jax.jit(kda.kda_chunk_gathered).lower(
+            sds((t, h, k), f), sds((t, h, k), f), sds((t, h, k), f),
+            sds((t, h, k), f), sds((t, h), f), sds((s, h, k, k), f),
+            sds((t,), jnp.int32), sds((g,), jnp.int32),
+            sds((g,), bool)).as_text()
+    else:
+        text = jax.jit(kda.kda_step).lower(
+            sds((s, h, k), f), sds((s, h, k), f), sds((s, h, k), f),
+            sds((s, h, k), f), sds((s, h), f), sds((s, h, k, k), f)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

@@ -58,6 +58,42 @@ def test_engine_greedy_matches_dense_generate(build, prefill_chunk):
         assert got["ttft_s"] is not None and got["latency_s"] > 0
 
 
+def gpt2_heads():
+    """``gpt2-small``'s published twelve heads (48 = 12 x 4)."""
+    pt.seed(0)
+    return GPTForCausalLM(gpt_config(
+        "gpt2-small", num_layers=2, hidden_size=48, vocab_size=97,
+        max_position_embeddings=96, hidden_dropout=0.0,
+        attention_dropout=0.0))
+
+
+def llama_six_kv_heads():
+    pt.seed(0)
+    return GPTForCausalLM(llama_config(
+        hidden_size=48, num_layers=2, num_heads=12, num_kv_heads=6,
+        vocab_size=97, max_position_embeddings=96, ffn_hidden_size=96))
+
+
+@pytest.mark.parametrize("build,kv_heads", [(gpt2_heads, 12),
+                                            (llama_six_kv_heads, 6)],
+                         ids=["gpt2-12-heads", "gqa-6-kv-heads"])
+def test_engine_serves_head_counts_that_are_no_sublane_tile(build, kv_heads):
+    """A model reads its K/V head count off the pool's shape
+    (``ragged_paged_attention``): the pool stores the heads the model
+    names, 12 as 12 and 6 as 6, and the gathered path serves them."""
+    net = build()
+    assert net.cfg.num_heads == 12
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, 97, n).tolist() for n in (9, 4)]
+    want = [np.asarray(net.generate(jnp.asarray([p]), max_new_tokens=6)
+                       )[0, len(p):].tolist() for p in prompts]
+    with LLMEngine(net, max_seqs=2, page_size=4, num_pages=64,
+                   prefill_chunk=8, attention_impl="xla") as eng:
+        assert eng.k_pages[0].shape[-2:] == (kv_heads, 4)
+        outs = eng.generate(prompts, max_new_tokens=6)
+    assert [o["output_ids"] for o in outs] == want
+
+
 def test_engine_continuous_admission_and_page_reuse():
     """Requests joining mid-flight don't perturb running sequences,
     and every page returns to the pool."""

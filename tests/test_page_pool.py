@@ -299,3 +299,47 @@ def test_pages_touched_by_a_chunk_twice_the_window():
     # from position 13 (page 3) to position 23 (page 5)
     assert got["full"] == {"read": 6, "live": 6}
     assert got["window"] == {"read": 3, "live": 3}
+
+
+# -- the head axis is the spec's: a model that wants whole tiles names them ----
+
+@pytest.mark.parametrize("kv_heads", [1, 2, 4, 8, 16, 6, 12, 25, 30, 32])
+def test_a_group_stores_the_heads_its_spec_names(kv_heads):
+    """The pool rounds nothing: 12 heads (GPT-2) are stored as 12, and the
+    32 that ``models/olmo_hybrid.py`` names for its thirty as 32;
+    ``status()``, ``page_bytes`` and the arrays agree."""
+    pool = pool_of([CacheGroup("full", 2, kv_heads, 8)])
+    (g,) = pool.groups
+    assert g.k_pages.shape == g.v_pages.shape == (2, 40, PS, kv_heads, 8)
+    assert g.page_bytes == 2 * 2 * PS * kv_heads * 8 * 4
+    status = g.status()
+    assert status["kv_heads"] == kv_heads
+    assert status["row_bytes"] == 2 * 2 * kv_heads * 8 * 4
+    assert status["k_row_bytes"] == status["v_row_bytes"] \
+        == 2 * kv_heads * 8 * 4
+
+
+@pytest.mark.parametrize("kv_heads", [6, 30])
+def test_pages_touched_do_not_depend_on_the_heads_a_page_stores(kv_heads):
+    """The counter reckons pages: the group a model of 6 or 30 K/V heads
+    names (8, 32 stored) reads and keeps live what a group of 2 does, row
+    walk, tiles and gathered path."""
+    from paddle_tpu.inference.page_pool import ChunkRows
+    from paddle_tpu.models.olmo_hybrid import OlmoHybridConfig
+    stored = OlmoHybridConfig(
+        hidden_size=kv_heads * 8, num_attention_heads=kv_heads,
+        num_key_value_heads=kv_heads, num_layers=4).stored_kv_heads
+    assert stored == (32 if kv_heads == 30 else 8)
+    odd, two = (pool_of([CacheGroup("full", 2, n, 8)])
+                for n in (stored, 2))
+    for p in (odd, two):
+        serve(p, 0, 21, 3)
+    chunk = ChunkRows(np.asarray([[1] * 8]),
+                      np.asarray([[p + 1 for p in range(16, 24)]]))
+    for call in (([(0, 24)], SLOTS, "pallas"),
+                 ([], 8, "pallas", chunk),
+                 ([(0, 24)], SLOTS, "xla")):
+        assert odd.pages_touched([call])["full"] \
+            == two.pages_touched([call])["full"]
+    assert odd.groups[0].page_bytes == two.groups[0].page_bytes \
+        * stored // 2

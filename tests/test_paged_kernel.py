@@ -1075,3 +1075,90 @@ def test_keys_of_two_lane_tiles_beside_values_of_one(tile_rows, let_go,
         n_chunk=n_chunk, sinks=sinks), np.float32)
     assert got.shape == (lens.shape[0], heads, dv)
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+# -- a head count that is no multiple of the sublane tile: 6 and 30 K/V heads --
+# -- in pages of 8 and 32, the zero heads the MODEL's (models/olmo_hybrid.py) --
+
+def _stored(kv_heads, group, seed=21):
+    """``(mixer, q, k_rows, v_rows, k_pool, v_pool, tables, lens,
+    n_chunk)``: a mixed tick's rows (:func:`packing`) of ``kv_heads`` K/V
+    heads, padded by the model's ``FullAttention.stored`` and WRITTEN
+    through ``kv_write`` into a stacked pool of the heads the model's
+    ``kv_cache_spec()`` names, each row at its own position. ``q`` comes
+    back padded, the K and V rows as the model has them."""
+    from paddle_tpu.models.olmo_hybrid import (FullAttention,
+                                               OlmoHybridConfig)
+    heads = group * kv_heads
+    mixer = FullAttention(OlmoHybridConfig(
+        hidden_size=heads * D, num_attention_heads=heads,
+        num_key_value_heads=kv_heads, num_layers=4))
+    stored = mixer.cfg.stored_kv_heads
+    tables, lens, n_chunk = packing()
+    q = queries(len(lens), heads, seed=seed)
+    # every position of every row's table holds rows of its own
+    kk, vv = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                               (2, PAGES * PS, kv_heads, D))
+    q, k_rows, v_rows = mixer.stored(q, kk, vv)
+    assert q.shape[1] == group * stored
+    assert k_rows.shape == v_rows.shape == (PAGES * PS, stored, D)
+    zeros = jnp.zeros((LAYERS, PAGES, PS, stored, D))
+    page, off = (t.reshape(-1) for t in jnp.meshgrid(
+        jnp.arange(PAGES), jnp.arange(PS), indexing="ij"))
+    k_pool = pa.kv_write(zeros, 1, page, off, k_rows)
+    v_pool = pa.kv_write(zeros, 1, page, off, v_rows)
+    shape = (PAGES, PS, kv_heads, D)
+    return (mixer, q, kk.reshape(shape), vv.reshape(shape), k_pool, v_pool,
+            tables, lens, n_chunk)
+
+
+@pytest.mark.parametrize("kv_heads,group", [(6, 1), (30, 1), (6, 2)],
+                         ids=["6-mha", "30-mha", "6-gqa-2"])
+@pytest.mark.parametrize("impl", ["pallas", "xla", "reference"])
+def test_heads_stored_in_whole_tiles_attend_as_the_heads_written(
+        block, tile_rows, impl, kv_heads, group):
+    """6 and 30 K/V heads through the row walk, the query tiles and both
+    gathered paths, over pages of 8 and 32: the model pads q, k and v with
+    zero heads (a whole group of query heads a stored head) and cuts the
+    op's output; the pool, ``kv_write`` and the op know the stored heads
+    alone. Against the reference over a pool of the heads as written."""
+    mixer, q, kk, vv, k_pool, v_pool, tables, lens, n_chunk = _stored(
+        kv_heads, group)
+    stored = k_pool.shape[-2]
+    heads = group * kv_heads
+    assert stored % 8 == 0 and stored > kv_heads
+    assert not np.asarray(k_pool[1, :, :, kv_heads:]).any()
+    assert not np.asarray(k_pool[0]).any()
+    att = np.asarray(ragged_paged_attention(
+        q, k_pool, v_pool, tables, lens, impl=impl, layer=1,
+        n_chunk=n_chunk))
+    assert att.shape == (len(lens), group * stored, D)
+    assert not att[:, heads:].any(), "a zero head attends zero heads"
+    got = att[:, :heads]
+    want = np.asarray(ragged_paged_attention_reference(
+        q[:, :heads], kk, vv, tables, lens))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert not got[np.asarray(lens) == 0].any(), "limit 0 is a zero row"
+    # what ``project`` hands the output projection is the cut
+    cut = mixer.project(jnp.asarray(att))
+    np.testing.assert_array_equal(
+        np.asarray(cut), np.asarray(mixer.o_proj(
+            jnp.asarray(got).reshape(-1, heads * D))))
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(12, 12), (20, 20), (25, 25),
+                                            (12, 6)],
+                         ids=["12-mha", "20-mha", "25-mha", "12-over-6"])
+def test_the_gathered_path_serves_a_pool_of_any_head_count(heads, kv_heads):
+    """GPT-2's published head counts (12, 20, 25) and a 6-head GQA group:
+    the pool stores the heads it is told, ``kv_write`` refuses nothing and
+    pads nothing, and the gathered path attends them as the reference."""
+    k, v = pool(5, kv_heads)
+    tables, lens, n_chunk = packing()
+    q = queries(len(lens), heads)
+    rows = jax.random.normal(jax.random.PRNGKey(8), (len(lens), kv_heads, D))
+    assert pa.kv_write(k, 1, tables[:, 0], lens % PS, rows).shape == k.shape
+    got = np.asarray(ragged_paged_attention(
+        q, k, v, tables, lens, impl="xla", layer=1, n_chunk=n_chunk))
+    np.testing.assert_allclose(got, reference(q, k, v, tables, lens, 1),
+                               atol=2e-5, rtol=2e-5)
