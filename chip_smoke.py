@@ -20,8 +20,12 @@ paths through the entry points users call, at the full width of
            kernel beside ``ssd_step`` and the routed experts' grouped
            product beside ``jax.lax.ragged_dot`` at the hybrid cell's shapes,
            the delta rule's chunk form (``ops/kda.py``, plain jax.numpy)
-           held to its recurrence and timed at ``reason_closed_kda``'s
-           shapes
+           held to its recurrence and timed at ``reason_closed_kda``'s and
+           ``reason_closed_gdn``'s shapes, and its step's kernel beside
+           ``kda_step``, whole state arrays held to the equation
+  gdn_program  (alone: ``--phase gdn_program``) ``reason_closed_gdn``'s own
+           decode program under ``kda_step`` and under the step's kernel,
+           the same ticks, every delta-rule layer's state compared
   staging  a decode dispatch's host arrays sent one ``jnp.asarray`` each
            beside the one packed vector of ``inference/staging.py``, timed
            at ``chat_closed``'s and ``mixed_len_closed_sink``'s shapes
@@ -683,7 +687,8 @@ def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
     last ``v_dim - v_live`` columns are the stored padding (zero values, a
     zero state), ONE decay a head (``scalar_decay``: ``log a`` ``[T, heads,
     1]``, the chunk form's scalar pair products) and write strengths up to
-    ``write_max``."""
+    ``write_max``. Then the step call of a decode tick at the same shapes
+    (:func:`time_delta_step`: ``kda_step`` beside the kernel)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -769,66 +774,121 @@ def time_kda_chunk(seed: int, rows: int = 256, slots: int = 48,
               "ms_per_call": round(ms, 4), "max_abs_err_o": round(o_err, 8),
               "max_abs_err_state": round(s_err, 8), "calls": calls})
         free_device_memory()
+    del inputs, first
+    time_delta_step(seed, kernel.replace("chunk", "step"), slots, heads, d,
+                    v_dim, v_live or v_dim, scalar_decay, write_max,
+                    layers=layers)
 
 
 def time_gdn_chunk(seed: int, slots: int = 64, heads: int = 30, dk: int = 96,
                    v_live: int = 192, v_dim: int = 256, layers: int = 3,
                    calls: int = 12) -> None:
-    """The delta rule with ONE decay a head as ``reason_closed_gdn`` calls
-    it a ``linear_attention`` layer: the chunk form over 256 packed prompt
-    rows of 30 heads of 96 x 192 (the state stored ``[30, 96, 256]``), write
-    strengths in (0, 2), held to ``kda_recurrence`` fed the broadcast decay
-    and timed as :func:`time_kda_chunk` does; then ONE STEP CALL over the 64
-    slots' rows (``kda_step``: a decode tick's call a layer), its ``o`` and
-    whole state array held to THE EQUATION written out here (``S' = a S + b
-    k (v - k^T a S)^T``, ``o = S'^T q`` at ``highest``; nothing of
-    ``ops/kda.py``: ``kda_recurrence`` is ``kda_step`` scanned and could not
-    hold it) and to the chunk form of one row a sequence, and timed in a
-    program of ``calls`` chained calls that donates the state.
-    GB/s counts the rows' state read once and written once AS STORED. Smoke
-    readings of one layer's call, not a benchmark."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops.kda import kda_chunked, kda_step, l2norm
-
+    """:func:`time_kda_chunk` with the delta rule with ONE decay a head as
+    ``reason_closed_gdn`` calls it a ``linear_attention`` layer: the chunk
+    form over 256 packed prompt rows of 30 heads of 96 x 192 (the state
+    stored ``[30, 96, 256]``), write strengths in (0, 2), held to
+    ``kda_recurrence`` fed the broadcast decay; then the step call over the
+    64 slots' rows (:func:`time_delta_step`)."""
     time_kda_chunk(seed, slots=slots, heads=heads, d=dk, layers=layers,
                    calls=calls, v_dim=v_dim, v_live=v_live,
                    scalar_decay=True, write_max=2.0, kernel="gdn_chunk")
+
+
+def copy_state_tiles(state, live, tile_bytes: int = 1 << 20):
+    """The tile walk of ``ops/kda.py kda_step_kernel`` with nothing but the
+    copies: every live row's ``[head block, K, V]`` tiles of ``state``
+    ``[slots + 1, H, K, V]`` brought into VMEM and sent back as they are, in
+    place, a row that is not live naming the tile the pipeline holds
+    (``tile_bytes``: the step kernels' budget a tile). What the kernel's
+    arithmetic hides behind, timed beside it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.ssd import held_tiles
+
+    slots, heads, dk, dv = state.shape
+    hb = max(1, min(heads, tile_bytes // (dk * dv * 4)))
+    nb = -(-heads // hb)
+    row, blk = held_tiles(live, slots, nb)
+
+    def index(r, b, row_ref, blk_ref):
+        return row_ref[r], jnp.where(blk_ref[r] < 0, b, blk_ref[r]), 0, 0
+
+    def body(row_ref, blk_ref, s_ref, o_ref):
+        o_ref[...] = s_ref[...]
+
+    tile = pl.BlockSpec((1, hb, dk, dv), index)
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(live.shape[0], nb),
+            in_specs=[tile], out_specs=tile),
+        out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="copy_state_tiles",
+    )(row, blk, state)
+
+
+def time_delta_step(seed: int, kernel: str, slots: int, heads: int, dk: int,
+                    v_dim: int, v_live: int, scalar_decay: bool,
+                    write_max: float, layers: int = 3,
+                    calls: int = 12) -> None:
+    """ONE STEP CALL of the delta rule over a layer's ``slots`` rows, as a
+    decode tick calls it a delta-rule layer: ``kda_step`` over the slots'
+    rows sliced out and written back (every engine's path today) beside
+    ``kda_step_kernel`` over the whole array in place (``state_impl``
+    ``"pallas"``, which no model's spec names yet), same inputs. First one
+    call of each with rows that are not live and rows that start a
+    sequence among them: ``o`` and
+    the WHOLE state array held to THE EQUATION written out here (``S' = a S
+    + b k (v - k^T a S)^T``, ``o = S'^T q`` at ``highest``; nothing of
+    ``ops/kda.py``: ``kda_recurrence`` is ``kda_step`` scanned and could not
+    hold it), the rows that are not live and the scratch row bit for bit,
+    and ``kda_step`` to the chunk form of one row a sequence. Then ms a
+    call of ``calls`` chained calls in one program that donates the state,
+    every row live, each call with inputs of its own, and beside them the
+    tiles' copies alone (:func:`copy_state_tiles`: the same walk of the
+    same tiles with no arithmetic, which is what bounds the kernel). GB/s
+    counts the rows' state read once and written once AS STORED. Smoke
+    readings of one layer's call, not a benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.kda import (kda_chunked, kda_step, kda_step_kernel,
+                                    l2norm)
+
     ks = jax.random.split(jax.random.PRNGKey(seed + 47), 6)
     stored = jnp.arange(v_dim) < v_live
     shape = (calls, slots, heads, dk)
     inputs = (l2norm(jax.random.normal(ks[0], shape)) * dk ** -0.5,
               l2norm(jax.random.normal(ks[1], shape)),
               jax.random.normal(ks[2], shape[:3] + (v_dim,)) * stored,
-              -0.3 * jnp.exp(jax.random.normal(ks[3], shape[:3] + (1,))),
-              2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], shape[:3])))
+              -0.3 * jnp.exp(jax.random.normal(
+                  ks[3], shape[:3] + (1,) if scalar_decay else shape)),
+              write_max * jax.nn.sigmoid(jax.random.normal(ks[4],
+                                                           shape[:3])))
+    all_live = jnp.ones((slots,), bool)
+    none_first = jnp.zeros((slots,), bool)
 
     def fresh_state():
         return tuple(jax.random.normal(k, (slots + 1, heads, dk, v_dim),
                                        jnp.float32) * stored
                      for k in jax.random.split(ks[5], layers))
 
-    def run(state, inputs):
-        state = list(state)
-        total = jnp.zeros(inputs[2].shape[1:], jnp.float32)
-        for i in range(calls):
-            s = state[i % layers]
-            o, new = kda_step(*(x[i] for x in inputs), s[:slots])
-            state[i % layers] = s.at[:slots].set(new)
-            total = total + o
-        return total, tuple(state)
+    def plain(s, live, first, *x):
+        # as models/kimi_linear.py delta_rule_rows calls it off the TPU
+        o, new = kda_step(*x, jnp.where(
+            (first & live)[:, None, None, None], 0.0, s[:slots]))
+        return o, s.at[:slots].set(new)
 
-    start = fresh_state()[0]
-    first = tuple(x[0] for x in inputs)
-    o, new = jax.jit(kda_step)(*first, start[:slots])
-    # the same rows as a packed run of one row a sequence
-    want_o, want_new = jax.jit(kda_chunked)(
-        *first, start[:slots], jnp.arange(slots, dtype=jnp.int32))
-    o_err = float(jnp.abs(o - want_o).max())
-    s_err = float(jnp.abs(new - want_new).max())
-    check(o_err <= 2e-5 and s_err <= 2e-5 * float(jnp.abs(start).max()),
-          f"kda_step (one decay a head) disagrees with the chunk form: "
-          f"o {o_err}, state {s_err}")
+    def through_kernel(s, live, first, *x):
+        return kda_step_kernel(*x, s, live, first, interpret=False)
+
+    def copies_alone(s, live, first, *x):
+        return jnp.zeros_like(x[2]), copy_state_tiles(s, live)
 
     def definition(q, k, v, log_a, b, s):
         hi = jax.lax.Precision.HIGHEST
@@ -838,33 +898,194 @@ def time_gdn_chunk(seed: int, slots: int = 64, heads: int = 30, dk: int = 96,
         )[..., None, :]
         return jnp.einsum("rhk,rhkv->rhv", q, s, precision=hi), s
 
-    def_o, def_new = jax.jit(definition)(*first, start[:slots])
-    def_o_err = float(jnp.abs(o - def_o).max())
-    def_s_err = float(jnp.abs(new - def_new).max())
-    check(def_o_err <= 2e-5
-          and def_s_err <= 2e-5 * float(jnp.abs(start).max()),
-          f"kda_step (one decay a head) disagrees with the equation: "
-          f"o {def_o_err}, state {def_s_err}")
-    del start, new, want_new, def_new
-    chained = jax.jit(run, donate_argnums=(0,))
-    total, state = chained(fresh_state(), inputs)
-    jax.block_until_ready(state)
-    t1 = time.perf_counter()
-    for _ in range(3):
-        total, state = chained(state, inputs)
-    jax.block_until_ready((total, state))
-    ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
-    del state
+    # -- one call, held to the equation ------------------------------------
+    rng = np.random.RandomState(seed + 47)
+    live = np.ones((slots,), bool)
+    live[rng.permutation(slots)[:slots // 5]] = False
+    first = np.zeros((slots,), bool)
+    first[rng.permutation(slots)[:4]] = True
+    lv, fs = jnp.asarray(live), jnp.asarray(first)
+    start = fresh_state()[0]
+    x0 = tuple(x[0] for x in inputs)
+    # a dead row as the model's gates make it: no decay, no write
+    x_gated = x0[:3] + (jnp.where(lv[:, None, None], x0[3], 0.0),
+                        jnp.where(lv[:, None], x0[4], 0.0))
+    entering = jnp.where((fs & lv)[:, None, None, None], 0.0, start[:slots])
+    def_o, def_new = jax.jit(definition)(*x0, entering)
+    want = start.at[:slots].set(
+        jnp.where(lv[:, None, None, None], def_new, start[:slots]))
+    s_max = float(jnp.abs(start).max())
+    errs = {}
+    for path, step in (("kda_step", jax.jit(plain)),
+                       ("kda_step_kernel", lambda s, lv, fs, *x:
+                        kda_step_kernel(*x, s, lv, fs, interpret=False))):
+        o, new = step(start, lv, fs, *x_gated)
+        o_err = float(jnp.abs(o - def_o)[live].max())
+        s_err = float(jnp.abs(new - want).max())
+        check(o_err <= 1e-5 and s_err <= 1e-5 * s_max,
+              f"{path} ({kernel}) disagrees with the equation: o {o_err}, "
+              f"state {s_err} of {s_max}")
+        if path == "kda_step_kernel":
+            held = np.append(~live, True)           # and the scratch row
+            check(bool(jnp.array_equal(new[held], start[held])),
+                  f"{path} ({kernel}) moved a row that is not live")
+        errs[path] = (o_err, s_err)
+    # the same rows as a packed run of one row a sequence
+    o, _ = jax.jit(kda_step)(*x0, start[:slots])
+    chunk_o, _ = jax.jit(kda_chunked)(
+        *x0, start[:slots], jnp.arange(slots, dtype=jnp.int32))
+    chunk_err = float(jnp.abs(o - chunk_o).max())
+    check(chunk_err <= 2e-5,
+          f"kda_step ({kernel}) disagrees with the chunk form: o {chunk_err}")
+    del start, new, want, def_new, entering
+
+    # -- ms a call, every row live -----------------------------------------
+    def chained(step):
+        def run(state, inputs):
+            state = list(state)
+            total = jnp.zeros(inputs[2].shape[1:], jnp.float32)
+            for i in range(calls):
+                o, state[i % layers] = step(
+                    state[i % layers], all_live, none_first,
+                    *(x[i] for x in inputs))
+                total = total + o
+            return total, tuple(state)
+        return jax.jit(run, donate_argnums=(0,))
+
     moved = 2 * slots * heads * dk * v_dim * 4
-    emit({"phase": "kernels", "kernel": "gdn_step", "path": "kda_step",
-          "rows": slots, "heads": heads, "head_dim": dk, "value_dim": v_dim,
-          "ms_per_call": round(ms, 4),
-          "state_gb_per_s_as_stored": round(moved / ms / 1e6, 1),
-          "max_abs_err_o": round(o_err, 8),
-          "max_abs_err_state": round(s_err, 8),
-          "max_abs_err_o_to_equation": round(def_o_err, 8),
-          "max_abs_err_state_to_equation": round(def_s_err, 8),
-          "calls": calls})
+    chain = None        # kda_step's sum of outputs and states after a run
+    for path, step in (("kda_step", plain),
+                       ("kda_step_kernel", through_kernel),
+                       ("kda_step_kernel/copy", copies_alone)):
+        fn = chained(step)
+        total, state = fn(fresh_state(), inputs)
+        jax.block_until_ready(state)
+        if path == "kda_step":
+            chain = (total, tuple(jnp.copy(s) for s in state))
+        elif path == "kda_step_kernel":
+            # the program a tick runs: several calls, every row live
+            run_err = max(float(jnp.abs(a - b).max()) for a, b in
+                          zip((total,) + state, (chain[0],) + chain[1]))
+            check(run_err <= 1e-4 * s_max,
+                  f"{path} ({kernel}): {calls} chained calls end "
+                  f"{run_err} from kda_step's")
+            chain = None
+        t1 = time.perf_counter()
+        for _ in range(3):
+            total, state = fn(state, inputs)
+        jax.block_until_ready((total, state))
+        ms = (time.perf_counter() - t1) * 1e3 / (3 * calls)
+        del state
+        line = {"phase": "kernels", "kernel": kernel, "path": path,
+                "rows": slots, "heads": heads, "head_dim": dk,
+                "value_dim": v_dim, "scalar_decay": scalar_decay,
+                "ms_per_call": round(ms, 4),
+                "state_gb_per_s_as_stored": round(moved / ms / 1e6, 1),
+                "calls": calls}
+        if path in errs:
+            line.update(max_abs_err_o_to_equation=round(errs[path][0], 8),
+                        max_abs_err_state_to_equation=round(errs[path][1],
+                                                            8),
+                        max_abs_err_o_to_chunk_form=round(chunk_err, 8))
+        emit(line)
+        free_device_memory()
+
+
+def phase_gdn_program(seed: int, ticks: int = 4) -> None:
+    """``reason_closed_gdn``'s OWN decode program (the benchmark's
+    configuration, weights and engine sizes: 8 layers, the whole
+    vocabulary, 64 slots, 14,337 pages) under both values of
+    ``state_impl``, fed the same tokens, positions and live rows for a few
+    ticks from the same random state: ``sum |S|`` a (layer, row, head)
+    after every tick, the kernel's program against ``kda_step``'s. Every
+    ``linear_attention`` layer's ``o_proj`` is zeroed, so each layer sees
+    the same input whatever the step did before it and a fault shows in
+    the layer that has it. Not part of the default run (the weights take
+    the chip's memory): ``--phase gdn_program``. What it guards: in PR 48
+    the kernel's program wrote the LAST such layer's conv rows in place
+    before a rematerialised read of them fed the convolution (the
+    compiler's schedule, ``tests/test_chip_compile.py
+    reads_after_in_place_writes``), its state left ``kda_step``'s by up to
+    78% a head from the first tick, and the cell read ``correct`` false."""
+    import os
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark import weights_olmo
+    from benchmark.systems import serve_olmo
+    from paddle_tpu.inference import llm
+
+    root = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-serve-pp4.json")) as f:
+        model = json.load(f)
+    params = weights_olmo.make(weights_olmo.dims_of(model), seed + 123,
+                               jnp.bfloat16)
+    linear = [k for k in params if k.endswith("mixer.o_proj.weight")
+              and int(k.split(".")[1]) % 4 != 3]
+    for k in linear:
+        params[k] = jnp.zeros_like(params[k])
+    net = serve_olmo.build_net(model, params)
+    del params
+    net.eval()
+    cfg, slots = net.cfg, 64
+    rng = np.random.RandomState(seed + 5)
+    lives = [np.isin(np.arange(slots), [36, 44]), np.ones(slots, bool),
+             rng.rand(slots) < 0.5, np.arange(slots) < 8][:ticks]
+    sched, pos = [], np.zeros((slots,), np.int32)
+    for live in lives:
+        pos = np.where(live, pos + 1, pos).astype(np.int32)
+        sched.append((pos.copy(), np.where(live, pos + 1, 0).astype(np.int32),
+                      rng.randint(0, cfg.vocab_size, size=(slots,))
+                      .astype(np.int32)))
+    real = llm._state_impl
+    sums = {}
+    try:
+        for impl in ("xla", "pallas"):
+            llm._state_impl = lambda state, impls=None, impl=impl: impl
+            eng = llm.LLMEngine(net, max_seqs=slots, page_size=PAGE,
+                                num_pages=14337, max_len=3584,
+                                prefill_chunk=256, kv_dtype="bf16",
+                                attention_impl="pallas")
+            try:
+                check(eng.state_impl == impl, f"engine took {eng.state_impl}")
+                for g in eng._pool.groups:
+                    g.tables[:, :16] = 1 + np.arange(slots * 16).reshape(
+                        slots, 16)
+                conv = tuple(jnp.zeros_like(a) for a in eng.conv_state)
+                stored = jnp.arange(cfg.value_width) \
+                    < cfg.linear_value_head_dim
+                ssm = tuple(
+                    jax.random.normal(k, a.shape, jnp.float32) * 0.1 * stored
+                    for k, a in zip(jax.random.split(
+                        jax.random.PRNGKey(seed + 11), len(eng.ssm_state)),
+                        eng.ssm_state))
+                kp, vp, seen = eng.k_pages, eng.v_pages, []
+                for p, lens, toks in sched:
+                    _, kp, vp, conv, ssm = eng._decode_fn(
+                        eng._params, eng._buffers, jnp.asarray(toks),
+                        eng._stage_decode(p, lens), kp, vp, eng._key, conv,
+                        ssm)
+                    seen.append(np.asarray(jnp.stack(
+                        [jnp.sum(jnp.abs(s), axis=(2, 3)) for s in ssm])))
+                sums[impl] = np.stack(seen)     # [ticks, layers, rows, heads]
+            finally:
+                eng.close()
+            del eng, kp, vp, conv, ssm
+            free_device_memory()
+    finally:
+        llm._state_impl = real
+    rel = np.abs(sums["pallas"] - sums["xla"]) \
+        / np.maximum(np.abs(sums["xla"]), 1e-3)
+    worst = rel.max(axis=(0, 2, 3))
+    emit({"phase": "gdn_program", "ticks": len(sched),
+          "delta_rule_layers": len(linear),
+          "max_rel_err_of_sum_abs_state_by_layer":
+              [float(f"{x:.3g}") for x in worst]})
+    check(float(worst.max()) <= 1e-5,
+          f"the kernel's decode program leaves kda_step's: worst relative "
+          f"error of sum |S| a (row, head), by layer {worst.tolist()}")
+    del net
     free_device_memory()
 
 
@@ -1710,7 +1931,8 @@ def main(argv=None) -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", default=None,
-                    choices=("kernels", "gdn", "staging", "serve",
+                    choices=("kernels", "gdn", "gdn_program", "staging",
+                             "serve",
                              "serve_hybrid", "serve_looped", "serve_swa",
                              "train"),
                     help="one chip: run this phase alone (default: all)")
@@ -1731,6 +1953,8 @@ def main(argv=None) -> int:
                 # (part of the kernels phase; alone, the one-decay delta
                 # rule's chunk and step calls)
                 time_gdn_chunk(args.seed)
+            if args.phase == "gdn_program":
+                phase_gdn_program(args.seed)
             for name, phase in phases.items():
                 if args.phase in (None, name):
                     phase(args.seed)

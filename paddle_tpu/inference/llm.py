@@ -596,16 +596,19 @@ def _all_on_tpu(arrays) -> bool:
 def _state_impl(ssm_state, impls=("xla", "pallas")) -> str:
     """How an engine's programs advance the recurrent state of their
     rows, by the platform of the state's device and by the
-    implementations the model's recurrence has (``impls``:
+    implementations the model's recurrence names (``impls``:
     ``state_cache_spec()["impls"]``, both where it names none):
-    ``"pallas"`` (``ops/ssd.py ssd_step_kernel`` for the decode rows and
-    ``ssd_chunk_kernel`` for a chunk of prompt rows: the layer's whole
-    state array in place, the rows of the sequences at work only) on a
-    TPU, ``"xla"`` (``ssd_step`` over the slots' rows, ``ssd_chunked``
+    ``"pallas"`` on a TPU (the decode rows through ``ops/ssd.py
+    ssd_step_kernel`` or, a delta rule that names it, ``ops/kda.py
+    kda_step_kernel``: the layer's whole state array in place, the live
+    rows' tiles only; a chunk of prompt rows through ``ssd_chunk_kernel``,
+    the rows of the sequences in the chunk only, where the spec's
+    ``chunk_impls`` do not leave the kernel out: a delta rule's chunk
+    form is ``kda_chunk_gathered`` under either value), ``"xla"``
+    (``ssd_step`` / ``kda_step`` over the slots' rows, the chunk forms
     over the gathered rows of as many sequences as a chunk may hold)
-    anywhere else, for a model without state and for a recurrence
-    without a kernel (a delta rule exists in plain ``jax.numpy`` alone:
-    ``"xla"`` on every platform)."""
+    anywhere else, for a model without state and for a recurrence that
+    names no kernel."""
     on_tpu = ssm_state is not None and _all_on_tpu(ssm_state)
     return "pallas" if on_tpu and "pallas" in impls else "xla"
 
@@ -1408,8 +1411,11 @@ class LLMEngine:
                 // n_rows,
                 "ssm_state": sum(a.nbytes for a in self.ssm_state)
                 // n_rows}
-        self.state_impl = _state_impl(
-            self.ssm_state, (spec or {}).get("impls", ("xla", "pallas")))
+        impls = (spec or {}).get("impls", ("xla", "pallas"))
+        self.state_impl = _state_impl(self.ssm_state, impls)
+        # whether a chunk moves the rows of its own sequences alone
+        self._chunk_in_place = self.state_impl == "pallas" and \
+            "pallas" in (spec or {}).get("chunk_impls", impls)
         self._slots: List[Optional[_Request]] = [None] * max_seqs
         # device-chained last tokens (authoritative between fetches)
         self._tokens_dev = jnp.zeros((max_seqs,), jnp.int32)
@@ -3202,9 +3208,11 @@ class LLMEngine:
         chunk may hold sequences; the decode half steps every slot's (an
         inactive row is read and written back unchanged). Through the
         kernels (``state_impl`` ``"pallas"``) the ``ssm_state`` rows that
-        move are those of the sequences in the chunk and of the live
-        decode rows alone; ``ssd_chunked`` moves as many as a chunk may
-        hold, ``ssd_step`` every slot's."""
+        move are those of the live decode rows and, where the chunk form
+        has a kernel too (not a delta rule's: ``chunk_impls`` of the
+        model's spec), of the sequences in the chunk alone; a gathered
+        chunk form moves as many as a chunk may hold, ``ssd_step`` /
+        ``kda_step`` every slot's."""
         if ph is _trace.NOOP_SPAN:
             return
         rows = moved = 0
@@ -3215,8 +3223,9 @@ class LLMEngine:
             slots = self.max_seqs
             row = self._state_row_bytes
             moved = (slots + chunk) * row["conv_state"] + (
-                rows if self.state_impl == "pallas"
-                else slots + chunk) * row["ssm_state"]
+                (live_rows if self.state_impl == "pallas" else slots)
+                + (chunk_seqs if self._chunk_in_place else chunk)
+            ) * row["ssm_state"]
         ph.set_attr("state_rows", rows).set_attr("state_bytes", 2 * moved)
 
     def _split_fetch(self, host):

@@ -415,15 +415,19 @@ class KimiLinearLayer(Layer):
 
 
 def delta_rule_rows(mixer, u, rows, valid, conv_s, ssm_s, fresh,
-                 scope: str = "kda"):
+                    scope: str = "kda", state_impl: str = "xla"):
     """A delta-rule layer (``mixer``: ``gates``, ``qkv_proj``,
     ``conv_weight``, ``qkv``; this model's and ``models/olmo_hybrid.py``'s)
     over ragged rows: the first ``rows.n_chunk`` packed prompt rows through
     the chunk form (each sequence present carries its state row in and out
     once), the others one token a sequence through the step (row ``i`` of
     them on state row ``i``), under the scopes ``<scope>_chunk`` /
-    ``<scope>_step``. ``(o [T, heads, V] float32, conv_state,
-    ssm_state)``."""
+    ``<scope>_step``. ``state_impl`` (``CacheView.state_impl``) chooses
+    the step: ``"pallas"`` the kernel over the layer's whole array in
+    place, the live rows' tiles alone crossing HBM; else ``kda_step`` over
+    the slots' rows, sliced out and written back. The chunk form is
+    ``kda_chunk_gathered`` under either. ``(o [T, heads, V] float32,
+    conv_state, ssm_state)``."""
     c = rows.n_chunk
     n_dec = u.shape[0] - c
     log_a, b = mixer.gates(u, valid)
@@ -438,6 +442,8 @@ def delta_rule_rows(mixer, u, rows, valid, conv_s, ssm_s, fresh,
             conv, tail = ssd.causal_conv_chunk(
                 qkv[:c], mixer.conv_weight, no_bias, tail,
                 rows.chunk_seg)
+            # (the write waits for the read: see the decode rows below)
+            conv, tail = jax.lax.optimization_barrier((conv, tail))
             conv_s = conv_s.at[rows.seg_rows].set(tail)
         with jax.named_scope(scope + "_chunk"):
             o, ssm_s = kda.kda_chunk_gathered(
@@ -452,16 +458,24 @@ def delta_rule_rows(mixer, u, rows, valid, conv_s, ssm_s, fresh,
             conv, tail = ssd.causal_conv_step(
                 qkv[c:], mixer.conv_weight, no_bias,
                 jnp.where(first[:, None, None], 0, old))
+            # the new tails go over ``old`` in place, and not before the
+            # convolution has read it: XLA was seen to hoist the write over
+            # a rematerialised read of the donated rows (PERF.md, PR 48)
+            conv, tail = jax.lax.optimization_barrier((conv, tail))
             conv_s = conv_s.at[:n_dec].set(
                 jnp.where(live[:, None, None], tail, old))
         with jax.named_scope(scope + "_step"):
-            # a row that is not live has log_a 0 and b 0: its state
-            # row is written back as it was
-            o, new = kda.kda_step(
-                *mixer.qkv(conv), log_a[c:], b[c:],
-                jnp.where((first & live)[:, None, None, None], 0.0,
-                          ssm_s[:n_dec]))
-            ssm_s = ssm_s.at[:n_dec].set(new)
+            if state_impl == "pallas":
+                o, ssm_s = kda.kda_step_kernel(
+                    *mixer.qkv(conv), log_a[c:], b[c:], ssm_s, live, first)
+            else:
+                # a row that is not live has log_a 0 and b 0: its state
+                # row is written back as it was
+                o, new = kda.kda_step(
+                    *mixer.qkv(conv), log_a[c:], b[c:],
+                    jnp.where((first & live)[:, None, None, None], 0.0,
+                              ssm_s[:n_dec]))
+                ssm_s = ssm_s.at[:n_dec].set(new)
             os.append(o)
     return (os[0] if len(os) == 1 else jnp.concatenate(os)), \
         conv_s, ssm_s
@@ -541,8 +555,14 @@ class KimiLinearForCausalLM(Layer):
         per KDA layer a ``conv_state`` row (the last ``kernel - 1`` inputs
         of the convolution over q, k and v, in the activations' type) and
         an ``ssm_state`` row (the delta rule's ``[heads, d, d]`` state,
-        float32). ``impls``: the delta rule exists in plain ``jax.numpy``
-        alone. None for a stack without a KDA layer."""
+        float32). ``impls``: what an engine may choose for the step:
+        ``kda_step`` (``"xla"``) or, on a TPU, the kernel ``ops/kda.py
+        kda_step_kernel`` over the layer's whole array in place
+        (``"pallas"``: :func:`delta_rule_rows` takes it under that
+        ``state_impl``). ``chunk_impls``: the chunk form exists in plain
+        ``jax.numpy`` alone, so a chunk gathers and scatters as many rows
+        as it may hold sequences under either. None for a stack without a
+        KDA layer."""
         cfg = self.cfg
         n = len(cfg.layers_of("kda"))
         if not n:
@@ -553,7 +573,8 @@ class KimiLinearForCausalLM(Layer):
                               cfg.kda_head_dim),
                 "conv_dtype": self._dtype,
                 "max_chunk_sequences": MAX_CHUNK_SEQUENCES,
-                "impls": ("xla",)}
+                "impls": ("xla", "pallas"),
+                "chunk_impls": ("xla",)}
 
     def moe_aux_spec(self):
         """``(routed layers, held experts)``: :meth:`ragged_forward`'s
@@ -618,7 +639,8 @@ class KimiLinearForCausalLM(Layer):
                 with jax.named_scope("kda"):
                     o, conv_state[i_st], ssm_state[i_st] = delta_rule_rows(
                         mixer, u, rows, valid, conv_state[i_st],
-                        ssm_state[i_st], fresh)
+                        ssm_state[i_st], fresh,
+                        state_impl=cache.state_impl)
                     out = mixer.finish(o, u)
                 i_st += 1
             x = x + out

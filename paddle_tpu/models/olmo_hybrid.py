@@ -433,7 +433,10 @@ class OlmoHybridForCausalLM(Layer):
         ``kernel - 1`` inputs of the convolution over q, k and v, in the
         activations' type) and an ``ssm_state`` row, the rule's state AS
         STORED: ``[heads, K, value_width]`` float32, a head's value padded
-        to whole lanes. ``impls``: the rule exists in plain ``jax.numpy``
+        to whole lanes. ``impls``: what an engine may choose for the
+        step: ``kda_step`` or, on a TPU, the kernel ``ops/kda.py
+        kda_step_kernel`` (one decay a head is a scalar a tile there);
+        ``chunk_impls``: the chunk form exists in plain ``jax.numpy``
         alone. None for a stack without such a layer."""
         cfg = self.cfg
         n = len(cfg.layers_of(LINEAR))
@@ -446,7 +449,8 @@ class OlmoHybridForCausalLM(Layer):
                               cfg.linear_key_head_dim, cfg.value_width),
                 "conv_dtype": self._dtype,
                 "max_chunk_sequences": MAX_CHUNK_SEQUENCES,
-                "impls": ("xla",),
+                "impls": ("xla", "pallas"),
+                "chunk_impls": ("xla",),
                 "rule": "delta, scalar decay"}
 
     def moe_aux_spec(self):
@@ -505,7 +509,8 @@ class OlmoHybridForCausalLM(Layer):
                     # step for the others: the per-channel model's walk)
                     o, conv_state[i_st], ssm_state[i_st] = delta_rule_rows(
                         mixer, u, rows, valid, conv_state[i_st],
-                        ssm_state[i_st], fresh, scope="gdn")
+                        ssm_state[i_st], fresh, scope="gdn",
+                        state_impl=cache.state_impl)
                     out = mixer.finish(o, u)
                 i_st += 1
             x = x + layer.mixer_norm(out.astype(jnp.float32))

@@ -27,11 +27,15 @@ no ``[16, 16, H, K]`` operand a block, no second product through a
 reference row). Everything after the pair products (the solve, the inverse,
 the state products, the pieces, the padded rows) is the same code for both.
 
-Three forms, all plain ``jax.numpy`` (no kernel exists yet: ROADMAP A):
+Three forms in plain ``jax.numpy``, and the step once more as a kernel:
 
 - :func:`kda_recurrence`: the definition, a ``lax.scan`` over the tokens of
   ONE sequence. The oracle of the other two; never on the engine's path.
-- :func:`kda_step`: one token a row, the decode tick.
+- :func:`kda_step`: one token a row, the decode tick off the TPU, and the
+  oracle of :func:`kda_step_kernel`: the same step as a Pallas kernel over
+  a layer's whole state array, in place, that reads a live row's tile once
+  and writes it once (both decays; what ``delta_rule_rows`` takes under
+  ``state_impl`` ``"pallas"``: an engine's decode rows on a TPU).
 - :func:`kda_chunked`: a PACKED run of ``T`` rows that holds up to ``G``
   sequences, each contiguous and in order (``tok_seg[t]`` = the local index
   of row ``t``'s sequence, ``G`` for a padded row), every sequence entering
@@ -63,8 +67,16 @@ token), and every float32 product asks for ``Precision.HIGHEST``.
 
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _default_interpret
+from .ssd import _head_block, held_tiles
 
 _HI = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
@@ -99,6 +111,179 @@ def kda_step(q, k, v, log_a, b, state):
     new = decayed + k[..., None] * w[..., None, :]
     o = seen[1] + jnp.sum(q * k, axis=-1, keepdims=True) * w
     return o, new
+
+
+def kda_step_kernel(q, k, v, log_a, b, state, live, first,
+                    head_block: Optional[int] = None,
+                    interpret: Optional[bool] = None):
+    """:func:`kda_step` as a Pallas kernel over ONE layer's whole state
+    array, updated in place. ``q``, ``k`` [R, H, K]; ``log_a`` [R, H, K]
+    or, one decay a head, [R, H, 1]; ``v`` [R, H, V]; ``b`` [R, H];
+    ``state`` [S, H, K, V] float32 with ``S > R`` (the engine's ``slots +
+    1`` rows: row ``i`` of the inputs steps state row ``i``); ``live``,
+    ``first`` [R] bool. Returns ``(o [R, H, V] float32, state)``; the
+    returned state IS the argument's buffer (``input_output_aliases``)
+    wherever the caller donates it.
+
+    The grid is (row, block of heads), as ``ops/ssd.py ssd_step_kernel``'s,
+    with its tile size (``ssd._head_block``: 16 heads of 128 x 128, 10 of
+    96 x 256) and its walk of the tiles (``ssd.held_tiles``). A live row's
+    step brings its ``[head_block, K, V]`` tile into VMEM ONCE and, a head
+    at a time and all in float32 on the vector unit (a multiply and an add
+    an element: no product is rounded to bfloat16), forms ``S' = Diag(a)
+    S``, ``k^T S'`` and ``q^T S'`` from that one pass, ``w = b (v - k^T
+    S')``, ``S' + k w^T`` (sent back, once) and ``o = q^T S' + (q . k) w``
+    as :func:`kda_step` writes them. A ``first`` row starts from zeros and
+    its old tile is never read. A row that is not ``live`` moves NOTHING:
+    its steps name the block the pipeline already holds, so its state row
+    (and every row past ``R``: the scratch row) keeps its bytes, and its
+    ``o`` is zeros.
+
+    A head's tile lies ``[K, V]`` (``V`` on lanes), so ``v``, ``w`` and
+    ``o`` are rows as they arrive and the sums over ``K`` add registers
+    (one sublane reduction a head and sum). ``k``, ``q`` and a decay a
+    channel run along ``K``, the sublanes: a (row, block)'s operands cross
+    as one ``[channels x heads, K]`` block, are turned once (``K`` a
+    multiple of 8: whole sublane groups) and a head's column is spread
+    along the lanes by a lane broadcast. The finding in
+    ``ssd_step_kernel``'s docstring did NOT carry over: the same columns
+    built from SMEM scalars, eight selects a register, were bound by that
+    arithmetic at 0.83 / 1.06 ms a call where this form is bound by the
+    tiles' copies at 0.64 / 0.36 (read on the chip, PR 48). ``b``, ``q .
+    k`` and ONE decay a head are SMEM scalars; ``e^{log a}`` and ``q . k``
+    are computed beside the kernel, the same float32 operations as
+    :func:`kda_step`'s. ONE decay a head (``log_a`` [R, H, 1]) multiplies
+    its tile as a scalar; a decay a channel as a third column: one kernel,
+    the form chosen by the decay's shape. ``interpret`` defaults to the
+    module switch ``flash_attention.INTERPRET``.
+    """
+    if interpret is None:
+        interpret = _default_interpret()
+    _, n_heads, d_k = k.shape
+    if d_k % 8:
+        raise ValueError(f"key size {d_k} is not a multiple of 8")
+    if log_a.shape[-1] not in (1, d_k):
+        raise ValueError(f"a decay of {log_a.shape[-1]} channels beside "
+                         f"keys of {d_k}")
+    if head_block is None:
+        head_block = _head_block(n_heads, d_k, state.shape[-1])
+    return _kda_step_call(q, k, v, log_a, b, state, live, first,
+                          head_block=int(min(head_block, n_heads)),
+                          interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("head_block", "interpret"))
+def _kda_step_call(q, k, v, log_a, b, state, live, first, *, head_block,
+                   interpret):
+    """Jitted so that an engine program, which calls it once a delta-rule
+    layer with the same shapes, traces and lowers the kernel once."""
+    f32, i32 = _F32, jnp.int32
+    rows, n_heads, d_k = k.shape
+    slots, _, _, d_v = state.shape
+    hb = head_block
+    nb = -(-n_heads // hb)
+    channel = log_a.shape[-1] != 1
+
+    live = live.astype(bool)
+    reads = live & ~first.astype(bool)
+    in_row, in_blk = held_tiles(reads, slots, nb)
+    out_row, out_blk = held_tiles(live, slots, nb)
+    flags = live.astype(i32) + 2 * reads.astype(i32)
+    any_live = jnp.any(live).astype(i32)[None]
+
+    def blocks(x):
+        """[R, H, ...] -> [R * nb, 1, hb, ...], the heads past H zeros."""
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, nb * hb - n_heads))
+                    + ((0, 0),) * (x.ndim - 2))
+        return x.reshape((rows * nb, 1, hb) + x.shape[2:])
+
+    # a (row, block)'s operands. A scalar a head through SMEM: b, q . k
+    # and ONE decay. What runs along K as they arrive, [channels * hb, K]
+    # (whole lanes; the kernel turns the block into columns): k, q and a
+    # decay a channel.
+    qf, kf = q.astype(f32), k.astype(f32)
+    decay = jnp.exp(log_a.astype(f32))
+    by_head = [b, jnp.sum(qf * kf, axis=-1)] \
+        + ([] if channel else [decay[..., 0]])
+    scalars = jnp.concatenate([blocks(x) for x in by_head], axis=-1)
+    by_channel = [kf, qf] + ([decay] if channel else [])
+    along_k = jnp.concatenate([blocks(x) for x in by_channel], axis=2)
+
+    def tile(row_ref, blk_ref):
+        def index(r, b, *refs):
+            blk = refs[blk_ref][r]
+            return refs[row_ref][r], jnp.where(blk < 0, b, blk), 0, 0
+        return pl.BlockSpec((1, hb, d_k, d_v), index)
+
+    def kernel(in_row_ref, in_blk_ref, out_row_ref, out_blk_ref, flag_ref,
+               any_ref, sc_ref, v_ref, c_ref, s_ref, y_ref, o_ref):
+        r, blk = pl.program_id(0), pl.program_id(1)
+        flag = flag_ref[r]
+
+        @pl.when((any_ref[0] == 0) & (r == 0) & (blk == 0))
+        def _nothing_live():              # the held tile, onto itself
+            o_ref[...] = s_ref[...]
+
+        @pl.when(flag == 0)
+        def _not_live():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        @pl.when(flag > 0)
+        def _step():
+            fresh = flag < 2
+            cols = c_ref[0, 0].T          # [K, channels * hb]: K on sublanes
+
+            def column(c, j):
+                """[K, V]: channel operand ``c`` of head ``j`` down the
+                sublanes, spread along the lanes."""
+                at = c * hb + j
+                return jnp.broadcast_to(cols[:, at:at + 1], (d_k, d_v))
+
+            ys = []
+            for j in range(hb):           # static: a column is a lane slice
+                k_col = column(0, j)
+                old = jnp.where(fresh, 0.0, s_ref[0, j])           # [K, V]
+                decayed = (column(2, j) if channel
+                           else sc_ref[0, 0, 2 * hb + j]) * old
+                k_s = jnp.sum(k_col * decayed, axis=0, keepdims=True)
+                q_s = jnp.sum(column(1, j) * decayed, axis=0, keepdims=True)
+                w = sc_ref[0, 0, j] * (v_ref[0, 0, j:j + 1, :] - k_s)
+                o_ref[0, j] = decayed + k_col * w
+                ys.append(q_s + sc_ref[0, 0, hb + j] * w)          # [1, V]
+            y_ref[0, 0] = jnp.concatenate(ys)
+
+    def per_step(*block, **kw):
+        # a live row's own operands; for any other row those already held
+        def index(r, b, *refs):
+            blk = refs[3][r]
+            return (jnp.minimum(refs[2][r], rows - 1) * nb
+                    + jnp.where(blk < 0, b, blk),) + (0,) * len(block)
+        return pl.BlockSpec((1,) + block, index, **kw)
+
+    y, new_state = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(rows, nb),
+            in_specs=[per_step(1, scalars.shape[-1],
+                               memory_space=pltpu.SMEM),
+                      per_step(1, hb, d_v),
+                      per_step(*along_k.shape[1:]), tile(0, 1)],
+            out_specs=[pl.BlockSpec((1, 1, hb, d_v),
+                                    lambda r, b, *_: (r * nb + b, 0, 0, 0)),
+                       tile(2, 3)]),
+        out_shape=[jax.ShapeDtypeStruct((rows * nb, 1, hb, d_v), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # operand 9 (6 prefetched + 3 small ones) is the state
+        input_output_aliases={9: 1},
+        # sequential: a tile stays in VMEM across the steps that name it
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kda_step",
+    )(in_row, in_blk, out_row, out_blk, flags, any_live, scalars,
+      blocks(v), along_k, state)
+    return y.reshape(rows, nb * hb, d_v)[:, :n_heads], new_state
 
 
 def kda_recurrence(q, k, v, log_a, b, state):

@@ -179,6 +179,24 @@ def ssd_step_kernel(x, dt, A, B, C, D, state, live, first,
                           interpret=bool(interpret))
 
 
+def held_tiles(moves, slots: int, nb: int):
+    """Where each row's grid steps of a step kernel find their state tile
+    (this file's and ``ops/kda.py``'s): ``(row, blk)`` int32 ``[rows]``. A
+    row that ``moves`` its tile names its own row and the step's block
+    (``blk`` -1); any other names the tile the pipeline holds (the last
+    one moved, or before the first the one to come), so nothing is copied
+    for it. With nothing to move at all, every step names block 0 of the
+    last of the ``slots`` rows and copies it onto itself."""
+    rows = moves.shape[0]
+    ids = jnp.arange(rows, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(moves, ids, -1))
+    after = jax.lax.cummin(jnp.where(moves, ids, rows), reverse=True)
+    row = jnp.where(before >= 0, before,
+                    jnp.where(after < rows, after, slots - 1))
+    blk = jnp.where(moves, -1, jnp.where(before >= 0, nb - 1, 0))
+    return row.astype(jnp.int32), blk.astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("head_block", "interpret"))
 def _ssd_step_call(x, dt, A, B, C, D, state, live, first, *, head_block,
                    interpret):
@@ -192,26 +210,10 @@ def _ssd_step_call(x, dt, A, B, C, D, state, live, first, *, head_block,
     # heads a loop step holds: independent work hides the lane sum's wait
     unroll = next(u for u in (4, 2, 1) if hb % u == 0)
 
-    # where each grid step's state tile lies. A row that moves its tile
-    # names its own row and the step's block; any other step names the
-    # tile the pipeline holds (the last one moved, or before the first
-    # the one to come), so nothing is copied for it. With nothing to move
-    # at all, every step names block 0 of the last row and copies it onto
-    # itself.
-    ids = jnp.arange(rows, dtype=i32)
-
-    def held(moves):
-        before = jax.lax.cummax(jnp.where(moves, ids, -1))
-        after = jax.lax.cummin(jnp.where(moves, ids, rows), reverse=True)
-        row = jnp.where(before >= 0, before,
-                        jnp.where(after < rows, after, slots - 1))
-        blk = jnp.where(moves, -1, jnp.where(before >= 0, nb - 1, 0))
-        return row.astype(i32), blk.astype(i32)
-
     live = live.astype(bool)
     reads = live & ~first.astype(bool)
-    in_row, in_blk = held(reads)
-    out_row, out_blk = held(live)
+    in_row, in_blk = held_tiles(reads, slots, nb)
+    out_row, out_blk = held_tiles(live, slots, nb)
     flags = live.astype(i32) + 2 * reads.astype(i32)
     any_live = jnp.any(live).astype(i32)[None]
 

@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops.flash_attention import flash_attention
 from paddle_tpu.ops.grouped_matmul import grouped_matmul
 from paddle_tpu.ops.paged_attention import paged_attention_kernel
+from paddle_tpu.ops.kda import kda_step_kernel
 from paddle_tpu.ops.ssd import ssd_chunk_kernel, ssd_step_kernel
 
 HEADS, HEAD_DIM, PAGE, MAX_LEN = 16, 128, 16, 2048
@@ -50,6 +51,89 @@ def _no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def reads_after_in_place_writes(text):
+    """In a compiled, scheduled program's text: every read of a donated
+    parameter (``input_output_alias`` of the module's head) that ends AFTER
+    a fusion or a custom call which takes the parameter and returns its
+    shape, that is, after the buffer was written in place. A read is any
+    other instruction that names the parameter, or a bitcast of it; an
+    asynchronous one ends at its ``-done``. ``[(parameter, reader,
+    writer)]``: a sound schedule gives none. (PR 48: with the step's kernel
+    in ``reason_closed_gdn``'s decode program the compiler wrote the last
+    delta-rule layer's conv rows in place BEFORE a rematerialised read of
+    them that fed the convolution, and the cell read ``correct`` false.)"""
+    import re
+    head = text[:text.index("\n")]
+    numbers = {int(n) for n in re.findall(r"\}: \((\d+), \{\}", head)}
+    ins = []
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*)", line)
+        if m:
+            ins.append((m.group(1), m.group(2)))
+
+    def bare(shape):
+        return re.sub(r"\{[^}]*\}", "", shape)
+
+    found = []
+    for par, rhs in ins:
+        m = re.match(r"(\S+) parameter\((\d+)\)", rhs)
+        if not m or int(m.group(2)) not in numbers:
+            continue
+        shape = bare(m.group(1))
+        names = {par} | {n for n, r in ins if re.search(
+            r" bitcast\(" + re.escape(par) + r"\)", r)}
+        uses = [i for i, (n, r) in enumerate(ins) if n not in names and any(
+            re.search(re.escape(x) + r"[,)]", r) for x in names)]
+        writers = [i for i in uses
+                   if re.search(r" (fusion|custom-call)\(", ins[i][1])
+                   and shape in bare(re.split(
+                       r" (?:fusion|custom-call)\(", ins[i][1])[0])]
+        for i in uses:
+            if i in writers:
+                continue
+            name, end = ins[i][0], i
+            if "-start" in name:
+                end = next((j for j, (_, r) in enumerate(ins) if re.search(
+                    r"-done\(" + re.escape(name) + r"\)", r)), i)
+            found += [(par, name, ins[w][0]) for w in writers if w < end]
+    return found
+
+
+_SCHEDULE = """HloModule jit_decode_fn, is_scheduled=true, input_output_alias={ {0}: (1, {}, may-alias) }
+
+ENTRY %main (x.1: bf16[64,8], rows.1: bf16[65,3,8]) -> (bf16[65,3,8]) {
+  %x.1 = bf16[64,8]{1,0} parameter(0)
+  %rows.1 = bf16[65,3,8]{2,0,1:T(8,128)(2,1)} parameter(1)
+READ_BEFORE
+  %fusion.34 = bf16[65,3,8]{2,0,1:T(8,128)(2,1)} fusion(%rows.1, %x.1), kind=kLoop, calls=%scatter
+READ_AFTER
+  %conv = f32[64,8]{1,0} fusion(%x.1, %old), kind=kLoop, calls=%window
+  ROOT %tuple = (bf16[65,3,8]{2,0,1:T(8,128)(2,1)}) tuple(%fusion.34)
+}
+"""
+
+
+@pytest.mark.parametrize("before,after,found", [
+    ("  %old = bf16[64,3,8]{2,0,1} fusion(%rows.1), kind=kLoop, calls=%cut",
+     "", []),
+    ("", "  %old = bf16[64,3,8]{2,0,1} fusion(%rows.1), kind=kLoop, "
+         "calls=%cut", [("%rows.1", "%old", "%fusion.34")]),
+    ("  %slice-start.48 = ((bf16[65,3,8]{2,0,1}), bf16[65,1,8]{2,0,1:S(1)}, "
+     "s32[]{:S(2)}) async-start(%rows.1), calls=%cut",
+     "  %old = bf16[65,1,8]{2,0,1:S(1)} async-done(%slice-start.48)",
+     [("%rows.1", "%slice-start.48", "%fusion.34")]),
+], ids=["read_then_write", "write_then_read", "a_read_that_spans_the_write"])
+def test_a_read_of_donated_rows_after_their_write_in_place_is_found(
+        before, after, found):
+    """The reader of the engine programs' texts, on a schedule of five
+    lines: a parameter the program's output aliases, one fusion that writes
+    it in place, and a read before it, after it, or begun before and done
+    after (the schedule the chip ran wrong in PR 48)."""
+    text = _SCHEDULE.replace("READ_BEFORE", before).replace("READ_AFTER",
+                                                            after)
+    assert reads_after_in_place_writes(text) == found
 
 
 def _compiled(fn, *shapes):
@@ -371,6 +455,34 @@ def test_ssd_chunk_kernel_compiles_for_v5e(one_chip, head_block, rows):
 
 # the hybrid serving benchmark's routed experts: 36 held, hidden 4096,
 # width 768; a decode tick's 64 rows x 10 experts and a mixed tick's 320
+@pytest.mark.parametrize("rows,heads,dk,dv,channel", [
+    (48, 32, 128, 128, True), (64, 30, 96, 256, False)],
+    ids=["kimi_a_decay_a_channel", "olmo_one_decay_a_head"])
+def test_kda_step_kernel_compiles_for_v5e(one_chip, rows, heads, dk, dv,
+                                          channel):
+    """The delta rule's step at both cells' shapes, a layer's whole
+    ``[49, 32, 128, 128]`` / ``[65, 30, 96, 256]`` array donated: aliased in
+    place, no temporary the size of a state row (the kernel's small
+    operands are a few hundred KB)."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, k, v, log_a, b, state, live, first):
+        return kda_step_kernel(q, k, v, log_a, b, state, live, first,
+                               interpret=False)
+
+    row_bytes = heads * dk * dv * 4
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        sds((rows, heads, dk)), sds((rows, heads, dk)),
+        sds((rows, heads, dv)), sds((rows, heads, dk if channel else 1)),
+        sds((rows, heads)), sds((rows + 1, heads, dk, dv)),
+        sds((rows,), jnp.bool_), sds((rows,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == (rows + 1) * row_bytes
+    assert mem.temp_size_in_bytes < row_bytes // 2
+
+
 @pytest.mark.parametrize("pairs", [640, 3200], ids=["decode", "mixed"])
 @pytest.mark.parametrize("weights", [(4096, 1536), (768, 4096)],
                          ids=["w_in", "w_out"])
@@ -532,6 +644,7 @@ def test_hybrid_program_keeps_no_copy_of_a_layers_state(hybrid_programs,
 
     assert len(calls("ssd_step")) == 3
     assert len(calls("ssd_chunk")) == (3 if program == "mixed" else 0)
+    assert reads_after_in_place_writes(text) == []
     # four layers, two grouped products each, and no ragged-dot left
     assert len(calls("grouped_matmul")) == 8 and "ragged-dot" not in text
     mem = compiled.memory_analysis()
@@ -788,6 +901,9 @@ def kda_programs(one_chip):
     mp = pytest.MonkeyPatch()
     mp.setattr(importlib.import_module("paddle_tpu.ops.flash_attention"),
                "INTERPRET", False)
+    # the engine here lies on the CPU: the test, not an option, hands it
+    # what the spec and a TPU give, the step's kernel
+    mp.setattr(llm, "_state_impl", lambda ssm_state, impls=None: "pallas")
     mp.setattr(llm, "_moe_impl", lambda net: "pallas")
     c = KDA_CELL
     cfg = KimiLinearConfig(num_hidden_layers=5, vocab_size=1024,
@@ -804,7 +920,9 @@ def kda_programs(one_chip):
                         prefill_chunk=c["chunk"], kv_dtype="bf16",
                         attention_impl="pallas")
     try:
-        assert (eng.state_impl, eng.moe_impl) == ("xla", "pallas")
+        assert (eng.state_impl, eng.moe_impl) == ("pallas", "pallas")
+        # the chunk form is the gathered one under either value
+        assert not eng._chunk_in_place
 
         def described(tree):
             return jax.tree_util.tree_map(
@@ -862,6 +980,10 @@ def test_kda_program_fits_the_chip_at_full_depth_and_keeps_state_and_pool_in_pla
     assert len(calls("paged_attention.")) == 1
     assert len(calls("paged_attention_chunk")) == (program == "mixed")
     assert len(calls("grouped_matmul")) == 8 and "ragged-dot" not in text
+    # every KDA layer's decode rows step their state through the kernel,
+    # in the decode program and in the decode half of the mixed one
+    assert len(calls("kda_step")) == 4
+    assert reads_after_in_place_writes(text) == []
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= state + pool
     # a copy of ONE layer's state rows would be 103 MB; the chunk form's
@@ -1032,6 +1154,9 @@ def gdn_programs(one_chip):
     mp = pytest.MonkeyPatch()
     mp.setattr(importlib.import_module("paddle_tpu.ops.flash_attention"),
                "INTERPRET", False)
+    # the engine here lies on the CPU: the test, not an option, hands it
+    # what the spec and a TPU give, the step's kernel
+    mp.setattr(llm, "_state_impl", lambda ssm_state, impls=None: "pallas")
     c = GDN_CELL
     cfg = OlmoHybridConfig(num_layers=4, vocab_size=1024)
     assert (cfg.hidden_size, cfg.linear_num_value_heads,
@@ -1048,7 +1173,7 @@ def gdn_programs(one_chip):
                         prefill_chunk=c["chunk"], kv_dtype="bf16",
                         attention_impl="pallas")
     try:
-        assert eng.state_impl == "xla"
+        assert eng.state_impl == "pallas" and not eng._chunk_in_place
 
         def described(tree):
             return jax.tree_util.tree_map(
@@ -1113,6 +1238,10 @@ def test_gdn_program_fits_the_chip_at_eight_layers_and_keeps_state_and_pool_in_p
 
     assert len(calls("paged_attention.")) == 1
     assert len(calls("paged_attention_chunk")) == (program == "mixed")
+    # every ``linear_attention`` layer's decode rows step their state
+    # through the kernel, in both programs
+    assert len(calls("kda_step")) == 3
+    assert reads_after_in_place_writes(text) == []
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= state + pool
     # a copy of ONE layer's state rows would be 196 MB

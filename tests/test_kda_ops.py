@@ -478,3 +478,107 @@ def test_a_decay_a_channel_lowers_to_the_text_of_the_parent_of_pr_47(form,
             sds((s, h, k), f), sds((s, h, k), f), sds((s, h, k), f),
             sds((s, h, k), f), sds((s, h), f), sds((s, h, k, k), f)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+# -- the step as a kernel over a layer's whole state array -------------------
+
+def step_inputs(rows, heads, dk, dv_live, dv, channel, seed=0):
+    """A decode tick's rows at strong decays, ``b`` over (0, 2); values (and
+    so the state) past ``dv_live`` are the stored padding: zeros."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + 200), 6)
+    stored = jnp.arange(dv) < dv_live
+    q = kda.l2norm(jax.random.normal(ks[0], (rows, heads, dk))) * dk ** -0.5
+    k = kda.l2norm(jax.random.normal(ks[1], (rows, heads, dk)))
+    v = jax.random.normal(ks[2], (rows, heads, dv)) * stored
+    log_a = -3.0 * jnp.exp(jax.random.normal(
+        ks[3], (rows, heads, dk if channel else 1)))
+    b = jax.random.uniform(ks[4], (rows, heads), minval=0.02, maxval=1.98)
+    state = jax.random.normal(ks[5], (rows + 2, heads, dk, dv)) * stored
+    return (q, k, v, log_a, b), state
+
+
+@pytest.mark.parametrize("heads,head_block", [(5, 2), (3, None)],
+                         ids=["ragged_last_block", "one_block"])
+@pytest.mark.parametrize("dk,dv_live,dv", [(16, 128, 128), (96, 192, 256)],
+                         ids=["K=V-lanes", "96x192_stored_256"])
+@pytest.mark.parametrize("channel", [True, False],
+                         ids=["decay_a_channel", "one_decay_a_head"])
+def test_step_kernel_is_kda_step_over_the_whole_array_in_place(
+        channel, dk, dv_live, dv, heads, head_block):
+    """``kda_step_kernel`` (interpret mode) against ``kda_step``: ``o`` and
+    the live rows' new state to float32 tolerance, both decays, ``K != V``,
+    a head count that leaves a ragged last block; the rows that are not
+    live, the rows past the inputs and the scratch row bit for bit; a
+    ``first`` row whose old state is NaN starts from zeros."""
+    rows = 6
+    x, state = step_inputs(rows, heads, dk, dv_live, dv, channel)
+    live = jnp.asarray([True, False, True, True, False, True])
+    first = jnp.asarray([False, False, True, False, True, False])
+    state = state.at[2].set(jnp.nan)        # the first & live row's old tile
+    assert float(x[4].max()) > 1.5 and float(jnp.exp(x[3]).min()) < 0.01
+    o, new = kda.kda_step_kernel(*x, state, live, first,
+                                 head_block=head_block)
+    entering = jnp.where((first & live)[:, None, None, None], 0.0,
+                         state[:rows])
+    want_o, want_new = kda.kda_step(*x, entering)
+    lv = np.asarray(live)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(o[lv], want_o[lv], atol=TOL)
+    np.testing.assert_allclose(new[:rows][lv], want_new[lv], atol=TOL)
+    assert np.array_equal(o[~lv], np.zeros_like(o[~lv]))
+    held = np.append(~lv, [True, True])     # and the rows past the inputs
+    assert np.array_equal(np.asarray(new[held]), np.asarray(state[held]))
+    # the stored padding stays zeros
+    assert not np.asarray(new[:rows][lv][..., dv_live:]).any()
+    # the same row from zeros, by the definition
+    ref_o, ref_s = kda.kda_recurrence(
+        *(a[2:3] for a in x), jnp.zeros_like(state[2]))
+    np.testing.assert_allclose(o[2], ref_o[0], atol=TOL)
+    np.testing.assert_allclose(new[2], ref_s, atol=TOL)
+
+
+@pytest.mark.parametrize("live", [(False,) * 4, (False, False, True, False)],
+                         ids=["nothing_live", "one_live"])
+def test_step_kernel_moves_no_row_that_is_not_live(live):
+    """With nothing live the call returns the array as it was (the held
+    tile copied onto itself); with one live row, that row alone moves."""
+    x, state = step_inputs(4, 3, 16, 128, 128, True, seed=1)
+    live = jnp.asarray(live)
+    o, new = kda.kda_step_kernel(*x, state, live, jnp.zeros((4,), bool),
+                                 head_block=2)
+    moved = np.asarray((new != state).any(axis=(1, 2, 3)))
+    assert np.array_equal(moved, np.append(np.asarray(live), [False, False]))
+    assert not np.asarray(o[~np.asarray(live)]).any()
+
+
+def test_step_kernel_refuses_a_key_size_that_is_no_multiple_of_8():
+    x, state = step_inputs(2, 2, 12, 128, 128, True)
+    with pytest.raises(ValueError, match="key size 12 is not a multiple"):
+        kda.kda_step_kernel(*x, state, jnp.ones((2,), bool),
+                            jnp.zeros((2,), bool))
+    x, state = step_inputs(2, 2, 16, 128, 128, True)
+    with pytest.raises(ValueError, match="a decay of 4 channels"):
+        kda.kda_step_kernel(x[0], x[1], x[2], x[3][..., :4], x[4], state,
+                            jnp.ones((2,), bool), jnp.zeros((2,), bool))
+
+
+def test_step_kernel_sums_in_float32_not_in_a_bfloat16_pass():
+    """The sums over ``K`` are float32 multiply-adds: against the step
+    computed in float64 the kernel errs as ``kda_step`` at ``HIGHEST``
+    does, and fifty times under a bfloat16 product's error."""
+    x, state = step_inputs(4, 2, 96, 192, 256, False, seed=3)
+    live, first = jnp.ones((4,), bool), jnp.zeros((4,), bool)
+    o, new = kda.kda_step_kernel(*x, state, live, first)
+    q, k, v, log_a, b = (np.asarray(a, np.float64) for a in x)
+    dec = np.exp(log_a)[..., None] * np.asarray(state[:4], np.float64)
+    w = b[..., None] * (v - np.einsum("rhk,rhkv->rhv", k, dec))
+    want_new = dec + k[..., None] * w[..., None, :]
+    want_o = np.einsum("rhk,rhkv->rhv", q, want_new)
+    rounded = np.einsum(
+        "rhk,rhkv->rhv", np.asarray(x[1].astype(jnp.bfloat16), np.float64),
+        np.asarray(jnp.asarray(dec, jnp.float32).astype(jnp.bfloat16),
+                   np.float64))
+    bf16_err = np.abs(rounded - np.einsum("rhk,rhkv->rhv", k, dec)).max()
+    err = max(np.abs(np.asarray(o) - want_o).max(),
+              np.abs(np.asarray(new[:4]) - want_new).max())
+    assert err < 2e-6 and bf16_err > 50 * err

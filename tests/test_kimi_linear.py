@@ -114,7 +114,8 @@ def test_one_latent_group_and_one_state_row_a_kda_layer(model):
     assert (group.v_head_dim, group.sink) == (None, False)
     spec = net.state_cache_spec()
     assert (spec["layers"], spec["conv_state"], spec["ssm_state"],
-            spec["impls"]) == (4, (3, 96), (2, 16, 16), ("xla",))
+            spec["impls"], spec["chunk_impls"]) == (
+        4, (3, 96), (2, 16, 16), ("xla", "pallas"), ("xla",))
     assert net.moe_aux_spec() == (4, 8) and net.experts_held == (0, 8)
 
 
@@ -161,6 +162,8 @@ def test_engine_holds_to_the_reference_through_chunks_and_decode(model,
     net, params, d = model
     prompts = prompts_of((70, 45, 9, 30, 61))
     with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
+        # the spec names the step's kernel too; the platform decides, and
+        # an engine off the TPU stays on ``kda_step``
         assert eng.state_impl == "xla"
         futs = [eng.submit(p, max_new_tokens=24) for p in prompts[:4]]
         outs = [f.result(timeout=900) for f in futs]
@@ -178,6 +181,58 @@ def test_engine_holds_to_the_reference_through_chunks_and_decode(model,
         assert served_gap(params, d, p, toks) <= TOL
         want = np.asarray(net.generate(jnp.asarray([p], jnp.int32), 24))
         assert toks == want[0, len(p):].tolist()
+
+
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1),
+                                   dict(decode_ticks_per_dispatch=2)],
+                         ids=["mixed_and_decode_ticks", "one_slot",
+                              "fused_slab"])
+def test_the_step_kernel_serves_what_kda_step_serves(model, knobs,
+                                                     monkeypatch):
+    """What an engine runs on a TPU (the spec names the kernel), here
+    through the Pallas interpreter: the decode rows' state stepped in
+    place by ``kda_step_kernel`` (a decay a channel; live rows only; 3
+    slots and 5 requests, so rows stand empty and are restarted) serves
+    the tokens ``kda_step`` serves, each logit within TOL of the
+    reference's best. Off the TPU an engine takes ``kda_step``; the test,
+    not an option, steers it. While tracing, a decode dispatch's
+    ``state_bytes`` count its LIVE rows' ``ssm_state`` and a mixed one's
+    chunk half the 8 rows a gathered chunk moves."""
+    from paddle_tpu.inference import llm
+    from paddle_tpu.observability import tracing
+    net, params, d = model
+    prompts = prompts_of((70, 45, 9, 30, 61))
+    monkeypatch.setattr(llm, "_state_impl",
+                        lambda ssm_state, impls=None: "pallas")
+    was = tracing.enabled()
+    tracing.enable()
+    tracing.clear()
+    try:
+        with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
+            assert eng.state_impl == "pallas" and not eng._chunk_in_place
+            futs = [eng.submit(p, max_new_tokens=24) for p in prompts[:4]]
+            outs = [f.result(timeout=900) for f in futs]
+            outs.append(eng.submit(prompts[4], max_new_tokens=24)
+                        .result(timeout=900))
+            row, slots = eng._state_row_bytes, eng.max_seqs
+        spans = tracing.finished_spans()
+    finally:
+        (tracing.enable if was else tracing.disable)()
+    for p, o in zip(prompts, outs):
+        toks = list(o["output_ids"])
+        assert served_gap(params, d, p, toks) <= TOL
+        want = np.asarray(net.generate(jnp.asarray([p], jnp.int32), 24))
+        assert toks == want[0, len(p):].tolist()
+    issued = [s for s in spans if s["name"].startswith("llm.issue.")
+              and "state_bytes" in s["attrs"]]
+    assert issued
+    for s in issued:
+        a = s["attrs"]
+        seqs = a.get("chunk_rows", 0)
+        chunk = 8 if seqs else 0
+        assert a["state_bytes"] == 2 * (
+            (slots + chunk) * row["conv_state"]
+            + (a["state_rows"] - seqs + chunk) * row["ssm_state"]), s["name"]
 
 
 def test_a_sequences_state_and_pages_are_untouched_by_its_neighbours(model):
@@ -240,6 +295,7 @@ def test_what_the_state_does_not_compose_with_is_refused_by_name(model):
                 group["row_bytes"], group["page_bytes"]) == (
             "latent", 1, 32, 128 * 4, 8 * 128 * 4)
         state = status["recurrent_state"]
+        # (the platform decides: "xla" off the TPU)
         assert state["state_impl"] == "xla" and state["rows"] == 3
         assert state["row_bytes"] == {"conv_state": 4 * 3 * 96 * 4,
                                       "ssm_state": 4 * 2 * 16 * 16 * 4}

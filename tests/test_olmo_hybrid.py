@@ -145,8 +145,9 @@ def test_one_group_of_six_heads_stored_as_eight_and_one_state_row_a_rule_layer(
     spec = net.state_cache_spec()
     # the state as STORED: a head's value of 16 in whole lanes
     assert (spec["layers"], spec["conv_state"], spec["ssm_state"],
-            spec["impls"], spec["rule"]) == (
-        6, (3, 3 * (8 + 8 + 16)), (3, 8, 128), ("xla",),
+            spec["impls"], spec["chunk_impls"], spec["rule"]) == (
+        6, (3, 3 * (8 + 8 + 16)), (3, 8, 128), ("xla", "pallas"),
+        ("xla",),
         "delta, scalar decay")
     assert net.moe_aux_spec() is None and net.loop_aux_spec() is None
 
@@ -176,6 +177,8 @@ def test_engine_holds_to_the_reference_through_chunks_and_decode(model,
     net, params, d = model
     prompts = prompts_of((70, 45, 9, 30, 61))
     with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
+        # the spec names the step's kernel too; the platform decides, and
+        # an engine off the TPU stays on ``kda_step``
         assert eng.state_impl == "xla"
         futs = [eng.submit(p, max_new_tokens=24) for p in prompts[:4]]
         outs = [f.result(timeout=900) for f in futs]
@@ -190,6 +193,38 @@ def test_engine_holds_to_the_reference_through_chunks_and_decode(model,
     for p, o in zip(prompts, outs):
         toks = list(o["output_ids"])
         assert len(toks) == 24 and not o["truncated"]
+        assert served_gap(params, d, p, toks) <= TOL
+        want = np.asarray(net.generate(jnp.asarray([p], jnp.int32), 24))
+        assert toks == want[0, len(p):].tolist()
+
+
+@pytest.mark.parametrize("knobs", [dict(), dict(max_seqs=1)],
+                         ids=["mixed_and_decode_ticks", "one_slot"])
+def test_the_step_kernel_serves_what_kda_step_serves(model, knobs,
+                                                     monkeypatch):
+    """What an engine runs on a TPU (the spec names the kernel), here
+    through the Pallas interpreter: the decode rows' state (``[3, 8, 128]``
+    as stored, ONE decay a head, write strengths in (0, 2)) stepped in
+    place by ``kda_step_kernel`` serves the
+    tokens ``kda_step`` serves, each logit within TOL of the reference's
+    best; 3 slots and 5 requests, so rows stand empty and are restarted.
+    Off the TPU an engine takes ``kda_step``; the test, not an option,
+    steers it."""
+    from paddle_tpu.inference import llm
+    net, params, d = model
+    prompts = prompts_of((70, 45, 9, 30, 61))
+    monkeypatch.setattr(llm, "_state_impl",
+                        lambda ssm_state, impls=None: "pallas")
+    with LLMEngine(net, **{"max_seqs": 3, **ENGINE, **knobs}) as eng:
+        assert eng.state_impl == "pallas" and not eng._chunk_in_place
+        futs = [eng.submit(p, max_new_tokens=24) for p in prompts[:4]]
+        outs = [f.result(timeout=900) for f in futs]
+        outs.append(eng.submit(prompts[4], max_new_tokens=24)
+                    .result(timeout=900))
+        status = dbgsrv._collect_status()[eng._status_name]
+        assert status["recurrent_state"]["state_impl"] == "pallas"
+    for p, o in zip(prompts, outs):
+        toks = list(o["output_ids"])
         assert served_gap(params, d, p, toks) <= TOL
         want = np.asarray(net.generate(jnp.asarray([p], jnp.int32), 24))
         assert toks == want[0, len(p):].tolist()
@@ -239,6 +274,7 @@ def test_what_the_state_does_not_compose_with_is_refused_by_name(model):
         assert state["rule"] == "delta, scalar decay"
         assert state["stored_shape"] == {"conv_state": [3, 96],
                                          "ssm_state": [3, 8, 128]}
+        # (the platform decides: "xla" off the TPU)
         assert state["state_impl"] == "xla" and state["rows"] == 3
         assert state["row_bytes"] == {"conv_state": 6 * 3 * 96 * 4,
                                       "ssm_state": 6 * 3 * 8 * 128 * 4}
